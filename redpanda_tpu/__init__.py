@@ -8,17 +8,17 @@ registry), re-designed TPU-first:
 - The host runtime (storage, raft, RPC, Kafka protocol, control plane) is an
   asyncio-based broker with a native extension for the hot byte paths.
 - The per-batch data plane — CRC32c validation, (de)compression staging, and
-  user map/filter transforms — executes as batched XLA/Pallas kernels over a
-  ``[partition, batch, record]`` axis on TPU, fed through a device bridge
-  (``redpanda_tpu.bridge``), with shardings laid over a ``jax.sharding.Mesh``
-  for multi-chip scale-out (``redpanda_tpu.parallel``).
+  user map/filter transforms — executes as batched XLA programs over a
+  ``[partition, batch, record]`` axis on TPU (``redpanda_tpu.ops``, driven
+  by ``redpanda_tpu.coproc.engine``), with shardings laid over a
+  ``jax.sharding.Mesh`` for multi-chip scale-out (``redpanda_tpu.parallel``).
 
 Layer map (mirrors SURVEY.md §1 of the reference analysis):
 
     utils/ hashing/ compression/ models/   foundation (bytes, CRC, codecs,
                                            record-batch domain model)
-    ops/ parallel/ bridge/                 device data plane (TPU kernels,
-                                           mesh shardings, host<->device)
+    ops/ parallel/                         device data plane (XLA programs,
+                                           mesh shardings)
     storage/                               segmented log + kvstore + snapshots
     rpc/ raft/                             internal RPC + consensus
     cluster/                               controller, topic table, allocator

@@ -658,9 +658,18 @@ class AdminServer:
             return web.json_response(
                 {"enabled": False, "hint": "coproc_enable is false"}
             )
+        from redpanda_tpu import native
+
         stats = api.engine.stats()
         return web.json_response({
             "enabled": True,
+            # platform / device_kind / count the engine's programs run on
+            # (+ a warning when no accelerator was found and the CPU
+            # backend was not asked for)
+            "device": stats.pop("device", None),
+            # the hot byte paths' native library: a failed build shows
+            # here, never as a silent switch to the numpy twins
+            "native": native.status(),
             "scripts": api.active_scripts(),
             "breaker": stats.pop("breaker", None),
             # multi-chip meshrunner block surfaced explicitly (devices,

@@ -6,6 +6,11 @@ TPU hosts the natural unit is a PROCESS, not a container: each broker is a
 detached `python -m redpanda_tpu start`, the cluster state (ports, pids,
 data dirs) lives in one JSON file, and teardown is signal + rm. Same
 lifecycle surface: start / status / stop / purge.
+
+An accelerator chip belongs to one process at a time, so ``start`` hands
+each visible chip to one broker and pins every other broker to JAX's CPU
+backend explicitly (``chip_assignment``); a broker that merely inherited
+the environment would fail at its first device touch, or hang.
 """
 
 from __future__ import annotations
@@ -23,6 +28,28 @@ DEFAULT_DIR = os.path.join(
     os.environ.get("XDG_STATE_HOME", os.path.expanduser("~/.local/state")),
     "rptpu-container",
 )
+
+
+def chip_assignment(n_brokers: int, n_chips: int) -> list[dict]:
+    """Per broker, the environment that decides its JAX platform.
+
+    Broker i < n_chips gets chip i: with one chip the inherited environment
+    already means "the chip"; with several, libtpu's per-process variables
+    narrow the broker to its own. Every other broker is pinned to the CPU
+    backend."""
+    envs = []
+    for i in range(n_brokers):
+        if i >= n_chips:
+            envs.append({"JAX_PLATFORMS": "cpu"})
+        elif n_chips == 1:
+            envs.append({})
+        else:
+            envs.append({
+                "TPU_VISIBLE_CHIPS": str(i),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+            })
+    return envs
 
 
 def _free_port() -> int:
@@ -91,6 +118,10 @@ class LocalCluster:
             for _ in range(n)
         ]
         seeds = ",".join(f"{i}@127.0.0.1:{p['rpc']}" for i, p in enumerate(ports))
+        from redpanda_tpu.utils.platform import visible_chips
+
+        n_chips = visible_chips()
+        envs = chip_assignment(n, n_chips)
         nodes = []
         for i, p in enumerate(ports):
             data_dir = os.path.join(self.base_dir, f"n{i}")
@@ -113,8 +144,12 @@ class LocalCluster:
             proc = subprocess.Popen(
                 cmd, stdout=log, stderr=subprocess.STDOUT,
                 start_new_session=True,  # survives the rpk process exiting
+                env={**os.environ, **envs[i]},
             )
-            nodes.append({"node_id": i, "pid": proc.pid, **p, "data_dir": data_dir})
+            nodes.append({
+                "node_id": i, "pid": proc.pid, **p, "data_dir": data_dir,
+                "device": f"chip {i}" if i < n_chips else "cpu",
+            })
         state = {"nodes": nodes, "started_at": time.time()}
         self._save(state)
         deadline = time.monotonic() + wait_s
@@ -198,7 +233,8 @@ def cmd_container(args) -> int:
         for nd in state["nodes"]:
             print(
                 f"  node {nd['node_id']}: kafka 127.0.0.1:{nd['kafka']} "
-                f"admin 127.0.0.1:{nd['admin']} pid {nd['pid']}"
+                f"admin 127.0.0.1:{nd['admin']} pid {nd['pid']} "
+                f"device {nd['device']}"
             )
         return 0
     if args.container_cmd == "status":

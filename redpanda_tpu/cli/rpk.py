@@ -424,6 +424,20 @@ async def cmd_debug(args) -> int:
         if not body.get("enabled"):
             print("coproc disabled (set coproc_enable: true)")
             return 0
+        dev = body.get("device") or {}
+        print(
+            f"device:  platform={dev.get('platform', '?')} "
+            f"device_kind={dev.get('device_kind', '?')} "
+            f"count={dev.get('count', '?')}"
+        )
+        if dev.get("warning"):
+            print(f"  WARNING: {dev['warning']}")
+        native = body.get("native") or {}
+        print(
+            "native:  "
+            + ("loaded" if native.get("loaded") else "NOT LOADED")
+            + (f" — {native['build_error']}" if native.get("build_error") else "")
+        )
         b = body.get("breaker") or {}
         print(
             f"breaker: {b.get('state', '?'):<10} trips={b.get('trips', 0)} "
@@ -452,10 +466,11 @@ async def cmd_debug(args) -> int:
             "columnar_backend", "host_pool_probe", "host_pool_probe_prev",
             "host_pool_recal", "columnar_probe", "parse_path", "parse_probe",
             "colcache", "arena", "breakers", "lockwatch", "leakwatch",
+            "mesh_error", "device_launches_by_script",
         ):
             if stats.get(k) is not None:
                 print(f"  {k:<28}{stats[k]}")
-        return 0
+        return 0 if native.get("loaded") else 1
 
     if args.debug_cmd == "profile":
         if args.perfetto:

@@ -15,12 +15,10 @@ from __future__ import annotations
 import ctypes
 import ctypes.util
 import struct
+import threading
 import zlib
 
-try:
-    import zstandard
-except ImportError:  # optional: zstd produce/fetch raises, everything else works
-    zstandard = None
+import zstandard
 
 # ------------------------------------------------------------------ gzip
 
@@ -34,19 +32,22 @@ def gzip_uncompress(data: bytes) -> bytes:
 
 
 # ------------------------------------------------------------------ zstd
-_zc = None
-_zd = None
+# One reusable (compressor, decompressor) pair per THREAD (parity with the
+# per-core stream_zstd workspaces): a zstandard object wraps one native
+# context and releases the GIL inside it, so two threads in the same
+# object crash the process — and the coproc engine seals output batches
+# from several tick and pool threads at once.
+_zstd_local = threading.local()
 
 
 def _zstd_ctx():
-    global _zc, _zd
-    if zstandard is None:
-        raise RuntimeError("zstd codec unavailable: `zstandard` is not installed")
-    if _zc is None:
-        # per-process reusable contexts (parity with stream_zstd workspaces)
-        _zc = zstandard.ZstdCompressor(level=3)
-        _zd = zstandard.ZstdDecompressor()
-    return _zc, _zd
+    ctx = getattr(_zstd_local, "ctx", None)
+    if ctx is None:
+        ctx = _zstd_local.ctx = (
+            zstandard.ZstdCompressor(level=3),
+            zstandard.ZstdDecompressor(),
+        )
+    return ctx
 
 
 def zstd_compress(data: bytes) -> bytes:

@@ -82,10 +82,8 @@ def uncompress(data: bytes, codec: Compression | int) -> bytes:
 def is_available(codec: Compression | int) -> bool:
     """Can the active backend actually run this codec in THIS process?
 
-    gzip (stdlib zlib) is always available; zstd needs the `zstandard`
-    package; lz4/snappy need the system libraries. Callers that merely
-    prefer a codec (e.g. the coproc output recompressor) use this to fall
-    back instead of failing per batch.
+    gzip (stdlib zlib) and zstd (the `zstandard` package, a hard
+    dependency) always are; lz4/snappy need the system libraries.
     """
     codec = Compression(codec)
     if codec == Compression.none:
@@ -94,10 +92,8 @@ def is_available(codec: Compression | int) -> bool:
         return False  # the active backend's table is authoritative
     if _active is not _HOST:
         return True  # plugin backends declare support via their table
-    if codec == Compression.gzip:
-        return True  # stdlib zlib
-    if codec == Compression.zstd:
-        return _codecs.zstandard is not None
+    if codec in (Compression.gzip, Compression.zstd):
+        return True
     try:
         if codec == Compression.lz4:
             _codecs._lz4_handle()

@@ -122,6 +122,11 @@ class CoprocApi:
 
     # ------------------------------------------------------------ lifecycle
     async def start(self) -> "CoprocApi":
+        # name the platform at start, in the log and in /v1/coproc/status:
+        # a broker that was not pinned to the CPU backend and finds no
+        # accelerator must not look like one that has it. Backend
+        # start-up takes seconds on an accelerator, so off the loop.
+        await asyncio.to_thread(self.engine.resolve_device)
         await self.pacemaker.start()
         # topic creation happens inside the listener loop with retries:
         # at startup the cluster may not have a quorum of REGISTERED nodes

@@ -14,7 +14,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from redpanda_tpu.compression import compress, registry, uncompress
+from redpanda_tpu.compression import compress, uncompress
 from redpanda_tpu.models.record import Compression, Record, RecordBatch, RecordBatchHeader
 from redpanda_tpu.utils.vint import decode_zigzag, encode_zigzag
 
@@ -532,10 +532,6 @@ def build_output_batch(
         return None
     attrs = 0
     if len(payload) >= compress_threshold and codec != Compression.none:
-        if not registry.is_available(codec):
-            # degrade, don't drop: a missing optional codec library must
-            # not silently discard every transformed batch (gzip is stdlib)
-            codec = Compression.gzip
         payload = compress(payload, codec)
         attrs = int(codec)
     hdr = RecordBatchHeader(

@@ -70,8 +70,6 @@ PARSE_PATH = "parse_path"
 # device-resident column cache (coproc/colcache.py): budget/eviction
 # pressure notes land here when the cache has to shed entries
 COLUMN_CACHE = "column_cache"
-# bench.py regression-diagnosis verdicts (A/A-bracketed config reruns)
-DIAGNOSIS = "diagnosis"
 # coproc_lockwatch: each newly observed runtime lock-order edge journals
 # here (coproc/lockwatch.py) — the dynamic validation trail of the
 # pandaraces static acquisition graph
@@ -99,7 +97,7 @@ TREND = "trend"
 
 DOMAINS = (
     HOST_POOL, COLUMNAR_BACKEND, DEVICE_LZ4, BREAKER, HARVEST_PATH,
-    SHARDED_SEAL, DEADLINE, PARSE_PATH, COLUMN_CACHE, DIAGNOSIS, LOCKWATCH,
+    SHARDED_SEAL, DEADLINE, PARSE_PATH, COLUMN_CACHE, LOCKWATCH,
     LEAKWATCH, MESH, ADMISSION, TREND,
 )
 

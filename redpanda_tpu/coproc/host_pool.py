@@ -1,7 +1,8 @@
 """Host-stage worker pool: per-core sharding of the engine's record stages.
 
-BENCH_r05 made the bottleneck explicit: with the device predicate leg down
-to ~2% of stage wall time, the engine is bound by SINGLE-THREADED host
+A CPU-sandbox bench capture made the bottleneck explicit: with the device
+predicate leg down to ~2% of stage wall time, the engine is bound by
+SINGLE-THREADED host
 stages — ``t_explode_find`` alone is ~57% and projection extraction another
 ~26%. Every one of those stages is a ctypes crossing (GIL released) or a
 bulk numpy pass over **disjoint record ranges**, which is the classic

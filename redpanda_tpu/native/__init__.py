@@ -1,16 +1,20 @@
 """ctypes loader for the native runtime helpers (native/redpanda_native.cc).
 
-Builds on demand with `make` the first time it is imported; all callers must
-tolerate `lib is None` (pure numpy fallbacks exist for every entry point).
+Builds on demand with `make` the first time it is imported. Callers still
+tolerate `lib is None` (pure numpy twins exist for every entry point and
+tests exercise them), but a failed build is never silent: see ``status()``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 
 import numpy as np
+
+logger = logging.getLogger("rptpu.native")
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))), "native")
 _SO = os.path.join(_NATIVE_DIR, "libredpanda_native.so")
@@ -773,10 +777,14 @@ class _NativeLib:
 
 
 def _build_and_load():
+    """(lib or None, build error or None). ``make`` decides staleness (a
+    cheap no-op when the .so is current). A failed build is logged at
+    ERROR and kept in ``build_error`` whether or not an older .so could
+    still be loaded — ``status()`` carries it into the broker's
+    /v1/coproc/status, and chip_smoke.py fails on it."""
+    error = None
     src = os.path.join(_NATIVE_DIR, "redpanda_native.cc")
     if os.path.exists(src):
-        # Let make's own dependency rule decide staleness (cheap no-op when
-        # the .so is current); fall back to an existing .so if make fails.
         try:
             subprocess.run(
                 ["make", "-C", _NATIVE_DIR],
@@ -784,17 +792,44 @@ def _build_and_load():
                 capture_output=True,
                 timeout=120,
             )
-        except Exception:
-            pass
-    if not os.path.exists(_SO):
-        return None
-    try:
-        return _NativeLib(ctypes.CDLL(_SO))
-    except (OSError, AttributeError):
-        # AttributeError = a stale .so missing a required symbol; a raising
-        # module-level import would evict the module and re-run `make` on
-        # every later _native() call
-        return None
+        except subprocess.CalledProcessError as exc:
+            error = f"make failed ({exc.returncode}): " + exc.stderr.decode(
+                errors="replace"
+            )[-2000:]
+        except (OSError, subprocess.TimeoutExpired) as exc:
+            error = f"make did not run: {exc!r}"
+    loaded = None
+    if os.path.exists(_SO):
+        try:
+            loaded = _NativeLib(ctypes.CDLL(_SO))
+        except (OSError, AttributeError) as exc:
+            # AttributeError = a stale .so missing a required symbol; a
+            # raising module-level import would evict the module and
+            # re-run `make` on every later _native() call
+            error = (error + "; " if error else "") + f"load failed: {exc!r}"
+    elif error is None:
+        error = f"{_SO} does not exist and there is no source to build it"
+    if error is not None:
+        logger.error(
+            "native library %s: %s",
+            "is STALE (older build loaded)" if loaded else "unavailable "
+            "(numpy twins in use)",
+            error,
+        )
+    return loaded, error
 
 
-lib = _build_and_load()
+lib, build_error = _build_and_load()
+
+
+def status() -> dict:
+    """Whether the native library loaded, from what build, and which
+    optional entry points (``has_*``) it exports."""
+    return {
+        "loaded": lib is not None,
+        "build_error": build_error,
+        "symbols": {
+            k: bool(v) for k, v in sorted(vars(lib).items())
+            if k.startswith("has_")
+        } if lib is not None else {},
+    }

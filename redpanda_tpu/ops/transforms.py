@@ -351,11 +351,8 @@ def _find_byte_from(jnp, window, byte: int):
 
 
 # ------------------------------------------------------------ compiler
-@functools.lru_cache(maxsize=64)
-def _compile_cached(spec_json: str, r_in: int):
-    import jax
-    import jax.numpy as jnp
-
+def _validated(spec_json: str, r_in: int):
+    """(spec, r_out) of a payload-pipeline spec, or ValueError."""
     spec = TransformSpec.from_json(spec_json)
     if spec.where is not None:
         raise ValueError(
@@ -373,66 +370,82 @@ def _compile_cached(spec_json: str, r_in: int):
             raise ValueError("projected width exceeds input width")
     else:
         r_out = r_in
+    return spec, r_out
 
-    @jax.jit
+
+def _transform_body(xp, spec: TransformSpec, r_out: int):
+    """The transform as array code over namespace ``xp``: jax.numpy for the
+    device program, numpy for the engine's host fallback. Every operation
+    is an integer or boolean one, so the two evaluate bit-identically."""
+    mapper = spec.mapper
+
     def fn(data, lengths):
-        data = data.astype(jnp.uint8)
-        lengths = lengths.astype(jnp.int32)
+        data = data.astype(xp.uint8)
+        lengths = lengths.astype(xp.int32)
         keep = lengths > 0
         for f in spec.filters:
-            idx = _find_pattern(jnp, data, lengths, f.pattern, f.require_nonnum_suffix)
+            idx = _find_pattern(xp, data, lengths, f.pattern, f.require_nonnum_suffix)
             hit = idx >= 0
             keep = keep & (~hit if f.negate else hit)
 
         if isinstance(mapper, _MapUppercase):
             is_lower = (data >= ord("a")) & (data <= ord("z"))
-            out = jnp.where(is_lower, data - 32, data)
+            out = xp.where(is_lower, data - 32, data)
             return out, lengths, keep
         if isinstance(mapper, _MapProject):
             n = data.shape[0]
             parts = []
-            ok_all = jnp.ones(n, dtype=bool)
+            ok_all = xp.ones(n, dtype=bool)
             for f in mapper.fields:
                 if isinstance(f, Int):
                     pat = f'"{f.key}":'.encode()
-                    pos = _find_pattern(jnp, data, lengths, pat)
-                    vpos = jnp.where(pos >= 0, pos + len(pat), jnp.int32(-1))
-                    val, ok = _parse_int_at(jnp, data, vpos)
+                    pos = _find_pattern(xp, data, lengths, pat)
+                    vpos = xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
+                    val, ok = _parse_int_at(xp, data, vpos)
                     ok_all = ok_all & ok
-                    le = val.astype(jnp.uint32)
+                    le = val.astype(xp.uint32)
                     parts.append(
-                        jnp.stack(
-                            [(le >> (8 * k)).astype(jnp.uint8) for k in range(4)], axis=1
+                        xp.stack(
+                            [(le >> (8 * k)).astype(xp.uint8) for k in range(4)], axis=1
                         )
                     )
                 else:
                     pat = f'"{f.key}":"'.encode()
-                    pos = _find_pattern(jnp, data, lengths, pat)
-                    spos = jnp.where(pos >= 0, pos + len(pat), jnp.int32(-1))
-                    win = _gather_window(jnp, data, spos, f.max_len + 1)
-                    slen = _find_byte_from(jnp, win, ord('"'))
+                    pos = _find_pattern(xp, data, lengths, pat)
+                    spos = xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
+                    win = _gather_window(xp, data, spos, f.max_len + 1)
+                    slen = _find_byte_from(xp, win, ord('"'))
                     found_quote = slen <= f.max_len
-                    slen = jnp.minimum(slen, f.max_len)
+                    slen = xp.minimum(slen, f.max_len)
                     ok_all = ok_all & (pos >= 0) & found_quote
                     body = win[:, : f.max_len]
-                    mask = jnp.arange(f.max_len, dtype=jnp.int32)[None, :] < slen[:, None]
-                    body = jnp.where(mask, body, jnp.uint8(0))
-                    lenhdr = jnp.stack(
+                    mask = xp.arange(f.max_len, dtype=xp.int32)[None, :] < slen[:, None]
+                    body = xp.where(mask, body, xp.uint8(0))
+                    lenhdr = xp.stack(
                         [
-                            (slen & 0xFF).astype(jnp.uint8),
-                            ((slen >> 8) & 0xFF).astype(jnp.uint8),
+                            (slen & 0xFF).astype(xp.uint8),
+                            ((slen >> 8) & 0xFF).astype(xp.uint8),
                         ],
                         axis=1,
                     )
-                    parts.append(jnp.concatenate([lenhdr, body], axis=1))
-            out = jnp.concatenate(parts, axis=1)
+                    parts.append(xp.concatenate([lenhdr, body], axis=1))
+            out = xp.concatenate(parts, axis=1)
             keep2 = keep & ok_all
-            out_len = jnp.where(keep2, jnp.int32(r_out), 0)
+            out_len = xp.where(keep2, xp.int32(r_out), 0)
             return out, out_len, keep2
         # identity map
         return data, lengths, keep
 
-    return fn, r_out
+    return fn
+
+
+@functools.lru_cache(maxsize=64)
+def _compile_cached(spec_json: str, r_in: int):
+    import jax
+    import jax.numpy as jnp
+
+    spec, r_out = _validated(spec_json, r_in)
+    return jax.jit(_transform_body(jnp, spec, r_out)), r_out
 
 
 def compile_transform(spec: TransformSpec, r_in: int):
@@ -443,6 +456,15 @@ def compile_transform(spec: TransformSpec, r_in: int):
     """
     fn, _ = _compile_cached(spec.to_json(), int(r_in))
     return fn
+
+
+def compile_transform_host(spec: TransformSpec, r_in: int):
+    """compile_transform's numpy twin: the same array code with no JAX
+    backend under it (the engine's exact payload fallback)."""
+    import numpy as np
+
+    spec, r_out = _validated(spec.to_json(), int(r_in))
+    return _transform_body(np, spec, r_out)
 
 
 def transform_out_width(spec: TransformSpec, r_in: int) -> int:

@@ -19,10 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from redpanda_tpu.parallel.mesh import PARTITION_AXIS

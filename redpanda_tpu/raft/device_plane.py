@@ -13,9 +13,8 @@ Where that program runs is a MEASURED decision, exactly like the coproc
 engine's probes: the first representative validation times the device
 step against the host ``crc32c_many`` oracle on the same rows and the
 process keeps the winner (``host_pool.PROBE_MARGIN`` posture, journaled
-in the governor's ``mesh`` domain). On a tunneled link the host wins and
-the plane honestly self-demotes; on co-located chips the mesh step wins.
-Either backend is bit-exact — ``validate`` and ``tally_votes`` return
+in the governor's ``mesh`` domain). Which side wins on a local chip is not
+measured yet (ROADMAP B8). Either backend is bit-exact — ``validate`` and ``tally_votes`` return
 identical arrays, only the executor changes.
 
 Consumers: ``Consensus._do_handle_append`` (follower-side batched CRC

@@ -3,7 +3,7 @@
 import time
 
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 class Runner:

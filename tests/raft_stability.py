@@ -96,15 +96,11 @@ def flaky_election_retry(reason: str, times: int = 2):
                     kwargs["tmp_path"] = retry_dir
                 try:
                     return fn(*args, **kwargs)
-                except (AssertionError, TimeoutError, asyncio.TimeoutError) as e:
+                except (AssertionError, TimeoutError) as e:
                     last = e
                     # a bare TimeoutError (asyncio.wait_for; often empty
-                    # str) is a liveness failure by definition — retryable.
-                    # asyncio.TimeoutError is NOT a builtin-TimeoutError
-                    # subclass until 3.11, and this repo floors at 3.10
-                    thrash = isinstance(
-                        e, (TimeoutError, asyncio.TimeoutError)
-                    ) or bool(
+                    # str) is a liveness failure by definition — retryable
+                    thrash = isinstance(e, TimeoutError) or bool(
                         _ELECTION_THRASH_RE.search(str(e))
                     )
                     if attempt + 1 >= times or not thrash:

@@ -244,11 +244,7 @@ def test_microbench_runs_and_reports(tmp_path):
         "batch_decode_per_s", "compaction_keyindex_keys_per_s",
         "allocator_assignments_per_s", "rpc_echo_rtt_per_s",
     }
-    from redpanda_tpu.compression import is_available
-    from redpanda_tpu.models.record import Compression
-
-    if is_available(Compression.zstd):
-        expected |= {"zstd_compress_mb_s", "zstd_uncompress_mb_s"}
+    expected |= {"zstd_compress_mb_s", "zstd_uncompress_mb_s"}
     assert expected <= set(out), out
     # rates/costs must be positive; the tracer-overhead percentages and
     # the propagation bench's disabled-tracer wire delta are MEANT to sit

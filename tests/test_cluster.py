@@ -501,7 +501,7 @@ def test_shard_table_stable_and_grouped():
     "load-delayed election and leave no leader within the wait budget"
 )
 def test_offsets_gap_free_across_leadership_transfers(tmp_path):
-    """VERDICT round-1 acceptance for offset translation: force leadership
+    """Round-1 acceptance for offset translation: force leadership
     changes mid-produce (each election/config change appends non-data
     batches to the raft log) and assert the Kafka-visible offsets stay
     contiguous from 0 with no client-visible gaps."""

@@ -92,14 +92,7 @@ def test_process_batch_compressed_input_and_output():
         ProcessBatchRequest([ProcessBatchItem(1, NTP.kafka("orders", 3), [batch])])
     )
     ob = reply.items[0].batches[0]
-    # zstd-recompressed output; without the zstandard package the engine
-    # degrades to gzip rather than dropping batches (registry.is_available)
-    from redpanda_tpu.compression import is_available
-
-    expected = (
-        Compression.zstd if is_available(Compression.zstd) else Compression.gzip
-    )
-    assert ob.header.compression == expected
+    assert ob.header.compression == Compression.zstd
     assert ob.header.record_count == 10
     assert ob.verify_kafka_crc()
     import struct
@@ -384,3 +377,37 @@ def test_payload_reply_parity_ptr_vs_classic(monkeypatch):
     if "t_explode_ptrs" in st_ptr:  # native present: the lane engaged
         assert "t_explode_ptrs" not in st_classic
         assert "t_explode" in st_classic
+
+
+def test_stats_name_the_device_and_count_device_launches():
+    """n_launches counts every launch; n_device_launches only those whose
+    program ran on the device, per script too; the first run of a program
+    is its compile, counted apart; and stats() names the platform once
+    the engine has touched JAX (never before)."""
+    from redpanda_tpu.ops.exprs import field
+    from redpanda_tpu.ops.transforms import where
+
+    spec = where(field("level") == "error")
+    req = ProcessBatchRequest(
+        [ProcessBatchItem(7, NTP.kafka("orders", 0), [_json_batch(20)])]
+    )
+    stats = {}
+    for mode in ("columnar_device", "columnar_host"):
+        engine = TpuEngine(row_stride=256, force_mode=mode, host_workers=0)
+        _deploy(engine, 7, spec=spec)
+        assert engine.stats()["device"] is None
+        engine.process_batch(req)
+        engine.process_batch(req)
+        stats[mode] = engine.stats()
+        engine.shutdown()
+    dev, host = stats["columnar_device"], stats["columnar_host"]
+    assert dev["device"] == {
+        "platform": "cpu", "device_kind": "cpu", "count": 8, "cpu_pinned": True,
+    }
+    assert dev["n_launches"] == dev["n_device_launches"] == 2
+    assert dev["device_launches_by_script"] == {7: 2}
+    assert dev["n_compiles"] == 1 and dev["t_compile"] > 0
+    assert host["n_launches"] == 2
+    assert host.get("n_device_launches", 0) == 0
+    assert host["device_launches_by_script"] == {}
+    assert host["device"] is None

@@ -621,7 +621,12 @@ def test_breaker_state_gauge_is_per_domain_labeled():
     assert gauge("harvest") == faults.STATE_NUM[faults.STATE_CLOSED]
 
 
-def test_payload_mode_dispatch_fault_exact_fallback():
+def test_payload_mode_dispatch_fault_exact_fallback(monkeypatch):
+    """The payload lane's fallback is the same integer program in numpy:
+    exact, and with no JAX backend under it (a process started with
+    JAX_PLATFORMS=tpu has no CPU backend to fall back to)."""
+    import jax
+
     spec = filter_contains(b"error")
 
     def mk(**kw):
@@ -636,6 +641,12 @@ def test_payload_mode_dispatch_fault_exact_fallback():
 
     baseline = mk().process_batch(_req())
     engine = mk(launch_retries=0, breaker_threshold=100)
+
+    def no_backend(*a, **k):
+        raise RuntimeError("Unknown backend cpu")
+
+    monkeypatch.setattr(jax, "local_devices", no_backend)
+    monkeypatch.setattr(jax, "device_put", no_backend)
     honey_badger.enable()
     honey_badger.set_exception(faults.MODULE, faults.DEVICE_DISPATCH)
     try:
@@ -644,7 +655,9 @@ def test_payload_mode_dispatch_fault_exact_fallback():
         honey_badger.unset(faults.MODULE, faults.DEVICE_DISPATCH)
         honey_badger.disable()
     assert _payloads(faulted) == _payloads(baseline)
-    assert engine.stats()["n_fallback_rows"] > 0
+    stats = engine.stats()
+    assert stats["n_fallback_rows"] > 0
+    assert stats.get("n_device_launches", 0) == 0, "nothing ran on the device"
 
 
 def test_sandbox_compile_fault_refuses_registration():

@@ -138,6 +138,51 @@ def test_codec_none_passthrough():
     assert compress(b"abc", Compression.none) == b"abc"
 
 
+def test_zstd_is_safe_from_many_threads():
+    """The coproc engine seals output batches from several tick and pool
+    threads at once. A zstandard context shared between threads releases
+    the GIL inside native code and segfaulted the broker under two
+    concurrent scripts; the codec keeps one context per thread."""
+    import os
+    import sys
+    import threading
+    import time
+
+    rng = np.random.default_rng(5)
+    blobs = [
+        bytes(rng.integers(0, 8, 64 * 1024, dtype=np.uint8)) for _ in range(4)
+    ]
+    stop = time.monotonic() + 1.5
+    errors: list[BaseException] = []
+    rounds = [0]
+
+    def worker(k: int) -> None:
+        try:
+            while time.monotonic() < stop:
+                blob = blobs[k % len(blobs)]
+                assert uncompress(compress(blob, Compression.zstd), Compression.zstd) == blob
+                rounds[0] += 1
+        except BaseException as exc:  # noqa: BLE001 — reported by the asserting thread
+            errors.append(exc)
+
+    threads = [
+        threading.Thread(target=worker, args=(k,))
+        for k in range(2 * (os.cpu_count() or 4))
+    ]
+    saved = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(saved)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert rounds[0] > 0
+
+
 # ------------------------------------------------------------------ record model
 def _mk_records(n=5):
     return [

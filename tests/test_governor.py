@@ -443,7 +443,9 @@ def test_deadline_source_ignores_timeout_inflated_stage_histogram():
 
 def test_engine_device_legs_feed_success_only_histogram():
     """A real device-leg success records exactly one sample into the
-    domain's device-leg histogram; an injected failure records none."""
+    domain's device-leg histogram; an injected failure records none, and
+    neither does a program's first run (trace + compile is not a
+    steady-state leg: it is stats()["t_compile"])."""
     from redpanda_tpu.observability import probes
 
     engine = _engine(
@@ -452,6 +454,9 @@ def test_engine_device_legs_feed_success_only_histogram():
     )
     hist = probes.coproc_device_leg_hist(faults.DEVICE_DISPATCH).hist
     before = hist.count
+    engine.process_batch(_req())
+    assert hist.count == before
+    assert engine.stats()["n_compiles"] == 1.0
     engine.process_batch(_req())
     after_success = hist.count
     assert after_success > before

@@ -323,7 +323,7 @@ def test_simple_commit_rejected_on_live_group():
 
 
 def test_group_topic_compaction_shrinks_and_replays(tmp_path):
-    """VERDICT round 1 acceptance: a group topic with many commits for the
+    """Round-1 acceptance: a group topic with many commits for the
     same key compacts down to live keys only, and a restart replays the
     compacted log to the correct offsets."""
     async def main():

@@ -67,11 +67,6 @@ def test_header_roundtrip_and_corruption():
 
 
 def test_frame_compression_roundtrip():
-    from redpanda_tpu.compression import is_available
-    from redpanda_tpu.models.record import Compression
-
-    if not is_available(Compression.zstd):
-        pytest.skip("rpc wire compression is zstd by protocol; zstandard not installed")
     payload = b"z" * 4096
     framed = wire.frame(payload, meta=1, correlation_id=2, compress=True)
     h = wire.Header.decode(framed[: wire.HEADER_SIZE])

@@ -323,7 +323,7 @@ def test_cache_rejects_escaping_keys(tmp_path):
 
 
 def test_tiered_read_after_prefix_truncate(tmp_path):
-    """VERDICT round-1 acceptance: produce -> archive -> local prefix
+    """Round-1 acceptance: produce -> archive -> local prefix
     truncate -> consume from offset 0 succeeds via the remote + cache."""
     async def main():
         from redpanda_tpu.cloud_storage.cache import CacheService

@@ -62,8 +62,6 @@ sys.path.insert(0, REPO)
 # the S3 imposter (tiered-storage scenarios) lives with the tests
 sys.path.insert(0, os.path.join(REPO, "tests"))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 # ================================================================ scenarios
 # Objective threshold notes: clean runs must PASS on a busy shared box, so
@@ -873,6 +871,29 @@ async def _disarm_chaos(stack, chaos: dict) -> None:
                 pass  # a node lost mid-chaos: nothing to disarm there
 
 
+async def _engine_devices(stack) -> list:
+    """Per node, the platform its coproc engine runs on as the broker
+    reports it (/v1/coproc/status "device"), or None where coproc is off —
+    so a report says where its numbers were taken, not where the
+    environment suggested. The in-process stack is one JAX process and
+    runs on whatever platform the environment selects; the process stack's
+    brokers are CPU-pinned by the test harness."""
+    import aiohttp
+
+    out = []
+    async with aiohttp.ClientSession() as sess:
+        for port in stack.admin_ports:
+            try:
+                async with sess.get(
+                    f"http://127.0.0.1:{port}/v1/coproc/status",
+                    timeout=aiohttp.ClientTimeout(total=5),
+                ) as r:
+                    out.append((await r.json()).get("device"))
+            except (aiohttp.ClientError, asyncio.TimeoutError, ValueError) as exc:
+                out.append({"error": repr(exc)})
+    return out
+
+
 async def _scrape_counter_total(stack, name: str) -> float:
     """Sum one counter series across every node's /metrics (uniform for
     both backends: in-process stacks expose admin /metrics too)."""
@@ -1166,6 +1187,7 @@ async def run_scenario_async(
             }
         report.update({
             "backend": stack.backend,
+            "engine_devices": await _engine_devices(stack),
             "chaos": chaos_info,
             "duration_s": round(elapsed, 3),
             "setup_s": round(t0 - t_setup0, 3),
@@ -1556,6 +1578,7 @@ async def run_overload_async(
             "scenario": name,
             "kind": "overload",
             "backend": stack.backend,
+            "engine_devices": await _engine_devices(stack),
             "nodes": s["nodes"],
             "partitions": s["partitions"],
             "overload_factor": s["overload_factor"],

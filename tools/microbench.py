@@ -51,11 +51,9 @@ def bench_xxhash(secs: float) -> dict:
 
 
 def bench_zstd_stream(secs: float) -> dict:
-    from redpanda_tpu.compression import compress, is_available, uncompress
+    from redpanda_tpu.compression import compress, uncompress
     from redpanda_tpu.models.record import Compression
 
-    if not is_available(Compression.zstd):
-        return {"zstd_skipped": "zstandard not installed"}
     rng = np.random.default_rng(7)
     # compressible-ish payload (zstd_stream_bench uses realistic frames)
     blob = bytes(rng.integers(0, 16, 1 << 20, dtype=np.uint8))
@@ -313,7 +311,7 @@ def bench_mesh_scaling(secs: float) -> dict:
     from redpanda_tpu.hashing.crc32c import crc32c
     from redpanda_tpu.parallel import make_crc_vote_step, partition_mesh, shard_to_mesh
 
-    devs = jax.local_devices(backend="cpu")
+    devs = jax.devices()
     rng = np.random.default_rng(7)
     n_batches, r, groups = 512, 1024, 64
     payloads = [rng.bytes(r - (i % 129)) for i in range(n_batches)]
@@ -1509,6 +1507,9 @@ def main(argv=None) -> int:
         " implies the explode_find bench",
     )
     args = p.parse_args(argv)
+    from redpanda_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()  # before any bench's first jit
     names = list(args.benches)
     if args.only:
         names.extend(n.strip() for n in args.only.split(","))

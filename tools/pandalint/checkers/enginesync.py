@@ -6,8 +6,8 @@ D2H round trip is the dedicated harvester thread (engine._harvest_loop runs
 on its own daemon thread, off the event loop). A ``np.asarray(device_arr)``
 / ``.tobytes()`` / ``block_until_ready()`` inside an ``async def`` — or
 inside a tick/harvest-named loop body — blocks the broker's event loop for
-a full link round trip (~70 ms over a tunneled link): raft heartbeats stop,
-elections fire, and the launch pipeline serializes.
+a full link round trip plus whatever device work is still queued: raft
+heartbeats stall and the launch pipeline serializes.
 
 Heuristic scope (no type inference): any call of these shapes inside an
 ``async def``, or inside a function whose name mentions tick/harvest, in

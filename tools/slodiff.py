@@ -8,17 +8,15 @@ PRs stop being unjudged by definition." This module is that diff.
 Usage::
 
     python -m tools.slodiff SLO_r10.json SLO_r14.json [--noise-band-pct 20]
-    python -m tools.slodiff BENCH_r05.json BENCH_r06.json --json
+    python -m tools.slodiff BENCH_r02.json BENCH_r06.json --json
 
-Verdict vocabulary (the BENCH_r06 ``config3_diagnosis`` vocabulary,
-promoted to the release flow):
+Verdict vocabulary:
 
 - **PASS**    — no worse than the baseline (or better) on this item.
 - **WEATHER** — worse, but inside the noise band: the same-code A/A skew
   measured on the box (``aa_skew_pct`` when the artifacts carry it, the
   ``--noise-band-pct`` knob otherwise) is larger than the move, so the
-  delta is indistinguishable from weather — exactly the judgment the
-  r04→r05 payload-bridge "drop" needed before anyone bisected it.
+  delta is indistinguishable from weather.
 - **REGRESS** — worse beyond the band, or a hard status flip
   (an objective that PASSed the baseline now FAILs).
 
