@@ -792,8 +792,10 @@ class AdminServer:
     # ------------------------------------------------------------ pulse
     async def _profile(self, req: web.Request) -> web.Response:
         """pandapulse status: flight-recorder summary, per-stage totals,
-        wall-profiler folded-stack top — `rpk debug profile` renders this;
-        profile.json in the debug bundle."""
+        wall-profiler folded-stack top, and the event loop's newest stalls
+        (loopwatch) — `rpk debug profile` renders this; profile.json in the
+        debug bundle."""
+        from redpanda_tpu.observability.loopwatch import loopwatch
         from redpanda_tpu.observability.pulse import pulse
 
         try:
@@ -802,6 +804,7 @@ class AdminServer:
             return web.json_response({"error": "top must be an int"}, status=400)
         body = pulse.snapshot(top=top)
         body["node"] = self.broker.config.node_id
+        body["loop_stalls"] = loopwatch.stalls()
         if req.query.get("stacks", "").lower() in ("1", "true", "yes"):
             body["stacks"] = pulse.profiler.stacks()
             body["folded"] = pulse.profiler.folded()

@@ -130,6 +130,13 @@ class Application:
             windows=c.history_windows,
             max_bytes=c.history_max_bytes,
         )
+        # loopwatch: the event loop's lag histogram and stall ring, the
+        # interpreter's collection pauses (always on; one ticker task, one
+        # watchdog thread, gc callbacks; shared by in-process brokers)
+        from redpanda_tpu.observability.loopwatch import loopwatch
+
+        loopwatch.start()
+        self._stop_order.append(loopwatch)
         # SLO engine: operator objectives (or the lenient broker defaults)
         # judged at GET /v1/slo; loading arms per-metric breach thresholds
         # so over-threshold observations record trace exemplars
@@ -513,41 +520,6 @@ class Application:
         registry.gauge(
             "trace_enabled", lambda: 1.0 if tracer.enabled else 0.0,
             "pandaprobe span tracer armed",
-        )
-        registry.gauge(
-            "trace_spans_recorded", lambda: tracer.spans_recorded,
-            "Spans committed to the trace ring since start",
-        )
-        from redpanda_tpu.observability.pulse import pulse as _pulse
-
-        registry.gauge(
-            "pulse_spans_recorded",
-            lambda: float(_pulse.recorder.spans_recorded),
-            "Spans the pandapulse flight recorder has retained-or-rotated",
-        )
-        registry.gauge(
-            "pulse_profile_samples",
-            lambda: float(_pulse.profiler.samples),
-            "Wall-profile sampling ticks taken (profile_hz > 0)",
-        )
-        from redpanda_tpu.observability.history import history as _history
-
-        registry.gauge(
-            "history_windows_retained",
-            lambda: float(len(_history.windows())),
-            "Delta windows currently held in the pandatrend history ring",
-        )
-        registry.gauge(
-            "history_breaches_total",
-            lambda: float(_history.breaches_total),
-            "EWMA-band breaches the trend judge has journaled since start",
-        )
-        from redpanda_tpu.observability.slo import slo as _slo
-
-        registry.gauge(
-            "slo_objectives_total",
-            lambda: float(len(_slo.spec.objectives)),
-            "Objectives in the active SLO spec (GET /v1/slo)",
         )
         if self.io_config:
             io = self.io_config

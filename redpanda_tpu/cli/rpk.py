@@ -542,10 +542,17 @@ async def cmd_debug(args) -> int:
             return 0
         totals = body.get("stage_totals_s") or {}
         if totals:
-            print("stage totals (s, ring window):")
+            own = body.get("self_totals_s") or {}
+            print(f"stage totals (s, ring window):{'TOTAL':>23}{'SELF':>12}")
             ordered = sorted(totals.items(), key=lambda kv: -kv[1])
             for k, v in ordered[:16]:
-                print(f"  {k:<40}{v:>12.6f}")
+                print(f"  {k:<40}{v:>12.6f}{own.get(k, v):>12.6f}")
+        for st in body.get("loop_stalls") or []:
+            print(
+                f"loop stall {st.get('dur_us', 0) / 1000.0:.0f} ms at "
+                f"{st.get('start', 0):.3f} in {st.get('phase') or '?'}: "
+                f"{' > '.join(st.get('stack') or []) or 'no stack taken'}"
+            )
         return 0
 
     if args.debug_cmd == "trend":

@@ -10,6 +10,8 @@ layer plugs in behind the same interface.
 
 from __future__ import annotations
 
+import collections
+import time
 from dataclasses import dataclass
 
 from redpanda_tpu.models.fundamental import NTP, NodeId
@@ -87,6 +89,11 @@ class DirectConsensus:
         )
 
 
+# Newest appended batches whose append time a partition remembers (one
+# tuple per batch; ~8 s of a partition taking 8 batches a second).
+APPEND_STAMPS = 64
+
+
 class Partition:
     """Broker-facing partition handle (cluster/partition.h:34).
 
@@ -106,6 +113,14 @@ class Partition:
         self.otl = OffsetTranslator(ntp, kvs)
         log.append_listeners.append(self.otl.observe)
         log.truncate_listeners.append(self.otl.truncate)
+        # (last raft offset, perf_counter()) of the newest appended batches,
+        # ascending: what the queue-wait probes (coproc input wait, fetch
+        # wake) take "how long has this batch been in the log" from
+        self._append_stamps: collections.deque = collections.deque(
+            maxlen=APPEND_STAMPS
+        )
+        log.append_listeners.append(self._stamp_append)
+        log.truncate_listeners.append(self._unstamp_from)
         self._otl_ready = False
         # tiered storage read side (cloud_storage.RemotePartition); serves
         # offsets below the local log start when attached
@@ -156,6 +171,33 @@ class Partition:
     @property
     def last_stable_offset(self) -> int:
         return self.otl.to_kafka_excl(self.consensus.last_stable_offset)
+
+    # -------------------------------------------------------------- append stamps
+    def _stamp_append(self, btype, base: int, last: int) -> None:
+        self._append_stamps.append((last, time.perf_counter()))
+
+    def _unstamp_from(self, offset: int) -> None:
+        stamps = self._append_stamps
+        while stamps and stamps[-1][0] >= offset:
+            stamps.pop()
+
+    def append_stamp(self, last_kafka_offset: int) -> float | None:
+        """``time.perf_counter()`` at which the batch whose last record is
+        ``last_kafka_offset`` was appended to this log; None once it has
+        left the ring (or was not appended by this process)."""
+        stamps = self._append_stamps
+        if not stamps:
+            return None
+        raft = self.otl.from_kafka(last_kafka_offset)
+        if raft < stamps[0][0]:
+            return None  # an old backlog: no scan
+        # newest first: readers ask about what was just appended
+        for last, t in reversed(stamps):
+            if last == raft:
+                return t
+            if last < raft:
+                break
+        return None
 
     # -------------------------------------------------------------- io
     async def replicate(self, batches: list[RecordBatch], level: int) -> ReplicateResult:

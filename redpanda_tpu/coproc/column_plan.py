@@ -210,12 +210,17 @@ class ColumnarPlan:
         # exactly the hot-path impurity pandalint HPS201/HPN211 flags
         consts = _prepare_cmp_consts(expr)
 
-        def predicate(*arrays):
-            keep = _build_expr(jnp, expr, self._bind_slots(arrays), consts)
-            return _packbits(jnp, keep)
+        # the function's name is the program's (``jit_rp_columnar_predicate``
+        # on the profile's module line and in compile logs); the scopes name
+        # its operations
+        def rp_columnar_predicate(*arrays):
+            with jax.named_scope("predicate"):
+                keep = _build_expr(jnp, expr, self._bind_slots(arrays), consts)
+            with jax.named_scope("frame"):
+                return _packbits(jnp, keep)
 
         if mesh is None:
-            fn = jax.jit(predicate)
+            fn = jax.jit(rp_columnar_predicate)
         else:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -224,7 +229,7 @@ class ColumnarPlan:
             for c in self.dev_cols:
                 shardings += [row_sharded] * _COL_ARITY[c.kind]
             fn = jax.jit(
-                predicate,
+                rp_columnar_predicate,
                 in_shardings=tuple(shardings),
                 out_shardings=NamedSharding(mesh, PartitionSpec()),
             )
@@ -262,19 +267,21 @@ class ColumnarPlan:
         consts = _prepare_cmp_consts(expr)
         plan = self
 
-        def _local(*arrays):
+        def rp_columnar_predicate_mesh(*arrays):
             # per-device block: [1, n_pad, ...] -> strip the device dim,
             # evaluate the shared predicate tree, re-add it for out_specs
             flat = [a[0] for a in arrays]
-            keep = _build_expr(jnp, expr, plan._bind_slots(flat), consts)
-            return _packbits(jnp, keep)[None, :]
+            with jax.named_scope("predicate"):
+                keep = _build_expr(jnp, expr, plan._bind_slots(flat), consts)
+            with jax.named_scope("frame"):
+                return _packbits(jnp, keep)[None, :]
 
         in_specs = []
         for c in self.dev_cols:
             in_specs += [PartitionSpec(PARTITION_AXIS)] * _COL_ARITY[c.kind]
         fn = jax.jit(
             shard_map(
-                _local,
+                rp_columnar_predicate_mesh,
                 mesh=mesh,
                 in_specs=tuple(in_specs),
                 out_specs=PartitionSpec(PARTITION_AXIS),

@@ -53,7 +53,7 @@ import numpy as np
 from redpanda_tpu.hashing.xx import xxhash64
 from redpanda_tpu.models.fundamental import NTP
 from redpanda_tpu.models.record import Compression, RecordBatch
-from redpanda_tpu.observability import probes
+from redpanda_tpu.observability import probes, stages
 from redpanda_tpu.observability.trace import tracer
 from redpanda_tpu.ops.pipeline import (
     IN_META,
@@ -275,7 +275,7 @@ class _Launch:
                 np.zeros(0, np.int32),
                 np.zeros(0, bool),
             )
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_fetch")
         dev = self._packed_dev
         eng = self.engine
         if isinstance(dev, np.ndarray) or eng is None:
@@ -333,7 +333,7 @@ class _Launch:
             keep = np.unpackbits(slot._mask_np)[:n].astype(bool)
             slot._mask_np = None
             return keep
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_fetch")
         eng = self.engine
         # wait out the harvester's WHOLE retry envelope, not one attempt's
         # deadline: timing out mid-envelope would start a duplicate
@@ -457,7 +457,7 @@ class _Launch:
             )
         keep = self._resolve_keep(self, n)
         keep &= self._proj_ok
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_assemble")
         plan: ColumnarPlan = self._plan
         if plan.passthrough:
             # Output = input value bytes of kept records (empty values are
@@ -483,7 +483,7 @@ class _Launch:
                 np.zeros(0, np.int32),
                 np.zeros(0, bool),
             )
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_assemble")
         if plan.kind == "python":
             outs = []
             for i in range(n):
@@ -541,7 +541,7 @@ class _Launch:
                     arena = self.engine._arena if self.engine is not None else None
                     if gv is not None:
                         ex, keep = gv
-                        t0 = time.perf_counter()
+                        t0 = _stage_t0("t_frame_gather")
                         self._framed = batch_codec.frame_ranges_gather(
                             ex.joined, ex.offsets, ex.sizes, keep,
                             self.ranges, arena=arena,
@@ -552,7 +552,7 @@ class _Launch:
                         self._gather_mat = None
                     else:
                         out, out_len, keep = self._materialize_locked()
-                        t0 = time.perf_counter()
+                        t0 = _stage_t0("t_rebuild")
                         self._framed = batch_codec.frame_ranges(
                             out, out_len, keep, self.ranges, arena=arena
                         )
@@ -638,7 +638,7 @@ class _Launch:
             and ex is not None
             and shard.n > 0
         ):
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_shard_frame_gather")
             framed = batch_codec.frame_ranges_gather(
                 ex.joined, ex.offsets, ex.sizes, keep, shard.ranges,
                 arena=arena,
@@ -646,7 +646,7 @@ class _Launch:
             self._stat("t_shard_frame_gather", t0)
             self._count_frame("n_frame_gather")
             return framed
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_shard_assemble")
         if shard.n == 0:
             rows = np.zeros((0, max(self.r_out, 1)), np.uint8)
             lens = np.zeros(0, np.int32)
@@ -659,7 +659,7 @@ class _Launch:
         # the launch-wall t_assemble/t_rebuild of the inline path (the
         # fan-out's wall time is t_sharded_frame)
         self._stat("t_shard_assemble", t0)
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_shard_rebuild")
         framed = batch_codec.frame_ranges(
             rows, lens, keep, shard.ranges, arena=arena
         )
@@ -679,7 +679,7 @@ class _Launch:
             for shard, keep in zip(shards, keeps)
         ]
         pool = self.engine._host_pool if self.engine is not None else None
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_sharded_frame")
         parts = pool.run(thunks) if pool is not None else [t() for t in thunks]
         self._stat("t_sharded_frame", t0)
         for shard in shards:
@@ -707,12 +707,7 @@ class _Launch:
         if self.engine is not None:
             self.engine._stat_stage(key, t0, trace_id=self.trace_id)
         else:
-            tracer.record(
-                "coproc.stage." + key[2:],
-                (time.perf_counter() - t0) * 1e6,
-                self.trace_id,
-                start_perf=t0,
-            )
+            stages.close("coproc.stage." + key[2:], None, t0, trace_id=self.trace_id)
 
 
 def _pack_values(ex, stride: int):
@@ -765,7 +760,14 @@ _UNKNOWN, _EMPTY, _DEREGISTERED, _LAUNCHED = range(4)
 
 # "resolve the trace id from the ambient contextvar" sentinel for
 # _stat_stage (None is a real value there: "caller had no trace").
-_AMBIENT = object()
+_AMBIENT = stages.AMBIENT
+
+
+def _stage_t0(key: str) -> float:
+    """Begin the engine stage whose stat key is ``t_<stage>``: the ``t0``
+    that ``_stat_stage`` / ``_Launch._stat`` closes."""
+    return stages.begin("coproc.stage." + key[2:])
+
 
 # Sharding threshold: below this many records the pool's fan-out/merge
 # overhead (thread handoff, per-shard native-call fixed costs) eats the
@@ -1655,25 +1657,20 @@ class TpuEngine:
                 probes.coproc_harvest_padded.inc(v)
 
     def _stat_stage(self, key: str, t0: float, trace_id=_AMBIENT) -> float:
-        """Close one stage timer: ONE clock read, stat + probe mirror via
-        ``_stat_add``, and the same duration mirrored as a pandapulse
-        lifecycle span (so timeline slices sum to the ``t_*`` splits by
-        construction — both sides see the identical ``dt``). Submit-side
-        call sites run inside the ``coproc.dispatch`` span, so the ambient
-        trace id resolves on the dispatching thread; pool/mesh workers
-        pass the launch's trace id explicitly (no ambient there). Tracer
-        off → ``tracer.record`` is a cheap early return."""
-        dt = time.perf_counter() - t0
+        """Close one stage timer (``t0 = _stage_t0(key)``) through the stage
+        helper: ONE clock read, the ``rp:coproc.stage.*`` annotation ended,
+        the duration mirrored as a pandapulse lifecycle span, and the same
+        ``dt`` into stat + probe via ``_stat_add`` (under the stats lock, so
+        the helper takes no histogram here): timeline slices sum to the
+        ``t_*`` splits by construction. Submit-side call sites run inside
+        the ``coproc.dispatch`` span, so the ambient trace id resolves on
+        the dispatching thread; pool/mesh workers pass the launch's trace id
+        explicitly (no ambient there)."""
+        # "coproc.stage." namespace: stage slices must not collide with the
+        # wrapper spans (t_dispatch vs the coproc.dispatch span around the
+        # whole submit fan-out)
+        dt = stages.close("coproc.stage." + key[2:], None, t0, trace_id=trace_id)
         self._stat_add(key, dt)
-        if tracer.enabled:
-            tid = tracer.current_trace() if trace_id is _AMBIENT else trace_id
-            if tid is not None:
-                # "coproc.stage." namespace: stage slices must not collide
-                # with the wrapper spans (t_dispatch vs the coproc.dispatch
-                # span around the whole submit fan-out)
-                tracer.record(
-                    "coproc.stage." + key[2:], dt * 1e6, tid, start_perf=t0
-                )
         return dt
 
     def _count_fallback(self, n: int) -> None:
@@ -1720,7 +1717,7 @@ class TpuEngine:
             )
             if len(parts) >= 2:
                 def run_chunk(s: int, e: int) -> list:
-                    t0 = time.perf_counter()
+                    t0 = _stage_t0("t_shard_seal")
                     out = [seal_one(*jobs[i]) for i in range(s, e)]
                     # per-chunk CPU-seconds; the fan-out wall time is
                     # t_sharded_seal (same split discipline as t_shard_*).
@@ -1729,7 +1726,7 @@ class TpuEngine:
                     self._stat_stage("t_shard_seal", t0, trace_id=trace_id)
                     return out
 
-                t0 = time.perf_counter()
+                t0 = _stage_t0("t_sharded_seal")
                 try:
                     chunks = pool.run([
                         (lambda s=s, e=e: run_chunk(s, e)) for s, e in parts
@@ -1764,7 +1761,7 @@ class TpuEngine:
                 "inline decision, or pool-machinery degradation",
                 {"jobs": len(jobs)},
             )
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_seal")
         out = [seal_one(*j) for j in jobs]
         self._stat_stage("t_seal", t0)
         return out
@@ -2024,7 +2021,16 @@ class TpuEngine:
             if plan.mode == "columnar"
             else "staged"
         )
-        t0 = time.perf_counter()
+        # the annotation takes the lane's first-choice name; a lane that
+        # falls back closes under the stage that ran (histogram and ring)
+        if plan.mode == "columnar":
+            t0 = _stage_t0(
+                "t_explode_find2" if parse == "structural" else "t_explode_find"
+            )
+        else:
+            t0 = _stage_t0(
+                "t_explode_ptrs" if plan.mode == "payload" else "t_explode"
+            )
         cache = None
         if plan.mode == "columnar":
             paths = plan.flat_paths()
@@ -2386,7 +2392,7 @@ class TpuEngine:
             # the first representative launch) — shard workers must not
             # race the calibration or mix ladders within a launch
             structural = self._parse_path(plan, all_batches) == "structural"
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_sharded_dispatch")
             try:
                 shards = pool.run([
                     (
@@ -2428,7 +2434,7 @@ class TpuEngine:
             # dispatch; shard it and merge back into one launch-wide table
             # (merge_exploded rebases offsets/ranges) so the existing
             # device staging / host materialize paths run unchanged.
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_explode")
             try:
                 exploded = batch_codec.merge_exploded(
                     pool.run([
@@ -2541,7 +2547,10 @@ class TpuEngine:
             )
             shard.stages[key] = round(shard.stages.get(key, 0.0) + dt, 6)
 
-        t0 = time.perf_counter()
+        t0 = _stage_t0(
+            "t_shard_explode_find2" if structural and paths
+            else "t_shard_explode_find"
+        )
         cache = None
         cols = None
         fused_proj = None  # (proj_data, proj_ok) from the fused lane
@@ -2563,7 +2572,7 @@ class TpuEngine:
             # passthrough framing gathers from the joined blob the fused
             # crossing built; projection shards never need raw bytes again
             shard.exploded = sp.exploded() if plan.byte_identity else None
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_shard_fused_extract")
             if n_pad is None:
                 n_pad = _bucket_rows(n)
             cols, proj_data, proj_ok = plan.extract_fused(sp, n_pad)
@@ -2588,11 +2597,11 @@ class TpuEngine:
                 shard.proj_ok = np.zeros(0, dtype=bool)
                 return None, n_pad or 0
             if cache is None:
-                t0 = time.perf_counter()
+                t0 = _stage_t0("t_shard_find")
                 cache = plan.build_find_cache(ex.joined, ex.offsets, ex.sizes)
                 stage("t_find", t0)
             if plan.dev_cols:
-                t0 = time.perf_counter()
+                t0 = _stage_t0("t_shard_extract_pred")
                 if n_pad is None:
                     n_pad = _bucket_rows(n)
                 cols = plan.extract_device_inputs(
@@ -2605,7 +2614,7 @@ class TpuEngine:
             # projection rows came out of the fused extraction crossing
             shard.proj_data, shard.proj_ok = fused_proj
         else:
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_shard_extract_proj")
             data, ok = plan.extract_projection(
                 ex.joined, ex.offsets, ex.sizes, cache
             )
@@ -2661,7 +2670,7 @@ class TpuEngine:
         if cols is not None:
             slot = _MaskSlot(n)
             slot.trace_id = launch.trace_id
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_shard_dispatch")
             if use_host:
                 slot._mask_np = plan.eval_host_mask(cols)
                 dt = self._stat_stage(
@@ -2807,7 +2816,7 @@ class TpuEngine:
         # one COMMON row bucket across every device shard: the stacked
         # SPMD input is one [D, n_pad, ...] array per column
         n_pad = _bucket_rows(max(sum(counts[s:e]) for s, e in parts))
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_mesh_ladder")
         thunks = [
             (
                 lambda d=d, s=s, e=e: self._run_mesh_shard(
@@ -2874,7 +2883,7 @@ class TpuEngine:
                 # from the next launch on)
                 return False
         launch.r_out = plan.r_out
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_dispatch")
 
         def leg():
             faults.inject(faults.MESH_DISPATCH)
@@ -3000,7 +3009,7 @@ class TpuEngine:
         launch.fits = exploded.sizes <= self._row_stride
         if n == 0:
             return
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_pack")
         n_pad = _bucket_rows(n)
         staged = self._pack_staged(exploded, n_pad)
         self._stat_stage("t_pack", t0)
@@ -3016,7 +3025,7 @@ class TpuEngine:
         launch.fits = pe.sizes <= self._row_stride
         if n == 0:
             return
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_pack")
         n_pad = _bucket_rows(n)
         staged = self._pack_staged_ptrs(pe, n_pad)
         self._stat_stage("t_pack", t0)
@@ -3034,7 +3043,7 @@ class TpuEngine:
         # retained until the packed result lands: the host fallback re-runs
         # the pipeline on the CPU backend over exactly these rows
         launch._staged_np = staged
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_dispatch")
         if not self._breaker.allow_device():
             launch._packed_dev = launch._payload_host_fallback()
             self._stat_stage("t_dispatch", t0)
@@ -3113,7 +3122,7 @@ class TpuEngine:
             # predicate over the same columns — identical bits, no
             # device touch until the half-open probe re-admits it
             use_host = breaker_demoted = True
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_dispatch")
         if use_host:
             # measured-host predicate: SAME extracted columns, numpy —
             # what the probe (or the bench ablation) picked on this link
@@ -3176,7 +3185,7 @@ class TpuEngine:
             # split path (fused explode_find unavailable): ONE JSON walk
             # per record locates every referenced top-level field
             # (rp_find_multi); extraction gathers from the span tables
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_find")
             cache = plan.build_find_cache(
                 exploded.joined, exploded.offsets, exploded.sizes
             )
@@ -3185,7 +3194,7 @@ class TpuEngine:
         cols = None
         n_pad = _bucket_rows(n)
         if plan.dev_cols:
-            t0 = time.perf_counter()
+            t0 = _stage_t0("t_extract_pred")
             cols = plan.extract_device_inputs(
                 exploded.joined, exploded.offsets, exploded.sizes, n_pad, cache
             )
@@ -3198,7 +3207,7 @@ class TpuEngine:
                 )
             self._dispatch_predicate(launch, plan, cols, n, n_pad, entry=entry)
         # Projection extraction overlaps the device launch.
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_extract_proj")
         if plan.passthrough:
             launch._proj_ok = np.ones(n, bool)
             launch._exploded = exploded
@@ -3228,7 +3237,7 @@ class TpuEngine:
         if n == 0:
             launch._proj_ok = np.zeros(0, bool)
             return
-        t0 = time.perf_counter()
+        t0 = _stage_t0("t_fused_extract")
         n_pad = _bucket_rows(n)
         cols, proj_data, proj_ok = plan.extract_fused(sp, n_pad)
         self._stat_stage("t_fused_extract", t0)

@@ -30,6 +30,11 @@ from redpanda_tpu.coproc.engine import (
     TpuEngine,
 )
 from redpanda_tpu.models.fundamental import NTP, MaterializedNTP
+from redpanda_tpu.observability import stages
+from redpanda_tpu.observability.probes import (
+    coproc_input_wait_hist,
+    coproc_tick_hist,
+)
 from redpanda_tpu.observability.trace import tracer
 from redpanda_tpu.resource_mgmt.admission import ShedError
 from redpanda_tpu.resource_mgmt.budgets import MemoryAccount
@@ -74,6 +79,9 @@ class ScriptContext:
         # (ntp_context.h:54-60 offset_tracker)
         self.offsets: dict[NTP, int] = {}
         self._task: asyncio.Task | None = None
+        # perf_counter() at the end of the newest productive tick (the
+        # ``gap`` phase runs from there to the next productive tick's read)
+        self._t_tick_end: float | None = None
 
     def start(self) -> None:
         self._task = asyncio.create_task(self._loop())
@@ -131,67 +139,114 @@ class ScriptContext:
         Offsets advance ONLY after the materialized write lands
         (script_context.cc's read → process → write → last_acked order) —
         advancing at read time would drop records on any write failure.
+
+        Every phase is a stage (observability/stages.py): always a sample
+        in ``coproc_tick_latency_us{phase=}`` and, in a profile, an
+        ``rp:coproc.*`` annotation; with tracing on, a span under the tick.
+        read + gate + engine + write = tick; tick + gap tiles the fiber.
         """
         pm = self.pacemaker
         knobs = pm.launch_knobs()
         items = []
         read_high: dict[NTP, int] = {}
-        t_read0 = time.perf_counter()
+        t_tick = stages.begin("coproc.tick")
+        t_read = stages.begin("coproc.read")
         # group_ticks_per_launch fuses N ticks' worth of input into one
         # launch (deeper batching amortizes the device round trip; the
         # governor shrinks it back to 1 under memory pressure)
         read_budget = pm.max_batch_size * knobs["group_ticks"]
-        for ntp in self._input_ntps():
-            batches = await self._read_ntp(ntp, read_budget)
-            if batches:
-                items.append(ProcessBatchItem(self.script_id, ntp, batches))
-                read_high[ntp] = batches[-1].last_offset
+        try:
+            for ntp in self._input_ntps():
+                batches = await self._read_ntp(ntp, read_budget)
+                if batches:
+                    items.append(ProcessBatchItem(self.script_id, ntp, batches))
+                    read_high[ntp] = batches[-1].last_offset
+        finally:
+            if not items:
+                # an idle tick is no sample and no trace (it would drown
+                # the ring): only the annotations end
+                stages.close("coproc.read", None, t_read, trace_id=None)
+                stages.close("coproc.tick", None, t_tick, trace_id=None)
         if not items:
             return False
-        # One trace per productive tick (idle ticks would drown the ring);
-        # the read phase is back-dated into it once we know work exists.
-        with tracer.span(
+        # One trace per productive tick, begun at the start of its read:
+        # the tick span and the ``tick`` sample cover the read phase.
+        tick_span = tracer.span(
             "coproc.tick", root=True,
             node=self.pacemaker.broker.config.node_id,
-        ) as tick_span:
-            tracer.record(
-                "coproc.read",
-                (time.perf_counter() - t_read0) * 1e6,
-                tick_span.trace_id,
-                start_perf=t_read0,
+        )
+        tick_span.enter_at(t_tick)
+        if self._t_tick_end is not None:
+            coproc_tick_hist["gap"].record(int((t_tick - self._t_tick_end) * 1e6))
+        try:
+            stages.close("coproc.read", coproc_tick_hist["read"], t_read)
+            moved, shed_retry_s = await self._launch_and_write(
+                items, read_high, knobs, tick_span.trace_id
             )
-            # Submit AND harvest run in worker threads: the first dispatch of
-            # a spec jit-compiles for seconds, and anything that blocks the
-            # broker's event loop that long stops raft heartbeats and forces
-            # cluster-wide re-elections (measured: every group re-elected
-            # ~10s after the first deploy when submit ran on-loop).
-            loop = asyncio.get_running_loop()
-            req = ProcessBatchRequest(items, trace_id=tick_span.trace_id)
-            ex = pm.engine_executor
-            # tick deadline: the engine's internal deadlines bound every
-            # device leg, so these only fire when that machinery is itself
-            # wedged. A timed-out executor call is ABANDONED, not retried
-            # in place: its ticket is never harvested, so nothing is
-            # written (no duplicates), and the un-advanced offsets make the
-            # next tick re-read the same records (no loss). The governor
-            # may have adaptively RAISED per-domain deadlines since the
-            # static backstop was sized at startup, so re-derive per tick:
-            # the backstop must always sit above the engine's own envelope
-            # or it would abandon legitimately mid-envelope ticks.
-            deadline_s = pm.tick_deadline_for(pm.engine)
-            # launch_depth bounds concurrent submit+harvest regions across
-            # every script fiber: the staged bytes of at most depth
-            # launches are in flight, which is what keeps the coproc
-            # account's occupancy (and so the pressure signal) meaningful
+        finally:
+            # the gap starts on the clock read that ended the tick
+            self._t_tick_end = t_tick + stages.close(
+                "coproc.tick", coproc_tick_hist["tick"], t_tick, span=tick_span
+            )
+        if shed_retry_s is not None:
+            # backoff OUTSIDE the depth gate: under a floored depth a
+            # shed script sleeping inside the slot would head-of-line
+            # block every other script's admissible launch
+            await asyncio.sleep(shed_retry_s)
+            return False
+        if moved:
+            # append-invalidation hook for the device column cache:
+            # this script's input window just advanced, so its cached
+            # columns can never be re-read (the cache key is
+            # content-addressed — this reclaims memory, it is not
+            # what keeps hits correct)
+            pm.engine.invalidate_columns(self.script_id)
+        return moved
+
+    async def _launch_and_write(
+        self, items: list, read_high: dict, knobs: dict, trace_id
+    ) -> tuple[bool, float | None]:
+        """The gate, engine and write phases of a productive tick:
+        (any offset moved, seconds to back off after an admission shed)."""
+        pm = self.pacemaker
+        # launch_depth bounds concurrent submit+harvest regions across
+        # every script fiber: the staged bytes of at most depth
+        # launches are in flight, which is what keeps the coproc
+        # account's occupancy (and so the pressure signal) meaningful
+        with stages.stage("coproc.gate", coproc_tick_hist["gate"]):
             async with pm._launch_cond:
                 while pm._launch_inflight >= knobs["launch_depth"]:
                     await pm._launch_cond.wait()
                 pm._launch_inflight += 1
-            shed_retry_s = None
-            try:
+        try:
+            # engine: request built and submit dispatched to reply in hand,
+            # executor queueing included; the two waits are its children in
+            # the ring
+            with stages.stage("coproc.engine", coproc_tick_hist["engine"]):
+                # Submit AND harvest run in worker threads: the first
+                # dispatch of a spec jit-compiles for seconds, and anything
+                # that blocks the broker's event loop that long stops raft
+                # heartbeats and forces cluster-wide re-elections (measured:
+                # every group re-elected ~10s after the first deploy when
+                # submit ran on-loop).
+                loop = asyncio.get_running_loop()
+                req = ProcessBatchRequest(items, trace_id=trace_id)
+                ex = pm.engine_executor
+                # tick deadline: the engine's internal deadlines bound every
+                # device leg, so these only fire when that machinery is
+                # itself wedged. A timed-out executor call is ABANDONED, not
+                # retried in place: its ticket is never harvested, so
+                # nothing is written (no duplicates), and the un-advanced
+                # offsets make the next tick re-read the same records (no
+                # loss). The governor may have adaptively RAISED per-domain
+                # deadlines since the static backstop was sized at startup,
+                # so re-derive per tick: the backstop must always sit above
+                # the engine's own envelope or it would abandon legitimately
+                # mid-envelope ticks.
+                deadline_s = pm.tick_deadline_for(pm.engine)
                 sub_fut = loop.run_in_executor(ex, pm.engine.submit, req)
                 try:
-                    with tracer.span("coproc.submit.wait"):
+                    with stages.stage("coproc.submit.wait"):
                         ticket = await asyncio.wait_for(
                             asyncio.shield(sub_fut), timeout=deadline_s
                         )
@@ -205,7 +260,7 @@ class ScriptContext:
                     raise
                 res_fut = loop.run_in_executor(ex, ticket.result)
                 try:
-                    with tracer.span("coproc.harvest.wait"):
+                    with stages.stage("coproc.harvest.wait"):
                         reply = await asyncio.wait_for(
                             asyncio.shield(res_fut), timeout=deadline_s
                         )
@@ -218,45 +273,32 @@ class ScriptContext:
                     # harmless either way.
                     pm.engine._release_admission(ticket)
                     raise
-            except ShedError as exc:
-                # admission refused the staged bytes BEFORE any dispatch:
-                # no offsets moved, nothing was written — back off the
-                # throttle hint and re-read the same records (counted via
-                # coproc_admission_shed_total, journaled as an ADMISSION
-                # shed episode; not a fault, so no note_failure here)
-                logger.debug(
-                    "script %s submit shed: %s", self.name, exc
-                )
-                shed_retry_s = min(exc.retry_after_ms / 1000.0, 5.0)
-            finally:
-                async with pm._launch_cond:
-                    pm._launch_inflight -= 1
-                    pm._launch_cond.notify_all()
-            if shed_retry_s is not None:
-                # backoff OUTSIDE the depth gate: under a floored depth a
-                # shed script sleeping inside the slot would head-of-line
-                # block every other script's admissible launch
-                await asyncio.sleep(shed_retry_s)
-                return False
-            if self.script_id in reply.deregistered:
-                logger.warning("script %s deregistered by engine policy", self.name)
-                pm.detach_script(self.name)
-                self._task = None
-                raise _StopScript()
-            moved = False
-            with tracer.span("coproc.write"):
-                for item in reply.items:
-                    if await self._write_materialized(item.source, item.batches):
-                        self.offsets[item.source] = read_high[item.source]
-                        moved = True
-            if moved:
-                # append-invalidation hook for the device column cache:
-                # this script's input window just advanced, so its cached
-                # columns can never be re-read (the cache key is
-                # content-addressed — this reclaims memory, it is not
-                # what keeps hits correct)
-                pm.engine.invalidate_columns(self.script_id)
-            return moved
+        except ShedError as exc:
+            # admission refused the staged bytes BEFORE any dispatch:
+            # no offsets moved, nothing was written — back off the
+            # throttle hint and re-read the same records (counted via
+            # coproc_admission_shed_total, journaled as an ADMISSION
+            # shed episode; not a fault, so no note_failure here)
+            logger.debug(
+                "script %s submit shed: %s", self.name, exc
+            )
+            return False, min(exc.retry_after_ms / 1000.0, 5.0)
+        finally:
+            async with pm._launch_cond:
+                pm._launch_inflight -= 1
+                pm._launch_cond.notify_all()
+        if self.script_id in reply.deregistered:
+            logger.warning("script %s deregistered by engine policy", self.name)
+            pm.detach_script(self.name)
+            self._task = None
+            raise _StopScript()
+        moved = False
+        with stages.stage("coproc.write", coproc_tick_hist["write"]):
+            for item in reply.items:
+                if await self._write_materialized(item.source, item.batches):
+                    self.offsets[item.source] = read_high[item.source]
+                    moved = True
+        return moved, None
 
     def _input_ntps(self) -> list[NTP]:
         out = []
@@ -285,9 +327,17 @@ class ScriptContext:
             # read what was RESERVED, not what was asked: an oversized
             # budget clamps to the whole account and must read that much,
             # or the bytes in flight exceed the bound they reserved against
-            return await p.make_reader(start, reserved, max_offset=lso - 1)
+            batches = await p.make_reader(start, reserved, max_offset=lso - 1)
         finally:
             pm.read_budget.release(reserved)
+        if batches:
+            # how long the oldest batch of this read waited for a tick
+            t_append = p.append_stamp(batches[0].last_offset)
+            if t_append is not None:
+                coproc_input_wait_hist.record(  # pandalint: disable=HST1001 -- every script fiber runs on the broker's event loop, and nothing off it records this histogram
+                    int((time.perf_counter() - t_append) * 1e6)
+                )
+        return batches
 
     async def _write_materialized(self, source: NTP, batches: list) -> bool:
         """do_write_materialized_partition (script_context_backend.cc:40-68):

@@ -19,7 +19,11 @@ from redpanda_tpu.kafka.protocol.batch import decode_wire_batches, encode_wire_b
 from redpanda_tpu.kafka.protocol.errors import ErrorCode
 from redpanda_tpu.cluster.partition import ConsistencyLevel
 from redpanda_tpu.cluster.topic_table import TopicConfig
-from redpanda_tpu.observability.trace import tracer
+from redpanda_tpu.observability import stages
+from redpanda_tpu.observability.probes import (
+    kafka_fetch_serve_hist,
+    kafka_fetch_wake_hist,
+)
 from redpanda_tpu.security.acl import AclOperation, ResourceType
 
 E = ErrorCode
@@ -230,7 +234,7 @@ async def handle_produce(ctx) -> dict | None:
     # storage.append spans below join it via the ambient id. The latency
     # HISTOGRAM is recorded once at the dispatch layer (protocol._dispatch
     # → probes.kafka_produce_hist), which also covers decode/encode.
-    with tracer.span(
+    with stages.stage(
         "kafka.produce", root=True, node=ctx.broker.config.node_id
     ) as sp:
         # carried out to the dispatch layer so the histogram record there
@@ -419,7 +423,7 @@ async def handle_fetch(ctx) -> dict:
     # latency) but is exempt from the slow-request log: an empty long poll
     # hitting max_wait_ms is intentional waiting, and would otherwise bury
     # genuinely slow work in the slow ring. Histogram: protocol._dispatch.
-    with tracer.span(
+    with stages.stage(
         "kafka.fetch", root=True, no_slow=True,
         node=ctx.broker.config.node_id,
     ) as sp:
@@ -446,12 +450,21 @@ async def _do_handle_fetch(ctx) -> dict:
     max_bytes = req.get("max_bytes", 0x7FFFFFFF)
     deadline = time.monotonic() + max(max_wait_ms, 0) / 1000.0
     poll = ctx.broker.config.fetch_poll_interval_s
+    serve_s = 0.0  # inside the read + encode passes only, never the gate
+    waited = False
     while True:
-        responses, total, any_error = await _fetch_once(ctx, topics, max_bytes)
+        t0 = stages.begin("kafka.fetch.serve")
+        try:
+            responses, total, any_error, appended = await _fetch_once(
+                ctx, topics, max_bytes, stamps=waited
+            )
+        finally:
+            serve_s += stages.close("kafka.fetch.serve", None, t0)
         # respond immediately on any partition error (kafka semantics) or
         # once min_bytes is satisfied / the wait budget is spent
         if any_error or total >= min_bytes or time.monotonic() >= deadline:
             break
+        waited = True
         # Long-poll gate: re-reading and re-encoding every poll tick is
         # wasted work — only rerun _fetch_once after some requested
         # partition's high watermark advances.
@@ -460,6 +473,11 @@ async def _do_handle_fetch(ctx) -> dict:
             await asyncio.sleep(min(poll, max(deadline - time.monotonic(), 0)))
             if _fetch_hwm_snapshot(ctx, topics) != hwms:
                 break
+    kafka_fetch_serve_hist.record(int(serve_s * 1e6))
+    if appended is not None:
+        # a long poll that returns data: how long its oldest batch had
+        # been in the log (the price of the re-check interval above)
+        kafka_fetch_wake_hist.record(int((time.perf_counter() - appended) * 1e6))
     throttle = ctx.broker.quota_manager.record_fetch(ctx.header.client_id, total)
     if session is not None:
         responses = session.prune_response(responses)
@@ -479,11 +497,17 @@ def _fetch_hwm_snapshot(ctx, topics) -> tuple:
     return tuple(out)
 
 
-async def _fetch_once(ctx, topics, max_bytes: int) -> tuple[list, int, bool]:
+async def _fetch_once(
+    ctx, topics, max_bytes: int, stamps: bool = False
+) -> tuple[list, int, bool, float | None]:
+    """One read + encode pass over the requested partitions: (responses,
+    bytes, any partition error, and with ``stamps`` the append time
+    (``Partition.append_stamp``) of the oldest batch returned, if known)."""
     broker = ctx.broker
     responses = []
     total = 0
     any_error = False
+    appended = None
     budget = max_bytes
     for t in topics:
         parts = []
@@ -548,6 +572,10 @@ async def _fetch_once(ctx, topics, max_bytes: int) -> tuple[list, int, bool]:
                     policy.spec_json, batches
                 )
             records = encode_wire_batches(batches) if batches else b""
+            if stamps and batches:
+                t_append = partition.append_stamp(batches[0].last_offset)
+                if t_append is not None and (appended is None or t_append < appended):
+                    appended = t_append
             total += len(records)
             budget -= len(records)
             parts.append(
@@ -563,7 +591,7 @@ async def _fetch_once(ctx, topics, max_bytes: int) -> tuple[list, int, bool]:
                 }
             )
         responses.append({"name": t["name"], "partitions": parts})
-    return responses, total, any_error
+    return responses, total, any_error, appended
 
 
 def _fetch_partition_error(index: int, code: ErrorCode, hwm: int = -1) -> dict:

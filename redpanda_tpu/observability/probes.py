@@ -42,8 +42,43 @@ kafka_fetch_hist = registry.histogram(
     "kafka_fetch_latency_us",
     "Fetch handler latency incl. long-poll wait (microseconds)",
 )
+# One sample per fetch request: the time inside the read + encode passes
+# only, without the long-poll sleep that kafka_fetch_latency_us includes.
+kafka_fetch_serve_hist = registry.histogram(
+    "kafka_fetch_serve_latency_us",
+    "Fetch handler time reading and encoding, long-poll wait left out (us)",
+)
+# One sample per fetch that went through the long-poll gate and returned
+# data: append of the oldest batch it returns -> the response is built
+# (what the fetch_poll_interval_s re-check costs a tailing consumer).
+kafka_fetch_wake_hist = registry.histogram(
+    "kafka_fetch_wake_latency_us",
+    "Append of the oldest batch a long-polling fetch returns to its return (us)",
+)
 rpc_request_hist = registry.histogram(
     "rpc_request_latency_us", "Internal RPC round-trip latency (us)"
+)
+
+# ------------------------------------------------------------ coproc pacemaker
+# One sample per PRODUCTIVE tick of a script's fiber (coproc/pacemaker.py):
+# read + gate + engine + write = tick, and tick + gap tiles the fiber's
+# time (gap: end of one productive tick to the start of the next one's
+# read: loop hand-off, idle sleeps, the unproductive ticks between).
+COPROC_TICK_PHASES = ("tick", "read", "gate", "engine", "write", "gap")
+coproc_tick_hist = {
+    phase: registry.histogram(
+        "coproc_tick_latency_us",
+        "Pacemaker wall time per productive tick, by phase (us)",
+        phase=phase,
+    )
+    for phase in COPROC_TICK_PHASES
+}
+# One sample per partition read of a tick: append of the oldest batch read
+# -> the pacemaker has it (how long input waits for a tick). Skipped when
+# that batch has left the partition's append-stamp ring (an old backlog).
+coproc_input_wait_hist = registry.histogram(
+    "coproc_input_wait_latency_us",
+    "Append of the oldest batch a tick reads from a partition to that read (us)",
 )
 
 # ------------------------------------------------------------ coproc engine
@@ -403,15 +438,19 @@ __all__ = [
     "coproc_harvest_gather",
     "coproc_harvest_padded",
     "coproc_host_pool_busy",
+    "coproc_input_wait_hist",
     "coproc_launch_rows_hist",
     "coproc_leakwatch_imbalance",
     "coproc_lockwatch_edges",
     "coproc_retries_total",
     "coproc_shard_rows_hist",
     "coproc_stage_hist",
+    "coproc_tick_hist",
     "host_pool_task_finished",
     "host_pool_task_started",
     "kafka_fetch_hist",
+    "kafka_fetch_serve_hist",
+    "kafka_fetch_wake_hist",
     "kafka_produce_hist",
     "observe_us",
     "raft_replicate_hist",

@@ -54,7 +54,7 @@ import sys
 import threading
 import time
 
-from redpanda_tpu.observability.trace import tracer
+from redpanda_tpu.observability.trace import self_times, tracer
 
 # Span names that mark a trace as a LAUNCH lifecycle group (a coproc tick
 # or a bare-engine submit both qualify; produce/fetch traces with no
@@ -203,6 +203,17 @@ class FlightRecorder:
             totals[s["name"]] = totals.get(s["name"], 0.0) + s["dur_us"] / 1e6
         return totals
 
+    def self_totals(self) -> dict[str, float]:
+        """Per-span-name summed SELF seconds: each span less the part its
+        children (spans whose ``parent`` it is) cover. ``coproc.tick``'s
+        self time is what a tick spends in none of its phases."""
+        spans = [s for s in self.spans() if not s.get("derived")]
+        own = self_times(spans)
+        totals: dict[str, float] = {}
+        for s in spans:
+            totals[s["name"]] = totals.get(s["name"], 0.0) + own[s["span_id"]] / 1e6
+        return totals
+
     def summary(self) -> dict:
         spans = self.spans()
         return {
@@ -214,6 +225,19 @@ class FlightRecorder:
 
 
 # ================================================================ profiler
+def fold_frame(frame, max_depth: int) -> list[str]:
+    """A thread's innermost ``max_depth`` frames as ``file.py:function``,
+    root first (the folded-stack convention). The wall profiler's samples
+    and the loop watchdog's stall stacks (loopwatch.py) are both this."""
+    stack: list[str] = []
+    while frame is not None and len(stack) < max_depth:
+        co = frame.f_code
+        stack.append(f"{os.path.basename(co.co_filename)}:{co.co_name}")
+        frame = frame.f_back
+    stack.reverse()
+    return stack
+
+
 class WallProfiler:
     """Low-frequency wall-clock sampling profiler over every live thread.
 
@@ -291,15 +315,7 @@ class WallProfiler:
         for ident, frame in frames.items():
             if ident == me:
                 continue  # the sampler observing itself is pure noise
-            stack: list[str] = []
-            f = frame
-            while f is not None and len(stack) < self.MAX_DEPTH:
-                co = f.f_code
-                stack.append(
-                    f"{os.path.basename(co.co_filename)}:{co.co_name}"
-                )
-                f = f.f_back
-            stack.reverse()  # root-first, the folded-stack convention
+            stack = fold_frame(frame, self.MAX_DEPTH)
             folded.append(((names.get(ident, f"tid-{ident}"), tuple(stack)), 1))
         with self._lock:
             self._samples += 1
@@ -422,6 +438,10 @@ class Pulse:
             "stage_totals_s": {
                 k: round(v, 6)
                 for k, v in sorted(self.recorder.stage_totals().items())
+            },
+            "self_totals_s": {
+                k: round(v, 6)
+                for k, v in sorted(self.recorder.self_totals().items())
             },
             "top": self.profiler.top(top),
         }

@@ -17,6 +17,11 @@ tracer that stitches one batch's journey back together:
   Work hopping to another thread carries the id EXPLICITLY
   (``ProcessBatchRequest.trace_id`` → ``Ticket`` → ``_Launch`` → the
   harvester thread) because executor threads do not inherit task context.
+* Every span carries ``parent``, the ``span_id`` of the span that was
+  ambient when it started (a second ContextVar beside the trace id), so a
+  layer's SELF time — its span less what its children cover — falls out of
+  the ring (``self_times``). Stages timed on the always-on path go through
+  ``observability/stages.py``, which feeds this ring as one of three sinks.
 * Completed spans land in a bounded ring (``collections.deque(maxlen=N)``)
   — tracing a busy broker must never grow memory; old traces fall off.
 * Spans record wall time; stages that wait in a queue or block on the
@@ -56,6 +61,14 @@ _current_trace: ContextVar[int | None] = ContextVar("rptpu_trace_id", default=No
 # configured node id.
 _current_node: ContextVar[int | None] = ContextVar("rptpu_trace_node", default=None)
 
+# Ambient SPAN id: the span that is open around the current task / thread,
+# i.e. the span that CAUSED whatever commits next. Every committed span
+# carries it as ``parent``, which is what makes a layer's self time (its
+# span less the part its children cover, ``self_times`` below) computable
+# from the ring. It only means something inside the ambient trace: a span
+# recorded under an explicit, different trace id takes no ambient parent.
+_current_span: ContextVar[int | None] = ContextVar("rptpu_trace_span", default=None)
+
 _UNSET = object()
 
 
@@ -71,6 +84,12 @@ class _NoopSpan:
 
     def __exit__(self, *exc) -> bool:
         return False
+
+    def enter_at(self, t0: float) -> None:
+        pass
+
+    def exit_at(self, t1: float) -> None:
+        pass
 
     def set(self, key: str, value) -> None:
         pass
@@ -95,30 +114,34 @@ def _current_thread_name() -> str:
 class _Detached:
     """Nulls the ambient trace id for the duration of the block."""
 
-    __slots__ = ("_token",)
+    __slots__ = ("_token", "_stoken")
 
     def __enter__(self) -> "_Detached":
         self._token = _current_trace.set(None)
+        self._stoken = _current_span.set(None)
         return self
 
     def __exit__(self, *exc) -> bool:
+        _current_span.reset(self._stoken)
         _current_trace.reset(self._token)
         return False
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "trace_id", "span_id", "_token", "_t0",
-                 "extras", "_no_slow", "_node", "_ntoken")
+    __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent", "_token",
+                 "_stoken", "_t0", "extras", "_no_slow", "_node", "_ntoken")
 
     def __init__(
         self, tracer: "Tracer", name: str, trace_id: int, no_slow: bool,
-        node: int | None = None,
+        node: int | None = None, parent: int | None = None,
     ) -> None:
         self._tracer = tracer
         self.name = name
         self.trace_id = trace_id
         self.span_id = tracer.new_span_id()
+        self.parent = parent
         self._token = None
+        self._stoken = None
         self._t0 = 0.0
         self.extras: dict | None = None
         self._no_slow = no_slow
@@ -132,17 +155,28 @@ class _Span:
         self.extras[key] = value
 
     def __enter__(self) -> "_Span":
+        self.enter_at(time.perf_counter())
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.exit_at(time.perf_counter())
+        return False
+
+    def enter_at(self, t0: float) -> None:
+        """``__enter__`` on a clock read the caller already took (the stage
+        helper shares ONE read between histogram, annotation and span; the
+        pacemaker back-dates a tick to the start of its read phase)."""
         self._token = _current_trace.set(self.trace_id)
+        self._stoken = _current_span.set(self.span_id)
         if self._node is not None:
             # entry-point span: publish the node for every child span
             self._ntoken = _current_node.set(self._node)
         else:
             self._node = _current_node.get()
-        self._t0 = time.perf_counter()
-        return self
+        self._t0 = t0
 
-    def __exit__(self, *exc) -> bool:
-        t1 = time.perf_counter()
+    def exit_at(self, t1: float) -> None:
+        _current_span.reset(self._stoken)
         _current_trace.reset(self._token)
         if self._ntoken is not None:
             _current_node.reset(self._ntoken)
@@ -158,8 +192,8 @@ class _Span:
             self._no_slow,
             self.span_id,
             self._node,
+            self.parent,
         )
-        return False
 
 
 class Tracer:
@@ -256,12 +290,6 @@ class Tracer:
             return None
         return _current_trace.get()
 
-    def current_node(self) -> int | None:
-        """Ambient node id set by the nearest entry-point span, or the
-        tracer's configured node (None when neither is known)."""
-        n = _current_node.get()
-        return n if n is not None else self._node_id
-
     @property
     def spans_recorded(self) -> int:
         return self._recorded
@@ -302,17 +330,21 @@ class Tracer:
         """
         if not self.enabled:
             return _NOOP
+        parent = None
         if root:
             tid = self.new_trace_id()
         elif trace_id is _UNSET:
             tid = _current_trace.get()
             if tid is None:
                 return _NOOP
+            parent = _current_span.get()
         elif trace_id is None:
             return _NOOP
         else:
             tid = trace_id
-        return _Span(self, name, tid, no_slow, node=node)
+            if tid == _current_trace.get():
+                parent = _current_span.get()
+        return _Span(self, name, tid, no_slow, node=node, parent=parent)
 
     def detached(self):
         """Wrap creation of LONG-LIVED tasks (a replicate batcher's flush
@@ -331,14 +363,21 @@ class Tracer:
         trace_id: int | None = None,
         *,
         start_perf: float | None = None,
+        parent=_UNSET,
         **extras,
     ) -> None:
         """Manually record a completed stage (used where a context manager
-        cannot wrap the work: harvester thread, pre-trace read phases)."""
+        cannot wrap the work: harvester thread, stage timers closed from a
+        ``t0``). ``parent``: the span that caused this one; by default the
+        ambient span, when ``trace_id`` is the ambient trace."""
         if not self.enabled or trace_id is None:
             return
         t0 = start_perf if start_perf is not None else time.perf_counter() - dur_us / 1e6
-        self._commit(name, trace_id, t0, dur_us, extras or None)
+        if parent is _UNSET:
+            parent = (
+                _current_span.get() if trace_id == _current_trace.get() else None
+            )
+        self._commit(name, trace_id, t0, dur_us, extras or None, parent=parent)
 
     def _commit(
         self,
@@ -350,6 +389,7 @@ class Tracer:
         no_slow: bool = False,
         span_id: int | None = None,
         node: int | None = None,
+        parent: int | None = None,
     ) -> None:
         span = {
             "trace_id": trace_id,
@@ -361,6 +401,8 @@ class Tracer:
         if span_id is None:
             span_id = self.new_span_id()  # manual record(): still unique
         span["span_id"] = span_id
+        if parent is not None:
+            span["parent"] = parent
         if node is None:
             # ambient first: tracer.record() calls inside an entry-point
             # span (pacemaker's back-dated read phase) belong to THAT
@@ -438,6 +480,30 @@ class Tracer:
                 if s["trace_id"] == trace_id:
                     seen[id(s)] = s
         return sorted(seen.values(), key=lambda s: s["start_us"])
+
+
+def self_times(spans: list[dict]) -> dict[int, int]:
+    """span_id -> self time in us: a span's duration less the part of its
+    interval that its children (spans naming it as ``parent``) cover. The
+    children's intervals are merged first, so two that overlap (stages on
+    different threads) are not taken off twice, and clipped to the parent,
+    so a child that outlives it takes off no more than the parent has."""
+    kids: dict[int, list[tuple[int, int]]] = {}
+    for s in spans:
+        p = s.get("parent")
+        if p is not None:
+            kids.setdefault(p, []).append((s["start_us"], s["start_us"] + s["dur_us"]))
+    out: dict[int, int] = {}
+    for s in spans:
+        lo, hi = s["start_us"], s["start_us"] + s["dur_us"]
+        covered, end = 0, lo
+        for a, b in sorted(kids.get(s["span_id"], ())):
+            a, b = max(a, end), min(b, hi)
+            if b > a:
+                covered += b - a
+                end = b
+        out[s["span_id"]] = s["dur_us"] - covered
+    return out
 
 
 # Process-wide tracer, like the metrics registry singleton: subsystems

@@ -81,7 +81,7 @@ def make_crc_fn(r: int):
     k_r_dev = jnp.uint32(k_r)
 
     @jax.jit
-    def crc_fn(data, lengths):
+    def rp_crc32c(data, lengths):
         n = data.shape[0]
         # Zero out bytes beyond each record's length: the GF(2) linear part
         # only ignores padding if the padding is zero.
@@ -114,7 +114,7 @@ def make_crc_fn(r: int):
         s_n = v[:, 0]
         return s_n ^ jnp.uint32(0xFFFFFFFF)
 
-    return crc_fn
+    return rp_crc32c
 
 
 def crc32c_device(data, lengths):

@@ -49,7 +49,7 @@ def make_block_decoder(max_in: int, max_out: int):
     import jax.numpy as jnp
     from jax import lax
 
-    def decode(comp, comp_len):
+    def rp_lz4_decode(comp, comp_len):
         n = comp.shape[0]
         comp = comp.astype(jnp.uint8)
         comp_len = comp_len.astype(jnp.int32)
@@ -158,7 +158,7 @@ def make_block_decoder(max_in: int, max_out: int):
 
     import jax
 
-    return jax.jit(decode)
+    return jax.jit(rp_lz4_decode)
 
 
 # ------------------------------------------------------------------ host refs

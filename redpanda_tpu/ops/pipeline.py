@@ -28,6 +28,7 @@ dispatch (see coproc/engine.py).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -55,31 +56,37 @@ def make_batch_validator(r: int):
     crc = make_crc_fn(r)
 
     @jax.jit
-    def validate(rows, lens, claimed):
+    def rp_batch_validate(rows, lens, claimed):
         got = crc(rows, lens)
         return (got == claimed) & (lens > 0)
 
-    return validate
+    return rp_batch_validate
 
 
-def _packed_body(xp, tfn, r_in: int):
+def _packed_body(xp, tfn, r_in: int, scope=contextlib.nullcontext):
     """staged -> packed around a compiled transform, over namespace ``xp``
-    (jax.numpy on the device, numpy for the engine's host fallback)."""
+    (jax.numpy on the device, numpy for the engine's host fallback).
+    ``scope``: ``jax.named_scope`` for the device program, whose name
+    (``jit_rp_payload_transform``) is this function's."""
 
-    def run(staged):
-        data = staged[:, :r_in]
-        c = staged[:, r_in : r_in + 4].astype(xp.int32)
-        lens = c[:, 0] | (c[:, 1] << 8) | (c[:, 2] << 16) | (c[:, 3] << 24)
-        out, out_len, keep = tfn(data, lens)
-        masked = xp.where(keep, out_len, 0).astype(xp.int32)
-        lenb = xp.stack(
-            [((masked >> (8 * k)) & 0xFF).astype(xp.uint8) for k in range(4)], axis=1
-        )
-        keepb = keep.astype(xp.uint8)[:, None]
-        pad = xp.zeros((out.shape[0], OUT_META - 5), dtype=xp.uint8)
-        return xp.concatenate([out, lenb, keepb, pad], axis=1)
+    def rp_payload_transform(staged):
+        with scope("parse"):
+            data = staged[:, :r_in]
+            c = staged[:, r_in : r_in + 4].astype(xp.int32)
+            lens = c[:, 0] | (c[:, 1] << 8) | (c[:, 2] << 16) | (c[:, 3] << 24)
+        with scope("transform"):
+            out, out_len, keep = tfn(data, lens)
+        with scope("frame"):
+            masked = xp.where(keep, out_len, 0).astype(xp.int32)
+            lenb = xp.stack(
+                [((masked >> (8 * k)) & 0xFF).astype(xp.uint8) for k in range(4)],
+                axis=1,
+            )
+            keepb = keep.astype(xp.uint8)[:, None]
+            pad = xp.zeros((out.shape[0], OUT_META - 5), dtype=xp.uint8)
+            return xp.concatenate([out, lenb, keepb, pad], axis=1)
 
-    return run
+    return rp_payload_transform
 
 
 @functools.lru_cache(maxsize=64)
@@ -87,7 +94,7 @@ def _packed_pipeline_cached(spec_json: str, r_in: int):
     spec = TransformSpec.from_json(spec_json)
     tfn = compile_transform(spec, r_in)
     r_out = transform_out_width(spec, r_in)
-    return jax.jit(_packed_body(jnp, tfn, r_in)), r_out
+    return jax.jit(_packed_body(jnp, tfn, r_in, scope=jax.named_scope)), r_out
 
 
 def make_packed_pipeline(spec: TransformSpec, r_in: int):
@@ -110,12 +117,12 @@ def _record_pipeline_cached(spec_json: str, r_in: int):
     r_out = transform_out_width(spec, r_in)
 
     @jax.jit
-    def run(data, lengths):
+    def rp_record_transform(data, lengths):
         out, out_len, keep = tfn(data, lengths)
         masked_len = jnp.where(keep, out_len, 0)
         return out, masked_len, keep
 
-    return run, r_out
+    return rp_record_transform, r_out
 
 
 def make_record_pipeline(spec: TransformSpec, r_in: int):

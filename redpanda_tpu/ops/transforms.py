@@ -379,7 +379,7 @@ def _transform_body(xp, spec: TransformSpec, r_out: int):
     is an integer or boolean one, so the two evaluate bit-identically."""
     mapper = spec.mapper
 
-    def fn(data, lengths):
+    def rp_transform(data, lengths):
         data = data.astype(xp.uint8)
         lengths = lengths.astype(xp.int32)
         keep = lengths > 0
@@ -436,7 +436,7 @@ def _transform_body(xp, spec: TransformSpec, r_out: int):
         # identity map
         return data, lengths, keep
 
-    return fn
+    return rp_transform
 
 
 @functools.lru_cache(maxsize=64)

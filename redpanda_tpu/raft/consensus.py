@@ -20,12 +20,11 @@ import bisect
 import logging
 import random
 import struct
-import time
 
 from redpanda_tpu.finjector import honey_badger
 from redpanda_tpu.metrics import registry
 from redpanda_tpu.models.fundamental import NTP
-from redpanda_tpu.observability import probes
+from redpanda_tpu.observability import probes, stages
 from redpanda_tpu.observability.trace import tracer
 from redpanda_tpu.models.record import Record, RecordBatch, RecordBatchType
 from redpanda_tpu.raft import device_plane
@@ -465,16 +464,12 @@ class Consensus:
         consistency: ConsistencyLevel = ConsistencyLevel.quorum_ack,
         timeout: float | None = 10.0,
     ) -> ReplicateResult:
-        t0 = time.perf_counter()
-        try:
-            with tracer.span("raft.replicate"):
-                enqueued, replicated = await self.replicate_in_stages(
-                    batches, consistency, timeout
-                )
-                await enqueued
-                return await replicated
-        finally:
-            probes.observe_us(probes.raft_replicate_hist, t0)
+        with stages.stage("raft.replicate", probes.raft_replicate_hist):
+            enqueued, replicated = await self.replicate_in_stages(
+                batches, consistency, timeout
+            )
+            await enqueued
+            return await replicated
 
     async def replicate_in_stages(
         self,

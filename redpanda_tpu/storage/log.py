@@ -22,8 +22,7 @@ from dataclasses import dataclass, field
 from redpanda_tpu.finjector import honey_badger
 from redpanda_tpu.models.fundamental import NTP
 from redpanda_tpu.models.record import RecordBatch
-from redpanda_tpu.observability import probes
-from redpanda_tpu.observability.trace import tracer
+from redpanda_tpu.observability import probes, stages
 from redpanda_tpu.storage.segment import Segment
 from redpanda_tpu.storage.recovery import recover_segment
 
@@ -181,14 +180,13 @@ class DiskLog:
             reserved = await acct.acquire(
                 sum(b.size_bytes for b in batches)
             )
-        t_probe = time.perf_counter()
+        t0 = stages.begin("storage.append")
         try:
-            with tracer.span("storage.append"):
-                return await self._append_locked(batches, term, assign_offsets)
+            return await self._append_locked(batches, term, assign_offsets)
         finally:
             if acct is not None:
                 acct.release(reserved)
-            probes.observe_us(probes.storage_append_hist, t_probe)
+            stages.close("storage.append", probes.storage_append_hist, t0)
 
     async def _append_locked(
         self, batches: list[RecordBatch], term: int | None, assign_offsets: bool

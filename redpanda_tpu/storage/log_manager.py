@@ -10,10 +10,9 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
-import time
 
 from redpanda_tpu.models.fundamental import NTP
-from redpanda_tpu.observability import probes
+from redpanda_tpu.observability import probes, stages
 from redpanda_tpu.storage.kvstore import KvStore
 from redpanda_tpu.storage.log import DiskLog, LogConfig
 
@@ -82,11 +81,11 @@ class LogManager:
         )
 
         async def housekeep_once(log) -> None:
-            t0 = time.perf_counter()
-            policy = log.config.cleanup_policy
-            if "delete" in policy:
-                await log.apply_retention()
-            probes.observe_us(probes.storage_housekeeping_hist, t0)
+            with stages.stage(
+                "storage.housekeeping", probes.storage_housekeeping_hist
+            ):
+                if "delete" in log.config.cleanup_policy:
+                    await log.apply_retention()
 
         async def loop():
             while True:
@@ -111,9 +110,10 @@ class LogManager:
                     if not log.is_compacted:
                         continue
                     try:
-                        t0 = time.perf_counter()
-                        await log.compact()
-                        probes.observe_us(probes.storage_housekeeping_hist, t0)
+                        with stages.stage(
+                            "storage.housekeeping", probes.storage_housekeeping_hist
+                        ):
+                            await log.compact()
                     except Exception:
                         pass
 

@@ -1,0 +1,188 @@
+"""The stage helper (observability/stages.py): one clock pair, three sinks;
+``parent`` on ring spans and the self time that follows from it."""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+from redpanda_tpu.metrics import Histogram
+from redpanda_tpu.observability import stages
+from redpanda_tpu.observability.trace import _NOOP, Tracer, self_times, tracer
+
+
+@pytest.fixture
+def tracing():
+    tracer.reset()
+    tracer.configure(enabled=True)
+    yield tracer
+    tracer.configure(enabled=False)
+    tracer.reset()
+
+
+def _spans():
+    return {s["name"]: s for t in tracer.recent(0) for s in t["spans"]}
+
+
+# ------------------------------------------------------------------ sinks
+@pytest.mark.parametrize("form", ["with", "begin_close"])
+def test_histogram_always_ring_only_when_enabled(form):
+    tracer.configure(enabled=False)
+    tracer.reset()
+    hist = Histogram("t_us", "")
+    if form == "with":
+        with stages.stage("unit.stage", hist, root=True) as sp:
+            assert sp is _NOOP  # no span object while tracing is off
+    else:
+        t0 = stages.begin("unit.stage")
+        assert type(t0) is float  # no profile: a bare clock read
+        assert stages.close("unit.stage", hist, t0) >= 0.0
+    assert hist.hist.count == 1
+    assert tracer.spans_recorded == 0
+
+
+def test_ring_spans_carry_the_parent_that_was_ambient(tracing):
+    hist = Histogram("t_us", "")
+    with stages.stage("root", hist, root=True) as root:
+        t0 = stages.begin("kid.closed")
+        stages.close("kid.closed", hist, t0)
+        with stages.stage("kid.with") as kid:
+            t0 = stages.begin("grandkid")
+            stages.close("grandkid", None, t0)
+        # an explicit other trace takes no ambient parent
+        t0 = stages.begin("elsewhere")
+        stages.close("elsewhere", None, t0, trace_id=root.trace_id + 1000)
+    spans = _spans()
+    assert "parent" not in spans["root"]
+    assert spans["kid.closed"]["parent"] == root.span_id
+    assert spans["kid.with"]["parent"] == root.span_id
+    assert spans["grandkid"]["parent"] == kid.span_id
+    assert "parent" not in spans["elsewhere"]
+    assert {s["trace_id"] for n, s in spans.items() if n != "elsewhere"} == {root.trace_id}
+    assert hist.hist.count == 2  # root and kid.closed; the others took none
+    # one clock pair: the ring's duration is the histogram's sample
+    assert spans["kid.closed"]["dur_us"] <= hist.hist.max
+
+
+def test_a_stage_outside_any_trace_mints_no_orphan(tracing):
+    hist = Histogram("t_us", "")
+    with stages.stage("mid.path", hist):
+        pass
+    t0 = stages.begin("mid.path")
+    stages.close("mid.path", hist, t0)
+    assert hist.hist.count == 2 and tracer.spans_recorded == 0
+
+
+def test_record_takes_an_explicit_parent():
+    t = Tracer(enabled=True)
+    t.record("a", 10.0, 5, start_perf=t.epoch_perf, parent=42)
+    t.record("b", 10.0, 5, start_perf=t.epoch_perf)
+    a, b = t.spans_for(5)
+    assert a["parent"] == 42 and "parent" not in b
+
+
+def test_detached_drops_the_ambient_span(tracing):
+    with stages.stage("root", root=True):
+        with tracer.detached():
+            with stages.stage("inside"):
+                pass
+    assert "inside" not in _spans()
+
+
+# ------------------------------------------------------------------ self time
+def test_self_time_is_span_less_what_children_cover():
+    def span(i, start, dur, parent=None):
+        s = {"span_id": i, "name": f"s{i}", "start_us": start, "dur_us": dur}
+        if parent is not None:
+            s["parent"] = parent
+        return s
+
+    nest = [
+        span(1, 0, 1000),
+        span(2, 100, 300, parent=1),
+        span(3, 300, 300, parent=1),   # overlaps 2 by 100: not taken off twice
+        span(4, 900, 400, parent=1),   # outlives 1: clipped to it
+        span(5, 150, 100, parent=2),
+        span(6, 5000, 50, parent=99),  # parent fell off the ring
+    ]
+    assert self_times(nest) == {1: 1000 - 500 - 100, 2: 200, 3: 300, 4: 400, 5: 100, 6: 50}
+
+
+def test_pulse_self_totals_sum_self_time_by_name(tracing):
+    from redpanda_tpu.observability.pulse import FlightRecorder
+
+    rec = FlightRecorder()
+    tracer.set_sink(rec.record)
+    try:
+        with stages.stage("coproc.tick", root=True):
+            with stages.stage("coproc.read"):
+                pass
+    finally:
+        tracer.set_sink(None)
+    total, own = rec.stage_totals(), rec.self_totals()
+    assert own["coproc.read"] == pytest.approx(total["coproc.read"])
+    assert own["coproc.tick"] == pytest.approx(
+        total["coproc.tick"] - total["coproc.read"], abs=2e-6
+    )
+
+
+# ------------------------------------------------------------------ annotation
+_NO_JAX = """
+import sys
+from redpanda_tpu.metrics import Histogram
+from redpanda_tpu.observability import stages
+h = Histogram("t_us", "")
+with stages.stage("a", h):
+    pass
+t0 = stages.begin("b"); stages.close("b", h, t0)
+assert type(t0) is float and h.hist.count == 2
+assert "jax" not in sys.modules, "the stage helper imported jax"
+assert stages._annotation is None
+print("ok")
+"""
+
+
+def test_annotation_is_a_noop_without_jax_and_never_imports_it():
+    out = subprocess.run(
+        [sys.executable, "-c", _NO_JAX], capture_output=True, text=True, timeout=60,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_annotation_binds_once_jax_is_there_and_is_idle_without_a_profile():
+    import jax
+
+    t0 = stages.begin("unit.stage")
+    assert stages._annotation is jax.profiler.TraceAnnotation
+    assert type(t0) is float  # no profile running: no annotation object
+    stages.close("unit.stage", None, t0)
+
+
+def test_annotation_lands_on_the_profiles_host_plane(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    hist = Histogram("t_us", "")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t0 = stages.begin("unit.closed")
+        assert type(t0) is not float and float(t0) > 0
+        stages.close("unit.closed", hist, t0)
+        with stages.stage("unit.with", hist):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+    }
+    assert {"rp:unit.closed", "rp:unit.with"} <= names
+    assert hist.hist.count == 2

@@ -570,12 +570,64 @@ def _bench_tracer_overhead_disabled(secs: float) -> dict:
     ab_pct = (best_traced / best_base - 1.0) * 100.0 if best_base else 0.0
     overhead_pct = span_ns / (best_base * 1e9) * 100.0 if best_base else 0.0
     return {
+        **_stage_helper_costs(hist),
         "tracer_block_ops": n_done,
         "tracer_span_cost_ns": round(span_ns, 1),
         "probe_cost_ns": round(probe_ns, 1),
         "tracer_op_cost_ns": round(best_base * 1e9, 1),
         "tracer_ab_overhead_pct": round(max(ab_pct, 0.0), 2),
         "tracer_disabled_overhead_pct": round(overhead_pct, 2),
+    }
+
+
+def _stage_helper_costs(hist) -> dict:
+    """Per-call cost of the stage helper (observability/stages.py), the
+    always-on timer of the serving path, in both forms: tracing off
+    (histogram record + the profiler annotation's ``is_enabled`` check, jax
+    imported, no profile running) and tracing on (the ring span on top).
+    ``stage_tick_cost_us``: what one productive pacemaker tick pays for its
+    own stages (2 begin/close, 5 ``with``, the gap sample), tracing off."""
+    import jax  # noqa: F401  (binds the annotation: the broker's posture with coproc on)
+
+    from redpanda_tpu.observability import stages, tracer
+
+    def closed():
+        stages.close("bench.stage", hist, stages.begin("bench.stage"))
+
+    def with_form():
+        with stages.stage("bench.stage", hist):
+            pass
+
+    def best_ns(fn) -> float:
+        best = float("inf")
+        for _ in range(10):
+            t0 = time.perf_counter()
+            for _ in range(2000):
+                fn()
+            best = min(best, (time.perf_counter() - t0) / 2000 * 1e9)
+        return best
+
+    hist_ns = best_ns(lambda: hist.record(1234))
+    off = {"close": best_ns(closed), "with": best_ns(with_form)}
+    tracer.configure(enabled=True)
+    try:
+        with tracer.span("bench.root", root=True):
+            on = {"close": best_ns(closed), "with": best_ns(with_form)}
+    finally:
+        tracer.configure(enabled=False)
+        tracer.reset()
+    return {
+        "histogram_record_ns": round(hist_ns, 1),
+        "stage_close_off_ns": round(off["close"], 1),
+        "stage_with_off_ns": round(off["with"], 1),
+        "stage_close_on_ns": round(on["close"], 1),
+        "stage_with_on_ns": round(on["with"], 1),
+        "stage_tick_cost_us": round(
+            (2 * off["close"] + 5 * off["with"] + hist_ns) / 1e3, 2
+        ),
+        "stage_tick_cost_on_us": round(
+            (2 * on["close"] + 5 * on["with"] + hist_ns) / 1e3, 2
+        ),
     }
 
 
