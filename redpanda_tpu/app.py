@@ -506,6 +506,19 @@ class Application:
         registry.gauge(
             "readers_cache_misses", lambda: rc.misses, "Read cursor misses"
         )
+        registry.gauge(
+            "readers_cache_window_reads",
+            lambda: rc.window_reads,
+            "Scanned reads served from a cursor's window (no file read)",
+        )
+        registry.gauge(
+            "readers_cache_file_reads", lambda: rc.file_reads, "Segment file preads"
+        )
+        registry.gauge(
+            "readers_cache_window_bytes",
+            lambda: rc.window_bytes,
+            "Bytes held by read cursors' windows",
+        )
         if self.coproc is not None:
             eng = self.coproc.engine
             # pool size is static per process; the busy-worker gauge

@@ -25,6 +25,10 @@ from redpanda_tpu.observability.trace import tracer
 storage_append_hist = registry.histogram(
     "storage_append_latency_us", "Storage log append latency (us)"
 )
+storage_read_hist = registry.histogram(
+    "storage_read_latency_us",
+    "Storage log read latency, lock wait included (us)",
+)
 storage_housekeeping_hist = registry.histogram(
     "storage_housekeeping_latency_us",
     "One compaction/retention housekeeping pass over a log (us)",
@@ -457,4 +461,5 @@ __all__ = [
     "rpc_request_hist",
     "storage_append_hist",
     "storage_housekeeping_hist",
+    "storage_read_hist",
 ]

@@ -208,6 +208,7 @@ def self_compact_segment(
         f.flush()
         os.fsync(f.fileno())
     os.replace(tmp, seg.data_path)
+    seg.release_reader()  # its descriptor is of the file just replaced
     seg.size_bytes = len(out)
     seg.rebuild_index(bytes(out))
     seg.index.persist(seg.dirty_offset, seg.max_timestamp)

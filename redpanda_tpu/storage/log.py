@@ -288,56 +288,65 @@ class DiskLog:
         max_offset: int | None = None,
         type_filter=None,
     ) -> list[RecordBatch]:
-        async with self._lock:
-            start = max(start_offset, self._start_offset)
-            cached = self._read_cached(start, max_bytes, max_offset, type_filter)
-            if cached is not None:
-                return cached
-            out: list[RecordBatch] = []
-            taken = 0
-            # adopt a cached read cursor for the first touched segment: the
-            # scan seeks straight to the frame boundary instead of going
-            # through the sparse index (readers_cache.h continuation)
-            cursor = (
-                self.readers_cache.get(id(self), start)
-                if self.readers_cache is not None
-                else None
-            )
-            end_seg = end_pos = None
-            for seg in self.segments:
-                if seg.dirty_offset < start:
-                    continue
-                if max_offset is not None and seg.base_offset > max_offset:
-                    break
-                start_pos = None
-                if cursor is not None and cursor.segment_base == seg.base_offset:
-                    start_pos = cursor.file_pos
-                cursor = None  # only valid for the first segment touched
-                batches, next_pos = seg.scan(
-                    start,
-                    max_bytes - taken,
-                    type_filter=type_filter,
-                    max_offset=max_offset,
-                    start_pos=start_pos,
-                )
-                end_seg, end_pos = seg, next_pos
-                for b in batches:
-                    out.append(b)
-                    self._cache_put(b)
-                    taken += b.size_bytes
-                if taken >= max_bytes:
-                    break
-                if out:
-                    start = out[-1].last_offset + 1
-            if self.readers_cache is not None and out and end_seg is not None:
-                from redpanda_tpu.storage.readers_cache import ReadCursor
+        with stages.stage("storage.read", probes.storage_read_hist):
+            async with self._lock:
+                return self._read_locked(start_offset, max_bytes, max_offset, type_filter)
 
-                self.readers_cache.put(
+    def _read_locked(self, start_offset, max_bytes, max_offset, type_filter):
+        start = max(start_offset, self._start_offset)
+        cached = self._read_cached(start, max_bytes, max_offset, type_filter)
+        if cached is not None:
+            return cached
+        out: list[RecordBatch] = []
+        taken = 0
+        # adopt a cached read cursor for the first touched segment: the
+        # scan decodes out of the window the last read left and otherwise
+        # reads straight at the frame boundary, instead of going through
+        # the sparse index (readers_cache.h continuation)
+        rc = self.readers_cache
+        first = start
+        cursor = rc.get(id(self), start) if rc is not None else None
+        # a cursor hit is the evidence of a sequential reader: read ahead
+        sequential = cursor is not None
+        end = None
+        file_reads = 0
+        for seg in self.segments:
+            if seg.dirty_offset < start:
+                continue
+            if max_offset is not None and seg.base_offset > max_offset:
+                break
+            if cursor is not None and cursor.segment_base != seg.base_offset:
+                cursor = None
+            batches, end, n = seg.scan(
+                start,
+                max_bytes - taken,
+                type_filter=type_filter,
+                max_offset=max_offset,
+                cursor=cursor,
+                read_ahead=sequential,
+            )
+            cursor = None  # only valid for the first segment touched
+            if n and rc is not None:
+                rc.reader_opened(seg)
+            file_reads += n
+            for b in batches:
+                out.append(b)
+                self._cache_put(b)
+                taken += b.size_bytes
+            if taken >= max_bytes:
+                break
+            if out:
+                start = out[-1].last_offset + 1
+        if rc is not None and end is not None:
+            rc.note_read(file_reads)
+            if out:
+                rc.put(
                     id(self),
                     out[-1].last_offset + 1,
-                    ReadCursor(end_seg.base_offset, end_pos),
+                    end,
+                    consumed=first if sequential else None,
                 )
-            return out
+        return out
 
     def _read_cached(self, start, max_bytes, max_offset, type_filter):
         """Serve the read purely from the batch cache, or None.

@@ -24,8 +24,14 @@ from redpanda_tpu.models.record import (
     RecordBatchHeader,
 )
 from redpanda_tpu.storage import file_sanitizer
+from redpanda_tpu.storage.readers_cache import ReadCursor
 
 INDEX_STEP = 32 * 1024
+# What a sequential reader (a read that continues from a cursor) takes from
+# the file at once; the unread rest travels in its cursor. 256 KiB is four
+# catch-up reads of two ~32 KB batches: of 128 / 256 / 512 KiB it cost the
+# least per read on the v5e host (PERF.md §6, PR 25).
+READ_AHEAD_BYTES = 256 * 1024
 _INDEX_ENTRY = struct.Struct("<IQq")  # rel_offset u32, file_pos u64, ts i64
 _INDEX_MAGIC = b"RPXI\x02"
 _INDEX_FOOTER = struct.Struct("<qq")  # dirty_offset i64, max_timestamp i64
@@ -123,6 +129,9 @@ class Segment:
         self.data_path = os.path.join(dir_path, stem + ".log")
         self.index = SegmentIndex(os.path.join(dir_path, stem + ".index"), base_offset)
         self._file = None
+        # the ONE read handle of the data file, opened by the first read
+        # and kept: reads are os.pread on it, no open/seek/close each
+        self._rfile = None
         self._buf = bytearray()
         self.size_bytes = 0
         self.dirty_offset = base_offset - 1  # highest appended offset
@@ -189,10 +198,31 @@ class Segment:
             self._file = None
         self.index.persist(self.dirty_offset, self.max_timestamp)
 
+    def release_reader(self):
+        """Close the read descriptor; the next read opens a new one. Called
+        when the file goes away or is replaced (close, remove, compaction's
+        rewrite) and when the readers cache bounds the open descriptors."""
+        if self._rfile is not None:
+            self._rfile.close()
+            self._rfile = None
+
     def close(self):
         self.release_appender()
+        self.release_reader()
 
     # ------------------------------------------------------------ read
+    def _pread(self, n: int, pos: int) -> bytes:
+        """Up to `n` bytes at `pos` of the data file, every appended frame
+        visible (the append buffer is flushed first)."""
+        self.flush_buffer()
+        if self._file:
+            self._file.flush()
+        if self._rfile is None:
+            self._rfile = file_sanitizer.maybe_wrap(
+                open(self.data_path, "rb", buffering=0), self.data_path
+            )
+        return os.pread(self._rfile.fileno(), n, pos)
+
     def read_from(self, file_pos: int, max_len: int | None = None) -> bytes:
         self.flush_buffer()
         if self._file:
@@ -208,31 +238,45 @@ class Segment:
         *,
         type_filter=None,
         max_offset: int | None = None,
-        start_pos: int | None = None,
-    ) -> tuple[list[RecordBatch], int]:
+        cursor: ReadCursor | None = None,
+        read_ahead: bool = False,
+    ) -> tuple[list[RecordBatch], ReadCursor, int]:
         """Batches overlapping [start_offset, max_offset], bounded by size,
         with cursor support (readers_cache.h continuation).
 
-        `start_pos` is an exact file position of a frame boundary (from a
-        cached read cursor) — when given, the sparse-index lookup and the
-        decode-and-skip scan up to `start_offset` are bypassed. Returns
-        (batches, next_file_pos) where next_file_pos is the byte position
-        just past the last KEPT batch (or the scan start when nothing was
-        kept) — the cursor for the follow-up read at
-        `batches[-1].last_offset + 1`. Frames consumed but filtered out
-        AFTER the last kept batch are deliberately not covered by the
-        cursor, so a continuation under a different type_filter re-scans
-        them instead of silently skipping.
+        `cursor` (a cached read cursor of this segment) holds the exact
+        file position of a frame boundary — the sparse-index lookup and the
+        decode-and-skip scan up to `start_offset` are bypassed — and the
+        window its reader left unread: frames are decoded out of that
+        first, and the file is read only when it holds no whole next frame.
+        `read_ahead`: the caller continues a sequential read (it adopted a
+        cursor); a request under READ_AHEAD_BYTES then takes that much from
+        the file at once. A cold read, and a request at or over it, read
+        about what they asked for and keep only what they over-read.
+
+        Returns (batches, next_cursor, file_reads). next_cursor's position
+        is just past the last KEPT batch (or the scan start when nothing
+        was kept) — the cursor for the follow-up read at
+        `batches[-1].last_offset + 1` — with the window's unread rest.
+        Frames consumed but filtered out AFTER the last kept batch are
+        deliberately not covered by the cursor, so a continuation under a
+        different type_filter re-scans them instead of silently skipping.
         """
-        pos = start_pos if start_pos is not None else self.index.lookup(start_offset)
-        # bounded chunked reads (ONE handle, window trimmed as frames are
-        # consumed): a sequential consumer with a cursor reads ~max_bytes
-        # per call instead of slurping the segment tail
-        chunk = max(min(max_bytes * 2, 8 << 20), 1 << 16)
+        if read_ahead and max_bytes < READ_AHEAD_BYTES:
+            chunk = READ_AHEAD_BYTES
+        else:
+            # bounded reads: ~max_bytes per call, never the segment tail
+            chunk = max(min(max_bytes * 2, 8 << 20), 1 << 16)
+        if cursor is not None:
+            pos = cursor.file_pos
+            frames = _FrameReader(self, pos, chunk, cursor.window, cursor.window_pos)
+        else:
+            pos = self.index.lookup(start_offset)
+            frames = _FrameReader(self, pos, chunk)
         out: list[RecordBatch] = []
         taken = 0
         kept_end = pos  # file offset just past the last KEPT batch
-        for batch, end_pos in self._frames_from(pos, chunk):
+        for batch, end_pos in frames:
             if max_offset is not None and batch.base_offset > max_offset:
                 break  # NOT consumed: cursor stays before this frame
             if batch.last_offset < start_offset:
@@ -248,51 +292,19 @@ class Segment:
             taken += batch.size_bytes
             if taken >= max_bytes:
                 break
-        return out, kept_end
-
-    def _frames_from(self, pos: int, chunk: int):
-        """Yield (batch, end_file_pos) for each frame from file position
-        `pos`, reading the file in `chunk`-sized windows trimmed as frames
-        are consumed. A frame cut at EOF raises CorruptBatchError: appends
-        are whole-frame and recovery truncates torn tails at open, so a
-        partial frame is corruption, never a legitimate state."""
-        self.flush_buffer()
-        if self._file:
-            self._file.flush()
-        with open(self.data_path, "rb") as f:
-            f.seek(pos)
-            blob = bytearray(f.read(chunk))
-            base = pos  # file offset of blob[0]
-            at = 0  # decode position within blob
-            while True:
-                if at >= chunk:
-                    del blob[:at]
-                    base += at
-                    at = 0
-                if at + INTERNAL_HEADER_SIZE > len(blob):
-                    more = f.read(chunk)
-                    if not more:
-                        if at < len(blob):
-                            raise CorruptBatchError(
-                                f"partial batch header at EOF ({self.data_path}"
-                                f" pos {base + at})"
-                            )
-                        return
-                    blob += more
-                    continue
-                frame_len = RecordBatch.peek_size(blob, at)
-                if at + frame_len > len(blob):
-                    more = f.read(chunk)
-                    if not more:
-                        raise CorruptBatchError(
-                            f"batch frame overruns EOF ({self.data_path} pos "
-                            f"{base + at}, size_bytes={frame_len})"
-                        )
-                    blob += more
-                    continue
-                batch, consumed = RecordBatch.decode_internal(blob, at)
-                at += consumed
-                yield batch, base + at
+        # the unread rest travels on, unless the window moved past kept_end
+        # (filtered frames) or a large request left more than a reader's own
+        window, window_pos = frames.buf, frames.base
+        if not (
+            len(window) <= 2 * READ_AHEAD_BYTES
+            and window_pos <= kept_end < window_pos + len(window)
+        ):
+            window, window_pos = b"", 0
+        return (
+            out,
+            ReadCursor(self.base_offset, kept_end, window, window_pos),
+            frames.file_reads,
+        )
 
     def first_offset_with_ts(self, ts: int) -> int | None:
         """First batch offset whose max_timestamp >= ts (index-accelerated).
@@ -301,7 +313,7 @@ class Segment:
         that resolves near the index point must not slurp the rest of the
         segment file; corruption raises loudly like every read path."""
         pos = self.index.lookup_time(ts)
-        for batch, _end in self._frames_from(pos, 1 << 20):
+        for batch, _end in _FrameReader(self, pos, 1 << 20):
             if batch.header.max_timestamp >= ts:
                 return batch.base_offset
         return None
@@ -343,6 +355,7 @@ class Segment:
 
     def remove(self):
         self.release_appender()
+        self.release_reader()
         for p in (self.data_path, self.index.path):
             try:
                 os.remove(p)
@@ -351,3 +364,74 @@ class Segment:
 
     def __repr__(self):
         return f"Segment(base={self.base_offset}, term={self.term}, size={self.size_bytes})"
+
+
+class _FrameReader:
+    """Iterator of (batch, end_file_pos) over a segment's frames from file
+    position `pos`, decoded out of an immutable window of the file that is
+    read anew `chunk` bytes at a time (ONE copy per payload: the slice that
+    becomes `RecordBatch.payload`). `window` / `window_pos`: bytes of the
+    file already in hand, from a read cursor. After the last `next()`,
+    `buf` / `base` are the window as it stands, for the next cursor.
+
+    A frame cut at EOF raises CorruptBatchError: appends are whole-frame
+    and recovery truncates torn tails at open, so a partial frame is
+    corruption, never a legitimate state."""
+
+    __slots__ = ("seg", "chunk", "buf", "base", "at", "file_reads")
+
+    def __init__(
+        self, seg: Segment, pos: int, chunk: int, window: bytes = b"", window_pos: int = 0
+    ):
+        self.seg = seg
+        self.chunk = chunk
+        if window_pos <= pos < window_pos + len(window):
+            self.buf, self.base, self.at = window, window_pos, pos - window_pos
+        else:
+            self.buf, self.base, self.at = b"", pos, 0
+        self.file_reads = 0
+
+    def __iter__(self):
+        return self
+
+    def _refill(self, need: int) -> bool:
+        """Read the window anew from the next frame's boundary, at least
+        `need` bytes if the file has them; False when it has no more than
+        the window already held. The consumed front and the cut frame at
+        the old window's end are let go (re-read: no concatenation, so no
+        second copy of a window)."""
+        seg = self.seg
+        pos = self.base + self.at
+        # a corrupt size field must not size the read: the file's own length
+        # (as the segment accounts it) bounds what one call asks for
+        n = min(max(self.chunk, need), max(self.chunk, seg.size_bytes - pos))
+        more = seg._pread(n, pos)
+        self.file_reads += 1
+        if len(more) <= len(self.buf) - self.at:
+            return False
+        self.buf, self.base, self.at = more, pos, 0
+        return True
+
+    def __next__(self) -> tuple[RecordBatch, int]:
+        while True:
+            have = len(self.buf) - self.at
+            if have < INTERNAL_HEADER_SIZE:
+                if self._refill(INTERNAL_HEADER_SIZE):
+                    continue
+                if have:
+                    raise CorruptBatchError(
+                        f"partial batch header at EOF ({self.seg.data_path}"
+                        f" pos {self.base + self.at})"
+                    )
+                raise StopIteration
+            frame_len = RecordBatch.peek_size(self.buf, self.at)
+            if have < frame_len:
+                if self._refill(frame_len):
+                    continue
+                raise CorruptBatchError(
+                    f"batch frame overruns EOF ({self.seg.data_path} pos "
+                    f"{self.base + self.at}, size_bytes={frame_len})"
+                )
+            batch, consumed = RecordBatch.decode_internal(self.buf, self.at)
+            self.at += consumed
+            return batch, self.base + self.at

@@ -507,6 +507,90 @@ def test_opfuzz_with_caches_and_cursors(tmp_path):
     _run(main())
 
 
+# ------------------------------------------------------------------ read windows
+def test_segment_scan_hands_on_the_unread_rest_of_its_window(ntp, cfg):
+    """Segment.scan reads the file through ONE kept descriptor and returns,
+    with the batches, the cursor for the follow-up read: the position past
+    the last kept frame and what the window holds beyond it (PR 25)."""
+    from redpanda_tpu.storage import segment as segment_mod
+
+    async def main():
+        log = await DiskLog.open(ntp, cfg)
+        sizes = []
+        for i in range(40):
+            b = _batch(2, value_size=4000, ts=i)
+            sizes.append(b.size_bytes)
+            await log.append([b])
+        seg = log.segments[0]
+        assert seg._rfile is None  # nothing read yet: no descriptor
+        # a cold read takes about what it was asked for, and keeps the rest
+        got, cur, file_reads = seg.scan(0, sizes[0])
+        assert [b.base_offset for b in got] == [0] and file_reads == 1
+        fd = seg._rfile.fileno()
+        assert cur.segment_base == 0 and cur.file_pos == sizes[0]
+        assert cur.window_pos == 0 and len(cur.window) == 1 << 16
+        # a continuation decodes out of the window: no file read
+        got, cur, file_reads = seg.scan(2, sizes[1], cursor=cur, read_ahead=True)
+        assert [b.base_offset for b in got] == [2] and file_reads == 0
+        # ... until the window holds no whole next frame: then one read-ahead
+        # window from that frame's boundary on
+        seen = []
+        preads = 0
+        while got:
+            at = got[-1].last_offset + 1
+            got, cur, file_reads = seg.scan(at, sizes[0], cursor=cur, read_ahead=True)
+            seen += [b.base_offset for b in got]
+            preads += file_reads
+            assert len(cur.window) <= segment_mod.READ_AHEAD_BYTES
+        assert seen == list(range(4, 80, 2))
+        # 38 frames of ~8 KB from 64 KiB on: one 256 KiB window and the EOF probe
+        assert preads == 2 and seg._rfile.fileno() == fd
+        # appended after the window ended at EOF: the exhausted cursor reads on
+        await log.append([_batch(2, value_size=4000, ts=99)])
+        got, cur, file_reads = seg.scan(80, sizes[0], cursor=cur, read_ahead=True)
+        assert [b.base_offset for b in got] == [80] and file_reads == 1
+        # a request at or over the window size reads as asked and keeps no more
+        # than a reader's own window
+        got, cur, _ = seg.scan(0, 4 * segment_mod.READ_AHEAD_BYTES, read_ahead=True)
+        assert len(got) == 41 and cur.window == b""
+        await log.close()
+        assert seg._rfile is None
+
+    _run(main())
+
+
+def test_read_is_a_stage_and_the_cursor_counters_add_up(tmp_path):
+    from redpanda_tpu.observability import probes
+
+    async def main():
+        mgr = LogManager(LogConfig(base_dir=str(tmp_path)), batch_cache_bytes=0)
+        log = await mgr.manage(NTP.kafka("stage", 0))
+        for i in range(32):
+            await log.append([_batch(2, value_size=2000, ts=i)])
+        one = _batch(2, value_size=2000).size_bytes
+        rc = mgr.readers_cache
+        h0 = probes.storage_read_hist.hist.count
+        at, reads = 0, 0
+        while True:
+            got = await log.read(at, one)
+            reads += 1
+            if not got:
+                break
+            at = got[-1].last_offset + 1
+        assert at == 64 and probes.storage_read_hist.hist.count == h0 + reads
+        st = rc.stats()
+        # the empty read at the tail is answered before any cursor is looked at
+        assert st["hits"] == reads - 2 and st["misses"] == 1
+        # every scanned read either touched no file or made at least one pread
+        assert st["window_reads"] + st["file_reads"] >= reads - 1 > st["window_reads"] > 0
+        assert st["window_reads"] >= 0.7 * (st["window_reads"] + st["file_reads"])
+        assert st["window_bytes"] == sum(len(c.window) for c in rc._lru.values())
+        await mgr.stop()
+        assert rc.stats()["window_bytes"] == 0 and rc.stats()["entries"] == 0
+
+    _run(main())
+
+
 # ------------------------------------------------------------------ compaction
 def _kv_batch(pairs, ts=0):
     """pairs: [(key, value-or-None)]"""
