@@ -228,7 +228,7 @@ class _Launch:
                  "_mask_event", "_proj_data", "_proj_ok", "_plan",
                  "_exploded", "_mat", "_gather_mat", "_framed", "_lock",
                  "_shards", "trace_id", "_enq_t", "_cols", "_staged_np",
-                 "_mask_state", "_pending_slots")
+                 "_mask_state", "_pending_slots", "_trial")
 
     def __init__(self, script_id: int, policy: ErrorPolicy):
         self.script_id = script_id
@@ -266,6 +266,9 @@ class _Launch:
         # cost no envelopes and feed no stale verdicts to the breaker
         self._pending_slots: list[_MaskSlot] = []
         self._staged_np = None
+        # set while this launch is one sample of the host-pool A/B
+        # (host_pool.LaunchTrial)
+        self._trial: host_pool.TrialSample | None = None
 
 
     def _mat_payload(self):
@@ -820,6 +823,23 @@ class Ticket:
         finally:
             self._engine._release_admission(self)
 
+    def _trial_launch(self) -> "_Launch | None":
+        """The launch this ticket times for the host-pool A/B: a trial
+        launch that this ticket alone harvests, whole. A launch fused over
+        several tickets is framed by one and sealed by each, so no single
+        harvest is its cost: such a sample is left out."""
+        launch, n_ranges = None, 0
+        for disp, _, slot_launch, rng in self._slots:
+            if disp != _LAUNCHED:
+                continue
+            if slot_launch._trial is None or launch not in (None, slot_launch):
+                return None
+            launch = slot_launch
+            n_ranges += len(rng)
+        if launch is None or n_ranges != len(launch.ranges):
+            return None
+        return launch
+
     def _result_impl(self) -> ProcessBatchReply:
         reply = ProcessBatchReply()
         dereg: set[int] = set()
@@ -833,6 +853,8 @@ class Ticket:
         seal_jobs: list[tuple] = []  # (source batch, payload, kept)
         slot_plans: list = []  # per slot: list[int] | Exception | None
         framing_failed: set[int] = set()
+        trial = self._trial_launch()
+        mark = self._engine._trial_mark() if trial is not None else None
         for disp, item, launch, rng in self._slots:
             if disp != _LAUNCHED or launch.script_id in framing_failed:
                 # a later slot of a script whose framing already failed is
@@ -853,7 +875,12 @@ class Ticket:
                 # slot order there, exactly like the old per-slot loop
                 slot_plans.append(exc)
                 framing_failed.add(launch.script_id)
-        sealed = self._engine._seal_jobs(seal_jobs, trace_id=self.trace_id)
+        sealed = self._engine._seal_jobs(
+            seal_jobs, trace_id=self.trace_id,
+            arm=trial._trial.arm if trial is not None else None,
+        )
+        if trial is not None:
+            self._engine._trial_harvested(trial, mark, not framing_failed)
         # Phase 2: assemble the reply in slot order under the script's
         # ErrorPolicy — this is the policy boundary (deregister failures
         # ride through here), so programming errors must not bypass it.
@@ -1045,12 +1072,19 @@ class TpuEngine:
             else None
         )
         # Pool on/off is a MEASURED per-process decision, exactly like the
-        # columnar device-vs-host probe: the first shardable launch times
-        # its own explode stage inline vs sharded and pins the winner
-        # (quota-limited boxes advertise CPUs that thrash instead of
-        # scale). host_pool_probe=False pins "sharded" unmeasured — bench
-        # scaling runs and parity tests need the fan-out deterministically.
+        # columnar device-vs-host probe: the first shardable launches run
+        # alternately inline and sharded, each timed whole, and the winner
+        # pins (host_pool.LaunchTrial; quota-limited boxes advertise CPUs
+        # that thrash instead of scale). host_pool_probe=False pins
+        # "sharded" unmeasured — bench scaling runs and parity tests need
+        # the fan-out deterministically.
         self._pool_decision: str | None = None if host_pool_probe else "sharded"
+        # the trial in progress while the decision is None (made by the
+        # first shardable launch that finds none)
+        self._pool_trial: host_pool.LaunchTrial | None = None
+        # first runs of programs, probes' extra passes and host fallbacks,
+        # counted as they happen: a trial launch that met one is no sample
+        self._spoilers = 0
         self.governor.update_config_snapshot(host_workers=self._host_workers)
         if not host_pool_probe:
             # config pin, not a measurement — posture only, no journal
@@ -1059,8 +1093,8 @@ class TpuEngine:
         self._pool_decision_lock = lockwatch.wrap(
             threading.Lock(), "TpuEngine._pool_decision_lock"
         )
-        # set while a periodic re-calibration is pending, so the next
-        # calibration journals itself as a recal rather than a first probe
+        # set while a periodic re-calibration runs, so its verdict
+        # journals itself as a recal rather than a first trial
         self._recal_pending = False
         self._host_pool_probe: dict | None = None
         self._host_pool_probe_prev: dict | None = None
@@ -1204,6 +1238,11 @@ class TpuEngine:
         # once, with its first-run seconds: its next launch is past trace
         # + compile (_try_device_leg)
         self._compiled: dict[tuple, float] = {}
+        # (jitted function, n_pad) of every payload pipeline that has run:
+        # scripts of one spec share the function (ops/pipeline.py caches it
+        # by spec), so a new script's launch at a bucket its function has
+        # seen is past trace + compile too
+        self._ran_fns: set[tuple] = set()
         self._compile_lock = lockwatch.wrap(
             threading.Lock(), "TpuEngine._compile_lock"
         )
@@ -1651,6 +1690,10 @@ class TpuEngine:
                 probes.coproc_h2d_bytes.inc(v)
             elif key == "bytes_d2h":
                 probes.coproc_d2h_bytes.inc(v)
+            elif key == "n_staged_rows":
+                probes.coproc_staged_rows.inc(v)
+            elif key == "n_oversize_rows":
+                probes.coproc_oversize_rows.inc(v)
             elif key == "n_frame_gather":
                 probes.coproc_harvest_gather.inc(v)
             elif key == "n_frame_padded":
@@ -1673,16 +1716,30 @@ class TpuEngine:
         self._stat_add(key, dt)
         return dt
 
+    def _note_spoiler(self) -> None:
+        """Something ran inside the launch in hand that is not its road's
+        steady cost (a program's first run, a probe's extra passes, a host
+        fallback): a host-pool trial must not take that launch as a
+        sample."""
+        with self._stats_lock:
+            self._spoilers += 1
+
     def _count_fallback(self, n: int) -> None:
         """Account records whose stages re-executed on the pure-host
         fallback (exhausted device retries or an open breaker)."""
         self._stat_add("n_fallback_rows", float(n))
         probes.coproc_fallback_rows.inc(n)
+        self._note_spoiler()
 
-    def _seal_jobs(self, jobs: list[tuple], trace_id: int | None = None) -> list:
+    def _seal_jobs(
+        self, jobs: list[tuple], trace_id: int | None = None,
+        arm: str | None = None,
+    ) -> list:
         """Recompress + seal framed payloads into output batches
         (batch_codec.build_output_batch), sharded over the host pool when
-        the measured pool decision is on and the reply is big enough.
+        the measured pool decision is on and the reply is big enough
+        (``arm``: the road of the trial launch this reply harvests, which
+        stands in for a decision still being measured).
         Jobs are independent (build_output_batch is pure per batch) and
         chunks merge in input order, so offsets/CRCs are bit-identical to
         the serial loop. A per-job failure comes back AS the exception
@@ -1702,8 +1759,10 @@ class TpuEngine:
                 return exc
 
         pool = self._host_pool
-        with self._pool_decision_lock:  # coherent read vs concurrent recal
-            decision = self._pool_decision
+        decision = arm
+        if decision is None:
+            with self._pool_decision_lock:  # coherent read vs concurrent recal
+                decision = self._pool_decision
         if (
             pool is not None
             and decision == "sharded"
@@ -1778,7 +1837,9 @@ class TpuEngine:
                 if slot._mask_state == "queued":
                     slot._mask_state = "abandoned"
 
-    def _try_device_leg(self, domain: str, leg, program: tuple | None = None):
+    def _try_device_leg(
+        self, domain: str, leg, program: tuple | None = None, fn=None
+    ):
         """One device leg under the engine's fault envelope: the DOMAIN's
         per-attempt deadline (adaptive, governor-derived) + bounded retry
         (faults.retry_call), classified failure accounting, and a failure
@@ -1796,10 +1857,17 @@ class TpuEngine:
         workers reaching the same program wait for it rather than compile
         it again), and its wall time is ``t_compile``, not a deadline
         sample. Every successful dispatch leg is one ``n_device_launches``.
+        ``fn``: the jitted function the leg calls, where scripts of one
+        spec share it (the payload lane's pipelines are cached by spec): a
+        script whose function another script already ran at this row
+        bucket inherits that program, and its first launch is no first run.
         """
         if program is not None:
             with self._stats_lock:
                 known = program in self._compiled
+                if not known and (fn, program[2]) in self._ran_fns:
+                    self._compiled[program] = 0.0
+                    known = True
                 unresolved = self._device is None
             if unresolved:
                 self.resolve_device()
@@ -1808,10 +1876,12 @@ class TpuEngine:
                     with self._stats_lock:
                         known = program in self._compiled
                     if not known:
-                        return self._device_leg(domain, leg, program, True)
-        return self._device_leg(domain, leg, program, False)
+                        return self._device_leg(domain, leg, program, True, fn)
+        return self._device_leg(domain, leg, program, False, fn)
 
-    def _device_leg(self, domain: str, leg, program, first_run: bool):
+    def _device_leg(
+        self, domain: str, leg, program, first_run: bool, fn=None
+    ):
         """_try_device_leg's envelope. Each SUCCESSFUL steady-state
         attempt's wall time feeds the governor's success-only device-leg
         histogram — the adaptive-deadline source. The timing wraps the leg
@@ -1836,6 +1906,7 @@ class TpuEngine:
                 first_run_s = dt
                 self._stat_add("t_compile", dt)
                 self._stat_add("n_compiles", 1.0)
+                self._note_spoiler()
             else:
                 gov.observe_leg(domain, dt)
             return out
@@ -1852,6 +1923,8 @@ class TpuEngine:
             self._stat_add("n_device_launches", 1.0)
             with self._stats_lock:
                 self._compiled.setdefault(program, first_run_s)
+                if fn is not None:
+                    self._ran_fns.add((fn, program[2]))
                 self._device_launches[program[0]] += 1
         return out
 
@@ -1948,7 +2021,10 @@ class TpuEngine:
             launch.trace_id = entries[0][0].trace_id
             try:
                 with tracer.span("coproc.dispatch", trace_id=launch.trace_id):
+                    mark = self._trial_mark()
                     self._dispatch(script_id, launch, entries)
+                    if launch._trial is not None:
+                        self._trial_dispatched(launch, mark)
                 ridx = 0
                 for ticket, slot_idx, item in entries:
                     rng = list(range(ridx, ridx + len(item.batches)))
@@ -2137,7 +2213,7 @@ class TpuEngine:
     def _measure_parse_ratio(self, plan, all_batches) -> tuple[float, float]:
         """(t_staged, t_structural) for this launch's REAL parse+extract
         ladders, each best-of-2 — the same measure-the-true-workload
-        posture as _measure_pool_ratio."""
+        posture as the host-pool trial."""
         paths = plan.flat_paths()
         n = sum(b.header.record_count for b in all_batches)
         n_pad = _bucket_rows(n)
@@ -2177,6 +2253,7 @@ class TpuEngine:
         """One-shot engine-sticky fused-vs-staged pin off the first
         representative columnar launch. Caller holds the probe RUN lock;
         the decision fields publish under the short decision lock."""
+        self._note_spoiler()
         try:
             t_staged, t_structural = self._measure_parse_ratio(
                 plan, all_batches
@@ -2218,50 +2295,63 @@ class TpuEngine:
         )
 
     # ------------------------------------------------------ pool calibration
-    def _measure_pool_ratio(self, plan, all_batches, counts) -> tuple[float, float]:
-        """(t_inline, t_sharded) for this launch's REAL explode stage, each
-        best-of-2. Measuring the true workload, not a synthetic spin: on
-        burstable virtualized hosts a millisecond-scale synthetic probe can
-        show phantom 2-3x thread scaling while sustained parsing thrashes."""
-        pool = self._host_pool
-        parts = host_pool.partition_counts(counts, pool.workers)
-        paths = plan.flat_paths() if plan.mode == "columnar" else None
+    def _trial_mark(self) -> tuple[float, int]:
+        """The engine's clock and its spoiler count, at one instant."""
+        with self._stats_lock:
+            return time.perf_counter(), self._spoilers
 
-        def explode(batches):
-            if paths:
-                got = batch_codec.explode_and_find(batches, paths)
-                if got is not None:
-                    return got
-            return batch_codec.explode_batches(batches)
+    def _trial_elapsed(self, mark: tuple[float, int]) -> float | None:
+        """Seconds since ``mark``; None if a program's first run, a probe
+        or a host fallback ran meanwhile (no sample of a road's cost)."""
+        with self._stats_lock:
+            clean = self._spoilers == mark[1]
+        return time.perf_counter() - mark[0] if clean else None
 
-        t_inline = t_sharded = float("inf")
-        for _ in range(2):
-            t0 = time.perf_counter()
-            explode(all_batches)
-            t_inline = min(t_inline, time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            pool.run([
-                (lambda s=s, e=e: explode(all_batches[s:e])) for s, e in parts
-            ])
-            t_sharded = min(t_sharded, time.perf_counter() - t0)
-        return t_inline, t_sharded
+    def _trial_dispatched(self, launch: _Launch, mark) -> None:
+        """The dispatch half of a trial launch's cost."""
+        seconds = self._trial_elapsed(mark)
+        if seconds is None:
+            self._trial_dropped(launch)
+        else:
+            launch._trial.dispatch_s = seconds
 
-    def _calibrate_host_pool(self, plan, all_batches, counts) -> None:
-        """One-shot, process-sticky pool on/off decision off the first
-        shardable launch (the same measure-first posture as
-        _probe_columnar_backend: never assume the cores are real). The
-        ~4 extra explode passes cost one launch a few ms, once."""
+    def _trial_dropped(self, launch: _Launch) -> None:
+        sample, launch._trial = launch._trial, None
+        with self._pool_decision_lock:
+            sample.trial.drop()
+
+    def _trial_harvested(self, launch: _Launch, mark, clean: bool) -> None:
+        """The harvest half (fetch wait, assemble, frame, seal) lands: the
+        launch is one sample of its arm, and the trial's last sample
+        decides."""
+        seconds = self._trial_elapsed(mark)
+        sample, launch._trial = launch._trial, None
+        trial = sample.trial
+        with self._pool_decision_lock:
+            if trial is not self._pool_trial:
+                return  # a launch of a trial already concluded
+            if clean and seconds is not None:
+                trial.add(sample.arm, sample.dispatch_s + seconds, launch.n)
+            else:
+                trial.drop()
+            if trial.complete or trial.exhausted:
+                self._conclude_trial(trial)
+
+    def _conclude_trial(self, trial: host_pool.LaunchTrial) -> None:
+        """Pin the pool on or off for the process by the trial's verdict
+        (the same measure-first posture as _probe_columnar_backend: never
+        assume the cores are real) and journal what was measured. Caller
+        holds the decision lock."""
         recal = self._recal_pending
         self._recal_pending = False
+        self._pool_trial = None
         why = (
             "periodic recalibration (coproc_host_pool_recal_launches)"
             if recal
-            else "first shardable launch calibration"
+            else "first shardable launches"
         )
         try:
-            t_inline, t_sharded = self._measure_pool_ratio(
-                plan, all_batches, counts
-            )
+            probe = dict(trial.verdict(), workers=self._host_workers)
         except Exception as exc:
             # classified: a box whose calibration keeps blowing up runs
             # inline forever, which must be visible on /metrics
@@ -2275,24 +2365,26 @@ class TpuEngine:
                 {"error": faults.kind_of(exc), "workers": self._host_workers},
             )
         else:
-            ratio = t_inline / t_sharded if t_sharded > 0 else 0.0
-            self._pool_decision = (
-                "sharded" if ratio >= host_pool.PROBE_MARGIN else "inline"
-            )
-            self._host_pool_probe = {
-                "t_inline_ms": round(t_inline * 1e3, 3),
-                "t_sharded_ms": round(t_sharded * 1e3, 3),
-                "speedup": round(ratio, 3),
-                "workers": self._host_workers,
-                "chosen": self._pool_decision,
-            }
-            logger.info("host pool calibration: %s", self._host_pool_probe)
+            self._pool_decision = probe["chosen"]
+            self._host_pool_probe = probe
+            logger.info("host pool calibration: %s", probe)
+            if probe.get("incomplete"):
+                what = (
+                    f"no {trial.per_arm} clean launches a road in "
+                    f"{trial.issued} shardable launches; keeping inline path"
+                )
+            else:
+                what = (
+                    f"whole launches, inline {probe['inline_us_per_row']} vs "
+                    f"sharded {probe['sharded_us_per_row']} us a row (medians "
+                    f"of {trial.per_arm}): speedup {probe['speedup']:.3f}x vs "
+                    f"margin {host_pool.PROBE_MARGIN}"
+                )
             self.governor.record(
                 governor.HOST_POOL,
                 self._pool_decision,
-                f"{why}: measured explode speedup {ratio:.3f}x vs margin "
-                f"{host_pool.PROBE_MARGIN} at {self._host_workers} workers",
-                dict(self._host_pool_probe, recalibration=recal),
+                f"{why}: {what} at {self._host_workers} workers",
+                dict(probe, samples=trial.samples, recalibration=recal),
             )
         if self._pool_decision == "inline":
             self._host_pool.shutdown()  # threads idle forever otherwise
@@ -2319,15 +2411,27 @@ class TpuEngine:
             # caller thread, so t_sharded ~= t_inline and the pool would be
             # demoted process-wide off a meaningless measurement
             return False
-        # ONE locked region owns the recal counter, the calibrate-once
-        # double-check AND the decision read this launch acts on: the old
-        # shape re-read self._pool_decision unlocked after the calibrate
-        # block (pandaraces RAC1101 — a concurrent recal archiving the
-        # probe could flip the value between the calibration and its use).
-        # Serializing concurrent first submits here also keeps them from
-        # calibrating against each other's measurement load, which would
-        # depress the sharded ratio below PROBE_MARGIN on boxes where the
-        # pool truly wins.
+        # a launch that cannot shard whatever the decision takes no part
+        # in it: an SPMD predicate stays one launch over the mesh, and a
+        # columnar plan whose backend is not probed yet probes inline
+        use_host = backend = None
+        if plan.mode == "columnar" and plan.dev_cols:
+            if self._mesh is not None:
+                return False
+            backend = TpuEngine.sticky_columnar_backend()
+            if self._force_mode == "columnar_host":
+                use_host = True
+            elif self._force_mode == "columnar_device":
+                use_host = False
+            elif backend is not None:
+                use_host = backend == "host"
+            else:
+                return False
+        # ONE locked region owns the recal counter, the trial's next arm
+        # AND the decision read this launch acts on: the old shape re-read
+        # self._pool_decision unlocked after the calibrate block
+        # (pandaraces RAC1101 — a concurrent recal archiving the probe
+        # could flip the value between the calibration and its use).
         with self._pool_decision_lock:
             decision = self._pool_decision
             if (
@@ -2336,8 +2440,9 @@ class TpuEngine:
                 and decision is not None
             ):
                 # periodic re-calibration: after N shardable launches the
-                # pinned decision is archived and THIS launch re-measures —
-                # burstable hosts that gained (or lost) capacity re-pin
+                # pinned decision is archived and a new trial re-measures,
+                # from THIS launch on — burstable hosts that gained (or
+                # lost) capacity re-pin
                 self._launches_since_cal += 1
                 if self._launches_since_cal >= self._recal_interval:
                     if self._host_pool_probe is not None:
@@ -2346,30 +2451,36 @@ class TpuEngine:
                         )
                     decision = self._pool_decision = None
                     self._launches_since_cal = 0
-                    # the calibration this triggers journals itself as
-                    # a recal (read + cleared in _calibrate_host_pool)
                     self._recal_pending = True
             if decision is None:
-                self._calibrate_host_pool(plan, all_batches, counts)
-                decision = self._pool_decision
+                trial = self._pool_trial
+                if trial is None:
+                    trial = self._pool_trial = host_pool.LaunchTrial()
+                if trial.exhausted:
+                    # its launches never came back as samples (fused
+                    # tickets, one-shot costs): keep the inline path
+                    self._conclude_trial(trial)
+                    decision = self._pool_decision
+                else:
+                    decision = trial.next_arm()
+                    launch._trial = host_pool.TrialSample(trial, decision)
         if decision != "sharded":
-            return False  # calibration: no real win on this box
-        use_host = None
-        if plan.mode == "columnar" and plan.dev_cols:
-            if self._mesh is not None:
-                return False  # SPMD predicate stays one launch over the mesh
-            backend = TpuEngine.sticky_columnar_backend()
-            if self._force_mode == "columnar_host":
-                use_host = True
-            elif self._force_mode == "columnar_device":
-                use_host = False
-            elif backend is not None:
-                use_host = backend == "host"
-                self.governor.note_posture(
-                    governor.COLUMNAR_BACKEND, backend
-                )
-            else:
-                return False
+            return False  # measured: no real win on this box
+        if backend is not None and self._force_mode is None:
+            self.governor.note_posture(governor.COLUMNAR_BACKEND, backend)
+        if self._run_sharded(launch, plan, all_batches, counts, parts, use_host):
+            return True
+        if launch._trial is not None:
+            # degraded to the inline path: not a sample of this arm
+            self._trial_dropped(launch)
+        return False
+
+    def _run_sharded(
+        self, launch: _Launch, plan, all_batches, counts, parts, use_host
+    ) -> bool:
+        """The sharded road of one launch; False degrades it to the inline
+        path (a faulted shard worker: nothing was emitted yet)."""
+        pool = self._host_pool
         breaker_demoted_rows = 0
         if plan.mode == "columnar" and plan.dev_cols and use_host is False:
             if not self._breaker.allow_device():
@@ -3043,6 +3154,12 @@ class TpuEngine:
         # retained until the packed result lands: the host fallback re-runs
         # the pipeline on the CPU backend over exactly these rows
         launch._staged_np = staged
+        # what the lane adds to a launch: the rows it pads the bucket with,
+        # and the values it drops for exceeding the staging row
+        self._stat_add("n_staged_rows", float(n_pad))
+        n_oversize = launch.n - int(np.count_nonzero(launch.fits))
+        if n_oversize:
+            self._stat_add("n_oversize_rows", float(n_oversize))
         t0 = _stage_t0("t_dispatch")
         if not self._breaker.allow_device():
             launch._packed_dev = launch._payload_host_fallback()
@@ -3051,14 +3168,17 @@ class TpuEngine:
 
         def leg():
             faults.inject(faults.DEVICE_DISPATCH)
+            # the leg runs on the fault envelope's worker: no ambient trace
+            t_h2d = _stage_t0("t_h2d")
             dev = jax.device_put(staged)
+            self._stat_stage("t_h2d", t_h2d, trace_id=launch.trace_id)
             packed = fn(dev)
             packed.copy_to_host_async()
             return packed
 
         packed = self._try_device_leg(
             faults.DEVICE_DISPATCH, leg,
-            program=(launch.script_id, "payload", n_pad),
+            program=(launch.script_id, "payload", n_pad), fn=fn,
         )
         if packed is None:
             launch._packed_dev = launch._payload_host_fallback()
@@ -3302,6 +3422,7 @@ class TpuEngine:
         probes cannot grow threads."""
         import time as _t
 
+        self._note_spoiler()
         t0 = _t.perf_counter()
         plan.eval_host_mask(cols)
         t_host = _t.perf_counter() - t0

@@ -28,6 +28,7 @@ show the fan-out.
 from __future__ import annotations
 
 import os
+import statistics
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -43,13 +44,104 @@ def default_host_workers() -> int:
     return min(4, os.cpu_count() or 1)
 
 
-# The measured sharded/inline ratio must clear this margin before the
-# engine pins the pool on (see TpuEngine._calibrate_host_pool): a real
-# 2-core box shards the explode stage ~1.8x faster; a quota-limited box
-# advertising CPUs it doesn't have measures <= 1.0 with scheduler-thrash
-# tails. Requiring a real win also keeps borderline boxes (whose burst
-# capacity comes and goes) on the predictable inline path.
+# The measured inline/sharded ratio must clear this margin before the
+# engine pins the pool on (see LaunchTrial): a real 2-core box shards the
+# explode stage ~1.8x faster; a quota-limited box advertising CPUs it
+# doesn't have measures <= 1.0 with scheduler-thrash tails. Requiring a
+# real win also keeps borderline boxes (whose burst capacity comes and
+# goes) on the predictable inline path.
 PROBE_MARGIN = 1.25
+
+# Whole launches a trial times on each arm before it decides, and the
+# launches it may spend in all (samples that met a one-shot cost, a
+# fallback or a fused ticket are dropped) before it gives up and keeps
+# the inline path.
+TRIAL_LAUNCHES = 5
+TRIAL_MAX_LAUNCHES = 40
+
+
+class TrialSample:
+    """One launch of a trial, from its dispatch to its harvest: which
+    trial, which road, and the seconds its dispatch took."""
+
+    __slots__ = ("trial", "arm", "dispatch_s")
+
+    def __init__(self, trial: "LaunchTrial", arm: str):
+        self.trial = trial
+        self.arm = arm
+        self.dispatch_s = 0.0
+
+
+class LaunchTrial:
+    """The pool's on/off decision, taken on what it governs.
+
+    The decision switches a whole launch between two roads: inline, or
+    per-shard stages on the pool, the merge of their tables and the sharded
+    seal. An explode timed alone (the old probe) says little about that: on
+    the chip's host it read speedups of 0.46-2.2 while every run that chose
+    ``sharded`` was slower end to end. So the first shardable launches of a
+    process run alternately inline and sharded (the two are bit-identical,
+    no work is wasted), each timed whole on the engine's clock, dispatch
+    plus harvest up to the sealed reply, per row; the medians are compared
+    and sharded must win by PROBE_MARGIN. The periodic re-calibration is a
+    new trial.
+
+    Bookkeeping only: the engine owns the clock, the lock and the journal.
+    """
+
+    ARMS = ("inline", "sharded")
+
+    def __init__(self):
+        self.samples: dict[str, list[float]] = {arm: [] for arm in self.ARMS}
+        self.issued = 0
+        self.dropped = 0
+        self.per_arm = TRIAL_LAUNCHES
+
+    def next_arm(self) -> str:
+        """The road the next shardable launch takes: the arm with fewer
+        samples, alternating from inline while they are level (launches in
+        flight have not sampled yet)."""
+        self.issued += 1
+        n_inline, n_sharded = (len(self.samples[arm]) for arm in self.ARMS)
+        if n_inline != n_sharded:
+            return self.ARMS[n_inline > n_sharded]
+        return self.ARMS[(self.issued - 1) % 2]
+
+    def add(self, arm: str, seconds: float, rows: int) -> None:
+        self.samples[arm].append(seconds * 1e6 / max(rows, 1))
+
+    def drop(self) -> None:
+        self.dropped += 1
+
+    @property
+    def complete(self) -> bool:
+        return all(len(v) >= self.per_arm for v in self.samples.values())
+
+    @property
+    def exhausted(self) -> bool:
+        return self.issued >= TRIAL_MAX_LAUNCHES
+
+    def verdict(self) -> dict:
+        """What was measured and what it chose; an incomplete trial keeps
+        the inline path and says so."""
+        out = {
+            "measured": "whole launches, dispatch to sealed reply, us a row",
+            "launches": {arm: len(v) for arm, v in self.samples.items()},
+            "dropped": self.dropped,
+        }
+        if not self.complete:
+            return dict(out, incomplete=True, chosen="inline")
+        inline, sharded = (
+            statistics.median(self.samples[arm]) for arm in self.ARMS
+        )
+        ratio = inline / sharded if sharded > 0 else 0.0
+        return dict(
+            out,
+            inline_us_per_row=round(inline, 4),
+            sharded_us_per_row=round(sharded, 4),
+            speedup=round(ratio, 3),
+            chosen="sharded" if ratio >= PROBE_MARGIN else "inline",
+        )
 
 
 def measure_parallel_capacity(workers: int = 2) -> dict:
@@ -57,8 +149,8 @@ def measure_parallel_capacity(workers: int = 2) -> dict:
     here? ``os.cpu_count()`` lies on quota-limited boxes, so
     tools/microbench.py reports this next to the pool-scaling numbers.
     NOTE this synthetic answer is context only — the engine calibrates on
-    its REAL explode stage (burstable hosts can pass a millisecond-scale
-    synthetic probe and still thrash on sustained parsing work).
+    its REAL launches (LaunchTrial; burstable hosts can pass a
+    millisecond-scale synthetic probe and still thrash on sustained work).
     Returns {'speedup', 'workers'}; best-of-3 on both sides."""
     workers = max(2, int(workers))
 
@@ -145,16 +237,20 @@ class HostStagePool:
         self._executor: ThreadPoolExecutor | None = None
         self._lock = lockwatch.wrap(threading.Lock(), "HostStagePool._lock")
 
-    def _ensure_executor(self) -> ThreadPoolExecutor:
+    def _submit_all(self, fns: list) -> list:
         # locked check-then-create: concurrent first launches must not
-        # each build (and leak) an executor
+        # each build (and leak) an executor. The submits stay under the
+        # lock too: a trial's verdict may shut the pool down (shutdown())
+        # while another launch of the trial is still fanning out, and an
+        # executor that is shut down refuses new work (work it already
+        # holds still runs)
         with self._lock:
             if self._executor is None:
                 self._executor = ThreadPoolExecutor(
                     max_workers=self.workers,
                     thread_name_prefix="rptpu-host-stage",
                 )
-            return self._executor
+            return [self._executor.submit(self._tracked, fn) for fn in fns]
 
     def run(self, fns: list) -> list:
         """Run thunks concurrently; returns results in input order.
@@ -167,8 +263,7 @@ class HostStagePool:
         """
         if len(fns) == 1:
             return [self._tracked(fns[0])]
-        ex = self._ensure_executor()
-        futures = [ex.submit(self._tracked, fn) for fn in fns]
+        futures = self._submit_all(fns)
         results = []
         first_exc: BaseException | None = None
         for f in futures:

@@ -96,6 +96,17 @@ coproc_d2h_bytes = registry.counter(
     "Bytes staged to / fetched from the device",
     direction="d2h",
 )
+# What the payload lane adds to a launch: the rows of the bucket it stages
+# (padding included, so records / staged rows is the staging's fill) and
+# the values it drops because they are wider than the staging row.
+coproc_staged_rows = registry.counter(
+    "coproc_staged_rows_total",
+    "Rows the payload lane staged to the device, bucket padding included",
+)
+coproc_oversize_rows = registry.counter(
+    "coproc_oversize_rows_total",
+    "Values wider than the staging row, which the payload lane drops",
+)
 coproc_launch_rows_hist = registry.histogram(
     "coproc_launch_rows",
     "Records fused into one device launch (bucket size after shape rounding)",
@@ -446,9 +457,11 @@ __all__ = [
     "coproc_launch_rows_hist",
     "coproc_leakwatch_imbalance",
     "coproc_lockwatch_edges",
+    "coproc_oversize_rows",
     "coproc_retries_total",
     "coproc_shard_rows_hist",
     "coproc_stage_hist",
+    "coproc_staged_rows",
     "coproc_tick_hist",
     "host_pool_task_finished",
     "host_pool_task_started",

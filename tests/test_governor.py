@@ -29,6 +29,7 @@ from redpanda_tpu.coproc import (
 )
 from redpanda_tpu.coproc import faults
 from redpanda_tpu.coproc import governor
+from redpanda_tpu.coproc import host_pool
 from redpanda_tpu.coproc.engine import ProcessBatchItem
 from redpanda_tpu.finjector import honey_badger
 from redpanda_tpu.models import NTP, Record, RecordBatch
@@ -109,6 +110,7 @@ def test_journal_covers_all_six_domains_under_real_launches(monkeypatch):
     harvest-path and seal verdicts; an armed mask-fetch fault drives a
     breaker transition; the lz4 probe drives device_lz4."""
     TpuEngine.reset_columnar_probe()
+    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", 1)
     # pure filter => passthrough plan => gather framing; 64 batches x 32
     # records = 2048 rows clears both _PROBE_MIN_ROWS and _SHARD_MIN_ROWS
     spec = where(field("level") == "error")
@@ -123,7 +125,9 @@ def test_journal_covers_all_six_domains_under_real_launches(monkeypatch):
     big = _req(parts=64, n=32)
     engine.process_batch(big)  # first columnar launch: backend probe
     assert governor.COLUMNAR_BACKEND in _domains()
-    engine.process_batch(big)  # now shardable: pool calibration
+    # now shardable: the pool's A/B over whole launches, one of each road
+    engine.process_batch(big)
+    engine.process_batch(big)
     got = _domains()
     assert governor.HOST_POOL in got
     assert governor.HARVEST_PATH in got

@@ -29,7 +29,7 @@ from redpanda_tpu.coproc import (
     ProcessBatchRequest,
     TpuEngine,
 )
-from redpanda_tpu.coproc import batch_codec
+from redpanda_tpu.coproc import batch_codec, host_pool
 from redpanda_tpu.coproc import engine as engine_mod
 from redpanda_tpu.coproc.column_plan import plan_spec
 from redpanda_tpu.coproc.engine import ProcessBatchItem
@@ -443,15 +443,22 @@ def test_engine_arena_reuse_and_reset():
 
 # ------------------------------------------------------ pool re-calibration
 def _recal_engine(monkeypatch, interval, ratios):
-    """Engine whose pool measurement returns the next (t_inline, t_sharded)
-    pair from `ratios` on each calibration."""
+    """Engine whose pool trials (one launch a road) sample the next
+    (t_inline, t_sharded) pair from `ratios`, one pair per trial."""
     monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
+    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", 1)
     seq = list(ratios)
+    real_init = host_pool.LaunchTrial.__init__
 
-    def fake_measure(self, plan, batches, counts):
-        return seq.pop(0)
+    def init(self):
+        real_init(self)
+        self.costs = dict(zip(self.ARMS, seq.pop(0)))
 
-    monkeypatch.setattr(TpuEngine, "_measure_pool_ratio", fake_measure)
+    def add(self, arm, seconds, rows):
+        self.samples[arm].append(self.costs[arm] * 1e6)
+
+    monkeypatch.setattr(host_pool.LaunchTrial, "__init__", init)
+    monkeypatch.setattr(host_pool.LaunchTrial, "add", add)
     engine = TpuEngine(
         row_stride=256, compress_threshold=10**9,
         force_mode="columnar_host", host_workers=4,
@@ -462,31 +469,32 @@ def _recal_engine(monkeypatch, interval, ratios):
 
 
 def test_recalibration_reprobes_and_archives(monkeypatch):
-    """interval=2: launch 1 calibrates (inline wins), launch 3 re-measures
-    (sharded now wins) — the decision flips and the first probe is
-    archived under host_pool_probe_prev."""
+    """interval=2: launches 1-2 are the first trial (inline wins), 3-4
+    count to the interval, 4-5 are the second trial (sharded now wins) —
+    the decision flips and the first probe is archived under
+    host_pool_probe_prev."""
     engine = _recal_engine(
         monkeypatch, 2, [(0.010, 0.009), (0.010, 0.005)]
     )
     req = _matrix_request(n_items=4)
-    for _ in range(3):
+    for _ in range(6):
         engine.process_batch(req)
     stats = engine.stats()
     engine.shutdown()
     assert stats["host_pool_probe"]["chosen"] == "sharded"
     assert stats["host_pool_probe_prev"]["chosen"] == "inline"
     assert stats["host_pool_recal"]["interval"] == 2
-    assert stats["n_sharded_launches"] >= 1
+    assert stats["n_sharded_launches"] >= 3  # one a trial, then launch 6
 
 
 def test_recalibration_zero_pins_forever(monkeypatch):
     engine = _recal_engine(monkeypatch, 0, [(0.010, 0.009)])
     req = _matrix_request(n_items=4)
-    for _ in range(4):
+    for _ in range(5):
         engine.process_batch(req)
     stats = engine.stats()
     engine.shutdown()
-    # one calibration, never re-measured (the fake would IndexError)
+    # one trial, never re-measured (the fake would IndexError)
     assert stats["host_pool_probe"]["chosen"] == "inline"
     assert "host_pool_probe_prev" not in stats
     assert stats["host_pool_recal"]["interval"] == 0
@@ -497,10 +505,10 @@ def test_recalibration_skipped_when_probe_pinned_off(monkeypatch):
     re-calibration must never override it."""
     monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
 
-    def boom(self, plan, batches, counts):  # pragma: no cover
+    def boom(self):  # pragma: no cover
         raise AssertionError("pinned engine must never measure")
 
-    monkeypatch.setattr(TpuEngine, "_measure_pool_ratio", boom)
+    monkeypatch.setattr(host_pool.LaunchTrial, "__init__", boom)
     engine = TpuEngine(
         row_stride=256, compress_threshold=10**9,
         force_mode="columnar_host", host_workers=4,

@@ -24,7 +24,7 @@ from redpanda_tpu.coproc import (
     ProcessBatchRequest,
     EnableResponseCode,
 )
-from redpanda_tpu.coproc import batch_codec, host_pool
+from redpanda_tpu.coproc import batch_codec, governor, host_pool
 from redpanda_tpu.coproc import engine as engine_mod
 from redpanda_tpu.coproc.column_plan import plan_spec
 from redpanda_tpu.coproc.engine import ProcessBatchItem
@@ -104,6 +104,32 @@ def test_pool_propagates_first_exception_in_order():
 
 
 # ------------------------------------------------------ frame_ranges empty
+def test_pool_shut_down_under_a_fan_out_serves_it_and_the_next():
+    """A trial's verdict shuts the pool down while another launch may be
+    fanning out: work the executor already holds still runs, and the next
+    fan-out gets a new executor (never 'cannot schedule new futures')."""
+    import threading
+
+    pool = host_pool.HostStagePool(2)
+    started, release = threading.Event(), threading.Event()
+
+    def held():
+        started.set()
+        release.wait(5.0)
+        return "held"
+
+    got = []
+    t = threading.Thread(target=lambda: got.append(pool.run([held, lambda: "b"])))
+    t.start()
+    assert started.wait(5.0)
+    pool.shutdown()  # mid fan-out
+    assert pool.run([lambda: 1, lambda: 2]) == [1, 2]  # a new executor
+    release.set()
+    t.join(5.0)
+    assert got == [["held", "b"]]
+    pool.shutdown()
+
+
 def test_frame_ranges_empty_ranges_native_and_python(monkeypatch):
     rows = np.zeros((4, 8), np.uint8)
     lens = np.full(4, 8, np.int32)
@@ -259,82 +285,200 @@ def test_sharded_bit_identical_columnar_device(monkeypatch):
 
 
 # ------------------------------------------------------ pool calibration
-def _calibration_engine(monkeypatch, t_inline, t_sharded):
-    """Engine with the real-work calibration measurement pinned to the
-    given timings (the decision logic is what's under test; the actual
-    explode timing is the box's business)."""
+# The decision is taken on what it governs (host_pool.LaunchTrial): the
+# first shardable launches run alternately inline and sharded, each timed
+# whole, dispatch to sealed reply; medians, sharded must win by PROBE_MARGIN.
+def _slowed(monkeypatch, name, seconds):
+    """Make ``batch_codec.<name>`` take ``seconds`` longer (a real sleep:
+    the trial times real launches)."""
+    import time
+
+    real = getattr(batch_codec, name)
+
+    def slow(*a, **kw):
+        time.sleep(seconds)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(batch_codec, name, slow)
+
+
+def _fixed_costs(monkeypatch, per_trial):
+    """Trials whose samples are the given (inline, sharded) seconds a row,
+    one pair per trial in order: the rule is under test, the launches still
+    run both roads for real."""
+    costs = list(per_trial)
+    real_init = host_pool.LaunchTrial.__init__
+
+    def init(self):
+        real_init(self)
+        self.costs = dict(zip(self.ARMS, costs.pop(0)))
+
+    def add(self, arm, seconds, rows):
+        assert seconds > 0 and rows > 0
+        self.samples[arm].append(self.costs[arm] * 1e6)
+
+    monkeypatch.setattr(host_pool.LaunchTrial, "__init__", init)
+    monkeypatch.setattr(host_pool.LaunchTrial, "add", add)
+
+
+def _trial_run(monkeypatch, spec, launches, per_arm=2, **engine_kw):
+    """Drive ``launches`` shardable launches through an engine whose pool
+    decision is still to be measured; returns (engine, stats)."""
     monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    monkeypatch.setattr(
-        TpuEngine,
-        "_measure_pool_ratio",
-        lambda self, plan, batches, counts: (t_inline, t_sharded),
-    )
+    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", per_arm)
+    governor.reset_journal()
     engine = TpuEngine(
-        row_stride=256, compress_threshold=10**9,
-        force_mode="columnar_host", host_workers=4,
+        row_stride=256, compress_threshold=10**9, host_workers=4, **engine_kw
     )
-    engine.enable_coprocessors([(1, _columnar_spec().to_json(), ("orders",))])
+    engine.enable_coprocessors([(1, spec.to_json(), ("orders",))])
     req = ProcessBatchRequest(
         [ProcessBatchItem(1, NTP.kafka("orders", 0), [_json_batch(40), _json_batch(40)])]
     )
-    reply = engine.process_batch(req)
-    assert reply.items[0].batches
+    want = None
+    for _ in range(launches):
+        reply = engine.process_batch(req)
+        got = [b.payload for b in reply.items[0].batches]
+        assert got and (want is None or got == want)  # both roads, same bits
+        want = got
+    return engine, engine.stats()
+
+
+def _case_explode_faster_but_whole_launch_slower(monkeypatch):
+    # what the old explode-only probe got wrong: the pool explodes faster
+    # (the inline explode is slowed), yet the sharded launch as a whole is
+    # slower (its merge is slowed more) -> inline
+    _slowed(monkeypatch, "explode_ptrs", 0.02)
+    _slowed(monkeypatch, "merge_exploded", 0.08)
+    engine, stats = _trial_run(monkeypatch, filter_contains(b"error"), 7)
+    probe = stats["host_pool_probe"]
+    assert probe["chosen"] == "inline" and probe["speedup"] < 1.0
+    assert probe["launches"] == {"inline": 2, "sharded": 2}
+    assert probe["dropped"] >= 1  # the launch that compiled the program
+    assert stats["n_sharded_launches"] == 2  # the trial's own, none after
     return engine
 
 
-def test_calibration_keeps_inline_when_sharding_loses(monkeypatch):
-    """No real win measured -> the engine keeps the inline path (no
-    sharded launches, no thread thrash) and records why."""
-    engine = _calibration_engine(monkeypatch, t_inline=0.010, t_sharded=0.009)
-    stats = engine.stats()
-    assert "n_sharded_launches" not in stats
-    assert stats["host_pool_probe"]["chosen"] == "inline"
-    assert stats["host_pool_probe"]["speedup"] == round(10 / 9, 3)
+def _case_whole_launch_win_pins_sharded(monkeypatch):
+    _slowed(monkeypatch, "explode_ptrs", 0.06)  # the inline road alone
+    engine, stats = _trial_run(monkeypatch, filter_contains(b"error"), 8)
+    probe = stats["host_pool_probe"]
+    assert probe["chosen"] == "sharded"
+    assert probe["speedup"] >= host_pool.PROBE_MARGIN
+    assert stats["n_sharded_launches"] >= 3  # the trial's two, then every one
+    return engine
 
 
-def test_calibration_pins_sharded_on_a_real_win(monkeypatch):
-    engine = _calibration_engine(monkeypatch, t_inline=0.010, t_sharded=0.005)
-    stats = engine.stats()
-    assert stats["n_sharded_launches"] >= 1
-    assert stats["host_pool_probe"]["chosen"] == "sharded"
+def _case_win_below_the_margin_keeps_inline(monkeypatch):
+    _fixed_costs(monkeypatch, [(0.010, 0.009)])
+    engine, stats = _trial_run(
+        monkeypatch, _columnar_spec(), 6, force_mode="columnar_host"
+    )
+    probe = stats["host_pool_probe"]
+    assert probe["chosen"] == "inline"
+    assert probe["speedup"] == round(10 / 9, 3)
+    assert stats["n_sharded_launches"] == 2
+    (entry,) = governor.journal.entries(domain="host_pool")
+    assert entry["verdict"] == "inline" and "whole launches" in entry["reason"]
+    assert entry["inputs"]["samples"] == {
+        "inline": [10000.0] * 2, "sharded": [9000.0] * 2
+    }
+    return engine
 
 
-def test_calibration_failure_falls_back_inline(monkeypatch):
-    def boom(self, plan, batches, counts):
+def _case_failing_calibration_keeps_inline(monkeypatch):
+    def boom(self):
         raise RuntimeError("measurement exploded")
 
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    monkeypatch.setattr(TpuEngine, "_measure_pool_ratio", boom)
-    engine = TpuEngine(
-        row_stride=256, compress_threshold=10**9,
-        force_mode="columnar_host", host_workers=4,
+    monkeypatch.setattr(host_pool.LaunchTrial, "verdict", boom)
+    engine, stats = _trial_run(
+        monkeypatch, _columnar_spec(), 6, force_mode="columnar_host"
     )
-    engine.enable_coprocessors([(1, _columnar_spec().to_json(), ("orders",))])
-    req = ProcessBatchRequest(
-        [ProcessBatchItem(1, NTP.kafka("orders", 0), [_json_batch(40), _json_batch(40)])]
-    )
-    reply = engine.process_batch(req)
-    assert reply.items[0].batches
-    assert engine._pool_decision == "inline"
-    engine.shutdown()
+    assert engine._pool_decision == "inline" and "host_pool_probe" not in stats
+    (entry,) = governor.journal.entries(domain="host_pool")
+    assert entry["verdict"] == "inline" and "FAILED" in entry["reason"]
+    return engine
 
 
-def test_measure_pool_ratio_runs_real_stages(monkeypatch):
-    """The un-mocked measurement must return positive wall times for both
-    legs on the real explode stage."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    engine = TpuEngine(
-        row_stride=256, compress_threshold=10**9,
-        force_mode="columnar_host", host_workers=2,
+def _case_no_clean_sample_keeps_inline(monkeypatch):
+    # every launch meets a spoiler (a first run, a probe, a fallback): the
+    # trial spends its launches, says so, and the inline path stays
+    monkeypatch.setattr(host_pool, "TRIAL_MAX_LAUNCHES", 6)
+    monkeypatch.setattr(TpuEngine, "_trial_elapsed", lambda self, mark: None)
+    engine, stats = _trial_run(
+        monkeypatch, _columnar_spec(), 8, force_mode="columnar_host"
     )
-    engine.enable_coprocessors([(1, _columnar_spec().to_json(), ("orders",))])
-    plan = engine._plans[1]
-    batches = [_json_batch(64), _json_batch(64)]
-    t_inline, t_sharded = engine._measure_pool_ratio(
-        plan, batches, [b.header.record_count for b in batches]
+    probe = stats["host_pool_probe"]
+    assert probe["chosen"] == "inline" and probe["incomplete"] is True
+    assert probe["launches"] == {"inline": 0, "sharded": 0}
+    assert probe["dropped"] == 6
+    return engine
+
+
+def _case_recalibration_follows_the_same_rule(monkeypatch):
+    _fixed_costs(monkeypatch, [(0.010, 0.009), (0.010, 0.005)])
+    engine, stats = _trial_run(
+        monkeypatch, _columnar_spec(), 6, per_arm=1,
+        force_mode="columnar_host", host_pool_recal_launches=2,
     )
-    assert t_inline > 0 and t_sharded > 0
-    engine.shutdown()
+    # launches 1-2: the first trial (inline); 3-4 count to the interval;
+    # 4-5: the second trial, which the pool now wins
+    assert stats["host_pool_probe_prev"]["chosen"] == "inline"
+    assert stats["host_pool_probe"]["chosen"] == "sharded"
+    second, first = governor.journal.entries(domain="host_pool")
+    assert first["inputs"]["recalibration"] is False
+    assert second["inputs"]["recalibration"] is True
+    return engine
+
+
+def _case_unmocked_trial_times_both_roads(monkeypatch):
+    engine, stats = _trial_run(
+        monkeypatch, _columnar_spec(), 6, force_mode="columnar_host"
+    )
+    probe = stats["host_pool_probe"]
+    assert probe["inline_us_per_row"] > 0 and probe["sharded_us_per_row"] > 0
+    assert probe["chosen"] in ("inline", "sharded")
+    assert probe["workers"] == 4
+    return engine
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        _case_explode_faster_but_whole_launch_slower,
+        _case_whole_launch_win_pins_sharded,
+        _case_win_below_the_margin_keeps_inline,
+        _case_failing_calibration_keeps_inline,
+        _case_no_clean_sample_keeps_inline,
+        _case_recalibration_follows_the_same_rule,
+        _case_unmocked_trial_times_both_roads,
+    ],
+    ids=lambda f: f.__name__[len("_case_"):],
+)
+def test_host_pool_trial(case, monkeypatch):
+    case(monkeypatch).shutdown()
+
+
+def test_launch_trial_bookkeeping(monkeypatch):
+    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", 3)
+    monkeypatch.setattr(host_pool, "TRIAL_MAX_LAUNCHES", 9)
+    trial = host_pool.LaunchTrial()
+    # level: alternate from inline (launches in flight have not sampled)
+    assert [trial.next_arm() for _ in range(3)] == ["inline", "sharded", "inline"]
+    trial.add("inline", 0.004, 1000)
+    assert trial.next_arm() == "sharded"  # the arm that is behind
+    for us in (9.0, 1.0, 2.0):
+        trial.add("sharded", us * 1e-6 * 500, 500)
+    assert trial.next_arm() == "inline" and not trial.complete
+    trial.add("inline", 0.006, 1000)
+    trial.add("inline", 0.050, 1000)  # one far-off launch moves no median
+    assert trial.complete and not trial.exhausted
+    got = trial.verdict()
+    assert (got["inline_us_per_row"], got["sharded_us_per_row"]) == (6.0, 2.0)
+    assert got["speedup"] == 3.0 and got["chosen"] == "sharded"
+    assert got["launches"] == {"inline": 3, "sharded": 3} and got["dropped"] == 0
+    for _ in range(4):
+        trial.next_arm()
+    assert trial.exhausted
 
 
 def test_measure_parallel_capacity_shape():

@@ -1,0 +1,156 @@
+"""The served payload lane against the benchmark's plain reference.
+
+Configuration ``json64p-v1`` (benchmarks/configs/json64p-v1.json) is the
+north star's JSON filter, ``filter_contains('"level":"warn"')``: a v1
+script, so a PayloadPlan that stages whole rows to the device. Here, at a
+small size on the CPU: ``TpuEngine`` submit -> sealed reply over the seeded
+document stream plus the values on the lane's edges, byte-equal and in
+order to ``benchmarks/references/filter_contains.py`` (loaded by path, the
+way ``benchmarks/loadgen.load_reference`` loads it; the reference imports
+nothing of the program). The same runs hold the two counters the lane's
+per-layer metrics read.
+"""
+
+import importlib.util
+import json
+import os
+
+import pytest
+
+from redpanda_tpu.coproc import EnableResponseCode, ProcessBatchRequest, TpuEngine
+from redpanda_tpu.coproc.engine import ProcessBatchItem
+from redpanda_tpu.coproc.reference import make_documents
+from redpanda_tpu.models import NTP, Record, RecordBatch
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
+NEEDLE = b'"level":"warn"'
+
+
+def _config() -> dict:
+    with open(os.path.join(BENCH, "configs", "json64p-v1.json")) as f:
+        return json.load(f)
+
+
+def _reference(name: str):
+    path = os.path.join(BENCH, "references", name + ".py")
+    spec = importlib.util.spec_from_file_location("perfbench_reference_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _doc(size: int, needle_at: int | None) -> bytes:
+    """A document of exactly ``size`` bytes holding the needle at byte
+    ``needle_at`` (nowhere when None)."""
+    body = bytearray(b"y" * size)
+    if needle_at is not None:
+        body[needle_at : needle_at + len(NEEDLE)] = NEEDLE
+    assert len(body) == size
+    return bytes(body)
+
+
+# the lane's edges: empty, exactly the stride, one over it, and a value
+# whose only needle lies beyond the staging row
+EDGES = [
+    b"",
+    _doc(1024, 8),            # fits to the byte and matches: kept
+    _doc(1024, 1024 - 14),    # the needle ends on the row's last byte: kept
+    _doc(1024, None),         # fits, no needle: dropped
+    _doc(1025, 8),            # one byte too wide: dropped though it matches
+    _doc(1060, 1030),         # the needle only beyond byte 1,024: dropped
+    b"",
+    NEEDLE,                   # the needle alone
+]
+
+
+def _batches(values: list[bytes], per_batch: int, base: int) -> list[RecordBatch]:
+    out = []
+    for s in range(0, len(values), per_batch):
+        chunk = values[s : s + per_batch]
+        out.append(RecordBatch.build(
+            [Record(offset_delta=i, timestamp_delta=i, value=v) for i, v in enumerate(chunk)],
+            base_offset=base + s, first_timestamp=1000,
+        ))
+    return out
+
+
+@pytest.mark.parametrize("seed", [7, 2**31 + 11])
+def test_payload_lane_matches_the_plain_reference(seed):
+    config = _config()
+    ref = _reference(config["reference"]["name"])
+    params = config["reference"]["params"]
+    stride = params["row_stride"]
+    parts = make_documents(seed, 4, 96)
+    parts[1] = parts[1][:40] + EDGES + parts[1][40:]
+    parts[3] = EDGES[::-1] + parts[3]
+
+    engine = TpuEngine(row_stride=stride, host_workers=0)
+    try:
+        codes = engine.enable_coprocessors(
+            [(1, json.dumps(config["script"]["spec"]), ("bench",))]
+        )
+        assert codes == [EnableResponseCode.success]
+        assert engine._plans[1].mode == "payload"
+        req = ProcessBatchRequest([
+            ProcessBatchItem(1, NTP.kafka("bench", p), _batches(values, 32, 1000 * p))
+            for p, values in enumerate(parts)
+        ])
+        reply = engine.submit(req).result()
+        stats = engine.stats()
+    finally:
+        engine.shutdown()
+
+    n_in = sum(map(len, parts))
+    kept_total = 0
+    for item, values in zip(reply.items, parts):
+        got = [r.value for b in item.batches for r in b.records()]
+        want = [o for o in (ref.reference(v, **params) for v in values) if o is not None]
+        assert got == want, f"partition {item.source.partition}"
+        kept_total += len(want)
+    # the comparison bites on both sides of the stride
+    assert ref.reference(EDGES[1], **params) == EDGES[1]
+    assert ref.reference(EDGES[4], **params) is None
+    assert 0 < kept_total < n_in
+    # one launch: the bucket it was padded to, and the values it dropped
+    # for exceeding the staging row
+    assert stats["n_launches"] == 1 and stats["n_records"] == n_in
+    assert stats["n_staged_rows"] == 512 >= n_in
+    assert stats["n_oversize_rows"] == sum(len(v) > stride for vs in parts for v in vs) >= 4
+    assert stats["t_h2d"] > 0 and stats["bytes_h2d"] == 512 * (stride + 8)
+    assert stats.get("n_fallback_rows", 0) == 0
+
+
+def test_a_script_inherits_the_programs_its_spec_already_ran():
+    """Scripts of one spec share one jitted pipeline (ops/pipeline.py caches
+    it by spec), so a second script's launch at a row bucket the function
+    has seen is no first run: ``n_compiles`` counts traces + compiles, not
+    scripts (a re-deployed filter starts past them, as the benchmark's
+    catch-up window does after its warm-up scripts)."""
+    config = _config()
+    spec = json.dumps(config["script"]["spec"])
+    engine = TpuEngine(row_stride=1024, host_workers=0)
+    try:
+        assert engine.enable_coprocessors(
+            [(1, spec, ("bench",)), (2, spec, ("bench",))]
+        ) == [EnableResponseCode.success] * 2
+        small = make_documents(5, 1, 64)[0]
+        large = make_documents(5, 1, 200)[0]
+
+        def launch(script_id, values):
+            req = ProcessBatchRequest(
+                [ProcessBatchItem(script_id, NTP.kafka("bench", 0), _batches(values, 32, 0))]
+            )
+            engine.submit(req).result()
+            return engine.stats()["n_compiles"]
+
+        assert launch(1, small) == 1  # bucket 128: the function's first run
+        assert launch(2, small) == 1  # script 2, same function, same bucket
+        assert launch(2, large) == 2  # bucket 256: a new shape compiles
+        assert launch(1, large) == 2
+        programs = engine.stats()["compiled_programs"]
+        assert {(c["script_id"], c["n_pad"]) for c in programs} == {
+            (1, 128), (2, 128), (1, 256), (2, 256)
+        }
+        assert engine.stats()["n_device_launches"] == 4
+    finally:
+        engine.shutdown()

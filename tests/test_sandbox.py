@@ -295,7 +295,10 @@ def test_deadline_layer_kills_slow_loop(monkeypatch):
     from redpanda_tpu.coproc.governor import reset_journal
 
     reset_journal()
-    monkeypatch.setattr(sandbox, "EXEC_WALL_DEADLINE_S", 0.05)
+    # 5 ms: a quiet machine runs this loop's 100,000 traced lines in under
+    # 50 ms, and then the line budget tripped first (the test failed at the
+    # parent commit too); no machine traces a line in 50 ns
+    monkeypatch.setattr(sandbox, "EXEC_WALL_DEADLINE_S", 0.005)
     fn = compile_transform(
         # each iteration sleeps via a modest str*int (guard-permitted) so
         # few line events burn real time: deadline trips before budget

@@ -186,3 +186,47 @@ def test_annotation_lands_on_the_profiles_host_plane(tmp_path):
     }
     assert {"rp:unit.closed", "rp:unit.with"} <= names
     assert hist.hist.count == 2
+
+
+def test_the_payload_lanes_h2d_stage_feeds_all_its_sinks(tmp_path):
+    """``t_h2d`` (PR 26): the ``jax.device_put`` of a payload launch's
+    staged matrix is a stage of its own inside ``t_dispatch``: the stat,
+    the ``coproc_stage_latency_us{stage="h2d"}`` histogram, and the
+    ``rp:coproc.stage.h2d`` annotation beside pack, fetch and rebuild."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from redpanda_tpu.coproc import ProcessBatchRequest, TpuEngine
+    from redpanda_tpu.coproc.engine import ProcessBatchItem
+    from redpanda_tpu.models import NTP, Record, RecordBatch
+    from redpanda_tpu.observability import probes
+    from redpanda_tpu.ops.transforms import filter_contains
+
+    engine = TpuEngine(row_stride=64, host_workers=0)
+    engine.enable_coprocessors([(1, filter_contains(b"warn").to_json(), ("t",))])
+    batch = RecordBatch.build(
+        [Record(offset_delta=i, timestamp_delta=i, value=b"warn %d" % i) for i in range(8)],
+        base_offset=0, first_timestamp=1000,
+    )
+    req = ProcessBatchRequest([ProcessBatchItem(1, NTP.kafka("t", 0), [batch])])
+    hist = probes.coproc_stage_hist("h2d").hist
+    before = hist.count
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        reply = engine.process_batch(req)
+    finally:
+        jax.profiler.stop_trace()
+        stats = engine.stats()
+        engine.shutdown()
+    assert reply.items[0].batches[0].header.record_count == 8
+    assert 0 < stats["t_h2d"] <= stats["t_dispatch"] + stats.get("t_compile", 0.0)
+    assert hist.count == before + 1
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+    }
+    assert {"rp:coproc.stage." + s for s in ("pack", "h2d", "dispatch", "fetch", "rebuild")} <= names
