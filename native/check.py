@@ -153,6 +153,59 @@ def main() -> int:
         expect = b"".join(frame_record(i, v) for i, v in enumerate(vals))
         check("frame_gather bytes", dst.raw[:ln] == expect and kept.value == nn)
 
+    # ---- pointer-table gather (rp_frame_many_gather_ptrs): one source
+    # buffer a range, offsets relative to it; same bytes as the joined gather
+    if hasattr(dll, "rp_frame_many_gather_ptrs"):
+        dll.rp_frame_many_gather_ptrs.restype = ctypes.c_int64
+        vals = [v for v in values if v is not None]
+        split = len(vals) // 2
+        groups = [vals[:split], vals[split:]]
+        bufs = [b"\xff" + b"".join(g) for g in groups]  # values start at 1
+        offs, lens = [], []
+        for g in groups:
+            pos = 1
+            for v in g:
+                offs.append(pos)
+                lens.append(len(v))
+                pos += len(v)
+        nn = len(vals)
+        keep_list = [i % 3 != 1 for i in range(nn)]
+        starts = (ctypes.c_int64 * 2)(0, split)
+        ends = (ctypes.c_int64 * 2)(split, nn)
+        src_lens = (ctypes.c_int64 * 2)(*(len(b) for b in bufs))
+
+        def gather_ptrs(off_list):
+            dst = ctypes.create_string_buffer(sum(lens) + 16 * nn + 16)
+            out_off = (ctypes.c_int64 * 2)()
+            out_len = (ctypes.c_int64 * 2)()
+            out_kept = (ctypes.c_int32 * 2)()
+            total = dll.rp_frame_many_gather_ptrs(
+                (ctypes.c_char_p * 2)(*bufs), src_lens,
+                (ctypes.c_int64 * nn)(*off_list), (ctypes.c_int32 * nn)(*lens),
+                (ctypes.c_uint8 * nn)(*keep_list), starts, ends, ctypes.c_int64(2), dst,
+                out_off, out_len, out_kept,
+            )
+            parts = [
+                (dst.raw[out_off[r] : out_off[r] + out_len[r]], out_kept[r])
+                for r in range(2)
+            ]
+            return total, parts
+
+        total, parts = gather_ptrs(offs)
+        expect = []
+        for g, lo in ((groups[0], 0), (groups[1], split)):
+            kept = [v for i, v in enumerate(g) if keep_list[lo + i]]
+            expect.append(
+                (b"".join(frame_record(i, v) for i, v in enumerate(kept)), len(kept))
+            )
+        check(
+            "frame_many_gather_ptrs bytes",
+            parts == expect and total == sum(len(p) for p, _ in expect),
+        )
+        bad = list(offs)
+        bad[-1] = len(bufs[1])  # a span that ends past ITS buffer
+        check("frame_many_gather_ptrs bounds", gather_ptrs(bad)[0] == -1)
+
     print(("PASS" if failures == 0 else f"FAIL ({failures})"))
     return 1 if failures else 0
 

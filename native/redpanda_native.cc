@@ -402,6 +402,37 @@ int64_t rp_frame_many_gather(const uint8_t* src, const int64_t* offsets,
   return total;
 }
 
+// The pointer-table twin of rp_frame_many_gather: range r's records take
+// their (offset, len) RELATIVE to their own source buffer srcs[r] (one
+// range = one input batch = one retained decompressed payload buffer, the
+// payload staging lane's PtrExploded), so kept values frame straight from
+// those buffers and no joined blob is ever built. Every record of every
+// range is bounds-checked against src_lens[r] BEFORE anything is read or
+// written; returns -1 on a span outside its buffer, else total bytes.
+int64_t rp_frame_many_gather_ptrs(const uint8_t* const* srcs,
+                                  const int64_t* src_lens,
+                                  const int64_t* offsets, const int32_t* lens,
+                                  const uint8_t* keep, const int64_t* starts,
+                                  const int64_t* ends, int64_t n_ranges,
+                                  uint8_t* dst, int64_t* out_off,
+                                  int64_t* out_len, int32_t* out_kept) {
+  for (int64_t r = 0; r < n_ranges; r++) {
+    for (int64_t i = starts[r]; i < ends[r]; i++) {
+      int64_t vlen = lens[i] < 0 ? 0 : lens[i];
+      if (offsets[i] < 0 || offsets[i] + vlen > src_lens[r]) return -1;
+    }
+  }
+  int64_t total = 0;
+  for (int64_t r = 0; r < n_ranges; r++) {
+    int64_t s = starts[r];
+    out_off[r] = total;
+    out_len[r] = rp_frame_gather(srcs[r], offsets + s, lens + s, keep + s,
+                                 ends[r] - s, dst + total, out_kept + r);
+    total += out_len[r];
+  }
+  return total;
+}
+
 // ---------------------------------------------------------------- columnar
 // JSON field extraction for the columnar pushdown path (coproc engine v2).
 // The device link charges per byte (tools/link_probe.py measures it), so

@@ -254,6 +254,14 @@ class PtrExploded:
     sizes: np.ndarray  # int32 [N] launch-wide, clamped >= 0
     ranges: list[tuple[int, int]]  # per input batch: [start, end) in N
 
+    @property
+    def offsets(self) -> np.ndarray:
+        """int64 [N] launch-wide, each still relative to its own payload:
+        the (offset, len) columns frame_ranges_gather_ptrs frames from."""
+        if not self.rel_off:
+            return np.zeros(0, np.int64)
+        return np.concatenate(self.rel_off)
+
 
 def explode_ptrs(batches: list[RecordBatch]) -> PtrExploded | None:
     """Explode a batch list WITHOUT building the joined blob. Returns
@@ -403,6 +411,28 @@ def frame_records(rows: np.ndarray, lens: np.ndarray, keep: np.ndarray) -> tuple
     return bytes(out), seq
 
 
+def _range_cols(ranges: list[tuple[int, int]]):
+    """(starts, ends) int64 columns of a launch's record ranges."""
+    starts = np.fromiter((s for s, _ in ranges), np.int64, len(ranges))
+    ends = np.fromiter((e for _, e in ranges), np.int64, len(ranges))
+    return starts, ends
+
+
+def _slice_framed(dst, off, ln, kept, scratch, arena: Arena | None):
+    """[(payload, kept)] per range out of a native framer's dst, which
+    goes back to the arena (with the undersized scratch the binding
+    replaced, if it did: that can still serve a smaller launch)."""
+    parts = [
+        (dst[off[i] : off[i] + ln[i]].tobytes(), int(kept[i]))
+        for i in range(len(off))
+    ]
+    if arena is not None:
+        arena.release(dst)
+        if dst is not scratch:
+            arena.release(scratch)
+    return parts
+
+
 def frame_ranges(
     rows: np.ndarray,
     lens: np.ndarray,
@@ -422,24 +452,13 @@ def frame_ranges(
         return []
     lib = _native()
     if lib is not None and getattr(lib, "has_frame_many", False):
-        starts = np.fromiter((s for s, _ in ranges), np.int64, len(ranges))
-        ends = np.fromiter((e for _, e in ranges), np.int64, len(ranges))
+        starts, ends = _range_cols(ranges)
         n, stride = rows.shape
         scratch = arena.acquire(n * (stride + 16) + 16) if arena else None
         dst, off, ln, kept = lib.frame_many(
             rows, lens, keep, starts, ends, out=scratch
         )
-        parts = [
-            (dst[off[i] : off[i] + ln[i]].tobytes(), int(kept[i]))
-            for i in range(len(ranges))
-        ]
-        if arena is not None:
-            arena.release(dst)
-            if dst is not scratch:
-                # the binding replaced an undersized scratch; keep the old
-                # buffer too — it can still serve a smaller launch
-                arena.release(scratch)
-        return parts
+        return _slice_framed(dst, off, ln, kept, scratch, arena)
     return [frame_records(rows[s:e], lens[s:e], keep[s:e]) for s, e in ranges]
 
 
@@ -489,8 +508,7 @@ def frame_ranges_gather(
         return []
     lib = _native()
     if lib is not None and getattr(lib, "has_frame_many_gather", False):
-        starts = np.fromiter((s for s, _ in ranges), np.int64, len(ranges))
-        ends = np.fromiter((e for _, e in ranges), np.int64, len(ranges))
+        starts, ends = _range_cols(ranges)
         n = len(offsets)
         scratch = (
             arena.acquire(int(np.maximum(lens, 0).sum()) + 16 * n + 16)
@@ -500,18 +518,62 @@ def frame_ranges_gather(
         dst, off, ln, kept = lib.frame_many_gather(
             src, offsets, lens, keep, starts, ends, out=scratch
         )
-        parts = [
-            (dst[off[i] : off[i] + ln[i]].tobytes(), int(kept[i]))
-            for i in range(len(ranges))
-        ]
-        if arena is not None:
-            arena.release(dst)
-            if dst is not scratch:
-                arena.release(scratch)
-        return parts
+        return _slice_framed(dst, off, ln, kept, scratch, arena)
     return [
         _frame_gather_py(src, offsets, lens, keep, s, e) for s, e in ranges
     ]
+
+
+def frame_ranges_gather_ptrs(
+    payloads: list[bytes],
+    offsets: np.ndarray,
+    lens: np.ndarray,
+    keep: np.ndarray,
+    ranges: list[tuple[int, int]],
+    arena: Arena | None = None,
+) -> list[tuple[bytes, int]]:
+    """frame_ranges_gather over a pointer table
+    (rp_frame_many_gather_ptrs): range r is one input batch, and its
+    records' (offset, len) are relative to that batch's own retained
+    payload buffer ``payloads[r]`` (PtrExploded) — a filter-only payload
+    launch frames its kept values from the bytes the pack stage just read,
+    with no joined blob and no result matrix. Byte-identical to
+    ``frame_ranges_gather`` over the joined payloads."""
+    if not ranges:
+        return []
+    lib = _native()
+    if lib is not None and getattr(lib, "has_frame_many_gather_ptrs", False):
+        starts, ends = _range_cols(ranges)
+        n = len(offsets)
+        scratch = (
+            arena.acquire(int(np.maximum(lens, 0).sum()) + 16 * n + 16)
+            if arena
+            else None
+        )
+        dst, off, ln, kept = lib.frame_many_gather_ptrs(
+            payloads, offsets, lens, keep, starts, ends, out=scratch
+        )
+        return _slice_framed(dst, off, ln, kept, scratch, arena)
+    return [
+        _frame_gather_py(payloads[r], offsets, lens, keep, s, e)
+        for r, (s, e) in enumerate(ranges)
+    ]
+
+
+def frame_exploded_gather(
+    ex, keep: np.ndarray, ranges: list[tuple[int, int]],
+    arena: Arena | None = None,
+) -> list[tuple[bytes, int]]:
+    """Gather-frame a launch's kept records from whichever exploded table
+    it holds: per-batch payload buffers (PtrExploded) or the joined blob
+    (ExplodedBatches). Same bytes either way."""
+    if isinstance(ex, PtrExploded):
+        return frame_ranges_gather_ptrs(
+            ex.payloads, ex.offsets, ex.sizes, keep, ranges, arena=arena
+        )
+    return frame_ranges_gather(
+        ex.joined, ex.offsets, ex.sizes, keep, ranges, arena=arena
+    )
 
 
 def build_output_batch(

@@ -44,6 +44,7 @@ from redpanda_tpu.ops.transforms import (
     TransformSpec,
     _MapProject,
     _MapUppercase,
+    packbits as _packbits,
     project_out_width,
 )
 
@@ -522,8 +523,15 @@ class ColumnarPlan:
 class PayloadPlan:
     spec: TransformSpec
     mode = "payload"
-    # device-transformed rows: never a view into the input blob
-    byte_identity = False
+
+    @property
+    def byte_identity(self) -> bool:
+        """True for a pure raw-byte filter: the mapper is the identity, so
+        a kept record's output IS its input value and the device has only
+        one keep bit a row to send back (the engine then frames kept values
+        from the bytes the host still holds). A projection or uppercase
+        builds new bytes on the device and keeps the result matrix."""
+        return bool(self.spec.filters) and self.spec.mapper is None
 
 
 @dataclass
@@ -611,15 +619,6 @@ def _collect_dev_cols(expr) -> list[DevCol]:
     if expr is not None:
         walk(expr)
     return list(cols.values())
-
-
-def _packbits(jnp, keep):
-    """bool [n] -> uint8 [n/8], big-endian bit order (numpy unpackbits)."""
-    n = keep.shape[0]
-    assert n % 8 == 0, "row buckets are multiples of 8"
-    b = keep.astype(jnp.uint8).reshape(n // 8, 8)
-    weights = jnp.array([128, 64, 32, 16, 8, 4, 2, 1], dtype=jnp.uint8)
-    return (b * weights[None, :]).sum(axis=1).astype(jnp.uint8)
 
 
 def _prepare_cmp_consts(expr) -> dict[int, tuple]:

@@ -211,7 +211,9 @@ class _Launch:
     ``materialize()`` yields (out_rows, out_len, keep) host arrays with one
     row per input record; mode decides where they come from:
 
-    - payload: the fetched packed device result (full transformed rows).
+    - payload: the fetched packed device result (full transformed rows);
+      a filter-only plan fetches only the keep mask and never materializes
+      (``framed()`` gathers kept values from the retained exploded table).
     - columnar: keep = device mask bits & host projection-ok; rows are
       host-assembled projection columns (or packed input values for
       passthrough specs).
@@ -308,19 +310,22 @@ class _Launch:
     def _payload_host_fallback(self) -> np.ndarray:
         """Fail closed per-launch: re-run the packed pipeline in numpy over
         the retained staged rows — the same integer program over the same
-        bytes (ops/pipeline.make_packed_pipeline_host), so output is exact
+        bytes (ops/pipeline.make_packed_pipeline_host), in the launch's own
+        result format (matrix, or mask bits), so output is exact
         and no JAX backend is needed: a process started with
         JAX_PLATFORMS=tpu has no CPU backend to fall back to. Raises when
         nothing was retained (the launch then follows ErrorPolicy, exactly
         like any unrecoverable script failure)."""
-        staged = self._staged_np  # pandalint: disable=RAC1102 -- the unlocked caller is _dispatch_payload, which runs BEFORE the launch is published to tickets (thread-local construction phase); every harvest-time caller reaches here under _Launch._lock via _materialize_locked
+        staged = self._staged_np  # pandalint: disable=RAC1102 -- the unlocked caller is _dispatch_payload, which runs BEFORE the launch is published to tickets (thread-local construction phase); every harvest-time caller reaches here under _Launch._lock via _materialize_locked / _gather_view
         eng = self.engine
         if staged is None or eng is None:
             raise RuntimeError(
                 "payload host fallback impossible: staged rows not retained"
             )
-        spec = eng._handles[self.script_id].spec
-        packed = make_packed_pipeline_host(spec, eng._row_stride)(staged)
+        plan = self._plan
+        packed = make_packed_pipeline_host(
+            plan.spec, eng._row_stride, eng._mask_result(plan)
+        )(staged)
         eng._count_fallback(self.n)
         return packed
 
@@ -438,9 +443,12 @@ class _Launch:
 
     def _mask_host_fallback(self, slot) -> np.ndarray:
         """Exact numpy re-evaluation of the predicate over the retained
-        extracted columns (same expression tree, same column bytes).
+        extracted columns (same expression tree, same column bytes) — or,
+        for a payload launch's mask, over its retained staged rows.
         Raises when nothing was retained — the launch then follows the
         script's ErrorPolicy like any unrecoverable failure."""
+        if self.mode == "payload":
+            return self._payload_host_fallback()
         cols = slot._cols
         if cols is None:
             raise RuntimeError(
@@ -531,10 +539,12 @@ class _Launch:
         threads (the pacemaker harvests via run_in_executor).
 
         Byte-identity transforms take the ZERO-COPY gather path: kept
-        records frame straight from the joined blob via the (offset, len)
-        columns the explode stage already produced — the padded row matrix
-        the padded path packs just to copy from never exists. Output is
-        bit-identical either way (the gather parity suite pins it)."""
+        records frame straight from the joined blob (or, on the payload
+        lane's pointer-table road, from the per-batch payload buffers) via
+        the (offset, len) columns the explode stage already produced — the
+        padded row matrix the padded path packs (or fetches) just to copy
+        from never exists. Output is bit-identical either way (the gather
+        parity suite pins it)."""
         with self._lock:
             if self._framed is None:
                 if self._shards is not None:
@@ -545,9 +555,8 @@ class _Launch:
                     if gv is not None:
                         ex, keep = gv
                         t0 = _stage_t0("t_frame_gather")
-                        self._framed = batch_codec.frame_ranges_gather(
-                            ex.joined, ex.offsets, ex.sizes, keep,
-                            self.ranges, arena=arena,
+                        self._framed = batch_codec.frame_exploded_gather(
+                            ex, keep, self.ranges, arena=arena
                         )
                         self._stat("t_frame_gather", t0)
                         self._count_frame("n_frame_gather")
@@ -565,9 +574,11 @@ class _Launch:
 
     def _gather_view(self):
         """(exploded, keep) when this launch's output bytes are an
-        (offset, len) view into the joined blob — byte-identity plans
-        (columnar passthrough, host identity) with the exploded table
-        still in hand; None sends the launch down the padded path.
+        (offset, len) view into bytes the host holds — byte-identity plans
+        (columnar passthrough, host identity, filter-only payload) with
+        the exploded table still in hand, which a payload launch retains
+        only when its device result is the keep mask; None sends the
+        launch down the padded path.
 
         The resolved view is CACHED (like _materialize_locked's _mat):
         _resolve_keep consumes the mask slot, so an uncached re-entry
@@ -591,7 +602,10 @@ class _Launch:
             # _mat_host's `ex.sizes > 0`)
             keep = ex.sizes > 0
         else:
-            return None
+            # payload mask launch: empty and null values are dropped by
+            # the device's keep (lengths > 0), oversize ones by fits
+            keep = self._resolve_keep(self, self.n) & self.fits
+            self._staged_np = None
         self._gather_mat = (ex, keep)
         return self._gather_mat
 
@@ -607,7 +621,7 @@ class _Launch:
         eng.governor.record_mode(
             governor.HARVEST_PATH,
             mode,
-            "byte-identity plan framed zero-copy from the joined blob"
+            "byte-identity plan framed zero-copy from the bytes the host holds"
             if mode == "gather"
             else "byte-mutating plan framed via the padded row matrix",
             {"script_id": self.script_id, "mode": self.mode},
@@ -1393,7 +1407,7 @@ class TpuEngine:
                     plan = PayloadPlan(spec)
                 if plan.mode == "payload":
                     self._pipelines[script_id] = make_packed_pipeline(
-                        spec, self._row_stride
+                        spec, self._row_stride, self._mask_result(plan)
                     )
                 self._plans[script_id] = plan
             except Exception as exc:
@@ -2161,7 +2175,7 @@ class TpuEngine:
                     self._stat_add("n_launches", 1)
                     with self._stats_lock:
                         probes.coproc_launch_rows_hist.record(n)
-                    self._dispatch_payload_ptrs(launch, pe, n)
+                    self._dispatch_payload(launch, pe, n)
                     return
             exploded = batch_codec.explode_batches(all_batches)
             self._stat_stage("t_explode", t0)
@@ -2833,15 +2847,8 @@ class TpuEngine:
                             "bytes_h2d", sum(c.nbytes for c in cols)
                         )
                     self._stat_add("bytes_d2h", n_pad // 8)
-                    slot._mask_dev = mask
                     slot._cols = cols
-                    slot._mask_event = threading.Event()
-                    slot._mask_state = "queued"
-                    with launch._lock:
-                        launch._pending_slots.append(slot)
-                    self._ensure_harvester()
-                    slot._enq_t = time.perf_counter()
-                    self._harvest_q.put(slot)
+                    self._enqueue_mask(slot, mask, shard_of=launch)
             shard.mask = slot
         if store_entry is not None:
             # put AFTER the dispatch leg so a populated entry carries its
@@ -3115,30 +3122,26 @@ class TpuEngine:
         return shard, cols
 
     def _dispatch_payload(self, launch: _Launch, exploded, n: int) -> None:
+        """Stage and launch one payload plan over an exploded table: the
+        classic joined-blob table, or the pointer table
+        (batch_codec.PtrExploded), whose staging packs each batch's records
+        straight from its retained decompressed payload buffer —
+        byte-identical staged rows, one fewer full copy of the launch's
+        record bytes. A launch whose result is the keep mask retains the
+        table: its kept values are framed from it."""
         fn, r_out = self._pipelines[launch.script_id]
         launch.r_out = r_out
         launch.fits = exploded.sizes <= self._row_stride
+        if self._mask_result(launch._plan):
+            launch._exploded = exploded
         if n == 0:
             return
         t0 = _stage_t0("t_pack")
         n_pad = _bucket_rows(n)
-        staged = self._pack_staged(exploded, n_pad)
-        self._stat_stage("t_pack", t0)
-        self._launch_payload(launch, staged, n_pad, fn, r_out)
-
-    def _dispatch_payload_ptrs(self, launch: _Launch, pe, n: int) -> None:
-        """The pointer-table twin of _dispatch_payload: staging packs
-        each batch's records straight from its retained decompressed
-        payload buffer (batch_codec.PtrExploded) — byte-identical staged
-        rows, one fewer full copy of the launch's record bytes."""
-        fn, r_out = self._pipelines[launch.script_id]
-        launch.r_out = r_out
-        launch.fits = pe.sizes <= self._row_stride
-        if n == 0:
-            return
-        t0 = _stage_t0("t_pack")
-        n_pad = _bucket_rows(n)
-        staged = self._pack_staged_ptrs(pe, n_pad)
+        if isinstance(exploded, batch_codec.PtrExploded):
+            staged = self._pack_staged_ptrs(exploded, n_pad)
+        else:
+            staged = self._pack_staged(exploded, n_pad)
         self._stat_stage("t_pack", t0)
         self._launch_payload(launch, staged, n_pad, fn, r_out)
 
@@ -3148,11 +3151,15 @@ class TpuEngine:
         """Issue one payload-plan device launch over a built staging
         matrix (breaker gate, fault envelope, exact host fallback) —
         shared by the classic joined-blob and pointer-table staging
-        lanes."""
+        lanes. The result format follows the plan (_mask_result): the
+        packed result matrix, fetched at harvest by _mat_payload, or the
+        bit-packed keep mask, which rides the mask harvester and
+        _resolve_keep like a columnar predicate's."""
         import jax
 
-        # retained until the packed result lands: the host fallback re-runs
-        # the pipeline on the CPU backend over exactly these rows
+        mask_result = self._mask_result(launch._plan)
+        # retained until the result lands: the host fallback re-runs the
+        # pipeline in numpy over exactly these rows
         launch._staged_np = staged
         # what the lane adds to a launch: the rows it pads the bucket with,
         # and the values it drops for exceeding the staging row
@@ -3161,10 +3168,6 @@ class TpuEngine:
         if n_oversize:
             self._stat_add("n_oversize_rows", float(n_oversize))
         t0 = _stage_t0("t_dispatch")
-        if not self._breaker.allow_device():
-            launch._packed_dev = launch._payload_host_fallback()
-            self._stat_stage("t_dispatch", t0)
-            return
 
         def leg():
             faults.inject(faults.DEVICE_DISPATCH)
@@ -3176,12 +3179,21 @@ class TpuEngine:
             packed.copy_to_host_async()
             return packed
 
-        packed = self._try_device_leg(
-            faults.DEVICE_DISPATCH, leg,
-            program=(launch.script_id, "payload", n_pad), fn=fn,
-        )
+        packed = None
+        if self._breaker.allow_device():
+            packed = self._try_device_leg(
+                faults.DEVICE_DISPATCH, leg,
+                program=(launch.script_id, "payload", n_pad), fn=fn,
+            )
         if packed is None:
-            launch._packed_dev = launch._payload_host_fallback()
+            # open breaker or exhausted retries: the exact host result, in
+            # the slot its harvest reads as already on the host
+            packed = launch._payload_host_fallback()
+            if mask_result:
+                launch._mask_np = packed
+                launch._staged_np = None
+            else:
+                launch._packed_dev = packed
             self._stat_stage("t_dispatch", t0)
             return
         # dispatch success IS the dispatch-domain verdict (the device
@@ -3190,8 +3202,33 @@ class TpuEngine:
         self._breaker.record_success()
         self._stat_stage("t_dispatch", t0)
         self._stat_add("bytes_h2d", staged.nbytes)
-        self._stat_add("bytes_d2h", n_pad * (r_out + 8))
-        launch._packed_dev = packed
+        if mask_result:
+            self._stat_add("bytes_d2h", n_pad // 8)
+            self._enqueue_mask(launch, packed)
+        else:
+            self._stat_add("bytes_d2h", n_pad * (r_out + 8))
+            launch._packed_dev = packed
+
+    def _mask_result(self, plan) -> bool:
+        """Whether a payload plan's device result is the keep mask alone:
+        its mapper is the identity (read from the spec), and the gather
+        harvest that frames from host bytes is on (``gather_frame=False``
+        keeps the matrix road for everything)."""
+        return self._gather_frame and plan.byte_identity
+
+    def _enqueue_mask(self, slot, mask, shard_of: _Launch | None = None) -> None:
+        """Hand a dispatched device mask (a launch's, or a shard slot's of
+        the launch ``shard_of``) to the harvester thread, which pays its
+        D2H round trip while the caller keeps doing host work."""
+        slot._mask_dev = mask
+        slot._mask_event = threading.Event()
+        slot._mask_state = "queued"
+        if shard_of is not None:
+            with shard_of._lock:
+                shard_of._pending_slots.append(slot)
+        self._ensure_harvester()
+        slot._enq_t = time.perf_counter()
+        self._harvest_q.put(slot)
 
     def _dispatch_predicate(
         self, launch: _Launch, plan: ColumnarPlan, cols, n: int, n_pad: int,
@@ -3285,13 +3322,8 @@ class TpuEngine:
                 if dev_cols is None:
                     self._stat_add("bytes_h2d", sum(c.nbytes for c in cols))
                 self._stat_add("bytes_d2h", n_pad // 8)
-                launch._mask_dev = mask
                 launch._cols = cols
-                launch._mask_event = threading.Event()
-                launch._mask_state = "queued"
-                self._ensure_harvester()
-                launch._enq_t = time.perf_counter()
-                self._harvest_q.put(launch)
+                self._enqueue_mask(launch, mask)
 
     def _dispatch_columnar(
         self, launch: _Launch, plan: ColumnarPlan, exploded, n: int,

@@ -55,6 +55,20 @@ def _take_scratch(out: np.ndarray | None, cap: int) -> np.ndarray:
     return np.empty(max(cap, 1), dtype=np.uint8)
 
 
+def _check_ranges(starts, ends, n: int, what: str) -> None:
+    """The framers' C walks are unchecked: out-of-bounds or overlapping
+    [start, end) ranges must be a ValueError here, not a heap write."""
+    if len(ends) != len(starts):
+        raise ValueError("starts/ends length mismatch")
+    if len(starts) and (
+        (starts > ends).any()
+        or starts.min() < 0
+        or ends.max() > n
+        or int((ends - starts).sum()) > n
+    ):
+        raise ValueError(f"{what} ranges out of bounds or overlapping")
+
+
 def _check_gather_cols(src_arr, offsets, lens, n: int) -> None:
     """Every (offset, len) span must lie inside src — the C gather memcpys
     unchecked."""
@@ -199,6 +213,17 @@ class _NativeLib:
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p,
+            ]
+        self.has_frame_many_gather_ptrs = hasattr(
+            dll, "rp_frame_many_gather_ptrs"
+        )
+        if self.has_frame_many_gather_ptrs:
+            dll.rp_frame_many_gather_ptrs.restype = ctypes.c_int64
+            dll.rp_frame_many_gather_ptrs.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ]
         dll.rp_json_find.restype = ctypes.c_int32
         dll.rp_json_find.argtypes = [
@@ -386,17 +411,7 @@ class _NativeLib:
         ends = np.ascontiguousarray(ends, dtype=np.int64)
         n, stride = rows.shape
         n_ranges = len(starts)
-        # guard the unchecked C walk: out-of-bounds or overlapping ranges
-        # must be a ValueError here, not a heap write past dst
-        if len(ends) != n_ranges:
-            raise ValueError("starts/ends length mismatch")
-        if n_ranges and (
-            (starts > ends).any()
-            or starts.min() < 0
-            or ends.max() > n
-            or int((ends - starts).sum()) > n
-        ):
-            raise ValueError("frame_many ranges out of bounds or overlapping")
+        _check_ranges(starts, ends, n, "frame_many")
         dst = _take_scratch(out, n * (stride + 16) + 16)
         out_off = np.empty(n_ranges, dtype=np.int64)
         out_len = np.empty(n_ranges, dtype=np.int64)
@@ -460,20 +475,9 @@ class _NativeLib:
         ends = np.ascontiguousarray(ends, dtype=np.int64)
         n = len(offsets)
         n_ranges = len(starts)
-        # same posture as frame_many: the C walk is unchecked, so malformed
-        # ranges or out-of-blob (offset, len) spans must be a ValueError
-        # here, not a heap read/write
-        if len(ends) != n_ranges:
-            raise ValueError("starts/ends length mismatch")
-        if n_ranges and (
-            (starts > ends).any()
-            or starts.min() < 0
-            or ends.max() > n
-            or int((ends - starts).sum()) > n
-        ):
-            raise ValueError(
-                "frame_many_gather ranges out of bounds or overlapping"
-            )
+        # malformed ranges or out-of-blob (offset, len) spans must be a
+        # ValueError here, not a heap read/write
+        _check_ranges(starts, ends, n, "frame_many_gather")
         src_arr = np.frombuffer(src, dtype=np.uint8)
         _check_gather_cols(src_arr, offsets, lens, n)
         cap = _gather_dst_cap(lens, n)
@@ -487,6 +491,50 @@ class _NativeLib:
             n_ranges, dst.ctypes.data,
             out_off.ctypes.data, out_len.ctypes.data, out_kept.ctypes.data,
         )
+        return dst, out_off, out_len, out_kept
+
+    def frame_many_gather_ptrs(
+        self,
+        srcs: list[bytes],
+        offsets: np.ndarray,
+        lens: np.ndarray,
+        keep: np.ndarray,
+        starts: np.ndarray,
+        ends: np.ndarray,
+        out: np.ndarray | None = None,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """frame_many_gather over a POINTER TABLE: range r's records frame
+        from their own buffer ``srcs[r]``, their (offset, len) relative to
+        it (the payload staging lane's per-batch payload buffers), so no
+        joined blob is needed. Same returns and the same posture: malformed
+        ranges or a span outside its buffer are a ValueError here."""
+        offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+        lens = np.ascontiguousarray(lens, dtype=np.int32)
+        keep = np.ascontiguousarray(keep, dtype=np.uint8)
+        starts = np.ascontiguousarray(starts, dtype=np.int64)
+        ends = np.ascontiguousarray(ends, dtype=np.int64)
+        n = len(offsets)
+        n_ranges = len(starts)
+        if len(srcs) != n_ranges:
+            raise ValueError("srcs/ranges length mismatch")
+        if len(lens) != n or len(keep) != n:
+            raise ValueError("offsets/lens/keep length mismatch")
+        _check_ranges(starts, ends, n, "frame_many_gather_ptrs")
+        # bytes -> borrowed char*; the ctypes array retains the objects
+        ptrs = (ctypes.c_char_p * n_ranges)(*srcs)
+        src_lens = np.fromiter((len(b) for b in srcs), np.int64, n_ranges)
+        dst = _take_scratch(out, _gather_dst_cap(lens, n))
+        out_off = np.empty(n_ranges, dtype=np.int64)
+        out_len = np.empty(n_ranges, dtype=np.int64)
+        out_kept = np.empty(n_ranges, dtype=np.int32)
+        total = self._dll.rp_frame_many_gather_ptrs(
+            ptrs, src_lens.ctypes.data, offsets.ctypes.data,
+            lens.ctypes.data, keep.ctypes.data, starts.ctypes.data,
+            ends.ctypes.data, n_ranges, dst.ctypes.data,
+            out_off.ctypes.data, out_len.ctypes.data, out_kept.ctypes.data,
+        )
+        if total < 0:
+            raise ValueError("gather (offset, len) span outside its buffer")
         return dst, out_off, out_len, out_kept
 
     def parse_many(

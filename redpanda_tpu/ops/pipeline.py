@@ -12,7 +12,9 @@ Two device programs cover the engine's steady-state loop (SURVEY §3.4):
    result out. The link between the broker runtime and the device charges
    per *transfer* as well as per byte, so lengths ride in trailing
    metadata columns of the input array and (out_len, keep) ride in trailing
-   columns of the output — exactly one H2D and one D2H per launch.
+   columns of the output — exactly one H2D and one D2H per launch. A
+   pure filter (``mask_only``) maps nothing, so its result is one keep bit
+   a row and the host frames kept values from the bytes it already holds.
 
 The transform output is deliberately CRC-free: output batches are sealed
 host-side after framing + optional compression (the Kafka CRC covers the
@@ -39,6 +41,7 @@ from redpanda_tpu.ops.transforms import (
     TransformSpec,
     compile_transform,
     compile_transform_host,
+    packbits,
     transform_out_width,
 )
 
@@ -63,11 +66,14 @@ def make_batch_validator(r: int):
     return rp_batch_validate
 
 
-def _packed_body(xp, tfn, r_in: int, scope=contextlib.nullcontext):
+def _packed_body(
+    xp, tfn, r_in: int, scope=contextlib.nullcontext, mask_only: bool = False
+):
     """staged -> packed around a compiled transform, over namespace ``xp``
     (jax.numpy on the device, numpy for the engine's host fallback).
     ``scope``: ``jax.named_scope`` for the device program, whose name
-    (``jit_rp_payload_transform``) is this function's."""
+    (``jit_rp_payload_transform``) is this function's. ``mask_only``: the
+    result is the keep mask alone, bit-packed (uint8 [N/8])."""
 
     def rp_payload_transform(staged):
         with scope("parse"):
@@ -77,6 +83,8 @@ def _packed_body(xp, tfn, r_in: int, scope=contextlib.nullcontext):
         with scope("transform"):
             out, out_len, keep = tfn(data, lens)
         with scope("frame"):
+            if mask_only:
+                return packbits(xp, keep)
             masked = xp.where(keep, out_len, 0).astype(xp.int32)
             lenb = xp.stack(
                 [((masked >> (8 * k)) & 0xFF).astype(xp.uint8) for k in range(4)],
@@ -90,24 +98,29 @@ def _packed_body(xp, tfn, r_in: int, scope=contextlib.nullcontext):
 
 
 @functools.lru_cache(maxsize=64)
-def _packed_pipeline_cached(spec_json: str, r_in: int):
+def _packed_pipeline_cached(spec_json: str, r_in: int, mask_only: bool):
     spec = TransformSpec.from_json(spec_json)
     tfn = compile_transform(spec, r_in)
     r_out = transform_out_width(spec, r_in)
-    return jax.jit(_packed_body(jnp, tfn, r_in, scope=jax.named_scope)), r_out
+    body = _packed_body(jnp, tfn, r_in, jax.named_scope, mask_only)
+    return jax.jit(body), r_out
 
 
-def make_packed_pipeline(spec: TransformSpec, r_in: int):
-    """fn(staged uint8 [N, r_in+IN_META]) -> packed uint8 [N, r_out+OUT_META]."""
-    return _packed_pipeline_cached(spec.to_json(), int(r_in))
+def make_packed_pipeline(spec: TransformSpec, r_in: int, mask_only: bool = False):
+    """fn(staged uint8 [N, r_in+IN_META]) -> packed uint8 [N, r_out+OUT_META],
+    or the bit-packed keep mask uint8 [N/8] with ``mask_only``."""
+    return _packed_pipeline_cached(spec.to_json(), int(r_in), bool(mask_only))
 
 
-def make_packed_pipeline_host(spec: TransformSpec, r_in: int):
+def make_packed_pipeline_host(
+    spec: TransformSpec, r_in: int, mask_only: bool = False
+):
     """make_packed_pipeline's numpy twin (same bytes out, no JAX backend):
     the engine's payload-lane host fallback."""
     import numpy as np
 
-    return _packed_body(np, compile_transform_host(spec, int(r_in)), int(r_in))
+    tfn = compile_transform_host(spec, int(r_in))
+    return _packed_body(np, tfn, int(r_in), mask_only=mask_only)
 
 
 @functools.lru_cache(maxsize=64)

@@ -373,6 +373,16 @@ def _validated(spec_json: str, r_in: int):
     return spec, r_out
 
 
+def packbits(xp, keep):
+    """bool [n] -> uint8 [n/8], big-endian bit order (numpy unpackbits):
+    the one bit packing of every keep mask that crosses the link."""
+    n = keep.shape[0]
+    assert n % 8 == 0, "row buckets are multiples of 8"
+    b = keep.astype(xp.uint8).reshape(n // 8, 8)
+    weights = xp.array([128, 64, 32, 16, 8, 4, 2, 1], dtype=xp.uint8)
+    return (b * weights[None, :]).sum(axis=1).astype(xp.uint8)
+
+
 def _transform_body(xp, spec: TransformSpec, r_out: int):
     """The transform as array code over namespace ``xp``: jax.numpy for the
     device program, numpy for the engine's host fallback. Every operation

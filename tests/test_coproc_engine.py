@@ -411,3 +411,165 @@ def test_stats_name_the_device_and_count_device_launches():
     assert host.get("n_device_launches", 0) == 0
     assert host["device_launches_by_script"] == {}
     assert host["device"] is None
+
+
+# ------------------------------------------- payload mask launches (ISSUE 27)
+def _mask_engine(**kw):
+    from redpanda_tpu.ops.transforms import filter_contains
+
+    engine = TpuEngine(
+        row_stride=256, compress_threshold=10**9, host_workers=0,
+        retry_backoff_ms=1, **kw,
+    )
+    _deploy(engine, spec=filter_contains(b'"level":"error"'))
+    return engine
+
+
+def _mask_req():
+    return ProcessBatchRequest([
+        ProcessBatchItem(
+            1, NTP.kafka("orders", p),
+            [_json_batch(11, base_offset=20 * p), _json_batch(0), _json_batch(6)],
+        )
+        for p in range(3)
+    ])
+
+
+def _bits(reply):
+    return [
+        (it.source, [(b.payload, b.header.crc, b.header.record_count) for b in it.batches])
+        for it in reply.items
+    ]
+
+
+_MASK_FAULTS = {
+    # name -> (engine kwargs, armed probe | None, trip the HARVEST breaker,
+    #          the harvester thread never runs)
+    "harvest_fault": (dict(launch_retries=1, breaker_threshold=100), "harvest", False, False),
+    "harvest_breaker_open": (
+        dict(breaker_threshold=1, breaker_cooldown_ms=3_600_000), None, True, False,
+    ),
+    "dispatch_fault": (
+        dict(launch_retries=0, breaker_threshold=100), "device_dispatch", False, False,
+    ),
+    "starved_harvester_dead_fetch": (
+        dict(launch_retries=0, device_deadline_ms=100, breaker_threshold=100,
+             adaptive_deadline=False),
+        "mask_fetch", False, True,
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_MASK_FAULTS))
+def test_payload_mask_launch_faults_end_in_the_exact_host_fallback(name, monkeypatch):
+    """A filter-only payload launch rides the ONE mask D2H discipline
+    (harvester, _resolve_keep, breaker, claim): a dead harvest, an open
+    harvest breaker, a dead dispatch, or a starved harvester with a dead
+    caller fetch all end in the numpy twin over the retained staged rows,
+    fallback rows counted, output unchanged."""
+    from redpanda_tpu.coproc import faults
+    from redpanda_tpu.finjector import honey_badger
+
+    kw, probe, trip, starve = _MASK_FAULTS[name]
+    clean = _mask_engine()
+    baseline = _bits(clean.process_batch(_mask_req()))
+    assert clean.stats()["n_frame_gather"] == 1
+    clean.shutdown()
+    assert any(batches for _, batches in baseline)
+
+    engine = _mask_engine(**kw)
+    if trip:
+        engine.governor.breaker_for(faults.HARVEST).record_failure()
+    if starve:
+        monkeypatch.setattr(engine, "_ensure_harvester", lambda: None)
+    honey_badger.enable()
+    if probe:
+        honey_badger.set_exception(faults.MODULE, probe)
+    try:
+        faulted = _bits(engine.process_batch(_mask_req()))
+        stats = engine.stats()
+    finally:
+        if probe:
+            honey_badger.unset(faults.MODULE, probe)
+        honey_badger.disable()
+        engine.shutdown()
+    assert faulted == baseline
+    assert stats["n_fallback_rows"] == 51  # every record of the one launch
+    assert stats["n_frame_gather"] == 1 and "n_frame_padded" not in stats
+    if name == "harvest_fault":
+        # one failed mask is ONE verdict and one envelope: the harvester's
+        assert stats["breakers"]["harvest"]["consecutive_failures"] == 1
+        assert stats["n_retries"] == 1
+    if name == "harvest_breaker_open":
+        assert stats.get("n_retries", 0) == 0
+        assert stats["breakers"]["device_dispatch"]["state"] == faults.STATE_CLOSED
+    if name == "dispatch_fault":
+        assert stats.get("n_device_launches", 0) == 0
+
+
+def test_payload_mask_reentry_after_framing_failure_keeps_the_mask(monkeypatch):
+    """_resolve_keep consumes the launch's mask slot; a second framed()
+    after a framing failure must reuse the resolved keep, not read the
+    emptied slot as keep-all."""
+    from redpanda_tpu.coproc import batch_codec
+
+    engine = _mask_engine()
+    expected = _bits(engine.process_batch(_mask_req()))
+    name = (
+        "frame_ranges_gather_ptrs"
+        if batch_codec.explode_ptrs([_json_batch(1)]) is not None
+        else "frame_ranges_gather"
+    )
+    real = getattr(batch_codec, name)
+    calls = {"n": 0}
+
+    def flaky(*a, **kw):
+        calls["n"] += 1
+        if calls["n"] == 1:
+            raise MemoryError("simulated framing allocation failure")
+        return real(*a, **kw)
+
+    monkeypatch.setattr(batch_codec, name, flaky)
+    ticket = engine.submit(_mask_req())
+    first = ticket.result()  # framing fails -> skip_on_failure empties items
+    assert all(not it.batches for it in first.items)
+    second = ticket.result()
+    engine.shutdown()
+    assert calls["n"] == 2
+    assert _bits(second) == expected
+    assert any(len(b) < 11 for _, b in expected)
+
+
+@pytest.mark.parametrize("gather", [True, False], ids=["mask", "matrix"])
+def test_payload_launch_accounting_follows_what_crosses(gather):
+    """bytes_d2h is the mask's size on a mask launch and the matrix's on
+    the matrix road; the launch, staging and compile counters count the
+    same on both; the governor journals the script's harvest path."""
+    from redpanda_tpu.coproc import governor
+
+    engine = _mask_engine(gather_frame=gather)
+    engine.process_batch(_mask_req())
+    engine.process_batch(_mask_req())
+    stats = engine.stats()
+    posture = stats["governor"]["posture"]["harvest_path"]
+    engine.shutdown()
+    assert stats["n_launches"] == stats["n_device_launches"] == 2
+    assert stats["n_compiles"] == 1
+    assert stats["n_staged_rows"] == 2 * 128 and stats["n_records"] == 2 * 51
+    assert stats["bytes_h2d"] == 2 * 128 * (256 + 8)
+    assert stats["t_fetch"] > 0
+    if gather:
+        assert stats["bytes_d2h"] == 2 * 128 // 8
+        assert stats["n_frame_gather"] == 2 and stats["t_frame_gather"] > 0
+        assert "t_rebuild" not in stats and posture == "gather"
+    else:
+        assert stats["bytes_d2h"] == 2 * 128 * (256 + 8)
+        assert stats["n_frame_padded"] == 2 and "t_frame_gather" not in stats
+        assert posture == "padded"
+    mine = [
+        e for e in governor.journal.entries(domain=governor.HARVEST_PATH)
+        if e["engine"] == engine.governor.engine_tag
+    ]
+    # journalled once a script (on change), not once a launch
+    assert [e["verdict"] for e in mine] == [posture]
+    assert mine[0]["inputs"] == {"script_id": 1, "mode": "payload"}

@@ -39,6 +39,7 @@ from redpanda_tpu.ops.transforms import (
     Int,
     Str,
     filter_contains,
+    filter_field_eq,
     identity,
     map_project,
     map_uppercase,
@@ -116,14 +117,100 @@ def test_frame_gather_matches_padded_python(name, monkeypatch):
     assert gathered == padded
 
 
+def _gather_ptrs_vs_padded(batches, use_native: bool, monkeypatch):
+    """frame_ranges_gather_ptrs over the per-batch payload buffers against
+    frame_ranges over rows packed from the same table, and against
+    frame_ranges_gather over the joined blob."""
+    pe = batch_codec.explode_ptrs(batches)
+    if pe is None:
+        pytest.skip("native packer unavailable")
+    ex = batch_codec.explode_batches(batches)
+    n = len(ex.sizes)
+    keep = (np.arange(n) % 3) != 1
+    stride = max(int(ex.sizes.max()) if n else 1, 1)
+    rows, lens = engine_mod._pack_values(ex, stride)
+    if not use_native:
+        monkeypatch.setattr(batch_codec, "_native", lambda: None)
+    padded = batch_codec.frame_ranges(rows, lens, keep, ex.ranges)
+    joined = batch_codec.frame_ranges_gather(
+        ex.joined, ex.offsets, ex.sizes, keep, ex.ranges
+    )
+    ptrs = batch_codec.frame_ranges_gather_ptrs(
+        pe.payloads, pe.offsets, pe.sizes, keep, pe.ranges
+    )
+    return padded, joined, ptrs
+
+
+@pytest.mark.parametrize("use_native", [True, False], ids=["native", "python"])
+@pytest.mark.parametrize("name", sorted(_scenarios()))
+def test_frame_gather_ptrs_matches_padded_and_joined(name, use_native, monkeypatch):
+    """The pointer-table gather (native symbol AND its Python twin) emits
+    what the padded road and the joined-blob gather emit, over compressed,
+    null-value, empty-value and zero-record batches."""
+    padded, joined, ptrs = _gather_ptrs_vs_padded(
+        _scenarios()[name], use_native, monkeypatch
+    )
+    assert ptrs == padded
+    assert ptrs == joined
+
+
+def test_frame_gather_ptrs_arena_reuse_is_bit_identical():
+    pe = batch_codec.explode_ptrs(_scenarios()["plain"])
+    if pe is None:
+        pytest.skip("native packer unavailable")
+    keep = np.ones(len(pe.sizes), bool)
+    arena = batch_codec.Arena()
+    runs = [
+        batch_codec.frame_ranges_gather_ptrs(
+            pe.payloads, pe.offsets, pe.sizes, keep, pe.ranges, arena=arena
+        )
+        for _ in range(2)
+    ]
+    assert runs[0] == runs[1]
+    assert arena.stats()["reuses"] >= 1
+
+
+_BAD_PTR_SPANS = {
+    # (offsets, lens, starts, ends, n_srcs)
+    "span_past_its_buffer": ([0, 4], [3, 10], [0], [2], 1),
+    "negative_offset": ([-1, 0], [1, 1], [0], [2], 1),
+    "span_inside_the_joined_bytes_only": ([0, 7], [6, 1], [0, 1], [1, 2], 2),
+    "overlapping_ranges": ([0, 1], [1, 1], [0, 0], [2, 2], 2),
+    "range_past_the_table": ([0, 1], [1, 1], [0], [3], 1),
+    "start_after_end": ([0, 1], [1, 1], [2], [0], 1),
+    "one_source_for_two_ranges": ([0, 1], [1, 1], [0, 1], [1, 2], 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_BAD_PTR_SPANS))
+def test_frame_many_gather_ptrs_rejects_bad_spans(name):
+    """Malformed ranges and a span outside ITS OWN buffer are a ValueError
+    in the binding, never a heap read — the same posture as the joined
+    gather's."""
+    from redpanda_tpu.native import lib
+
+    if lib is None or not getattr(lib, "has_frame_many_gather_ptrs", False):
+        pytest.skip("native ptr-table gather unavailable")
+    offsets, lens, starts, ends, n_srcs = _BAD_PTR_SPANS[name]
+    with pytest.raises(ValueError):
+        lib.frame_many_gather_ptrs(
+            [b"abcdef"] * n_srcs,
+            np.array(offsets, np.int64), np.array(lens, np.int32),
+            np.ones(len(offsets), np.uint8),
+            np.array(starts, np.int64), np.array(ends, np.int64),
+        )
+
+
 def test_frame_gather_empty_ranges_both_paths(monkeypatch):
     src = b"abcdef"
     offs = np.zeros(0, np.int64)
     lens = np.zeros(0, np.int32)
     keep = np.zeros(0, bool)
     assert batch_codec.frame_ranges_gather(src, offs, lens, keep, []) == []
+    assert batch_codec.frame_ranges_gather_ptrs([], offs, lens, keep, []) == []
     monkeypatch.setattr(batch_codec, "_native", lambda: None)
     assert batch_codec.frame_ranges_gather(src, offs, lens, keep, []) == []
+    assert batch_codec.frame_ranges_gather_ptrs([], offs, lens, keep, []) == []
 
 
 def test_frame_gather_single_range_matches_frame_records():
@@ -296,7 +383,16 @@ _MATRIX = [
     ("identity", identity(), None, True),
     ("projection", _project_spec(), "columnar_host", False),
     ("uppercase", map_uppercase(), None, False),
-    ("payload", filter_contains(b"error"), None, False),
+    # a filter-only payload plan maps nothing: its launch fetches the keep
+    # mask and frames from the bytes the host holds (ISSUE 27) ...
+    ("payload", filter_contains(b"error"), None, True),
+    # ... and one that builds new bytes keeps the result matrix
+    (
+        "payload_project",
+        filter_contains(b"error") | map_project(Int("code"), Str("msg", 16)),
+        None, False,
+    ),
+    ("payload_uppercase", filter_contains(b"error") | map_uppercase(), None, False),
 ]
 
 
@@ -340,6 +436,153 @@ def test_sharded_gather_matches_inline_gather(monkeypatch):
     assert stats["n_sharded_launches"] >= 1
     assert stats.get("n_frame_gather", 0.0) >= 2.0  # one per shard
     assert _reply_bits(inline) == _reply_bits(sharded)
+
+
+# ------------------------------------------- payload mask harvest (ISSUE 27)
+_STRIDE = 256
+
+
+def _edge_value(size: int, level: str = "error") -> bytes:
+    head = b'{"level":"%s","pad":"' % level.encode()
+    return head + b"x" * (size - len(head) - 2) + b'"}'
+
+
+def _value_batches(values, per_batch=5, base=0):
+    return [
+        RecordBatch.build(
+            [
+                Record(offset_delta=i, timestamp_delta=i, value=v)
+                for i, v in enumerate(values[s : s + per_batch])
+            ],
+            base_offset=base + s, first_timestamp=1000,
+        )
+        for s in range(0, len(values), per_batch)
+    ]
+
+
+def _payload_shapes():
+    """name -> list of requests (submitted as ONE submit_group)."""
+    edge = [
+        _edge_value(_STRIDE),            # exactly the staging row: kept
+        _edge_value(_STRIDE + 1),        # one byte over: dropped, never cut
+        _edge_value(_STRIDE, "info"),    # fits, no match
+        b"", None,                       # empty and null: dropped
+        _edge_value(_STRIDE - 1),
+        _edge_value(3 * _STRIDE),
+        b'{"level":"error"}',
+        _edge_value(40, "warn"),
+        _edge_value(_STRIDE),
+        _edge_value(64),                 # 11 values: n % 8 == 3, bucket 128
+    ]
+    assert len(edge) % 8 and len(edge[0]) == _STRIDE
+    return {
+        "mixed": [_matrix_request()],
+        "stride_edges": [ProcessBatchRequest([
+            ProcessBatchItem(1, NTP.kafka("orders", 0), _value_batches(edge)),
+        ])],
+        "zero_record_launch": [ProcessBatchRequest([
+            ProcessBatchItem(
+                1, NTP.kafka("orders", 0), [_json_batch(0), _json_batch(0)]
+            ),
+        ])],
+        "submit_group": [
+            _matrix_request(n_items=2, n_recs=19),
+            ProcessBatchRequest([
+                ProcessBatchItem(1, NTP.kafka("orders", 7), _value_batches(edge)),
+            ]),
+            _matrix_request(n_items=1, n_recs=9),
+        ],
+    }
+
+
+_PAYLOAD_SPECS = {
+    # name -> (spec, the launch fetches a mask)
+    "contains": (filter_contains(b'"level":"error"'), True),
+    "contains_negate": (filter_contains(b'"level":"error"', negate=True), True),
+    "field_eq": (filter_field_eq("level", "error"), True),
+    "two_filters": (
+        filter_contains(b'"level":"error"') | filter_contains(b"m1", negate=True),
+        True,
+    ),
+    "filter_project": (
+        filter_contains(b'"level":"error"') | map_project(Int("code"), Str("msg", 16)),
+        False,
+    ),
+    "filter_uppercase": (filter_contains(b'"level":"error"') | map_uppercase(), False),
+    "project_only": (map_project(Int("code"), Str("msg", 16)), False),
+}
+
+
+def _run_group(spec, gather, reqs):
+    engine = TpuEngine(
+        row_stride=_STRIDE, compress_threshold=10**9, host_workers=0,
+        gather_frame=gather,
+    )
+    try:
+        codes = engine.enable_coprocessors([(1, spec.to_json(), ("orders",))])
+        assert codes == [EnableResponseCode.success]
+        assert engine._plans[1].mode == "payload"
+        replies = [t.result() for t in engine.submit_group(reqs)]
+        return [_reply_bits(r) for r in replies], engine.stats()
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("use_native", [True, False], ids=["native", "no_native"])
+@pytest.mark.parametrize("shape", sorted(_payload_shapes()))
+@pytest.mark.parametrize("name", sorted(_PAYLOAD_SPECS))
+def test_payload_mask_harvest_matches_matrix_road(name, shape, use_native, monkeypatch):
+    """A filter-only payload launch (mask fetched, kept values framed from
+    the pointer table, or from the joined blob without the native library)
+    gives byte for byte what the matrix road (``gather_frame=False``)
+    gives; a payload plan that builds new bytes still takes the matrix
+    road and its output does not move."""
+    spec, mask = _PAYLOAD_SPECS[name]
+    if not use_native:
+        monkeypatch.setattr(batch_codec, "_native", lambda: None)
+        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches: None)
+    reqs = _payload_shapes()[shape]
+    on, stats_on = _run_group(spec, True, reqs)
+    off, stats_off = _run_group(spec, False, reqs)
+    assert on == off
+    assert "n_frame_gather" not in stats_off
+    assert stats_on["n_launches"] == stats_off["n_launches"] == 1
+    assert stats_on.get("n_fallback_rows", 0) == 0
+    n_pad = stats_off.get("n_staged_rows", 0)
+    assert stats_on.get("n_staged_rows", 0) == n_pad
+    if mask:
+        assert stats_on["n_frame_gather"] == 1 and "n_frame_padded" not in stats_on
+        assert "t_rebuild" not in stats_on
+        # what crosses back is one bit a staged row
+        assert stats_on.get("bytes_d2h", 0) == n_pad // 8
+        assert stats_off.get("bytes_d2h", 0) == n_pad * (_STRIDE + 8)
+        if use_native and n_pad:
+            assert "t_explode_ptrs" in stats_on  # framed from the pointer table
+    else:
+        assert "n_frame_gather" not in stats_on
+        assert stats_on.get("bytes_d2h", 0) == stats_off.get("bytes_d2h", 0)
+        assert stats_on["n_frame_padded"] == 1
+
+
+def test_payload_mask_harvest_drops_what_the_lane_drops():
+    """Not only parity with the matrix road: the kept values themselves.
+    Empty, null and over-stride values are dropped, a value of exactly the
+    stride is kept whole."""
+    spec, _ = _PAYLOAD_SPECS["contains"]
+    req = _payload_shapes()["stride_edges"][0]
+    engine = TpuEngine(row_stride=_STRIDE, compress_threshold=10**9, host_workers=0)
+    try:
+        engine.enable_coprocessors([(1, spec.to_json(), ("orders",))])
+        reply = engine.process_batch(req)
+    finally:
+        engine.shutdown()
+    got = [bytes(v) for b in reply.items[0].batches for v in b.record_values()]
+    values = [r.value for b in req.items[0].batches for r in b.records()]
+    want = [
+        v for v in values
+        if v and len(v) <= _STRIDE and b'"level":"error"' in v
+    ]
+    assert got == want and _edge_value(_STRIDE) in got and len(got) == 5
 
 
 # ------------------------------------------------------ sharded seal

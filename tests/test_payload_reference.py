@@ -74,8 +74,18 @@ def _batches(values: list[bytes], per_batch: int, base: int) -> list[RecordBatch
     return out
 
 
+@pytest.mark.parametrize("road", ["mask", "mask_joined_blob", "matrix"])
 @pytest.mark.parametrize("seed", [7, 2**31 + 11])
-def test_payload_lane_matches_the_plain_reference(seed):
+def test_payload_lane_matches_the_plain_reference(seed, road, monkeypatch):
+    """``mask``: the configuration's script maps nothing, so the launch
+    fetches one keep bit a row and frames kept values from the pointer
+    table; ``mask_joined_blob``: the same without the pointer-table
+    explode (the classic staging road); ``matrix``: the result-matrix road
+    (``gather_frame=False``). One reference holds all three."""
+    from redpanda_tpu.coproc import batch_codec
+
+    if road == "mask_joined_blob":
+        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches: None)
     config = _config()
     ref = _reference(config["reference"]["name"])
     params = config["reference"]["params"]
@@ -84,13 +94,16 @@ def test_payload_lane_matches_the_plain_reference(seed):
     parts[1] = parts[1][:40] + EDGES + parts[1][40:]
     parts[3] = EDGES[::-1] + parts[3]
 
-    engine = TpuEngine(row_stride=stride, host_workers=0)
+    engine = TpuEngine(
+        row_stride=stride, host_workers=0, gather_frame=road != "matrix"
+    )
     try:
         codes = engine.enable_coprocessors(
             [(1, json.dumps(config["script"]["spec"]), ("bench",))]
         )
         assert codes == [EnableResponseCode.success]
         assert engine._plans[1].mode == "payload"
+        assert engine._plans[1].byte_identity
         req = ProcessBatchRequest([
             ProcessBatchItem(1, NTP.kafka("bench", p), _batches(values, 32, 1000 * p))
             for p, values in enumerate(parts)
@@ -118,6 +131,12 @@ def test_payload_lane_matches_the_plain_reference(seed):
     assert stats["n_oversize_rows"] == sum(len(v) > stride for vs in parts for v in vs) >= 4
     assert stats["t_h2d"] > 0 and stats["bytes_h2d"] == 512 * (stride + 8)
     assert stats.get("n_fallback_rows", 0) == 0
+    # what comes back: one bit a staged row, or the whole result matrix
+    if road == "matrix":
+        assert stats["bytes_d2h"] == 512 * (stride + 8) and "n_frame_gather" not in stats
+    else:
+        assert stats["bytes_d2h"] == 512 // 8 and stats["n_frame_gather"] == 1
+        assert "t_rebuild" not in stats
 
 
 def test_a_script_inherits_the_programs_its_spec_already_ran():

@@ -188,11 +188,14 @@ def test_annotation_lands_on_the_profiles_host_plane(tmp_path):
     assert hist.hist.count == 2
 
 
-def test_the_payload_lanes_h2d_stage_feeds_all_its_sinks(tmp_path):
+@pytest.mark.parametrize("result", ["mask", "matrix"])
+def test_the_payload_lanes_h2d_stage_feeds_all_its_sinks(result, tmp_path):
     """``t_h2d`` (PR 26): the ``jax.device_put`` of a payload launch's
     staged matrix is a stage of its own inside ``t_dispatch``: the stat,
     the ``coproc_stage_latency_us{stage="h2d"}`` histogram, and the
-    ``rp:coproc.stage.h2d`` annotation beside pack, fetch and rebuild."""
+    ``rp:coproc.stage.h2d`` annotation beside pack, fetch and the framing
+    stage: ``frame_gather`` for a filter-only script (its launch fetches a
+    keep mask, PR 27), ``rebuild`` for one that builds new bytes."""
     import glob
 
     import jax
@@ -202,10 +205,13 @@ def test_the_payload_lanes_h2d_stage_feeds_all_its_sinks(tmp_path):
     from redpanda_tpu.coproc.engine import ProcessBatchItem
     from redpanda_tpu.models import NTP, Record, RecordBatch
     from redpanda_tpu.observability import probes
-    from redpanda_tpu.ops.transforms import filter_contains
+    from redpanda_tpu.ops.transforms import filter_contains, map_uppercase
 
+    spec = filter_contains(b"warn")
+    if result == "matrix":
+        spec = spec | map_uppercase()
     engine = TpuEngine(row_stride=64, host_workers=0)
-    engine.enable_coprocessors([(1, filter_contains(b"warn").to_json(), ("t",))])
+    engine.enable_coprocessors([(1, spec.to_json(), ("t",))])
     batch = RecordBatch.build(
         [Record(offset_delta=i, timestamp_delta=i, value=b"warn %d" % i) for i in range(8)],
         base_offset=0, first_timestamp=1000,
@@ -229,4 +235,5 @@ def test_the_payload_lanes_h2d_stage_feeds_all_its_sinks(tmp_path):
         for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
         for line in plane.lines for ev in line.events
     }
-    assert {"rp:coproc.stage." + s for s in ("pack", "h2d", "dispatch", "fetch", "rebuild")} <= names
+    frame = "frame_gather" if result == "mask" else "rebuild"
+    assert {"rp:coproc.stage." + s for s in ("pack", "h2d", "dispatch", "fetch", frame)} <= names
