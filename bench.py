@@ -141,7 +141,7 @@ def _harvest_mode(stats: dict) -> str:
 def _run_engine_mode(
     req, force_mode: str | None, host_workers: int = HOST_WORKERS,
     colcache_mb: int = 0, **engine_kw,
-) -> tuple[float, dict, list | None, dict]:
+) -> tuple[float, dict, dict]:
     """One measured engine run. force_mode None = the PRODUCT path (the
     engine's own measured device-vs-host probe picks where the predicate
     runs); "columnar_device"/"columnar_host" pin each half so every BENCH
@@ -151,8 +151,7 @@ def _run_engine_mode(
     default posture) — the HEADLINE runs with it because the bench's
     steady state IS a repeat script over unchanged partitions; the
     machinery ablations run cache-off so they still measure the machinery
-    they are named for. Returns (rate, stage dict, per-shard stage splits
-    of the last launch, probe record)."""
+    they are named for. Returns (rate, stage dict, probe record)."""
     from redpanda_tpu.coproc import TpuEngine
 
     engine = TpuEngine(
@@ -173,10 +172,6 @@ def _run_engine_mode(
     probe = {
         "columnar_backend": stats.get("columnar_backend"),
         "columnar_probe": stats.get("columnar_probe"),
-        "host_pool_probe": stats.get("host_pool_probe"),
-        # previous probe result when the periodic re-calibration
-        # (coproc_host_pool_recal_launches) has re-measured at least once
-        "host_pool_probe_prev": stats.get("host_pool_probe_prev"),
         # zero-copy harvest: which framing path the run took (the
         # projection headline mutates bytes, so it reports padded
         # honestly) and the scratch arena's reuse accounting
@@ -203,11 +198,10 @@ def _run_engine_mode(
         # multi-chip meshrunner block (absent on single-device engines)
         "mesh": stats.get("mesh"),
     }
-    shards = engine.last_launch_shards
     # a live harvester pins the engine (jit executables, staged arrays)
     # for the rest of the multi-mode bench process
     engine.shutdown()
-    return rate, _fmt_stages(stats), shards, probe
+    return rate, _fmt_stages(stats), probe
 
 
 def _measure_aa_skew(req) -> dict:
@@ -517,12 +511,12 @@ def run_mesh_64p() -> dict:
     # verdict about it — the probe's own measured verdict is reported
     # separately by the headline bench's product path
     TpuEngine.reset_columnar_probe()
-    mesh_rate, mesh_stages, _, mesh_probe = _run_engine_mode(
+    mesh_rate, mesh_stages, mesh_probe = _run_engine_mode(
         req, None, colcache_mb=32,
         mesh_devices=n_dev, mesh_backend="cpu", mesh_probe=False,
     )
     TpuEngine.reset_columnar_probe()
-    one_rate, one_stages, _, _ = _run_engine_mode(req, None, colcache_mb=32)
+    one_rate, one_stages, _ = _run_engine_mode(req, None, colcache_mb=32)
     # live bit-parity assertion between the two paths
     TpuEngine.reset_columnar_probe()
     em = TpuEngine(
@@ -666,22 +660,22 @@ def main(diff_against: str | None = None):
     # PRODUCT path: broker posture — column cache on (the bench's steady
     # state is a repeat script over unchanged partitions, exactly the
     # workload the cache exists for; its hit rate rides in the artifact)
-    value, stages, shard_stages, probe = _run_engine_mode(
+    value, stages, probe = _run_engine_mode(
         req, None, colcache_mb=32
     )
     # cache-off ablation of the SAME product path: attributes the headline
     # delta between the parse/extract machinery and the cache
     TpuEngine.reset_columnar_probe()
-    nc_rate, nc_stages, _, nc_probe = _run_engine_mode(req, None)
+    nc_rate, nc_stages, nc_probe = _run_engine_mode(req, None)
     TpuEngine.reset_columnar_probe()
-    dev_rate, dev_stages, _, _ = _run_engine_mode(req, "columnar_device")
-    host_col_rate, host_col_stages, _, _ = _run_engine_mode(req, "columnar_host")
+    dev_rate, dev_stages, _ = _run_engine_mode(req, "columnar_device")
+    host_col_rate, host_col_stages, _ = _run_engine_mode(req, "columnar_host")
     # pool-off ablation: the acceptance bar is "no regression when the pool
     # is off", so the same product path runs again with ONE worker (inline).
     # Reset the sticky backend probe first — the ablation engine must
     # re-measure device-vs-host itself, not inherit the headline's pick.
     TpuEngine.reset_columnar_probe()
-    w1_rate, w1_stages, _, w1_probe = _run_engine_mode(req, None, host_workers=1)
+    w1_rate, w1_stages, w1_probe = _run_engine_mode(req, None, host_workers=1)
     baseline = run_cpu_baseline(req)
 
     columnar_probe = probe["columnar_probe"]
@@ -754,17 +748,7 @@ def main(diff_against: str | None = None):
                 "group_ticks_per_launch": GROUP,
                 "launch_depth": DEPTH,
                 "engine_mode": "columnar",
-                # host-stage shard pool (coproc/host_pool.py): headline pool
-                # size, the per-shard stage splits of the last launch, and
-                # the workers=1 inline ablation proving the pool-off path
-                # holds the pre-pool rate
                 "host_workers": HOST_WORKERS,
-                # the engine's one-shot parallel-capacity probe: when
-                # parallel_ok is false this box has no real concurrency
-                # (advertised CPUs backed by ~1 core of quota) and the
-                # pool self-demoted to the inline path for the headline
-                "host_pool_probe": probe["host_pool_probe"],
-                "host_pool_probe_prev": probe["host_pool_probe_prev"],
                 # zero-copy harvest bookkeeping for the headline run (the
                 # projection headline assembles new bytes, so this is
                 # honestly "padded"; harvest_passthrough_64p carries the
@@ -786,7 +770,6 @@ def main(diff_against: str | None = None):
                     "parse_probe": nc_probe["parse_probe"],
                     "stages": nc_stages,
                 },
-                "shard_stages": shard_stages,
                 "host_workers1_ablation": {
                     "record_batches_per_sec": round(w1_rate, 1),
                     "stages": w1_stages,

@@ -682,9 +682,9 @@ class AdminServer:
 
     async def _governor(self, req: web.Request) -> web.Response:
         """The coproc decision plane (coproc/governor.py): every adaptive
-        decision this process made — host-pool calibration, columnar
-        backend, device_lz4, breaker transitions, harvest path, seal
-        engagement, adaptive deadlines — as a journal (newest-first, with
+        decision this process made — columnar backend, parse ladder,
+        mesh-vs-single, device_lz4, breaker transitions, harvest path,
+        adaptive deadlines — as a journal (newest-first, with
         measured inputs + verdict + reason + active-config snapshot) plus
         the live per-domain posture. ``?limit=N`` caps the journal slice,
         ``?domain=NAME`` filters it. `rpk debug governor` renders this."""

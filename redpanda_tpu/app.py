@@ -526,7 +526,7 @@ class Application:
             registry.gauge(
                 "coproc_host_workers",
                 lambda: float(eng._host_workers),
-                "Configured host-stage worker pool size (0 = inline)",
+                "Configured width of the mesh lane's per-device host ladder",
             )
         from redpanda_tpu.observability import tracer
 

@@ -463,8 +463,7 @@ async def cmd_debug(args) -> int:
             v = round(v, 6) if isinstance(v, float) else v
             print(f"  {k:<28}{v}")
         for k in (
-            "columnar_backend", "host_pool_probe", "host_pool_probe_prev",
-            "host_pool_recal", "columnar_probe", "parse_path", "parse_probe",
+            "columnar_backend", "columnar_probe", "parse_path", "parse_probe",
             "colcache", "arena", "breakers", "lockwatch", "leakwatch",
             "mesh_error", "device_launches_by_script",
         ):
@@ -720,10 +719,7 @@ async def cmd_debug(args) -> int:
         posture = body.get("posture")
         if posture:
             print("posture:")
-            for dom in (
-                "host_pool", "columnar_backend", "device_lz4",
-                "harvest_path", "sharded_seal",
-            ):
+            for dom in ("columnar_backend", "device_lz4", "harvest_path"):
                 print(f"  {dom:<20}{posture.get(dom) or '(undecided)'}")
             for dom, b in sorted((posture.get("breakers") or {}).items()):
                 print(

@@ -214,18 +214,8 @@ PROPERTIES: list[Property] = [
     Property("coproc_offset_flush_interval_ms", "Offset snapshot cadence", 300_000, int, _positive),
     Property(
         "coproc_host_workers",
-        "Host-stage worker pool size for the transform engine (0 = inline single-thread path)",
+        "Width of the mesh lane's per-device host ladder: worker threads that run the devices' parse/extract and framing concurrently (0 or 1 = one after another; unused without coproc_mesh_devices)",
         min(4, os.cpu_count() or 1), int, _non_negative,
-    ),
-    Property(
-        "coproc_host_pool_probe",
-        "Measure real parallel capacity before sharding host stages (quota-limited boxes advertise CPUs they don't have); false trusts coproc_host_workers as-is",
-        True, bool,
-    ),
-    Property(
-        "coproc_host_pool_recal_launches",
-        "Re-run the inline-vs-sharded host-pool probe every N shardable launches (burstable hosts change capacity over time); 0 pins the first measurement forever",
-        512, int, _non_negative,
     ),
     Property(
         "coproc_gather_frame",

@@ -56,10 +56,6 @@ class CoprocApi:
         # matches, so an unset config and a default config agree
         self.engine = TpuEngine(
             host_workers=_knob("coproc_host_workers", None),
-            host_pool_probe=_knob("coproc_host_pool_probe", True),
-            host_pool_recal_launches=_knob(
-                "coproc_host_pool_recal_launches", None
-            ),
             gather_frame=_knob("coproc_gather_frame", True),
             structural_parse=_knob("coproc_structural_parse", None),
             device_column_cache_mb=_knob(

@@ -36,7 +36,7 @@ class Arena:
     out; at a steady tick cadence that is megabytes of allocator churn per
     launch for buffers whose size barely changes. The arena keeps a small
     free list instead: ``acquire`` hands back a previously released buffer
-    when one is big enough, ``release`` returns it. Thread-safe — sharded
+    when one is big enough, ``release`` returns it. Thread-safe — mesh
     harvests frame concurrently on pool workers.
 
     The engine owns one arena per instance (``TpuEngine.reset_arenas()``
@@ -301,36 +301,6 @@ def explode_ptrs(batches: list[RecordBatch]) -> PtrExploded | None:
         else np.zeros(0, np.int32)
     )
     return PtrExploded(payloads, rel_off, rel_len, sizes, ranges)
-
-
-def merge_exploded(parts: list[ExplodedBatches]) -> ExplodedBatches:
-    """Concatenate per-shard explode results into one launch-wide table.
-
-    Shards are contiguous batch slices in input order (host_pool
-    .partition_counts), so the merge is pure concatenation with rebasing:
-    value offsets shift by the preceding shards' joined length, per-batch
-    record ranges by their record count. The result is byte- and
-    index-identical to exploding the whole batch list inline — the
-    downstream stages (_pack_staged, _mat_host, frame_ranges) cannot tell
-    the difference, which is what the workers=0 parity tests assert.
-    """
-    if len(parts) == 1:
-        return parts[0]
-    if not parts:
-        return ExplodedBatches(b"", np.zeros(0, np.int64), np.zeros(0, np.int32), [])
-    joined = b"".join(p.joined for p in parts)
-    offs, sizes, ranges = [], [], []
-    byte_base = 0
-    rec_base = 0
-    for p in parts:
-        offs.append(p.offsets + byte_base)
-        sizes.append(p.sizes)
-        ranges.extend((s + rec_base, e + rec_base) for s, e in p.ranges)
-        byte_base += len(p.joined)
-        rec_base += len(p.sizes)
-    return ExplodedBatches(
-        joined, np.concatenate(offs), np.concatenate(sizes), ranges
-    )
 
 
 def explode_batches(batches: list[RecordBatch]) -> ExplodedBatches:

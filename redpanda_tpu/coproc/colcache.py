@@ -140,9 +140,9 @@ class DeviceColumnCache:
 
     def lookup(self, key: tuple) -> Entry | None:
         """The cached entry (refreshing LRU order) or None. Misses carry
-        no side state: since the sharded path populates per shard, every
-        miss — inline or sharded — populates on the SAME launch, so
-        nothing needs to recognize a repeating workload anymore."""
+        no side state: every miss — launch-wide, or per shard on the mesh
+        lane — populates on the SAME launch, so nothing needs to recognize
+        a repeating workload."""
         with self._lock:
             entry = self._entries.get(key)
             if entry is not None:

@@ -145,43 +145,35 @@ def _bucket_rows(n: int) -> int:
     return b
 
 
-# serializes _mask_state transitions between the harvester, timed-out
-# callers claiming their still-queued mask, and sharded-launch abandonment
-# (transitions are rare and O(1); one process-wide lock is plenty)
+# serializes _mask_state transitions between the harvester and timed-out
+# callers claiming their still-queued mask (transitions are rare and O(1);
+# one process-wide lock is plenty)
 _mask_claim_lock = threading.Lock()
 
 
 class _MaskSlot:
-    """One shard's predicate mask in flight (host-evaluated or device).
+    """One mesh shard's predicate mask (host-evaluated, or its device's
+    block of the SPMD result, fetched at harvest).
 
     Field names deliberately mirror _Launch's mask fields
-    (``_mask_dev``/``_mask_np``/``_mask_event``/``trace_id``/``_enq_t``):
-    the harvester loop serves either shape without caring which it got.
+    (``_mask_dev``/``_mask_np``/``_mask_event``/``trace_id``):
+    _resolve_keep serves either shape without caring which it got. A
+    slot never rides the harvester, so its ``_mask_event`` stays None.
     """
 
     __slots__ = ("n", "_mask_dev", "_mask_np", "_mask_event",
-                 "trace_id", "_enq_t", "_cols", "_mask_state")
+                 "trace_id", "_cols")
 
     def __init__(self, n: int):
         self.n = n
         self._mask_dev = None
         self._mask_np = None
-        self._mask_event: threading.Event | None = None
+        self._mask_event = None
         self.trace_id: int | None = None
-        self._enq_t = 0.0
         # extracted predicate columns, retained while a device mask is in
         # flight: the exact numpy fallback re-evaluates over these if the
         # D2H fetch dies (faults.MASK_FETCH domain)
         self._cols = None
-        # claim protocol (guarded by _mask_claim_lock): "idle" -> "queued"
-        # on enqueue; the harvester CASes queued -> "harvesting" on
-        # dequeue; a caller that timed out while its mask was still QUEUED
-        # (harvester busy on an earlier wedged mask) CASes queued ->
-        # "claimed" and fetches itself; a degraded sharded launch marks
-        # its orphans "abandoned". The harvester skips claimed/abandoned
-        # without a fetch or a breaker verdict — one mask, one envelope,
-        # one verdict, no matter how deep the harvest queue is.
-        self._mask_state = "idle"
 
 
 class _HostShard:
@@ -219,10 +211,10 @@ class _Launch:
       passthrough specs).
     - host: computed synchronously from the exploded inputs at harvest.
 
-    When the engine's host-stage pool sharded the launch (``_shards`` set),
+    When the mesh lane ran the launch (``_shards`` set, one per device),
     the columnar harvest side assembles and frames per shard instead of
     launch-wide; the framed list is the in-order concatenation of the
-    shards' framed lists, byte-identical to the inline path.
+    shards' framed lists, byte-identical to the single-device path.
     """
 
     __slots__ = ("script_id", "policy", "mode", "r_out", "ranges", "fits",
@@ -230,7 +222,7 @@ class _Launch:
                  "_mask_event", "_proj_data", "_proj_ok", "_plan",
                  "_exploded", "_mat", "_gather_mat", "_framed", "_lock",
                  "_shards", "trace_id", "_enq_t", "_cols", "_staged_np",
-                 "_mask_state", "_pending_slots", "_trial")
+                 "_mask_state")
 
     def __init__(self, script_id: int, policy: ErrorPolicy):
         self.script_id = script_id
@@ -260,18 +252,15 @@ class _Launch:
         # retained until their device result lands, so an exhausted device
         # retry can re-execute the stage host-side with exact output
         self._cols = None
-        # see _MaskSlot._mask_state: same claim protocol, same harvester
+        # claim protocol (guarded by _mask_claim_lock): "idle" -> "queued"
+        # on enqueue; the harvester CASes queued -> "harvesting" on
+        # dequeue; a caller that timed out while its mask was still QUEUED
+        # (harvester busy on an earlier wedged mask) CASes queued ->
+        # "claimed" and fetches itself. The harvester skips a claimed mask
+        # without a fetch or a breaker verdict — one mask, one envelope,
+        # one verdict, no matter how deep the harvest queue is.
         self._mask_state = "idle"
-        # per-shard _MaskSlots this launch has enqueued to the harvester
-        # (appended under self._lock by shard workers): a sharded launch
-        # that degrades to the inline path abandons these so orphan masks
-        # cost no envelopes and feed no stale verdicts to the breaker
-        self._pending_slots: list[_MaskSlot] = []
         self._staged_np = None
-        # set while this launch is one sample of the host-pool A/B
-        # (host_pool.LaunchTrial)
-        self._trial: host_pool.TrialSample | None = None
-
 
     def _mat_payload(self):
         if self._packed_dev is None:  # zero-record launch
@@ -685,10 +674,10 @@ class _Launch:
         return framed
 
     def _framed_sharded(self) -> list[tuple[bytes, int]]:
-        """Sharded harvest: per-shard masks resolved in shard order, then
-        assembly + framing fan out over the host pool; the concatenated
-        framed lists are byte-identical to the launch-wide path because
-        shards are contiguous record ranges in input order."""
+        """Per-shard harvest of a mesh launch: masks resolved in shard
+        order, then assembly + framing fan out over the host pool; the
+        concatenated framed lists are byte-identical to the launch-wide
+        path because shards are contiguous record ranges in input order."""
         shards = self._shards
         keeps = [self._shard_keep(shard) for shard in shards]
         thunks = [
@@ -765,13 +754,6 @@ def _fit_cols(cols, n_pad: int) -> list:
     return out
 
 
-def _explode_shard(batches):
-    """One payload/host-plan explode shard on a pool worker (the
-    shard_worker fault domain covers every dispatch-side worker body)."""
-    faults.inject(faults.SHARD_WORKER)
-    return batch_codec.explode_batches(batches)
-
-
 # Per-slot dispositions inside a Ticket.
 _UNKNOWN, _EMPTY, _DEREGISTERED, _LAUNCHED = range(4)
 
@@ -785,15 +767,6 @@ def _stage_t0(key: str) -> float:
     that ``_stat_stage`` / ``_Launch._stat`` closes."""
     return stages.begin("coproc.stage." + key[2:])
 
-
-# Sharding threshold: below this many records the pool's fan-out/merge
-# overhead (thread handoff, per-shard native-call fixed costs) eats the
-# win, so small launches keep the inline path.
-_SHARD_MIN_ROWS = 2048
-
-# Harvest-side seal sharding threshold: below this many output batches the
-# pool's thread handoff costs more than the recompress+CRC it spreads.
-_SEAL_MIN_BATCHES = 8
 
 # Columnar backend probe: don't pin the process-wide device-vs-host choice
 # on a batch too small to represent steady state, and bound the device leg
@@ -837,38 +810,17 @@ class Ticket:
         finally:
             self._engine._release_admission(self)
 
-    def _trial_launch(self) -> "_Launch | None":
-        """The launch this ticket times for the host-pool A/B: a trial
-        launch that this ticket alone harvests, whole. A launch fused over
-        several tickets is framed by one and sealed by each, so no single
-        harvest is its cost: such a sample is left out."""
-        launch, n_ranges = None, 0
-        for disp, _, slot_launch, rng in self._slots:
-            if disp != _LAUNCHED:
-                continue
-            if slot_launch._trial is None or launch not in (None, slot_launch):
-                return None
-            launch = slot_launch
-            n_ranges += len(rng)
-        if launch is None or n_ranges != len(launch.ranges):
-            return None
-        return launch
-
     def _result_impl(self) -> ProcessBatchReply:
         reply = ProcessBatchReply()
         dereg: set[int] = set()
         failed_scripts: set[int] = set()
         # Phase 1: frame every launch and collect the recompress+seal jobs
-        # REPLY-WIDE, so the seal can fan out over the host pool in one
-        # batch instead of serially per item — the harvest-side analogue
+        # REPLY-WIDE, sealed in one pass below — the harvest-side analogue
         # of submit_group's launch fusion. Jobs are independent
-        # (build_output_batch is pure per batch) and merge in input order,
-        # so offsets/CRCs are bit-identical to the serial loop.
+        # (build_output_batch is pure per batch) and land in input order.
         seal_jobs: list[tuple] = []  # (source batch, payload, kept)
         slot_plans: list = []  # per slot: list[int] | Exception | None
         framing_failed: set[int] = set()
-        trial = self._trial_launch()
-        mark = self._engine._trial_mark() if trial is not None else None
         for disp, item, launch, rng in self._slots:
             if disp != _LAUNCHED or launch.script_id in framing_failed:
                 # a later slot of a script whose framing already failed is
@@ -889,12 +841,7 @@ class Ticket:
                 # slot order there, exactly like the old per-slot loop
                 slot_plans.append(exc)
                 framing_failed.add(launch.script_id)
-        sealed = self._engine._seal_jobs(
-            seal_jobs, trace_id=self.trace_id,
-            arm=trial._trial.arm if trial is not None else None,
-        )
-        if trial is not None:
-            self._engine._trial_harvested(trial, mark, not framing_failed)
+        sealed = self._engine._seal_jobs(seal_jobs)
         # Phase 2: assemble the reply in slot order under the script's
         # ErrorPolicy — this is the policy boundary (deregister failures
         # ride through here), so programming errors must not bypass it.
@@ -989,8 +936,6 @@ class TpuEngine:
         mesh=None,
         force_mode: str | None = None,
         host_workers: int | None = None,
-        host_pool_probe: bool = True,
-        host_pool_recal_launches: int | None = None,
         gather_frame: bool = True,
         structural_parse: bool | None = None,
         structural_probe: bool = True,
@@ -1075,54 +1020,15 @@ class TpuEngine:
         self._output_codec = output_codec
         self._mesh = mesh
         self._force_mode = force_mode
-        # host-stage worker pool (coproc/host_pool.py): None = config
-        # default min(4, cores); 0 or 1 = the inline single-thread path
+        # width of the mesh lane's per-device host ladder
+        # (coproc/host_pool.py; the pool is built with the mesh runner
+        # below): None = config default min(4, cores); 0 or 1 runs the
+        # per-device ladders one after another on the dispatching thread
         if host_workers is None:
             host_workers = host_pool.default_host_workers()
         self._host_workers = max(0, int(host_workers))
-        self._host_pool = (
-            host_pool.HostStagePool(self._host_workers)
-            if self._host_workers >= 2
-            else None
-        )
-        # Pool on/off is a MEASURED per-process decision, exactly like the
-        # columnar device-vs-host probe: the first shardable launches run
-        # alternately inline and sharded, each timed whole, and the winner
-        # pins (host_pool.LaunchTrial; quota-limited boxes advertise CPUs
-        # that thrash instead of scale). host_pool_probe=False pins
-        # "sharded" unmeasured — bench scaling runs and parity tests need
-        # the fan-out deterministically.
-        self._pool_decision: str | None = None if host_pool_probe else "sharded"
-        # the trial in progress while the decision is None (made by the
-        # first shardable launch that finds none)
-        self._pool_trial: host_pool.LaunchTrial | None = None
-        # first runs of programs, probes' extra passes and host fallbacks,
-        # counted as they happen: a trial launch that met one is no sample
-        self._spoilers = 0
+        self._host_pool: host_pool.HostStagePool | None = None
         self.governor.update_config_snapshot(host_workers=self._host_workers)
-        if not host_pool_probe:
-            # config pin, not a measurement — posture only, no journal
-            # entry (a decision the operator made is not an adaptive one)
-            self.governor.note_posture(governor.HOST_POOL, "sharded")
-        self._pool_decision_lock = lockwatch.wrap(
-            threading.Lock(), "TpuEngine._pool_decision_lock"
-        )
-        # set while a periodic re-calibration runs, so its verdict
-        # journals itself as a recal rather than a first trial
-        self._recal_pending = False
-        self._host_pool_probe: dict | None = None
-        self._host_pool_probe_prev: dict | None = None
-        # Periodic re-calibration (config coproc_host_pool_recal_launches):
-        # burstable boxes gain/lose capacity over time, so a pinned on/off
-        # decision re-measures every N shardable launches. 0 pins forever;
-        # an explicit host_pool_probe=False pin is never re-measured.
-        self._probe_enabled = bool(host_pool_probe)
-        self._recal_interval = (
-            512
-            if host_pool_recal_launches is None
-            else max(0, int(host_pool_recal_launches))
-        )
-        self._launches_since_cal = 0
         # Zero-copy harvest: byte-identity transforms gather-frame straight
         # from the joined blob (gather_frame=False is the bench ablation /
         # operator escape hatch), and framing scratch reuses across
@@ -1131,9 +1037,9 @@ class TpuEngine:
         self._arena = leakwatch.wrap(batch_codec.Arena(), "engine.arena")
         # Structural-index parse path (native rp_explode_find2 +
         # rp_extract_cols2): fused-vs-staged is a MEASURED per-engine
-        # decision with the host-pool posture — the first representative
-        # columnar launch times BOTH full ladders on its own batches and
-        # the winner pins (PROBE_MARGIN; the scalar staged ladder is the
+        # decision — the first representative columnar launch times BOTH
+        # full ladders on its own batches and the winner pins
+        # (governor.PROBE_MARGIN; the scalar staged ladder is the
         # known path, so structural must show a real win). config
         # coproc_structural_parse=False pins staged outright;
         # structural_probe=False pins structural unmeasured (bench
@@ -1182,7 +1088,8 @@ class TpuEngine:
         )
         # Multi-chip sharded engine (coproc/meshrunner.py): the partition
         # axis pjit/shard_map-sharded over an N-device mesh, per-device
-        # sub-launches over the host-pool range shard. None/0/1 keeps the
+        # sub-launches over the host-pool range shard (the one user of the
+        # pool: a single-device engine has none). None/0/1 keeps the
         # single-device engine (config coproc_mesh_devices wires the
         # broker knob). mesh_probe=False pins "mesh" unmeasured — parity
         # tests and bench ablations need the mesh lane deterministically;
@@ -1203,6 +1110,8 @@ class TpuEngine:
                 faults.note_failure("mesh_init", exc)
                 logger.warning("meshrunner unavailable: %s", exc)
                 self._mesh_error = f"{faults.kind_of(exc)}: {exc}"
+        if self._meshrunner is not None and self._host_workers >= 2:
+            self._host_pool = host_pool.HostStagePool(self._host_workers)
         self.governor.update_config_snapshot(
             mesh_devices=(
                 self._meshrunner.n_devices if self._meshrunner else 0
@@ -1242,7 +1151,7 @@ class TpuEngine:
         self.governor.update_config_snapshot(
             admission=self._admission is not None
         )
-        # per-shard stage splits of the most recent sharded launch (bench
+        # per-shard stage splits of the most recent mesh launch (bench
         # artifact + debugging aid; overwritten per launch under the lock)
         self.last_launch_shards: list[dict] | None = None
         # the platform this engine's programs run on ({"platform",
@@ -1313,11 +1222,10 @@ class TpuEngine:
             if launch is None:  # shutdown sentinel
                 return
             with _mask_claim_lock:
-                if launch._mask_state in ("claimed", "abandoned"):
-                    # claimed: its caller gave up waiting and is fetching
-                    # the mask itself; abandoned: a degraded sharded launch
-                    # orphaned it. Either way a fetch here would be a
-                    # duplicate envelope and a stale breaker verdict.
+                if launch._mask_state == "claimed":
+                    # its caller gave up waiting and is fetching the mask
+                    # itself: a fetch here would be a duplicate envelope
+                    # and a stale breaker verdict
                     continue
                 launch._mask_state = "harvesting"
             t_get = time.perf_counter()
@@ -1571,15 +1479,6 @@ class TpuEngine:
             out["mesh"] = self._meshrunner.stats()
         if self._mesh_error is not None:
             out["mesh_error"] = self._mesh_error
-        if self._host_pool_probe is not None:
-            out["host_pool_probe"] = dict(self._host_pool_probe)
-        if self._host_pool_probe_prev is not None:
-            out["host_pool_probe_prev"] = dict(self._host_pool_probe_prev)
-        if self._host_pool is not None:
-            out["host_pool_recal"] = {
-                "interval": self._recal_interval if self._probe_enabled else 0,
-                "launches_since": self._launches_since_cal,
-            }
         with TpuEngine._columnar_probe_lock:  # coherent two-field snapshot
             backend = TpuEngine._columnar_backend
             probe = TpuEngine._columnar_probe
@@ -1730,35 +1629,17 @@ class TpuEngine:
         self._stat_add(key, dt)
         return dt
 
-    def _note_spoiler(self) -> None:
-        """Something ran inside the launch in hand that is not its road's
-        steady cost (a program's first run, a probe's extra passes, a host
-        fallback): a host-pool trial must not take that launch as a
-        sample."""
-        with self._stats_lock:
-            self._spoilers += 1
-
     def _count_fallback(self, n: int) -> None:
         """Account records whose stages re-executed on the pure-host
         fallback (exhausted device retries or an open breaker)."""
         self._stat_add("n_fallback_rows", float(n))
         probes.coproc_fallback_rows.inc(n)
-        self._note_spoiler()
 
-    def _seal_jobs(
-        self, jobs: list[tuple], trace_id: int | None = None,
-        arm: str | None = None,
-    ) -> list:
+    def _seal_jobs(self, jobs: list[tuple]) -> list:
         """Recompress + seal framed payloads into output batches
-        (batch_codec.build_output_batch), sharded over the host pool when
-        the measured pool decision is on and the reply is big enough
-        (``arm``: the road of the trial launch this reply harvests, which
-        stands in for a decision still being measured).
-        Jobs are independent (build_output_batch is pure per batch) and
-        chunks merge in input order, so offsets/CRCs are bit-identical to
-        the serial loop. A per-job failure comes back AS the exception
-        instance (the caller owns the script error policy); a pool
-        machinery failure degrades the whole list to the inline loop."""
+        (batch_codec.build_output_batch), in input order on the caller's
+        thread. A per-job failure comes back AS the exception instance
+        (the caller owns the script error policy)."""
         if not jobs:
             return []
 
@@ -1772,84 +1653,10 @@ class TpuEngine:
             except Exception as exc:  # pandalint: disable=EXC901 -- delivered as a value to the ErrorPolicy boundary (note_failure("rebuild") classifies it there)
                 return exc
 
-        pool = self._host_pool
-        decision = arm
-        if decision is None:
-            with self._pool_decision_lock:  # coherent read vs concurrent recal
-                decision = self._pool_decision
-        if (
-            pool is not None
-            and decision == "sharded"
-            and len(jobs) >= _SEAL_MIN_BATCHES
-        ):
-            # chunks balance by payload bytes: recompression cost tracks
-            # size, and one fat batch must not serialize a whole chunk
-            # behind it (+1 keeps zero-length payloads partitionable)
-            parts = host_pool.partition_counts(
-                [len(p) + 1 for _, p, _ in jobs], pool.workers
-            )
-            if len(parts) >= 2:
-                def run_chunk(s: int, e: int) -> list:
-                    t0 = _stage_t0("t_shard_seal")
-                    out = [seal_one(*jobs[i]) for i in range(s, e)]
-                    # per-chunk CPU-seconds; the fan-out wall time is
-                    # t_sharded_seal (same split discipline as t_shard_*).
-                    # Explicit trace id: chunks run on pool workers where
-                    # no ambient trace is set.
-                    self._stat_stage("t_shard_seal", t0, trace_id=trace_id)
-                    return out
-
-                t0 = _stage_t0("t_sharded_seal")
-                try:
-                    chunks = pool.run([
-                        (lambda s=s, e=e: run_chunk(s, e)) for s, e in parts
-                    ])
-                except Exception as exc:
-                    faults.note_failure(
-                        faults.SHARD_WORKER, exc, reraise_programming=True
-                    )
-                else:
-                    self._stat_stage("t_sharded_seal", t0)
-                    # journaled only once the fan-out COMMITTED: a pool-
-                    # machinery failure falls through to the inline loop
-                    # below, and recording "sharded" first would both lie
-                    # and flip-flop the dedupe into flooding the ring
-                    self.governor.record_mode(
-                        governor.SHARDED_SEAL,
-                        "sharded",
-                        f"reply-wide seal fan-out engaged: {len(jobs)} jobs "
-                        f">= {_SEAL_MIN_BATCHES} over {len(parts)} chunks",
-                        {"jobs": len(jobs), "chunks": len(parts)},
-                    )
-                    return [b for chunk in chunks for b in chunk]
-        if len(jobs) >= _SEAL_MIN_BATCHES:
-            # only an ELIGIBLE reply sealing inline is a decision (pool off
-            # or degraded); small replies below the threshold are trivia,
-            # and journaling them would flip-flop the ring on workloads
-            # whose reply sizes oscillate around _SEAL_MIN_BATCHES
-            self.governor.record_mode(
-                governor.SHARDED_SEAL,
-                "inline",
-                "serial seal despite an eligible reply: pool off, measured "
-                "inline decision, or pool-machinery degradation",
-                {"jobs": len(jobs)},
-            )
         t0 = _stage_t0("t_seal")
         out = [seal_one(*j) for j in jobs]
         self._stat_stage("t_seal", t0)
         return out
-
-    def _abandon_pending_masks(self, launch: _Launch) -> None:
-        """Mark a degraded sharded launch's still-queued shard masks
-        abandoned (the harvester skips them: no fetch, no verdict). A mask
-        already being harvested keeps its in-flight verdict — that device
-        interaction genuinely happened."""
-        with launch._lock:
-            slots, launch._pending_slots = launch._pending_slots, []
-        with _mask_claim_lock:
-            for slot in slots:
-                if slot._mask_state == "queued":
-                    slot._mask_state = "abandoned"
 
     def _try_device_leg(
         self, domain: str, leg, program: tuple | None = None, fn=None
@@ -1867,10 +1674,10 @@ class TpuEngine:
 
         ``program``: dispatch legs name the device program they launch as
         ``(script_id, lane, n_pad)``. The first leg of a program traces
-        and compiles it, so it runs under _COMPILE_DEADLINE_S, alone (shard
-        workers reaching the same program wait for it rather than compile
-        it again), and its wall time is ``t_compile``, not a deadline
-        sample. Every successful dispatch leg is one ``n_device_launches``.
+        and compiles it, so it runs under _COMPILE_DEADLINE_S, alone (a
+        concurrent launch reaching the same program waits for it rather
+        than compile it again), and its wall time is ``t_compile``, not a
+        deadline sample. Every successful dispatch leg is one ``n_device_launches``.
         ``fn``: the jitted function the leg calls, where scripts of one
         spec share it (the payload lane's pipelines are cached by spec): a
         script whose function another script already ran at this row
@@ -1920,7 +1727,6 @@ class TpuEngine:
                 first_run_s = dt
                 self._stat_add("t_compile", dt)
                 self._stat_add("n_compiles", 1.0)
-                self._note_spoiler()
             else:
                 gov.observe_leg(domain, dt)
             return out
@@ -2035,10 +1841,7 @@ class TpuEngine:
             launch.trace_id = entries[0][0].trace_id
             try:
                 with tracer.span("coproc.dispatch", trace_id=launch.trace_id):
-                    mark = self._trial_mark()
                     self._dispatch(script_id, launch, entries)
-                    if launch._trial is not None:
-                        self._trial_dispatched(launch, mark)
                 ridx = 0
                 for ticket, slot_idx, item in entries:
                     rng = list(range(ridx, ridx + len(item.batches)))
@@ -2081,10 +1884,8 @@ class TpuEngine:
         # find, extract) and — when the predicate ran on-device — the H2D
         # replay (the cached cols are device-resident). The key is
         # content-addressed (colcache.fingerprint), so an append produces
-        # a clean miss by construction. Sharded launches consult and
-        # populate the cache PER SHARD inside their workers (the old
-        # second-miss inline self-route is gone), so this launch-wide
-        # lookup serves the inline path and full-launch repeat windows.
+        # a clean miss by construction. (Mesh launches consult and
+        # populate the cache PER SHARD inside their own lane, above.)
         store_key = None
         if (
             plan.mode == "columnar"
@@ -2100,8 +1901,6 @@ class TpuEngine:
                 return
             self._count_colcache(False)
             store_key = key
-        if self._dispatch_sharded(launch, plan, all_batches):
-            return
         # decide the parse ladder BEFORE the stage timer starts: the first
         # representative launch runs the fused-vs-staged calibration here,
         # and its four ladder passes must not masquerade as that launch's
@@ -2226,8 +2025,7 @@ class TpuEngine:
 
     def _measure_parse_ratio(self, plan, all_batches) -> tuple[float, float]:
         """(t_staged, t_structural) for this launch's REAL parse+extract
-        ladders, each best-of-2 — the same measure-the-true-workload
-        posture as the host-pool trial."""
+        ladders, each best-of-2."""
         paths = plan.flat_paths()
         n = sum(b.header.record_count for b in all_batches)
         n_pad = _bucket_rows(n)
@@ -2267,7 +2065,6 @@ class TpuEngine:
         """One-shot engine-sticky fused-vs-staged pin off the first
         representative columnar launch. Caller holds the probe RUN lock;
         the decision fields publish under the short decision lock."""
-        self._note_spoiler()
         try:
             t_staged, t_structural = self._measure_parse_ratio(
                 plan, all_batches
@@ -2288,7 +2085,7 @@ class TpuEngine:
             )
             return
         ratio = t_staged / t_structural if t_structural > 0 else 0.0
-        decision = "structural" if ratio >= host_pool.PROBE_MARGIN else "staged"
+        decision = "structural" if ratio >= governor.PROBE_MARGIN else "staged"
         probe = {
             "t_staged_ms": round(t_staged * 1e3, 3),
             "t_structural_ms": round(t_structural * 1e3, 3),
@@ -2304,297 +2101,9 @@ class TpuEngine:
             decision,
             f"measured parse+extract ladders: staged {t_staged * 1e3:.3f} ms"
             f" vs structural {t_structural * 1e3:.3f} ms (structural must "
-            f"win {host_pool.PROBE_MARGIN}x; engine-sticky)",
+            f"win {governor.PROBE_MARGIN}x; engine-sticky)",
             dict(probe),
         )
-
-    # ------------------------------------------------------ pool calibration
-    def _trial_mark(self) -> tuple[float, int]:
-        """The engine's clock and its spoiler count, at one instant."""
-        with self._stats_lock:
-            return time.perf_counter(), self._spoilers
-
-    def _trial_elapsed(self, mark: tuple[float, int]) -> float | None:
-        """Seconds since ``mark``; None if a program's first run, a probe
-        or a host fallback ran meanwhile (no sample of a road's cost)."""
-        with self._stats_lock:
-            clean = self._spoilers == mark[1]
-        return time.perf_counter() - mark[0] if clean else None
-
-    def _trial_dispatched(self, launch: _Launch, mark) -> None:
-        """The dispatch half of a trial launch's cost."""
-        seconds = self._trial_elapsed(mark)
-        if seconds is None:
-            self._trial_dropped(launch)
-        else:
-            launch._trial.dispatch_s = seconds
-
-    def _trial_dropped(self, launch: _Launch) -> None:
-        sample, launch._trial = launch._trial, None
-        with self._pool_decision_lock:
-            sample.trial.drop()
-
-    def _trial_harvested(self, launch: _Launch, mark, clean: bool) -> None:
-        """The harvest half (fetch wait, assemble, frame, seal) lands: the
-        launch is one sample of its arm, and the trial's last sample
-        decides."""
-        seconds = self._trial_elapsed(mark)
-        sample, launch._trial = launch._trial, None
-        trial = sample.trial
-        with self._pool_decision_lock:
-            if trial is not self._pool_trial:
-                return  # a launch of a trial already concluded
-            if clean and seconds is not None:
-                trial.add(sample.arm, sample.dispatch_s + seconds, launch.n)
-            else:
-                trial.drop()
-            if trial.complete or trial.exhausted:
-                self._conclude_trial(trial)
-
-    def _conclude_trial(self, trial: host_pool.LaunchTrial) -> None:
-        """Pin the pool on or off for the process by the trial's verdict
-        (the same measure-first posture as _probe_columnar_backend: never
-        assume the cores are real) and journal what was measured. Caller
-        holds the decision lock."""
-        recal = self._recal_pending
-        self._recal_pending = False
-        self._pool_trial = None
-        why = (
-            "periodic recalibration (coproc_host_pool_recal_launches)"
-            if recal
-            else "first shardable launches"
-        )
-        try:
-            probe = dict(trial.verdict(), workers=self._host_workers)
-        except Exception as exc:
-            # classified: a box whose calibration keeps blowing up runs
-            # inline forever, which must be visible on /metrics
-            faults.note_failure("pool_calibration", exc)
-            logger.exception("host pool calibration failed; keeping inline path")
-            self._pool_decision = "inline"
-            self.governor.record(
-                governor.HOST_POOL,
-                "inline",
-                f"{why} FAILED ({faults.kind_of(exc)}); keeping inline path",
-                {"error": faults.kind_of(exc), "workers": self._host_workers},
-            )
-        else:
-            self._pool_decision = probe["chosen"]
-            self._host_pool_probe = probe
-            logger.info("host pool calibration: %s", probe)
-            if probe.get("incomplete"):
-                what = (
-                    f"no {trial.per_arm} clean launches a road in "
-                    f"{trial.issued} shardable launches; keeping inline path"
-                )
-            else:
-                what = (
-                    f"whole launches, inline {probe['inline_us_per_row']} vs "
-                    f"sharded {probe['sharded_us_per_row']} us a row (medians "
-                    f"of {trial.per_arm}): speedup {probe['speedup']:.3f}x vs "
-                    f"margin {host_pool.PROBE_MARGIN}"
-                )
-            self.governor.record(
-                governor.HOST_POOL,
-                self._pool_decision,
-                f"{why}: {what} at {self._host_workers} workers",
-                dict(probe, samples=trial.samples, recalibration=recal),
-            )
-        if self._pool_decision == "inline":
-            self._host_pool.shutdown()  # threads idle forever otherwise
-
-    # ------------------------------------------------------ sharded dispatch
-    def _dispatch_sharded(self, launch: _Launch, plan, all_batches) -> bool:
-        """Shard the launch's host stages over the worker pool.
-
-        Returns False when this launch should take the inline path: no
-        pool, too small, SPMD mesh, or a columnar plan whose device-vs-host
-        probe has not run yet (the first columnar launch probes inline and
-        pins the backend; every later launch shards).
-        """
-        pool = self._host_pool
-        if pool is None or len(all_batches) < 2:
-            return False
-        counts = [b.header.record_count for b in all_batches]
-        if sum(counts) < _SHARD_MIN_ROWS:
-            return False
-        parts = host_pool.partition_counts(counts, pool.workers)
-        if len(parts) < 2:
-            # skewed batches can collapse to a single shard; never CALIBRATE
-            # on such a launch either — a 1-thunk pool.run executes on the
-            # caller thread, so t_sharded ~= t_inline and the pool would be
-            # demoted process-wide off a meaningless measurement
-            return False
-        # a launch that cannot shard whatever the decision takes no part
-        # in it: an SPMD predicate stays one launch over the mesh, and a
-        # columnar plan whose backend is not probed yet probes inline
-        use_host = backend = None
-        if plan.mode == "columnar" and plan.dev_cols:
-            if self._mesh is not None:
-                return False
-            backend = TpuEngine.sticky_columnar_backend()
-            if self._force_mode == "columnar_host":
-                use_host = True
-            elif self._force_mode == "columnar_device":
-                use_host = False
-            elif backend is not None:
-                use_host = backend == "host"
-            else:
-                return False
-        # ONE locked region owns the recal counter, the trial's next arm
-        # AND the decision read this launch acts on: the old shape re-read
-        # self._pool_decision unlocked after the calibrate block
-        # (pandaraces RAC1101 — a concurrent recal archiving the probe
-        # could flip the value between the calibration and its use).
-        with self._pool_decision_lock:
-            decision = self._pool_decision
-            if (
-                self._probe_enabled
-                and self._recal_interval > 0
-                and decision is not None
-            ):
-                # periodic re-calibration: after N shardable launches the
-                # pinned decision is archived and a new trial re-measures,
-                # from THIS launch on — burstable hosts that gained (or
-                # lost) capacity re-pin
-                self._launches_since_cal += 1
-                if self._launches_since_cal >= self._recal_interval:
-                    if self._host_pool_probe is not None:
-                        self._host_pool_probe_prev = dict(
-                            self._host_pool_probe
-                        )
-                    decision = self._pool_decision = None
-                    self._launches_since_cal = 0
-                    self._recal_pending = True
-            if decision is None:
-                trial = self._pool_trial
-                if trial is None:
-                    trial = self._pool_trial = host_pool.LaunchTrial()
-                if trial.exhausted:
-                    # its launches never came back as samples (fused
-                    # tickets, one-shot costs): keep the inline path
-                    self._conclude_trial(trial)
-                    decision = self._pool_decision
-                else:
-                    decision = trial.next_arm()
-                    launch._trial = host_pool.TrialSample(trial, decision)
-        if decision != "sharded":
-            return False  # measured: no real win on this box
-        if backend is not None and self._force_mode is None:
-            self.governor.note_posture(governor.COLUMNAR_BACKEND, backend)
-        if self._run_sharded(launch, plan, all_batches, counts, parts, use_host):
-            return True
-        if launch._trial is not None:
-            # degraded to the inline path: not a sample of this arm
-            self._trial_dropped(launch)
-        return False
-
-    def _run_sharded(
-        self, launch: _Launch, plan, all_batches, counts, parts, use_host
-    ) -> bool:
-        """The sharded road of one launch; False degrades it to the inline
-        path (a faulted shard worker: nothing was emitted yet)."""
-        pool = self._host_pool
-        breaker_demoted_rows = 0
-        if plan.mode == "columnar" and plan.dev_cols and use_host is False:
-            if not self._breaker.allow_device():
-                # open breaker demotes the whole sharded launch to the
-                # exact numpy predicate (identical bits per shard). Rows
-                # are COUNTED only after the fan-out commits: a shard
-                # fault degrades this launch to the inline path, which
-                # counts its own demotion — counting here too would
-                # double n_fallback_rows for the same records.
-                use_host = True
-                breaker_demoted_rows = sum(counts)
-        if plan.mode == "columnar":
-            if use_host is False:
-                # compile in THIS thread before fan-out: plan._fn_cache is
-                # a plain dict and first-touch jit takes seconds — shard
-                # workers must find the function already cached
-                plan.compile_device(None)
-            paths = plan.flat_paths()
-            # parse ladder decided ONCE per launch (may probe, inline, on
-            # the first representative launch) — shard workers must not
-            # race the calibration or mix ladders within a launch
-            structural = self._parse_path(plan, all_batches) == "structural"
-            t0 = _stage_t0("t_sharded_dispatch")
-            try:
-                shards = pool.run([
-                    (
-                        lambda i=i, s=s, e=e: self._run_columnar_shard(
-                            i, launch, plan, all_batches[s:e], paths,
-                            use_host, structural
-                        )
-                    )
-                    for i, (s, e) in enumerate(parts)
-                ])
-            except Exception as exc:
-                # fail closed per-launch: a faulted shard worker degrades
-                # this launch to the inline path, which re-executes every
-                # stage launch-wide from the original batches (exact output,
-                # nothing lost or duplicated — nothing was emitted yet).
-                # Sibling shards may have already enqueued device masks:
-                # abandon them, or each orphan costs the harvester a full
-                # envelope and feeds the breaker verdicts for a launch
-                # that no longer exists.
-                faults.note_failure(
-                    faults.SHARD_WORKER, exc, reraise_programming=True
-                )
-                self._abandon_pending_masks(launch)
-                return False
-            self._stat_stage("t_sharded_dispatch", t0)
-            if breaker_demoted_rows:
-                self._count_fallback(breaker_demoted_rows)
-            launch._shards = shards
-            launch.r_out = plan.r_out
-            n = 0
-            ranges: list[tuple[int, int]] = []
-            for shard in shards:
-                ranges.extend((a + n, b + n) for a, b in shard.ranges)
-                n += shard.n
-            launch.ranges = ranges
-            launch.n = n
-        else:
-            # payload/host plans: only explode is per-record host work at
-            # dispatch; shard it and merge back into one launch-wide table
-            # (merge_exploded rebases offsets/ranges) so the existing
-            # device staging / host materialize paths run unchanged.
-            t0 = _stage_t0("t_explode")
-            try:
-                exploded = batch_codec.merge_exploded(
-                    pool.run([
-                        (lambda s=s, e=e: _explode_shard(all_batches[s:e]))
-                        for s, e in parts
-                    ])
-                )
-            except Exception as exc:
-                faults.note_failure(
-                    faults.SHARD_WORKER, exc, reraise_programming=True
-                )
-                return False  # degrade this launch to the inline path
-            self._stat_stage("t_explode", t0)
-            launch.ranges = exploded.ranges
-            n = len(exploded.sizes)
-            launch.n = n
-            if plan.mode == "payload":
-                self._dispatch_payload(launch, exploded, n)
-            else:
-                launch._exploded = exploded
-        self._stat_add("n_records", n)
-        self._stat_add("n_launches", 1)
-        self._stat_add("n_sharded_launches", 1)
-        with self._stats_lock:  # HdrHist isn't thread-safe
-            probes.coproc_launch_rows_hist.record(n)
-            if plan.mode == "columnar":
-                for shard in launch._shards:
-                    probes.coproc_shard_rows_hist.record(shard.n)
-                self.last_launch_shards = [
-                    {"rows": shard.n, **shard.stages} for shard in launch._shards
-                ]
-            else:
-                for s, e in parts:
-                    probes.coproc_shard_rows_hist.record(sum(counts[s:e]))
-        return True
 
     def _count_colcache(self, hit: bool) -> None:
         if hit:
@@ -2605,8 +2114,8 @@ class TpuEngine:
             probes.coproc_colcache_misses.inc()
 
     def _shard_cache_key(self, script_id: int, batches) -> tuple | None:
-        """Per-shard column-cache key (cross-launch cache for the sharded
-        path, ROADMAP item 1 follow-on c): the SAME content fingerprint as
+        """Per-shard column-cache key (cross-launch cache for the mesh
+        lane, ROADMAP item 1 follow-on c): the SAME content fingerprint as
         the launch-wide key, over the shard's batch slice. Contiguous
         range shards of a repeating launch produce identical slices, so
         every shard of the second identical launch hits."""
@@ -2618,9 +2127,7 @@ class TpuEngine:
         self, shard: _HostShard, plan: ColumnarPlan, cols, n_pad: int,
         structural: bool,
     ) -> "colcache.Entry":
-        """The per-shard cache entry for a just-run ladder — ONE builder
-        so the mesh and standard sharded paths can never cache divergent
-        contents for the same shard."""
+        """The per-shard cache entry for a just-run ladder."""
         return colcache.Entry(
             n=shard.n, n_pad=n_pad, ranges=shard.ranges, cols=cols,
             proj_data=shard.proj_data, proj_ok=shard.proj_ok,
@@ -2664,7 +2171,7 @@ class TpuEngine:
             # shards run concurrently: summing their durations into the
             # launch-wall t_* keys would inflate those ~workers-fold, so
             # per-shard time lands under t_shard_* (CPU-seconds across
-            # workers); the fan-out's wall time is t_sharded_dispatch.
+            # workers); the fan-out's wall time is t_mesh_ladder.
             # _stat_stage mirrors the slice into the pandapulse timeline
             # under the same t_shard_* name (one clock read, shared dt).
             dt = self._stat_stage(
@@ -2748,121 +2255,6 @@ class TpuEngine:
             shard.exploded = None  # framing reads proj_data, not raw records
             stage("t_extract_proj", t0)
         return cols, (n_pad or 0)
-
-    def _run_columnar_shard(
-        self, idx: int, launch: _Launch, plan: ColumnarPlan, batches, paths,
-        use_host, structural: bool = False,
-    ) -> _HostShard:
-        """One shard's dispatch-side host stages, on a pool worker:
-        per-shard column-cache consult (a hit skips the whole ladder),
-        explode + find, predicate column extraction, predicate dispatch
-        (the shard's own device launch or numpy eval — issued as soon as
-        THIS shard's columns land, overlapping later shards' extraction),
-        projection extraction, cache populate. ``structural`` runs the
-        shard through the fused structural ladder instead (same outputs;
-        the engine-level decision is per launch). Touches only its own
-        shard (SHD6xx)."""
-        shard = _HostShard()
-        t_shard0 = time.perf_counter()
-        # shard-worker fault domain: a fault here (injected or real) fails
-        # the fan-out, and _dispatch_sharded degrades the LAUNCH to the
-        # inline path — stages re-execute launch-wide with exact output
-        faults.inject(faults.SHARD_WORKER)
-        key = self._shard_cache_key(launch.script_id, batches)
-        entry = None
-        dev_cols = None
-        store_entry = None
-        if key is not None:
-            entry = self._colcache.lookup(key)
-            self._count_colcache(entry is not None)
-        if entry is not None:
-            n_pad = _bucket_rows(entry.n) if entry.n else 0
-            cols = self._shard_from_entry(shard, plan, entry, n_pad)
-            if entry.cols_dev is not None and entry.n_pad == n_pad:
-                dev_cols = entry.cols_dev
-        else:
-            cols, n_pad = self._shard_ladder(
-                shard, plan, batches, paths, structural,
-                trace_id=launch.trace_id,
-            )
-            if key is not None and shard.n and cols is not None:
-                store_entry = self._shard_cache_entry(
-                    shard, plan, cols, n_pad, structural
-                )
-        n = shard.n
-        if n == 0:
-            return shard
-        if cols is not None:
-            slot = _MaskSlot(n)
-            slot.trace_id = launch.trace_id
-            t0 = _stage_t0("t_shard_dispatch")
-            if use_host:
-                slot._mask_np = plan.eval_host_mask(cols)
-                dt = self._stat_stage(
-                    "t_shard_dispatch", t0, trace_id=launch.trace_id
-                )
-                shard.stages["t_dispatch"] = round(
-                    shard.stages.get("t_dispatch", 0.0) + dt, 6
-                )
-            else:
-                def leg():
-                    faults.inject(faults.DEVICE_DISPATCH)
-                    fn = plan.compile_device(None)
-                    args = dev_cols
-                    if args is None:
-                        if store_entry is not None:
-                            # explicit device_put so the shard's cache
-                            # entry owns committed device arrays — later
-                            # hits launch with zero H2D (the PR-11 device
-                            # residency, now per shard)
-                            import jax
-
-                            args = [jax.device_put(c) for c in cols]
-                            store_entry.cols_dev = args
-                        else:
-                            args = cols
-                    mask = fn(*args)
-                    mask.copy_to_host_async()
-                    return mask
-
-                mask = self._try_device_leg(
-                    faults.DEVICE_DISPATCH, leg,
-                    program=(launch.script_id, "predicate", n_pad),
-                )
-                dt = self._stat_stage(
-                    "t_shard_dispatch", t0, trace_id=launch.trace_id
-                )
-                shard.stages["t_dispatch"] = round(
-                    shard.stages.get("t_dispatch", 0.0) + dt, 6
-                )
-                if mask is None:
-                    # this shard's exact host fallback; sibling shards keep
-                    # their own device launches
-                    slot._mask_np = plan.eval_host_mask(cols)
-                    self._count_fallback(n)
-                else:
-                    self._breaker.record_success()  # dispatch-domain verdict
-                    if dev_cols is None:
-                        self._stat_add(
-                            "bytes_h2d", sum(c.nbytes for c in cols)
-                        )
-                    self._stat_add("bytes_d2h", n_pad // 8)
-                    slot._cols = cols
-                    self._enqueue_mask(slot, mask, shard_of=launch)
-            shard.mask = slot
-        if store_entry is not None:
-            # put AFTER the dispatch leg so a populated entry carries its
-            # device-resident twins when the device path is live
-            self._colcache.put(key, store_entry)
-        tracer.record(
-            "coproc.shard",
-            (time.perf_counter() - t_shard0) * 1e6,
-            launch.trace_id,
-            start_perf=t_shard0,
-            shard=idx,
-            rows=n,
-        )
-        return shard
 
     # ------------------------------------------------------ mesh dispatch
     def _dispatch_mesh(self, launch: _Launch, plan, all_batches) -> bool:
@@ -3216,16 +2608,13 @@ class TpuEngine:
         keeps the matrix road for everything)."""
         return self._gather_frame and plan.byte_identity
 
-    def _enqueue_mask(self, slot, mask, shard_of: _Launch | None = None) -> None:
-        """Hand a dispatched device mask (a launch's, or a shard slot's of
-        the launch ``shard_of``) to the harvester thread, which pays its
-        D2H round trip while the caller keeps doing host work."""
+    def _enqueue_mask(self, slot, mask) -> None:
+        """Hand a launch's dispatched device mask to the harvester thread,
+        which pays its D2H round trip while the caller keeps doing host
+        work."""
         slot._mask_dev = mask
         slot._mask_event = threading.Event()
         slot._mask_state = "queued"
-        if shard_of is not None:
-            with shard_of._lock:
-                shard_of._pending_slots.append(slot)
         self._ensure_harvester()
         slot._enq_t = time.perf_counter()
         self._harvest_q.put(slot)
@@ -3454,7 +2843,6 @@ class TpuEngine:
         probes cannot grow threads."""
         import time as _t
 
-        self._note_spoiler()
         t0 = _t.perf_counter()
         plan.eval_host_mask(cols)
         t_host = _t.perf_counter() - t0

@@ -1,10 +1,10 @@
 """The coproc governor: one decision plane for every adaptive choice.
 
-The engine carries a family of measured probes — host-pool calibration (+
-periodic recal), the columnar device-vs-host backend probe, the device_lz4
-keep-or-kill probe, the circuit breakers, the harvest framing path and the
-sharded-seal engagement — and before this module each made its call in its
-own corner: a self-demoted pool or a tripped breaker could silently halve
+The engine carries a family of measured probes — the columnar
+device-vs-host backend probe, the parse-ladder probe, the mesh-vs-single
+calibration, the device_lz4 keep-or-kill probe, the circuit breakers and
+the harvest framing path — and before this module each made its call in its
+own corner: a self-demoted lane or a tripped breaker could silently halve
 the headline rb/s with no forensic trail beyond scattered stats keys. The
 governor routes every such decision through ONE policy surface:
 
@@ -55,17 +55,15 @@ from redpanda_tpu.observability import probes
 logger = logging.getLogger("rptpu.coproc.governor")
 
 # ------------------------------------------------------------ decision domains
-HOST_POOL = "host_pool"
 COLUMNAR_BACKEND = "columnar_backend"
 DEVICE_LZ4 = "device_lz4"
 BREAKER = "breaker"
 HARVEST_PATH = "harvest_path"
-SHARDED_SEAL = "sharded_seal"
 DEADLINE = "deadline"
 # structural-index parse: the engine's measured fused-vs-staged probe
 # (staged = scalar rp_explode_find ladder + per-column gathers; structural
 # = rp_explode_find2 + one fused extraction crossing) journals its pick
-# here — slower boxes self-demote honestly, same posture as host_pool
+# here — slower boxes self-demote honestly (PROBE_MARGIN)
 PARSE_PATH = "parse_path"
 # device-resident column cache (coproc/colcache.py): budget/eviction
 # pressure notes land here when the cache has to shed entries
@@ -96,10 +94,14 @@ ADMISSION = "admission"
 TREND = "trend"
 
 DOMAINS = (
-    HOST_POOL, COLUMNAR_BACKEND, DEVICE_LZ4, BREAKER, HARVEST_PATH,
-    SHARDED_SEAL, DEADLINE, PARSE_PATH, COLUMN_CACHE, LOCKWATCH,
-    LEAKWATCH, MESH, ADMISSION, TREND,
+    COLUMNAR_BACKEND, DEVICE_LZ4, BREAKER, HARVEST_PATH, DEADLINE,
+    PARSE_PATH, COLUMN_CACHE, LOCKWATCH, LEAKWATCH, MESH, ADMISSION, TREND,
 )
+
+# A measured A/B (parse ladder, mesh vs single device, the raft device
+# plane) pins the new road only when it beats the known one by this
+# ratio: a borderline reading keeps the predictable path.
+PROBE_MARGIN = 1.25
 
 # fault domains that get their own breaker + adaptive deadline. Each
 # deadline derives from the domain's SUCCESS-ONLY device-leg histogram
@@ -134,11 +136,9 @@ _AUTOTUNE_SHRINK_FRAC = 0.8
 
 # posture verdict -> gauge value per domain (unknown/undecided = -1)
 _STATE_ENCODING: dict[str, dict[str, float]] = {
-    HOST_POOL: {"inline": 0.0, "sharded": 1.0},
     COLUMNAR_BACKEND: {"host": 0.0, "device": 1.0},
     DEVICE_LZ4: {"host": 0.0, "device": 1.0},
     HARVEST_PATH: {"padded": 0.0, "gather": 1.0},
-    SHARDED_SEAL: {"inline": 0.0, "sharded": 1.0},
     PARSE_PATH: {"staged": 0.0, "structural": 1.0},
     MESH: {"single": 0.0, "mesh": 1.0},
 }
@@ -891,11 +891,9 @@ class Governor:
             modes = dict(self._posture_modes)
         return {
             "engine": self.engine_tag,
-            HOST_POOL: modes.get(HOST_POOL),
             COLUMNAR_BACKEND: modes.get(COLUMNAR_BACKEND),
             DEVICE_LZ4: modes.get(DEVICE_LZ4),
             HARVEST_PATH: modes.get(HARVEST_PATH),
-            SHARDED_SEAL: modes.get(SHARDED_SEAL),
             PARSE_PATH: modes.get(PARSE_PATH),
             MESH: modes.get(MESH),
             ADMISSION: modes.get(ADMISSION),

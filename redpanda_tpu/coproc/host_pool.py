@@ -1,34 +1,27 @@
-"""Host-stage worker pool: per-core sharding of the engine's record stages.
+"""Host-stage worker pool: the mesh lane's per-device host ladders.
 
-A CPU-sandbox bench capture made the bottleneck explicit: with the device
-predicate leg down to ~2% of stage wall time, the engine is bound by
-SINGLE-THREADED host
-stages — ``t_explode_find`` alone is ~57% and projection extraction another
-~26%. Every one of those stages is a ctypes crossing (GIL released) or a
-bulk numpy pass over **disjoint record ranges**, which is the classic
-vectorized-execution sharding setup (MonetDB/X100 style) and the per-core
-analogue of the reference's per-shard pacemaker fibers
-(coproc/pacemaker.h:41-145): partition a launch's batches into contiguous
-shards, run every per-record stage per shard on a small thread pool, and
-merge index tables by rebasing.
+A mesh launch (engine._dispatch_mesh) splits its batches into one
+contiguous, record-count-balanced range per device; each device's parse /
+extract ladder, and at harvest its assembly + framing
+(_Launch._framed_sharded), is a ctypes crossing (GIL released) or a bulk
+numpy pass over a **disjoint record range**, so the ranges run concurrently
+on a small thread pool and their tables merge by rebasing. A single-device
+launch has one host road, on its dispatching thread, and the engine builds
+no pool for it.
 
 This module owns only the generic machinery — the pool itself and the
-contiguous, record-count-balanced batch partitioner. What runs per shard
-(explode/find, column extraction, projection, framing) is the engine's
-business (engine._dispatch_sharded / _Launch._framed_sharded).
+contiguous batch partitioner. What runs per shard is the engine's business.
 
 Sizing: ``coproc_host_workers`` (config/properties.py), default
-``min(4, os.cpu_count())``; ``0`` (or 1) keeps today's inline path — the
-pool only exists at >= 2 workers. Observability: every task ticks the
+``min(4, os.cpu_count())``; the pool only exists at >= 2 workers and with
+a mesh runner. Observability: every task ticks the
 ``coproc_host_pool_busy_workers`` gauge (observability/probes.py) and the
-engine records ``coproc_shard_rows`` per shard, so traceview and /metrics
-show the fan-out.
+engine records ``coproc_shard_rows`` per device shard.
 """
 
 from __future__ import annotations
 
 import os
-import statistics
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -44,113 +37,13 @@ def default_host_workers() -> int:
     return min(4, os.cpu_count() or 1)
 
 
-# The measured inline/sharded ratio must clear this margin before the
-# engine pins the pool on (see LaunchTrial): a real 2-core box shards the
-# explode stage ~1.8x faster; a quota-limited box advertising CPUs it
-# doesn't have measures <= 1.0 with scheduler-thrash tails. Requiring a
-# real win also keeps borderline boxes (whose burst capacity comes and
-# goes) on the predictable inline path.
-PROBE_MARGIN = 1.25
-
-# Whole launches a trial times on each arm before it decides, and the
-# launches it may spend in all (samples that met a one-shot cost, a
-# fallback or a fused ticket are dropped) before it gives up and keeps
-# the inline path.
-TRIAL_LAUNCHES = 5
-TRIAL_MAX_LAUNCHES = 40
-
-
-class TrialSample:
-    """One launch of a trial, from its dispatch to its harvest: which
-    trial, which road, and the seconds its dispatch took."""
-
-    __slots__ = ("trial", "arm", "dispatch_s")
-
-    def __init__(self, trial: "LaunchTrial", arm: str):
-        self.trial = trial
-        self.arm = arm
-        self.dispatch_s = 0.0
-
-
-class LaunchTrial:
-    """The pool's on/off decision, taken on what it governs.
-
-    The decision switches a whole launch between two roads: inline, or
-    per-shard stages on the pool, the merge of their tables and the sharded
-    seal. An explode timed alone (the old probe) says little about that: on
-    the chip's host it read speedups of 0.46-2.2 while every run that chose
-    ``sharded`` was slower end to end. So the first shardable launches of a
-    process run alternately inline and sharded (the two are bit-identical,
-    no work is wasted), each timed whole on the engine's clock, dispatch
-    plus harvest up to the sealed reply, per row; the medians are compared
-    and sharded must win by PROBE_MARGIN. The periodic re-calibration is a
-    new trial.
-
-    Bookkeeping only: the engine owns the clock, the lock and the journal.
-    """
-
-    ARMS = ("inline", "sharded")
-
-    def __init__(self):
-        self.samples: dict[str, list[float]] = {arm: [] for arm in self.ARMS}
-        self.issued = 0
-        self.dropped = 0
-        self.per_arm = TRIAL_LAUNCHES
-
-    def next_arm(self) -> str:
-        """The road the next shardable launch takes: the arm with fewer
-        samples, alternating from inline while they are level (launches in
-        flight have not sampled yet)."""
-        self.issued += 1
-        n_inline, n_sharded = (len(self.samples[arm]) for arm in self.ARMS)
-        if n_inline != n_sharded:
-            return self.ARMS[n_inline > n_sharded]
-        return self.ARMS[(self.issued - 1) % 2]
-
-    def add(self, arm: str, seconds: float, rows: int) -> None:
-        self.samples[arm].append(seconds * 1e6 / max(rows, 1))
-
-    def drop(self) -> None:
-        self.dropped += 1
-
-    @property
-    def complete(self) -> bool:
-        return all(len(v) >= self.per_arm for v in self.samples.values())
-
-    @property
-    def exhausted(self) -> bool:
-        return self.issued >= TRIAL_MAX_LAUNCHES
-
-    def verdict(self) -> dict:
-        """What was measured and what it chose; an incomplete trial keeps
-        the inline path and says so."""
-        out = {
-            "measured": "whole launches, dispatch to sealed reply, us a row",
-            "launches": {arm: len(v) for arm, v in self.samples.items()},
-            "dropped": self.dropped,
-        }
-        if not self.complete:
-            return dict(out, incomplete=True, chosen="inline")
-        inline, sharded = (
-            statistics.median(self.samples[arm]) for arm in self.ARMS
-        )
-        ratio = inline / sharded if sharded > 0 else 0.0
-        return dict(
-            out,
-            inline_us_per_row=round(inline, 4),
-            sharded_us_per_row=round(sharded, 4),
-            speedup=round(ratio, 3),
-            chosen="sharded" if ratio >= PROBE_MARGIN else "inline",
-        )
-
-
 def measure_parallel_capacity(workers: int = 2) -> dict:
     """Diagnostic: do GIL-releasing numpy tasks actually run concurrently
     here? ``os.cpu_count()`` lies on quota-limited boxes, so
-    tools/microbench.py reports this next to the pool-scaling numbers.
-    NOTE this synthetic answer is context only — the engine calibrates on
-    its REAL launches (LaunchTrial; burstable hosts can pass a
-    millisecond-scale synthetic probe and still thrash on sustained work).
+    tools/microbench.py reports this next to the mesh-scaling numbers.
+    NOTE this synthetic answer is context only — the mesh calibration
+    times its REAL launches (burstable hosts can pass a millisecond-scale
+    synthetic probe and still thrash on sustained work).
     Returns {'speedup', 'workers'}; best-of-3 on both sides."""
     workers = max(2, int(workers))
 
@@ -217,15 +110,15 @@ def partition_counts(counts: list[int], n_shards: int) -> list[tuple[int, int]]:
 
 
 class HostStagePool:
-    """A named thread pool for the engine's per-shard host stages.
+    """A named thread pool for the mesh lane's per-shard host stages.
 
-    Threads, not processes: the sharded stages spend their time inside
+    Threads, not processes: the per-shard stages spend their time inside
     ctypes calls (GIL dropped for the whole crossing), zlib/lz4
     decompression, or wide numpy kernels — real parallelism without
     pickling record payloads across a process boundary.
 
-    The executor is created lazily (an engine configured with workers but
-    never fed a shardable launch costs nothing) and torn down by
+    The executor is created lazily (a mesh engine that never launches on
+    the mesh costs nothing) and torn down by
     interpreter exit like any ThreadPoolExecutor; engines are long-lived
     process singletons in the broker (one per CoprocApi).
     """
@@ -240,10 +133,9 @@ class HostStagePool:
     def _submit_all(self, fns: list) -> list:
         # locked check-then-create: concurrent first launches must not
         # each build (and leak) an executor. The submits stay under the
-        # lock too: a trial's verdict may shut the pool down (shutdown())
-        # while another launch of the trial is still fanning out, and an
-        # executor that is shut down refuses new work (work it already
-        # holds still runs)
+        # lock too: shutdown() may land while a launch is still fanning
+        # out, and an executor that is shut down refuses new work (work
+        # it already holds still runs)
         with self._lock:
             if self._executor is None:
                 self._executor = ThreadPoolExecutor(

@@ -20,7 +20,7 @@ that shape end to end; this module promotes it into the product path:
   ``raft/device_plane.py`` behind its own measured probe.
 
 Mesh-vs-single-device is a MEASURED, journaled governor decision (domain
-``mesh``, ``host_pool.PROBE_MARGIN`` posture: the mesh must show a real
+``mesh``, ``governor.PROBE_MARGIN`` posture: the mesh must show a real
 win over the known single-device path before it pins). The
 ``mesh_dispatch`` fault domain gives the mesh its own circuit breaker —
 a flaky mesh path demotes mesh launches to the bit-identical
@@ -39,7 +39,7 @@ import time
 import numpy as np
 
 from redpanda_tpu.coproc import host_pool, lockwatch
-from redpanda_tpu.coproc.governor import MESH
+from redpanda_tpu.coproc.governor import MESH, PROBE_MARGIN
 from redpanda_tpu.observability import probes
 
 logger = logging.getLogger("rptpu.coproc.meshrunner")
@@ -178,7 +178,7 @@ class MeshRunner:
         representative launch's own columns: the SAME predicate over the
         SAME bytes, once as the stacked SPMD program over the mesh and
         once as the single-device program over the concatenated columns.
-        The mesh must win by ``host_pool.PROBE_MARGIN`` — on co-located
+        The mesh must win by ``governor.PROBE_MARGIN`` — on co-located
         multi-chip ICI it does by construction, on a 1-core host-platform
         mesh it honestly self-demotes. Returns the decision."""
         with self._decision_lock:
@@ -241,7 +241,7 @@ class MeshRunner:
             )
             return "single"
         ratio = t_single / t_mesh if t_mesh > 0 else 0.0
-        decision = "mesh" if ratio >= host_pool.PROBE_MARGIN else "single"
+        decision = "mesh" if ratio >= PROBE_MARGIN else "single"
         probe = {
             "t_single_ms": round(t_single * 1e3, 3),
             "t_mesh_ms": round(t_mesh * 1e3, 3),
@@ -258,7 +258,7 @@ class MeshRunner:
             decision,
             f"measured predicate leg: single-device {t_single * 1e3:.3f} ms"
             f" vs {self.n_devices}-device mesh {t_mesh * 1e3:.3f} ms (mesh "
-            f"must win {host_pool.PROBE_MARGIN}x; engine-sticky)",
+            f"must win {PROBE_MARGIN}x; engine-sticky)",
             dict(probe),
         )
         return decision

@@ -411,7 +411,7 @@ class Pacemaker:
         self._flush_task: asyncio.Task | None = None
         self._materialized_locks: dict[NTP, asyncio.Lock] = {}
         # Dedicated executor for engine submit/harvest: these block for a
-        # whole launch (sharded host stages + a device round trip), and on
+        # whole launch (host stages + a device round trip), and on
         # the loop's DEFAULT executor they would starve every
         # asyncio.to_thread user in the broker (storage/archival blocking
         # I/O shares that pool). Lazily created; sized like the default

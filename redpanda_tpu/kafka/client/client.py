@@ -130,6 +130,11 @@ class BrokerConnection:
         corr = next(self._correlation)
         header = RequestHeader(api_key, v, corr, self.client_id)
         payload = header.encode(api.is_flexible(v)) + encode_message(api, "request", body, v)
+        if self._recv_task is None or self._recv_task.done():
+            # the receive loop has ended (peer gone: a killed broker leaves
+            # the socket half-open, so the write below would still succeed)
+            # and nothing would ever complete this future
+            raise ConnectionError("connection lost")
         fut = asyncio.get_running_loop().create_future()
         self._inflight[corr] = (fut, api, v)
         async with self._lock:

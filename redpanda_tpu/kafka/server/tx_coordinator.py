@@ -286,7 +286,13 @@ class TxCoordinator:
         if md.state == TxState.prepare_abort and commit:
             return E.invalid_txn_state
         if md.state in (TxState.complete_commit, TxState.complete_abort):
-            return E.invalid_txn_state
+            # a retry of an EndTxn whose first attempt came back retriable
+            # (a marker or the offset fold failed; state stayed prepare_*)
+            # may find that the 1 Hz re-drive finished it meanwhile: the
+            # same direction is that success, the other one is an error
+            # (Kafka's TransactionCoordinator answers the same)
+            done_commit = md.state == TxState.complete_commit
+            return E.none if done_commit == commit else E.invalid_txn_state
         if md.state == TxState.empty and not md.partitions and not md.staged_offsets:
             return E.none  # nothing to do; kafka allows the no-op commit
         return await self._finish(md, commit)

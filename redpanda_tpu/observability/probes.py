@@ -113,7 +113,7 @@ coproc_launch_rows_hist = registry.histogram(
 )
 coproc_shard_rows_hist = registry.histogram(
     "coproc_shard_rows",
-    "Records per host-stage shard (coproc_host_workers fan-out)",
+    "Records per device shard of a mesh launch",
 )
 # Harvest framing path, per framing crossing (launch- or shard-level):
 # gather = zero-copy framing straight from the joined blob's (offset, len)
@@ -229,7 +229,7 @@ coproc_leakwatch_imbalance = registry.counter(
 # multi-engine tests.
 
 # ------------------------------------------------------ host-stage pool
-# Busy-worker gauge for the coproc host-stage pool (coproc/host_pool.py).
+# Busy-worker gauge for the mesh lane's host-stage pool (coproc/host_pool.py).
 # The counter lives HERE, not on the pool: the gauge must be registered
 # exactly once per process while pools are per-engine, and probes already
 # owns the process-wide registry. inc/dec under a lock — += on an int is

@@ -12,7 +12,7 @@ bits tallied per group by a single mesh psum.
 Where that program runs is a MEASURED decision, exactly like the coproc
 engine's probes: the first representative validation times the device
 step against the host ``crc32c_many`` oracle on the same rows and the
-process keeps the winner (``host_pool.PROBE_MARGIN`` posture, journaled
+process keeps the winner (``governor.PROBE_MARGIN`` posture, journaled
 in the governor's ``mesh`` domain). Which side wins on a local chip is not
 measured yet (ROADMAP B8). Either backend is bit-exact — ``validate`` and ``tally_votes`` return
 identical arrays, only the executor changes.
@@ -31,7 +31,7 @@ import time
 
 import numpy as np
 
-from redpanda_tpu.coproc import host_pool
+from redpanda_tpu.coproc import governor as gov_mod
 from redpanda_tpu.hashing.crc32c import crc32c, crc32c_many
 
 logger = logging.getLogger("rptpu.raft.device_plane")
@@ -152,8 +152,6 @@ class RaftDevicePlane:
     def _calibrate(self, regions, rows, lens, claimed) -> str:
         """Host-vs-device pin on representative rows; journaled (mesh
         domain) so ``rpk debug governor`` reconstructs the choice."""
-        from redpanda_tpu.coproc import governor as gov_mod
-
         try:
             # time the host leg that actually SERVES a "host" pin
             # (_host_validate, unpadded per-region crcs) — measuring
@@ -196,7 +194,7 @@ class RaftDevicePlane:
             )
             return "host"
         ratio = t_host / t_dev if t_dev > 0 else 0.0
-        decision = "device" if ratio >= host_pool.PROBE_MARGIN else "host"
+        decision = "device" if ratio >= gov_mod.PROBE_MARGIN else "host"
         probe = {
             "t_host_ms": round(t_host * 1e3, 3),
             "t_device_ms": round(t_dev * 1e3, 3),
@@ -213,7 +211,7 @@ class RaftDevicePlane:
             decision,
             f"raft batched CRC/vote probe: host {t_host * 1e3:.3f} ms vs "
             f"device ({self.n_devices} dev) {t_dev * 1e3:.3f} ms (device "
-            f"must win {host_pool.PROBE_MARGIN}x; process-sticky)",
+            f"must win {gov_mod.PROBE_MARGIN}x; process-sticky)",
             dict(probe),
         )
         return decision

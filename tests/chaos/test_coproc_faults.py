@@ -25,7 +25,6 @@ from redpanda_tpu.coproc import (
     ProcessBatchRequest,
     EnableResponseCode,
 )
-from redpanda_tpu.coproc import engine as engine_mod
 from redpanda_tpu.coproc import faults
 from redpanda_tpu.coproc.engine import ProcessBatchItem
 from redpanda_tpu.finjector import honey_badger
@@ -67,14 +66,13 @@ _live_engines: list[TpuEngine] = []
 
 
 @pytest.fixture(autouse=True)
-def _fast_faults(monkeypatch):
-    """Chaos must finish inside CI budgets: short wedges and delays, the
-    pool engaged at test-sized launches, and a guaranteed-clean badger.
+def _fast_faults():
+    """Chaos must finish inside CI budgets: short wedges and delays, and
+    a guaranteed-clean badger.
     Teardown also SHUTS DOWN every engine the test created: this file runs
     early in the suite (inside the chaos package, before the in-process
     cluster tests), and leaked daemon harvesters pin engines — plans, jit
     executables, pool threads — for the rest of the run."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 64)
     saved_wedge = honey_badger.wedge_max_s
     saved_delay = honey_badger.delay_ms
     honey_badger.wedge_max_s = 0.12
@@ -117,14 +115,19 @@ def _workload():
     return ProcessBatchRequest(items)
 
 
-def _engine(spec, force_mode, workers):
+def _engine(spec, force_mode, mesh_devices=0):
+    """``mesh_devices`` >= 2 pins the mesh lane (per-device ladders on a
+    4-worker pool: the one place the shard_worker probe point fires) for
+    the launches that can take it; every other launch of that engine runs
+    the single-device road."""
     engine = TpuEngine(
         row_stride=256,
         compress_threshold=10**9,
         force_mode=force_mode,
-        host_workers=workers,
-        host_pool_probe=False,  # chaos must exercise the fan-out even on
-        # boxes whose capacity calibration would demote the pool
+        host_workers=4 if mesh_devices else 0,
+        mesh_devices=mesh_devices or None,
+        mesh_backend="cpu" if mesh_devices else None,
+        mesh_probe=False,
         # Tight fault envelope so wedge runs stay fast: the per-attempt
         # deadline (60ms) sits BELOW wedge_max_s (120ms), which is what
         # forces the deadline-abandonment path a real wedged link takes.
@@ -174,23 +177,27 @@ def _total_records(reply):
     )
 
 
-@pytest.mark.parametrize("workers", [0, 4], ids=["pool_off", "pool_on"])
+@pytest.mark.parametrize("mesh_devices", [0, 2], ids=["single", "mesh"])
 @pytest.mark.parametrize(
     "mode_name,spec_fn,force_mode", MODES, ids=[m[0] for m in MODES]
 )
-def test_chaos_parity_every_probe_point(mode_name, spec_fn, force_mode, workers):
+def test_chaos_parity_every_probe_point(
+    mode_name, spec_fn, force_mode, mesh_devices, eight_devices
+):
     req = _workload()
     # ONE engine serves the whole probe x effect matrix (its breaker
     # threshold is unreachable, so no run demotes the next): in the full
     # suite this file shares the box with the package's live 3-node
     # cluster, and an engine-per-combination matrix of jit compiles
     # starves the brokers' elections
-    engine = _engine(spec_fn(), force_mode, workers)
+    engine = _engine(spec_fn(), force_mode, mesh_devices)
     baseline = _fingerprint(engine.process_batch(req))
     base_records = sum(
         bc[2] for _sid, _src, batches in baseline for bc in batches
     )
     assert base_records > 0, "workload must actually produce output"
+    if mesh_devices and force_mode == "columnar_device":
+        assert engine.stats()["n_mesh_launches"] == 1, "mesh lane must engage"
 
     honey_badger.enable()
     try:
@@ -207,7 +214,7 @@ def test_chaos_parity_every_probe_point(mode_name, spec_fn, force_mode, workers)
                     honey_badger.unset(faults.MODULE, probe)
                 got = _fingerprint(reply)
                 assert got == baseline, (
-                    f"{mode_name}/workers={workers}: output diverged under "
+                    f"{mode_name}/mesh={mesh_devices}: output diverged under "
                     f"{effect} at {probe}"
                 )
                 assert _total_records(reply) == base_records, (

@@ -27,7 +27,9 @@ TOPIC = "overload-chaos"
 
 
 def _run(coro):
-    return asyncio.run(asyncio.wait_for(coro, 300))
+    # the test's own limit: ~15-25 s on a busy box (cluster start, 5 s of
+    # flood, read-back)
+    return asyncio.run(asyncio.wait_for(coro, 120))
 
 
 async def _flood(clients, stop, acked, shed, errors, partitions):
@@ -50,10 +52,14 @@ async def _flood(clients, stop, acked, shed, errors, partitions):
     while not stop.is_set():
         for _ in range(24):  # a burst per 10ms tick: well past capacity
             key = f"k-{seq}"
-            # 4 x 4KiB records per op: the offered byte rate must dwarf
-            # the shrunken kafka_produce account so admission MUST shed
+            # one 32 KiB record (and three small ones) per op: a single
+            # 24-op burst is 0.8 MB in flight against the shrunken 256 KiB
+            # kafka_produce account, so admission MUST shed however few
+            # bursts a client starved by the rest of the suite gets to
+            # issue (at 4 KiB an op it took 64 ops in flight at once, which
+            # six xdist workers' worth of brokers never let it reach)
             values = [
-                b'{"k":"' + key.encode() + b'","pad":"' + b"x" * 4096 + b'"}'
+                b'{"k":"' + key.encode() + b'","pad":"' + b"x" * 32768 + b'"}'
             ] + [b'{"k":"%s-f%d","pad":""}' % (key.encode(), j) for j in range(3)]
             t = asyncio.create_task(
                 one(clients[seq % len(clients)], seq % partitions, key, values)

@@ -448,27 +448,46 @@ def test_starved_harvester_caller_pays_fetch_with_exact_fallback(monkeypatch):
     assert snap["breaker"]["consecutive_failures"] >= 1, "caller's verdict"
 
 
-def test_sharded_breaker_demotion_counts_fallback_once(monkeypatch):
-    """An open-breaker sharded launch that then degrades to the inline
-    path on a shard fault must count its fallback rows ONCE (the inline
-    demotion's count), not sharded-demote + inline-demote."""
-    from redpanda_tpu.coproc import engine as engine_mod
+def _mesh_shard_fault_under_open_breaker(engine):
+    # the mesh ladder faults -> the launch degrades to the single-device
+    # road, whose open dispatch breaker demotes it to the numpy predicate
+    engine._breaker.record_failure()
+    return faults.SHARD_WORKER
 
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 16)
+
+def _mesh_leg_exhausted(engine):
+    # the SPMD leg fails -> this launch's shards evaluate in numpy
+    return faults.MESH_DISPATCH
+
+
+@pytest.mark.parametrize(
+    "arm", [_mesh_shard_fault_under_open_breaker, _mesh_leg_exhausted],
+    ids=["shard_fault_under_open_breaker", "mesh_leg_exhausted"],
+)
+def test_mesh_demotion_counts_fallback_once(arm, eight_devices):
+    """A mesh launch that loses its device stage, by whichever hop, emits
+    the reference bytes and counts its fallback rows ONCE — not once per
+    degradation hop."""
+    baseline = _engine(force_mode="columnar_device").process_batch(
+        _req(parts=4, n=12)
+    )
     engine = _engine(
-        force_mode="columnar_device", host_workers=4, host_pool_probe=False,
+        force_mode="columnar_device", host_workers=2, mesh_devices=2,
+        mesh_backend="cpu", mesh_probe=False, launch_retries=0,
         breaker_threshold=1, breaker_cooldown_ms=3_600_000,
     )
-    engine._breaker.record_failure()  # trip: breaker open for the test
+    domain = arm(engine)
     honey_badger.enable()
-    honey_badger.set_exception(faults.MODULE, faults.SHARD_WORKER)
+    honey_badger.set_exception(faults.MODULE, domain)
     try:
         reply = engine.process_batch(_req(parts=4, n=12))  # 48 rows, 1 launch
     finally:
-        honey_badger.unset(faults.MODULE, faults.SHARD_WORKER)
+        honey_badger.unset(faults.MODULE, domain)
         honey_badger.disable()
-    assert reply.items[0].batches, "launch must still produce output"
-    assert engine.stats()["n_fallback_rows"] == 48.0, (
+    assert _payloads(reply) == _payloads(baseline)
+    stats = engine.stats()
+    assert stats["n_launches"] == 1 and stats.get("n_mesh_launches", 0) == 0
+    assert stats["n_fallback_rows"] == 48.0, (
         "same records counted once, not per degradation hop"
     )
 
@@ -480,12 +499,12 @@ def test_queued_mask_claim_single_fetch_single_verdict():
     one verdict, at any harvest-queue depth."""
     import time as _t
 
-    from redpanda_tpu.coproc.engine import _Launch, _MaskSlot
+    from redpanda_tpu.coproc.engine import _Launch
 
     engine = _engine(force_mode="columnar_device", device_deadline_ms=100,
                      launch_retries=0)
     expected = np.array([1, 0, 1, 1, 0, 0, 1, 0], dtype=bool)
-    slot = _MaskSlot(8)
+    slot = _Launch(1, None)
     slot._mask_dev = np.packbits(expected)
     slot._mask_event = threading.Event()  # never set: harvester never ran
     slot._mask_state = "queued"
@@ -503,11 +522,11 @@ def test_queued_mask_claim_single_fetch_single_verdict():
         def __array__(self, *a, **k):
             raise RuntimeError("orphan mask must never be fetched")
 
-    skipped = _MaskSlot(8)
+    skipped = _Launch(1, None)
     skipped._mask_dev = Bomb()
     skipped._mask_event = threading.Event()
     skipped._mask_state = "claimed"
-    probe = _MaskSlot(8)
+    probe = _Launch(1, None)
     probe._mask_dev = np.packbits(expected)
     probe._mask_event = threading.Event()
     probe._mask_state = "queued"
@@ -519,46 +538,6 @@ def test_queued_mask_claim_single_fetch_single_verdict():
     assert engine._breaker.snapshot()["consecutive_failures"] == v0
 
 
-def test_abandoned_sharded_masks_are_skipped():
-    """A sharded launch that degrades to the inline path abandons its
-    already-enqueued shard masks: the harvester must not spend envelopes
-    on them or feed their verdicts to the breaker."""
-    from redpanda_tpu.coproc.engine import _Launch, _MaskSlot
-
-    engine = _engine(force_mode="columnar_device")
-    launch = _Launch(1, None)
-    launch.engine = engine
-
-    class Bomb:
-        def __array__(self, *a, **k):
-            raise RuntimeError("abandoned mask must never be fetched")
-
-    queued = _MaskSlot(8)
-    queued._mask_dev = Bomb()
-    queued._mask_event = threading.Event()
-    queued._mask_state = "queued"
-    harvesting = _MaskSlot(8)
-    harvesting._mask_state = "harvesting"
-    launch._pending_slots = [queued, harvesting]
-    engine._abandon_pending_masks(launch)
-    assert queued._mask_state == "abandoned"
-    assert harvesting._mask_state == "harvesting", (
-        "an in-flight harvest keeps its verdict — it genuinely happened"
-    )
-    assert launch._pending_slots == []
-    v0 = engine._breaker.snapshot()["consecutive_failures"]
-    good = _MaskSlot(8)
-    good._mask_dev = np.packbits(np.ones(8, bool))
-    good._mask_event = threading.Event()
-    good._mask_state = "queued"
-    engine._ensure_harvester()
-    engine._harvest_q.put(queued)
-    engine._harvest_q.put(good)
-    assert good._mask_event.wait(10.0)
-    assert not queued._mask_event.is_set()
-    assert engine._breaker.snapshot()["consecutive_failures"] == v0
-
-
 def test_harvester_programming_error_counted_but_no_breaker_verdict():
     """A bug in our own harvest code (AssertionError et al.) must be
     visible in coproc_failures_total but must NOT demote the engine:
@@ -566,7 +545,7 @@ def test_harvester_programming_error_counted_but_no_breaker_verdict():
     bug as 'device degraded' until process restart."""
     import time as _t
 
-    from redpanda_tpu.coproc.engine import _MaskSlot
+    from redpanda_tpu.coproc.engine import _Launch
 
     engine = _engine(force_mode="columnar_device", breaker_threshold=1)
     engine._ensure_harvester()
@@ -575,7 +554,7 @@ def test_harvester_programming_error_counted_but_no_breaker_verdict():
         def __array__(self, *a, **k):
             raise AssertionError("engine bug, not a device fault")
 
-    slot = _MaskSlot(8)
+    slot = _Launch(1, None)
     slot._mask_dev = Bomb()
     slot._mask_event = threading.Event()
     slot._enq_t = _t.perf_counter()

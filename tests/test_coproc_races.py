@@ -18,7 +18,6 @@ import time
 import pytest
 
 from redpanda_tpu.coproc import EnableResponseCode, ProcessBatchRequest, TpuEngine
-from redpanda_tpu.coproc import engine as engine_mod
 from redpanda_tpu.coproc.engine import ProcessBatchItem
 from redpanda_tpu.models import NTP, Record, RecordBatch
 from redpanda_tpu.ops.exprs import field
@@ -110,59 +109,3 @@ def test_counter_inc_is_thread_exact():
     finally:
         sys.setswitchinterval(old)
     assert c.value == per_thread * n_threads
-
-
-def test_pool_decision_read_is_lock_coherent():
-    """Seal-path reads of the pool decision go through
-    _pool_decision_lock now; a concurrent recalibration archiving the
-    probe must never be observable as a torn half-updated state. Drive
-    the REAL seal path (non-empty jobs — the empty-reply early return
-    sits before the locked read) while a writer flips the decision."""
-    engine = TpuEngine(
-        row_stride=128, host_workers=2, host_pool_probe=False,
-        compress_threshold=10**9,
-    )
-    try:
-        src = RecordBatch.build(
-            [Record(offset_delta=0, timestamp_delta=0, value=b"v")],
-            base_offset=0,
-            first_timestamp=1000,
-        )
-        framed = engine_mod.batch_codec.frame_ranges(
-            *_one_row(b"v"), [(0, 1)]
-        )
-        payload, kept = framed[0]
-        jobs = [(src, payload, kept)]
-        stop = threading.Event()
-
-        def flipper():
-            while not stop.is_set():
-                with engine._pool_decision_lock:
-                    engine._pool_decision = None
-                    engine._host_pool_probe = None
-                with engine._pool_decision_lock:
-                    engine._pool_decision = "sharded"
-                    engine._host_pool_probe = {"chosen": "sharded"}
-
-        t = threading.Thread(target=flipper)
-        t.start()
-        try:
-            for _ in range(200):
-                sealed = engine._seal_jobs(jobs)  # locked decision read
-                assert len(sealed) == 1
-                assert sealed[0].header.record_count == 1
-        finally:
-            stop.set()
-            t.join()
-    finally:
-        engine.shutdown()
-
-
-def _one_row(value: bytes):
-    """(rows, lens, keep) for a single kept record of `value` bytes."""
-    import numpy as np
-
-    rows = np.frombuffer(value, dtype=np.uint8).reshape(1, len(value))
-    lens = np.array([len(value)], dtype=np.int32)
-    keep = np.array([True])
-    return rows, lens, keep

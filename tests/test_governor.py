@@ -3,8 +3,8 @@
 Four sides of coproc/governor.py:
 
 - the decision journal: entries for every decision domain under real
-  launches (host-pool calibration, columnar backend probe, device_lz4
-  probe, breaker transitions, harvest-path mode, sharded-seal engagement),
+  launches (columnar backend probe, parse-ladder probe, device_lz4
+  probe, breaker transitions, harvest-path mode),
   bounded capacity, monotonic seq, per-entry inputs/verdict/reason/config;
 - adaptive deadlines: provably track the observed stage p99.9 against an
   injected histogram source, never undercut the configured static floor,
@@ -29,7 +29,6 @@ from redpanda_tpu.coproc import (
 )
 from redpanda_tpu.coproc import faults
 from redpanda_tpu.coproc import governor
-from redpanda_tpu.coproc import host_pool
 from redpanda_tpu.coproc.engine import ProcessBatchItem
 from redpanda_tpu.finjector import honey_badger
 from redpanda_tpu.models import NTP, Record, RecordBatch
@@ -104,34 +103,28 @@ def _domains():
 
 
 # ------------------------------------------------------------ decision journal
-def test_journal_covers_all_six_domains_under_real_launches(monkeypatch):
-    """Every decision domain lands in the journal from REAL code paths:
-    a big columnar launch drives the backend probe, pool calibration,
-    harvest-path and seal verdicts; an armed mask-fetch fault drives a
+def test_journal_covers_the_engine_domains_under_real_launches(monkeypatch):
+    """Every decision the engine takes lands in the journal from REAL code
+    paths: a big columnar launch drives the backend probe, the parse-ladder
+    probe and the harvest-path verdict; an armed mask-fetch fault drives a
     breaker transition; the lz4 probe drives device_lz4."""
     TpuEngine.reset_columnar_probe()
-    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", 1)
     # pure filter => passthrough plan => gather framing; 64 batches x 32
-    # records = 2048 rows clears both _PROBE_MIN_ROWS and _SHARD_MIN_ROWS
+    # records = 2048 rows clears _PROBE_MIN_ROWS
     spec = where(field("level") == "error")
     engine = TpuEngine(
-        row_stride=256, compress_threshold=10**9, host_workers=2,
-        host_pool_probe=True, host_pool_recal_launches=0, retry_backoff_ms=1,
+        row_stride=256, compress_threshold=10**9, retry_backoff_ms=1,
     )
     _live_engines.append(engine)
     assert engine.enable_coprocessors([(1, spec.to_json(), ("orders",))]) == [
         EnableResponseCode.success
     ]
     big = _req(parts=64, n=32)
-    engine.process_batch(big)  # first columnar launch: backend probe
-    assert governor.COLUMNAR_BACKEND in _domains()
-    # now shardable: the pool's A/B over whole launches, one of each road
-    engine.process_batch(big)
-    engine.process_batch(big)
+    engine.process_batch(big)  # first columnar launch: both probes
     got = _domains()
-    assert governor.HOST_POOL in got
+    assert governor.COLUMNAR_BACKEND in got
+    assert governor.PARSE_PATH in got
     assert governor.HARVEST_PATH in got
-    assert governor.SHARDED_SEAL in got
 
     # breaker transition through the real data path: a starved harvester
     # forces the caller's MASK_FETCH leg, whose armed fault trips that
@@ -156,8 +149,8 @@ def test_journal_covers_all_six_domains_under_real_launches(monkeypatch):
     got = _domains()
     assert governor.DEVICE_LZ4 in got
     for domain in (
-        governor.HOST_POOL, governor.COLUMNAR_BACKEND, governor.DEVICE_LZ4,
-        governor.BREAKER, governor.HARVEST_PATH, governor.SHARDED_SEAL,
+        governor.COLUMNAR_BACKEND, governor.PARSE_PATH, governor.DEVICE_LZ4,
+        governor.BREAKER, governor.HARVEST_PATH,
     ):
         assert domain in got, f"missing journal domain {domain}"
 
@@ -171,9 +164,9 @@ def test_journal_covers_all_six_domains_under_real_launches(monkeypatch):
         assert isinstance(e["config"], dict)
         assert e["ts"] > 0
     # engine-made decisions carry the active-config snapshot
-    cal = [e for e in entries if e["domain"] == governor.HOST_POOL][0]
+    cal = [e for e in entries if e["domain"] == governor.PARSE_PATH][0]
     assert "device_deadline_ms" in cal["config"]
-    assert cal["inputs"].get("workers") == 2
+    assert cal["inputs"].get("chosen") in ("staged", "structural")
 
 
 def test_journal_bounded_capacity_and_summary():
@@ -267,10 +260,10 @@ def test_decision_counters_by_domain_and_verdict():
     gov = governor.Governor(
         fault_policy=faults.FaultPolicy(), register_gauges=False
     )
-    key = 'coproc_governor_decisions_total{domain="sharded_seal",verdict="sharded"}'
+    key = 'coproc_governor_decisions_total{domain="harvest_path",verdict="gather"}'
     before = registry.snapshot().get(key, 0.0)
-    gov.record("sharded_seal", "sharded", "test")
-    gov.record("sharded_seal", "sharded", "test again")
+    gov.record("harvest_path", "gather", "test")
+    gov.record("harvest_path", "gather", "test again")
     assert registry.snapshot()[key] == before + 2
 
 
@@ -587,7 +580,7 @@ def test_governor_deadline_gauges_registered():
     for domain in governor.BREAKER_DOMAINS:
         assert snap[f'coproc_governor_deadline_ms{{domain="{domain}"}}'] == 1234.0
     # posture gauges exist per mode-domain, -1 while undecided
-    assert f'coproc_governor_state{{domain="host_pool"}}' in snap
+    assert f'coproc_governor_state{{domain="harvest_path"}}' in snap
 
 
 # ------------------------------------------------------------ admin surface

@@ -9,13 +9,15 @@ padded row matrix and framing from that" — pinned down from four sides:
   compressed/null-value/empty-value/zero-record batch scenarios;
 - engine level: gather-on vs gather-off engines produce bit-identical
   replies for every plan kind (passthrough filter, identity, projection,
-  uppercase, payload) × pool on/off × native on/off, with the
-  byte-mutating plans proving they stay on the padded path;
-- the sharded recompress+seal merges in input order with offsets/CRCs
-  bit-identical to the serial loop, and sealed batches survive a CRC
-  round trip through a real storage append;
-- arena reuse accounting, reset_arenas(), and the periodic host-pool
-  re-calibration hook.
+  uppercase, payload) × native on/off on the single-device road, and for
+  the columnar plans on the mesh lane's per-shard harvest at 2, 4 and 8
+  devices, with the byte-mutating plans proving they stay on the padded
+  path;
+- the reply-wide recompress+seal at the catch-up tick's shape (up to 64
+  output batches, each codec): every sealed batch decodes, carries valid
+  CRCs and holds the framed records; sealed batches survive a CRC round
+  trip through a real storage append;
+- arena reuse accounting and reset_arenas().
 """
 
 import asyncio
@@ -29,7 +31,7 @@ from redpanda_tpu.coproc import (
     ProcessBatchRequest,
     TpuEngine,
 )
-from redpanda_tpu.coproc import batch_codec, host_pool
+from redpanda_tpu.coproc import batch_codec
 from redpanda_tpu.coproc import engine as engine_mod
 from redpanda_tpu.coproc.column_plan import plan_spec
 from redpanda_tpu.coproc.engine import ProcessBatchItem
@@ -338,13 +340,18 @@ def _reply_bits(reply):
     ]
 
 
-def _run_engine(spec, force_mode, workers, gather, req):
+def _run_engine(spec, force_mode, mesh_devices, gather, req):
+    """One engine, one launch. ``mesh_devices``: 0 is the single-device
+    road; N >= 2 pins the mesh lane (its per-device ladders and per-shard
+    harvest on a 2-worker pool) for the columnar plans."""
     engine = TpuEngine(
         row_stride=256,
         compress_threshold=10**9,
         force_mode=force_mode,
-        host_workers=workers,
-        host_pool_probe=False,  # parity must exercise the fan-out
+        host_workers=2 if mesh_devices else 0,
+        mesh_devices=mesh_devices or None,
+        mesh_backend="cpu" if mesh_devices else None,
+        mesh_probe=False,  # parity needs the lane deterministically
         gather_frame=gather,
     )
     codes = engine.enable_coprocessors([(1, spec.to_json(), ("orders",))])
@@ -396,26 +403,44 @@ _MATRIX = [
 ]
 
 
+# (spec name, mesh devices): every plan kind on the single-device road;
+# the columnar plans (the only ones with a mesh stage) on the mesh lane at
+# each mesh size. The mesh runs its own SPMD predicate whatever the
+# backend pick says, so its cases leave force_mode unset (a
+# ``columnar_host`` pin declines the lane).
+_MESH_SPECS = ("passthrough_device", "projection")
+_LANES = [(m[0], 0) for m in _MATRIX] + [
+    (name, n_dev) for name in _MESH_SPECS for n_dev in (2, 4, 8)
+]
+
+
+def _lane_id(n_dev: int) -> str:
+    return "inline" if not n_dev else "mesh" if n_dev == 2 else f"mesh{n_dev}"
+
+
 @pytest.mark.parametrize("use_native", [True, False], ids=["native", "no_native"])
-@pytest.mark.parametrize("workers", [0, 4], ids=["inline", "pool"])
 @pytest.mark.parametrize(
-    "name,spec,force_mode,expect_gather",
-    _MATRIX,
-    ids=[m[0] for m in _MATRIX],
+    "name,n_dev", _LANES, ids=[f"{n}-{_lane_id(d)}" for n, d in _LANES]
 )
 def test_gather_bit_identical_to_padded(
-    name, spec, force_mode, expect_gather, workers, use_native, monkeypatch
+    name, n_dev, use_native, monkeypatch, eight_devices
 ):
     """Gather-on vs gather-off engines must agree byte-for-byte in every
-    plan kind × pool × native combination — and only byte-identity plans
-    may actually take the gather path."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
+    plan kind × lane × native combination — and only byte-identity plans
+    may actually take the gather path (launch-wide on the single road,
+    per shard in _frame_shard on the mesh lane)."""
+    _, spec, force_mode, expect_gather = next(m for m in _MATRIX if m[0] == name)
+    if n_dev:
+        force_mode = None
     if not use_native:
         monkeypatch.setattr(batch_codec, "_native", lambda: None)
     req = _matrix_request()
-    on, stats_on = _run_engine(spec, force_mode, workers, True, req)
-    off, stats_off = _run_engine(spec, force_mode, workers, False, req)
+    on, stats_on = _run_engine(spec, force_mode, n_dev, True, req)
+    off, stats_off = _run_engine(spec, force_mode, n_dev, False, req)
     assert _reply_bits(on) == _reply_bits(off)
+    if n_dev:
+        assert stats_on["n_mesh_launches"] == stats_off["n_mesh_launches"] == 1
+        assert stats_on["mesh"]["devices"] == n_dev
     if expect_gather:
         assert stats_on.get("n_frame_gather", 0.0) >= 1.0, stats_on
         assert "n_frame_padded" not in stats_on
@@ -426,16 +451,19 @@ def test_gather_bit_identical_to_padded(
     assert "n_frame_gather" not in stats_off
 
 
-def test_sharded_gather_matches_inline_gather(monkeypatch):
-    """Sharded launches gather-frame per shard; concatenated output must be
-    bit-identical to the inline gather path (extends the PR 3 suite)."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_mesh_gather_matches_inline_gather(n_dev, eight_devices):
+    """Mesh launches gather-frame per shard (_frame_shard); concatenated
+    output must be bit-identical to the launch-wide gather of the single
+    road."""
     req = _matrix_request()
-    inline, _ = _run_engine(_filter_spec(), "columnar_host", 0, True, req)
-    sharded, stats = _run_engine(_filter_spec(), "columnar_host", 4, True, req)
-    assert stats["n_sharded_launches"] >= 1
-    assert stats.get("n_frame_gather", 0.0) >= 2.0  # one per shard
-    assert _reply_bits(inline) == _reply_bits(sharded)
+    inline, stats0 = _run_engine(_filter_spec(), None, 0, True, req)
+    mesh, stats = _run_engine(_filter_spec(), None, n_dev, True, req)
+    assert stats0["n_frame_gather"] == 1 and "n_mesh_launches" not in stats0
+    assert stats["n_mesh_launches"] == 1
+    assert stats["n_frame_gather"] == n_dev  # one crossing per shard
+    assert "t_shard_frame_gather" in stats and "t_frame_gather" not in stats
+    assert _reply_bits(inline) == _reply_bits(mesh)
 
 
 # ------------------------------------------- payload mask harvest (ISSUE 27)
@@ -585,46 +613,69 @@ def test_payload_mask_harvest_drops_what_the_lane_drops():
     assert got == want and _edge_value(_STRIDE) in got and len(got) == 5
 
 
-# ------------------------------------------------------ sharded seal
-def test_sharded_seal_engages_and_matches_serial(monkeypatch):
-    """With the pool pinned on and a reply of >= _SEAL_MIN_BATCHES output
-    batches, the recompress+seal fans out (t_sharded_seal/t_shard_seal)
-    and the sealed batches are bit-identical to the workers=0 serial
-    loop — compression ON so the recompress actually runs."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    req = _matrix_request(n_items=10, n_recs=48)
-
-    def run(workers):
-        engine = TpuEngine(
-            row_stride=256,
-            compress_threshold=64,  # small: every batch recompresses
-            force_mode="columnar_host",
-            host_workers=workers,
-            host_pool_probe=False,
-            gather_frame=True,
+# ------------------------------------------------------ reply-wide seal
+def _seal_request(n_batches, per_batch=24):
+    """One request of ``n_batches`` input batches over 8 partitions: one
+    reply seals that many output batches in one _seal_jobs pass (a
+    catch-up tick seals 64)."""
+    per_item = n_batches // 8
+    return ProcessBatchRequest([
+        ProcessBatchItem(
+            1, NTP.kafka("orders", p),
+            [
+                _json_batch(
+                    per_batch + k, base_offset=1000 * p + 100 * k,
+                    empty_every=5 if k % 2 else 0,
+                )
+                for k in range(per_item)
+            ],
         )
-        engine.enable_coprocessors([(1, _filter_spec().to_json(), ("orders",))])
-        reply = engine.process_batch(req)
-        stats = engine.stats()
-        engine.shutdown()
-        return reply, stats
-
-    serial, stats0 = run(0)
-    sharded, stats4 = run(4)
-    assert "t_sharded_seal" in stats4 and "t_shard_seal" in stats4, stats4
-    assert "t_seal" in stats0 and "t_sharded_seal" not in stats0
-    assert _reply_bits(serial) == _reply_bits(sharded)
-    for it in sharded.items:  # the recompressed output really is compressed
-        for b in it.batches:
-            assert b.header.attrs != 0
+        for p in range(8)
+    ])
 
 
-def test_seal_below_threshold_stays_inline(monkeypatch):
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    req = _matrix_request(n_items=2)  # 2 slots < _SEAL_MIN_BATCHES jobs? 4 jobs
-    _, stats = _run_engine(_filter_spec(), "columnar_host", 4, True, req=req)
-    # 4 output batches < 8: the fan-out must not engage
-    assert "t_sharded_seal" not in stats
+@pytest.mark.parametrize("n_batches", [8, 64])
+@pytest.mark.parametrize(
+    "codec",
+    [Compression.none, Compression.zstd, Compression.lz4, Compression.gzip],
+    ids=lambda c: c.name,
+)
+def test_seal_round_trip_at_the_ticks_shape(codec, n_batches):
+    """Every batch the reply-wide seal emits decodes from its wire form,
+    carries valid kafka + header CRCs, is compressed with the engine's
+    codec, and holds exactly the records its input batch's filter kept —
+    in input order, one output batch per input batch, sealed in one pass
+    on the caller's thread."""
+    req = _seal_request(n_batches)
+    engine = TpuEngine(
+        row_stride=256,
+        compress_threshold=64,  # small: every batch recompresses
+        output_codec=codec,
+        force_mode="columnar_host",
+        host_workers=0,
+    )
+    engine.enable_coprocessors([(1, _filter_spec().to_json(), ("orders",))])
+    reply = engine.process_batch(req)
+    stats = engine.stats()
+    engine.shutdown()
+    assert stats["n_launches"] == 1 and "t_seal" in stats
+    n_sealed = 0
+    for item_in, item_out in zip(req.items, reply.items):
+        assert item_out.source == item_in.ntp
+        assert len(item_out.batches) == len(item_in.batches)
+        for src, out in zip(item_in.batches, item_out.batches):
+            want = [
+                r.value for r in src.records()
+                if r.value and b'"level":"error"' in r.value
+            ]
+            back, _ = RecordBatch.decode_internal(out.encode_internal())
+            assert back.verify_kafka_crc() and back.verify_header_crc()
+            assert back.header.compression == codec
+            assert back.header.record_count == len(want)
+            assert back.header.first_timestamp == src.header.first_timestamp
+            assert [bytes(v) for v in back.record_values()] == want
+            n_sealed += 1
+    assert n_sealed == n_batches
 
 
 # ------------------------------------------------------ storage round trip
@@ -682,86 +733,3 @@ def test_engine_arena_reuse_and_reset():
     st2 = engine.stats()["arena"]
     assert st2["allocs"] == 0 and st2["reuses"] == 0
     engine.shutdown()
-
-
-# ------------------------------------------------------ pool re-calibration
-def _recal_engine(monkeypatch, interval, ratios):
-    """Engine whose pool trials (one launch a road) sample the next
-    (t_inline, t_sharded) pair from `ratios`, one pair per trial."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", 1)
-    seq = list(ratios)
-    real_init = host_pool.LaunchTrial.__init__
-
-    def init(self):
-        real_init(self)
-        self.costs = dict(zip(self.ARMS, seq.pop(0)))
-
-    def add(self, arm, seconds, rows):
-        self.samples[arm].append(self.costs[arm] * 1e6)
-
-    monkeypatch.setattr(host_pool.LaunchTrial, "__init__", init)
-    monkeypatch.setattr(host_pool.LaunchTrial, "add", add)
-    engine = TpuEngine(
-        row_stride=256, compress_threshold=10**9,
-        force_mode="columnar_host", host_workers=4,
-        host_pool_recal_launches=interval,
-    )
-    engine.enable_coprocessors([(1, _filter_spec().to_json(), ("orders",))])
-    return engine
-
-
-def test_recalibration_reprobes_and_archives(monkeypatch):
-    """interval=2: launches 1-2 are the first trial (inline wins), 3-4
-    count to the interval, 4-5 are the second trial (sharded now wins) —
-    the decision flips and the first probe is archived under
-    host_pool_probe_prev."""
-    engine = _recal_engine(
-        monkeypatch, 2, [(0.010, 0.009), (0.010, 0.005)]
-    )
-    req = _matrix_request(n_items=4)
-    for _ in range(6):
-        engine.process_batch(req)
-    stats = engine.stats()
-    engine.shutdown()
-    assert stats["host_pool_probe"]["chosen"] == "sharded"
-    assert stats["host_pool_probe_prev"]["chosen"] == "inline"
-    assert stats["host_pool_recal"]["interval"] == 2
-    assert stats["n_sharded_launches"] >= 3  # one a trial, then launch 6
-
-
-def test_recalibration_zero_pins_forever(monkeypatch):
-    engine = _recal_engine(monkeypatch, 0, [(0.010, 0.009)])
-    req = _matrix_request(n_items=4)
-    for _ in range(5):
-        engine.process_batch(req)
-    stats = engine.stats()
-    engine.shutdown()
-    # one trial, never re-measured (the fake would IndexError)
-    assert stats["host_pool_probe"]["chosen"] == "inline"
-    assert "host_pool_probe_prev" not in stats
-    assert stats["host_pool_recal"]["interval"] == 0
-
-
-def test_recalibration_skipped_when_probe_pinned_off(monkeypatch):
-    """host_pool_probe=False is an explicit operator pin: the periodic
-    re-calibration must never override it."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-
-    def boom(self):  # pragma: no cover
-        raise AssertionError("pinned engine must never measure")
-
-    monkeypatch.setattr(host_pool.LaunchTrial, "__init__", boom)
-    engine = TpuEngine(
-        row_stride=256, compress_threshold=10**9,
-        force_mode="columnar_host", host_workers=4,
-        host_pool_probe=False, host_pool_recal_launches=1,
-    )
-    engine.enable_coprocessors([(1, _filter_spec().to_json(), ("orders",))])
-    req = _matrix_request(n_items=4)
-    for _ in range(3):
-        engine.process_batch(req)
-    stats = engine.stats()
-    engine.shutdown()
-    assert stats["n_sharded_launches"] >= 3
-    assert stats["host_pool_recal"]["interval"] == 0  # reported as pinned

@@ -1,14 +1,15 @@
-"""Host-stage pool parity tests (ISSUE 3).
+"""Host-stage pool tests.
 
-The sharded pipeline's whole correctness argument is "contiguous record
-ranges + index rebasing == byte-identical to the inline path"; these tests
-pin that argument down from three sides:
+The pool serves the mesh lane's per-device ladders (tests/test_meshrunner.py
+holds that lane's parity matrix); what lives here is the machinery and what
+it leans on:
 
 - partition_counts invariants (contiguity, coverage, no empty shards);
 - fused explode_and_find vs split explode_batches+build_find_cache span
   parity, with and without the native lib;
-- end-to-end: a sharded engine (workers=4, threshold lowered) produces
-  bit-identical replies to workers=0 for all three engine modes.
+- the pool's exception and shutdown contracts;
+- the single-device engine has one host road: no pool, no pool thread, and
+  none of the surface that used to choose between two.
 
 Plus the frame_ranges empty-ranges regression and the columnar-probe
 reset hook.
@@ -25,7 +26,6 @@ from redpanda_tpu.coproc import (
     EnableResponseCode,
 )
 from redpanda_tpu.coproc import batch_codec, governor, host_pool
-from redpanda_tpu.coproc import engine as engine_mod
 from redpanda_tpu.coproc.column_plan import plan_spec
 from redpanda_tpu.coproc.engine import ProcessBatchItem
 from redpanda_tpu.models import Compression, NTP, Record, RecordBatch
@@ -105,9 +105,9 @@ def test_pool_propagates_first_exception_in_order():
 
 # ------------------------------------------------------ frame_ranges empty
 def test_pool_shut_down_under_a_fan_out_serves_it_and_the_next():
-    """A trial's verdict shuts the pool down while another launch may be
-    fanning out: work the executor already holds still runs, and the next
-    fan-out gets a new executor (never 'cannot schedule new futures')."""
+    """shutdown() may land while a launch is fanning out: work the
+    executor already holds still runs, and the next fan-out gets a new
+    executor (never 'cannot schedule new futures')."""
     import threading
 
     pool = host_pool.HostStagePool(2)
@@ -197,288 +197,119 @@ def test_explode_python_fallback_parity(name, monkeypatch):
     assert native.joined == py.joined
 
 
-@pytest.mark.parametrize("name", sorted(_batch_scenarios()))
-def test_merge_exploded_matches_whole_list(name):
-    batches = _batch_scenarios()[name]
-    whole = batch_codec.explode_batches(batches)
-    parts = host_pool.partition_counts(
-        [b.header.record_count for b in batches], 2
-    )
-    merged = batch_codec.merge_exploded(
-        [batch_codec.explode_batches(batches[s:e]) for s, e in parts]
-    )
-    np.testing.assert_array_equal(whole.offsets, merged.offsets)
-    np.testing.assert_array_equal(whole.sizes, merged.sizes)
-    assert whole.ranges == merged.ranges
-    assert whole.joined == merged.joined
+# ------------------------------------------------------ one host road
+_RETIRED = (
+    "coproc_host_pool_probe", "coproc_host_pool_recal_launches",
+    "host_pool_probe", "host_pool_recal", "sharded_seal", "n_sharded_launches",
+)
 
 
-# ------------------------------------------------------ sharded == inline
-def _engine_pair_replies(spec, force_mode, monkeypatch, n_batches=6, n_recs=40):
-    """Run the same request through workers=0 and workers=4 engines (shard
-    threshold lowered so the pool actually engages) and return both reply
-    lists plus the sharded engine's stats."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    req = ProcessBatchRequest(
-        [
-            ProcessBatchItem(
-                1,
-                NTP.kafka("orders", p),
-                [
-                    _json_batch(n_recs, base_offset=100 * p),
-                    _json_batch(n_recs - 7, base_offset=100 * p + 50, empty_every=5),
-                ],
-            )
-            for p in range(n_batches // 2)
-        ]
-    )
-    replies = []
-    stats = None
-    for workers in (0, 4):
-        engine = TpuEngine(
-            row_stride=256,
-            compress_threshold=10**9,
-            force_mode=force_mode,
-            host_workers=workers,
-            host_pool_probe=False,  # parity must exercise the fan-out even
-            # on boxes whose capacity probe would demote the pool
+def _launch_request(parts=64, batches=2, records=32):
+    return ProcessBatchRequest([
+        ProcessBatchItem(
+            1, NTP.kafka("orders", p),
+            [_json_batch(records, base_offset=1000 * p + 100 * k) for k in range(batches)],
         )
-        codes = engine.enable_coprocessors([(1, spec.to_json(), ("orders",))])
-        assert codes == [EnableResponseCode.success]
-        replies.append(engine.process_batch(req))
-        if workers:
-            stats = engine.stats()
-    return replies[0], replies[1], stats
+        for p in range(parts)
+    ])
+
+
+def test_retired_surface_is_gone(tmp_path, capsys):
+    """Nothing names the fork any more: not the config registry, not
+    ``stats()``, not the governor's domains or posture, not what
+    ``/v1/coproc/status`` and ``/v1/governor`` serve, not what ``rpk debug
+    coproc`` / ``rpk debug governor`` print of them."""
+    import asyncio
+
+    from redpanda_tpu.admin import AdminServer
+    from redpanda_tpu.cli import rpk
+    from redpanda_tpu.config.properties import PROPERTIES
+    from redpanda_tpu.kafka.server.broker import Broker, BrokerConfig
+    from redpanda_tpu.storage.log_manager import StorageApi
+
+    names = {p.name for p in PROPERTIES}
+    assert not names & set(_RETIRED)
+    assert "coproc_host_workers" in names
+    assert not {"host_pool", "sharded_seal"} & set(governor.DOMAINS)
+    assert not hasattr(governor, "HOST_POOL") and not hasattr(governor, "SHARDED_SEAL")
+    for gone in ("LaunchTrial", "TrialSample", "TRIAL_LAUNCHES", "PROBE_MARGIN"):
+        assert not hasattr(host_pool, gone)
+    assert not hasattr(batch_codec, "merge_exploded")
+    assert governor.PROBE_MARGIN == 1.25
+
+    engine = TpuEngine(row_stride=256, compress_threshold=10**9)
+    engine.enable_coprocessors([(1, _columnar_spec().to_json(), ("orders",))])
+    engine.process_batch(_launch_request())  # 4,096 rows: every probe fires
+    stats = engine.stats()
+    assert not set(stats) & set(_RETIRED)
+    posture = stats["governor"]["posture"]
+    assert not {"host_pool", "sharded_seal"} & set(posture)
+
+    class _FakeApi:
+        @staticmethod
+        def active_scripts():
+            return ["demo"]
+
+    _FakeApi.engine = engine
+
+    async def main():
+        storage = await StorageApi(str(tmp_path)).start()
+        broker = Broker(BrokerConfig(data_dir=str(tmp_path)), storage)
+        broker.coproc_api = _FakeApi()
+        admin = await AdminServer(broker, port=0).start()
+        try:
+            for cmd in ("coproc", "governor"):
+                argv = ["--admin-api", f"127.0.0.1:{admin.port}", "debug", cmd]
+                assert await asyncio.to_thread(rpk.main, argv) == 0
+                assert await asyncio.to_thread(rpk.main, argv + ["--json"]) == 0
+        finally:
+            await admin.stop()
+            await storage.stop()
+
+    try:
+        asyncio.run(main())
+    finally:
+        engine.shutdown()
+    printed = capsys.readouterr().out
+    assert "harvest_path" in printed and "host_workers" in printed
+    for gone in _RETIRED + ("host_pool",):
+        assert gone not in printed, gone
 
 
 @pytest.mark.parametrize(
-    "mode_name,spec,force_mode",
-    [
-        ("columnar", _columnar_spec(), "columnar_host"),
-        ("payload", filter_contains(b"error"), None),
-        ("host", identity(), None),
-    ],
+    "spec",
+    [_columnar_spec(), filter_contains(b"error"), identity()],
+    ids=["columnar", "payload", "host"],
 )
-def test_sharded_bit_identical_to_inline(mode_name, spec, force_mode, monkeypatch):
-    inline, sharded, stats = _engine_pair_replies(spec, force_mode, monkeypatch)
-    assert stats["n_sharded_launches"] >= 1, "pool path did not engage"
-    assert stats["host_workers"] == 4.0
-    assert len(inline.items) == len(sharded.items)
-    for a, b in zip(inline.items, sharded.items):
-        assert a.source == b.source
-        assert len(a.batches) == len(b.batches)
-        for ba, bb in zip(a.batches, b.batches):
-            assert ba.payload == bb.payload
-            assert ba.header.crc == bb.header.crc
-            assert ba.header.record_count == bb.header.record_count
+def test_single_device_engine_starts_no_pool_thread(spec):
+    """Without a mesh runner the engine builds no pool, whatever
+    ``host_workers`` says, and a 4,096-row launch of each lane runs its
+    host stages on the dispatching thread: no ``rptpu-host-stage`` thread
+    ever exists."""
+    import threading
 
+    def pool_threads():
+        return [
+            t.name for t in threading.enumerate()
+            if t.name.startswith("rptpu-host-stage")
+        ]
 
-def test_sharded_bit_identical_columnar_device(monkeypatch):
-    """The device-predicate leg of the sharded path (per-shard launches +
-    async mask harvest through _MaskSlot) against the inline device path."""
-    inline, sharded, stats = _engine_pair_replies(
-        _columnar_spec(), "columnar_device", monkeypatch
-    )
-    assert stats["n_sharded_launches"] >= 1
-    for a, b in zip(inline.items, sharded.items):
-        assert [x.payload for x in a.batches] == [y.payload for y in b.batches]
-
-
-# ------------------------------------------------------ pool calibration
-# The decision is taken on what it governs (host_pool.LaunchTrial): the
-# first shardable launches run alternately inline and sharded, each timed
-# whole, dispatch to sealed reply; medians, sharded must win by PROBE_MARGIN.
-def _slowed(monkeypatch, name, seconds):
-    """Make ``batch_codec.<name>`` take ``seconds`` longer (a real sleep:
-    the trial times real launches)."""
-    import time
-
-    real = getattr(batch_codec, name)
-
-    def slow(*a, **kw):
-        time.sleep(seconds)
-        return real(*a, **kw)
-
-    monkeypatch.setattr(batch_codec, name, slow)
-
-
-def _fixed_costs(monkeypatch, per_trial):
-    """Trials whose samples are the given (inline, sharded) seconds a row,
-    one pair per trial in order: the rule is under test, the launches still
-    run both roads for real."""
-    costs = list(per_trial)
-    real_init = host_pool.LaunchTrial.__init__
-
-    def init(self):
-        real_init(self)
-        self.costs = dict(zip(self.ARMS, costs.pop(0)))
-
-    def add(self, arm, seconds, rows):
-        assert seconds > 0 and rows > 0
-        self.samples[arm].append(self.costs[arm] * 1e6)
-
-    monkeypatch.setattr(host_pool.LaunchTrial, "__init__", init)
-    monkeypatch.setattr(host_pool.LaunchTrial, "add", add)
-
-
-def _trial_run(monkeypatch, spec, launches, per_arm=2, **engine_kw):
-    """Drive ``launches`` shardable launches through an engine whose pool
-    decision is still to be measured; returns (engine, stats)."""
-    monkeypatch.setattr(engine_mod, "_SHARD_MIN_ROWS", 32)
-    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", per_arm)
-    governor.reset_journal()
-    engine = TpuEngine(
-        row_stride=256, compress_threshold=10**9, host_workers=4, **engine_kw
-    )
-    engine.enable_coprocessors([(1, spec.to_json(), ("orders",))])
-    req = ProcessBatchRequest(
-        [ProcessBatchItem(1, NTP.kafka("orders", 0), [_json_batch(40), _json_batch(40)])]
-    )
-    want = None
-    for _ in range(launches):
-        reply = engine.process_batch(req)
-        got = [b.payload for b in reply.items[0].batches]
-        assert got and (want is None or got == want)  # both roads, same bits
-        want = got
-    return engine, engine.stats()
-
-
-def _case_explode_faster_but_whole_launch_slower(monkeypatch):
-    # what the old explode-only probe got wrong: the pool explodes faster
-    # (the inline explode is slowed), yet the sharded launch as a whole is
-    # slower (its merge is slowed more) -> inline
-    _slowed(monkeypatch, "explode_ptrs", 0.02)
-    _slowed(monkeypatch, "merge_exploded", 0.08)
-    engine, stats = _trial_run(monkeypatch, filter_contains(b"error"), 7)
-    probe = stats["host_pool_probe"]
-    assert probe["chosen"] == "inline" and probe["speedup"] < 1.0
-    assert probe["launches"] == {"inline": 2, "sharded": 2}
-    assert probe["dropped"] >= 1  # the launch that compiled the program
-    assert stats["n_sharded_launches"] == 2  # the trial's own, none after
-    return engine
-
-
-def _case_whole_launch_win_pins_sharded(monkeypatch):
-    _slowed(monkeypatch, "explode_ptrs", 0.06)  # the inline road alone
-    engine, stats = _trial_run(monkeypatch, filter_contains(b"error"), 8)
-    probe = stats["host_pool_probe"]
-    assert probe["chosen"] == "sharded"
-    assert probe["speedup"] >= host_pool.PROBE_MARGIN
-    assert stats["n_sharded_launches"] >= 3  # the trial's two, then every one
-    return engine
-
-
-def _case_win_below_the_margin_keeps_inline(monkeypatch):
-    _fixed_costs(monkeypatch, [(0.010, 0.009)])
-    engine, stats = _trial_run(
-        monkeypatch, _columnar_spec(), 6, force_mode="columnar_host"
-    )
-    probe = stats["host_pool_probe"]
-    assert probe["chosen"] == "inline"
-    assert probe["speedup"] == round(10 / 9, 3)
-    assert stats["n_sharded_launches"] == 2
-    (entry,) = governor.journal.entries(domain="host_pool")
-    assert entry["verdict"] == "inline" and "whole launches" in entry["reason"]
-    assert entry["inputs"]["samples"] == {
-        "inline": [10000.0] * 2, "sharded": [9000.0] * 2
-    }
-    return engine
-
-
-def _case_failing_calibration_keeps_inline(monkeypatch):
-    def boom(self):
-        raise RuntimeError("measurement exploded")
-
-    monkeypatch.setattr(host_pool.LaunchTrial, "verdict", boom)
-    engine, stats = _trial_run(
-        monkeypatch, _columnar_spec(), 6, force_mode="columnar_host"
-    )
-    assert engine._pool_decision == "inline" and "host_pool_probe" not in stats
-    (entry,) = governor.journal.entries(domain="host_pool")
-    assert entry["verdict"] == "inline" and "FAILED" in entry["reason"]
-    return engine
-
-
-def _case_no_clean_sample_keeps_inline(monkeypatch):
-    # every launch meets a spoiler (a first run, a probe, a fallback): the
-    # trial spends its launches, says so, and the inline path stays
-    monkeypatch.setattr(host_pool, "TRIAL_MAX_LAUNCHES", 6)
-    monkeypatch.setattr(TpuEngine, "_trial_elapsed", lambda self, mark: None)
-    engine, stats = _trial_run(
-        monkeypatch, _columnar_spec(), 8, force_mode="columnar_host"
-    )
-    probe = stats["host_pool_probe"]
-    assert probe["chosen"] == "inline" and probe["incomplete"] is True
-    assert probe["launches"] == {"inline": 0, "sharded": 0}
-    assert probe["dropped"] == 6
-    return engine
-
-
-def _case_recalibration_follows_the_same_rule(monkeypatch):
-    _fixed_costs(monkeypatch, [(0.010, 0.009), (0.010, 0.005)])
-    engine, stats = _trial_run(
-        monkeypatch, _columnar_spec(), 6, per_arm=1,
-        force_mode="columnar_host", host_pool_recal_launches=2,
-    )
-    # launches 1-2: the first trial (inline); 3-4 count to the interval;
-    # 4-5: the second trial, which the pool now wins
-    assert stats["host_pool_probe_prev"]["chosen"] == "inline"
-    assert stats["host_pool_probe"]["chosen"] == "sharded"
-    second, first = governor.journal.entries(domain="host_pool")
-    assert first["inputs"]["recalibration"] is False
-    assert second["inputs"]["recalibration"] is True
-    return engine
-
-
-def _case_unmocked_trial_times_both_roads(monkeypatch):
-    engine, stats = _trial_run(
-        monkeypatch, _columnar_spec(), 6, force_mode="columnar_host"
-    )
-    probe = stats["host_pool_probe"]
-    assert probe["inline_us_per_row"] > 0 and probe["sharded_us_per_row"] > 0
-    assert probe["chosen"] in ("inline", "sharded")
-    assert probe["workers"] == 4
-    return engine
-
-
-@pytest.mark.parametrize(
-    "case",
-    [
-        _case_explode_faster_but_whole_launch_slower,
-        _case_whole_launch_win_pins_sharded,
-        _case_win_below_the_margin_keeps_inline,
-        _case_failing_calibration_keeps_inline,
-        _case_no_clean_sample_keeps_inline,
-        _case_recalibration_follows_the_same_rule,
-        _case_unmocked_trial_times_both_roads,
-    ],
-    ids=lambda f: f.__name__[len("_case_"):],
-)
-def test_host_pool_trial(case, monkeypatch):
-    case(monkeypatch).shutdown()
-
-
-def test_launch_trial_bookkeeping(monkeypatch):
-    monkeypatch.setattr(host_pool, "TRIAL_LAUNCHES", 3)
-    monkeypatch.setattr(host_pool, "TRIAL_MAX_LAUNCHES", 9)
-    trial = host_pool.LaunchTrial()
-    # level: alternate from inline (launches in flight have not sampled)
-    assert [trial.next_arm() for _ in range(3)] == ["inline", "sharded", "inline"]
-    trial.add("inline", 0.004, 1000)
-    assert trial.next_arm() == "sharded"  # the arm that is behind
-    for us in (9.0, 1.0, 2.0):
-        trial.add("sharded", us * 1e-6 * 500, 500)
-    assert trial.next_arm() == "inline" and not trial.complete
-    trial.add("inline", 0.006, 1000)
-    trial.add("inline", 0.050, 1000)  # one far-off launch moves no median
-    assert trial.complete and not trial.exhausted
-    got = trial.verdict()
-    assert (got["inline_us_per_row"], got["sharded_us_per_row"]) == (6.0, 2.0)
-    assert got["speedup"] == 3.0 and got["chosen"] == "sharded"
-    assert got["launches"] == {"inline": 3, "sharded": 3} and got["dropped"] == 0
-    for _ in range(4):
-        trial.next_arm()
-    assert trial.exhausted
+    assert not pool_threads()
+    engine = TpuEngine(row_stride=256, compress_threshold=10**9, host_workers=4)
+    try:
+        assert engine._host_pool is None
+        assert engine.enable_coprocessors(
+            [(1, spec.to_json(), ("orders",))]
+        ) == [EnableResponseCode.success]
+        reply = engine.process_batch(_launch_request())
+        stats = engine.stats()
+        assert stats["n_records"] == 4096 and stats["host_workers"] == 4.0
+        assert sum(len(it.batches) for it in reply.items) == 128
+        assert "t_seal" in stats
+        assert not [k for k in stats if k.startswith("t_shard")]
+        assert not pool_threads()
+    finally:
+        engine.shutdown()
 
 
 def test_measure_parallel_capacity_shape():

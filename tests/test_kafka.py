@@ -202,6 +202,30 @@ def test_e2e_produce_fetch(tmp_path):
     run(main())
 
 
+def test_request_on_a_lost_connection_fails_instead_of_hanging(tmp_path):
+    """A broker that goes away can leave the client's socket half-open: a
+    request written after the receive loop has ended would never be
+    answered. It must raise, as the requests in flight at that moment do
+    (tests/chaos/test_overload_chaos.py floods a leader it then kills)."""
+
+    async def main():
+        broker, server = await _start_broker(tmp_path)
+        client = await KafkaClient([("127.0.0.1", server.port)]).connect()
+        try:
+            await client.create_topic("lost", partitions=1)
+            assert await client.produce("lost", 0, [b"r0"]) == 0
+            conn = await client.leader_connection("lost", 0)
+            await server.stop()
+            await asyncio.wait_for(asyncio.shield(conn._recv_task), 10)
+            with pytest.raises(ConnectionError):
+                await asyncio.wait_for(client.produce("lost", 0, [b"r1"]), 10)
+        finally:
+            await client.close()
+            await broker.storage.stop()
+
+    run(main())
+
+
 def test_e2e_offsets_and_auto_create(tmp_path):
     async def main():
         broker, server = await _start_broker(tmp_path)

@@ -30,7 +30,6 @@ from redpanda_tpu.coproc import (
     TpuEngine,
     leakwatch,
 )
-from redpanda_tpu.coproc import engine as engine_mod
 from redpanda_tpu.coproc import faults, governor
 from redpanda_tpu.coproc.engine import ProcessBatchItem
 from redpanda_tpu.finjector import honey_badger
@@ -81,13 +80,17 @@ def _workload() -> ProcessBatchRequest:
     return ProcessBatchRequest(items)
 
 
-def _engine(spec, force_mode, workers, budget_plane=None) -> TpuEngine:
+def _engine(spec, force_mode, mesh_devices=0, budget_plane=None) -> TpuEngine:
     engine = TpuEngine(
         row_stride=256,
         compress_threshold=10**9,
         force_mode=force_mode,
-        host_workers=workers,
-        host_pool_probe=False,
+        # a mesh engine runs its per-device ladders and per-shard harvest
+        # on a 4-worker pool; a single-device engine has no pool
+        host_workers=4 if mesh_devices else 0,
+        mesh_devices=mesh_devices or None,
+        mesh_backend="cpu" if mesh_devices else None,
+        mesh_probe=False,
         device_deadline_ms=60,
         adaptive_deadline=False,
         launch_retries=1,
@@ -186,7 +189,7 @@ def test_leakwatch_off_installs_no_proxy():
     plane = BudgetPlane(total_bytes=1 << 20)
     for name, acct in plane.accounts.items():
         assert type(acct) is MemoryAccount, name
-    engine = TpuEngine(host_workers=2, host_pool_probe=False)
+    engine = TpuEngine(host_workers=2)
     try:
         assert not isinstance(engine._arena, leakwatch.WatchedArena)
     finally:
@@ -194,17 +197,15 @@ def test_leakwatch_off_installs_no_proxy():
 
 
 # ------------------------------------------------- on = analyzer verified
-def test_chaos_parity_balances_zero_and_sites_in_static_model():
-    """Run the parity workload matrix (every engine mode, pool on and
-    off, every probe point faulted, cancellation injected) under
+def test_chaos_parity_balances_zero_and_sites_in_static_model(eight_devices):
+    """Run the parity workload matrix (every engine mode, the mesh lane
+    and its pool, every probe point faulted, cancellation injected) under
     leakwatch; assert (a) the parity invariant still holds, (b) every
     balance nets to zero and zero imbalances fired, (c) every observed
     acquire site is in the static lifecycle model."""
     leakwatch.reset()
     leakwatch.enable()
     engines: list[TpuEngine] = []
-    saved_shard_min = engine_mod._SHARD_MIN_ROWS
-    engine_mod._SHARD_MIN_ROWS = 64
     saved_wedge, saved_delay = honey_badger.wedge_max_s, honey_badger.delay_ms
     honey_badger.wedge_max_s = 0.12
     honey_badger.delay_ms = 5
@@ -216,19 +217,25 @@ def test_chaos_parity_balances_zero_and_sites_in_static_model():
                 where(field("level") == "error")
                 | map_project(Int("code"), Str("msg", 16)),
                 "columnar_device",
-                4,
+                0,
+            ),
+            (
+                where(field("level") == "error")
+                | map_project(Int("code"), Str("msg", 16)),
+                "columnar_device",
+                2,
             ),
             (
                 where(field("level") == "error")
                 | map_project(Int("code"), Str("msg", 16)),
                 "columnar_host",
-                4,
+                0,
             ),
-            (filter_contains(b"error"), None, 4),
+            (filter_contains(b"error"), None, 0),
             (identity(), None, 0),
         ]
-        for spec, force_mode, workers in matrix:
-            engine = _engine(spec, force_mode, workers, budget_plane=plane)
+        for spec, force_mode, mesh_devices in matrix:
+            engine = _engine(spec, force_mode, mesh_devices, budget_plane=plane)
             engines.append(engine)
             assert isinstance(engine._arena, leakwatch.WatchedArena)
             baseline = engine.process_batch(req)
@@ -238,19 +245,22 @@ def test_chaos_parity_balances_zero_and_sites_in_static_model():
                 for b in item.batches
             )
             assert n_base > 0
-        # fault round on the async-mask engine: every coproc probe point,
+        # fault round on the async-mask and the mesh engine: every coproc
+        # probe point,
         # so breaker/fallback/abandonment release paths are exercised too
         honey_badger.enable()
         try:
-            for probe in (
-                faults.DEVICE_DISPATCH,
-                faults.MASK_FETCH,
-                faults.HARVEST,
-                faults.SHARD_WORKER,
-            ):
+            for engine, probe in [
+                (engines[0], faults.DEVICE_DISPATCH),
+                (engines[0], faults.MASK_FETCH),
+                (engines[0], faults.HARVEST),
+                (engines[1], faults.MESH_DISPATCH),
+                (engines[1], faults.MASK_FETCH),
+                (engines[1], faults.SHARD_WORKER),
+            ]:
                 honey_badger.set_exception(faults.MODULE, probe)
                 try:
-                    reply = engines[0].process_batch(req)
+                    reply = engine.process_batch(req)
                 finally:
                     honey_badger.unset(faults.MODULE, probe)
                 assert sum(
@@ -310,5 +320,4 @@ def test_chaos_parity_balances_zero_and_sites_in_static_model():
             engine.shutdown()
         honey_badger.wedge_max_s = saved_wedge
         honey_badger.delay_ms = saved_delay
-        engine_mod._SHARD_MIN_ROWS = saved_shard_min
         leakwatch.disable()

@@ -76,13 +76,17 @@ def _workload() -> ProcessBatchRequest:
     return ProcessBatchRequest(items)
 
 
-def _engine(spec, force_mode, workers) -> TpuEngine:
+def _engine(spec, force_mode, mesh_devices=0) -> TpuEngine:
     engine = TpuEngine(
         row_stride=256,
         compress_threshold=10**9,
         force_mode=force_mode,
-        host_workers=workers,
-        host_pool_probe=False,
+        # a mesh engine runs its per-device ladders and per-shard harvest
+        # on a 4-worker pool; a single-device engine has no pool
+        host_workers=4 if mesh_devices else 0,
+        mesh_devices=mesh_devices or None,
+        mesh_backend="cpu" if mesh_devices else None,
+        mesh_probe=False,
         device_deadline_ms=60,
         adaptive_deadline=False,
         launch_retries=1,
@@ -117,11 +121,11 @@ def test_lockwatch_off_installs_no_wrapper():
     assert not lockwatch.enabled()
     raw = threading.Lock()
     assert lockwatch.wrap(raw, "x") is raw
-    engine = TpuEngine(host_workers=2, host_pool_probe=False)
+    engine = TpuEngine(host_workers=2)
     try:
         assert not isinstance(engine._stats_lock, lockwatch.WatchedLock)
         assert not isinstance(
-            engine._pool_decision_lock, lockwatch.WatchedLock
+            engine._parse_decision_lock, lockwatch.WatchedLock
         )
         assert not isinstance(
             engine_mod._mask_claim_lock, lockwatch.WatchedLock
@@ -143,16 +147,14 @@ def test_disable_restores_module_locks():
 
 
 # ------------------------------------------------- on = analyzer verified
-def test_chaos_parity_lock_edges_are_subgraph_of_static_graph():
-    """Run the parity workload matrix (every engine mode, pool on and
-    off, every probe point faulted) under lockwatch; assert (a) the
+def test_chaos_parity_lock_edges_are_subgraph_of_static_graph(eight_devices):
+    """Run the parity workload matrix (every engine mode, the mesh lane
+    and its pool, every probe point faulted) under lockwatch; assert (a) the
     parity invariant still holds, (b) edges were actually observed,
     journaled and counted, (c) observed edges ⊆ static graph."""
     lockwatch.reset_edges()
     lockwatch.enable()
     engines: list[TpuEngine] = []
-    saved_shard_min = engine_mod._SHARD_MIN_ROWS
-    engine_mod._SHARD_MIN_ROWS = 64
     saved_wedge, saved_delay = honey_badger.wedge_max_s, honey_badger.delay_ms
     honey_badger.wedge_max_s = 0.12
     honey_badger.delay_ms = 5
@@ -163,19 +165,25 @@ def test_chaos_parity_lock_edges_are_subgraph_of_static_graph():
                 where(field("level") == "error")
                 | map_project(Int("code"), Str("msg", 16)),
                 "columnar_device",
-                4,
+                0,
+            ),
+            (
+                where(field("level") == "error")
+                | map_project(Int("code"), Str("msg", 16)),
+                "columnar_device",
+                2,
             ),
             (
                 where(field("level") == "error")
                 | map_project(Int("code"), Str("msg", 16)),
                 "columnar_host",
-                4,
+                0,
             ),
-            (filter_contains(b"error"), None, 4),
+            (filter_contains(b"error"), None, 0),
             (identity(), None, 0),
         ]
-        for spec, force_mode, workers in matrix:
-            engine = _engine(spec, force_mode, workers)
+        for spec, force_mode, mesh_devices in matrix:
+            engine = _engine(spec, force_mode, mesh_devices)
             engines.append(engine)
             baseline = engine.process_batch(req)
             n_base = sum(
@@ -184,19 +192,22 @@ def test_chaos_parity_lock_edges_are_subgraph_of_static_graph():
                 for b in item.batches
             )
             assert n_base > 0
-        # fault round on the async-mask engine: every coproc probe point,
+        # fault round on the async-mask and the mesh engine: every coproc
+        # probe point,
         # so breaker/fallback/abandonment lock paths are exercised too
         honey_badger.enable()
         try:
-            for probe in (
-                faults.DEVICE_DISPATCH,
-                faults.MASK_FETCH,
-                faults.HARVEST,
-                faults.SHARD_WORKER,
-            ):
+            for engine, probe in [
+                (engines[0], faults.DEVICE_DISPATCH),
+                (engines[0], faults.MASK_FETCH),
+                (engines[0], faults.HARVEST),
+                (engines[1], faults.MESH_DISPATCH),
+                (engines[1], faults.MASK_FETCH),
+                (engines[1], faults.SHARD_WORKER),
+            ]:
                 honey_badger.set_exception(faults.MODULE, probe)
                 try:
-                    reply = engines[0].process_batch(req)
+                    reply = engine.process_batch(req)
                 finally:
                     honey_badger.unset(faults.MODULE, probe)
                 assert sum(
@@ -236,5 +247,4 @@ def test_chaos_parity_lock_edges_are_subgraph_of_static_graph():
             engine.shutdown()
         honey_badger.wedge_max_s = saved_wedge
         honey_badger.delay_ms = saved_delay
-        engine_mod._SHARD_MIN_ROWS = saved_shard_min
         lockwatch.disable()

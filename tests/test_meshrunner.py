@@ -73,7 +73,6 @@ def _run(spec, *, mesh_devices=None, host_workers=0, **kw):
     engine = TpuEngine(
         row_stride=256,
         host_workers=host_workers,
-        host_pool_probe=False,
         mesh_devices=mesh_devices,
         mesh_backend="cpu" if mesh_devices else None,
         mesh_probe=False,  # pin "mesh": parity needs the lane deterministically

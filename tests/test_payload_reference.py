@@ -9,8 +9,13 @@ order to ``benchmarks/references/filter_contains.py`` (loaded by path, the
 way ``benchmarks/loadgen.load_reference`` loads it; the reference imports
 nothing of the program). The same runs hold the two counters the lane's
 per-layer metrics read.
+
+And both configurations' scripts (``json64p-where``: the columnar lane)
+at the launch shapes the ledger judges the benchmark's cells at, on the
+one host road a single-device launch has.
 """
 
+import functools
 import importlib.util
 import json
 import os
@@ -18,7 +23,7 @@ import os
 import pytest
 
 from redpanda_tpu.coproc import EnableResponseCode, ProcessBatchRequest, TpuEngine
-from redpanda_tpu.coproc.engine import ProcessBatchItem
+from redpanda_tpu.coproc.engine import ProcessBatchItem, _bucket_rows
 from redpanda_tpu.coproc.reference import make_documents
 from redpanda_tpu.models import NTP, Record, RecordBatch
 
@@ -26,17 +31,24 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NEEDLE = b'"level":"warn"'
 
 
-def _config() -> dict:
-    with open(os.path.join(BENCH, "configs", "json64p-v1.json")) as f:
+def _config(name: str = "json64p-v1") -> dict:
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
         return json.load(f)
 
 
-def _reference(name: str):
-    path = os.path.join(BENCH, "references", name + ".py")
-    spec = importlib.util.spec_from_file_location("perfbench_reference_" + name, path)
+def _load(relpath: str):
+    """A file of the benchmark, loaded by path (nothing under benchmarks/
+    is a package of the program)."""
+    path = os.path.join(BENCH, relpath)
+    name = "perfbench_" + relpath[:-3].replace("/", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _reference(name: str):
+    return _load("references/" + name + ".py")
 
 
 def _doc(size: int, needle_at: int | None) -> bytes:
@@ -173,3 +185,88 @@ def test_a_script_inherits_the_programs_its_spec_already_ran():
         assert engine.stats()["n_device_launches"] == 4
     finally:
         engine.shutdown()
+
+
+# ------------------------------------------- the cells' launch shapes
+# rows a launch: what json64p-where.paced launches (3 batches of 32), what
+# json64p-where.catchup launches (64 partitions x 2 batches), and
+# json64p-v1.catchup's governor fixed point in its 32,768-row bucket (64 x 9)
+_SHAPES = {96: (3, 1), 4096: (64, 2), 18432: (64, 9)}
+
+
+@functools.lru_cache(maxsize=1)  # the cases of one shape run back to back
+def _shape_inputs(rows: int):
+    """(values a partition, request) of one launch of ``rows`` records:
+    the benchmark's own seeded documents (benchmarks/docs.py), batches of
+    32 as the configurations' producers send them."""
+    partitions, batches = _SHAPES[rows]
+    docs = _load("docs.py").make_documents(
+        11, 64, 32 * batches, only=range(partitions)
+    )
+    parts = [docs[p] for p in range(partitions)]
+    assert sum(map(len, parts)) == rows
+    req = ProcessBatchRequest([
+        ProcessBatchItem(1, NTP.kafka("bench", p), _batches(values, 32, 1000 * p))
+        for p, values in enumerate(parts)
+    ])
+    return parts, req
+
+
+@pytest.mark.parametrize("use_native", [True, False], ids=["native", "no_native"])
+@pytest.mark.parametrize("rows", sorted(_SHAPES))
+@pytest.mark.parametrize("config_name", ["json64p-where", "json64p-v1"])
+def test_single_road_at_the_cells_launch_shapes(
+    config_name, rows, use_native, monkeypatch
+):
+    """One launch of each cell's shape through an engine built as the
+    broker builds it (default ``host_workers``, Zstd-sealed output): its
+    records equal the configuration's plain reference one for one and in
+    order, it ran on the dispatching thread's one road, and ``stats()``
+    holds nothing of the fork that used to choose another."""
+    from redpanda_tpu.coproc import batch_codec
+    from redpanda_tpu.coproc import column_plan
+
+    if not use_native:
+        monkeypatch.setattr(batch_codec, "_native", lambda: None)
+        monkeypatch.setattr(column_plan, "_native", lambda: None)
+    config = _config(config_name)
+    ref = _reference(config["reference"]["name"])
+    params = config["reference"]["params"]
+    parts, req = _shape_inputs(rows)
+    TpuEngine.reset_columnar_probe()
+    engine = TpuEngine(row_stride=params.get("row_stride", 1024))
+    try:
+        codes = engine.enable_coprocessors(
+            [(1, json.dumps(config["script"]["spec"]), ("bench",))]
+        )
+        assert codes == [EnableResponseCode.success]
+        assert engine._plans[1].mode == config["lane"]
+        reply = engine.submit(req).result()
+        stats = engine.stats()
+        assert engine._host_pool is None
+    finally:
+        engine.shutdown()
+        TpuEngine.reset_columnar_probe()
+
+    kept = 0
+    for item, values in zip(reply.items, parts):
+        assert len(item.batches) <= len(values) // 32
+        got = [r.value for b in item.batches for r in b.records()]
+        want = [o for o in (ref.reference(v, **params) for v in values) if o is not None]
+        assert got == want, f"partition {item.source.partition}"
+        kept += len(want)
+    assert 0 < kept < rows
+    assert stats["n_launches"] == 1 and stats["n_records"] == rows
+    assert stats.get("n_fallback_rows", 0) == 0 and "t_seal" in stats
+    assert "host_pool_probe" not in stats and "host_pool_recal" not in stats
+    assert not [k for k in stats if k.startswith(("t_shard", "n_shard"))]
+    if config["lane"] == "payload":
+        n_pad = _bucket_rows(rows)
+        assert stats["n_staged_rows"] == n_pad and stats["n_device_launches"] == 1
+        assert stats["bytes_d2h"] == n_pad // 8 and stats["n_frame_gather"] == 1
+        n_over = sum(len(v) > params["row_stride"] for vs in parts for v in vs)
+        assert stats.get("n_oversize_rows", 0) == n_over
+        if rows >= 4096:  # about one document in seven is wider than the row
+            assert 0.12 < n_over / rows < 0.17
+    else:
+        assert stats["n_frame_padded"] == 1 and "n_frame_gather" not in stats

@@ -88,7 +88,6 @@ def _launch(**engine_kw):
     engine_kw.setdefault("row_stride", 256)
     engine_kw.setdefault("force_mode", "columnar_host")
     engine_kw.setdefault("host_workers", 0)
-    engine_kw.setdefault("host_pool_probe", False)
     eng = TpuEngine(**engine_kw)
     try:
         assert eng.enable_coprocessors(
@@ -182,23 +181,23 @@ def test_stage_slice_parity_inline():
     assert checked >= 4, pulse.recorder.stage_totals()
 
 
-def test_stage_slice_parity_sharded():
-    stats = _launch(host_workers=4)
-    assert stats.get("n_sharded_launches", 0) >= 1
-    totals = pulse.recorder.stage_totals()
-    assert any(k.startswith("coproc.stage.shard_") for k in totals), totals
-    assert any(k.startswith("coproc.stage.sharded_") for k in totals), totals
-    _assert_stage_parity(stats)
-
-
-def test_stage_slice_parity_mesh(eight_devices):
+@pytest.mark.parametrize(
+    "host_workers", [0, 2], ids=["ladders_inline", "ladders_on_pool"]
+)
+def test_stage_slice_parity_mesh(host_workers, eight_devices):
+    """The per-shard slices (``shard_*``) close on whatever thread ran the
+    shard — the dispatching one, or a pool worker with no ambient trace,
+    where the launch's trace id rides explicitly — and still sum to the
+    engine's ``t_shard_*`` stats."""
     stats = _launch(
         force_mode=None, mesh_devices=4, mesh_backend="cpu",
-        mesh_probe=False,
+        mesh_probe=False, host_workers=host_workers,
     )
     assert stats.get("n_mesh_launches", 0) >= 1
     totals = pulse.recorder.stage_totals()
     assert "coproc.stage.mesh_ladder" in totals, totals
+    assert any(k.startswith("coproc.stage.shard_") for k in totals), totals
+    assert "coproc.stage.sharded_frame" in totals, totals
     _assert_stage_parity(stats)
     # the per-device mesh shard spans carry their shard index
     mesh_spans = [

@@ -247,24 +247,27 @@ def _fresh_probe():
 
 class TestEngineParity:
     @pytest.mark.parametrize("spec", [PROJ_SPEC, PASS_SPEC], ids=["proj", "pass"])
-    @pytest.mark.parametrize("pool", [0, 4], ids=["inline", "pool"])
-    def test_structural_vs_staged_bit_identical(self, spec, pool):
-        # the pool cell needs a launch over _SHARD_MIN_ROWS or the
-        # fan-out never engages and the "sharded" lane goes untested
-        req = (
-            _request(n_items=32, records=64, pad=60)
-            if pool
-            else _request()
-        )
+    @pytest.mark.parametrize("mesh", [0, 4], ids=["inline", "mesh"])
+    def test_structural_vs_staged_bit_identical(self, spec, mesh, eight_devices):
+        # the mesh cell runs the same ladders per device shard
+        # (_shard_ladder) on a 2-worker pool; force_mode unset there (a
+        # columnar_host pin declines the lane)
+        req = _request(n_items=32, records=64, pad=60) if mesh else _request()
         adv = _adversarial_request()
         replies = {}
+        lane = (
+            dict(
+                host_workers=2, mesh_devices=mesh, mesh_backend="cpu",
+                mesh_probe=False, force_mode=None,
+            )
+            if mesh
+            else {}
+        )
         for mode, kw in (
             ("staged", dict(structural_parse=False)),
             ("structural", dict(structural_parse=True, structural_probe=False)),
         ):
-            engine = _engine(
-                host_workers=pool, host_pool_probe=pool == 0, **kw
-            )
+            engine = _engine(**lane, **kw)
             try:
                 codes = engine.enable_coprocessors(
                     [(1, spec.to_json(), ("bench",))]
@@ -277,9 +280,11 @@ class TestEngineParity:
                 stats = engine.stats()
             finally:
                 engine.shutdown()
+            if mesh:
+                assert stats["n_mesh_launches"] == 2
             if mode == "structural" and _native_available():
-                if pool:
-                    # the big launch fanned out: the structural lane ran
+                if mesh:
+                    # both launches fanned out: the structural lane ran
                     # per shard (per-shard CPU-seconds under t_shard_*)
                     assert stats.get("t_shard_explode_find2", 0.0) > 0.0
                     assert stats.get("t_shard_fused_extract", 0.0) > 0.0
@@ -485,34 +490,6 @@ class TestColumnCache:
         assert cache.lookup((1, 9)) is None
         assert not cache.put((1, 9), entry(5000))
         assert cache.lookup((1, 9)) is None
-
-    def test_sharded_launches_populate_and_hit_per_shard(self):
-        # Cross-launch cache for the SHARDED path (ROADMAP item 1
-        # follow-on c): the first identical launch's shard workers each
-        # populate their own per-shard entry, and every shard of every
-        # later identical launch hits — no inline self-route. Pinned
-        # counters: per launch, 1 launch-wide miss (the pre-shard lookup)
-        # + 4 shard lookups (workers=4 over 32 distinct batches), so
-        # 3 launches = 3 + 4 = 7 misses and 2 * 4 = 8 hits.
-        req = _request(n_items=32, records=64)  # >= _SHARD_MIN_ROWS
-        engine = _engine(
-            host_workers=4, host_pool_probe=False, device_column_cache_mb=32
-        )
-        try:
-            engine.enable_coprocessors([(1, PASS_SPEC.to_json(), ("bench",))])
-            r1 = _payloads(engine.process_batch(req))
-            r2 = _payloads(engine.process_batch(req))
-            r3 = _payloads(engine.process_batch(req))
-            assert r1 == r2 == r3
-            st = engine.stats()["colcache"]
-            assert st["hits"] == 8 and st["misses"] == 7
-            assert st["entries"] == 4
-            # the hits actually skipped the ladder: only the first
-            # launch's shards ran a parse crossing
-            n_sharded = engine.stats().get("n_sharded_launches", 0)
-            assert n_sharded == 3
-        finally:
-            engine.shutdown()
 
     def test_reset_hook_and_stats_shape(self):
         engine = _engine(device_column_cache_mb=8)

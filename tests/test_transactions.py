@@ -181,6 +181,57 @@ def test_eos_send_offsets(tmp_path):
     run(main())
 
 
+def test_end_txn_retry_after_the_redrive_finished_it(tmp_path):
+    """EndTxn's first attempt comes back retriable (the group-offset fold
+    failed; the tx stays prepare_commit), the coordinator's own re-drive
+    then completes the commit, and the client's retry arrives at a
+    complete_commit tx: that retry is the success it asked for, not
+    invalid_txn_state (tests/chaos/test_tx_chaos.py met this under load)."""
+
+    async def main():
+        broker, server = await _start_broker(tmp_path)
+        client = await KafkaClient([("127.0.0.1", server.port)]).connect()
+        await client.create_topic("src", partitions=1)
+        await client.create_topic("dst", partitions=1)
+        await client.produce("src", 0, [b"in1", b"in2"])
+        prod = await TransactionalProducer(client, "tx-retry").init()
+        prod.begin()
+        await prod.send("dst", 0, [b"out1", b"out2"])
+        await prod.send_offsets("cg-retry", {("src", 0): 2})
+
+        co = broker.tx_coordinator
+        fold, folds = co.router.commit_group_offsets, []
+
+        async def flaky(group_id, commits):
+            folds.append(group_id)
+            if len(folds) == 1:
+                return int(ErrorCode.coordinator_not_available)
+            return await fold(group_id, commits)
+
+        co.router.commit_group_offsets = flaky
+        ident = ("tx-retry", prod.producer_id, prod.epoch)
+        assert await co.end_txn(*ident, True) == ErrorCode.coordinator_not_available
+        md = co._txs["tx-retry"]
+        assert md.state.value == "PrepareCommit"
+        await co._finish(md, True, redrive=True)  # what expire_stale drives
+        assert md.state.value == "CompleteCommit" and len(folds) == 2
+        # the client's retry, and one in the other direction
+        assert await co.end_txn(*ident, True) == ErrorCode.none
+        assert await co.end_txn(*ident, False) == ErrorCode.invalid_txn_state
+        assert len(folds) == 2  # nothing was committed twice
+        rc, _ = await client.fetch("dst", 0, 0, isolation_level=1)
+        assert _values(rc) == [b"out1", b"out2"]
+        conn = await client.any_connection()
+        resp = await conn.request(m.OFFSET_FETCH, {
+            "group_id": "cg-retry",
+            "topics": [{"name": "src", "partition_indexes": [0]}],
+        })
+        assert resp["topics"][0]["partitions"][0]["committed_offset"] == 2
+        await _stop(server, broker, client)
+
+    run(main())
+
+
 def test_lso_blocks_read_committed_until_end(tmp_path):
     async def main():
         broker, server = await _start_broker(tmp_path)

@@ -211,74 +211,6 @@ def bench_explode_find(secs: float) -> dict:
     return out
 
 
-def bench_host_pool_scaling(secs: float) -> dict:
-    """Host-stage pool scaling: the same columnar launch at workers 1/2/4.
-
-    force_mode='columnar_host' keeps the whole run on host stages (explode
-    +find, extraction, numpy predicate, framing) — exactly the work the
-    pool shards — so the w4/w1 ratio is the pool's speedup, not device
-    noise. workers=1 is the inline path (the pool only exists at >= 2).
-    Rates are best-of-rounds (min-of-blocks posture: shared-machine load
-    spikes can only slow a round down)."""
-    from redpanda_tpu.coproc import TpuEngine, ProcessBatchRequest
-    from redpanda_tpu.coproc.engine import ProcessBatchItem
-    from redpanda_tpu.models import NTP
-    from redpanda_tpu.models.record import Record, RecordBatch
-    from redpanda_tpu.ops.exprs import field
-    from redpanda_tpu.ops.transforms import Int, Str, map_project, where
-
-    rng = np.random.default_rng(3)
-    spec = where(field("level") == "error") | map_project(Int("code"), Str("msg", 64))
-    batches = []
-    for _ in range(64):
-        recs = [
-            Record(
-                offset_delta=i,
-                value=json.dumps({
-                    "level": ["error", "info"][i % 2], "code": i,
-                    "msg": "x" * int(rng.integers(40, 90)),
-                }).encode(),
-            )
-            for i in range(64)
-        ]
-        batches.append(RecordBatch.build(recs, base_offset=0))
-    req = ProcessBatchRequest(
-        [ProcessBatchItem(1, NTP.kafka("bench", 0), batches)]
-    )
-    n_recs = 64 * 64
-    out = {}
-    for workers in (1, 2, 4):
-        engine = TpuEngine(
-            row_stride=256,
-            compress_threshold=10**9,
-            force_mode="columnar_host",
-            host_workers=workers,
-            host_pool_probe=False,  # this bench IS the capacity measurement
-        )
-        codes = engine.enable_coprocessors([(1, spec.to_json(), ("bench",))])
-        assert codes == [0]
-        engine.process_batch(req)  # warmup
-        best = 0.0
-        t_end = time.perf_counter() + secs
-        while time.perf_counter() < t_end:
-            t0 = time.perf_counter()
-            engine.process_batch(req)
-            best = max(best, n_recs / (time.perf_counter() - t0))
-        out[f"host_pool_w{workers}_recs_per_s"] = round(best, 1)
-    w1 = out["host_pool_w1_recs_per_s"]
-    out["host_pool_speedup_best"] = round(
-        max(out["host_pool_w2_recs_per_s"], out["host_pool_w4_recs_per_s"]) / w1, 3
-    )
-    # context for sub-1x results: synthetic thread-scaling on this box
-    # (quota-limited hosts advertise CPUs they don't have; the product
-    # engine calibrates on its real explode stage and self-demotes there)
-    from redpanda_tpu.coproc import host_pool
-
-    probe = host_pool.measure_parallel_capacity()
-    out["host_pool_synthetic_thread_speedup"] = probe["speedup"]
-    return out
-
-
 def bench_mesh_scaling(secs: float) -> dict:
     """Multi-chip mesh scaling: the config-5 sharded CRC+vote step
     (parallel.collectives.make_crc_vote_step — the device half of the
@@ -297,8 +229,8 @@ def bench_mesh_scaling(secs: float) -> dict:
     Threshold guidance for the gate: virtual host-platform devices share
     the box's real cores, so the achievable ratio is bounded by the
     MEASURED parallel capacity reported alongside
-    (``mesh_parallel_capacity``, same diagnostic the host-pool bench
-    carries) — on a quota-limited 1-core box the honest floor is ~1.0
+    (``mesh_parallel_capacity``) — on a quota-limited 1-core box the
+    honest floor is ~1.0
     (the sharded program must cost nothing over the 1-device mesh: a
     no-regression gate), while co-located multi-chip ICI justifies 1.5+.
     The engine itself never trusts this bench: the meshrunner's own
@@ -407,7 +339,7 @@ def bench_harvest_path(secs: float) -> dict:
     n_recs = 64 * 32
     stage_keys = (
         "t_extract_proj", "t_assemble", "t_rebuild",
-        "t_frame_gather", "t_seal", "t_sharded_seal",
+        "t_frame_gather", "t_seal",
     )
     ticks_per_block = 4
     out = {}
@@ -1429,7 +1361,6 @@ BENCHES = {
     "zstd_stream": bench_zstd_stream,
     "batch_codec": bench_batch_codec,
     "explode_find": bench_explode_find,
-    "host_pool_scaling": bench_host_pool_scaling,
     "mesh_scaling": bench_mesh_scaling,
     "harvest_path": bench_harvest_path,
     "compaction_index": bench_compaction_index,
@@ -1474,13 +1405,6 @@ def main(argv=None) -> int:
         "share of an rpc round trip exceeds PCT percent, OR if a disabled "
         "tracer adds ANY bytes to the wire; implies the "
         "trace_propagation_overhead bench",
-    )
-    p.add_argument(
-        "--assert-pool-speedup",
-        type=float,
-        metavar="RATIO",
-        help="fail (exit 1) if the host-stage pool's best speedup over "
-        "workers=1 falls below RATIO (e.g. 1.2); implies host_pool_scaling",
     )
     p.add_argument(
         "--assert-breaker-overhead",
@@ -1577,8 +1501,6 @@ def main(argv=None) -> int:
         and "trace_propagation_overhead" not in names
     ):
         names.append("trace_propagation_overhead")
-    if args.assert_pool_speedup is not None and "host_pool_scaling" not in names:
-        names.append("host_pool_scaling")
     if args.assert_mesh_speedup is not None and "mesh_scaling" not in names:
         names.append("mesh_scaling")
     if args.assert_breaker_overhead is not None and "breaker_overhead" not in names:
@@ -1638,15 +1560,6 @@ def main(argv=None) -> int:
                 f"disabled tracer added {extra} byte(s) to the wire "
                 f"(must be ZERO — header is feature-flagged on "
                 f"trace_enabled)",
-                file=sys.stderr,
-            )
-            return 1
-    if args.assert_pool_speedup is not None:
-        ratio = out.get("host_pool_speedup_best", 0.0)
-        if ratio < args.assert_pool_speedup:
-            print(
-                f"host pool speedup {ratio}x below floor "
-                f"{args.assert_pool_speedup}x",
                 file=sys.stderr,
             )
             return 1

@@ -328,8 +328,19 @@ class Program:
         classes = {c.cls for c in cands}
         if len(classes) == 1:
             return cands, False
-        if unique_methods or len(classes) > AMBIG_LIMIT:
+        if unique_methods:
             return [], len(classes) > 1
+        # a receiver named after its class (``arena.acquire``,
+        # ``self._host_pool.run``) narrows a common method name to the
+        # classes whose name ends with the receiver's last word: without
+        # it ``acquire`` / ``run`` exceed the bound and the lock graph
+        # loses the edges into Arena._lock and HostStagePool._lock
+        word = parts[-2].rsplit("_", 1)[-1].lower()
+        named = [c for c in cands if c.cls.lower().endswith(word)] if word else []
+        if named:
+            return named, len({c.cls for c in named}) > 1
+        if len(classes) > AMBIG_LIMIT:
+            return [], True
         return cands, True
 
     def calls_in(self, fn: ProgFunc) -> list[ast.Call]:

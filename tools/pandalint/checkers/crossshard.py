@@ -12,9 +12,9 @@ share everything, so the contract here is convention, and this checker
 is what keeps the convention honest.
 
 Naming convention the checker leans on (engine.py follows it): per-shard
-worker bodies carry a ``shard`` name token (``_run_columnar_shard``,
+worker bodies carry a ``shard`` name token (``_run_mesh_shard``,
 ``_frame_shard``); launch-wide coordinators use ``sharded``
-(``_dispatch_sharded``) and are exempt — they run on the submitter thread
+(``_framed_sharded``) and are exempt — they run on the submitter thread
 after the fan-in barrier and own the merge.
 
 Rules:
