@@ -206,6 +206,69 @@ def main() -> int:
         bad[-1] = len(bufs[1])  # a span that ends past ITS buffer
         check("frame_many_gather_ptrs bounds", gather_ptrs(bad)[0] == -1)
 
+    # ---- pointer-table pack (rp_pack_rows_ptrs): the payload lane's whole
+    # staging matrix in one crossing; parity with rp_pack_rows a batch plus
+    # the length column, into a matrix that held 0xFF, and the -1 on a bad span
+    if hasattr(dll, "rp_pack_rows_ptrs"):
+        dll.rp_pack_rows_ptrs.restype = ctypes.c_int64
+        dll.rp_pack_rows.restype = ctypes.c_int32
+        row = 48  # narrower than the longest values: they stage length 0
+        stride = row + 8
+        groups = [values[:4], [], values[4:]]  # the null value rides last
+        bufs = [b"\xff" + b"".join(v or b"" for v in g) for g in groups]
+        offs, lens, bounds, pos_row = [], [], [], 0
+        for g in groups:
+            pos = 1
+            for v in g:
+                offs.append(pos)
+                lens.append(-1 if v is None else len(v))
+                pos += len(v or b"")
+            bounds.append((pos_row, pos_row + len(g)))
+            pos_row += len(g)
+        nn, n_pad = len(offs), len(offs) + 5
+        starts = (ctypes.c_int64 * 3)(*(s for s, _ in bounds))
+        ends = (ctypes.c_int64 * 3)(*(e for _, e in bounds))
+        src_lens = (ctypes.c_int64 * 3)(*(len(b) for b in bufs))
+
+        def pack_ptrs(off_list):
+            dst = ctypes.create_string_buffer(b"\xff" * (n_pad * stride), n_pad * stride)
+            rc = dll.rp_pack_rows_ptrs(
+                (ctypes.c_char_p * 3)(*bufs), src_lens,
+                (ctypes.c_int64 * nn)(*off_list), (ctypes.c_int32 * nn)(*lens),
+                starts, ends, ctypes.c_int64(3), dst, ctypes.c_int64(nn),
+                ctypes.c_int64(n_pad), ctypes.c_size_t(row),
+            )
+            return rc, dst.raw
+
+        expect = bytearray()
+        for g, buf, (s, e) in zip(groups, bufs, bounds):
+            k = e - s
+            part = ctypes.create_string_buffer(max(k * stride, 1))
+            dll.rp_pack_rows(
+                buf, (ctypes.c_int64 * k)(*offs[s:e]),
+                (ctypes.c_int32 * k)(*lens[s:e]), ctypes.c_size_t(k), part,
+                ctypes.c_size_t(stride),
+            )
+            for i in range(k):
+                ln = lens[s + i]
+                staged_len = ln if 0 <= ln <= row else 0
+                r0 = part.raw[i * stride : i * stride + row]
+                expect += r0 + struct.pack("<I", staged_len) + b"\0" * 4
+        expect += b"\0" * ((n_pad - nn) * stride)
+        rc, got = pack_ptrs(offs)
+        check("pack_rows_ptrs bytes", rc == 0 and got == bytes(expect))
+        check(
+            "pack_rows_ptrs stages oversize/null as length 0",
+            any(ln > row for ln in lens) and lens[-1] == -1,
+        )
+        bad = list(offs)
+        bad[3] = len(bufs[0])  # a span that ends past ITS buffer
+        rc, got = pack_ptrs(bad)
+        check(
+            "pack_rows_ptrs bounds (-1, nothing written)",
+            rc == -1 and got == b"\xff" * (n_pad * stride),
+        )
+
     print(("PASS" if failures == 0 else f"FAIL ({failures})"))
     return 1 if failures else 0
 

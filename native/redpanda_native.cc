@@ -136,6 +136,51 @@ int32_t rp_pack_rows(const uint8_t* src, const int64_t* offsets,
   return truncated;
 }
 
+// The pointer-table twin of rp_pack_rows, and the payload staging lane's
+// whole pack stage in one crossing: batch r's records take their
+// (offset, len) RELATIVE to their own source buffer srcs[r] (one retained
+// decompressed payload buffer a batch: PtrExploded) and fill rows
+// [starts[r], ends[r]) of the launch's staging matrix dst
+// [n_pad, stride], stride = row_stride (the value part) + 8 meta bytes:
+// the value and its zeroed tail (rp_pack_rows over the batch's rows, so
+// the two symbols cannot diverge), then the LE32 length (0 for a null
+// value and for one wider than row_stride: staged, never transformed) and
+// four zero bytes; rows n..n_pad are cleared. dst may hold anything on
+// entry: a reused matrix comes out byte for byte like a fresh one. Every
+// span is bounds-checked against src_lens[r] BEFORE anything is written;
+// returns -1 on a span outside its buffer, else 0.
+int64_t rp_pack_rows_ptrs(const uint8_t* const* srcs, const int64_t* src_lens,
+                          const int64_t* offsets, const int32_t* lens,
+                          const int64_t* starts, const int64_t* ends,
+                          int64_t n_batches, uint8_t* dst, int64_t n,
+                          int64_t n_pad, size_t row_stride) {
+  const size_t stride = row_stride + 8;
+  for (int64_t r = 0; r < n_batches; r++) {
+    for (int64_t i = starts[r]; i < ends[r]; i++) {
+      int64_t vlen = lens[i] < 0 ? 0 : lens[i];
+      if (offsets[i] < 0 || offsets[i] + vlen > src_lens[r]) return -1;
+    }
+  }
+  for (int64_t r = 0; r < n_batches; r++) {
+    int64_t s = starts[r];
+    rp_pack_rows(srcs[r], offsets + s, lens + s, (size_t)(ends[r] - s),
+                 dst + (size_t)s * stride, stride);
+    for (int64_t i = s; i < ends[r]; i++) {
+      uint32_t len =
+          lens[i] < 0 || (size_t)lens[i] > row_stride ? 0u : (uint32_t)lens[i];
+      uint8_t* meta = dst + (size_t)i * stride + row_stride;
+      meta[0] = (uint8_t)len;
+      meta[1] = (uint8_t)(len >> 8);
+      meta[2] = (uint8_t)(len >> 16);
+      meta[3] = (uint8_t)(len >> 24);
+      std::memset(meta + 4, 0, 4);
+    }
+  }
+  if (n_pad > n)
+    std::memset(dst + (size_t)n * stride, 0, (size_t)(n_pad - n) * stride);
+  return 0;
+}
+
 // Gather rows back out into a contiguous buffer; returns total bytes.
 int64_t rp_unpack_rows(const uint8_t* src, size_t row_stride,
                        const int32_t* sizes, size_t n, uint8_t* dst) {

@@ -47,10 +47,11 @@ class Arena:
     # forever once traffic returns to normal size
     MAX_FREE = 8
 
-    def __init__(self) -> None:
+    def __init__(self, max_free: int = MAX_FREE) -> None:
         from redpanda_tpu.coproc import lockwatch
 
         self._lock = lockwatch.wrap(threading.Lock(), "Arena._lock")
+        self._max_free = max_free
         self._free: list[np.ndarray] = []
         self._allocs = 0
         self._reuses = 0
@@ -60,6 +61,11 @@ class Arena:
     def acquire(self, nbytes: int) -> np.ndarray:
         """A uint8 1-D buffer of AT LEAST nbytes (callers track their own
         logical lengths; the buffer may be bigger)."""
+        return self.take(nbytes)[0]
+
+    def take(self, nbytes: int) -> tuple[np.ndarray, bool]:
+        """``acquire`` that also says whether the buffer was a parked one
+        (True) or had to be allocated."""
         with self._lock:
             best = None
             for i, b in enumerate(self._free):
@@ -69,16 +75,16 @@ class Arena:
                     best = i
             if best is not None:
                 self._reuses += 1
-                return self._free.pop(best)
+                return self._free.pop(best), True
             self._allocs += 1
             self._alloc_bytes += max(nbytes, 1)
-        return np.empty(max(nbytes, 1), dtype=np.uint8)
+        return np.empty(max(nbytes, 1), dtype=np.uint8), False
 
     def release(self, buf: np.ndarray | None) -> None:
         if buf is None:
             return
         with self._lock:
-            if len(self._free) < self.MAX_FREE:
+            if len(self._free) < self._max_free:
                 self._free.append(buf)
             # else: drop — the launch that needed it can re-allocate
 
@@ -268,10 +274,9 @@ def explode_ptrs(batches: list[RecordBatch]) -> PtrExploded | None:
     None when the native packer is unavailable — the classic joined-blob
     lane is the fallback and the parity oracle."""
     lib = _native()
-    if lib is None:
-        # rp_pack_rows is a mandatory symbol — a .so without it fails
-        # _NativeLib binding entirely, so lib None IS the "packer
-        # unavailable" case
+    if lib is None or not getattr(lib, "has_pack_rows_ptrs", False):
+        # no library, or a stale one without the lane's packer
+        # (rp_pack_rows_ptrs, pack_exploded_ptrs below)
         return None
     payloads: list[bytes] = []
     rel_off: list[np.ndarray] = []
@@ -528,6 +533,19 @@ def frame_ranges_gather_ptrs(
         _frame_gather_py(payloads[r], offsets, lens, keep, s, e)
         for r, (s, e) in enumerate(ranges)
     ]
+
+
+def pack_exploded_ptrs(pe: PtrExploded, dst: np.ndarray, row_stride: int) -> None:
+    """Fill a payload launch's staging matrix ``dst`` [n_pad, row_stride +
+    8] from a pointer table in ONE native crossing (rp_pack_rows_ptrs):
+    values, zeroed tails, LE32 lengths (0 for a null value and for one
+    wider than ``row_stride``), zero meta bytes, cleared pad rows. ``dst``
+    may be a reused matrix holding anything. explode_ptrs hands out a
+    table only when the library has the symbol."""
+    starts, ends = _range_cols(pe.ranges)
+    _native().pack_rows_ptrs(
+        pe.payloads, pe.offsets, pe.sizes, starts, ends, dst, row_stride
+    )
 
 
 def frame_exploded_gather(

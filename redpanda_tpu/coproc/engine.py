@@ -137,6 +137,14 @@ class ProcessBatchReply:
     deregistered: list[int] = field(default_factory=list)
 
 
+# Parked staging matrices of the payload lane (TpuEngine._staging). A launch
+# in flight holds its matrix, so this bounds only the idle ones: one serves
+# a script's launches one after another, a few more a burst of launch_depth
+# launches that land together. NOT a leakwatch resource: a launch whose
+# device leg failed drops its matrix on purpose (_launch_payload).
+_STAGING_MAX_PARKED = 4
+
+
 def _bucket_rows(n: int) -> int:
     """Round the row count up so jit sees few distinct shapes."""
     b = 128
@@ -291,7 +299,7 @@ class _Launch:
                 eng.governor.breaker_for(faults.HARVEST).record_success()
         self._stat("t_fetch", t0)
         self._packed_dev = None
-        self._staged_np = None
+        self._park_staged()
         out, out_len, keep = unpack_result(packed, self.r_out)
         n = len(self.fits)
         return out[:n], out_len[:n], keep[:n] & self.fits
@@ -305,7 +313,7 @@ class _Launch:
         JAX_PLATFORMS=tpu has no CPU backend to fall back to. Raises when
         nothing was retained (the launch then follows ErrorPolicy, exactly
         like any unrecoverable script failure)."""
-        staged = self._staged_np  # pandalint: disable=RAC1102 -- the unlocked caller is _dispatch_payload, which runs BEFORE the launch is published to tickets (thread-local construction phase); every harvest-time caller reaches here under _Launch._lock via _materialize_locked / _gather_view
+        staged = self._staged_np
         eng = self.engine
         if staged is None or eng is None:
             raise RuntimeError(
@@ -315,8 +323,21 @@ class _Launch:
         packed = make_packed_pipeline_host(
             plan.spec, eng._row_stride, eng._mask_result(plan)
         )(staged)
+        # the device leg did not end in a landed result, so a transfer may
+        # still be reading the matrix: dropped here, never parked
+        # (_launch_payload has the rule)
+        self._staged_np = None  # pandalint: disable=RAC1101 -- the unlocked caller is _dispatch_payload, which runs BEFORE the launch is published to tickets (thread-local construction phase); every harvest-time caller reaches here under _Launch._lock via _materialize_locked / _gather_view
         eng._count_fallback(self.n)
         return packed
+
+    def _park_staged(self) -> None:
+        """The launch's device result has landed, so the program has
+        consumed its input: the staging matrix goes back to the engine's
+        pool. After a host fallback there is none left to give
+        (_launch_payload has the rule)."""
+        staged, self._staged_np = self._staged_np, None
+        if staged is not None and self.engine is not None:
+            self.engine._staging.release(staged.base)
 
     def _resolve_keep(self, slot, n: int) -> np.ndarray:
         """Resolve a keep mask from a mask holder — the launch itself or a
@@ -594,7 +615,7 @@ class _Launch:
             # payload mask launch: empty and null values are dropped by
             # the device's keep (lengths > 0), oversize ones by fits
             keep = self._resolve_keep(self, self.n) & self.fits
-            self._staged_np = None
+            self._park_staged()
         self._gather_mat = (ex, keep)
         return self._gather_mat
 
@@ -1035,6 +1056,7 @@ class TpuEngine:
         # launches through the arena (reset_arenas() for tests).
         self._gather_frame = bool(gather_frame)
         self._arena = leakwatch.wrap(batch_codec.Arena(), "engine.arena")
+        self._staging = batch_codec.Arena(max_free=_STAGING_MAX_PARKED)
         # Structural-index parse path (native rp_explode_find2 +
         # rp_extract_cols2): fused-vs-staged is a MEASURED per-engine
         # decision — the first representative columnar launch times BOTH
@@ -1459,6 +1481,7 @@ class TpuEngine:
         out["breakers"] = self.governor.breakers_snapshot()
         out["governor"] = self.governor.snapshot()
         out["arena"] = self._arena.stats()
+        out["staging_arena"] = self._staging.stats()
         if lockwatch.enabled():
             # debug mode only: the observed lock-order edge count rides
             # stats() into /v1/coproc/status, rpk debug coproc and BENCH
@@ -1548,14 +1571,15 @@ class TpuEngine:
     def _on_memory_pressure(self, level: str, snap: dict) -> None:
         """Budget-plane pressure transition (fired by BudgetPlane on level
         CHANGE, from whatever thread moved the occupancy). CRITICAL sheds
-        reclaimable memory: the arena free-list is trimmed and the column
+        reclaimable memory: both arenas' free lists (framing scratch,
+        parked staging matrices) are trimmed and the column
         cache evicts down to half its budget; OK restores the full cache
         budget. WARN only journals — the admission and autotune layers own
         the load response. Each transition is one ADMISSION-domain journal
         entry (level changes are rare by the plane's hysteresis)."""
         trims = evicted = 0
         if level == rm_budgets.PRESSURE_CRITICAL:
-            trims = self._arena.trim()
+            trims = self._arena.trim() + self._staging.trim()
             if self._colcache is not None:
                 evicted = self._colcache.set_pressure(True)
             self._stat_add("n_pressure_trims", 1.0)
@@ -1576,12 +1600,13 @@ class TpuEngine:
         )
 
     def reset_arenas(self) -> None:
-        """Swap in a fresh harvest scratch arena. The arena is deliberately
-        long-lived (buffer reuse across launches is the point), but tests
-        and bench ablations need deterministic alloc/reuse accounting —
-        and an engine parked after a giant launch can use this to return
-        the held buffers to the allocator."""
+        """Swap in a fresh harvest scratch arena and a fresh staging pool.
+        Both are deliberately long-lived (buffer reuse across launches is
+        the point), but tests and bench ablations need deterministic
+        alloc/reuse accounting — and an engine parked after a giant launch
+        can use this to return the held buffers to the allocator."""
         self._arena = leakwatch.wrap(batch_codec.Arena(), "engine.arena")
+        self._staging = batch_codec.Arena(max_free=_STAGING_MAX_PARKED)
 
     def reset_stats(self) -> None:
         with self._stats_lock:
@@ -2516,10 +2541,11 @@ class TpuEngine:
     def _dispatch_payload(self, launch: _Launch, exploded, n: int) -> None:
         """Stage and launch one payload plan over an exploded table: the
         classic joined-blob table, or the pointer table
-        (batch_codec.PtrExploded), whose staging packs each batch's records
-        straight from its retained decompressed payload buffer —
-        byte-identical staged rows, one fewer full copy of the launch's
-        record bytes. A launch whose result is the keep mask retains the
+        (batch_codec.PtrExploded), whose staging fills the matrix straight
+        from the batches' retained decompressed payload buffers in one
+        native crossing — byte-identical staged rows, one fewer full copy
+        of the launch's record bytes. Either way the matrix comes from the
+        staging pool. A launch whose result is the keep mask retains the
         table: its kept values are framed from it."""
         fn, r_out = self._pipelines[launch.script_id]
         launch.r_out = r_out
@@ -2546,7 +2572,22 @@ class TpuEngine:
         lanes. The result format follows the plan (_mask_result): the
         packed result matrix, fetched at harvest by _mat_payload, or the
         bit-packed keep mask, which rides the mask harvester and
-        _resolve_keep like a columnar predicate's."""
+        _resolve_keep like a columnar predicate's.
+
+        When a staging matrix goes back to the pool (the ONE rule):
+        ``jax.device_put`` returns long before the matrix has crossed the
+        link, and JAX reads the numpy memory until it has. So the launch
+        keeps the matrix until its device result has LANDED (the keep
+        mask resolved in _gather_view, or the result matrix fetched in
+        _mat_payload): the program has then consumed its input, and
+        _Launch._park_staged gives the matrix back. Whenever the host
+        fallback runs instead (breaker open, retries exhausted, envelope
+        timed out, harvest demoted), _payload_host_fallback drops the
+        matrix after reading it: a tried device leg may still be reading
+        it, so it never re-enters the pool. An abandoned launch's matrix
+        goes with the launch. Nothing reads the staged device array after
+        the result has landed (on a backend whose device_put aliases
+        numpy memory that is what makes reuse safe)."""
         import jax
 
         mask_result = self._mask_result(launch._plan)
@@ -2583,7 +2624,6 @@ class TpuEngine:
             packed = launch._payload_host_fallback()
             if mask_result:
                 launch._mask_np = packed
-                launch._staged_np = None
             else:
                 launch._packed_dev = packed
             self._stat_stage("t_dispatch", t0)
@@ -2890,6 +2930,17 @@ class TpuEngine:
             dict(TpuEngine._columnar_probe),
         )
 
+    def _take_staging(self, n_pad: int) -> np.ndarray:
+        """A [n_pad, row_stride + IN_META] staging matrix out of the pool,
+        holding anything: a parked one when one is big enough
+        (``n_staging_reuses``), else a new one. _Launch._park_staged gives
+        it back (its ``.base`` is the pool's buffer)."""
+        stride = self._row_stride + IN_META
+        buf, reused = self._staging.take(n_pad * stride)
+        if reused:
+            self._stat_add("n_staging_reuses", 1.0)
+        return buf[: n_pad * stride].reshape(n_pad, stride)
+
     def _pack_staged(self, exploded, n_pad: int) -> np.ndarray:
         """[n_pad, row_stride + IN_META] uint8: record bytes then LE32 length.
 
@@ -2900,59 +2951,38 @@ class TpuEngine:
         silently).
         """
         r = self._row_stride
-        stride = r + IN_META
         n = len(exploded.sizes)
-        offsets = exploded.offsets
         sizes = exploded.sizes
-        if n_pad != n:
-            offsets = np.concatenate([offsets, np.zeros(n_pad - n, np.int64)])
-            sizes = np.concatenate([sizes, np.zeros(n_pad - n, np.int32)])
-        fits = sizes <= r
-        lens = np.where(fits, sizes, 0).astype("<i4")
+        staged = self._take_staging(n_pad)
         try:
             from redpanda_tpu.native import lib
         except Exception:
             lib = None
         if lib is not None:
-            staged, _ = lib.pack_rows(exploded.joined, offsets, sizes, stride)
+            lib.pack_rows_into(
+                exploded.joined, exploded.offsets, sizes, staged[:n]
+            )
         else:
             from redpanda_tpu.ops.packing import pack_rows
 
             vals = [
-                exploded.joined[o : o + s] for o, s in zip(offsets, np.minimum(sizes, r))
+                exploded.joined[o : o + s]
+                for o, s in zip(exploded.offsets, np.minimum(sizes, r))
             ]
-            staged, _ = pack_rows(vals, stride)
-        staged[:, r : r + 4] = lens.view(np.uint8).reshape(n_pad, 4)
-        staged[:, r + 4 :] = 0
+            staged[:n, :r] = pack_rows(vals, r)[0]
+        staged[n:] = 0
+        lens = np.where(sizes <= r, sizes, 0).astype("<i4")
+        staged[:n, r : r + 4] = lens.view(np.uint8).reshape(n, 4)
+        staged[:n, r + 4 :] = 0
         return staged
 
     def _pack_staged_ptrs(self, pe, n_pad: int) -> np.ndarray:
         """_pack_staged's pointer-table twin: the staging matrix fills
         straight from each batch's retained decompressed payload buffer
-        (batch_codec.PtrExploded) — no joined blob is ever built or
-        re-read. Byte-identical output to _pack_staged over the merged
-        exploded table (the staging parity test pins it)."""
-        from redpanda_tpu.native import lib
-
-        r = self._row_stride
-        stride = r + IN_META
-        n = len(pe.sizes)
-        staged = np.empty((n_pad, stride), dtype=np.uint8)
-        row = 0
-        for payload, off, ln in zip(pe.payloads, pe.rel_off, pe.rel_len):
-            k = len(ln)
-            if k:
-                # rp_pack_rows clamps sizes to the stride and zero-fills
-                # each row's tail, so per-batch packing into row slices is
-                # byte-identical to one whole-launch pack
-                lib.pack_rows_into(payload, off, ln, staged[row : row + k])
-            row += k
-        if n_pad > n:
-            staged[n:] = 0
-        fits = pe.sizes <= r
-        lens = np.where(fits, pe.sizes, 0).astype("<i4")
-        if n_pad > n:
-            lens = np.concatenate([lens, np.zeros(n_pad - n, "<i4")])
-        staged[:, r : r + 4] = lens.view(np.uint8).reshape(n_pad, 4)
-        staged[:, r + 4 :] = 0
+        (batch_codec.PtrExploded) in one native crossing — no joined blob
+        is ever built or re-read. Byte-identical output to _pack_staged
+        over the merged exploded table, into a fresh matrix or a reused
+        one (the staging parity test pins it)."""
+        staged = self._take_staging(n_pad)
+        batch_codec.pack_exploded_ptrs(pe, staged, self._row_stride)
         return staged

@@ -225,6 +225,15 @@ class _NativeLib:
                 ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ]
+        self.has_pack_rows_ptrs = hasattr(dll, "rp_pack_rows_ptrs")
+        if self.has_pack_rows_ptrs:
+            dll.rp_pack_rows_ptrs.restype = ctypes.c_int64
+            dll.rp_pack_rows_ptrs.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_size_t,
+            ]
         dll.rp_json_find.restype = ctypes.c_int32
         dll.rp_json_find.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int32,
@@ -281,11 +290,11 @@ class _NativeLib:
         dst: np.ndarray,
     ) -> int:
         """rp_pack_rows into a CALLER-provided [n, stride] row block — a
-        contiguous slice of a larger staging matrix. The pointer-table
-        payload staging lane packs each batch's records straight from its
-        decompressed payload buffer this way, so no joined blob is ever
-        built. The C loop clamps sizes to the stride and zero-fills every
-        row tail (byte parity with a whole-launch pack_rows)."""
+        contiguous slice of a larger staging matrix: the classic
+        joined-blob staging road packs a whole launch into the head of its
+        pooled matrix this way (the pointer-table lane has
+        pack_rows_ptrs). The C loop clamps sizes to the stride and
+        zero-fills every row tail (byte parity with pack_rows)."""
         offsets = np.ascontiguousarray(offsets, dtype=np.int64)
         sizes = np.ascontiguousarray(sizes, dtype=np.int32)
         n, stride = dst.shape
@@ -306,6 +315,59 @@ class _NativeLib:
             src_arr.ctypes.data, offsets.ctypes.data, sizes.ctypes.data,
             n, dst.ctypes.data, stride,
         )
+
+    def pack_rows_ptrs(
+        self,
+        srcs: list[bytes],
+        offsets: np.ndarray,
+        lens: np.ndarray,
+        starts: np.ndarray,
+        ends: np.ndarray,
+        dst: np.ndarray,
+        row_stride: int,
+    ) -> None:
+        """Fill a payload launch's whole staging matrix in ONE crossing
+        (rp_pack_rows_ptrs): batch r's records, their (offset, len)
+        relative to their own buffer ``srcs[r]``, become rows
+        [starts[r], ends[r]) of ``dst`` [n_pad, row_stride + 8]: value,
+        zeroed tail, LE32 length (0 for a null value and for one wider
+        than ``row_stride``), four zero bytes; the rows past the last
+        range are cleared. ``dst`` may be a reused matrix holding
+        anything. The ranges must tile [0, n) in order; a span outside its
+        buffer is a ValueError and nothing has been written."""
+        offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+        lens = np.ascontiguousarray(lens, dtype=np.int32)
+        starts = np.ascontiguousarray(starts, dtype=np.int64)
+        ends = np.ascontiguousarray(ends, dtype=np.int64)
+        n = len(offsets)
+        n_batches = len(starts)
+        if len(srcs) != n_batches or len(ends) != n_batches:
+            raise ValueError("srcs/ranges length mismatch")
+        if len(lens) != n:
+            raise ValueError("offsets/lens length mismatch")
+        if dst.dtype != np.uint8 or not dst.flags["C_CONTIGUOUS"]:
+            raise ValueError("pack_rows_ptrs dst must be contiguous uint8")
+        n_pad, stride = dst.shape
+        if stride != row_stride + 8 or n_pad < n:
+            raise ValueError("pack_rows_ptrs dst shape does not fit the launch")
+        # every row is written exactly once: the ranges tile [0, n)
+        edges = np.concatenate(([0], ends))
+        if (
+            edges[-1] != n
+            or (starts > ends).any()
+            or not np.array_equal(edges[:-1], starts)
+        ):
+            raise ValueError("pack_rows_ptrs ranges do not tile the rows")
+        # bytes -> borrowed char*; the ctypes array retains the objects
+        ptrs = (ctypes.c_char_p * n_batches)(*srcs)
+        src_lens = np.fromiter((len(b) for b in srcs), np.int64, n_batches)
+        rc = self._dll.rp_pack_rows_ptrs(
+            ptrs, src_lens.ctypes.data, offsets.ctypes.data,
+            lens.ctypes.data, starts.ctypes.data, ends.ctypes.data,
+            n_batches, dst.ctypes.data, n, n_pad, row_stride,
+        )
+        if rc < 0:
+            raise ValueError("pack span outside its source buffer")
 
     def parse_record_values(self, payload: bytes, count: int) -> tuple[np.ndarray, np.ndarray]:
         """Offsets/lengths of each record's value within a batch payload."""

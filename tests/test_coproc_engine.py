@@ -306,16 +306,7 @@ def test_columnar_host_ablation_matches_device_mode():
         host.shutdown()
 
 
-def test_pack_staged_ptr_lane_bit_parity():
-    """The pointer-table payload staging (_pack_staged_ptrs over
-    batch_codec.explode_ptrs — no joined blob) produces byte-identical
-    staging matrices to the classic joined-blob _pack_staged, across
-    compression, empty batches, varied sizes and records wider than the
-    row stride."""
-    import numpy as np
-
-    from redpanda_tpu.coproc import batch_codec
-    from redpanda_tpu.coproc.engine import _bucket_rows
+def _staging_batches(n_batches=5):
     from redpanda_tpu.models.record import Record as R, RecordBatch as RB
 
     def mk(n, codec=Compression.none, wide=False):
@@ -328,18 +319,156 @@ def test_pack_staged_ptr_lane_bit_parity():
         ]
         return RB.build(recs, base_offset=0, compression=codec)
 
-    batches = [mk(12), mk(0), mk(5, Compression.gzip), mk(9, wide=True), mk(3)]
+    shapes = [mk(12), mk(0), mk(5, Compression.gzip), mk(9, wide=True), mk(3)]
+    return [shapes[i % len(shapes)] for i in range(n_batches)]
+
+
+@pytest.mark.parametrize("pool", ["fresh", "reused_dirty", "smaller_after_larger"])
+def test_pack_staged_ptr_lane_bit_parity(pool, monkeypatch):
+    """The pointer-table payload staging (_pack_staged_ptrs over
+    batch_codec.explode_ptrs — no joined blob, one native crossing)
+    produces byte-identical staging matrices to the classic joined-blob
+    _pack_staged, across compression, empty batches, varied sizes and
+    records wider than the row stride — into a fresh matrix, into a parked
+    one that a larger launch left all 0xFF, and into the front of a larger
+    launch's matrix when the bucket shrinks: pad rows and meta bytes
+    included."""
+    import numpy as np
+
+    from redpanda_tpu.coproc import batch_codec
+    from redpanda_tpu.coproc.engine import _bucket_rows
+
+    batches = _staging_batches()
     pe = batch_codec.explode_ptrs(batches)
     if pe is None:
         pytest.skip("native packer unavailable")
     ex = batch_codec.explode_batches(batches)
     assert pe.ranges == ex.ranges
     assert np.array_equal(pe.sizes, ex.sizes)
-    engine = TpuEngine(row_stride=128)
+    oracle = TpuEngine(row_stride=128)
     n_pad = _bucket_rows(len(ex.sizes))
-    classic = engine._pack_staged(ex, n_pad)
+    classic = oracle._pack_staged(ex, n_pad)
+    assert classic.shape == (n_pad, 136) and n_pad > len(ex.sizes)
+    oracle.shutdown()
+
+    engine = TpuEngine(row_stride=128)
+    if pool == "reused_dirty":
+        big = engine._take_staging(4 * n_pad)
+        big[:] = 0xFF
+        engine._staging.release(big.base)
+    elif pool == "smaller_after_larger":
+        larger = batch_codec.explode_ptrs(_staging_batches(40))
+        assert _bucket_rows(len(larger.sizes)) > n_pad
+        big = engine._pack_staged_ptrs(larger, _bucket_rows(len(larger.sizes)))
+        engine._staging.release(big.base)
     ptr = engine._pack_staged_ptrs(pe, n_pad)
     assert np.array_equal(classic, ptr)
+    st = engine.stats()
+    if pool == "fresh":
+        assert st["staging_arena"]["reuses"] == 0
+        assert "n_staging_reuses" not in st
+    else:
+        # the parked matrix of the larger launch served this one
+        assert ptr.base is big.base
+        assert st["staging_arena"]["reuses"] == 1
+        assert st["n_staging_reuses"] == 1
+    # the classic road draws from the same pool, and packs the same bytes
+    # over whatever the matrix held, with the native library and without
+    import redpanda_tpu.native as native_mod
+
+    for lib in (native_mod.lib, None):
+        monkeypatch.setattr(native_mod, "lib", lib)
+        ptr[:] = 0xEE
+        engine._staging.release(ptr.base)
+        again = engine._pack_staged(ex, n_pad)
+        assert again.base is ptr.base and np.array_equal(classic, again)
+    engine.shutdown()
+
+
+def test_pack_rows_ptrs_bad_span_raises_and_writes_nothing():
+    """Every span is bounds-checked against ITS buffer before the first
+    byte is written: a table whose record runs past its batch's payload is
+    a ValueError and the (reused) matrix still holds what it held."""
+    import numpy as np
+
+    from redpanda_tpu.coproc import batch_codec
+
+    pe = batch_codec.explode_ptrs(_staging_batches())
+    if pe is None:
+        pytest.skip("native packer unavailable")
+    from redpanda_tpu.native import lib
+
+    starts, ends = batch_codec._range_cols(pe.ranges)
+    n = len(pe.sizes)
+    dst = np.full((128, 136), 0xAB, np.uint8)
+    for bad_row, bad_off in (
+        (n - 1, len(pe.payloads[-1]) - int(pe.sizes[-1]) + 1),  # ends 1 past
+        (0, -1),
+    ):
+        offsets = pe.offsets
+        offsets[bad_row] = bad_off
+        with pytest.raises(ValueError):
+            lib.pack_rows_ptrs(
+                pe.payloads, offsets, pe.sizes, starts, ends, dst, 128
+            )
+        assert (dst == 0xAB).all()
+    # a span inside ANOTHER batch's buffer length but outside its own is
+    # still outside: the check is per buffer
+    assert len(pe.payloads[3]) > len(pe.payloads[4])
+    offsets = pe.offsets
+    offsets[n - 1] = len(pe.payloads[4])
+    with pytest.raises(ValueError):
+        lib.pack_rows_ptrs(pe.payloads, offsets, pe.sizes, starts, ends, dst, 128)
+    assert (dst == 0xAB).all()
+    # ranges that do not tile the rows, or a matrix of the wrong shape
+    with pytest.raises(ValueError):
+        lib.pack_rows_ptrs(
+            pe.payloads, pe.offsets, pe.sizes, starts, ends - 1, dst, 128
+        )
+    with pytest.raises(ValueError):
+        lib.pack_rows_ptrs(
+            pe.payloads, pe.offsets, pe.sizes, starts, ends, dst[:, :130], 128
+        )
+    with pytest.raises(ValueError):
+        lib.pack_rows_ptrs(
+            pe.payloads, pe.offsets, pe.sizes, starts, ends, dst[: n - 1], 128
+        )
+    assert (dst == 0xAB).all()
+    lib.pack_rows_ptrs(pe.payloads, pe.offsets, pe.sizes, starts, ends, dst, 128)
+    assert not (dst[n:] != 0).any()
+
+
+def test_pack_staged_null_empty_and_oversize_values_stage_length_zero():
+    """A null value, an empty value and a value wider than the staging row
+    all stage length 0 on both roads (the device's keep drops length 0,
+    launch.fits drops the oversize one): staged, never truncated."""
+    import numpy as np
+
+    from redpanda_tpu.coproc import batch_codec
+    from redpanda_tpu.models.record import Record as R, RecordBatch as RB
+
+    values = [b"abc", None, b"", b"x" * 64, b"y" * 65, b"z" * 200, b"tail"]
+    batch = RB.build(
+        [R(offset_delta=i, value=v) for i, v in enumerate(values)], base_offset=0
+    )
+    engine = TpuEngine(row_stride=64)
+    ex = batch_codec.explode_batches([batch])
+    mats = [engine._pack_staged(ex, 128)]
+    pe = batch_codec.explode_ptrs([batch])
+    if pe is not None:
+        assert [int(x) for x in pe.rel_len[0]] == [3, -1, 0, 64, 65, 200, 4]
+        dirty = engine._take_staging(128)
+        dirty[:] = 0xFF
+        engine._staging.release(dirty.base)
+        mats.append(engine._pack_staged_ptrs(pe, 128))
+        assert np.array_equal(mats[0], mats[1])
+    for staged in mats:
+        lens = staged[:, 64:68].copy().view("<i4")[:, 0]
+        assert lens[: len(values)].tolist() == [3, 0, 0, 64, 0, 0, 4]
+        assert not lens[len(values):].any() and not staged[:, 68:].any()
+        assert bytes(staged[0, :4]) == b"abc\0" and not staged[1, :64].any()
+        # an oversize value's first row_stride bytes ride along, length 0
+        assert bytes(staged[4, :64]) == b"y" * 64
     engine.shutdown()
 
 
@@ -505,6 +634,95 @@ def test_payload_mask_launch_faults_end_in_the_exact_host_fallback(name, monkeyp
         assert stats["breakers"]["device_dispatch"]["state"] == faults.STATE_CLOSED
     if name == "dispatch_fault":
         assert stats.get("n_device_launches", 0) == 0
+
+
+@pytest.mark.parametrize("probe", [None, "device_dispatch", "harvest"])
+@pytest.mark.parametrize("gather", [True, False], ids=["mask", "matrix"])
+def test_staging_matrix_parks_only_after_a_landed_result(gather, probe):
+    """A launch's staging matrix re-enters the pool only once its device
+    result has landed; a second launch of the same bucket then takes it
+    (n_staging_reuses). A launch whose device leg failed (dispatch or
+    harvest, host fallback taken) DROPS its matrix — a transfer may still
+    be reading it — and its reply is still exact."""
+    from redpanda_tpu.coproc import faults
+    from redpanda_tpu.finjector import honey_badger
+
+    clean = _mask_engine(gather_frame=gather)
+    baseline = _bits(clean.process_batch(_mask_req()))
+    clean.shutdown()
+    assert any(batches for _, batches in baseline)
+
+    engine = _mask_engine(
+        gather_frame=gather, launch_retries=0, breaker_threshold=100
+    )
+    honey_badger.enable()
+    if probe:
+        honey_badger.set_exception(faults.MODULE, probe)
+    try:
+        first = _bits(engine.process_batch(_mask_req()))
+        st1 = engine.stats()
+    finally:
+        if probe:
+            honey_badger.unset(faults.MODULE, probe)
+        honey_badger.disable()
+    try:
+        second = _bits(engine.process_batch(_mask_req()))
+        st2 = engine.stats()
+    finally:
+        engine.shutdown()
+    assert first == baseline and second == baseline
+    assert st1["staging_arena"]["allocs"] == 1
+    if probe is None:
+        assert st1["staging_arena"]["free_buffers"] == 1
+        assert "n_fallback_rows" not in st1
+        assert st2["n_staging_reuses"] == 1
+        assert st2["staging_arena"]["allocs"] == 1
+        assert st2["staging_arena"]["reuses"] == 1
+    else:
+        assert st1["n_fallback_rows"] == 51
+        assert st1["staging_arena"]["free_buffers"] == 0  # dropped, not parked
+        # nothing was parked, so the next launch allocates; it lands and parks
+        assert "n_staging_reuses" not in st2
+        assert st2["staging_arena"]["allocs"] == 2
+        assert st2["staging_arena"]["reuses"] == 0
+    assert st2["staging_arena"]["free_buffers"] == 1
+    assert st2["n_device_launches"] == (2 if probe != "device_dispatch" else 1)
+
+
+def test_staging_pool_is_reset_and_trimmed_with_the_arena():
+    """reset_arenas() swaps the staging pool with the framing arena, the
+    memory-pressure hook trims both, and the pool parks at most
+    _STAGING_MAX_PARKED matrices."""
+    from redpanda_tpu.coproc import engine as engine_mod
+    from redpanda_tpu.resource_mgmt import budgets
+
+    engine = _mask_engine()
+    engine.process_batch(_mask_req())
+    engine.process_batch(_mask_req())
+    st = engine.stats()
+    assert st["staging_arena"]["free_buffers"] == 1
+    assert st["staging_arena"]["reuses"] == 1
+    engine._on_memory_pressure(budgets.PRESSURE_CRITICAL, {})
+    st = engine.stats()
+    assert st["staging_arena"]["free_buffers"] == 0
+    assert st["staging_arena"]["trims"] == 1 and st["arena"]["trims"] == 1
+    engine.process_batch(_mask_req())  # allocates again, parks again
+    assert engine.stats()["staging_arena"]["allocs"] == 2
+    assert engine.stats()["staging_arena"]["free_buffers"] == 1
+    engine.reset_arenas()
+    st = engine.stats()
+    assert st["staging_arena"] == {
+        "allocs": 0, "reuses": 0, "alloc_bytes": 0, "free_buffers": 0, "trims": 0,
+    }
+    assert st["arena"]["allocs"] == 0
+    held = [engine._take_staging(128) for _ in range(6)]
+    for staged in held:
+        engine._staging.release(staged.base)
+    assert (
+        engine.stats()["staging_arena"]["free_buffers"]
+        == engine_mod._STAGING_MAX_PARKED
+    )
+    engine.shutdown()
 
 
 def test_payload_mask_reentry_after_framing_failure_keeps_the_mask(monkeypatch):
