@@ -569,7 +569,7 @@ class _Launch:
                             ex, keep, self.ranges, arena=arena
                         )
                         self._stat("t_frame_gather", t0)
-                        self._count_frame("n_frame_gather")
+                        self._count_frame("n_frame_gather", ex.sizes, keep)
                         self._exploded = None
                         self._gather_mat = None
                     else:
@@ -579,7 +579,7 @@ class _Launch:
                             out, out_len, keep, self.ranges, arena=arena
                         )
                         self._stat("t_rebuild", t0)
-                        self._count_frame("n_frame_padded")
+                        self._count_frame("n_frame_padded", out_len, keep)
             return self._framed
 
     def _gather_view(self):
@@ -619,11 +619,18 @@ class _Launch:
         self._gather_mat = (ex, keep)
         return self._gather_mat
 
-    def _count_frame(self, key: str) -> None:
+    def _count_frame(self, key: str, lens: np.ndarray, keep: np.ndarray) -> None:
+        """One framing crossing (launch- or shard-level) on either road:
+        which road, the rows it kept and the value bytes it framed
+        (``lens`` of the kept rows: 70 B a row of config 4's projection,
+        the value itself for a filter). Three adds a crossing, nothing
+        per record."""
         eng = self.engine
         if eng is None:
             return
         eng._stat_add(key, 1.0)
+        eng._stat_add("n_kept_rows", float(np.count_nonzero(keep)))
+        eng._stat_add("bytes_out", float(lens[keep].sum()))
         # decision-plane bookkeeping: which framing path this launch took.
         # record_mode journals only on CHANGE (first engagement or a mode
         # flip); the steady-state cost is one lock + one compare per launch
@@ -671,7 +678,7 @@ class _Launch:
                 arena=arena,
             )
             self._stat("t_shard_frame_gather", t0)
-            self._count_frame("n_frame_gather")
+            self._count_frame("n_frame_gather", ex.sizes, keep)
             return framed
         t0 = _stage_t0("t_shard_assemble")
         if shard.n == 0:
@@ -691,7 +698,7 @@ class _Launch:
             rows, lens, keep, shard.ranges, arena=arena
         )
         self._stat("t_shard_rebuild", t0)
-        self._count_frame("n_frame_padded")
+        self._count_frame("n_frame_padded", lens, keep)
         return framed
 
     def _framed_sharded(self) -> list[tuple[bytes, int]]:
@@ -1636,6 +1643,10 @@ class TpuEngine:
                 probes.coproc_harvest_gather.inc(v)
             elif key == "n_frame_padded":
                 probes.coproc_harvest_padded.inc(v)
+            elif key == "n_kept_rows":
+                probes.coproc_kept_rows.inc(v)
+            elif key == "bytes_out":
+                probes.coproc_output_bytes.inc(v)
 
     def _stat_stage(self, key: str, t0: float, trace_id=_AMBIENT) -> float:
         """Close one stage timer (``t0 = _stage_t0(key)``) through the stage

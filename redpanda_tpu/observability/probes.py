@@ -107,6 +107,17 @@ coproc_oversize_rows = registry.counter(
     "coproc_oversize_rows_total",
     "Values wider than the staging row, which the payload lane drops",
 )
+# What a launch's harvest lets through, on either framing road: the rows it
+# keeps and the value bytes it frames into output batches (the map's
+# shrink: 70 B a kept row of config 4's projection against ~1 KB in).
+coproc_kept_rows = registry.counter(
+    "coproc_kept_rows_total",
+    "Rows the harvest kept and framed into output batches",
+)
+coproc_output_bytes = registry.counter(
+    "coproc_output_bytes_total",
+    "Value bytes the harvest framed into output batches",
+)
 coproc_launch_rows_hist = registry.histogram(
     "coproc_launch_rows",
     "Records fused into one device launch (bucket size after shape rounding)",
@@ -454,9 +465,11 @@ __all__ = [
     "coproc_harvest_padded",
     "coproc_host_pool_busy",
     "coproc_input_wait_hist",
+    "coproc_kept_rows",
     "coproc_launch_rows_hist",
     "coproc_leakwatch_imbalance",
     "coproc_lockwatch_edges",
+    "coproc_output_bytes",
     "coproc_oversize_rows",
     "coproc_retries_total",
     "coproc_shard_rows_hist",

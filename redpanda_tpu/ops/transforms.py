@@ -28,6 +28,7 @@ partitions ride the leading axis and shard over the mesh 'p' axis
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 from dataclasses import dataclass
@@ -383,65 +384,80 @@ def packbits(xp, keep):
     return (b * weights[None, :]).sum(axis=1).astype(xp.uint8)
 
 
-def _transform_body(xp, spec: TransformSpec, r_out: int):
+def _project(xp, mapper: _MapProject, data, lengths):
+    """(out uint8 [N, r_out], ok bool [N]) of ``map_project``: the
+    fixed-width struct of every row, and whether the row's projection
+    could be made faithfully (a row it could not is dropped)."""
+    parts = []
+    ok_all = xp.ones(data.shape[0], dtype=bool)
+    for f in mapper.fields:
+        if isinstance(f, Int):
+            pat = f'"{f.key}":'.encode()
+            pos = _find_pattern(xp, data, lengths, pat)
+            vpos = xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
+            val, ok = _parse_int_at(xp, data, vpos)
+            ok_all = ok_all & ok
+            le = val.astype(xp.uint32)
+            parts.append(
+                xp.stack(
+                    [(le >> (8 * k)).astype(xp.uint8) for k in range(4)], axis=1
+                )
+            )
+        else:
+            pat = f'"{f.key}":"'.encode()
+            pos = _find_pattern(xp, data, lengths, pat)
+            spos = xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
+            win = _gather_window(xp, data, spos, f.max_len + 1)
+            slen = _find_byte_from(xp, win, ord('"'))
+            found_quote = slen <= f.max_len
+            slen = xp.minimum(slen, f.max_len)
+            ok_all = ok_all & (pos >= 0) & found_quote
+            body = win[:, : f.max_len]
+            mask = xp.arange(f.max_len, dtype=xp.int32)[None, :] < slen[:, None]
+            body = xp.where(mask, body, xp.uint8(0))
+            lenhdr = xp.stack(
+                [
+                    (slen & 0xFF).astype(xp.uint8),
+                    ((slen >> 8) & 0xFF).astype(xp.uint8),
+                ],
+                axis=1,
+            )
+            parts.append(xp.concatenate([lenhdr, body], axis=1))
+    return xp.concatenate(parts, axis=1), ok_all
+
+
+def _transform_body(
+    xp, spec: TransformSpec, r_out: int, scope=contextlib.nullcontext
+):
     """The transform as array code over namespace ``xp``: jax.numpy for the
     device program, numpy for the engine's host fallback. Every operation
-    is an integer or boolean one, so the two evaluate bit-identically."""
+    is an integer or boolean one, so the two evaluate bit-identically.
+    ``scope``: ``jax.named_scope`` for the device program, so that a
+    profile's operations carry the stage that owns them (``filter``,
+    ``project``); the numpy twin takes the null scope."""
     mapper = spec.mapper
 
     def rp_transform(data, lengths):
         data = data.astype(xp.uint8)
         lengths = lengths.astype(xp.int32)
-        keep = lengths > 0
-        for f in spec.filters:
-            idx = _find_pattern(xp, data, lengths, f.pattern, f.require_nonnum_suffix)
-            hit = idx >= 0
-            keep = keep & (~hit if f.negate else hit)
+        with scope("filter"):
+            keep = lengths > 0
+            for f in spec.filters:
+                idx = _find_pattern(
+                    xp, data, lengths, f.pattern, f.require_nonnum_suffix
+                )
+                hit = idx >= 0
+                keep = keep & (~hit if f.negate else hit)
 
         if isinstance(mapper, _MapUppercase):
             is_lower = (data >= ord("a")) & (data <= ord("z"))
             out = xp.where(is_lower, data - 32, data)
             return out, lengths, keep
         if isinstance(mapper, _MapProject):
-            n = data.shape[0]
-            parts = []
-            ok_all = xp.ones(n, dtype=bool)
-            for f in mapper.fields:
-                if isinstance(f, Int):
-                    pat = f'"{f.key}":'.encode()
-                    pos = _find_pattern(xp, data, lengths, pat)
-                    vpos = xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
-                    val, ok = _parse_int_at(xp, data, vpos)
-                    ok_all = ok_all & ok
-                    le = val.astype(xp.uint32)
-                    parts.append(
-                        xp.stack(
-                            [(le >> (8 * k)).astype(xp.uint8) for k in range(4)], axis=1
-                        )
-                    )
-                else:
-                    pat = f'"{f.key}":"'.encode()
-                    pos = _find_pattern(xp, data, lengths, pat)
-                    spos = xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
-                    win = _gather_window(xp, data, spos, f.max_len + 1)
-                    slen = _find_byte_from(xp, win, ord('"'))
-                    found_quote = slen <= f.max_len
-                    slen = xp.minimum(slen, f.max_len)
-                    ok_all = ok_all & (pos >= 0) & found_quote
-                    body = win[:, : f.max_len]
-                    mask = xp.arange(f.max_len, dtype=xp.int32)[None, :] < slen[:, None]
-                    body = xp.where(mask, body, xp.uint8(0))
-                    lenhdr = xp.stack(
-                        [
-                            (slen & 0xFF).astype(xp.uint8),
-                            ((slen >> 8) & 0xFF).astype(xp.uint8),
-                        ],
-                        axis=1,
-                    )
-                    parts.append(xp.concatenate([lenhdr, body], axis=1))
-            out = xp.concatenate(parts, axis=1)
-            keep2 = keep & ok_all
-            out_len = xp.where(keep2, xp.int32(r_out), 0)
+            with scope("project"):
+                out, ok_all = _project(xp, mapper, data, lengths)
+                keep2 = keep & ok_all
+                out_len = xp.where(keep2, xp.int32(r_out), 0)
             return out, out_len, keep2
         # identity map
         return data, lengths, keep
@@ -455,7 +471,7 @@ def _compile_cached(spec_json: str, r_in: int):
     import jax.numpy as jnp
 
     spec, r_out = _validated(spec_json, r_in)
-    return jax.jit(_transform_body(jnp, spec, r_out)), r_out
+    return jax.jit(_transform_body(jnp, spec, r_out, jax.named_scope)), r_out
 
 
 def compile_transform(spec: TransformSpec, r_in: int):
