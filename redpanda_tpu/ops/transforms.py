@@ -304,13 +304,36 @@ def _find_pattern(jnp, data, lengths, pat: bytes, require_nonnum_suffix: bool = 
     return jnp.where(match.any(axis=1), idx, jnp.int32(-1))
 
 
-def _gather_window(jnp, data, pos, width: int):
-    """data[i, pos[i] : pos[i]+width], zero-filled out of range. pos<0 -> zeros."""
-    n, r = data.shape
-    cols = pos[:, None] + jnp.arange(width, dtype=jnp.int32)[None, :]
-    valid = (cols >= 0) & (cols < r) & (pos >= 0)[:, None]
-    window = jnp.take_along_axis(data, jnp.clip(cols, 0, r - 1), axis=1)
-    return jnp.where(valid, window, jnp.uint8(0))
+def _columns(xp, row, start: int, count: int):
+    """row[:, start : start+count], zero-filled past the last column."""
+    part = row[:, start : start + count]
+    short = count - part.shape[1]
+    return xp.pad(part, ((0, 0), (0, short))) if short else part
+
+
+def _gather_window(xp, data, pos, width: int):
+    """data[i, pos[i] : pos[i]+width], zero-filled out of range. pos<0 -> zeros.
+
+    A log-step row shift (a barrel shifter), not a gather: for each bit k
+    of ``pos`` from high to low, a row whose bit is set moves left by 2^k,
+    and only the ``2^k - 1 + width`` columns that the lower bits can still
+    reach are kept, so the ten steps of a 1,024-byte row write ~1.6 passes
+    over it. Static slices and selects are what ``_find_pattern`` is made
+    of and the VPU runs (the v5e's compiler lays these byte matrices out
+    rows-minor, so a column slice shifts no lanes); a per-byte
+    ``take_along_axis`` is a scalar loop there, 10.7 ns a gathered byte
+    (PERF.md section 6, PRs 30-31). The numpy twin runs the same code.
+    """
+    r = data.shape[1]
+    row = data
+    for k in reversed(range((r - 1).bit_length())):
+        keep = (1 << k) - 1 + width
+        moved = ((pos >> k) & 1).astype(bool)[:, None]
+        row = xp.where(
+            moved, _columns(xp, row, 1 << k, keep), _columns(xp, row, 0, keep)
+        )
+    hit = ((pos >= 0) & (pos < r))[:, None]
+    return xp.where(hit, _columns(xp, row, 0, width), xp.uint8(0))
 
 
 _INT_WINDOW = 12  # sign + 9 digits + terminator fits comfortably

@@ -24,6 +24,7 @@ one host road a single-device launch has.
 """
 
 import functools
+import hashlib
 import importlib.util
 import json
 import os
@@ -527,3 +528,64 @@ def test_single_road_at_the_cells_launch_shapes(
             assert 0.12 < n_over / rows < 0.17
     else:
         assert stats["n_frame_padded"] == 1 and "n_frame_gather" not in stats
+
+
+# ------------------------------------ the projection's windows: no gather (PR 31)
+@pytest.mark.parametrize("namespace", ["numpy", "jax.numpy"])
+@pytest.mark.parametrize("width", [1, 12, 65, "r+3"])
+@pytest.mark.parametrize("r", [1024, 256, 100])
+def test_gather_window_is_each_rows_plain_slice(r, width, namespace):
+    """``_gather_window`` (a log-step row shift, no gather) against a plain
+    Python slice of every row: zeros where ``pos`` is negative or past the
+    row, zero fill past the row's end, a width that may exceed the row; the
+    edge positions and seeded ones, numpy and jit on the CPU backend."""
+    from redpanda_tpu.ops.transforms import _gather_window
+
+    w = r + 3 if width == "r+3" else width
+    edges = [-1, 0, r - w - 1, r - w, r - w + 1, r - 1, r, r + 4]
+    rng = np.random.default_rng(r * 131 + w)
+    pos = np.array(edges + list(rng.integers(-2, r + 2, 64)), np.int32)
+    data = rng.integers(1, 256, (len(pos), r), dtype=np.uint8)
+    if namespace == "numpy":
+        got = _gather_window(np, data, pos, w)
+    else:
+        import jax
+        import jax.numpy as jnp
+
+        got = np.asarray(jax.jit(lambda d, p: _gather_window(jnp, d, p, w))(data, pos))
+    assert got.dtype == np.uint8 and got.shape == (len(pos), w)
+    for row, p, window in zip(data, pos.tolist(), got):
+        want = bytes(row[p : p + w]).ljust(w, b"\0") if p >= 0 else bytes(w)
+        assert bytes(window) == want, (r, w, p)
+
+
+# sha256, first 16 hex digits, of the StableHLO text (``lower().as_text()``: no
+# source locations) of ``json64p-v1``'s mask program at [8, 1032], taken at the parent of PR 31
+# with this container's JAX. A change to ``_find_pattern``, ``packbits`` or
+# the parse moves it, and so does another JAX: print the text at both commits
+# and pin what a reading of the difference allows.
+_MASK_PROGRAM_DIGEST = "3f3175d599a3a6cb"
+
+
+def test_map_program_holds_no_gather_and_the_mask_program_is_what_it_was():
+    """``json64p-v1map``'s device program, lowered and compiled (CPU
+    backend), holds no ``gather`` operation: its two windows are static
+    slices and selects. ``json64p-v1``'s mask program never reaches
+    ``_gather_window``: its lowered text is the parent's, byte for byte."""
+    import jax
+
+    from redpanda_tpu.ops.pipeline import make_packed_pipeline
+    from redpanda_tpu.ops.transforms import TransformSpec
+
+    def lowered(name: str, mask_only: bool):
+        config = _config(name)
+        spec = TransformSpec.from_json(json.dumps(config["script"]["spec"]))
+        stride = config["reference"]["params"]["row_stride"]
+        fn, _ = make_packed_pipeline(spec, stride, mask_only=mask_only)
+        return fn.lower(jax.ShapeDtypeStruct((8, stride + 8), np.uint8))
+
+    map_program = lowered("json64p-v1map", False)
+    for text in (map_program.as_text(), map_program.compile().as_text()):
+        assert "select" in text and not re.search(r"\bgather\b", text)
+    mask_text = lowered("json64p-v1", True).as_text()
+    assert hashlib.sha256(mask_text.encode()).hexdigest()[:16] == _MASK_PROGRAM_DIGEST
