@@ -143,10 +143,19 @@ def reduce_window(*, kept, acked, arrivals, producer_logs, stream, records_per_b
     due: dict[tuple[int, int], float] = {}
     ack_ms, lag_ms = [], []
     offered = errors = 0
+    # what the producers fed: acknowledged batches due in the window or, for
+    # fixed work, seeded into the backlog before it
+    fed_batches = fed_bytes = 0
     for path in producer_logs:
         with open(path) as f:
-            for name, p, k, t_due, t_sent, t_ack, err in json.load(f):
-                if name != stream or not t0 <= t_due < t1:
+            for name, p, k, t_due, t_sent, t_ack, err, batch_bytes in json.load(f):
+                if name != stream:
+                    continue
+                in_window = t0 <= t_due < t1
+                if err == 0 and (fixed_work or in_window):
+                    fed_batches += 1
+                    fed_bytes += batch_bytes
+                if not in_window:
                     continue
                 offered += 1
                 due[(p, k)] = t_due
@@ -157,6 +166,8 @@ def reduce_window(*, kept, acked, arrivals, producer_logs, stream, records_per_b
                     errors += 1
     out["batches_offered"] = offered
     out["produce_errors"] = errors
+    if fed_batches:
+        out["input_wire_bytes_per_rec"] = fed_bytes / (fed_batches * records_per_batch)
     if not due:
         return out
     # consumer side: each kept record of those batches, due -> fetched

@@ -14,6 +14,35 @@ allow), ``run`` (open loop at a fixed rate, until ``stop_at``), ``dump``,
 ``exit``.
 Consumer commands: ``consume`` (tail a topic), ``progress``, ``drop``, ``finish``
 (drain, decode, compare, reduce), ``exit``.
+
+What a deployment feeds the broker is its configuration file's to say, and
+both kinds of worker take it from their spec:
+
+- ``producer.compression``: the codec producers seal their batches with
+  (``wire.CODECS``; ``"none"`` when the key is absent).
+- ``documents.generator``: ``"<module>.<function>"``, ``<module>`` a file of
+  this directory loaded by path (``load_generator``); ``documents.params``,
+  an optional flat object, is passed as keyword arguments after
+  ``(seed, partitions, records_per_partition, only)``.
+
+The generator's contract (``check_documents`` and ``Consumer.build`` hold
+it in every rehearsal, ``test_inputs.py`` over every generator a
+configuration names):
+
+1. it returns ``values[p][i]``, non-empty ``bytes``, for every partition
+   in ``only`` (all of them when ``only`` is None), ``records_per_partition``
+   to a partition;
+2. the values are a function of the seed (with the two sizes and the
+   params) alone;
+3. a partition's stream does not depend on ``only``: producers ask for
+   their own partitions, the consumer for all, and both must hold the
+   same bytes;
+4. at the rehearsal's size every value is inside the configuration's
+   ``documents.bytes_min`` / ``bytes_max`` (``code`` grows a digit or two
+   at a cell's own size: ``docs.py`` reads 924-1,062 B there);
+5. the configuration's reference recovers ``p * records_per_partition + i``
+   from each output it keeps (``sequence(output)``): ``transform_rate``
+   counts the inputs a consumer has seen the outcome of by it.
 """
 
 from __future__ import annotations
@@ -22,6 +51,7 @@ import asyncio
 import importlib.util
 import json
 import os
+import re
 import sys
 import time
 
@@ -29,18 +59,73 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import checks  # noqa: E402
-import docs  # noqa: E402
 import wire  # noqa: E402
+
+DEFAULT_GENERATOR = "docs.make_documents"
+_GENERATOR_NAME = re.compile(r"^([a-z_][a-z0-9_]*)\.([a-z_][a-z0-9_]*)$")
+
+
+def _load_by_path(module_name: str, path: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reference(name: str):
     """``references/<name>.py``: ``reference(value, **params)`` and
     ``sequence(output)``."""
-    path = os.path.join(HERE, "references", name + ".py")
-    spec = importlib.util.spec_from_file_location("perfbench_reference_" + name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load_by_path("perfbench_reference_" + name,
+                         os.path.join(HERE, "references", name + ".py"))
+
+
+def load_generator(name: str):
+    """The function ``documents.generator`` names; an unknown one is an
+    error that names the key."""
+    m = _GENERATOR_NAME.match(name) if isinstance(name, str) else None
+    path = os.path.join(HERE, m.group(1) + ".py") if m else ""
+    if not m or not os.path.isfile(path):
+        raise wire.InputShapeError(f"documents.generator {name!r}: not \"<module>.<function>\" "
+                         "of a file of benchmarks/")
+    fn = getattr(_load_by_path("perfbench_documents_" + m.group(1), path), m.group(2), None)
+    if not callable(fn):
+        raise wire.InputShapeError(f"documents.generator {name!r}: benchmarks/{m.group(1)}.py "
+                         f"has no function {m.group(2)}")
+    return fn
+
+
+def document_source(documents: dict | None):
+    """A configuration's ``documents`` -> ``source(stream, only=None)``,
+    the generator resolved once."""
+    documents = documents or {}
+    make = load_generator(documents.get("generator", DEFAULT_GENERATOR))
+    params = documents.get("params") or {}
+
+    def source(stream: dict, only=None) -> dict[int, list[bytes]]:
+        return make(stream["seed"], stream["partitions"],
+                    stream["records_per_partition"], only, **params)
+
+    return source
+
+
+def check_documents(values, documents: dict, stream: dict, only) -> None:
+    """Points 1 and 4 of the generator's contract, on what one call
+    returned."""
+    name = documents.get("generator", DEFAULT_GENERATOR)
+    lo, hi = documents.get("bytes_min", 1), documents.get("bytes_max", float("inf"))
+    want = list(only if only is not None else range(stream["partitions"]))
+    if not isinstance(values, dict) or any(p not in values for p in want):
+        raise wire.InputShapeError(f"documents.generator {name!r}: no values for some of partitions {want}")
+    for p in want:
+        part = values[p]
+        if len(part) != stream["records_per_partition"]:
+            raise wire.InputShapeError(f"documents.generator {name!r}: {len(part)} values in partition "
+                             f"{p}, not {stream['records_per_partition']}")
+        for i, v in enumerate(part):
+            if type(v) is not bytes or not max(lo, 1) <= len(v) <= hi:
+                what = f"{len(v)} B" if type(v) is bytes else type(v).__name__
+                raise wire.InputShapeError(f"documents.generator {name!r}: values[{p}][{i}] is {what}; "
+                                 f"the configuration states bytes of {lo}-{hi} B")
 
 
 def reply(obj: dict) -> None:
@@ -57,12 +142,6 @@ async def commands():
         yield json.loads(line)
 
 
-def stream_documents(stream: dict, only=None) -> dict[int, list[bytes]]:
-    return docs.make_documents(
-        stream["seed"], stream["partitions"], stream["records_per_partition"], only
-    )
-
-
 # ------------------------------------------------------------------ producer
 class Producer:
     def __init__(self, spec: dict):
@@ -70,8 +149,10 @@ class Producer:
         self.partitions = range(*spec["partition_range"])
         self.conns: list[wire.Conn] = []
         self.frames: dict[str, dict[int, list[bytes]]] = {}
+        self.batch_bytes: dict[str, dict[int, list[int]]] = {}
         self.next_batch: dict[str, dict[int, int]] = {}
-        # per sent batch: stream, partition, batch index, due, sent, acked, error
+        # per sent batch: stream, partition, batch index, due, sent, acked,
+        # error, bytes of the record batch on the wire
         self.log: list[tuple] = []
         self.stop_at = float("inf")
         self.exhausted = False  # a stream ran out of built frames
@@ -81,21 +162,32 @@ class Producer:
         from redpanda_tpu.hashing.crc32c import crc32c  # see wire.py docstring
 
         rpb = self.spec["records_per_batch"]
+        documents = self.spec.get("documents") or {}
+        source = document_source(documents)
+        codec = wire.codec_id(self.spec.get("compression", "none"))
+        compressor = wire.zstd_compressor() if codec == wire.ZSTD else None
         corr = 0
+        self.documents_s = self.seal_s = 0.0  # of build_s: the generator; batch + CRC + codec
         for name, stream in self.spec["streams"].items():
-            values = stream_documents(stream, self.partitions)
-            per_part = {}
+            t_d = time.monotonic()
+            values = source(stream, self.partitions)
+            self.documents_s += time.monotonic() - t_d
+            if self.spec.get("check_documents"):
+                check_documents(values, documents, stream, self.partitions)
+            per_part, sizes = {}, {}
             for p in self.partitions:
                 part = values[p]
-                frames = []
+                frames, sizes[p] = [], []
                 for s in range(0, len(part), rpb):
                     corr += 1
-                    frames.append(wire.produce_frame(
-                        stream["topic"], p,
-                        wire.build_batch(part[s : s + rpb], crc32c), corr,
-                    ))
+                    t_b = time.monotonic()
+                    batch = wire.build_batch(part[s : s + rpb], crc32c, codec=codec,
+                                             compressor=compressor)
+                    self.seal_s += time.monotonic() - t_b
+                    frames.append(wire.produce_frame(stream["topic"], p, batch, corr))
+                    sizes[p].append(len(batch))
                 per_part[p] = frames
-            self.frames[name] = per_part
+            self.frames[name], self.batch_bytes[name] = per_part, sizes
             self.next_batch[name] = {p: 0 for p in self.partitions}
 
     async def connect(self, host: str, port: int) -> None:
@@ -112,7 +204,7 @@ class Producer:
         if k >= len(frames):
             return None
         self.next_batch[name][p] = k + 1
-        entry = [name, p, k, due, time.monotonic(), None, None]
+        entry = [name, p, k, due, time.monotonic(), None, None, self.batch_bytes[name][p][k]]
         self.log.append(entry)
         fut = self.conn_for(p).request(frames[k])
 
@@ -175,6 +267,7 @@ async def producer_main(spec: dict) -> None:
     t0 = time.monotonic()
     prod.build()
     reply({"ready": True, "build_s": time.monotonic() - t0,
+           "documents_s": prod.documents_s, "seal_s": prod.seal_s,
            "frames": sum(len(f) for s in prod.frames.values() for f in s.values())})
     async for cmd in commands():
         op = cmd["cmd"]
@@ -208,6 +301,7 @@ async def producer_main(spec: dict) -> None:
 class Consumer:
     def __init__(self, spec: dict):
         self.spec = spec
+        self.documents_s = 0.0
         self.ref = load_reference(spec["reference"]["name"])
         self.params = spec["reference"].get("params", {})
         # per stream: kept[p] = input indices the reference keeps, expected[p]
@@ -219,13 +313,26 @@ class Consumer:
 
     def build(self) -> None:
         fn, params = self.ref.reference, self.params
+        documents = self.spec.get("documents") or {}
+        source = document_source(documents)
+        check = self.spec.get("check_documents")
         for name, stream in self.spec["streams"].items():
-            values = stream_documents(stream)
+            t_d = time.monotonic()
+            values = source(stream)
+            self.documents_s += time.monotonic() - t_d
+            if check:
+                check_documents(values, documents, stream, None)
             kept, expected = {}, {}
             for p, part in values.items():
                 outs = [fn(v, **params) for v in part]
                 kept[p] = [i for i, o in enumerate(outs) if o is not None]
                 expected[p] = [o for o in outs if o is not None]
+                if check and [self.ref.sequence(o) for o in expected[p]] != [
+                        p * len(part) + i for i in kept[p]]:
+                    raise wire.InputShapeError(  # point 5 of the generator's contract
+                        f"documents.generator {documents.get('generator', DEFAULT_GENERATOR)!r}: "
+                        f"reference {self.spec['reference']['name']!r} does not recover "
+                        f"p * records_per_partition + i from what it keeps of partition {p}")
             self.kept[name], self.expected[name] = kept, expected
 
 
@@ -315,7 +422,7 @@ async def consumer_main(spec: dict) -> None:
     cons = Consumer(spec)
     t0 = time.monotonic()
     cons.build()
-    reply({"ready": True, "build_s": time.monotonic() - t0,
+    reply({"ready": True, "build_s": time.monotonic() - t0, "documents_s": cons.documents_s,
            "expected": {n: sum(len(v) for v in e.values()) for n, e in cons.expected.items()}})
     async for cmd in commands():
         op = cmd["cmd"]
@@ -400,7 +507,10 @@ def main() -> None:
     if spec.get("cores"):
         os.sched_setaffinity(0, spec["cores"])
     sys.path.insert(0, spec["repo"])
-    asyncio.run(producer_main(spec) if role == "producer" else consumer_main(spec))
+    try:
+        asyncio.run(producer_main(spec) if role == "producer" else consumer_main(spec))
+    except wire.InputShapeError as exc:
+        sys.exit(f"{role}: {exc}")
 
 
 if __name__ == "__main__":

@@ -12,7 +12,14 @@ the plain reference, and prints one JSON object as its last line.
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a data file found by the name in ``BENCHMARK.json``:
 ``configs/<config>.json``, ``traffic/<traffic>.json``,
-``layer_metrics/<metric>.json``, ``references/<fn>.py``.
+``layer_metrics/<metric>.json``, ``references/<fn>.py``. A configuration
+file also names what its producers feed (``input_shape``): the document
+generator, ``documents.generator`` (+ ``documents.params``), and the codec
+its producers seal batches with, ``producer.compression``.
+
+``--manifest PATH`` (the driver does not pass it): look the cell and its
+configuration up in another file than ``BENCHMARK.json``, so that a
+configuration which is no cell yet can be run.
 
 ``--rehearse 1`` (not a measurement): the same path at the traffic file's
 ``rehearsal`` size on whatever platform JAX has, no ``metrics`` printed.
@@ -39,8 +46,10 @@ REPO = os.path.dirname(HERE)
 sys.path[:0] = [HERE, REPO]
 
 import broker as broker_mod  # noqa: E402
+import loadgen  # noqa: E402
 import readers  # noqa: E402
 import trace_reduce  # noqa: E402
+import wire  # noqa: E402
 
 WORKER = os.path.join(HERE, "loadgen.py")
 
@@ -56,6 +65,21 @@ def say(msg: str) -> None:
 def load_json(*parts: str) -> dict:
     with open(os.path.join(*parts)) as f:
         return json.load(f)
+
+
+def input_shape(config: dict) -> dict:
+    """What the configuration's producers feed the broker, checked before
+    anything starts: ``wire.InputShapeError`` names the key that asks for a
+    generator or a codec the benchmark does not have."""
+    documents = config.get("documents") or {}
+    shape = {
+        "generator": documents.get("generator", loadgen.DEFAULT_GENERATOR),
+        "params": documents.get("params") or {},
+        "compression": (config.get("producer") or {}).get("compression", "none"),
+    }
+    loadgen.load_generator(shape["generator"])
+    wire.codec_id(shape["compression"])
+    return shape
 
 
 # ------------------------------------------------------------------ workers
@@ -192,6 +216,19 @@ def snapshot(brk: broker_mod.Broker, cache_dir: str) -> dict:
     }
 
 
+def tree_bytes(directory: str) -> int:
+    """Bytes of the files under ``directory`` (the broker's data directory:
+    what the seeded backlog is on disk, as its codec left it)."""
+    total = 0
+    for root, _dirs, files in os.walk(directory):
+        for name in files:
+            try:
+                total += os.path.getsize(os.path.join(root, name))
+            except OSError:
+                pass  # a segment rolled away under the walk
+    return total
+
+
 def journal_size(brk: broker_mod.Broker) -> int:
     gov = json.loads(brk.admin("/v1/governor?domain=admission&limit=1000"))
     return len(gov.get("journal") or [])
@@ -255,7 +292,7 @@ class Settling:
 
 # ------------------------------------------------------------------ the run
 class Run:
-    def __init__(self, args, cell: dict, config: dict, traffic: dict):
+    def __init__(self, args, cell: dict, config: dict, traffic: dict, inputs: dict):
         self.args = args
         self.cell = cell
         self.config = config
@@ -275,7 +312,7 @@ class Run:
         self.topic = "bench"
         self.script = config["script"]["name"]
         self.mtopic = f"{self.topic}.${self.script}$"
-        self.notes: dict = {}
+        self.notes: dict = {"input": inputs}
         self.buckets: set = set()
         # --trace 1: capture name -> [its directory, seconds it ran]
         self.captures: dict[str, list] = {}
@@ -302,28 +339,38 @@ class Run:
         return {"main": stream(self.topic, int(t["records_per_s"] * horizon), seed)}
 
     # -------------------------------------------------------------- set-up
-    def start(self) -> None:
+    def worker_specs(self, cores: dict) -> tuple[list[dict], dict]:
+        """What each producer and the consumer is started with: every byte a
+        producer will send follows from its spec."""
         t, c = self.traffic, self.config
         n_prod = t["producers"]
-        self.cores = split_cores(n_prod)
+        partitions = c["topic"]["partitions"]
+        base = {"repo": REPO, "streams": self.streams(),
+                "records_per_batch": c["records_per_batch"],
+                "documents": c.get("documents") or {}, "check_documents": self.rehearse}
+        producers = [{
+            **base, "cores": cores["producers"][i],
+            "partition_range": [i * partitions // n_prod, (i + 1) * partitions // n_prod],
+            "connections": t["producer_connections"],
+            "compression": self.notes["input"]["compression"],
+        } for i in range(n_prod)]
+        consumer = {**base, "cores": cores["consumer"], "reference": c["reference"],
+                    **t["consumer"]}
+        return producers, consumer
+
+    def start(self) -> None:
+        c = self.config
+        self.cores = split_cores(self.traffic["producers"])
         os.sched_setaffinity(0, self.cores["harness"])
         say(f"cores: {self.cores}")
         self.brk = broker_mod.Broker(
             REPO, self.run_dir, c["broker_properties"], self.cores["broker"]
         )
-        streams = self.streams()
-        partitions = c["topic"]["partitions"]
-        base = {"repo": REPO, "streams": streams, "records_per_batch": c["records_per_batch"]}
-        for i in range(n_prod):
-            lo, hi = i * partitions // n_prod, (i + 1) * partitions // n_prod
-            self.producers.append(Worker("producer", {
-                **base, "cores": self.cores["producers"][i], "partition_range": [lo, hi],
-                "connections": t["producer_connections"],
-            }, self.run_dir, f"producer{i}"))
-        self.consumer = Worker("consumer", {
-            **base, "cores": self.cores["consumer"], "reference": c["reference"],
-            **t["consumer"],
-        }, self.run_dir, "consumer")
+        producers, consumer = self.worker_specs(self.cores)
+        streams = consumer["streams"]
+        for i, spec in enumerate(producers):
+            self.producers.append(Worker("producer", spec, self.run_dir, f"producer{i}"))
+        self.consumer = Worker("consumer", consumer, self.run_dir, "consumer")
 
         device = self.brk.wait_ready()
         say(f"broker ready in {self.brk.ready_s:.1f} s: {device}")
@@ -338,10 +385,12 @@ class Run:
         ))
         for w in self.producers:
             r = w.recv()
-            say(f"{w.tag} built {r['frames']} frames in {r['build_s']:.1f} s")
+            say(f"{w.tag} built {r['frames']} frames in {r['build_s']:.1f} s "
+                f"(documents {r['documents_s']:.1f}, batches sealed {r['seal_s']:.1f})")
             w.call({"cmd": "connect", "host": "127.0.0.1", "port": self.brk.ports["kafka"]})
         r = self.consumer.recv()
-        say(f"consumer built the reference in {r['build_s']:.1f} s: {r['expected']} outputs")
+        say(f"consumer built the reference in {r['build_s']:.1f} s "
+            f"(documents {r['documents_s']:.1f}): {r['expected']} outputs")
         self.consumer.call({"cmd": "connect", "host": "127.0.0.1",
                             "port": self.brk.ports["kafka"]})
 
@@ -383,6 +432,7 @@ class Run:
         for name in ("warm", "main"):
             rs = self.all_producers({"cmd": "seed", "stream": name, "inflight": t["seed_inflight"]})
             say(f"seeded {name} in {max(r['seconds'] for r in rs):.1f} s")
+        self.notes["data_dir_bytes_after_seeding"] = tree_bytes(os.path.join(self.run_dir, "data"))
         # warm-up: the same script under other names over the warm-up topic,
         # each drained to the end and removed (a new script reads its topic
         # from the start), until the program's one-shot choices and its
@@ -507,7 +557,7 @@ class Run:
             self.notes[w.tag] = d
             logs.append(path)
             with open(path) as f:
-                for name, p, _k, _due, _sent, _ack, err in json.load(f):
+                for name, p, _k, _due, _sent, _ack, err, _bytes in json.load(f):
                     if name == "main" and err == 0:
                         acked[str(p)] = acked.get(str(p), 0) + 1
         result_path = os.path.join(self.run_dir, "consumer.result.json")
@@ -520,6 +570,28 @@ class Run:
         final = snapshot(self.brk, self.cache_dir)
         memory = self.brk.control({"cmd": "memory"})
         return {"client": load_json(result_path), "final": final, "memory": memory}
+
+    def keep_evidence(self) -> None:
+        """A run that ends in a failure: the broker's log and the newest
+        event-loop stalls it will still tell of, copied beside the file
+        ``--keep-trace`` names or, without one, the log's end on stderr,
+        before ``stop`` removes the run directory."""
+        if self.brk is None:
+            return
+        try:
+            stalls = json.loads(self.brk.admin("/v1/profile", 5.0)).get("loop_stalls")
+        except (OSError, ValueError):
+            stalls = None  # the broker answers no more
+        if self.args.keep_trace:
+            os.makedirs(os.path.dirname(self.args.keep_trace) or ".", exist_ok=True)
+            if os.path.isfile(self.brk.log_path):
+                shutil.copy(self.brk.log_path, self.args.keep_trace + ".broker.log")
+            with open(self.args.keep_trace + ".loop_stalls.json", "w") as f:
+                json.dump(stalls, f)
+            return
+        tail = self.brk.tail(200_000).splitlines()[-60:]
+        print("broker.log, its last lines:\n" + "\n".join(tail), file=sys.stderr)
+        print(f"loop_stalls: {json.dumps(stalls)}", file=sys.stderr)
 
     def stop(self) -> None:
         for w in self.producers + ([self.consumer] if self.consumer else []):
@@ -550,13 +622,16 @@ def main() -> int:
     ap.add_argument("--rehearse", type=int, default=0)
     ap.add_argument("--control", type=int, default=0,
                     help="also judge the fetched output with one guarantee broken")
-    ap.add_argument("--keep-trace", default="", help="copy the window's .xplane.pb here")
+    ap.add_argument("--keep-trace", default="",
+                    help="copy the window's .xplane.pb here (a failed run: its broker.log beside it)")
+    ap.add_argument("--manifest", default="BENCHMARK.json",
+                    help="the file the cell and its configuration are looked up in")
     args = ap.parse_args()
 
-    manifest = load_json(REPO, "BENCHMARK.json")
+    manifest = load_json(REPO, args.manifest)
     cell = next((w for w in manifest["workloads"] if w["name"] == args.workload), None)
     if cell is None:
-        print(f"no workload {args.workload!r} in BENCHMARK.json", file=sys.stderr)
+        print(f"no workload {args.workload!r} in {args.manifest}", file=sys.stderr)
         return 2
     cfg_entry = next(c for c in manifest["configs"] if c["name"] == cell["config"])
     config = load_json(REPO, cfg_entry["file"])
@@ -565,7 +640,12 @@ def main() -> int:
         print("the program (redpanda_tpu/) is not in this checkout", file=sys.stderr)
         return 1
 
-    run = Run(args, cell, config, traffic)
+    try:
+        inputs = input_shape(config)
+    except wire.InputShapeError as exc:
+        print(f"RUN FAILED: {exc}", file=sys.stderr)
+        return 1
+    run = Run(args, cell, config, traffic, inputs)
     try:
         run.start()
         win = {"catchup": run.run_catchup, "paced": run.run_paced}[run.kind]()
@@ -576,6 +656,7 @@ def main() -> int:
             for name, (d, span_s) in run.captures.items()
         }
     except (RunFailure, broker_mod.BrokerFailure) as exc:
+        run.keep_evidence()
         print(f"RUN FAILED: {exc}", file=sys.stderr)
         return 1
     finally:
@@ -621,6 +702,10 @@ def main() -> int:
         k: round(a[k] - b.get(k, 0), 4) for k in sorted(a)
         if isinstance(a[k], (int, float)) and not isinstance(a[k], bool)
         and k[:2] in ("t_", "n_", "by") and a[k] != b.get(k, 0)}))
+    m0, m1 = win["before"]["metrics"], win["after"]["metrics"]
+    say("storage caches over the window: " + json.dumps({
+        k: round(m1[k] - m0.get(k, 0.0), 4) for k in sorted(m1)
+        if k.startswith(("batch_cache_", "readers_cache_")) and m1[k] != m0.get(k, 0.0)}))
     say("broker interpreter collections over the window (gen 0, 1, 2): "
         + json.dumps([y - x for x, y in zip(win["before"]["gc"], win["after"]["gc"])]))
     first_runs = win["after"]["stats"].get("n_compiles", 0) - win["before"]["stats"].get("n_compiles", 0)

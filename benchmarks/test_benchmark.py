@@ -88,12 +88,14 @@ def test_manifest_agrees_with_the_layer_metric_files():
 
 
 # ------------------------------------------------------------------ wire
-def test_batches_round_trip_and_match_the_programs_decoder():
+@pytest.mark.parametrize("codec", sorted(wire.CODECS))
+def test_batches_round_trip_and_match_the_programs_decoder(codec):
     from redpanda_tpu.hashing.crc32c import crc32c
     from redpanda_tpu.kafka.protocol.batch import decode_wire_batches
 
     values = docs.make_documents(7, 4, 32)[2]
-    raw = wire.build_batch(values, crc32c)
+    raw = wire.build_batch(values, crc32c, codec=wire.codec_id(codec))
+    assert raw[22] & 0x07 == wire.CODECS[codec] and (len(raw) < 4096) == (codec == "zstd")
     base, got = wire.decode_batch(raw, crc32c)
     assert (base, got) == (0, values)
     (res,) = decode_wire_batches(raw)
@@ -176,8 +178,8 @@ def test_transform_rate_bookkeeping():
 
 def test_latencies_are_timed_from_the_due_time(tmp_path):
     kept = {0: [1, 33]}  # one kept record in batch 0, one in batch 1
-    log = [["main", 0, 0, 10.0, 10.004, 10.020, 0], ["main", 0, 1, 10.5, 10.5, 10.530, 0],
-           ["main", 0, 2, 12.0, 12.0, 12.1, 0]]  # batch 2 is due after the window
+    log = [["main", 0, 0, 10.0, 10.004, 10.020, 0, 32000], ["main", 0, 1, 10.5, 10.5, 10.530, 0, 31000],
+           ["main", 0, 2, 12.0, 12.0, 12.1, 0, 30000]]  # batch 2 is due after the window
     path = tmp_path / "p.json"
     path.write_text(json.dumps(log))
     red = checks.reduce_window(
@@ -190,6 +192,14 @@ def test_latencies_are_timed_from_the_due_time(tmp_path):
     assert red["ack_ms"]["95"] == pytest.approx(30.0)
     assert red["generator_lag_ms"]["99"] == pytest.approx(4.0)
     assert red["produce_rate"] == 64.0
+    # what was fed: the batches due in the window; all that was seeded for fixed work
+    assert red["input_wire_bytes_per_rec"] == 63000 / 64
+    fixed = checks.reduce_window(
+        kept=kept, acked={0: 96}, arrivals={0: [(10.2, 1), (10.9, 2)]},
+        producer_logs=[str(path)], stream="main", records_per_batch=32,
+        t0=20.0, t1=21.0, fixed_work=True, t_complete=20.5,
+    )
+    assert fixed["input_wire_bytes_per_rec"] == 93000 / 96 and fixed["batches_offered"] == 0
     assert checks.percentile([1.0, 2.0, 3.0, 4.0], 50) == 2.0
     assert checks.percentile(list(range(1, 101)), 95) == 95
 

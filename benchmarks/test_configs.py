@@ -22,9 +22,8 @@ REPO = os.path.dirname(HERE)
 sys.path[:0] = [HERE, REPO]
 
 import checks  # noqa: E402
-import docs  # noqa: E402
 import readers  # noqa: E402
-from loadgen import load_reference  # noqa: E402
+from loadgen import document_source, load_reference  # noqa: E402
 from test_benchmark import BROKEN_LAUNCHER, RUN_WITH_LAUNCHER, manifest  # noqa: E402
 
 CONFIGS = [c["name"] for c in manifest()["configs"]]
@@ -104,7 +103,8 @@ def test_the_payload_lanes_metrics_read_nothing_on_the_columnar_lane():
 def test_each_reference_keeps_a_share_and_carries_its_input_sequence(name):
     c = config_file(name)
     ref, params = load_reference(c["reference"]["name"]), c["reference"]["params"]
-    values = docs.make_documents(2**31 + 5, 4, 256)
+    stream = {"seed": 2**31 + 5, "partitions": 4, "records_per_partition": 256}
+    values = document_source(c["documents"])(stream)  # the configuration's own generator
     kept = 0
     for p, part in values.items():
         outs = [(i, ref.reference(v, **params)) for i, v in enumerate(part)]
@@ -151,7 +151,8 @@ def test_rehearsal_is_correct_and_each_broken_guarantee_is_caught(cell, tmp_path
                        "one_reordered": True, "one_flipped_byte": True}
     assert "transform_rate" in last["not_metrics"]["end_to_end"]
     layer = last["not_metrics"]["per_layer"]
-    if cell.startswith("json64p-v1."):  # every launch a device program
+    config = next(w["config"] for w in manifest()["workloads"] if w["name"] == cell)
+    if config_file(config)["lane"] == "payload":  # every launch a device program
         assert layer["device_launch_share"]["value"] == 100.0
         assert 0 < layer["staging_fill_share"]["value"] <= 1.0
         assert layer["oversize_rows_per_launch"]["value"] > 0

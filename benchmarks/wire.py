@@ -7,6 +7,12 @@ Fixed, non-flexible API versions, hand-packed: Produce v7 and Fetch v4.
 RecordBatch v2 built and parsed here. The one borrowed function is the
 CRC-32C of a produced batch (``crc32c`` argument of ``build_batch``): the
 broker verifies it on every produce, so a wrong one fails the run.
+
+A producer seals its batches with the codec its configuration names
+(``producer.compression``, ``CODECS``): none, or Zstd through the
+``zstandard`` library. The benchmark's processes have no LZ4 library and
+may not borrow the program's ``compression/codecs.py``, whose output they
+are there to check.
 """
 
 from __future__ import annotations
@@ -26,6 +32,10 @@ BATCH_HDR_SIZE = _BATCH_HDR.size  # 61
 _CRC_COVER_START = 21
 ERR_UNKNOWN_TOPIC = 3
 ERR_NOT_LEADER = 6
+# producer.compression -> the codec id in a batch's attribute bits 0-2
+NONE, ZSTD = 0, 4
+CODECS = {"none": NONE, "zstd": ZSTD}
+ZSTD_LEVEL = 3  # the library's default, and Kafka's for compression.type=zstd
 
 
 def _uvarint(n: int) -> bytes:
@@ -62,12 +72,41 @@ def encode_records(values: list[bytes]) -> bytes:
     return b"".join(parts)
 
 
-def build_batch(values: list[bytes], crc32c, first_timestamp: int = 1_000_000) -> bytes:
-    """One uncompressed wire RecordBatch v2 holding ``values``."""
+class InputShapeError(ValueError):
+    """A configuration names a producer codec or a document generator that
+    the benchmark does not have, or a generator broke its contract."""
+
+
+def codec_id(name: str) -> int:
+    """The codec a configuration names; an unknown one is an error that
+    names the key."""
+    if name not in CODECS:
+        raise InputShapeError(
+            f"producer.compression {name!r}: the benchmark's producers seal "
+            f"batches with one of {sorted(CODECS)}"
+        )
+    return CODECS[name]
+
+
+def zstd_compressor() -> zstandard.ZstdCompressor:
+    """One a producer process, used from its one thread. The frame header
+    carries the content size."""
+    return zstandard.ZstdCompressor(level=ZSTD_LEVEL, write_content_size=True)
+
+
+def build_batch(values: list[bytes], crc32c, first_timestamp: int = 1_000_000,
+                codec: int = NONE, compressor: zstandard.ZstdCompressor | None = None) -> bytes:
+    """One wire RecordBatch v2 holding ``values``. With ``codec`` ``ZSTD`` the
+    records section is one Zstd frame; length and CRC are of the tail as it
+    goes on the wire."""
     n = len(values)
     records = encode_records(values)
+    if codec == ZSTD:
+        records = (compressor or zstd_compressor()).compress(records)
+    elif codec != NONE:
+        raise ValueError(f"codec {codec}: build_batch seals with {NONE} (none) or {ZSTD} (Zstd)")
     tail = struct.pack(
-        ">hiqqqhii", 0, n - 1, first_timestamp, first_timestamp + n - 1,
+        ">hiqqqhii", codec, n - 1, first_timestamp, first_timestamp + n - 1,
         -1, -1, -1, n,
     ) + records
     head = struct.pack(">qiibI", 0, len(tail) + 9, -1, 2, crc32c(tail) & 0xFFFFFFFF)
