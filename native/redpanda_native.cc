@@ -13,6 +13,11 @@
 #include <cstddef>
 #include <cmath>
 #include <cstdlib>
+#include <atomic>
+#include <mutex>
+#include <thread>
+#include <vector>
+#include <dlfcn.h>
 
 #if defined(__x86_64__)
 // x86intrin.h + per-function target attributes instead of a global -msse4.2:
@@ -294,6 +299,165 @@ int64_t rp_parse_many(const uint8_t* joined, const int64_t* payload_off,
     k += counts[b];
   }
   return k;
+}
+
+// rp_parse_many's pointer-table twin (the payload staging lane's explode):
+// batch b's payload is payloads[b][0 .. payload_len[b]) and its records'
+// val_off stay RELATIVE to that buffer, so no joined blob is needed. One
+// crossing a launch, where the lane made one rp_parse_record_values call a
+// batch. sizes[i] is val_len[i] clamped at 0 (a null value stages as
+// empty). Returns the number of records parsed (== sum(counts) on success).
+int64_t rp_parse_many_ptrs(const uint8_t* const* payloads,
+                           const int64_t* payload_len, const int32_t* counts,
+                           int64_t n_batches, int64_t* val_off,
+                           int32_t* val_len, int32_t* sizes) {
+  int64_t k = 0;
+  for (int64_t b = 0; b < n_batches; b++) {
+    int32_t parsed = rp_parse_record_values(
+        payloads[b], (size_t)payload_len[b], counts[b], val_off + k,
+        val_len + k);
+    if (parsed != counts[b]) return k + parsed;
+    k += counts[b];
+  }
+  for (int64_t i = 0; i < k; i++) sizes[i] = val_len[i] < 0 ? 0 : val_len[i];
+  return k;
+}
+
+// ------------------------------------------------- zstd, many frames a call
+// A launch's compressed batches decompress in ONE crossing (no interpreter
+// lock is held inside a ctypes call) into memory the caller owns and
+// reuses. libzstd is resolved at run time, as compression/codecs.py
+// resolves liblz4 and libsnappy: a host without it builds and loads this
+// library all the same, rp_zstd_available() says 0 there and callers keep
+// the per-batch codec.
+typedef struct ZSTD_DCtx_s ZSTD_DCtx;
+static struct {
+  ZSTD_DCtx* (*create)();
+  size_t (*free)(ZSTD_DCtx*);
+  size_t (*decompress)(ZSTD_DCtx*, void*, size_t, const void*, size_t);
+  unsigned long long (*content_size)(const void*, size_t);
+  size_t (*frame_size)(const void*, size_t);
+  unsigned (*is_error)(size_t);
+  bool ok;
+} zstd;
+static std::once_flag zstd_once;
+
+static void zstd_resolve() {
+  void* h = nullptr;
+  for (const char* name : {"libzstd.so.1", "libzstd.so"}) {
+    if ((h = dlopen(name, RTLD_NOW | RTLD_LOCAL))) break;
+  }
+  if (!h) return;
+  *(void**)&zstd.create = dlsym(h, "ZSTD_createDCtx");
+  *(void**)&zstd.free = dlsym(h, "ZSTD_freeDCtx");
+  *(void**)&zstd.decompress = dlsym(h, "ZSTD_decompressDCtx");
+  *(void**)&zstd.content_size = dlsym(h, "ZSTD_getFrameContentSize");
+  *(void**)&zstd.frame_size = dlsym(h, "ZSTD_findFrameCompressedSize");
+  *(void**)&zstd.is_error = dlsym(h, "ZSTD_isError");
+  zstd.ok = zstd.create && zstd.free && zstd.decompress &&
+            zstd.content_size && zstd.frame_size && zstd.is_error;
+}
+
+int32_t rp_zstd_available() {
+  std::call_once(zstd_once, zstd_resolve);
+  return zstd.ok ? 1 : 0;
+}
+
+// The size frame b's header states (out_len[b]), -1 where it states none
+// (a streaming producer's frame) or the header does not parse: those
+// frames are the per-batch codec's, which has no fixed output cap and
+// raises on garbage. out_off[b] is where frame b goes in one buffer that
+// holds the sized frames back to back. Returns that buffer's size, -1
+// without libzstd.
+int64_t rp_zstd_frame_sizes(const uint8_t* const* srcs,
+                            const int64_t* src_lens, int64_t n,
+                            int64_t* out_off, int64_t* out_len) {
+  if (!rp_zstd_available()) return -1;
+  int64_t total = 0;
+  for (int64_t b = 0; b < n; b++) {
+    unsigned long long sz = zstd.content_size(srcs[b], (size_t)src_lens[b]);
+    out_off[b] = total;
+    // ZSTD_CONTENTSIZE_UNKNOWN / _ERROR are the two largest values
+    if (sz >= (unsigned long long)INT64_MAX) {
+      out_len[b] = -1;
+    } else {
+      out_len[b] = (int64_t)sz;
+      total += (int64_t)sz;
+    }
+  }
+  return total;
+}
+
+// Decompress frame b (dst_len[b] >= 0) into dst + dst_off[b]; frames with
+// dst_len[b] < 0 are skipped. Only the FIRST frame of srcs[b] is read,
+// which is what the per-batch codec (one decompressobj a batch) decodes.
+// n_threads > 1 splits the frames over that many threads, the caller's
+// among them, each with a context of its own. A frame that fails, or does
+// not fill exactly dst_len[b] bytes, gets dst_len[b] = -1: it is the
+// per-batch codec's to decode or to refuse, as it always was. Returns the
+// number of such frames; -1 without libzstd or a context; -2, with
+// nothing written, when a span lies outside dst[0 .. dst_cap).
+int64_t rp_zstd_uncompress_many(const uint8_t* const* srcs,
+                                const int64_t* src_lens, int64_t n,
+                                uint8_t* dst, int64_t dst_cap,
+                                const int64_t* dst_off, int64_t* dst_len,
+                                int32_t n_threads) {
+  if (!rp_zstd_available()) return -1;
+  for (int64_t b = 0; b < n; b++) {
+    if (dst_len[b] >= 0 &&
+        (dst_off[b] < 0 || dst_off[b] > dst_cap - dst_len[b]))
+      return -2;
+  }
+  std::atomic<int64_t> next{0};
+  std::atomic<int64_t> failed{0};
+  std::atomic<bool> no_ctx{false};
+  auto work = [&]() {
+    // one context a thread for the life of the thread: the engine's
+    // dispatch thread comes back every launch
+    static thread_local struct Ctx {
+      ZSTD_DCtx* c = nullptr;
+      ~Ctx() { if (c) zstd.free(c); }
+    } ctx;
+    if (!ctx.c && !(ctx.c = zstd.create())) {
+      no_ctx.store(true);
+      return;
+    }
+    const int64_t kChunk = 16;  // frames a claim: few atomics, even split
+    for (;;) {
+      int64_t lo = next.fetch_add(kChunk);
+      if (lo >= n) return;
+      int64_t hi = lo + kChunk < n ? lo + kChunk : n;
+      for (int64_t b = lo; b < hi; b++) {
+        if (dst_len[b] < 0) continue;
+        size_t clen = zstd.frame_size(srcs[b], (size_t)src_lens[b]);
+        size_t got = zstd.is_error(clen)
+                         ? clen
+                         : zstd.decompress(ctx.c, dst + dst_off[b],
+                                           (size_t)dst_len[b], srcs[b], clen);
+        if (zstd.is_error(got) || (int64_t)got != dst_len[b]) {
+          dst_len[b] = -1;
+          failed.fetch_add(1);
+        }
+      }
+    }
+  };
+  int32_t extra = n_threads > 1 ? n_threads - 1 : 0;
+  if ((int64_t)extra > n / 32) extra = (int32_t)(n / 32);  // small launch
+  std::vector<std::thread> pool;
+  pool.reserve((size_t)extra);
+  for (int32_t t = 0; t < extra; t++) {
+    try {
+      pool.emplace_back(work);
+    } catch (...) {
+      break;  // no thread to be had: the caller's does the rest
+    }
+  }
+  work();
+  for (auto& t : pool) t.join();
+  // a thread without a context claimed nothing; if the caller's had none,
+  // frames may be left undone
+  if (no_ctx.load() && next.load() < n) return -1;
+  return failed.load();
 }
 
 // Build a records payload from kept transform outputs: record i (where

@@ -464,7 +464,8 @@ async def cmd_debug(args) -> int:
             print(f"  {k:<28}{v}")
         for k in (
             "columnar_backend", "columnar_probe", "parse_path", "parse_probe",
-            "colcache", "arena", "staging_arena", "breakers", "lockwatch",
+            "colcache", "arena", "staging_arena", "uncompress_arena",
+            "breakers", "lockwatch",
             "leakwatch", "mesh_error", "device_launches_by_script",
         ):
             if stats.get(k) is not None:

@@ -1,6 +1,7 @@
 from redpanda_tpu.compression.registry import (
     compress,
     uncompress,
+    uncompress_many,
     register_backend,
     active_backend,
     is_available,
@@ -9,6 +10,7 @@ from redpanda_tpu.compression.registry import (
 __all__ = [
     "compress",
     "uncompress",
+    "uncompress_many",
     "register_backend",
     "active_backend",
     "is_available",

@@ -64,6 +64,45 @@ def zstd_uncompress(data: bytes) -> bytes:
     return out
 
 
+# Threads one many-frames decompress runs on, the caller's among them. Fixed
+# in code: a launch's ~1,500 frames of ~33 KB split evenly, the crossing holds
+# no interpreter lock, and the broker's own threads (event loop, harvester)
+# leave cores idle on any host that holds a chip (PERF.md section 6, PR 33).
+ZSTD_MANY_THREADS = 4
+
+
+def zstd_uncompress_many(frames: list[bytes], pool):
+    """Many Zstd frames in ONE crossing that holds no interpreter lock
+    (native rp_zstd_uncompress_many), into one buffer taken from ``pool``
+    (``acquire(nbytes)`` / ``release(buf)``, e.g. batch_codec.Arena) and
+    the caller's to give back. Returns ``(buf, off, ln)``: frame i is
+    ``buf[off[i] : off[i] + ln[i]]``, and ``ln[i] == -1`` for a frame that
+    is ``zstd_uncompress``'s to decode or to refuse, as it always was: one
+    whose header states no content size (a streaming producer's; that
+    codec has no fixed output cap), and one that is truncated, corrupt or
+    not of its stated size. ``None`` when the native library or libzstd is
+    not there."""
+    try:
+        from redpanda_tpu.native import lib, src_table
+    except Exception:
+        lib = None
+    if lib is None or not getattr(lib, "has_zstd_many", False):
+        return None
+    # two crossings and nothing between them that hands the interpreter
+    # lock over: numpy drops it for any operation on more than ~500
+    # elements, and beside a busy event loop every hand-over costs up to
+    # the switch interval (PERF.md section 6, PR 33)
+    table = src_table(frames)
+    off, ln, total = lib.zstd_frame_sizes(table)
+    buf = pool.acquire(total)
+    try:
+        lib.zstd_uncompress_many(table, buf, off, ln, ZSTD_MANY_THREADS)
+    except BaseException:
+        pool.release(buf)
+        raise
+    return buf, off, ln
+
+
 # ------------------------------------------------------------------ lz4 frame
 _LZ4F_VERSION = 100
 

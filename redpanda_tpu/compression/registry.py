@@ -21,7 +21,11 @@ class CompressionError(Exception):
 
 
 class _Backend:
-    def __init__(self, name: str, table: dict[Compression, tuple[Callable, Callable]]):
+    """``table[codec]`` is ``(compress, uncompress)`` and, where the codec
+    has a many-frames form, a third entry ``uncompress_many(frames, pool)``
+    (see ``uncompress_many`` below)."""
+
+    def __init__(self, name: str, table: dict[Compression, tuple[Callable, ...]]):
         self.name = name
         self.table = table
 
@@ -48,7 +52,11 @@ _HOST = _Backend(
     "host",
     {
         Compression.gzip: (_codecs.gzip_compress, _codecs.gzip_uncompress),
-        Compression.zstd: (_codecs.zstd_compress, _codecs.zstd_uncompress),
+        Compression.zstd: (
+            _codecs.zstd_compress,
+            _codecs.zstd_uncompress,
+            _codecs.zstd_uncompress_many,
+        ),
         Compression.lz4: (_codecs.lz4_compress, _codecs.lz4_uncompress),
         Compression.snappy: (_codecs.snappy_compress, _codecs.snappy_uncompress),
     },
@@ -58,7 +66,7 @@ _backends: dict[str, _Backend] = {"host": _HOST}
 _active = _HOST
 
 
-def register_backend(name: str, table: dict[Compression, tuple[Callable, Callable]], *, activate: bool = False):
+def register_backend(name: str, table: dict[Compression, tuple[Callable, ...]], *, activate: bool = False):
     global _active
     backend = _Backend(name, table)
     _backends[name] = backend
@@ -77,6 +85,22 @@ def compress(data: bytes, codec: Compression | int) -> bytes:
 
 def uncompress(data: bytes, codec: Compression | int) -> bytes:
     return _active.uncompress(bytes(data), Compression(codec))
+
+
+def uncompress_many(frames: list[bytes], codec: Compression | int, pool):
+    """Many frames of ONE codec decompressed in one crossing, off the
+    interpreter lock, into a buffer out of ``pool`` (``acquire(nbytes)`` /
+    ``release(buf)``): ``(buf, off, ln)`` with frame i at ``buf[off[i] :
+    off[i] + ln[i]]`` and ``ln[i] == -1`` for a frame this form leaves to
+    ``uncompress`` (no stated content size; truncated or corrupt: whatever
+    ``uncompress`` makes of such a frame stays what the caller gets). The
+    buffer is the caller's to release. ``None`` when the active backend has
+    no many-frames form for ``codec`` (gzip, snappy, lz4; Zstd without the
+    native library): the caller calls ``uncompress`` a frame."""
+    entry = _active.table.get(Compression(codec))
+    if entry is None or len(entry) < 3:
+        return None
+    return entry[2](frames, pool)
 
 
 def is_available(codec: Compression | int) -> bool:

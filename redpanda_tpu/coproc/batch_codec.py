@@ -9,13 +9,16 @@ C per record, TPU per byte.
 
 from __future__ import annotations
 
+import ctypes
+import itertools
 import threading
 from dataclasses import dataclass
 
 import numpy as np
 
-from redpanda_tpu.compression import compress, uncompress
+from redpanda_tpu.compression import compress, uncompress, uncompress_many
 from redpanda_tpu.models.record import Compression, Record, RecordBatch, RecordBatchHeader
+from redpanda_tpu.observability import stages
 from redpanda_tpu.utils.vint import decode_zigzag, encode_zigzag
 
 
@@ -47,11 +50,14 @@ class Arena:
     # forever once traffic returns to normal size
     MAX_FREE = 8
 
-    def __init__(self, max_free: int = MAX_FREE) -> None:
+    def __init__(self, max_free: int = MAX_FREE, quantum: int = 1) -> None:
         from redpanda_tpu.coproc import lockwatch
 
         self._lock = lockwatch.wrap(threading.Lock(), "Arena._lock")
         self._max_free = max_free
+        # requests round up to a multiple of this: sizes that wander a
+        # little from launch to launch are served by one parked buffer
+        self._quantum = quantum
         self._free: list[np.ndarray] = []
         self._allocs = 0
         self._reuses = 0
@@ -66,6 +72,7 @@ class Arena:
     def take(self, nbytes: int) -> tuple[np.ndarray, bool]:
         """``acquire`` that also says whether the buffer was a parked one
         (True) or had to be allocated."""
+        nbytes = -(-max(nbytes, 1) // self._quantum) * self._quantum
         with self._lock:
             best = None
             for i, b in enumerate(self._free):
@@ -121,40 +128,156 @@ class ExplodedBatches:
     ranges: list[tuple[int, int]]  # per input batch: [start, end) in N
 
 
-def _gather_payloads(batches: list[RecordBatch]):
+class _Unpooled:
+    """The pool of a caller that has none: fresh memory, kept by whoever
+    holds the table."""
+
+    @staticmethod
+    def acquire(nbytes: int) -> np.ndarray:
+        return np.empty(max(nbytes, 1), dtype=np.uint8)
+
+    @staticmethod
+    def release(buf) -> None:
+        pass
+
+
+class LaunchPayloads:
+    """A launch's per-batch record payloads, decompressed, as the native
+    crossings take them: one address (``ptrs`` uint64 [B]) and one length
+    (``lens`` int64 [B]) a batch. An uncompressed batch's entry points at
+    the batch's own ``payload`` bytes (nothing is copied); a compressed
+    batch's at its span of a buffer out of ``pool`` that one many-frames
+    crossing filled (compression.uncompress_many), or at the ``bytes`` the
+    per-batch codec returned where the codec has no such form. Reads like
+    the list of payloads it replaces (``len``, ``[i]``, iteration: ``bytes``
+    or a uint8 view of the span). ``release()`` gives the pooled buffers
+    back: the table is dead from then on."""
+
+    __slots__ = ("ptrs", "lens", "_held", "_bufs", "_pool")
+
+    def __init__(self, held: list, bufs: list, pool):
+        self._held = held
+        self._bufs = bufs
+        self._pool = pool
+        n = len(held)
+        self.lens = np.fromiter(map(len, held), np.int64, n)
+        # a bytes object's address (the ctypes array retains every object
+        # it points into; None -> NULL), then the pooled spans' own
+        table = (ctypes.c_char_p * n)(
+            *(h if type(h) is bytes else None for h in held)
+        )
+        self.ptrs = np.frombuffer(table, dtype=np.uint64) if n else np.zeros(0, np.uint64)
+        for i, h in enumerate(held) if bufs else ():
+            if type(h) is not bytes:
+                self.ptrs[i] = h.ctypes.data
+
+    def __len__(self) -> int:
+        return len(self._held)
+
+    def __getitem__(self, i):
+        if self.ptrs is None:
+            raise ValueError("LaunchPayloads used after release()")
+        return self._held[i]
+
+    def __iter__(self):
+        return (self[i] for i in range(len(self)))
+
+    def release(self) -> None:
+        bufs, self._bufs, self.ptrs = self._bufs, [], None
+        for buf in bufs:
+            self._pool.release(buf)
+
+
+def launch_payloads(
+    batches: list[RecordBatch], pool=None, count=None
+) -> LaunchPayloads:
+    """Decompress a launch's batches and table their payloads: the ONE
+    place a scanned batch is decompressed, shared by every explode below.
+    All batches of one codec go through ``uncompress_many`` in a number of
+    crossings that does not grow with their count, off the interpreter
+    lock, into a buffer out of ``pool`` (``Arena``; None = fresh memory);
+    a codec without a many-frames form, and a frame that form leaves alone
+    (no stated content size, truncated, corrupt), takes ``uncompress`` a
+    batch. Uncompressed batches pass through untouched. Where ``uncompress``
+    raises, that is what the launch gets, with every buffer back in the
+    pool. ``count(n_batches, n_crossings, bytes_in, bytes_out, seconds)``
+    is called once when anything was decompressed (the engine's stage and
+    counters).
+
+    Per-batch bookkeeping is plain Python over lists, not numpy over
+    arrays, on purpose: numpy hands the interpreter lock over in any
+    operation on more than ~500 elements, and beside a busy event loop
+    each hand-over costs up to the switch interval; the lock is dropped
+    here in the codec's crossings and nowhere else."""
+    pool = pool or _Unpooled
+    held = [b.payload for b in batches]
+    codecs = [int(b.header.compression) for b in batches]
+    if not any(codecs):
+        return LaunchPayloads(held, [], pool)
+    t0 = stages.begin("coproc.stage.uncompress")
+    bufs: list[np.ndarray] = []
+    n_batches = crossings = bytes_in = bytes_out = 0
+    try:
+        for codec in sorted(set(codecs) - {0}):
+            idx = [i for i, c in enumerate(codecs) if c == codec]
+            frames = [held[i] for i in idx]
+            n_batches += len(idx)
+            bytes_in += sum(map(len, frames))
+            many = uncompress_many(frames, codec, pool)
+            if many is not None:
+                buf, off, ln = many
+                bufs.append(buf)
+                crossings += 2  # the frames' stated sizes, the decompress
+                for i, o, m in zip(idx, off.tolist(), ln.tolist()):
+                    if m >= 0:
+                        held[i] = buf[o : o + m]
+                idx = [i for i in idx if type(held[i]) is bytes]
+            for i in idx:  # no many-frames form, or a frame it left alone
+                held[i] = uncompress(held[i], codec)
+                crossings += 1
+        bytes_out = sum(len(h) for h, c in zip(held, codecs) if c)
+        out = LaunchPayloads(held, bufs, pool)
+    except BaseException:
+        for buf in bufs:
+            pool.release(buf)
+        stages.close("coproc.stage.uncompress", None, t0)
+        raise
+    dt = stages.close("coproc.stage.uncompress", None, t0)
+    if count is not None:
+        count(n_batches, crossings, bytes_in, bytes_out, dt)
+    return out
+
+
+def _batch_ranges(batches: list[RecordBatch]):
+    """(counts int32 [B], ranges [(start, end)] in launch rows, n)."""
+    per_batch = [b.header.record_count for b in batches]
+    ends = list(itertools.accumulate(per_batch))
+    ranges = list(zip([0] + ends[:-1], ends))
+    counts = np.fromiter(per_batch, np.int32, len(per_batch))
+    return counts, ranges, ends[-1] if ends else 0
+
+
+def _gather_payloads(batches: list[RecordBatch], count=None):
     """Decompress + concatenate batch payloads; shared by the split and
     fused explode paths."""
-    payloads: list[bytes] = []
-    counts = np.empty(len(batches), np.int32)
-    p_off = np.empty(len(batches), np.int64)
-    p_len = np.empty(len(batches), np.int32)
-    ranges: list[tuple[int, int]] = []
-    base = 0
-    n = 0
-    for i, b in enumerate(batches):
-        payload = b.payload
-        if b.header.compression != Compression.none:
-            payload = uncompress(payload, b.header.compression)
-        count = b.header.record_count
-        payloads.append(payload)
-        counts[i] = count
-        p_off[i] = base
-        p_len[i] = len(payload)
-        ranges.append((n, n + count))
-        base += len(payload)
-        n += count
-    return payloads, counts, p_off, p_len, ranges, b"".join(payloads), n
+    payloads = launch_payloads(batches, count=count)
+    counts, ranges, n = _batch_ranges(batches)
+    p_len = payloads.lens.astype(np.int32)
+    p_off = np.cumsum(payloads.lens) - payloads.lens
+    parts = list(payloads)
+    return parts, counts, p_off, p_len, ranges, b"".join(parts), n
 
 
-def explode_and_find(batches: list[RecordBatch], paths: list[str]):
+def explode_and_find(batches: list[RecordBatch], paths: list[str], count=None):
     """FUSED explode + find (rp_explode_find): framing parse and the
     k-path JSON walk in one native crossing and one cache-hot traversal.
     Returns (ExplodedBatches, types, vs, ve) or None when the native
-    symbol is unavailable (caller runs the split stages)."""
+    symbol is unavailable (caller runs the split stages). ``count``:
+    launch_payloads'."""
     lib = _native()
     if lib is None or not getattr(lib, "has_explode_find", False) or not paths:
         return None
-    _, counts, p_off, p_len, ranges, joined, n = _gather_payloads(batches)
+    _, counts, p_off, p_len, ranges, joined, n = _gather_payloads(batches, count)
     if n == 0:
         ex = ExplodedBatches(
             joined, np.zeros(0, np.int64), np.zeros(0, np.int32), ranges
@@ -205,30 +328,21 @@ class StructuralParse:
 
 
 def explode_find_structural(
-    batches: list[RecordBatch], paths: list[str], need_joined: bool
+    batches: list[RecordBatch], paths: list[str], need_joined: bool, count=None
 ) -> StructuralParse | None:
     """Structural-index fused parse (rp_explode_find2): decompressed
     payloads cross the native boundary ONCE as a pointer table — the
     Python-side b"".join copy of explode_and_find's path only happens
     in-crossing, and only when ``need_joined`` says the harvest will
     gather from the blob. Returns None when the native symbols are
-    unavailable (caller runs the staged ladder)."""
+    unavailable (caller runs the staged ladder). The payloads are a
+    ``LaunchPayloads`` over fresh memory (no pool: the parse is retained
+    for as long as the launch's columns are). ``count``: launch_payloads'."""
     lib = _native()
     if lib is None or not getattr(lib, "has_structural", False) or not paths:
         return None
-    payloads: list[bytes] = []
-    counts = np.empty(len(batches), np.int32)
-    ranges: list[tuple[int, int]] = []
-    n = 0
-    for i, b in enumerate(batches):
-        payload = b.payload
-        if b.header.compression != Compression.none:
-            payload = uncompress(payload, b.header.compression)
-        count = b.header.record_count
-        payloads.append(payload)
-        counts[i] = count
-        ranges.append((n, n + count))
-        n += count
+    payloads = launch_payloads(batches, count=count)
+    counts, ranges, n = _batch_ranges(batches)
     if n == 0:
         k = len(paths)
         return StructuralParse(
@@ -248,69 +362,65 @@ def explode_find_structural(
 @dataclass
 class PtrExploded:
     """Pointer-table explode for the payload staging lane (ROADMAP item 1
-    follow-on b): the decompressed per-batch payload buffers are retained
-    and record (offset, len) stay RELATIVE to their own buffer, so
-    staging packs straight from each buffer — the joined blob (and its
-    b"".join copy, plus _pack_staged's second cache-cold read of it)
-    never exists."""
+    follow-on b): the decompressed per-batch payloads stay where they lie
+    (``LaunchPayloads``: a batch's own bytes, or its span of a pooled
+    buffer) and record (offset, len) stay RELATIVE to their own payload, so
+    staging packs straight from each — the joined blob (and its b"".join
+    copy, plus _pack_staged's second cache-cold read of it) never exists.
+    ``release()`` when the launch has read its last value out of the
+    payloads (packed, and framed where the launch frames from them)."""
 
-    payloads: list[bytes]
-    rel_off: list[np.ndarray]  # int64 per batch, relative to its payload
-    rel_len: list[np.ndarray]  # int32 per batch (raw; -1 for null values)
+    payloads: LaunchPayloads
+    offsets: np.ndarray  # int64 [N] launch-wide, each relative to its payload
+    lens: np.ndarray  # int32 [N] launch-wide (raw; -1 for null values)
     sizes: np.ndarray  # int32 [N] launch-wide, clamped >= 0
     ranges: list[tuple[int, int]]  # per input batch: [start, end) in N
 
     @property
-    def offsets(self) -> np.ndarray:
-        """int64 [N] launch-wide, each still relative to its own payload:
-        the (offset, len) columns frame_ranges_gather_ptrs frames from."""
-        if not self.rel_off:
-            return np.zeros(0, np.int64)
-        return np.concatenate(self.rel_off)
+    def rel_off(self) -> list[np.ndarray]:
+        """``offsets`` a batch."""
+        return [self.offsets[s:e] for s, e in self.ranges]
+
+    @property
+    def rel_len(self) -> list[np.ndarray]:
+        """``lens`` a batch."""
+        return [self.lens[s:e] for s, e in self.ranges]
+
+    def release(self) -> None:
+        self.payloads.release()
 
 
-def explode_ptrs(batches: list[RecordBatch]) -> PtrExploded | None:
-    """Explode a batch list WITHOUT building the joined blob. Returns
-    None when the native packer is unavailable — the classic joined-blob
-    lane is the fallback and the parity oracle."""
+def explode_ptrs(
+    batches: list[RecordBatch], pool: Arena | None = None, count=None
+) -> PtrExploded | None:
+    """Explode a batch list WITHOUT building the joined blob: the payloads
+    decompressed by ``launch_payloads`` (into ``pool``; ``count`` is its
+    hook) and every record's (offset, len) parsed in ONE crossing
+    (rp_parse_many_ptrs). Returns None when the native packer or parser is
+    unavailable — the classic joined-blob lane is the fallback and the
+    parity oracle."""
     lib = _native()
-    if lib is None or not getattr(lib, "has_pack_rows_ptrs", False):
+    if (
+        lib is None
+        or not getattr(lib, "has_pack_rows_ptrs", False)
+        or not getattr(lib, "has_parse_many_ptrs", False)
+    ):
         # no library, or a stale one without the lane's packer
-        # (rp_pack_rows_ptrs, pack_exploded_ptrs below)
+        # (rp_pack_rows_ptrs, pack_exploded_ptrs below) or its parser
         return None
-    payloads: list[bytes] = []
-    rel_off: list[np.ndarray] = []
-    rel_len: list[np.ndarray] = []
-    sizes_parts: list[np.ndarray] = []
-    ranges: list[tuple[int, int]] = []
-    n = 0
-    for b in batches:
-        payload = b.payload
-        if b.header.compression != Compression.none:
-            payload = uncompress(payload, b.header.compression)
-        count = b.header.record_count
-        if count:
-            off, ln = lib.parse_record_values(payload, count)
-        else:
-            off = np.zeros(0, np.int64)
-            ln = np.zeros(0, np.int32)
-        payloads.append(payload)
-        rel_off.append(off)
-        rel_len.append(ln)
-        sizes_parts.append(np.maximum(ln, 0))
-        ranges.append((n, n + count))
-        n += count
-    sizes = (
-        np.concatenate(sizes_parts).astype(np.int32)
-        if sizes_parts
-        else np.zeros(0, np.int32)
-    )
-    return PtrExploded(payloads, rel_off, rel_len, sizes, ranges)
+    payloads = launch_payloads(batches, pool, count)
+    counts, ranges, n = _batch_ranges(batches)
+    try:
+        off, ln, sizes = lib.parse_many_ptrs(payloads, counts, n)
+    except BaseException:
+        payloads.release()
+        raise
+    return PtrExploded(payloads, off, ln, sizes, ranges)
 
 
-def explode_batches(batches: list[RecordBatch]) -> ExplodedBatches:
+def explode_batches(batches: list[RecordBatch], count=None) -> ExplodedBatches:
     lib = _native()
-    payloads, counts, p_off, p_len, ranges, joined, n = _gather_payloads(batches)
+    _, counts, p_off, p_len, ranges, joined, n = _gather_payloads(batches, count)
     if n == 0:
         return ExplodedBatches(
             joined, np.zeros(0, np.int64), np.zeros(0, np.int32), ranges
@@ -320,7 +430,9 @@ def explode_batches(batches: list[RecordBatch]) -> ExplodedBatches:
         off, ln = lib.parse_many(joined, p_off, p_len, counts)
     elif lib is not None:
         offs, lns = [], []
-        for i, payload in enumerate(payloads):
+        for i in range(len(counts)):
+            # a batch's payload out of the blob: a pooled span is no bytes
+            payload = joined[p_off[i] : p_off[i] + p_len[i]]
             o, l = lib.parse_record_values(payload, int(counts[i]))
             offs.append(o + p_off[i])
             lns.append(l)
@@ -328,7 +440,8 @@ def explode_batches(batches: list[RecordBatch]) -> ExplodedBatches:
         ln = np.concatenate(lns) if lns else np.zeros(0, np.int32)
     else:
         offs, lns = [], []
-        for i, payload in enumerate(payloads):
+        for i in range(len(counts)):
+            payload = joined[p_off[i] : p_off[i] + p_len[i]]
             o, l = _parse_record_values_py(payload, int(counts[i]))
             offs.append(o + p_off[i])
             lns.append(l)
@@ -500,7 +613,7 @@ def frame_ranges_gather(
 
 
 def frame_ranges_gather_ptrs(
-    payloads: list[bytes],
+    payloads,
     offsets: np.ndarray,
     lens: np.ndarray,
     keep: np.ndarray,
@@ -510,7 +623,8 @@ def frame_ranges_gather_ptrs(
     """frame_ranges_gather over a pointer table
     (rp_frame_many_gather_ptrs): range r is one input batch, and its
     records' (offset, len) are relative to that batch's own retained
-    payload buffer ``payloads[r]`` (PtrExploded) — a filter-only payload
+    payload ``payloads[r]`` (PtrExploded's LaunchPayloads, or a list of
+    ``bytes``) — a filter-only payload
     launch frames its kept values from the bytes the pack stage just read,
     with no joined blob and no result matrix. Byte-identical to
     ``frame_ranges_gather`` over the joined payloads."""

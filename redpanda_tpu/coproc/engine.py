@@ -137,12 +137,27 @@ class ProcessBatchReply:
     deregistered: list[int] = field(default_factory=list)
 
 
-# Parked staging matrices of the payload lane (TpuEngine._staging). A launch
-# in flight holds its matrix, so this bounds only the idle ones: one serves
-# a script's launches one after another, a few more a burst of launch_depth
-# launches that land together. NOT a leakwatch resource: a launch whose
-# device leg failed drops its matrix on purpose (_launch_payload).
+# Parked staging matrices of the payload lane (TpuEngine._staging), and
+# parked decompress buffers of its explode (TpuEngine._uncompress_pool). A
+# launch in flight holds its own, so this bounds only the idle ones: one
+# serves a script's launches one after another, a few more a burst of
+# launch_depth launches that land together. NOT leakwatch resources: a
+# launch whose device leg failed drops its matrix on purpose
+# (_launch_payload), and an abandoned launch's buffers go with the launch.
 _STAGING_MAX_PARKED = 4
+# A decompress buffer is asked for in steps of this many bytes, so that a
+# script's launches (45-50 MB of decompressed payloads each on the Zstd
+# cell) are served by one parked buffer and not by a ladder of ever larger
+# ones, each paying the first touch of its pages.
+_UNCOMPRESS_QUANTUM = 8 << 20
+
+
+def _release_exploded(ex) -> None:
+    """A launch has read its last byte out of its exploded table: a pointer
+    table's pooled decompress buffers go back to the engine's pool (the
+    joined-blob table owns nothing pooled)."""
+    if isinstance(ex, batch_codec.PtrExploded):
+        ex.release()
 
 
 def _bucket_rows(n: int) -> int:
@@ -570,6 +585,7 @@ class _Launch:
                         )
                         self._stat("t_frame_gather", t0)
                         self._count_frame("n_frame_gather", ex.sizes, keep)
+                        _release_exploded(ex)
                         self._exploded = None
                         self._gather_mat = None
                     else:
@@ -1064,6 +1080,9 @@ class TpuEngine:
         self._gather_frame = bool(gather_frame)
         self._arena = leakwatch.wrap(batch_codec.Arena(), "engine.arena")
         self._staging = batch_codec.Arena(max_free=_STAGING_MAX_PARKED)
+        self._uncompress_pool = batch_codec.Arena(
+            max_free=_STAGING_MAX_PARKED, quantum=_UNCOMPRESS_QUANTUM
+        )
         # Structural-index parse path (native rp_explode_find2 +
         # rp_extract_cols2): fused-vs-staged is a MEASURED per-engine
         # decision — the first representative columnar launch times BOTH
@@ -1489,6 +1508,7 @@ class TpuEngine:
         out["governor"] = self.governor.snapshot()
         out["arena"] = self._arena.stats()
         out["staging_arena"] = self._staging.stats()
+        out["uncompress_arena"] = self._uncompress_pool.stats()
         if lockwatch.enabled():
             # debug mode only: the observed lock-order edge count rides
             # stats() into /v1/coproc/status, rpk debug coproc and BENCH
@@ -1586,7 +1606,11 @@ class TpuEngine:
         entry (level changes are rare by the plane's hysteresis)."""
         trims = evicted = 0
         if level == rm_budgets.PRESSURE_CRITICAL:
-            trims = self._arena.trim() + self._staging.trim()
+            trims = (
+                self._arena.trim()
+                + self._staging.trim()
+                + self._uncompress_pool.trim()
+            )
             if self._colcache is not None:
                 evicted = self._colcache.set_pressure(True)
             self._stat_add("n_pressure_trims", 1.0)
@@ -1614,6 +1638,9 @@ class TpuEngine:
         can use this to return the held buffers to the allocator."""
         self._arena = leakwatch.wrap(batch_codec.Arena(), "engine.arena")
         self._staging = batch_codec.Arena(max_free=_STAGING_MAX_PARKED)
+        self._uncompress_pool = batch_codec.Arena(
+            max_free=_STAGING_MAX_PARKED, quantum=_UNCOMPRESS_QUANTUM
+        )
 
     def reset_stats(self) -> None:
         with self._stats_lock:
@@ -1647,6 +1674,8 @@ class TpuEngine:
                 probes.coproc_kept_rows.inc(v)
             elif key == "bytes_out":
                 probes.coproc_output_bytes.inc(v)
+            elif key in probes.coproc_uncompress:
+                probes.coproc_uncompress[key].inc(v)
 
     def _stat_stage(self, key: str, t0: float, trace_id=_AMBIENT) -> float:
         """Close one stage timer (``t0 = _stage_t0(key)``) through the stage
@@ -1670,6 +1699,23 @@ class TpuEngine:
         fallback (exhausted device retries or an open breaker)."""
         self._stat_add("n_fallback_rows", float(n))
         probes.coproc_fallback_rows.inc(n)
+
+    def _count_uncompress(
+        self, n_batches: int, n_crossings: int, bytes_in: int, bytes_out: int,
+        dt: float,
+    ) -> None:
+        """batch_codec.launch_payloads' hook, the ONE place the decompress
+        leg is counted: the compressed batches of a launch, the crossings
+        into a codec that served them (two a launch through the
+        many-frames form, one a batch otherwise), the bytes in and out,
+        and the ``uncompress`` stage's seconds (the helper timed it, with
+        its ``rp:`` annotation and span; it lies inside the launch's
+        explode stage)."""
+        self._stat_add("t_uncompress", dt)
+        self._stat_add("n_uncompressed_batches", float(n_batches))
+        self._stat_add("n_uncompress_crossings", float(n_crossings))
+        self._stat_add("bytes_uncompress_in", float(bytes_in))
+        self._stat_add("bytes_uncompress_out", float(bytes_out))
 
     def _seal_jobs(self, jobs: list[tuple]) -> list:
         """Recompress + seal framed payloads into output batches
@@ -1967,7 +2013,8 @@ class TpuEngine:
                 # plans, whose zero-copy harvest gathers from it), parsed
                 # by the two-stage structural-index kernel
                 sp = batch_codec.explode_find_structural(
-                    all_batches, paths, need_joined=plan.byte_identity
+                    all_batches, paths, need_joined=plan.byte_identity,
+                    count=self._count_uncompress,
                 )
             if sp is not None:
                 self._stat_stage("t_explode_find2", t0)
@@ -1983,24 +2030,32 @@ class TpuEngine:
             # STAGED lane: framing parse + k-path JSON walk in one scalar
             # native crossing (rp_explode_find) — the parity oracle, and
             # the measured pick on boxes where structural doesn't win
-            fused = batch_codec.explode_and_find(all_batches, paths)
+            fused = batch_codec.explode_and_find(
+                all_batches, paths, count=self._count_uncompress
+            )
             if fused is not None:
                 exploded, types, vs, ve = fused
                 cache = plan.make_cache_from_tables(exploded, paths, types, vs, ve)
                 self._stat_stage("t_explode_find", t0)
             else:
-                exploded = batch_codec.explode_batches(all_batches)
+                exploded = batch_codec.explode_batches(
+                    all_batches, count=self._count_uncompress
+                )
                 self._stat_stage("t_explode", t0)
         else:
             if plan.mode == "payload":
                 # POINTER-TABLE staging lane (ROADMAP item 1 follow-on b):
                 # record (offset, len) parse straight off the decompressed
-                # per-batch payload buffers and staging packs from the
-                # same buffers — the joined blob (and its b"".join copy,
-                # plus _pack_staged's second cache-cold pass over it)
-                # never exists. Bit-identical to the classic lane (the
+                # per-batch payloads (compressed batches decompress into a
+                # pooled buffer in one crossing; uncompressed ones are read
+                # where they lie) and staging packs from the same bytes —
+                # the joined blob (and its b"".join copy, plus
+                # _pack_staged's second cache-cold pass over it) never
+                # exists. Bit-identical to the classic lane (the
                 # _pack_staged parity test pins it).
-                pe = batch_codec.explode_ptrs(all_batches)
+                pe = batch_codec.explode_ptrs(
+                    all_batches, self._uncompress_pool, self._count_uncompress
+                )
                 if pe is not None:
                     self._stat_stage("t_explode_ptrs", t0)
                     launch.ranges = pe.ranges
@@ -2012,7 +2067,9 @@ class TpuEngine:
                         probes.coproc_launch_rows_hist.record(n)
                     self._dispatch_payload(launch, pe, n)
                     return
-            exploded = batch_codec.explode_batches(all_batches)
+            exploded = batch_codec.explode_batches(
+                all_batches, count=self._count_uncompress
+            )
             self._stat_stage("t_explode", t0)
         launch.ranges = exploded.ranges
         n = len(exploded.sizes)
@@ -2557,13 +2614,19 @@ class TpuEngine:
         native crossing — byte-identical staged rows, one fewer full copy
         of the launch's record bytes. Either way the matrix comes from the
         staging pool. A launch whose result is the keep mask retains the
-        table: its kept values are framed from it."""
+        table: its kept values are framed from it, and the pointer table's
+        pooled decompress buffers go back when that framing is done
+        (_Launch.framed); any other launch has read its last payload byte
+        once the matrix is packed, and gives them back here."""
         fn, r_out = self._pipelines[launch.script_id]
         launch.r_out = r_out
         launch.fits = exploded.sizes <= self._row_stride
-        if self._mask_result(launch._plan):
+        retained = self._mask_result(launch._plan)
+        if retained:
             launch._exploded = exploded
         if n == 0:
+            if not retained:
+                _release_exploded(exploded)
             return
         t0 = _stage_t0("t_pack")
         n_pad = _bucket_rows(n)
@@ -2571,6 +2634,8 @@ class TpuEngine:
             staged = self._pack_staged_ptrs(exploded, n_pad)
         else:
             staged = self._pack_staged(exploded, n_pad)
+        if not retained:
+            _release_exploded(exploded)
         self._stat_stage("t_pack", t0)
         self._launch_payload(launch, staged, n_pad, fn, r_out)
 

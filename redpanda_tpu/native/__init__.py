@@ -7,10 +7,13 @@ tests exercise them), but a failed build is never silent: see ``status()``.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
 import logging
 import os
 import subprocess
+import typing
 
 import numpy as np
 
@@ -79,6 +82,30 @@ def _check_gather_cols(src_arr, offsets, lens, n: int) -> None:
         raise ValueError("gather (offset, len) span outside the source blob")
 
 
+class SrcTable(typing.NamedTuple):
+    """A launch's per-batch source buffers as the ``*_ptrs`` crossings take
+    them: one address and one length a buffer."""
+
+    ptrs: np.ndarray  # uint64 [B]
+    lens: np.ndarray  # int64 [B]
+
+
+def src_table(srcs) -> SrcTable:
+    """The pointer table of ``srcs``: anything that already is one
+    (``.ptrs`` / ``.lens``: a ``SrcTable``, ``batch_codec.LaunchPayloads``,
+    alive as long as its owner) is taken as it lies; a list of ``bytes``
+    becomes one here (borrowed char*: the address array's base is the
+    ctypes array, which retains the objects)."""
+    ptrs = getattr(srcs, "ptrs", None)
+    if ptrs is not None:
+        return SrcTable(ptrs, srcs.lens)
+    n = len(srcs)
+    if not n:
+        return SrcTable(np.zeros(0, np.uint64), np.zeros(0, np.int64))
+    ptrs = np.frombuffer((ctypes.c_char_p * n)(*srcs), dtype=np.uint64)
+    return SrcTable(ptrs, np.fromiter((len(b) for b in srcs), np.int64, n))
+
+
 class _NativeLib:
     def __init__(self, dll: ctypes.CDLL):
         self._dll = dll
@@ -144,7 +171,7 @@ class _NativeLib:
         if self.has_structural:
             dll.rp_explode_find2.restype = ctypes.c_int64
             dll.rp_explode_find2.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p), ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p,
                 ctypes.c_char_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_int32, ctypes.c_void_p, ctypes.c_void_p,
@@ -152,7 +179,7 @@ class _NativeLib:
             ]
             dll.rp_extract_cols2.restype = None
             dll.rp_extract_cols2.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p), ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_int32, ctypes.c_void_p,
@@ -234,6 +261,32 @@ class _NativeLib:
                 ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
                 ctypes.c_int64, ctypes.c_size_t,
             ]
+        self.has_parse_many_ptrs = hasattr(dll, "rp_parse_many_ptrs")
+        if self.has_parse_many_ptrs:
+            dll.rp_parse_many_ptrs.restype = ctypes.c_int64
+            dll.rp_parse_many_ptrs.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p,
+            ]
+        # many zstd frames a crossing; libzstd is resolved at run time, so
+        # the symbol being there does not say the codec is
+        self.has_zstd_many = False
+        if hasattr(dll, "rp_zstd_uncompress_many"):
+            dll.rp_zstd_available.restype = ctypes.c_int32
+            dll.rp_zstd_available.argtypes = []
+            dll.rp_zstd_frame_sizes.restype = ctypes.c_int64
+            dll.rp_zstd_frame_sizes.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_void_p, ctypes.c_void_p,
+            ]
+            dll.rp_zstd_uncompress_many.restype = ctypes.c_int64
+            dll.rp_zstd_uncompress_many.argtypes = [
+                ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_int32,
+            ]
+            self.has_zstd_many = bool(dll.rp_zstd_available())
         dll.rp_json_find.restype = ctypes.c_int32
         dll.rp_json_find.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int32,
@@ -318,7 +371,7 @@ class _NativeLib:
 
     def pack_rows_ptrs(
         self,
-        srcs: list[bytes],
+        srcs,
         offsets: np.ndarray,
         lens: np.ndarray,
         starts: np.ndarray,
@@ -328,7 +381,8 @@ class _NativeLib:
     ) -> None:
         """Fill a payload launch's whole staging matrix in ONE crossing
         (rp_pack_rows_ptrs): batch r's records, their (offset, len)
-        relative to their own buffer ``srcs[r]``, become rows
+        relative to their own buffer ``srcs[r]`` (a list of ``bytes`` or
+        a pointer table, ``src_table``), become rows
         [starts[r], ends[r]) of ``dst`` [n_pad, row_stride + 8]: value,
         zeroed tail, LE32 length (0 for a null value and for one wider
         than ``row_stride``), four zero bytes; the rows past the last
@@ -341,7 +395,8 @@ class _NativeLib:
         ends = np.ascontiguousarray(ends, dtype=np.int64)
         n = len(offsets)
         n_batches = len(starts)
-        if len(srcs) != n_batches or len(ends) != n_batches:
+        ptrs, src_lens = src_table(srcs)
+        if len(ptrs) != n_batches or len(ends) != n_batches:
             raise ValueError("srcs/ranges length mismatch")
         if len(lens) != n:
             raise ValueError("offsets/lens length mismatch")
@@ -358,11 +413,8 @@ class _NativeLib:
             or not np.array_equal(edges[:-1], starts)
         ):
             raise ValueError("pack_rows_ptrs ranges do not tile the rows")
-        # bytes -> borrowed char*; the ctypes array retains the objects
-        ptrs = (ctypes.c_char_p * n_batches)(*srcs)
-        src_lens = np.fromiter((len(b) for b in srcs), np.int64, n_batches)
         rc = self._dll.rp_pack_rows_ptrs(
-            ptrs, src_lens.ctypes.data, offsets.ctypes.data,
+            ptrs.ctypes.data, src_lens.ctypes.data, offsets.ctypes.data,
             lens.ctypes.data, starts.ctypes.data, ends.ctypes.data,
             n_batches, dst.ctypes.data, n, n_pad, row_stride,
         )
@@ -557,7 +609,7 @@ class _NativeLib:
 
     def frame_many_gather_ptrs(
         self,
-        srcs: list[bytes],
+        srcs,
         offsets: np.ndarray,
         lens: np.ndarray,
         keep: np.ndarray,
@@ -577,20 +629,18 @@ class _NativeLib:
         ends = np.ascontiguousarray(ends, dtype=np.int64)
         n = len(offsets)
         n_ranges = len(starts)
-        if len(srcs) != n_ranges:
+        ptrs, src_lens = src_table(srcs)
+        if len(ptrs) != n_ranges:
             raise ValueError("srcs/ranges length mismatch")
         if len(lens) != n or len(keep) != n:
             raise ValueError("offsets/lens/keep length mismatch")
         _check_ranges(starts, ends, n, "frame_many_gather_ptrs")
-        # bytes -> borrowed char*; the ctypes array retains the objects
-        ptrs = (ctypes.c_char_p * n_ranges)(*srcs)
-        src_lens = np.fromiter((len(b) for b in srcs), np.int64, n_ranges)
         dst = _take_scratch(out, _gather_dst_cap(lens, n))
         out_off = np.empty(n_ranges, dtype=np.int64)
         out_len = np.empty(n_ranges, dtype=np.int64)
         out_kept = np.empty(n_ranges, dtype=np.int32)
         total = self._dll.rp_frame_many_gather_ptrs(
-            ptrs, src_lens.ctypes.data, offsets.ctypes.data,
+            ptrs.ctypes.data, src_lens.ctypes.data, offsets.ctypes.data,
             lens.ctypes.data, keep.ctypes.data, starts.ctypes.data,
             ends.ctypes.data, n_ranges, dst.ctypes.data,
             out_off.ctypes.data, out_len.ctypes.data, out_kept.ctypes.data,
@@ -623,6 +673,78 @@ class _NativeLib:
         if parsed != total:
             raise ValueError(f"record framing parse failed at record {parsed}/{total}")
         return val_off, val_len
+
+    def parse_many_ptrs(
+        self, srcs, counts: np.ndarray, total: int | None = None
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """parse_many over a pointer table (rp_parse_many_ptrs): the value
+        (offset, length) of every record of a launch in ONE crossing, each
+        offset relative to its own batch's buffer ``srcs[b]``, and the
+        lengths clamped at 0 (``sizes``). ``total``: ``counts.sum()``, from
+        a caller that has it."""
+        counts = np.ascontiguousarray(counts, dtype=np.int32)
+        ptrs, src_lens = src_table(srcs)
+        if len(ptrs) != len(counts):
+            raise ValueError("srcs/counts length mismatch")
+        if total is None:
+            total = int(counts.sum())
+        val_off = np.empty(total, dtype=np.int64)
+        val_len = np.empty(total, dtype=np.int32)
+        sizes = np.empty(total, dtype=np.int32)
+        parsed = self._dll.rp_parse_many_ptrs(
+            ptrs.ctypes.data, src_lens.ctypes.data, counts.ctypes.data,
+            len(counts), val_off.ctypes.data, val_len.ctypes.data,
+            sizes.ctypes.data,
+        )
+        if parsed != total:
+            raise ValueError(f"record framing parse failed at record {parsed}/{total}")
+        return val_off, val_len, sizes
+
+    def zstd_frame_sizes(self, frames) -> tuple[np.ndarray, np.ndarray, int]:
+        """(off int64 [n], ln int64 [n], total): the content size each
+        frame's header states (``ln``; -1 where it states none or does not
+        parse), and where each sized frame goes in one buffer of ``total``
+        bytes that holds them back to back (rp_zstd_frame_sizes).
+        ``frames``: a list of ``bytes`` or a pointer table (``src_table``)."""
+        ptrs, src_lens = src_table(frames)
+        off = np.empty(len(ptrs), dtype=np.int64)
+        ln = np.empty(len(ptrs), dtype=np.int64)
+        total = self._dll.rp_zstd_frame_sizes(
+            ptrs.ctypes.data, src_lens.ctypes.data, len(ptrs),
+            off.ctypes.data, ln.ctypes.data,
+        )
+        if total < 0:
+            raise RuntimeError("libzstd is not available")
+        return off, ln, int(total)
+
+    def zstd_uncompress_many(
+        self, frames, dst: np.ndarray, dst_off: np.ndarray,
+        dst_len: np.ndarray, n_threads: int = 1,
+    ) -> int:
+        """Decompress frame b into ``dst[dst_off[b] : dst_off[b] +
+        dst_len[b]]`` for every b with ``dst_len[b] >= 0``, in ONE crossing
+        that holds no interpreter lock (rp_zstd_uncompress_many), on
+        ``n_threads`` threads. A frame that fails or does not fill its span
+        exactly gets ``dst_len[b] = -1`` IN PLACE; returns how many did.
+        ``frames`` as for ``zstd_frame_sizes``; ``dst_off`` / ``dst_len``
+        contiguous int64; a span outside ``dst`` is a ValueError with
+        nothing written (checked inside the crossing)."""
+        ptrs, src_lens = src_table(frames)
+        n = len(ptrs)
+        for a in (dst_off, dst_len):
+            if a.dtype != np.int64 or not a.flags["C_CONTIGUOUS"] or len(a) != n:
+                raise ValueError("dst spans must be contiguous int64, one a frame")
+        if dst.dtype != np.uint8 or dst.ndim != 1 or not dst.flags["C_CONTIGUOUS"]:
+            raise ValueError("zstd_uncompress_many dst must be contiguous uint8")
+        failed = self._dll.rp_zstd_uncompress_many(
+            ptrs.ctypes.data, src_lens.ctypes.data, n, dst.ctypes.data,
+            dst.nbytes, dst_off.ctypes.data, dst_len.ctypes.data, n_threads,
+        )
+        if failed == -2:
+            raise ValueError("decompress span outside dst")
+        if failed < 0:
+            raise RuntimeError("libzstd is not available")
+        return int(failed)
 
     def explode_find(
         self,
@@ -675,12 +797,10 @@ class _NativeLib:
         val_off is absolute into the (possibly virtual) concatenation,
         identical to explode_find's tables."""
         counts = np.ascontiguousarray(counts, dtype=np.int32)
-        p_len = np.fromiter((len(p) for p in payloads), np.int32, len(payloads))
+        ptrs, src_lens = src_table(payloads)
+        p_len = src_lens.astype(np.int32)
         total = int(counts.sum())
         blob, path_off, path_len, k = _pack_paths(paths)
-        # bytes -> borrowed char*; the ctypes array retains the objects and
-        # the caller holds the payloads list across the call either way
-        ptrs = (ctypes.c_char_p * len(payloads))(*payloads)
         joined = (
             np.empty(max(int(p_len.sum()), 1), dtype=np.uint8)
             if build_joined
@@ -692,7 +812,7 @@ class _NativeLib:
         vs = np.empty((total, k), dtype=np.int64)
         ve = np.empty((total, k), dtype=np.int64)
         parsed = self._dll.rp_explode_find2(
-            ptrs, p_len.ctypes.data, counts.ctypes.data, len(payloads),
+            ptrs.ctypes.data, p_len.ctypes.data, counts.ctypes.data, len(ptrs),
             joined.ctypes.data if joined is not None else None,
             blob, path_off.ctypes.data, path_len.ctypes.data, k,
             val_off.ctypes.data, val_len.ctypes.data,
@@ -730,7 +850,8 @@ class _NativeLib:
         the flat list in desc order (num -> f32, i32, flags; str -> bytes
         [n_pad, w], vlen; exists -> u8) — the _bind_slots input shape."""
         counts = np.ascontiguousarray(counts, dtype=np.int32)
-        p_len = np.fromiter((len(p) for p in payloads), np.int32, len(payloads))
+        ptrs, src_lens = src_table(payloads)
+        p_len = src_lens.astype(np.int32)
         val_off = np.ascontiguousarray(val_off, dtype=np.int64)
         val_len = np.ascontiguousarray(val_len, dtype=np.int32)
         types = np.ascontiguousarray(types, dtype=np.int8)
@@ -738,7 +859,6 @@ class _NativeLib:
         ve = np.ascontiguousarray(ve, dtype=np.int64)
         pred_descs = np.ascontiguousarray(pred_descs, dtype=np.int32)
         n, _k = types.shape
-        ptrs = (ctypes.c_char_p * len(payloads))(*payloads)
         arrays: list[np.ndarray] = []
         for kind, _col, w, _ in pred_descs:
             if kind == 0:
@@ -769,7 +889,7 @@ class _NativeLib:
             rows = ok = None
             n_proj, rows_ptr, ok_ptr, proj_ptr = 0, None, None, None
         self._dll.rp_extract_cols2(
-            ptrs, p_len.ctypes.data, counts.ctypes.data, len(payloads),
+            ptrs.ctypes.data, p_len.ctypes.data, counts.ctypes.data, len(ptrs),
             val_off.ctypes.data, val_len.ctypes.data,
             types.ctypes.data, vs.ctypes.data, ve.ctypes.data, types.shape[1],
             pred_descs.ctypes.data, len(pred_descs), pred_ptrs, n_pad,
@@ -886,22 +1006,59 @@ class _NativeLib:
         return dst.tobytes()
 
 
-def _build_and_load():
-    """(lib or None, build error or None). ``make`` decides staleness (a
-    cheap no-op when the .so is current). A failed build is logged at
+@contextlib.contextmanager
+def _build_lock(native_dir: str):
+    """One on-demand build at a time per checkout: several interpreters that
+    import this module at once (pytest -n 6 on a fresh checkout) queue here,
+    the first builds, the rest find ``make`` a no-op. The Makefile links to a
+    name of its own and renames, so even an importer outside this lock never
+    loads a half-written file. A checkout that cannot be written to takes no
+    lock (its ``make`` has nothing to write either)."""
+    try:
+        fd = os.open(
+            os.path.join(native_dir, ".build.lock"), os.O_CREAT | os.O_RDWR, 0o644
+        )
+    except OSError:
+        yield
+        return
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        yield
+    finally:
+        os.close(fd)  # closing drops the lock
+
+
+def _stale(so: str, src: str) -> bool:
+    """``make``'s own rule, asked without starting it: there is a source, and
+    no library or an older one. A current library is whole (it was renamed
+    into place), so its importers take no lock and start no process: a
+    cluster of brokers and six test workers starting together would
+    otherwise queue on the lock for one no-op ``make`` each."""
+    try:
+        return not os.path.exists(so) or os.path.getmtime(so) < os.path.getmtime(src)
+    except OSError:
+        return False  # no source: nothing to build from
+
+
+def _build_and_load(native_dir: str = _NATIVE_DIR):
+    """(lib or None, build error or None). A library older than its source
+    (or none) is built with ``make``, under the build lock; inside it
+    ``make`` decides again, so of several importers one builds. A failed build is logged at
     ERROR and kept in ``build_error`` whether or not an older .so could
     still be loaded — ``status()`` carries it into the broker's
     /v1/coproc/status, and chip_smoke.py fails on it."""
     error = None
-    src = os.path.join(_NATIVE_DIR, "redpanda_native.cc")
-    if os.path.exists(src):
+    so = os.path.join(native_dir, os.path.basename(_SO))
+    src = os.path.join(native_dir, "redpanda_native.cc")
+    if _stale(so, src):
         try:
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
+            with _build_lock(native_dir):
+                subprocess.run(
+                    ["make", "-C", native_dir],
+                    check=True,
+                    capture_output=True,
+                    timeout=120,
+                )
         except subprocess.CalledProcessError as exc:
             error = f"make failed ({exc.returncode}): " + exc.stderr.decode(
                 errors="replace"
@@ -909,16 +1066,16 @@ def _build_and_load():
         except (OSError, subprocess.TimeoutExpired) as exc:
             error = f"make did not run: {exc!r}"
     loaded = None
-    if os.path.exists(_SO):
+    if os.path.exists(so):
         try:
-            loaded = _NativeLib(ctypes.CDLL(_SO))
+            loaded = _NativeLib(ctypes.CDLL(so))
         except (OSError, AttributeError) as exc:
             # AttributeError = a stale .so missing a required symbol; a
             # raising module-level import would evict the module and
             # re-run `make` on every later _native() call
             error = (error + "; " if error else "") + f"load failed: {exc!r}"
     elif error is None:
-        error = f"{_SO} does not exist and there is no source to build it"
+        error = f"{so} does not exist and there is no source to build it"
     if error is not None:
         logger.error(
             "native library %s: %s",

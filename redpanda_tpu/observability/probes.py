@@ -118,6 +118,28 @@ coproc_output_bytes = registry.counter(
     "coproc_output_bytes_total",
     "Value bytes the harvest framed into output batches",
 )
+# The decompress leg of the explode (batch_codec.launch_payloads): scanned
+# batches that arrived compressed, the crossings into a codec that served
+# them (two a launch through a many-frames form, one a batch otherwise) and
+# the bytes in and out; keyed by the engine's stats() name.
+coproc_uncompress = {
+    "n_uncompressed_batches": registry.counter(
+        "coproc_uncompressed_batches_total",
+        "Scanned batches the explode decompressed",
+    ),
+    "n_uncompress_crossings": registry.counter(
+        "coproc_uncompress_crossings_total",
+        "Crossings into a codec that decompressed scanned batches",
+    ),
+    "bytes_uncompress_in": registry.counter(
+        "coproc_uncompress_in_bytes_total",
+        "Compressed payload bytes the explode decompressed",
+    ),
+    "bytes_uncompress_out": registry.counter(
+        "coproc_uncompress_out_bytes_total",
+        "Payload bytes the explode's decompress produced",
+    ),
+}
 coproc_launch_rows_hist = registry.histogram(
     "coproc_launch_rows",
     "Records fused into one device launch (bucket size after shape rounding)",
@@ -476,6 +498,7 @@ __all__ = [
     "coproc_stage_hist",
     "coproc_staged_rows",
     "coproc_tick_hist",
+    "coproc_uncompress",
     "host_pool_task_finished",
     "host_pool_task_started",
     "kafka_fetch_hist",

@@ -405,7 +405,7 @@ def test_pack_rows_ptrs_bad_span_raises_and_writes_nothing():
         (n - 1, len(pe.payloads[-1]) - int(pe.sizes[-1]) + 1),  # ends 1 past
         (0, -1),
     ):
-        offsets = pe.offsets
+        offsets = pe.offsets.copy()
         offsets[bad_row] = bad_off
         with pytest.raises(ValueError):
             lib.pack_rows_ptrs(
@@ -415,7 +415,7 @@ def test_pack_rows_ptrs_bad_span_raises_and_writes_nothing():
     # a span inside ANOTHER batch's buffer length but outside its own is
     # still outside: the check is per buffer
     assert len(pe.payloads[3]) > len(pe.payloads[4])
-    offsets = pe.offsets
+    offsets = pe.offsets.copy()
     offsets[n - 1] = len(pe.payloads[4])
     with pytest.raises(ValueError):
         lib.pack_rows_ptrs(pe.payloads, offsets, pe.sizes, starts, ends, dst, 128)
@@ -500,7 +500,7 @@ def test_payload_reply_parity_ptr_vs_classic(monkeypatch):
         ], stats
 
     got_ptr, st_ptr = run()
-    monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches: None)
+    monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches, *a, **k: None)
     got_classic, st_classic = run()
     assert got_ptr == got_classic
     if "t_explode_ptrs" in st_ptr:  # native present: the lane engaged

@@ -568,7 +568,7 @@ def test_payload_mask_harvest_matches_matrix_road(name, shape, use_native, monke
     spec, mask = _PAYLOAD_SPECS[name]
     if not use_native:
         monkeypatch.setattr(batch_codec, "_native", lambda: None)
-        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches: None)
+        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches, *a, **k: None)
     reqs = _payload_shapes()[shape]
     on, stats_on = _run_group(spec, True, reqs)
     off, stats_off = _run_group(spec, False, reqs)

@@ -109,7 +109,7 @@ def test_payload_lane_matches_the_plain_reference(seed, road, monkeypatch):
     from redpanda_tpu.coproc import batch_codec
 
     if road == "mask_joined_blob":
-        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches: None)
+        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches, *a, **k: None)
     config = _config()
     ref = _reference(config["reference"]["name"])
     params = config["reference"]["params"]
@@ -352,7 +352,7 @@ def test_map_lane_matches_the_plain_reference(seed, staging, monkeypatch):
     from redpanda_tpu.observability import probes
 
     if staging == "joined_blob":
-        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches: None)
+        monkeypatch.setattr(batch_codec, "explode_ptrs", lambda batches, *a, **k: None)
     config, ref, params = _map_config()
     stride = params["row_stride"]
     edges = [v for v, _, _ in MAP_EDGES.values()]
