@@ -58,7 +58,6 @@ class Application:
             auto_create_topics=c.auto_create_topics_enabled,
             default_partitions=c.default_topic_partitions,
             default_replication=c.default_topic_replication,
-            fetch_poll_interval_s=c.fetch_poll_interval_ms / 1000.0,
             sasl_enabled=c.enable_sasl,
             superusers=[u for u in c.superusers.split(",") if u],
             unsafe_relaxed_acks=c.unsafe_relaxed_acks,

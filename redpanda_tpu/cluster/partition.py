@@ -16,6 +16,7 @@ from dataclasses import dataclass
 
 from redpanda_tpu.models.fundamental import NTP, NodeId
 from redpanda_tpu.models.record import RecordBatch, RecordBatchType
+from redpanda_tpu.raft.consensus import OffsetMonitor
 from redpanda_tpu.storage.log import DiskLog
 from redpanda_tpu.storage.log_manager import StorageApi
 
@@ -45,6 +46,9 @@ class DirectConsensus:
         self.log = log
         self.node_id = node_id
         self._term = term
+        # every append is a commit here: replicate notifies, so a parked
+        # fetch wakes on a direct log as it does on a raft group
+        self._commit_monitor = OffsetMonitor()
 
     @property
     def term(self) -> int:
@@ -74,9 +78,16 @@ class DirectConsensus:
 
     async def replicate(self, batches: list[RecordBatch], level: int) -> ReplicateResult:
         res = await self.log.append(batches, term=self._term)
+        self._commit_monitor.notify(res.last_offset)
         if level == ConsistencyLevel.quorum_ack:
             await self.log.flush()
         return ReplicateResult(res.base_offset, res.last_offset)
+
+    def watch_commit(self, fut):
+        return self._commit_monitor.watch(self.committed_offset + 1, fut)
+
+    def unwatch_commit(self, waiter) -> None:
+        self._commit_monitor.unwatch(waiter)
 
     async def make_reader(
         self, start: int, max_bytes: int, max_offset: int | None = None, type_filter=None
@@ -171,6 +182,21 @@ class Partition:
     @property
     def last_stable_offset(self) -> int:
         return self.otl.to_kafka_excl(self.consensus.last_stable_offset)
+
+    def watch_hwm(self, seen: int, fut):
+        """Have the caller's future resolved by this partition's next commit
+        advance (failed, if the group steps down or stops): what a parked
+        fetch waits on, one future over every partition it asked for.
+        ``seen`` is the high watermark the caller last read; None if it has
+        moved since, and there is nothing to wait for. The waiter returned
+        must go back to ``unwatch_hwm``, however the wait ends: only a
+        commit drops it, and an idle partition has none."""
+        if self.high_watermark != seen:
+            return None
+        return self.consensus.watch_commit(fut)
+
+    def unwatch_hwm(self, waiter) -> None:
+        self.consensus.unwatch_commit(waiter)
 
     # -------------------------------------------------------------- append stamps
     def _stamp_append(self, btype, base: int, last: int) -> None:

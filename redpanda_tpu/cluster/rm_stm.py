@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 
 from redpanda_tpu.kafka.protocol.errors import ErrorCode as E
 from redpanda_tpu.models.record import Record, RecordBatch
+from redpanda_tpu.raft.consensus import OffsetMonitor
 
 logger = logging.getLogger("rptpu.cluster.rm_stm")
 
@@ -74,6 +75,9 @@ class RmStm:
         # pids whose AddPartitionsToTxn arrived but no data yet (tx_fence)
         self._pending_begin: set[int] = set()
         self._aborted: list[AbortedTx] = []
+        # parked read_committed fetches: the LSO can move with no commit
+        # of its own (a tx's end is noted turns after its marker commits)
+        self._lso_monitor = OffsetMonitor()
         self._recovered = False
         self._recover_lock = None  # lazily created (needs a running loop)
         self._lock = None  # produce-path critical section, lazily created
@@ -159,11 +163,28 @@ class RmStm:
                 to_append.append(b)
             if not to_append:
                 return E.none, None
-            res = await self.partition.replicate(to_append, level)  # pandalint: disable=LCK702 -- idempotency stm: sequence-check + replicate + note_appended must be one atom or dedup state races the log
+            # A transaction clamps the LSO from BEFORE its first batch can
+            # commit: that commit wakes a parked read_committed fetch turns
+            # ahead of _note_appended below, which learns the batch's
+            # offset. Until then the high watermark stands in, a floor
+            # under whatever offset the batch gets.
+            opened = {
+                b.header.producer_id
+                for b in to_append
+                if b.header.is_transactional and b.header.producer_id not in self._ongoing
+            }
+            self._ongoing.update(dict.fromkeys(opened, self.partition.high_watermark))
+            try:
+                res = await self.partition.replicate(to_append, level)  # pandalint: disable=LCK702 -- idempotency stm: sequence-check + replicate + note_appended must be one atom or dedup state races the log
+            finally:
+                for pid in opened:
+                    self._ongoing.pop(pid, None)
             base = res.base_offset
             for b in to_append:
                 self._note_appended(b, base)
                 base += b.header.record_count
+            if opened:  # floor -> the offset itself: the clamp moved up
+                self._lso_monitor.notify(self.last_stable_offset)
             return E.none, res
 
     def _check(self, batch: RecordBatch, sim: dict[int, int]) -> E:
@@ -235,6 +256,7 @@ class RmStm:
         first = self._ongoing.pop(pid)
         if not commit:
             self._aborted.append(AbortedTx(pid, first, res.last_offset))
+        self._lso_monitor.notify(self.last_stable_offset)
         return E.none
 
     # ------------------------------------------------------------ fetch path
@@ -245,6 +267,17 @@ class RmStm:
         if not self._ongoing:
             return hwm
         return min(min(self._ongoing.values()), hwm)
+
+    def watch_lso(self, seen: int, fut):
+        """``Partition.watch_hwm``'s twin for a read_committed fetch: the
+        caller's future resolves once the LSO passes ``seen``; None if it
+        already has. The waiter goes back to ``unwatch_lso``."""
+        if self.last_stable_offset != seen:
+            return None
+        return self._lso_monitor.watch(seen + 1, fut)
+
+    def unwatch_lso(self, waiter) -> None:
+        self._lso_monitor.unwatch(waiter)
 
     def aborted_ranges(self, fetch_offset: int, max_offset: int) -> list[AbortedTx]:
         return [

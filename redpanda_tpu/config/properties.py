@@ -106,7 +106,6 @@ PROPERTIES: list[Property] = [
     Property("default_topic_partitions", "Default partition count", 1, int, _positive),
     Property("default_topic_replication", "Default replication factor", 1, int, _positive),
     Property("group_topic_partitions", "__consumer_offsets partitions", 16, int, _positive),
-    Property("fetch_poll_interval_ms", "Long-poll re-check cadence", 20, int, _positive, needs_restart=False),
     Property("unsafe_relaxed_acks", "CONSISTENCY-TESTING ONLY: ack acks=-1 at leader level (deliberately unsafe)", False, bool),
     Property("target_quota_byte_rate", "Per-client produce quota B/s (0 off)", 0, int, _non_negative, needs_restart=False),
     Property("kafka_qdc_enable", "Queue-depth latency control on the kafka path", False, bool),

@@ -27,7 +27,6 @@ class BrokerConfig:
     auto_create_topics: bool = True
     default_partitions: int = 1
     default_replication: int = 1
-    fetch_poll_interval_s: float = 0.02
     sasl_enabled: bool = False
     superusers: list = field(default_factory=list)
     # client quotas (quota_manager.h): bytes/s per client-id, None=unlimited

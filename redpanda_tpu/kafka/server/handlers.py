@@ -21,9 +21,11 @@ from redpanda_tpu.cluster.partition import ConsistencyLevel
 from redpanda_tpu.cluster.topic_table import TopicConfig
 from redpanda_tpu.observability import stages
 from redpanda_tpu.observability.probes import (
+    kafka_fetch_parks,
     kafka_fetch_serve_hist,
     kafka_fetch_wake_hist,
 )
+from redpanda_tpu.raft.types import RaftError
 from redpanda_tpu.security.acl import AclOperation, ResourceType
 
 E = ErrorCode
@@ -448,35 +450,35 @@ async def _do_handle_fetch(ctx) -> dict:
     max_wait_ms = req.get("max_wait_ms", 0)
     min_bytes = max(req.get("min_bytes", 0), 0)
     max_bytes = req.get("max_bytes", 0x7FFFFFFF)
-    deadline = time.monotonic() + max(max_wait_ms, 0) / 1000.0
-    poll = ctx.broker.config.fetch_poll_interval_s
+    loop = asyncio.get_running_loop()
+    deadline = loop.time() + max(max_wait_ms, 0) / 1000.0
     serve_s = 0.0  # inside the read + encode passes only, never the gate
-    waited = False
+    parked = None  # how the fetch's newest park ended, once it has parked
     while True:
         t0 = stages.begin("kafka.fetch.serve")
         try:
             responses, total, any_error, appended = await _fetch_once(
-                ctx, topics, max_bytes, stamps=waited
+                ctx, topics, max_bytes, stamps=parked is not None
             )
         finally:
             serve_s += stages.close("kafka.fetch.serve", None, t0)
         # respond immediately on any partition error (kafka semantics) or
         # once min_bytes is satisfied / the wait budget is spent
-        if any_error or total >= min_bytes or time.monotonic() >= deadline:
+        if any_error or total >= min_bytes or loop.time() >= deadline:
             break
-        waited = True
-        # Long-poll gate: re-reading and re-encoding every poll tick is
-        # wasted work — only rerun _fetch_once after some requested
-        # partition's high watermark advances.
-        hwms = _fetch_hwm_snapshot(ctx, topics)
-        while time.monotonic() < deadline:
-            await asyncio.sleep(min(poll, max(deadline - time.monotonic(), 0)))
-            if _fetch_hwm_snapshot(ctx, topics) != hwms:
-                break
+        # Long-poll gate: nothing can change the answer but a commit on a
+        # requested partition, so that (or the deadline) is what ends the
+        # park; a deadline leaves the answer just built as it stands.
+        if not await _park(ctx, responses, deadline):
+            parked = "deadline"
+            break
+        parked = "woken_by_commit"
     kafka_fetch_serve_hist.record(int(serve_s * 1e6))
+    if parked is not None:
+        kafka_fetch_parks[parked].inc()
     if appended is not None:
         # a long poll that returns data: how long its oldest batch had
-        # been in the log (the price of the re-check interval above)
+        # been in the log (the commit's wake, a late loop turn, the pass)
         kafka_fetch_wake_hist.record(int((time.perf_counter() - appended) * 1e6))
     throttle = ctx.broker.quota_manager.record_fetch(ctx.header.client_id, total)
     if session is not None:
@@ -488,13 +490,46 @@ async def _do_handle_fetch(ctx) -> dict:
     return out
 
 
-def _fetch_hwm_snapshot(ctx, topics) -> tuple:
-    out = []
-    for t in topics:
-        for p in t["partitions"]:
-            part = ctx.broker.get_partition(t["name"], p["partition_index"])
-            out.append(part.high_watermark if part is not None else -1)
-    return tuple(out)
+async def _park(ctx, responses: list, deadline: float) -> bool:
+    """The long-poll gate. Parks the fetch until a commit moves some
+    requested partition's high watermark (under read_committed, its LSO)
+    past what the pass that built ``responses`` saw: True, and the caller
+    reads again; or until ``deadline`` on the loop's clock: False. A group
+    that fails its waiters (stepped down, stopping) counts as a wake: the
+    next pass answers not_leader_for_partition instead of the deadline.
+    One future watches every partition, and every watch is taken back on
+    the way out, also when a closing connection cancels the fetch."""
+    broker = ctx.broker
+    read_committed = ctx.request.get("isolation_level", 0) == 1
+    woken = asyncio.get_running_loop().create_future()
+    watches = []
+    try:
+        for t in responses:
+            for p in t["partitions"]:
+                partition = broker.get_partition(t["name"], p["partition_index"])
+                if partition is None:
+                    return True  # deleted under the fetch: the next pass says so
+                waiter = partition.watch_hwm(p["high_watermark"], woken)
+                if waiter is None:
+                    return True  # committed while the pass read the others
+                watches.append((partition.unwatch_hwm, waiter))
+                if read_committed:
+                    stm = broker.rm_stm_for(partition)
+                    waiter = stm.watch_lso(p["last_stable_offset"], woken)
+                    if waiter is None:
+                        return True
+                    watches.append((stm.unwatch_lso, waiter))
+        try:
+            async with asyncio.timeout_at(deadline):
+                await woken
+        except TimeoutError:
+            return False
+        except RaftError:
+            pass
+        return True
+    finally:
+        for unwatch, waiter in watches:
+            unwatch(waiter)
 
 
 async def _fetch_once(

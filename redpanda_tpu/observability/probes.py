@@ -54,11 +54,24 @@ kafka_fetch_serve_hist = registry.histogram(
 )
 # One sample per fetch that went through the long-poll gate and returned
 # data: append of the oldest batch it returns -> the response is built
-# (what the fetch_poll_interval_s re-check costs a tailing consumer).
+# (the commit wakes the parked fetch, so what a tailing consumer pays is
+# the commit itself on a raft group, a late loop turn and the serve pass).
 kafka_fetch_wake_hist = registry.histogram(
     "kafka_fetch_wake_latency_us",
     "Append of the oldest batch a long-polling fetch returns to its return (us)",
 )
+# One count per fetch that parked in the long-poll gate, by what ended its
+# last park: a commit on a requested partition (or its group failing its
+# waiters: stepped down, stopping), or the request's own max_wait_ms.
+# woken_by_commit / (woken_by_commit + deadline) is the gate's engage share.
+kafka_fetch_parks = {
+    end: registry.counter(
+        "kafka_fetch_parks_total",
+        "Fetches that parked in the long-poll gate, by what ended the park",
+        end=end,
+    )
+    for end in ("woken_by_commit", "deadline")
+}
 rpc_request_hist = registry.histogram(
     "rpc_request_latency_us", "Internal RPC round-trip latency (us)"
 )

@@ -87,6 +87,8 @@ class OffsetMonitor:
         self._waiters: list[tuple[int, asyncio.Future]] = []
 
     def notify(self, offset: int) -> None:
+        if not self._waiters:
+            return
         fire = [w for w in self._waiters if w[0] <= offset]
         self._waiters = [w for w in self._waiters if w[0] > offset]
         for _, fut in fire:
@@ -99,17 +101,35 @@ class OffsetMonitor:
                 fut.set_exception(exc)
         self._waiters = []
 
+    def watch(self, offset: int, fut: asyncio.Future) -> tuple[int, asyncio.Future]:
+        """Resolve the caller's ``fut`` once ``offset`` is reached (or fail
+        it with the monitor). One future may watch several monitors: the
+        first to get there resolves it. Only a passing offset drops a
+        waiter, so the caller hands the returned waiter to ``unwatch`` on
+        every way out."""
+        waiter = (offset, fut)
+        self._waiters.append(waiter)
+        return waiter
+
+    def unwatch(self, waiter: tuple[int, asyncio.Future]) -> None:
+        try:
+            self._waiters.remove(waiter)
+        except ValueError:
+            pass  # notified or failed: already dropped
+
     async def wait_for(self, offset: int, current: int, timeout: float | None = None) -> int:
         if current >= offset:
             return current
         fut: asyncio.Future = asyncio.get_event_loop().create_future()
-        self._waiters.append((offset, fut))
-        if timeout is None:
-            return await fut
+        waiter = self.watch(offset, fut)
         try:
+            if timeout is None:
+                return await fut
             return await asyncio.wait_for(fut, timeout)
         except asyncio.TimeoutError:
             raise RaftError(Errc.timeout, f"offset {offset} not committed in time")
+        finally:
+            self.unwatch(waiter)
 
 
 class Consensus:
@@ -689,6 +709,14 @@ class Consensus:
 
     async def wait_for_commit(self, offset: int, timeout: float | None = None) -> int:
         return await self._commit_monitor.wait_for(offset, self._commit_index, timeout)
+
+    def watch_commit(self, fut: asyncio.Future) -> tuple[int, asyncio.Future]:
+        """Resolve ``fut`` at the next advance of the commit index (fail it
+        on step-down or stop); the waiter goes back to ``unwatch_commit``."""
+        return self._commit_monitor.watch(self._commit_index + 1, fut)
+
+    def unwatch_commit(self, waiter: tuple[int, asyncio.Future]) -> None:
+        self._commit_monitor.unwatch(waiter)
 
     # ---------------------------------------------------------------- append RPC
     async def handle_append_entries(self, req: dict) -> dict:
