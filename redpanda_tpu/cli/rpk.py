@@ -1117,7 +1117,10 @@ def build_parser() -> argparse.ArgumentParser:
         help="with --cluster: assemble this one trace id",
     )
     dc = dsub.add_parser(
-        "coproc", help="engine breaker + fault-domain + stage stats"
+        "coproc",
+        help="engine breaker + fault-domain + stage stats (t_<stage> seconds: "
+             "explode*, pack, dispatch > h2d, fetch > wait_h2d + wait_program "
+             "+ wait_d2h, rebuild / frame_gather, seal, ...)",
     )
     dc.add_argument("--json", action="store_true", help="raw JSON, no rendering")
     dres = dsub.add_parser(

@@ -245,7 +245,7 @@ class _Launch:
                  "_mask_event", "_proj_data", "_proj_ok", "_plan",
                  "_exploded", "_mat", "_gather_mat", "_framed", "_lock",
                  "_shards", "trace_id", "_enq_t", "_cols", "_staged_np",
-                 "_mask_state")
+                 "_staged_dev", "_fetch_span", "_mask_state")
 
     def __init__(self, script_id: int, policy: ErrorPolicy):
         self.script_id = script_id
@@ -284,6 +284,48 @@ class _Launch:
         # one verdict, no matter how deep the harvest queue is.
         self._mask_state = "idle"
         self._staged_np = None
+        # the staged matrix ON the device, kept beside the result only so
+        # that the fetch can time the H2D apart (_fetch_legs drops it)
+        self._staged_dev = None
+        # ring id of this launch's coproc.stage.fetch span, minted ahead of
+        # it: the link-wait legs name it as their parent, and on the mask
+        # road they begin (on the harvester's worker) before the fetch does
+        self._fetch_span = tracer.new_span_id() if tracer.enabled else None
+
+    def _fetch_legs(self, dev, own: bool = True) -> np.ndarray:
+        """The link wait for one device result, in dependency order with a
+        clock read between: the staged input is on the device (H2D), the
+        result is defined (the program has run), the result is on the host
+        (D2H: its copy was issued at dispatch). Three stages, ``t_wait_h2d``
+        / ``t_wait_program`` / ``t_wait_d2h``, children of the launch's
+        ``coproc.stage.fetch`` in the ring. Each call returns at once when
+        its array is already there, so together they wait what
+        ``np.asarray(dev)`` alone would. Runs inside the fault envelope's
+        leg, on its worker: on the matrix road that is the thread
+        ``t_fetch`` waits on (``t_fetch`` = the three + the envelope's own
+        hop); on the mask road it is the harvester's fetch worker, which
+        starts at the enqueue, so the caller's ``t_fetch`` is at most
+        their sum. A host fallback runs none of this and records no leg.
+        ``own``: the result is the launch's own (a mesh shard's mask has a
+        fetch span of its own, which no id was minted for)."""
+        import jax
+
+        parent = self._fetch_span if own else None
+        staged_dev, self._staged_dev = self._staged_dev, None
+        if staged_dev is not None:
+            t0 = _stage_t0("t_wait_h2d")
+            jax.block_until_ready(staged_dev)
+            # dropped before the next wait: the device's copy of the input
+            # lives no longer than the program needs it, as before
+            staged_dev = None
+            self._stat("t_wait_h2d", t0, parent=parent)
+        t0 = _stage_t0("t_wait_program")
+        jax.block_until_ready(dev)  # host bits (a bare launch in tests) pass through
+        self._stat("t_wait_program", t0, parent=parent)
+        t0 = _stage_t0("t_wait_d2h")
+        out = np.asarray(dev)
+        self._stat("t_wait_d2h", t0, parent=parent)
+        return out
 
     def _mat_payload(self):
         if self._packed_dev is None:  # zero-record launch
@@ -305,14 +347,14 @@ class _Launch:
         else:
             def leg():
                 faults.inject(faults.HARVEST)
-                return np.asarray(dev)
+                return self._fetch_legs(dev)
 
             packed = eng._try_device_leg(faults.HARVEST, leg)
             if packed is None:
                 packed = self._payload_host_fallback()
             else:
                 eng.governor.breaker_for(faults.HARVEST).record_success()
-        self._stat("t_fetch", t0)
+        self._stat("t_fetch", t0, span_id=self._fetch_span)
         self._packed_dev = None
         self._park_staged()
         out, out_len, keep = unpack_result(packed, self.r_out)
@@ -432,7 +474,7 @@ class _Launch:
                             )
         else:
             bits = self._fetch_mask_bits(slot)
-        self._stat("t_fetch", t0)
+        self._stat("t_fetch", t0, span_id=self._fetch_span if slot is self else None)
         slot._mask_dev = None
         slot._mask_np = None
         slot._cols = None
@@ -457,7 +499,7 @@ class _Launch:
 
         def leg():
             faults.inject(faults.MASK_FETCH)
-            return np.asarray(dev)
+            return self._fetch_legs(dev, own=slot is self)
 
         bits = eng._try_device_leg(faults.MASK_FETCH, leg)
         if bits is None:
@@ -749,15 +791,19 @@ class _Launch:
                 self._mat = self._mat_host()
         return self._mat
 
-    def _stat(self, key: str, t0: float):
+    def _stat(self, key: str, t0: float, **ring):
         # harvest-side stage (fetch/assemble/frame/seal): runs on whatever
         # thread materializes, so the launch's explicit trace id carries
         # the pulse slice (no ambient there); _stat_stage owns the single
-        # clock read + stat/probe/timeline fan-out
+        # clock read + stat/probe/timeline fan-out. ``ring``: the span's
+        # own id or its parent's, where the ambient span cannot say
+        # (stages.close)
         if self.engine is not None:
-            self.engine._stat_stage(key, t0, trace_id=self.trace_id)
+            self.engine._stat_stage(key, t0, trace_id=self.trace_id, **ring)
         else:
-            stages.close("coproc.stage." + key[2:], None, t0, trace_id=self.trace_id)
+            stages.close(
+                "coproc.stage." + key[2:], None, t0, trace_id=self.trace_id, **ring
+            )
 
 
 def _pack_values(ex, stride: int):
@@ -846,13 +892,22 @@ class Ticket:
         # admission is off); released exactly once when result() returns
         # OR raises — leaking them would starve every later submit
         self._admitted: int = 0
+        # the calling thread's clock as the engine call that last ran on
+        # this ticket (``TpuEngine.submit``, then ``result``) started and as
+        # it ended: the pacemaker hands both calls to an executor thread
+        # and splits its hand-off legs on these
+        self.worker_clock = (0.0, 0.0)
 
     def result(self) -> ProcessBatchReply:
+        t_run = time.perf_counter()
         try:
-            with tracer.span("coproc.harvest", trace_id=self.trace_id):
+            # a stage: on a profile the executor thread's line shows it
+            # beside the loop's rp:coproc.harvest.wait
+            with stages.stage("coproc.harvest", trace_id=self.trace_id):
                 return self._result_impl()
         finally:
             self._engine._release_admission(self)
+            self.worker_clock = (t_run, time.perf_counter())  # pandalint: disable=RAC1101 -- a ticket is harvested by one call; its reader is the fiber that awaited that call's executor future (the future's completion is the hand-off)
 
     def _result_impl(self) -> ProcessBatchReply:
         reply = ProcessBatchReply()
@@ -1290,10 +1345,11 @@ class TpuEngine:
                     def leg(dev=dev):
                         t0 = time.perf_counter()
                         faults.inject(faults.HARVEST)
-                        # the fetch worker pays the D2H sync; this thread
-                        # only coordinates, so a wedged link can no longer
-                        # freeze every later launch's mask behind it
-                        out = np.asarray(dev)
+                        # the fetch worker pays the link wait (timed
+                        # leg by leg); this thread only coordinates, so a
+                        # wedged link can no longer freeze every later
+                        # launch's mask behind it
+                        out = launch._fetch_legs(dev)
                         # success-only adaptive-deadline sample (a raise
                         # or abandonment never reaches this line)
                         self.governor.observe_leg(
@@ -1677,7 +1733,7 @@ class TpuEngine:
             elif key in probes.coproc_uncompress:
                 probes.coproc_uncompress[key].inc(v)
 
-    def _stat_stage(self, key: str, t0: float, trace_id=_AMBIENT) -> float:
+    def _stat_stage(self, key: str, t0: float, trace_id=_AMBIENT, **ring) -> float:
         """Close one stage timer (``t0 = _stage_t0(key)``) through the stage
         helper: ONE clock read, the ``rp:coproc.stage.*`` annotation ended,
         the duration mirrored as a pandapulse lifecycle span, and the same
@@ -1690,7 +1746,9 @@ class TpuEngine:
         # "coproc.stage." namespace: stage slices must not collide with the
         # wrapper spans (t_dispatch vs the coproc.dispatch span around the
         # whole submit fan-out)
-        dt = stages.close("coproc.stage." + key[2:], None, t0, trace_id=trace_id)
+        dt = stages.close(
+            "coproc.stage." + key[2:], None, t0, trace_id=trace_id, **ring
+        )
         self._stat_add(key, dt)
         return dt
 
@@ -1844,7 +1902,10 @@ class TpuEngine:
         return self.submit(req).result()
 
     def submit(self, req: ProcessBatchRequest) -> Ticket:
-        return self.submit_group([req])[0]
+        t_run = time.perf_counter()
+        ticket = self.submit_group([req])[0]
+        ticket.worker_clock = (t_run, time.perf_counter())
+        return ticket
 
     def submit_group(self, reqs: list[ProcessBatchRequest]) -> list[Ticket]:
         """Fuse many requests into ONE launch per script.
@@ -1922,7 +1983,7 @@ class TpuEngine:
             # trace adopts it (the pacemaker submits one request per tick)
             launch.trace_id = entries[0][0].trace_id
             try:
-                with tracer.span("coproc.dispatch", trace_id=launch.trace_id):
+                with stages.stage("coproc.dispatch", trace_id=launch.trace_id):
                     self._dispatch(script_id, launch, entries)
                 ridx = 0
                 for ticket, slot_idx, item in entries:
@@ -2686,6 +2747,12 @@ class TpuEngine:
             self._stat_stage("t_h2d", t_h2d, trace_id=launch.trace_id)
             packed = fn(dev)
             packed.copy_to_host_async()
+            # the staged device array rides on the launch until the fetch
+            # has timed its H2D (_Launch._fetch_legs drops it). Set here and
+            # not handed back beside the result: the envelope's worker keeps
+            # what a leg returned until its next job ends, and a 33.8 MB
+            # device array must not wait on that
+            launch._staged_dev = dev
             return packed
 
         packed = None

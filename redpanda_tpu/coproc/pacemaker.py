@@ -32,6 +32,7 @@ from redpanda_tpu.coproc.engine import (
 from redpanda_tpu.models.fundamental import NTP, MaterializedNTP
 from redpanda_tpu.observability import stages
 from redpanda_tpu.observability.probes import (
+    COPROC_HANDOFF_PHASES,
     coproc_input_wait_hist,
     coproc_tick_hist,
 )
@@ -61,6 +62,23 @@ def _release_abandoned(engine):
         engine._release_admission(ticket)
 
     return cb
+
+
+def _note_handoff(legs: list, wait_span, t_out: float, ticket) -> None:
+    """One executor call's three legs from its four clock reads: ``t_out``
+    on the loop just before ``run_in_executor``, the worker's two around
+    the engine call (``Ticket.worker_clock``), the fiber's resume, read
+    here. Handed to the executor -> the worker runs it, the worker's own
+    time, worker done -> the fiber runs again: added to the tick's sums,
+    and the loop-side wait span carries the two hand-offs for ``rpk debug
+    trace``."""
+    t_back = time.perf_counter()
+    t_run, t_done = ticket.worker_clock
+    legs[0] += t_run - t_out
+    legs[1] += t_done - t_run
+    legs[2] += t_back - t_done
+    wait_span.set("out_us", int((t_run - t_out) * 1e6))
+    wait_span.set("back_us", int((t_back - t_done) * 1e6))
 
 
 class ScriptContext:
@@ -221,7 +239,11 @@ class ScriptContext:
         try:
             # engine: request built and submit dispatched to reply in hand,
             # executor queueing included; the two waits are its children in
-            # the ring
+            # the ring. Its two executor calls are clocked on both threads:
+            # one sample a tick in each of COPROC_HANDOFF_PHASES, the sum over both
+            # calls, once both have come back (a timed-out or shed tick
+            # records none)
+            legs = [0.0, 0.0, 0.0]
             with stages.stage("coproc.engine", coproc_tick_hist["engine"]):
                 # Submit AND harvest run in worker threads: the first
                 # dispatch of a spec jit-compiles for seconds, and anything
@@ -244,12 +266,14 @@ class ScriptContext:
                 # the engine's own envelope or it would abandon legitimately
                 # mid-envelope ticks.
                 deadline_s = pm.tick_deadline_for(pm.engine)
+                t_out = time.perf_counter()
                 sub_fut = loop.run_in_executor(ex, pm.engine.submit, req)
                 try:
-                    with stages.stage("coproc.submit.wait"):
+                    with stages.stage("coproc.submit.wait") as wait:
                         ticket = await asyncio.wait_for(
                             asyncio.shield(sub_fut), timeout=deadline_s
                         )
+                        _note_handoff(legs, wait, t_out, ticket)
                 except (asyncio.TimeoutError, asyncio.CancelledError):
                     # timeout OR fiber cancellation (script removal): the
                     # executor thread cannot be cancelled, and the shielded
@@ -258,12 +282,14 @@ class ScriptContext:
                     # shut one abandoned tick at a time
                     sub_fut.add_done_callback(_release_abandoned(pm.engine))
                     raise
+                t_out = time.perf_counter()
                 res_fut = loop.run_in_executor(ex, ticket.result)
                 try:
-                    with stages.stage("coproc.harvest.wait"):
+                    with stages.stage("coproc.harvest.wait") as wait:
                         reply = await asyncio.wait_for(
                             asyncio.shield(res_fut), timeout=deadline_s
                         )
+                        _note_handoff(legs, wait, t_out, ticket)
                 except (asyncio.TimeoutError, asyncio.CancelledError):
                     # shield the work item too: an un-started queued
                     # result() would otherwise be CANCELLED outright and
@@ -273,6 +299,8 @@ class ScriptContext:
                     # harmless either way.
                     pm.engine._release_admission(ticket)
                     raise
+            for phase, dt in zip(COPROC_HANDOFF_PHASES, legs):
+                coproc_tick_hist[phase].record(int(dt * 1e6))
         except ShedError as exc:
             # admission refused the staged bytes BEFORE any dispatch:
             # no offsets moved, nothing was written — back off the

@@ -425,8 +425,11 @@ async def handle_fetch(ctx) -> dict:
     # latency) but is exempt from the slow-request log: an empty long poll
     # hitting max_wait_ms is intentional waiting, and would otherwise bury
     # genuinely slow work in the slow ring. Histogram: protocol._dispatch.
+    # Off the profile for the same reason: an ``rp:kafka.fetch`` annotation
+    # over a parked poll would claim the device's idle gaps for a consumer's
+    # wait; the serve passes are ``rp:kafka.fetch.serve``.
     with stages.stage(
-        "kafka.fetch", root=True, no_slow=True,
+        "kafka.fetch", annotate=False, root=True, no_slow=True,
         node=ctx.broker.config.node_id,
     ) as sp:
         ctx.trace_id = sp.trace_id

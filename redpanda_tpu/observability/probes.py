@@ -29,6 +29,13 @@ storage_read_hist = registry.histogram(
     "storage_read_latency_us",
     "Storage log read latency, lock wait included (us)",
 )
+# One sample per DiskLog.flush that had a segment to sync: the buffered
+# write and the fsync inside the log's lock, whoever asked (an acks=all
+# produce, raft's append path). It runs on the event loop.
+storage_flush_hist = registry.histogram(
+    "storage_flush_latency_us",
+    "Storage log flush (write + fsync of the active segment) latency (us)",
+)
 storage_housekeeping_hist = registry.histogram(
     "storage_housekeeping_latency_us",
     "One compaction/retention housekeeping pass over a log (us)",
@@ -81,7 +88,16 @@ rpc_request_hist = registry.histogram(
 # read + gate + engine + write = tick, and tick + gap tiles the fiber's
 # time (gap: end of one productive tick to the start of the next one's
 # read: loop hand-off, idle sleeps, the unproductive ticks between).
-COPROC_TICK_PHASES = ("tick", "read", "gate", "engine", "write", "gap")
+# The engine phase is two executor calls (submit, harvest); summed over
+# both, handoff_out + engine_run + handoff_back = engine less the request's
+# construction: call handed to the executor -> the worker runs it (queue,
+# thread wake, interpreter lock), the worker's own time in the call, worker
+# done -> the fiber runs again (the wake-up and a late loop). A timed-out
+# or shed tick records none of the three.
+COPROC_HANDOFF_PHASES = ("handoff_out", "engine_run", "handoff_back")
+COPROC_TICK_PHASES = (
+    "tick", "read", "gate", "engine", "write", "gap", *COPROC_HANDOFF_PHASES
+)
 coproc_tick_hist = {
     phase: registry.histogram(
         "coproc_tick_latency_us",
@@ -522,6 +538,7 @@ __all__ = [
     "raft_replicate_hist",
     "rpc_request_hist",
     "storage_append_hist",
+    "storage_flush_hist",
     "storage_housekeeping_hist",
     "storage_read_hist",
 ]

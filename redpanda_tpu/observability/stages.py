@@ -6,13 +6,16 @@ clock read at each end, and the same two reads feed
 
 (a) always: the stage's histogram on ``/metrics`` (what the benchmark's
     per-layer metrics read as window deltas);
-(b) always, and free while no profile runs: a ``jax.profiler``
-    ``TraceAnnotation`` named ``rp:<stage>`` around the interval, so that
-    in a profiled run the stage lies on the xplane's ``/host:`` plane, on
-    the clock of the device operations, and an idle gap of the device can
-    be laid at it. JAX is never imported for this: the annotation binds
-    once ``jax`` is in ``sys.modules`` (a broker with coproc off has none),
-    and costs one ``is_enabled()`` call (~20 ns) while no profile runs;
+(b) unless the stage says ``annotate=False``, and free while no profile
+    runs: a ``jax.profiler`` ``TraceAnnotation`` named ``rp:<stage>``
+    around the interval, so that in a profiled run the stage lies on the
+    xplane's ``/host:`` plane, on the clock of the device operations, and
+    an idle gap of the device can be laid at it. JAX is never imported for
+    this: the annotation binds once ``jax`` is in ``sys.modules`` (a broker
+    with coproc off has none), and costs one ``is_enabled()`` call (~20 ns)
+    while no profile runs. A stage that is mostly a wait for somebody else
+    (a parked long poll) opts out: its annotation would cover whatever the
+    host did meanwhile and take the device's idle gaps for itself;
 (c) only when ``tracer.enabled``: the span ring (``observability/trace.py``),
     with ``parent`` = the span that was ambient when the stage began.
 
@@ -61,10 +64,11 @@ class _Annotated(float):
     __slots__ = ("annotation",)
 
 
-def begin(name: str) -> float:
-    """Start stage ``name``: its ``t0`` (``time.perf_counter()``)."""
-    ann = _annotation or _bind_annotation()
-    if ann is None or not ann.is_enabled():
+def begin(name: str, *, annotate: bool = True) -> float:
+    """Start stage ``name``: its ``t0`` (``time.perf_counter()``).
+    ``annotate=False`` keeps the stage off the profile (sink (b))."""
+    ann = annotate and (_annotation or _bind_annotation())
+    if not ann or not ann.is_enabled():
         return time.perf_counter()
     a = ann("rp:" + name)
     a.__enter__()
@@ -73,7 +77,7 @@ def begin(name: str) -> float:
     return t0
 
 
-def close(name: str, hist, t0: float, *, trace_id=AMBIENT, span=_NOOP) -> float:
+def close(name: str, hist, t0: float, *, trace_id=AMBIENT, span=_NOOP, **ring) -> float:
     """End the stage begun at ``t0``; returns its duration in seconds.
 
     ``hist``: the histogram that takes the duration in microseconds (None:
@@ -81,7 +85,9 @@ def close(name: str, hist, t0: float, *, trace_id=AMBIENT, span=_NOOP) -> float:
     does under its stats lock). Ring: ``span``, if the caller entered one
     at ``t0`` (``enter_at``), is committed on this clock read; otherwise a
     span ``name`` is recorded under ``trace_id`` (default: the ambient
-    trace; None: no span)."""
+    trace; None: no span). ``ring``: ``Tracer.record``'s ``parent`` and
+    ``span_id``, for a stage whose parent is not the ambient span (it runs
+    on another thread, or begins before its parent does)."""
     t1 = time.perf_counter()
     dt = t1 - t0
     if type(t0) is _Annotated:
@@ -94,25 +100,26 @@ def close(name: str, hist, t0: float, *, trace_id=AMBIENT, span=_NOOP) -> float:
     elif tracer.enabled and trace_id is not None:
         tid = _current_trace.get() if trace_id is AMBIENT else trace_id
         if tid is not None:
-            tracer.record(name, dt * 1e6, tid, start_perf=float(t0))
+            tracer.record(name, dt * 1e6, tid, start_perf=float(t0), **ring)
     return dt
 
 
 class stage:
     """``with stage(name, hist) as sp:`` — the block is the stage. ``sp`` is
     the tracer's span (``sp.trace_id``, ``sp.set(k, v)``), the shared no-op
-    when tracing is off. Keywords are ``Tracer.span``'s (``root``,
-    ``trace_id``, ``no_slow``, ``node``)."""
+    when tracing is off. ``annotate`` is ``begin``'s; the other keywords
+    are ``Tracer.span``'s (``root``, ``trace_id``, ``no_slow``, ``node``)."""
 
-    __slots__ = ("_name", "_hist", "_span", "_t0")
+    __slots__ = ("_name", "_hist", "_span", "_t0", "_annotate")
 
-    def __init__(self, name: str, hist=None, **span_kw) -> None:
+    def __init__(self, name: str, hist=None, *, annotate: bool = True, **span_kw) -> None:
         self._name = name
         self._hist = hist
+        self._annotate = annotate
         self._span = tracer.span(name, **span_kw) if tracer.enabled else _NOOP
 
     def __enter__(self):
-        self._t0 = t0 = begin(self._name)
+        self._t0 = t0 = begin(self._name, annotate=self._annotate)
         self._span.enter_at(t0)
         return self._span
 

@@ -364,12 +364,15 @@ class Tracer:
         *,
         start_perf: float | None = None,
         parent=_UNSET,
+        span_id: int | None = None,
         **extras,
     ) -> None:
         """Manually record a completed stage (used where a context manager
         cannot wrap the work: harvester thread, stage timers closed from a
         ``t0``). ``parent``: the span that caused this one; by default the
-        ambient span, when ``trace_id`` is the ambient trace."""
+        ambient span, when ``trace_id`` is the ambient trace. ``span_id``:
+        an id minted ahead (``new_span_id``) so that children recorded
+        before this span could name it."""
         if not self.enabled or trace_id is None:
             return
         t0 = start_perf if start_perf is not None else time.perf_counter() - dur_us / 1e6
@@ -377,7 +380,9 @@ class Tracer:
             parent = (
                 _current_span.get() if trace_id == _current_trace.get() else None
             )
-        self._commit(name, trace_id, t0, dur_us, extras or None, parent=parent)
+        self._commit(
+            name, trace_id, t0, dur_us, extras or None, span_id=span_id, parent=parent
+        )
 
     def _commit(
         self,

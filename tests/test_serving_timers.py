@@ -38,6 +38,7 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 import readers  # noqa: E402  (the benchmark's own reader, as run.py imports it)
 
 PHASES = ("tick", "read", "gate", "engine", "write")
+HANDOFFS = probes.COPROC_HANDOFF_PHASES
 
 
 def run(coro, limit_s=60.0):
@@ -133,12 +134,19 @@ def test_a_productive_tick_records_every_phase_once(tmp_path, tracing):
             parts = sum(took(ph) for ph in PHASES[1:])
             assert parts <= took("tick") + len(PHASES)  # each sample truncates to a us
             assert parts >= 0.95 * took("tick"), (parts, took("tick"))
+            # the engine phase's two executor calls, clocked on both threads:
+            # out + run + back is the phase but for the request's construction
+            for ph in HANDOFFS:
+                assert first[ph][0] == before[ph][0] + 1, ph
+            legs = sum(took(ph) for ph in HANDOFFS)
+            assert legs <= took("engine") + len(HANDOFFS)
+            assert legs >= 0.95 * took("engine"), (legs, took("engine"))
 
             await _append(broker, "src", 0, _docs(64, base=1000))
             await asyncio.sleep(0.03)
             assert await ctx.tick() is True
             second = _counts(probes.coproc_tick_hist)
-            for ph in PHASES:
+            for ph in PHASES + HANDOFFS:
                 assert second[ph][0] == first[ph][0] + 1, ph
             assert second["gap"][0] == first["gap"][0] + 1
             assert second["gap"][1] - first["gap"][1] >= 30_000  # the sleep above
@@ -160,9 +168,47 @@ def test_a_productive_tick_records_every_phase_once(tmp_path, tracing):
                 waits = [s for s in t["spans"] if s.get("parent") == engine["span_id"]]
                 assert {"coproc.submit.wait", "coproc.harvest.wait"} == {
                     s["name"] for s in waits}
+                for w in waits:  # the legs ride the loop-side span
+                    assert 0 <= w["out_us"] and 0 <= w["back_us"]
+                    assert w["out_us"] + w["back_us"] <= engine["dur_us"]
+                # the worker-side intervals keep their ring names
+                assert {"coproc.dispatch", "coproc.harvest"} <= {
+                    s["name"] for s in t["spans"]}
         finally:
             tracer.configure(enabled=False)
             tracer.reset()
+            await _stop(storage, server, api)
+
+    run(main())
+
+
+def test_a_shed_tick_records_no_handoff(tmp_path):
+    from redpanda_tpu.resource_mgmt.admission import ShedError
+
+    async def main():
+        storage, broker, server, api = await _start(tmp_path)
+        try:
+            await broker.create_topic(TopicConfig("src", 1))
+            ctx = await _deployed_but_parked(api, broker)
+            await _append(broker, "src", 0, _docs(64))
+            engine = api.pacemaker.engine
+            real = engine.submit
+
+            def shed(req):
+                raise ShedError("coproc", 1, "test")
+
+            engine.submit = shed
+            before = _counts(probes.coproc_tick_hist)
+            try:
+                assert await ctx.tick() is False
+            finally:
+                engine.submit = real
+            after = _counts(probes.coproc_tick_hist)
+            assert after["engine"][0] == before["engine"][0] + 1
+            for ph in HANDOFFS:
+                assert after[ph] == before[ph], ph
+            assert await ctx.tick() is True  # the same records, next tick
+        finally:
             await _stop(storage, server, api)
 
     run(main())
@@ -267,6 +313,73 @@ def test_produce_transform_long_poll_round_leaves_wait_samples(tmp_path):
     run(main())
 
 
+def test_an_acks_all_produce_is_one_flush_sample_and_a_materialized_append_none(tmp_path):
+    async def main():
+        storage, broker, server, api = await _start(tmp_path)
+        client = await KafkaClient([("127.0.0.1", server.port)]).connect()
+        try:
+            await broker.create_topic(TopicConfig("src", 1))
+            ctx = await _deployed_but_parked(api, broker)
+            flush = probes.storage_flush_hist.hist
+            before = flush.count
+            await client.produce("src", 0, _docs(8), acks=1)
+            assert flush.count == before  # the leader's append alone: no sync
+            await client.produce("src", 0, _docs(8, base=100))  # acks=all
+            assert flush.count == before + 1
+            appends = probes.storage_append_hist.hist.count
+            assert await ctx.tick() is True  # writes the materialized log, no_ack
+            assert probes.storage_append_hist.hist.count > appends
+            assert flush.count == before + 1
+        finally:
+            await client.close()
+            await _stop(storage, server, api)
+
+    run(main())
+
+
+def test_a_profile_of_the_served_path_holds_the_waits_and_no_parked_poll(tmp_path):
+    """Sink (b) of the three waits: the flush, the worker-side intervals of
+    the tick's executor calls beside the loop-side waits. And a long poll is
+    not what the host was doing: ``kafka.fetch`` keeps off the profile, its
+    serve passes stay."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    async def main():
+        storage, broker, server, api = await _start(tmp_path / "data")
+        client = await KafkaClient([("127.0.0.1", server.port)]).connect()
+        try:
+            await broker.create_topic(TopicConfig("src", 1))
+            ctx = await _deployed_but_parked(api, broker)
+            fetches = probes.kafka_fetch_hist.hist.count
+            jax.profiler.start_trace(str(tmp_path / "profile"))
+            try:
+                await client.produce("src", 0, _docs(8))  # acks=all: one flush
+                assert await ctx.tick() is True
+                got, _ = await client.fetch("src", 0, 0, max_wait_ms=100)
+                assert got
+            finally:
+                jax.profiler.stop_trace()
+            assert probes.kafka_fetch_hist.hist.count == fetches + 1  # sink (a) stays
+        finally:
+            await client.close()
+            await _stop(storage, server, api)
+
+    run(main(), 120.0)
+    (path,) = glob.glob(str(tmp_path / "profile" / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+    }
+    assert {"rp:storage.flush", "rp:kafka.produce", "rp:kafka.fetch.serve",
+            "rp:coproc.submit.wait", "rp:coproc.harvest.wait",
+            "rp:coproc.dispatch", "rp:coproc.harvest"} <= names
+    assert "rp:kafka.fetch" not in names
+
+
 # ------------------------------------------------------------------ the loop
 def _block_the_loop_for_200_ms():
     time.sleep(0.2)
@@ -363,7 +476,15 @@ NEW_METRICS = {
     "paced.loop_lag_ms": ("broker_loop_lag_us", "", "paced"),
     "paced.input_wait_ms": ("coproc_input_wait_latency_us", "", "paced"),
     "paced.output_wait_ms": ("kafka_fetch_wake_latency_us", "", "paced"),
+    "tick_handoff_out_ms": ('coproc_tick_latency_us', 'phase="handoff_out"', "catchup"),
+    "tick_engine_run_ms": ('coproc_tick_latency_us', 'phase="engine_run"', "catchup"),
+    "tick_handoff_back_ms": ('coproc_tick_latency_us', 'phase="handoff_back"', "catchup"),
+    "paced.tick_handoff_out_ms": ('coproc_tick_latency_us', 'phase="handoff_out"', "paced"),
+    "paced.tick_engine_run_ms": ('coproc_tick_latency_us', 'phase="engine_run"', "paced"),
+    "paced.tick_handoff_back_ms": ('coproc_tick_latency_us', 'phase="handoff_back"', "paced"),
+    "paced.storage_flush_ms": ("storage_flush_latency_us", "", "paced"),
 }
+LINK_WAIT_LEGS = ("h2d", "program", "d2h")
 
 
 def _scrape():
@@ -405,3 +526,26 @@ def _registry_hists(series):
     from redpanda_tpu.metrics import registry
 
     return [h for h in registry._hists.values() if h.name == series]
+
+
+@pytest.mark.parametrize("leg", LINK_WAIT_LEGS)
+def test_link_wait_file_reads_its_stage_over_the_windows_device_launches(leg):
+    """The ``stats_ratio`` twin of the case above: ``link_wait_<leg>_ms_per_launch``
+    is ``t_wait_<leg>`` of ``TpuEngine.stats()`` over the device launches."""
+    name = f"link_wait_{leg}_ms_per_launch"
+    with open(os.path.join(REPO, "benchmarks", "layer_metrics", name + ".json")) as f:
+        d = json.load(f)
+    assert d["traffic"] == ["catchup"] and d["read"]["kind"] == "stats_ratio"
+    assert d["read"]["num"] == [f"t_wait_{leg}"] and d["read"]["den"] == ["n_device_launches"]
+    others = {f"t_wait_{o}": 9.0 for o in LINK_WAIT_LEGS if o != leg}
+    before = {"metrics": {}, "stats": {f"t_wait_{leg}": 1.0, "n_device_launches": 10.0, "t_fetch": 2.0}}
+    after = {"metrics": {}, "stats": {f"t_wait_{leg}": 1.3, "n_device_launches": 20.0, "t_fetch": 9.0, **others}}
+    read = lambda k, b, a: readers.read_all(  # noqa: E731
+        os.path.join(REPO, "benchmarks", "layer_metrics"), kind=k, before=b, after=a,
+        client={}, trace=None, window_s=1.0,
+    )
+    assert read("catchup", before, after)[name] == {"value": pytest.approx(30.0), "unit": "ms"}
+    assert name not in read("paced", before, after)
+    # no device launch in the window (the columnar lane): left out, no error
+    assert name not in read("catchup", before, before)
+    assert name not in read("catchup", {"metrics": {}, "stats": {}}, {"metrics": {}, "stats": {}})

@@ -176,6 +176,12 @@ def test_annotation_lands_on_the_profiles_host_plane(tmp_path):
         stages.close("unit.closed", hist, t0)
         with stages.stage("unit.with", hist):
             pass
+        # a stage that is mostly somebody else's wait keeps off the profile
+        with stages.stage("unit.quiet.with", hist, annotate=False):
+            pass
+        t0 = stages.begin("unit.quiet.closed", annotate=False)
+        assert type(t0) is float
+        stages.close("unit.quiet.closed", hist, t0)
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
@@ -185,7 +191,8 @@ def test_annotation_lands_on_the_profiles_host_plane(tmp_path):
         for line in plane.lines for ev in line.events
     }
     assert {"rp:unit.closed", "rp:unit.with"} <= names
-    assert hist.hist.count == 2
+    assert not {n for n in names if n.startswith("rp:unit.quiet")}
+    assert hist.hist.count == 4  # sink (a) takes all four
 
 
 @pytest.mark.parametrize("result", ["mask", "matrix"])
@@ -201,22 +208,9 @@ def test_the_payload_lanes_h2d_stage_feeds_all_its_sinks(result, tmp_path):
     import jax
     from jax.profiler import ProfileData
 
-    from redpanda_tpu.coproc import ProcessBatchRequest, TpuEngine
-    from redpanda_tpu.coproc.engine import ProcessBatchItem
-    from redpanda_tpu.models import NTP, Record, RecordBatch
     from redpanda_tpu.observability import probes
-    from redpanda_tpu.ops.transforms import filter_contains, map_uppercase
 
-    spec = filter_contains(b"warn")
-    if result == "matrix":
-        spec = spec | map_uppercase()
-    engine = TpuEngine(row_stride=64, host_workers=0)
-    engine.enable_coprocessors([(1, spec.to_json(), ("t",))])
-    batch = RecordBatch.build(
-        [Record(offset_delta=i, timestamp_delta=i, value=b"warn %d" % i) for i in range(8)],
-        base_offset=0, first_timestamp=1000,
-    )
-    req = ProcessBatchRequest([ProcessBatchItem(1, NTP.kafka("t", 0), [batch])])
+    engine, req = _payload_engine_and_request(result)
     hist = probes.coproc_stage_hist("h2d").hist
     before = hist.count
     jax.profiler.start_trace(str(tmp_path))
@@ -237,3 +231,112 @@ def test_the_payload_lanes_h2d_stage_feeds_all_its_sinks(result, tmp_path):
     }
     frame = "frame_gather" if result == "mask" else "rebuild"
     assert {"rp:coproc.stage." + s for s in ("pack", "h2d", "dispatch", "fetch", frame)} <= names
+
+
+def _payload_engine_and_request(result, trace_id=None):
+    from redpanda_tpu.coproc import ProcessBatchRequest, TpuEngine
+    from redpanda_tpu.coproc.engine import ProcessBatchItem
+    from redpanda_tpu.models import NTP, Record, RecordBatch
+    from redpanda_tpu.ops.transforms import filter_contains, map_uppercase
+
+    spec = filter_contains(b"warn")
+    if result == "matrix":
+        spec = spec | map_uppercase()
+    engine = TpuEngine(row_stride=64, host_workers=0)
+    engine.enable_coprocessors([(1, spec.to_json(), ("t",))])
+    batch = RecordBatch.build(
+        [Record(offset_delta=i, timestamp_delta=i, value=b"warn %d" % i) for i in range(8)],
+        base_offset=0, first_timestamp=1000,
+    )
+    req = ProcessBatchRequest(
+        [ProcessBatchItem(1, NTP.kafka("t", 0), [batch])], trace_id=trace_id
+    )
+    return engine, req
+
+
+LINK_LEGS = ("wait_h2d", "wait_program", "wait_d2h")
+
+
+@pytest.mark.parametrize("result", ["mask", "matrix"])
+def test_the_link_waits_three_legs_feed_all_their_sinks(result, tmp_path):
+    """The fetch of a payload launch's result waits in dependency order
+    with a clock read between: the staged matrix on the device, the result
+    defined, the result on the host. Each leg is a stage: the ``stats()``
+    twin ``t_wait_*``, ``coproc_stage_latency_us{stage="wait_*"}`` and the
+    ``rp:coproc.stage.wait_*`` annotation. On the matrix road the fetching
+    thread is the waiting one, so ``t_fetch`` is their sum and the fault
+    envelope's thread hop; on the mask road they are the harvester's."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from redpanda_tpu.observability import probes
+
+    engine, req = _payload_engine_and_request(result)
+    hists = {leg: probes.coproc_stage_hist(leg).hist for leg in LINK_LEGS}
+    before = {leg: h.count for leg, h in hists.items()}
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        reply = engine.process_batch(req)
+    finally:
+        jax.profiler.stop_trace()
+        stats = engine.stats()
+        engine.shutdown()
+    assert reply.items[0].batches[0].header.record_count == 8
+    assert not stats.get("n_fallback_rows")
+    for leg in LINK_LEGS:
+        assert hists[leg].count == before[leg] + 1, leg
+        assert stats["t_" + leg] > 0, leg
+    legs = sum(stats["t_" + leg] for leg in LINK_LEGS)
+    if result == "matrix":
+        assert legs <= stats["t_fetch"] <= legs + 0.05
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events
+    }
+    assert {"rp:coproc.stage." + leg for leg in LINK_LEGS} <= names
+    assert {"rp:coproc.dispatch", "rp:coproc.harvest", "rp:coproc.stage.fetch"} <= names
+
+
+@pytest.mark.parametrize("result", ["mask", "matrix"])
+def test_the_link_waits_legs_are_the_fetchs_children_in_the_ring(result, tracing):
+    engine, req = _payload_engine_and_request(result, trace_id=tracer.new_trace_id())
+    try:
+        engine.process_batch(req)
+    finally:
+        engine.shutdown()
+    spans = _spans()
+    fetch = spans["coproc.stage.fetch"]
+    for leg in LINK_LEGS:
+        assert spans["coproc.stage." + leg]["parent"] == fetch["span_id"], leg
+    if result == "matrix":
+        # the legs lie inside the fetch, so its self time is what they leave
+        # (the envelope's thread hop): a profile counts the wait once
+        own = self_times([s for t in tracer.recent(0) for s in t["spans"]])
+        kids = sum(spans["coproc.stage." + leg]["dur_us"] for leg in LINK_LEGS)
+        assert own[fetch["span_id"]] <= fetch["dur_us"] - kids + len(LINK_LEGS)
+
+
+@pytest.mark.parametrize("domain", ["device_dispatch", "harvest"])
+def test_a_host_fallback_records_no_link_wait_leg(domain):
+    """A launch whose dispatch or whose fetch fell back to the host never
+    waited on the link: no ``t_wait_*`` key appears in ``stats()``."""
+    from redpanda_tpu.coproc import faults
+    from redpanda_tpu.finjector import honey_badger
+
+    engine, req = _payload_engine_and_request("matrix")
+    honey_badger.enable()
+    honey_badger.set_exception(faults.MODULE, domain)
+    try:
+        reply = engine.process_batch(req)
+    finally:
+        honey_badger.unset(faults.MODULE, domain)
+        honey_badger.disable()
+        stats = engine.stats()
+        engine.shutdown()
+    assert reply.items[0].batches[0].header.record_count == 8
+    assert stats["n_fallback_rows"] == 8
+    assert not [k for k in stats if k.startswith("t_wait_")]
