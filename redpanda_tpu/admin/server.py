@@ -659,6 +659,7 @@ class AdminServer:
                 {"enabled": False, "hint": "coproc_enable is false"}
             )
         from redpanda_tpu import native
+        from redpanda_tpu.observability.probes import coproc_tick_hist
 
         stats = api.engine.stats()
         return web.json_response({
@@ -671,6 +672,14 @@ class AdminServer:
             # here, never as a silent switch to the numpy twins
             "native": native.status(),
             "scripts": api.active_scripts(),
+            # the pacemaker's read-ahead, from the tick histogram's sums:
+            # read_hidden_us / read_us is the share of its read that ran
+            # inside the previous tick's engine phase (a backlog engages it)
+            "read_ahead": {
+                "ticks": coproc_tick_hist["tick"].hist.count,
+                "read_us": coproc_tick_hist["read"].hist.sum,
+                "read_hidden_us": coproc_tick_hist["read_hidden"].hist.sum,
+            },
             "breaker": stats.pop("breaker", None),
             # multi-chip meshrunner block surfaced explicitly (devices,
             # mesh-vs-single decision + probe, per-device rows, demotions)

@@ -445,6 +445,15 @@ async def cmd_debug(args) -> int:
             f"/{b.get('threshold', '?')} cooldown={b.get('cooldown_ms', '?')}ms"
         )
         print(f"scripts: {', '.join(body.get('scripts') or []) or '(none)'}")
+        ra = body.get("read_ahead") or {}
+        if ra.get("read_us"):
+            # coproc_tick_latency_us{phase="read_hidden"} over {phase="read"}
+            print(
+                f"read-ahead: {ra['read_hidden_us'] / ra['read_us']:.0%} of the "
+                f"pacemaker's read ran inside the previous tick's engine phase "
+                f"({ra['ticks']} ticks; ~0% on a live stream, which leaves no "
+                f"backlog to read ahead of)"
+            )
         mesh = body.get("mesh")
         if mesh:
             print(
@@ -1120,7 +1129,9 @@ def build_parser() -> argparse.ArgumentParser:
         "coproc",
         help="engine breaker + fault-domain + stage stats (t_<stage> seconds: "
              "explode*, pack, dispatch > h2d, fetch > wait_h2d + wait_program "
-             "+ wait_d2h, rebuild / frame_gather, seal, ...)",
+             "+ wait_d2h, rebuild / frame_gather, seal, ...); read-ahead: the "
+             "share of the pacemaker's read hidden under the previous tick's "
+             "engine phase (coproc_tick_latency_us phase=read_hidden over read)",
     )
     dc.add_argument("--json", action="store_true", help="raw JSON, no rendering")
     dres = dsub.add_parser(

@@ -834,6 +834,19 @@ class Governor:
         )
         return {"group_ticks": new_gt, "launch_depth": new_ld}
 
+    def pressure_level(self) -> str:
+        """The budget plane's level as the autotune reads it: "ok" with no
+        plane behind it. A source that raises reads as not ok (whoever asks
+        holds back); ``launch_knobs`` is where that failure is classified."""
+        auto = self._auto
+        fn = auto["pressure_fn"] if auto is not None else None
+        if fn is None:
+            return "ok"
+        try:
+            return fn()[0]
+        except Exception:  # pandalint: disable=EXC901 -- not a swallow: the same source's failure is classified by launch_knobs (autotune_pressure) on this very tick; here it only has to read as "not ok"
+            return "unknown"
+
     def autotune_snapshot(self) -> dict | None:
         auto = self._auto
         if auto is None:

@@ -10,6 +10,13 @@ one ``ScriptContext`` fiber per deployed script runs
   DIRECTLY to the materialized storage log, bypassing raft —
   script_context_backend.cc:40-68)
   → advance last_acked.
+While the engine's submit (explode, pack, the H2D's start) holds one tick's
+input the fiber reads the next tick's (``_ReadAhead``, depth one), for the
+partitions whose read the byte budget cut short of the LSO: a backlog; it
+sees that read out before it sends the harvest after, so that the read
+overlaps the worker's long crossings and the launch's transfer and never
+the harvest's framing and seal, which drop and retake the interpreter lock
+once a batch. Offsets still move only after the write.
 Offsets are snapshotted per flush interval into the kvstore's coproc
 keyspace and recovered on startup (offset_storage_utils.cc:36-104).
 """
@@ -35,6 +42,7 @@ from redpanda_tpu.observability.probes import (
     COPROC_HANDOFF_PHASES,
     coproc_input_wait_hist,
     coproc_tick_hist,
+    record_us,
 )
 from redpanda_tpu.observability.trace import tracer
 from redpanda_tpu.resource_mgmt.admission import ShedError
@@ -81,6 +89,26 @@ def _note_handoff(legs: list, wait_span, t_out: float, ticket) -> None:
     wait_span.set("back_us", int((t_back - t_done) * 1e6))
 
 
+class _ReadAhead:
+    """Tick N+1's input, read inside tick N's engine phase (depth one: a
+    script holds at most this and the launch in flight). Nothing here moves
+    an offset; a partition's read is used by the next tick only if the
+    script's offset has by then reached exactly where it began."""
+
+    __slots__ = ("task", "reads", "yields", "error")
+
+    def __init__(self) -> None:
+        self.task: asyncio.Task | None = None
+        # one entry a partition that had records, in read order:
+        # (ntp, start offset, batches, the LSO read against, seconds)
+        self.reads: list[tuple] = []
+        # while the submit is out it hands the loop back between partitions
+        # (the reply, the consumer's fetches)
+        self.yields = True
+        # a read that raised: the next tick's failed read, raised there
+        self.error: Exception | None = None
+
+
 class ScriptContext:
     def __init__(
         self,
@@ -100,18 +128,26 @@ class ScriptContext:
         # perf_counter() at the end of the newest productive tick (the
         # ``gap`` phase runs from there to the next productive tick's read)
         self._t_tick_end: float | None = None
+        # the next tick's input, while (and after) the engine holds this one's
+        self._ahead: _ReadAhead | None = None
 
     def start(self) -> None:
         self._task = asyncio.create_task(self._loop())
 
     async def stop(self) -> None:
-        if self._task is not None:
-            self._task.cancel()
+        # the read-ahead goes with the fiber: cancelled inside a read, its
+        # read_budget reservation is handed back by _read_ntp's finally
+        # (taken before the fiber's own clean-up drops the reference)
+        ahead = self._ahead
+        tasks = [t for t in (self._task, ahead and ahead.task) if t is not None]
+        for t in tasks:
+            t.cancel()
+        for t in tasks:
             try:
-                await self._task
+                await t
             except asyncio.CancelledError:
                 pass
-            self._task = None
+        self._task = self._ahead = None
 
     async def _loop(self) -> None:
         """do_execute (script_context.cc:66): run ticks until cancelled;
@@ -161,12 +197,22 @@ class ScriptContext:
         Every phase is a stage (observability/stages.py): always a sample
         in ``coproc_tick_latency_us{phase=}`` and, in a profile, an
         ``rp:coproc.*`` annotation; with tracing on, a span under the tick.
-        read + gate + engine + write = tick; tick + gap tiles the fiber.
+        ``tick`` is what the fiber spent on this launch: what it read here
+        for itself, then gate + engine + write. ``read`` is the read's own
+        time wherever it ran and ``read_hidden`` the part of it that ran
+        inside the previous tick's engine phase (0 for a tick that read for
+        itself), so read - read_hidden + gate + engine + write = tick;
+        tick + gap tiles the fiber.
         """
         pm = self.pacemaker
         knobs = pm.launch_knobs()
         items = []
         read_high: dict[NTP, int] = {}
+        # partitions whose read stopped short of the LSO it read against
+        # (the byte budget ended it, not the log): ntp -> where the next
+        # read starts. A backlog, and the one thing that is read ahead
+        behind: dict[NTP, int] = {}
+        hidden_s = 0.0
         t_tick = stages.begin("coproc.tick")
         t_read = stages.begin("coproc.read")
         # group_ticks_per_launch fuses N ticks' worth of input into one
@@ -174,11 +220,23 @@ class ScriptContext:
         # governor shrinks it back to 1 under memory pressure)
         read_budget = pm.max_batch_size * knobs["group_ticks"]
         try:
+            ahead = self._take_ahead()
             for ntp in self._input_ntps():
-                batches = await self._read_ntp(ntp, read_budget)
+                got = ahead.get(ntp)
+                if got is None:
+                    batches, lso = await self._read_ntp(ntp, read_budget)
+                else:
+                    batches, lso, read_s = got
+                    hidden_s += read_s
                 if batches:
+                    # recorded where the records are taken for a launch,
+                    # not where they were read: append -> the tick that
+                    # takes it
+                    self._note_input_wait(ntp, batches)
                     items.append(ProcessBatchItem(self.script_id, ntp, batches))
-                    read_high[ntp] = batches[-1].last_offset
+                    read_high[ntp] = high = batches[-1].last_offset
+                    if high < lso - 1:
+                        behind[ntp] = high + 1
         finally:
             if not items:
                 # an idle tick is no sample and no trace (it would drown
@@ -196,12 +254,25 @@ class ScriptContext:
         tick_span.enter_at(t_tick)
         if self._t_tick_end is not None:
             coproc_tick_hist["gap"].record(int((t_tick - self._t_tick_end) * 1e6))
+        launched = False
         try:
-            stages.close("coproc.read", coproc_tick_hist["read"], t_read)
+            # the stage is what this tick read for itself; what it took
+            # out of the read-ahead is the rest
+            own_s = stages.close("coproc.read", None, t_read)
+            record_us(coproc_tick_hist["read"], int((own_s + hidden_s) * 1e6))
+            coproc_tick_hist["read_hidden"].record(int(hidden_s * 1e6))
+            if behind and not pm.read_ahead_allowed():
+                behind = {}
             moved, shed_retry_s = await self._launch_and_write(
-                items, read_high, knobs, tick_span.trace_id
+                items, read_high, behind, read_budget, knobs, tick_span.trace_id
             )
+            launched = shed_retry_s is None
         finally:
+            if not launched:
+                # failed, timed out or shed: no offset moved, so what was
+                # read ahead of them is no tick's input; the next tick
+                # re-reads from self.offsets
+                self._drop_ahead()
             # the gap starts on the clock read that ended the tick
             self._t_tick_end = t_tick + stages.close(
                 "coproc.tick", coproc_tick_hist["tick"], t_tick, span=tick_span
@@ -221,11 +292,107 @@ class ScriptContext:
             pm.engine.invalidate_columns(self.script_id)
         return moved
 
+    # ------------------------------------------------------------ read-ahead
+    def _begin_read_ahead(self, behind: dict, budget: int) -> None:
+        """Start reading the next tick's input (called with this tick's
+        submit handed to the executor): one task, the partitions in
+        ``behind``, at this tick's read budget. Holds no gate slot."""
+        if behind:
+            self._ahead = ahead = _ReadAhead()
+            ahead.task = asyncio.create_task(self._read_ahead(ahead, behind, budget))
+
+    async def _read_ahead(self, ahead: _ReadAhead, behind: dict, budget: int) -> None:
+        t_start = time.perf_counter()
+        try:
+            for ntp, start in behind.items():
+                # one annotation a partition, on the loop thread's line
+                # under the fiber's rp:coproc.engine; the ``read`` sample
+                # is the tick's that takes these records
+                t0 = stages.begin("coproc.read")
+                try:
+                    batches, lso = await self._read_ntp(ntp, budget, start)
+                finally:
+                    dt = stages.close("coproc.read", None, t0, trace_id=None)
+                if batches:
+                    ahead.reads.append((ntp, start, batches, lso, dt))
+                if ahead.yields:
+                    await asyncio.sleep(0)
+        except Exception as exc:  # pandalint: disable=EXC901 -- not a swallow: held for the next tick, which raises it as its own failed read (_take_ahead) so that _loop classifies it once
+            ahead.error = exc
+        if tracer.enabled and ahead.reads:
+            # one ring span a read-ahead, under the engine span of the tick
+            # it ran beneath (this task's context was copied inside it)
+            tracer.record(
+                "coproc.read", sum(r[4] for r in ahead.reads) * 1e6,
+                tracer.current_trace(), start_perf=t_start,
+                ahead_partitions=len(ahead.reads),
+            )
+
+    async def _see_read_ahead_out(self, ticket) -> None:
+        """Between a tick's two executor calls: wait for what is being read
+        ahead before the harvest goes out. The harvest's host stages (gather
+        or rebuild, and above all the seal: a compress and a CRC a batch)
+        drop and retake the interpreter lock once a batch, and beside a
+        loop thread that is reading, each retake waits out a partition's
+        read: measured on the chip, the seal took 2.4x and 5.8x its time
+        and ate the overlap (PERF.md section 6, PR 36). The submit's
+        crossings (explode, pack) and the launch's transfer, which is in
+        flight from the dispatch on, are what the read overlaps; the rest of
+        it runs here in one stretch, with the worker idle."""
+        ahead = self._ahead
+        if ahead is None or ahead.task.done():
+            return
+        ahead.yields = False
+        try:
+            with stages.stage("coproc.read_ahead.wait"):
+                # wait(), not await: cancelled here (script removal), the
+                # fiber leaves the task to stop()
+                await asyncio.wait([ahead.task])
+        except asyncio.CancelledError:
+            # this ticket will never be harvested
+            self.pacemaker.engine._release_admission(ticket)
+            raise
+
+    def _drop_ahead(self) -> None:
+        """The tick it ran under failed, timed out or was shed, or the
+        script is going: cancelled, it is gone within a turn of the loop."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is not None:
+            ahead.task.cancel()
+
+    def _take_ahead(self) -> dict:
+        """What was read ahead for this tick (it ended inside the engine
+        phase it ran under), partition by partition: ntp -> (batches, lso,
+        seconds the read took). A partition's read is used only if the
+        script's offset now stands exactly where the read began and the
+        partition is still led here; so after a tick whose write of that
+        partition did not land it is left out and the caller re-reads from
+        ``self.offsets``, as it always has (a tick that failed, timed out
+        or was shed dropped all of it)."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            return {}
+        if ahead.error is not None:
+            raise ahead.error
+        pm = self.pacemaker
+        out = {}
+        for ntp, start, batches, lso, dt in ahead.reads:
+            p = pm.broker.partition_manager.get(ntp)
+            if p is None or not p.is_leader():
+                continue
+            if self.offsets.get(ntp, p.start_offset - 1) + 1 != start:
+                continue
+            out[ntp] = (batches, lso, dt)
+        return out
+
     async def _launch_and_write(
-        self, items: list, read_high: dict, knobs: dict, trace_id
+        self, items: list, read_high: dict, behind: dict, read_budget: int,
+        knobs: dict, trace_id,
     ) -> tuple[bool, float | None]:
         """The gate, engine and write phases of a productive tick:
-        (any offset moved, seconds to back off after an admission shed)."""
+        (any offset moved, seconds to back off after an admission shed).
+        Inside the engine phase the partitions in ``behind`` are read ahead
+        for the next tick, ``read_budget`` bytes each."""
         pm = self.pacemaker
         # launch_depth bounds concurrent submit+harvest regions across
         # every script fiber: the staged bytes of at most depth
@@ -268,6 +435,7 @@ class ScriptContext:
                 deadline_s = pm.tick_deadline_for(pm.engine)
                 t_out = time.perf_counter()
                 sub_fut = loop.run_in_executor(ex, pm.engine.submit, req)
+                self._begin_read_ahead(behind, read_budget)
                 try:
                     with stages.stage("coproc.submit.wait") as wait:
                         ticket = await asyncio.wait_for(
@@ -282,6 +450,7 @@ class ScriptContext:
                     # shut one abandoned tick at a time
                     sub_fut.add_done_callback(_release_abandoned(pm.engine))
                     raise
+                await self._see_read_ahead_out(ticket)
                 t_out = time.perf_counter()
                 res_fut = loop.run_in_executor(ex, ticket.result)
                 try:
@@ -319,6 +488,7 @@ class ScriptContext:
             logger.warning("script %s deregistered by engine policy", self.name)
             pm.detach_script(self.name)
             self._task = None
+            self._drop_ahead()
             raise _StopScript()
         moved = False
         with stages.stage("coproc.write", coproc_tick_hist["write"]):
@@ -337,18 +507,24 @@ class ScriptContext:
             out.extend(pa.ntp for pa in md.assignments.values())
         return out
 
-    async def _read_ntp(self, ntp: NTP, max_bytes: int | None = None) -> list:
-        """read_ntp (script_context_frontend.cc:80-98): from last_acked+1 up
-        to the LSO, bounded by the read budget (max batch size scaled by
-        the group_ticks launch knob) + the read semaphore."""
+    async def _read_ntp(
+        self, ntp: NTP, max_bytes: int | None = None, start: int | None = None
+    ) -> tuple[list, int]:
+        """read_ntp (script_context_frontend.cc:80-98): from last_acked+1
+        (or ``start``, for a read ahead of the offsets) up to the LSO,
+        bounded by the read budget (max batch size scaled by the
+        group_ticks launch knob) + the read semaphore. Returns the batches
+        and the LSO (exclusive) they were read against: a last offset short
+        of it says the budget ended the read, not the log."""
         pm = self.pacemaker
         p = pm.broker.partition_manager.get(ntp)
         if p is None or not p.is_leader():
-            return []
-        start = self.offsets.get(ntp, p.start_offset - 1) + 1
+            return [], 0
+        if start is None:
+            start = self.offsets.get(ntp, p.start_offset - 1) + 1
         lso = p.last_stable_offset  # exclusive
         if start >= lso:
-            return []
+            return [], lso
         budget = max_bytes if max_bytes is not None else pm.max_batch_size
         reserved = await pm.read_budget.acquire(budget)
         try:
@@ -358,14 +534,17 @@ class ScriptContext:
             batches = await p.make_reader(start, reserved, max_offset=lso - 1)
         finally:
             pm.read_budget.release(reserved)
-        if batches:
-            # how long the oldest batch of this read waited for a tick
-            t_append = p.append_stamp(batches[0].last_offset)
-            if t_append is not None:
-                coproc_input_wait_hist.record(  # pandalint: disable=HST1001 -- every script fiber runs on the broker's event loop, and nothing off it records this histogram
-                    int((time.perf_counter() - t_append) * 1e6)
-                )
-        return batches
+        return batches, lso
+
+    def _note_input_wait(self, ntp: NTP, batches: list) -> None:
+        """How long the oldest batch a tick takes from a partition waited
+        for that tick."""
+        p = self.pacemaker.broker.partition_manager.get(ntp)
+        t_append = p.append_stamp(batches[0].last_offset) if p is not None else None
+        if t_append is not None:
+            coproc_input_wait_hist.record(  # pandalint: disable=HST1001 -- every script fiber runs on the broker's event loop, and nothing off it records this histogram
+                int((time.perf_counter() - t_append) * 1e6)
+            )
 
     async def _write_materialized(self, source: NTP, batches: list) -> bool:
         """do_write_materialized_partition (script_context_backend.cc:40-68):
@@ -460,6 +639,12 @@ class Pacemaker:
             "group_ticks": self.group_ticks_per_launch,
             "launch_depth": self.launch_depth,
         }
+
+    def read_ahead_allowed(self) -> bool:
+        """A script may hold a second read's input only while the budget
+        plane's pressure reads ``ok`` (engines without a governor: always)."""
+        gov = getattr(self.engine, "governor", None)
+        return gov is None or gov.pressure_level() == "ok"
 
     def tick_deadline_for(self, engine) -> float:
         """Effective tick backstop: the configured static deadline, never

@@ -85,9 +85,17 @@ rpc_request_hist = registry.histogram(
 
 # ------------------------------------------------------------ coproc pacemaker
 # One sample per PRODUCTIVE tick of a script's fiber (coproc/pacemaker.py):
-# read + gate + engine + write = tick, and tick + gap tiles the fiber's
-# time (gap: end of one productive tick to the start of the next one's
-# read: loop hand-off, idle sleeps, the unproductive ticks between).
+# read - read_hidden + gate + engine + write = tick, and tick + gap tiles
+# the fiber's time (gap: end of one productive tick to the start of the
+# next one's read: loop hand-off, idle sleeps, the unproductive ticks
+# between). read is the read's own time wherever it ran; read_hidden is the
+# part of it that ran inside the PREVIOUS tick's engine phase (the
+# read-ahead a backlog engages: under the submit call and, between the two
+# calls, under the launch's transfer; 0 for a tick that read for itself),
+# so sum(read_hidden) / sum(read) is the share of the read that left the
+# fiber's own read stretch. What it cost the engine phase it ran inside is
+# engine - (handoff_out + engine_run + handoff_back): the fiber seeing the
+# read-ahead out before it sends the harvest.
 # The engine phase is two executor calls (submit, harvest); summed over
 # both, handoff_out + engine_run + handoff_back = engine less the request's
 # construction: call handed to the executor -> the worker runs it (queue,
@@ -96,7 +104,8 @@ rpc_request_hist = registry.histogram(
 # or shed tick records none of the three.
 COPROC_HANDOFF_PHASES = ("handoff_out", "engine_run", "handoff_back")
 COPROC_TICK_PHASES = (
-    "tick", "read", "gate", "engine", "write", "gap", *COPROC_HANDOFF_PHASES
+    "tick", "read", "read_hidden", "gate", "engine", "write", "gap",
+    *COPROC_HANDOFF_PHASES,
 )
 coproc_tick_hist = {
     phase: registry.histogram(

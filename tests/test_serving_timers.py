@@ -483,6 +483,8 @@ NEW_METRICS = {
     "paced.tick_engine_run_ms": ('coproc_tick_latency_us', 'phase="engine_run"', "paced"),
     "paced.tick_handoff_back_ms": ('coproc_tick_latency_us', 'phase="handoff_back"', "paced"),
     "paced.storage_flush_ms": ("storage_flush_latency_us", "", "paced"),
+    "tick_read_hidden_ms": ('coproc_tick_latency_us', 'phase="read_hidden"', "catchup"),
+    "paced.tick_read_hidden_ms": ('coproc_tick_latency_us', 'phase="read_hidden"', "paced"),
 }
 LINK_WAIT_LEGS = ("h2d", "program", "d2h")
 
