@@ -39,6 +39,8 @@ from redpanda_tpu.ops.transforms import (
     Concat,
     Float,
     Int,
+    Long,
+    Scaled,
     Str,
     Substr,
     TransformSpec,
@@ -561,6 +563,14 @@ def plan_spec(spec: TransformSpec, py_fn=None):
         if isinstance(spec.mapper, _MapUppercase):
             raise ValueError("uppercase is a raw-byte map; use payload specs")
         proj = spec.mapper.fields if isinstance(spec.mapper, _MapProject) else ()
+        if any(isinstance(f, (Long, Scaled)) for f in proj):
+            # the exact 64-bit kinds live in the payload lane's device
+            # program (ops/transforms.py); the columnar projector has no
+            # column for them
+            raise ValueError(
+                "Long/Scaled projections run on the payload lane: write the "
+                "filter in the v1 form (filter_contains / filter_field_eq)"
+            )
         cols = _collect_dev_cols(spec.where)
         r_out = project_out_width(proj) if proj else 0
         return ColumnarPlan(

@@ -1722,6 +1722,10 @@ class TpuEngine:
                 probes.coproc_staged_rows.inc(v)
             elif key == "n_oversize_rows":
                 probes.coproc_oversize_rows.inc(v)
+            elif key == "bytes_staged":
+                probes.coproc_staged_bytes.inc(v)
+            elif key == "bytes_staged_values":
+                probes.coproc_staged_value_bytes.inc(v)
             elif key == "n_frame_gather":
                 probes.coproc_harvest_gather.inc(v)
             elif key == "n_frame_padded":
@@ -2689,6 +2693,7 @@ class TpuEngine:
             if not retained:
                 _release_exploded(exploded)
             return
+        value_bytes = float((exploded.sizes * launch.fits).sum(dtype=np.int64))
         t0 = _stage_t0("t_pack")
         n_pad = _bucket_rows(n)
         if isinstance(exploded, batch_codec.PtrExploded):
@@ -2698,6 +2703,11 @@ class TpuEngine:
         if not retained:
             _release_exploded(exploded)
         self._stat_stage("t_pack", t0)
+        # what the staging matrix holds against what it is: record bytes
+        # over rows x stride (a 130 B event in a 1,032 B row is mostly
+        # zeros that still cross the link)
+        self._stat_add("bytes_staged", float(staged.nbytes))
+        self._stat_add("bytes_staged_values", value_bytes)
         self._launch_payload(launch, staged, n_pad, fn, r_out)
 
     def _launch_payload(

@@ -145,6 +145,17 @@ coproc_oversize_rows = registry.counter(
     "coproc_oversize_rows_total",
     "Values wider than the staging row, which the payload lane drops",
 )
+# The staging matrices themselves, in bytes: rows x stride of every matrix
+# the lane packed, and the record bytes put into them (their ratio is what
+# of a launch's H2D is data; ~0.13 for 130 B events in 1,032 B rows).
+coproc_staged_bytes = registry.counter(
+    "coproc_staged_bytes_total",
+    "Bytes of the staging matrices the payload lane packed (rows x stride)",
+)
+coproc_staged_value_bytes = registry.counter(
+    "coproc_staged_value_bytes_total",
+    "Record value bytes the payload lane packed into staging matrices",
+)
 # What a launch's harvest lets through, on either framing road: the rows it
 # keeps and the value bytes it frames into output batches (the map's
 # shrink: 70 B a kept row of config 4's projection against ~1 KB in).
@@ -534,7 +545,9 @@ __all__ = [
     "coproc_retries_total",
     "coproc_shard_rows_hist",
     "coproc_stage_hist",
+    "coproc_staged_bytes",
     "coproc_staged_rows",
+    "coproc_staged_value_bytes",
     "coproc_tick_hist",
     "coproc_uncompress",
     "host_pool_task_finished",

@@ -48,6 +48,26 @@ class Str:
 
 
 @dataclass(frozen=True)
+class Long:
+    """Project an integer of 1-18 digits (an optional ``-``) as
+    little-endian int64 (8 bytes): an epoch-millisecond timestamp, an id."""
+
+    key: str
+
+
+@dataclass(frozen=True)
+class Scaled:
+    """Project ``floor(v * num / den)`` of a v1 ``Int`` (1-9 digits) as
+    little-endian int64 (8 bytes), exactly: a rational constant times an
+    integer (NEXmark Q1's ``0.908 * price`` is ``Scaled("price", 908,
+    1000)``), rounded toward minus infinity, never through a float."""
+
+    key: str
+    num: int
+    den: int
+
+
+@dataclass(frozen=True)
 class Float:
     """Project a JSON number as little-endian float32 (4 bytes)."""
 
@@ -140,6 +160,12 @@ class TransformSpec:
             for f in self.mapper.fields:
                 if isinstance(f, Int):
                     fields.append({"kind": "int", "key": f.key})
+                elif isinstance(f, Long):
+                    fields.append({"kind": "long", "key": f.key})
+                elif isinstance(f, Scaled):
+                    fields.append(
+                        {"kind": "scaled", "key": f.key, "num": f.num, "den": f.den}
+                    )
                 elif isinstance(f, Float):
                     fields.append({"kind": "float", "key": f.key})
                 elif isinstance(f, Substr):
@@ -183,14 +209,20 @@ class TransformSpec:
                     fk = f["kind"]
                     if fk == "int":
                         fields.append(Int(f["key"]))
+                    elif fk == "long":
+                        fields.append(Long(f["key"]))
+                    elif fk == "scaled":
+                        fields.append(Scaled(f["key"], f["num"], f["den"]))
                     elif fk == "float":
                         fields.append(Float(f["key"]))
                     elif fk == "substr":
                         fields.append(Substr(f["key"], f["start"], f["length"]))
                     elif fk == "concat":
                         fields.append(Concat(f["a"], f["b"], f["max_len"]))
-                    else:
+                    elif fk == "str":
                         fields.append(Str(f["key"], f["max_len"]))
+                    else:
+                        raise ValueError(f"unknown map_project field kind {fk!r}")
                 spec = spec | TransformSpec(mapper=_MapProject(tuple(fields)), name="")
             elif kind == "map_uppercase":
                 spec = spec | TransformSpec(mapper=_MapUppercase(), name="")
@@ -257,6 +289,8 @@ def project_out_width(fields: Sequence) -> int:
     for f in fields:
         if isinstance(f, (Int, Float)):
             w += 4
+        elif isinstance(f, (Long, Scaled)):
+            w += 8
         elif isinstance(f, Substr):
             w += 2 + f.length
         elif isinstance(f, Concat):
@@ -366,6 +400,104 @@ def _parse_int_at(jnp, data, pos):
     return val, ok
 
 
+# ---- exact 64-bit integers on 32-bit lanes
+# JAX's x64 is off in this tree, and a float is a wrong answer here, not a
+# tolerance (0.908 x 99,999,999 cents is off by whole cents in float32). A
+# 64-bit value is a pair of uint32 arrays (lo, hi); every step below is a
+# uint32 operation that wraps, or a select, so the device program and the
+# numpy twin agree bit for bit.
+_LONG_WINDOW = 20  # sign + 18 digits + terminator
+_SCALED_VALUE_BITS = 30  # a v1 Int's magnitude: 999,999,999 < 2**30
+
+
+def _u64_times10_plus(lo, hi, d):
+    """(hi:lo) * 10 + d for a digit ``d`` (uint32 < 10), by 16-bit halves
+    of ``lo`` so that no partial product passes 2**32."""
+    p0 = (lo & 0xFFFF) * 10 + d
+    p1 = (lo >> 16) * 10 + (p0 >> 16)
+    return (p1 << 16) | (p0 & 0xFFFF), hi * 10 + (p1 >> 16)
+
+
+def _u64_negate_where(xp, neg, lo, hi):
+    """Two's complement of (hi:lo) in the rows where ``neg``."""
+    nlo = ~lo + 1
+    nhi = ~hi + (nlo == 0).astype(xp.uint32)
+    return xp.where(neg, nlo, lo), xp.where(neg, nhi, hi)
+
+
+def _u64_le_bytes(xp, lo, hi):
+    """(hi:lo) as uint8 [N, 8], little-endian."""
+    return xp.stack(
+        [((w >> (8 * k)) & 0xFF).astype(xp.uint8) for w in (lo, hi) for k in range(4)],
+        axis=1,
+    )
+
+
+def _parse_long_at(xp, data, pos):
+    """Parse a decimal integer of 1-18 digits starting at pos[i]; returns
+    (lo, hi uint32: its int64 in two's complement; ok).
+
+    ``_parse_int_at``'s rules at a wider window: an optional ``-``, then
+    digits ended by a non-digit inside ``_LONG_WINDOW`` bytes; 19 digits or
+    more, or none, is ok=False, never a truncated number."""
+    win = _gather_window(xp, data, pos, _LONG_WINDOW)
+    n = win.shape[0]
+    neg = win[:, 0] == ord("-")
+    lo = xp.zeros(n, dtype=xp.uint32)
+    hi = xp.zeros(n, dtype=xp.uint32)
+    ndigits = xp.zeros(n, dtype=xp.int32)
+    seen = xp.zeros(n, dtype=bool)
+    stopped = xp.zeros(n, dtype=bool)
+    for i in range(_LONG_WINDOW):
+        c = win[:, i]
+        isdig = (c >= ord("0")) & (c <= ord("9"))
+        skip_sign = (i == 0) & neg
+        stopped = stopped | (~isdig & ~skip_sign)
+        active = ~stopped & isdig
+        nlo, nhi = _u64_times10_plus(lo, hi, (c & 0x0F).astype(xp.uint32))
+        lo = xp.where(active, nlo, lo)
+        hi = xp.where(active, nhi, hi)
+        ndigits = ndigits + active.astype(xp.int32)
+        seen = seen | active
+    ok = seen & stopped & (ndigits <= 18) & (pos >= 0)
+    lo, hi = _u64_negate_where(xp, neg, lo, hi)
+    return lo, hi, ok
+
+
+def _scale_exact(xp, val, num: int, den: int):
+    """floor(val * num / den) as (lo, hi uint32), for an int32 ``val`` of
+    magnitude under 2**30 (a v1 Int), ``|num|`` < 2**31 and 0 < ``den`` <
+    2**31: the 32 x 32 product by 16-bit halves, then a restoring long
+    division a bit at a time (the remainder stays under ``den``, so its
+    doubling fits 32 bits). A negative quotient rounds toward minus
+    infinity: -(q + (rem != 0))."""
+    a = xp.abs(val).astype(xp.uint32)
+    b = abs(num)
+    a0, a1 = a & 0xFFFF, a >> 16
+    b0, b1 = b & 0xFFFF, b >> 16
+    p00 = a0 * b0
+    mid = a0 * b1 + a1 * b0
+    plo = p00 + (mid << 16)
+    phi = a1 * b1 + (mid >> 16) + (plo < p00).astype(xp.uint32)
+    rem = xp.zeros(a.shape[0], dtype=xp.uint32)
+    qlo = xp.zeros(a.shape[0], dtype=xp.uint32)
+    qhi = xp.zeros(a.shape[0], dtype=xp.uint32)
+    for i in reversed(range(_SCALED_VALUE_BITS + b.bit_length())):
+        word, at = (phi, i - 32) if i >= 32 else (plo, i)
+        rem = (rem << 1) | ((word >> at) & 1)
+        ge = rem >= den
+        rem = xp.where(ge, rem - den, rem)
+        if i >= 32:
+            qhi = qhi | (ge.astype(xp.uint32) << at)
+        else:
+            qlo = qlo | (ge.astype(xp.uint32) << at)
+    neg = (val < 0) != (num < 0)
+    up = (neg & (rem != 0)).astype(xp.uint32)
+    rlo = qlo + up
+    rhi = qhi + (rlo < qlo).astype(xp.uint32)
+    return _u64_negate_where(xp, neg, rlo, rhi)
+
+
 def _find_byte_from(jnp, window, byte: int):
     """First index of `byte` in each row of window, else width (=miss)."""
     n, w = window.shape
@@ -385,10 +517,19 @@ def _validated(spec_json: str, r_in: int):
         )
     mapper = spec.mapper
     if isinstance(mapper, _MapProject):
-        if any(not isinstance(f, (Int, Str)) for f in mapper.fields):
+        if any(not isinstance(f, (Int, Str, Long, Scaled)) for f in mapper.fields):
             raise ValueError(
                 "Float/Substr/Concat projections require the columnar path"
             )
+        for f in mapper.fields:
+            if isinstance(f, Scaled) and not (
+                type(f.num) is int and type(f.den) is int
+                and abs(f.num) < 2**31 and 0 < f.den < 2**31
+            ):
+                raise ValueError(
+                    f"Scaled({f.key!r}, {f.num!r}, {f.den!r}): num and den are "
+                    "integers, |num| < 2**31 and 0 < den < 2**31"
+                )
         r_out = project_out_width(mapper.fields)
         if r_out > r_in:
             raise ValueError("projected width exceeds input width")
@@ -407,18 +548,33 @@ def packbits(xp, keep):
     return (b * weights[None, :]).sum(axis=1).astype(xp.uint8)
 
 
-def _project(xp, mapper: _MapProject, data, lengths):
+def _value_pos(xp, data, lengths, key: str):
+    """Where the value after the first ``"key":`` starts in each row, else -1."""
+    pat = f'"{key}":'.encode()
+    pos = _find_pattern(xp, data, lengths, pat)
+    return xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
+
+
+def _project(xp, mapper: _MapProject, data, lengths, scope=contextlib.nullcontext):
     """(out uint8 [N, r_out], ok bool [N]) of ``map_project``: the
     fixed-width struct of every row, and whether the row's projection
     could be made faithfully (a row it could not is dropped)."""
     parts = []
     ok_all = xp.ones(data.shape[0], dtype=bool)
     for f in mapper.fields:
-        if isinstance(f, Int):
-            pat = f'"{f.key}":'.encode()
-            pos = _find_pattern(xp, data, lengths, pat)
-            vpos = xp.where(pos >= 0, pos + len(pat), xp.int32(-1))
-            val, ok = _parse_int_at(xp, data, vpos)
+        if isinstance(f, (Long, Scaled)):
+            kind = "long" if isinstance(f, Long) else "scaled"
+            with scope("project." + kind):
+                vpos = _value_pos(xp, data, lengths, f.key)
+                if isinstance(f, Long):
+                    lo, hi, ok = _parse_long_at(xp, data, vpos)
+                else:
+                    val, ok = _parse_int_at(xp, data, vpos)
+                    lo, hi = _scale_exact(xp, val, f.num, f.den)
+                ok_all = ok_all & ok
+                parts.append(_u64_le_bytes(xp, lo, hi))
+        elif isinstance(f, Int):
+            val, ok = _parse_int_at(xp, data, _value_pos(xp, data, lengths, f.key))
             ok_all = ok_all & ok
             le = val.astype(xp.uint32)
             parts.append(
@@ -478,7 +634,7 @@ def _transform_body(
             return out, lengths, keep
         if isinstance(mapper, _MapProject):
             with scope("project"):
-                out, ok_all = _project(xp, mapper, data, lengths)
+                out, ok_all = _project(xp, mapper, data, lengths, scope)
                 keep2 = keep & ok_all
                 out_len = xp.where(keep2, xp.int32(r_out), 0)
             return out, out_len, keep2

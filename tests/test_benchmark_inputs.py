@@ -9,10 +9,23 @@ fixture's frames, ``docs_text.py``'s pins and the refusals by name. They
 are that file's functions, collected here, so the two cannot drift; the
 benchmark's modules are loaded by path, as ``test_serving_timers.py`` loads
 ``readers``.
+
+PR 37 added a generator that is not ``docs.py``'s document
+(``docs_nexmark.make_events``, configuration ``nexmark64p-q1``): two
+properties of that file's ``test_generator_contract`` are written for the
+``{"level", "code", "msg", "pad"}`` document of ~1 KB, so the case here
+calls that function for every generator it fits and holds the same two
+properties in NEXmark's own terms; the stream's shapes (proportions, sizes,
+price law, timestamps) follow.
 """
 
+import collections
+import json
 import os
+import statistics
 import sys
+
+import pytest
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmarks")
 if BENCH not in sys.path:
@@ -27,24 +40,111 @@ from test_inputs import (  # noqa: E402,F401
     test_docs_text_compresses_like_text_where_docs_compresses_like_filler,
     test_docs_text_is_docs_but_for_what_pad_holds,
     test_every_produce_frame_of_a_cell_is_the_parents_byte_for_byte,
-    test_generator_contract,
     test_the_compressed_fixtures_frames_are_zstd_sealed_and_a_third_the_size,
     test_unknown_generators_are_refused_by_name,
 )
 
 
+NEXMARK = "docs_nexmark.make_events"
+NEXMARK_CONFIG = os.path.join("benchmarks", "configs", "nexmark64p-q1.json")
+NEXMARK_FIELDS = {
+    0: ["event_type", "id", "name", "emailAddress", "creditCard", "city", "state",
+        "dateTime", "extra"],
+    1: ["event_type", "id", "itemName", "description", "initialBid", "reserve", "dateTime",
+        "expires", "seller", "category", "extra"],
+    2: ["event_type", "auction", "bidder", "price", "dateTime", "extra"],
+}
+
+
+@pytest.mark.parametrize("generator", bench.GENERATORS)
+@pytest.mark.parametrize("prop", ["bytes", "seed_alone", "independent_of_only",
+                                  "inside_the_stated_widths", "fields_in_order"])
+def test_generator_contract(generator, prop):
+    if generator != NEXMARK or prop in ("bytes", "seed_alone", "independent_of_only"):
+        return bench.test_generator_contract(generator, prop)
+    a = bench._make(generator, 2**31 + 11, 8, 64)
+    if prop == "inside_the_stated_widths":
+        d = bench.load(NEXMARK_CONFIG)["documents"]
+        stream = {"seed": 2**31 + 11, "partitions": 8, "records_per_partition": 64}
+        bench.loadgen.check_documents(a, d, stream, None)
+        with pytest.raises(bench.wire.InputShapeError, match="documents.generator"):
+            bench.loadgen.check_documents(a, {**d, "bytes_max": 400}, stream, None)
+        with pytest.raises(bench.wire.InputShapeError, match="documents.generator"):
+            bench.loadgen.check_documents({**a, 2: a[2][:-1]}, d, stream, None)
+    else:
+        for p, part in a.items():
+            for i, v in enumerate(part):
+                n = p * 64 + i
+                kind = 0 if n % 50 == 0 else 1 if n % 50 < 4 else 2
+                assert b"\\" not in v and b" \"" not in v and b": " not in v
+                doc = json.loads(v)
+                assert list(doc) == NEXMARK_FIELDS[kind] and doc["event_type"] == kind
+                assert doc["dateTime"] == 1_700_000_000_000 + n
+
+
+def test_the_nexmark_configuration_feeds_the_sources_shapes():
+    """Configuration ``nexmark64p-q1``: its generator under its own params
+    gives the proportions, average sizes, price law and timestamps the
+    source states, and its reference keeps the Bids and recovers the event
+    number from each (point 5 of the contract, which ``transform_rate``
+    counts by)."""
+    c = bench.load(NEXMARK_CONFIG)
+    assert c["documents"]["generator"] == NEXMARK and c["records_per_batch"] == 256
+    assert c["producer"] == {"compression": "none"} and c["broker_properties"] == {"coproc_enable": "true"}
+    per = 2000
+    stream = {"seed": 2**31 + 5, "partitions": 4, "records_per_partition": per}
+    values = bench.loadgen.document_source(c["documents"])(stream)
+    ref, params = bench.loadgen.load_reference(c["reference"]["name"]), c["reference"]["params"]
+    assert ref.BASE_MS == c["documents"]["params"]["base_ms"]
+    sizes, prices, kept = collections.defaultdict(list), [], 0
+    for p, part in values.items():
+        outs = [(i, ref.reference(v, **params)) for i, v in enumerate(part)]
+        assert [ref.sequence(o) for i, o in outs if o is not None] == [
+            p * per + i for i, o in outs if o is not None]
+        assert [i for i, o in outs if o is not None] == [
+            i for i in range(per) if (p * per + i) % 50 >= 4]  # the Bids, all of them
+        kept += sum(o is not None for _, o in outs)
+        for v in part:
+            doc = json.loads(v)
+            sizes[doc["event_type"]].append(len(v))
+            assert len(str(doc["dateTime"])) == 13
+            if doc["event_type"] == 2:
+                prices.append(doc["price"])
+                assert doc["auction"] >= 1000 and doc["bidder"] >= 1000
+    assert kept / (4 * per) == 0.92
+    assert [len(sizes[k]) for k in (0, 1, 2)] == [160, 480, 7360]  # 1 : 3 : 46
+    assert 195 <= statistics.mean(sizes[0]) <= 205
+    assert 480 <= statistics.mean(sizes[1]) <= 520
+    assert 95 <= statistics.mean(sizes[2]) <= 105
+    assert max(map(max, sizes.values())) <= c["documents"]["bytes_max"] < 1024
+    # round(10 ** (6u) * 100): 100 .. 10 ** 8 cents, a sixth to a decade
+    assert 100 <= min(prices) and max(prices) <= 10**8
+    decades = collections.Counter(len(str(x)) for x in prices)
+    assert all(0.12 < decades[k] / len(prices) < 0.21 for k in range(3, 9))
+    # hot auctions and bidders: half the bids on a hot auction's id (a multiple
+    # of 100 over 1,000), three quarters by a hot bidder (1 over one)
+    bids = [json.loads(v) for part in values.values() for v in part if v[14:15] == b"2"]
+    assert 0.45 < sum(b["auction"] % 100 == 0 for b in bids) / len(bids) < 0.56
+    assert 0.70 < sum(b["bidder"] % 100 == 1 for b in bids) / len(bids) < 0.80
+
+
 def test_the_pinned_cells_are_the_manifests_uncompressed_cells():
     """``bench.test_the_pinned_cells_are_the_manifests_cells`` as it has to
-    read since PR 33 added a cell whose producers compress: the frames
-    pinned at 29ef861 are those of every cell whose configuration feeds
-    ``docs.make_documents`` uncompressed, and the new cell is none of them
-    (its frames are the fixture's, which the case above pins by shape)."""
+    read since PR 33 added a cell whose producers compress and PR 37 one
+    that feeds another generator: the frames pinned at 29ef861 are those of
+    every cell whose configuration feeds ``docs.make_documents``
+    uncompressed, and every other cell names another generator or another
+    codec (its frames are its own generator's, which the contract cases
+    above hold by shape)."""
     man = bench.manifest()
-    plain = set()
+    inputs = {}
     for w in man["workloads"]:
         config = bench.config_of(man, w["name"])
-        if (config["documents"]["generator"] == "docs.make_documents"
-                and config.get("producer", {}).get("compression", "none") == "none"):
-            plain.add(w["name"])
+        inputs[w["name"]] = (config["documents"]["generator"],
+                             config.get("producer", {}).get("compression", "none"))
+    plain = {cell for cell, fed in inputs.items() if fed == ("docs.make_documents", "none")}
     assert plain == set(bench.PARENT_FRAMES)
-    assert {w["name"] for w in man["workloads"]} - plain <= {"json64p-v1map-zstd.catchup"}
+    for cell in set(inputs) - set(bench.PARENT_FRAMES):
+        generator, codec = inputs[cell]
+        assert generator != "docs.make_documents" or codec != "none", cell
+        assert callable(bench.loadgen.load_generator(generator)) and bench.wire.codec_id(codec) >= 0
