@@ -269,6 +269,102 @@ def main() -> int:
             rc == -1 and got == b"\xff" * (n_pad * stride),
         )
 
+    # ---- the seal, many batches a call, against the Python seal
+    # (models/record.py reseal: the Kafka CRC over the big-endian header
+    # prefix + the stored payload, the internal header CRC over the 57
+    # little-endian bytes after it), and its frames against the
+    # many-frames decompress
+    if hasattr(dll, "rp_seal_many"):
+        dll.rp_seal_available.restype = ctypes.c_int32
+        dll.rp_seal_many.restype = ctypes.c_int64
+        dll.rp_zstd_frame_sizes.restype = ctypes.c_int64
+        dll.rp_zstd_uncompress_many.restype = ctypes.c_int64
+
+        def py_seal(stored, attrs, kept, btype, ts0, ts1):
+            crc = crc32c_ref(
+                struct.pack(">hiqqqhii", attrs, kept - 1, ts0, ts1, -1, -1, -1, kept) + stored)
+            header_crc = crc32c_ref(struct.pack(
+                "<iqbIHiqqqhii", 61 + len(stored), 0, btype, crc, attrs, kept - 1,
+                ts0, ts1, -1, -1, -1, kept))
+            return crc, header_crc
+
+        doc = b'{"level":"warn","code":%d,"msg":"the seal in one crossing"}'
+        six = b"".join(frame_record(i, doc % i) for i in range(6))
+
+        def padded_to(size):  # seven records, the last one's value as long as it takes
+            return next(p for p in (six + frame_record(6, b"p" * k) for k in range(size))
+                        if len(p) == size)
+
+        payloads = [b"", padded_to(511), padded_to(512),
+                    b"".join(frame_record(i, doc % (i * 7)) for i in range(40)),
+                    frame_record(0, b""), b"never sealed"]
+        kept = [0, 7, 7, 40, 1, 0]
+        types = [1, 1, 5, 1, 1, 1]
+        ts0 = [0, 1700000000000, 1700000000001, -1, 5, 0]
+        ts1 = [0, 1700000000500, 1700000000001, 2**40, 5, 0]
+        n = len(payloads)
+        check("seal_many fixture straddles the threshold",
+              [len(p) for p in payloads[1:3]] == [511, 512])
+
+        def seal(codec, dst_cap, n_threads=4):
+            dst = ctypes.create_string_buffer(max(dst_cap, 1))
+            out_off = (ctypes.c_int64 * n)()
+            out_len = (ctypes.c_int64 * n)()
+            out_attrs = (ctypes.c_int32 * n)()
+            crc = (ctypes.c_uint32 * n)()
+            header_crc = (ctypes.c_uint32 * n)()
+            rc = dll.rp_seal_many(
+                (ctypes.c_char_p * n)(*payloads),
+                (ctypes.c_int64 * n)(*(len(p) for p in payloads)),
+                (ctypes.c_int32 * n)(*kept), (ctypes.c_int8 * n)(*types),
+                (ctypes.c_int64 * n)(*ts0), (ctypes.c_int64 * n)(*ts1),
+                ctypes.c_int64(n), ctypes.c_int64(512), ctypes.c_int32(codec),
+                ctypes.c_int32(3), dst, ctypes.c_int64(dst_cap), out_off, out_len,
+                out_attrs, crc, header_crc, ctypes.c_int32(n_threads),
+            )
+            return rc, dst, list(out_off), list(out_len), list(out_attrs), list(crc), list(header_crc)
+
+        rc, _, off, ln, attrs, crc, hcrc = seal(0, 0)
+        check("seal_many codec none: all stored, skipped jobs marked",
+              rc == 0 and attrs == [0] * n and off == [-1] * n
+              and ln == [-1, 511, 512, len(payloads[3]), len(payloads[4]), -1])
+        check("seal_many codec none CRCs == python seal", all(
+            (crc[b], hcrc[b]) == py_seal(payloads[b], 0, kept[b], types[b], ts0[b], ts1[b])
+            for b in range(n) if kept[b]))
+        check("seal_many refuses a codec it does not have", seal(3, 1 << 16)[0] == -1)
+        if dll.rp_seal_available():
+            rc, dst, off, ln, attrs, crc, hcrc = seal(4, 1 << 16)
+            check("seal_many zstd: frames from the threshold on",
+                  rc == 0 and attrs == [0, 0, 4, 4, 0, 0] and off[2] == 0 and off[3] > ln[2]
+                  and ln[1] == 511 and 0 < ln[3] < len(payloads[3]))
+            stored = [dst.raw[off[b] : off[b] + ln[b]] if attrs[b] else payloads[b]
+                      for b in range(n)]
+            check("seal_many zstd CRCs == python seal", all(
+                (crc[b], hcrc[b]) == py_seal(stored[b], attrs[b], kept[b], types[b], ts0[b], ts1[b])
+                for b in range(n) if kept[b]))
+            frames = [stored[2], stored[3]]
+            f_off = (ctypes.c_int64 * 2)()
+            f_len = (ctypes.c_int64 * 2)()
+            src = (ctypes.c_char_p * 2)(*frames)
+            src_lens = (ctypes.c_int64 * 2)(*(len(f) for f in frames))
+            total = dll.rp_zstd_frame_sizes(src, src_lens, ctypes.c_int64(2), f_off, f_len)
+            check("seal_many frames state their content size",
+                  list(f_len) == [len(payloads[2]), len(payloads[3])])
+            back = ctypes.create_string_buffer(max(total, 1))
+            failed = dll.rp_zstd_uncompress_many(
+                src, src_lens, ctypes.c_int64(2), back, ctypes.c_int64(total), f_off, f_len,
+                ctypes.c_int32(1))
+            check("seal_many frames decompress to their payloads",
+                  failed == 0 and back.raw[:total] == payloads[2] + payloads[3])
+            one = seal(4, 1 << 16, n_threads=1)
+            check("seal_many threads change nothing", (one[0], list(one[2:])) == (rc, [off, ln, attrs, crc, hcrc]))
+            rc, _, off, ln, attrs, crc, hcrc = seal(4, 600)  # room for the first frame alone
+            check("seal_many dst too small: that job alone is left unsealed",
+                  rc == 1 and ln[3] == -1 and ln[2] > 0 and attrs[2] == 4
+                  and (crc[4], hcrc[4]) == py_seal(payloads[4], 0, 1, 1, 5, 5))
+        else:
+            check("seal_many without libzstd serves no zstd job", seal(4, 1 << 16)[0] == -1)
+
     print(("PASS" if failures == 0 else f"FAIL ({failures})"))
     return 1 if failures else 0
 

@@ -18,6 +18,7 @@
 #include <thread>
 #include <vector>
 #include <dlfcn.h>
+#include <functional>
 
 #if defined(__x86_64__)
 // x86intrin.h + per-function target attributes instead of a global -msse4.2:
@@ -331,6 +332,7 @@ int64_t rp_parse_many_ptrs(const uint8_t* const* payloads,
 // library all the same, rp_zstd_available() says 0 there and callers keep
 // the per-batch codec.
 typedef struct ZSTD_DCtx_s ZSTD_DCtx;
+typedef struct ZSTD_CCtx_s ZSTD_CCtx;
 static struct {
   ZSTD_DCtx* (*create)();
   size_t (*free)(ZSTD_DCtx*);
@@ -339,6 +341,12 @@ static struct {
   size_t (*frame_size)(const void*, size_t);
   unsigned (*is_error)(size_t);
   bool ok;
+  // the way out (rp_seal_many): a libzstd without these still decompresses
+  ZSTD_CCtx* (*ccreate)();
+  size_t (*cfree)(ZSTD_CCtx*);
+  size_t (*compress)(ZSTD_CCtx*, void*, size_t, const void*, size_t, int);
+  size_t (*bound)(size_t);
+  bool can_compress;
 } zstd;
 static std::once_flag zstd_once;
 
@@ -356,6 +364,12 @@ static void zstd_resolve() {
   *(void**)&zstd.is_error = dlsym(h, "ZSTD_isError");
   zstd.ok = zstd.create && zstd.free && zstd.decompress &&
             zstd.content_size && zstd.frame_size && zstd.is_error;
+  *(void**)&zstd.ccreate = dlsym(h, "ZSTD_createCCtx");
+  *(void**)&zstd.cfree = dlsym(h, "ZSTD_freeCCtx");
+  *(void**)&zstd.compress = dlsym(h, "ZSTD_compressCCtx");
+  *(void**)&zstd.bound = dlsym(h, "ZSTD_compressBound");
+  zstd.can_compress = zstd.is_error && zstd.ccreate && zstd.cfree &&
+                      zstd.compress && zstd.bound;
 }
 
 int32_t rp_zstd_available() {
@@ -387,6 +401,28 @@ int64_t rp_zstd_frame_sizes(const uint8_t* const* srcs,
   }
   return total;
 }
+
+// Run `work` (which claims its items off a shared counter until none are
+// left) on the caller's thread and on up to n_threads - 1 more, none below
+// 32 of the n items a thread: a small launch stays on the caller's.
+extern "C++" {
+template <typename Work>
+static void run_split(Work& work, int64_t n, int32_t n_threads) {
+  int32_t extra = n_threads > 1 ? n_threads - 1 : 0;
+  if ((int64_t)extra > n / 32) extra = (int32_t)(n / 32);
+  std::vector<std::thread> pool;
+  pool.reserve((size_t)extra);
+  for (int32_t t = 0; t < extra; t++) {
+    try {
+      pool.emplace_back(std::ref(work));
+    } catch (...) {
+      break;  // no thread to be had: the caller's does the rest
+    }
+  }
+  work();
+  for (auto& t : pool) t.join();
+}
+}  // extern "C++"
 
 // Decompress frame b (dst_len[b] >= 0) into dst + dst_off[b]; frames with
 // dst_len[b] < 0 are skipped. Only the FIRST frame of srcs[b] is read,
@@ -441,23 +477,170 @@ int64_t rp_zstd_uncompress_many(const uint8_t* const* srcs,
       }
     }
   };
-  int32_t extra = n_threads > 1 ? n_threads - 1 : 0;
-  if ((int64_t)extra > n / 32) extra = (int32_t)(n / 32);  // small launch
-  std::vector<std::thread> pool;
-  pool.reserve((size_t)extra);
-  for (int32_t t = 0; t < extra; t++) {
-    try {
-      pool.emplace_back(work);
-    } catch (...) {
-      break;  // no thread to be had: the caller's does the rest
-    }
-  }
-  work();
-  for (auto& t : pool) t.join();
+  run_split(work, n, n_threads);
   // a thread without a context claimed nothing; if the caller's had none,
   // frames may be left undone
   if (no_ctx.load() && next.load() < n) return -1;
   return failed.load();
+}
+
+// ------------------------------------------------- the seal, many a call
+// A launch's framed payloads become output batches in ONE crossing (the
+// mirror of rp_zstd_uncompress_many on the way out): job b's payload is
+// compressed where payload_lens[b] >= threshold and codec is Zstd (one
+// frame that states its content size, as ZSTD_compressCCtx writes it and
+// as the many-frames decompress needs it), and both CRCs of the batch's
+// header are computed as models/record.py computes them: the Kafka CRC
+// over the 40-byte big-endian prefix (attributes .. record count) and the
+// payload as it is stored, with no joined copy, and the internal header
+// CRC over the 57 little-endian bytes after header_crc. What the job's
+// header does not carry here is what build_output_batch sets: base offset
+// 0, producer id / epoch / base sequence -1.
+int32_t rp_seal_available() {
+  std::call_once(zstd_once, zstd_resolve);
+  return zstd.can_compress ? 1 : 0;
+}
+
+static inline uint8_t* put_be(uint8_t* p, uint64_t v, int n) {
+  for (int i = n - 1; i >= 0; i--) *p++ = (uint8_t)(v >> (8 * i));
+  return p;
+}
+
+static inline uint8_t* put_le(uint8_t* p, uint64_t v, int n) {
+  for (int i = 0; i < n; i++) *p++ = (uint8_t)(v >> (8 * i));
+  return p;
+}
+
+// Both header CRCs of a batch build_output_batch would make around
+// `stored` (the payload as it goes to the log).
+static void seal_crcs(const uint8_t* stored, int64_t stored_len,
+                      int32_t attrs, int32_t kept, int8_t type,
+                      int64_t first_ts, int64_t max_ts, uint32_t* crc_out,
+                      uint32_t* header_crc_out) {
+  uint8_t be[40];
+  uint8_t* p = put_be(be, (uint16_t)attrs, 2);
+  p = put_be(p, (uint32_t)(kept - 1), 4);  // last offset delta
+  p = put_be(p, (uint64_t)first_ts, 8);
+  p = put_be(p, (uint64_t)max_ts, 8);
+  p = put_be(p, ~0ull, 8);                 // producer id -1
+  p = put_be(p, 0xFFFFu, 2);               // producer epoch -1
+  p = put_be(p, 0xFFFFFFFFu, 4);           // base sequence -1
+  p = put_be(p, (uint32_t)kept, 4);        // record count
+  uint32_t c = rp_crc32c_update(0xFFFFFFFFu, be, sizeof be);
+  uint32_t crc = rp_crc32c_update(c, stored, (size_t)stored_len) ^ 0xFFFFFFFFu;
+  uint8_t le[57];
+  p = put_le(le, (uint32_t)(61 + stored_len), 4);  // size_bytes
+  p = put_le(p, 0, 8);                             // base offset
+  *p++ = (uint8_t)type;
+  p = put_le(p, crc, 4);
+  p = put_le(p, (uint16_t)attrs, 2);
+  p = put_le(p, (uint32_t)(kept - 1), 4);
+  p = put_le(p, (uint64_t)first_ts, 8);
+  p = put_le(p, (uint64_t)max_ts, 8);
+  p = put_le(p, ~0ull, 8);
+  p = put_le(p, 0xFFFFu, 2);
+  p = put_le(p, 0xFFFFFFFFu, 4);
+  p = put_le(p, (uint32_t)kept, 4);
+  *crc_out = crc;
+  *header_crc_out = rp_crc32c(le, sizeof le);
+}
+
+// Seal jobs 0 .. n-1. Job b with kept[b] <= 0 makes no batch and is
+// skipped (out_len[b] = -1). For every other job, on success: out_len[b]
+// is the stored payload's length and out_attrs[b] its attributes; where
+// out_attrs[b] != 0 the stored payload is dst[out_off[b] .. + out_len[b])
+// (a Zstd frame), where it is 0 it is the job's own payload, untouched
+// (out_off[b] = -1); out_crc[b] / out_header_crc[b] are the header's two
+// CRCs. A job this call could not seal gets out_len[b] = -1 and is the
+// per-batch road's to seal or to refuse, as it always was: its frame's
+// bound does not fit what is left of dst, the codec fails, the batch
+// would be wider than a header's size field. codec is 0 (store all) or 4
+// (Zstd); level is Zstd's. n_threads > 1 splits the jobs over that many
+// threads, the caller's among them and none below 32 jobs a thread, each
+// with a compression context of its own. Returns the number of jobs it
+// could not seal (skipped ones are not among them); -1, and then every
+// job is the per-batch road's, for another codec, for Zstd without
+// libzstd's compress side, or where the caller's thread gets no context.
+int64_t rp_seal_many(const uint8_t* const* payloads,
+                     const int64_t* payload_lens, const int32_t* kept,
+                     const int8_t* types, const int64_t* first_ts,
+                     const int64_t* max_ts, int64_t n, int64_t threshold,
+                     int32_t codec, int32_t level, uint8_t* dst,
+                     int64_t dst_cap, int64_t* out_off, int64_t* out_len,
+                     int32_t* out_attrs, uint32_t* out_crc,
+                     uint32_t* out_header_crc, int32_t n_threads) {
+  const int32_t kZstd = 4;
+  if (codec != 0 && codec != kZstd) return -1;
+  if (codec == kZstd && !rp_seal_available()) return -1;
+  rp_crc32c_update(0, nullptr, 0);  // the dispatch is picked before threads
+  // every frame's place in dst, at its bound, before any thread starts:
+  // out_len < 0 marks the jobs that are not to be sealed
+  int64_t used = 0;
+  for (int64_t b = 0; b < n; b++) {
+    out_off[b] = -1;
+    out_attrs[b] = 0;
+    int64_t len = payload_lens[b];
+    if (kept[b] <= 0 || len < 0 || len > INT32_MAX - 61) {
+      out_len[b] = -1;
+      continue;
+    }
+    out_len[b] = len;
+    if (codec == kZstd && len >= threshold) {
+      int64_t cap = (int64_t)zstd.bound((size_t)len);
+      if (cap > dst_cap - used) {
+        out_len[b] = -1;  // dst too small for this one
+        continue;
+      }
+      out_off[b] = used;
+      out_attrs[b] = kZstd;
+      used += cap;
+    }
+  }
+  std::atomic<int64_t> next{0};
+  std::atomic<bool> no_ctx{false};
+  auto work = [&]() {
+    static thread_local struct Ctx {
+      ZSTD_CCtx* c = nullptr;
+      ~Ctx() { if (c) zstd.cfree(c); }
+    } ctx;
+    if (codec == kZstd && !ctx.c && !(ctx.c = zstd.ccreate())) {
+      no_ctx.store(true);
+      return;
+    }
+    const int64_t kChunk = 16;  // jobs a claim: few atomics, even split
+    for (;;) {
+      int64_t lo = next.fetch_add(kChunk);
+      if (lo >= n) return;
+      int64_t hi = lo + kChunk < n ? lo + kChunk : n;
+      for (int64_t b = lo; b < hi; b++) {
+        if (out_len[b] < 0) continue;
+        const uint8_t* stored = payloads[b];
+        int64_t stored_len = out_len[b];
+        if (out_attrs[b]) {
+          size_t cap = zstd.bound((size_t)stored_len);
+          size_t got = zstd.compress(ctx.c, dst + out_off[b], cap, stored,
+                                     (size_t)stored_len, level);
+          if (zstd.is_error(got)) {
+            out_len[b] = -1;
+            continue;
+          }
+          stored = dst + out_off[b];
+          stored_len = (int64_t)got;
+          out_len[b] = stored_len;
+        }
+        seal_crcs(stored, stored_len, out_attrs[b], kept[b], types[b],
+                  first_ts[b], max_ts[b], out_crc + b, out_header_crc + b);
+      }
+    }
+  };
+  run_split(work, n, n_threads);
+  // a thread without a context claimed nothing; if the caller's had none,
+  // jobs may be left undone
+  if (no_ctx.load() && next.load() < n) return -1;
+  int64_t failed = 0;
+  for (int64_t b = 0; b < n; b++)
+    if (out_len[b] < 0 && kept[b] > 0) failed++;
+  return failed;
 }
 
 // Build a records payload from kept transform outputs: record i (where

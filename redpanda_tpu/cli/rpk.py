@@ -474,7 +474,7 @@ async def cmd_debug(args) -> int:
         for k in (
             "columnar_backend", "columnar_probe", "parse_path", "parse_probe",
             "colcache", "arena", "staging_arena", "uncompress_arena",
-            "breakers", "lockwatch",
+            "seal_arena", "breakers", "lockwatch",
             "leakwatch", "mesh_error", "device_launches_by_script",
         ):
             if stats.get(k) is not None:

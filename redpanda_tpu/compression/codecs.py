@@ -38,13 +38,16 @@ def gzip_uncompress(data: bytes) -> bytes:
 # object crash the process — and the coproc engine seals output batches
 # from several tick and pool threads at once.
 _zstd_local = threading.local()
+# The level every Zstd frame of this package is written at: the per-batch
+# codec's below, and the many-batches seal's (batch_codec.build_output_batches).
+ZSTD_LEVEL = 3
 
 
 def _zstd_ctx():
     ctx = getattr(_zstd_local, "ctx", None)
     if ctx is None:
         ctx = _zstd_local.ctx = (
-            zstandard.ZstdCompressor(level=3),
+            zstandard.ZstdCompressor(level=ZSTD_LEVEL),
             zstandard.ZstdDecompressor(),
         )
     return ctx
@@ -64,10 +67,12 @@ def zstd_uncompress(data: bytes) -> bytes:
     return out
 
 
-# Threads one many-frames decompress runs on, the caller's among them. Fixed
-# in code: a launch's ~1,500 frames of ~33 KB split evenly, the crossing holds
-# no interpreter lock, and the broker's own threads (event loop, harvester)
-# leave cores idle on any host that holds a chip (PERF.md section 6, PR 33).
+# Threads one many-frames decompress (and one many-batches seal) runs on at
+# most, the caller's among them; the crossing itself takes fewer for fewer
+# than 32 frames a thread. Fixed in code: a launch's ~1,500 frames of ~33 KB
+# split evenly, the crossing holds no interpreter lock, and the broker's own
+# threads (event loop, harvester) leave cores idle on any host that holds a
+# chip (PERF.md section 6, PR 33).
 ZSTD_MANY_THREADS = 4
 
 

@@ -16,8 +16,11 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from redpanda_tpu.compression import compress, uncompress, uncompress_many
-from redpanda_tpu.models.record import Compression, Record, RecordBatch, RecordBatchHeader
+from redpanda_tpu.compression import active_backend, compress, uncompress, uncompress_many
+from redpanda_tpu.compression.codecs import ZSTD_LEVEL, ZSTD_MANY_THREADS
+from redpanda_tpu.models.record import (
+    INTERNAL_HEADER_SIZE, Compression, Record, RecordBatch, RecordBatchHeader,
+)
 from redpanda_tpu.observability import stages
 from redpanda_tpu.utils.vint import decode_zigzag, encode_zigzag
 
@@ -711,6 +714,92 @@ def build_output_batch(
     batch = RecordBatch(hdr, payload)
     batch.reseal()
     return batch
+
+
+# A slot of build_output_batches' result that build_output_batch has to fill.
+UNSEALED = object()
+_NO_DST = np.empty(1, dtype=np.uint8)
+
+
+def build_output_batches(
+    jobs: list[tuple],
+    *,
+    compress_threshold: int = 512,
+    codec: Compression = Compression.zstd,
+    pool=_Unpooled,
+) -> list | None:
+    """``build_output_batch`` over a launch's ``(source, payload, kept)``
+    jobs in ONE native crossing that holds no interpreter lock
+    (rp_seal_many: the compression and both header CRCs of every batch, on
+    up to four threads by the job count), its frames written into one
+    buffer out of ``pool`` (``acquire(nbytes)`` / ``release(buf)``) that
+    goes back before this returns. One slot a job: the sealed batch, None
+    where ``kept == 0``, ``UNSEALED`` for a job the crossing left alone,
+    which ``build_output_batch`` seals or refuses as it always did. The
+    batches are what ``build_output_batch`` makes, down to both CRCs,
+    except that a Zstd frame's bytes are the host libzstd's (same level,
+    content size stated). ``None`` when there is no such crossing here: no
+    native library or no libzstd under it, a codec other than zstd / none,
+    a compression backend other than the host's."""
+    lib = _native()
+    if (
+        lib is None
+        or not getattr(lib, "has_seal_many", False)
+        or codec not in (Compression.zstd, Compression.none)
+        or active_backend() != "host"
+    ):
+        return None
+    # plain Python up to the crossing: numpy hands the interpreter lock
+    # over for any operation on more than ~500 elements, and beside a busy
+    # event loop every hand-over costs up to the switch interval (PERF.md
+    # section 6, PR 33); the range checks are the crossing's own
+    payloads, kepts, types, first_ts, max_ts = [], [], [], [], []
+    to_compress = n_frames = 0
+    zstd = codec == Compression.zstd
+    for source, payload, kept in jobs:
+        h = source.header
+        payloads.append(payload)
+        kepts.append(kept)
+        types.append(h.type)
+        first_ts.append(h.first_timestamp)
+        max_ts.append(h.max_timestamp)
+        if zstd and len(payload) >= compress_threshold:
+            to_compress += len(payload)
+            n_frames += 1
+    # room for ZSTD_compressBound of every frame: len + len / 256 + <= 64
+    dst = (
+        pool.acquire(to_compress + (to_compress >> 8) + 64 * n_frames + 64)
+        if n_frames else _NO_DST
+    )
+    try:
+        sealed = lib.seal_many(
+            payloads, np.array(kepts, np.int32), np.array(types, np.int8),
+            np.array(first_ts, np.int64), np.array(max_ts, np.int64), dst,
+            threshold=compress_threshold, codec=int(codec), level=ZSTD_LEVEL,
+            n_threads=ZSTD_MANY_THREADS,
+        )
+        if sealed is None:
+            return None
+        frames = memoryview(dst)
+        out = []
+        for (_, payload, kept), off, ln, attrs, crc, header_crc, btype, ts0, ts1 in zip(
+            jobs, *(a.tolist() for a in sealed), types, first_ts, max_ts
+        ):
+            if kept == 0:
+                out.append(None)
+            elif ln < 0:
+                out.append(UNSEALED)
+            else:
+                if attrs:
+                    payload = frames[off : off + ln].tobytes()
+                out.append(RecordBatch(RecordBatchHeader(
+                    header_crc, INTERNAL_HEADER_SIZE + ln, 0, btype, crc, attrs,
+                    kept - 1, ts0, ts1, -1, -1, -1, kept, 0,
+                ), payload))
+        return out
+    finally:
+        if n_frames:
+            pool.release(dst)
 
 
 def rebuild_batch(

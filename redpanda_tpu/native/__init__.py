@@ -287,6 +287,21 @@ class _NativeLib:
                 ctypes.c_void_p, ctypes.c_int32,
             ]
             self.has_zstd_many = bool(dll.rp_zstd_available())
+        # the seal of many output batches a crossing; it compresses through
+        # the same run-time libzstd, so the same holds
+        self.has_seal_many = False
+        if hasattr(dll, "rp_seal_many"):
+            dll.rp_seal_available.restype = ctypes.c_int32
+            dll.rp_seal_available.argtypes = []
+            dll.rp_seal_many.restype = ctypes.c_int64
+            dll.rp_seal_many.argtypes = (
+                [ctypes.c_void_p] * 6
+                + [ctypes.c_int64, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+                   ctypes.c_void_p, ctypes.c_int64]
+                + [ctypes.c_void_p] * 5
+                + [ctypes.c_int32]
+            )
+            self.has_seal_many = bool(dll.rp_seal_available())
         dll.rp_json_find.restype = ctypes.c_int32
         dll.rp_json_find.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int32,
@@ -745,6 +760,52 @@ class _NativeLib:
         if failed < 0:
             raise RuntimeError("libzstd is not available")
         return int(failed)
+
+    def seal_many(
+        self, payloads, kept: np.ndarray, types: np.ndarray,
+        first_ts: np.ndarray, max_ts: np.ndarray, dst: np.ndarray, *,
+        threshold: int, codec: int, level: int, n_threads: int = 1,
+    ):
+        """Seal job b (framed payload ``payloads[b]``, ``kept[b]`` records,
+        its source's batch type and timestamps) for every b in ONE crossing
+        that holds no interpreter lock (rp_seal_many), on up to
+        ``n_threads`` threads: Zstd where ``len(payloads[b]) >= threshold``
+        and ``codec`` is 4, stored as it is otherwise (``codec`` 0 stores
+        all). Returns ``(out_off, out_len, out_attrs, crc, header_crc)``,
+        one a job: ``out_len[b] == -1`` for a job that makes no batch
+        (``kept[b] <= 0``) or that the crossing could not seal (its frame's
+        bound did not fit what was left of ``dst``, the codec failed); else
+        the stored payload is ``dst[out_off[b] : out_off[b] + out_len[b]]``
+        where ``out_attrs[b] != 0`` and ``payloads[b]`` itself where it is
+        0, and the two CRCs are the header's as ``RecordBatch.reseal``
+        computes them. ``None`` where the crossing serves no job at all
+        (another codec, no libzstd compress side, no context).
+        ``payloads``: a list of ``bytes`` or a pointer table
+        (``src_table``); the columns contiguous, ``kept`` int32, ``types``
+        int8, the timestamps int64."""
+        ptrs, src_lens = src_table(payloads)
+        n = len(ptrs)
+        for a, dt in ((kept, np.int32), (types, np.int8), (first_ts, np.int64),
+                      (max_ts, np.int64)):
+            if a.dtype != dt or not a.flags["C_CONTIGUOUS"] or len(a) != n:
+                raise ValueError("seal_many columns must be contiguous, one entry a job")
+        if dst.dtype != np.uint8 or dst.ndim != 1 or not dst.flags["C_CONTIGUOUS"]:
+            raise ValueError("seal_many dst must be contiguous uint8")
+        out_off = np.empty(n, dtype=np.int64)
+        out_len = np.empty(n, dtype=np.int64)
+        out_attrs = np.empty(n, dtype=np.int32)
+        crc = np.empty(n, dtype=np.uint32)
+        header_crc = np.empty(n, dtype=np.uint32)
+        failed = self._dll.rp_seal_many(
+            ptrs.ctypes.data, src_lens.ctypes.data, kept.ctypes.data,
+            types.ctypes.data, first_ts.ctypes.data, max_ts.ctypes.data,
+            n, threshold, codec, level, dst.ctypes.data, dst.nbytes,
+            out_off.ctypes.data, out_len.ctypes.data, out_attrs.ctypes.data,
+            crc.ctypes.data, header_crc.ctypes.data, n_threads,
+        )
+        if failed < 0:
+            return None
+        return out_off, out_len, out_attrs, crc, header_crc
 
     def explode_find(
         self,

@@ -189,6 +189,19 @@ coproc_uncompress = {
         "Payload bytes the explode's decompress produced",
     ),
 }
+# The seal (TpuEngine._seal_jobs): output batches sealed and the crossings
+# that served them (one a native call that sealed a launch's batches, one a
+# batch on the per-batch road); keyed by the engine's stats() name.
+coproc_seal = {
+    "n_sealed_batches": registry.counter(
+        "coproc_sealed_batches_total",
+        "Output batches the harvest sealed (compressed where over the threshold, both CRCs)",
+    ),
+    "n_seal_crossings": registry.counter(
+        "coproc_seal_crossings_total",
+        "Crossings that sealed output batches: one a many-batches native call, one a batch otherwise",
+    ),
+}
 coproc_launch_rows_hist = registry.histogram(
     "coproc_launch_rows",
     "Records fused into one device launch (bucket size after shape rounding)",
@@ -543,6 +556,7 @@ __all__ = [
     "coproc_output_bytes",
     "coproc_oversize_rows",
     "coproc_retries_total",
+    "coproc_seal",
     "coproc_shard_rows_hist",
     "coproc_stage_hist",
     "coproc_staged_bytes",
