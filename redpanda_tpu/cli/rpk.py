@@ -464,6 +464,15 @@ async def cmd_debug(args) -> int:
                 f"rows_per_device={mesh.get('rows_per_device')}"
             )
         stats = body.get("stats") or {}
+        if stats.get("n_json_rows"):
+            # coproc_json_rows_total{outcome="read|malformed|path_miss"}
+            print(
+                f"json:    {int(stats['n_json_rows'])} rows read as JSON by a "
+                f"structural program (map_project_json); dropped "
+                f"{int(stats.get('n_json_malformed_rows', 0))} as not one sound "
+                f"object, {int(stats.get('n_json_path_miss_rows', 0))} for a path "
+                f"absent or of a value its field cannot hold"
+            )
         shown = {
             k: v for k, v in sorted(stats.items())
             if k.startswith(("t_", "n_", "bytes_")) or k == "host_workers"

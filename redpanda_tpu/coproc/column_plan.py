@@ -17,7 +17,11 @@ columnar lane answers with *pushdown*:
   were always host work (ops/pipeline.py module docs).
 - **payload** (v1 raw-byte specs: filter_contains, map_uppercase with
   filters): the original full-row staging pipeline — whole rows cross the
-  link both ways.
+  link both ways. The v1 forms keep their byte semantics (substring
+  scans for ``"key":``); ``map_project_json`` rides the same lane and
+  geometry with a program that reads each row as JSON (a structural pass,
+  then dotted paths looked up by structure: ops/transforms.py), and says
+  in a trailing byte of the result row why it dropped a row.
 - **host** (identity, pure uppercase, py_transform escape hatch): no device
   stage exists or none is warranted; runs in the engine's host stage with
   the same interface and semantics.
@@ -45,8 +49,10 @@ from redpanda_tpu.ops.transforms import (
     Substr,
     TransformSpec,
     _MapProject,
+    _MapProjectJson,
     _MapUppercase,
     packbits as _packbits,
+    reports_reason,
     project_out_width,
 )
 
@@ -535,6 +541,13 @@ class PayloadPlan:
         builds new bytes on the device and keeps the result matrix."""
         return bool(self.spec.filters) and self.spec.mapper is None
 
+    @property
+    def structural(self) -> bool:
+        """True when the program reads its rows as JSON
+        (``map_project_json``): its result rows carry a reason code, which
+        the harvest counts (``n_json_*``)."""
+        return reports_reason(self.spec)
+
 
 @dataclass
 class HostPlan:
@@ -562,6 +575,13 @@ def plan_spec(spec: TransformSpec, py_fn=None):
             raise ValueError("where-exprs cannot combine with raw filters")
         if isinstance(spec.mapper, _MapUppercase):
             raise ValueError("uppercase is a raw-byte map; use payload specs")
+        if isinstance(spec.mapper, _MapProjectJson):
+            # the structural pass is the payload lane's device program; the
+            # columnar lane's columns come from the native host parser
+            raise ValueError(
+                "map_project_json runs on the payload lane: write the "
+                "filter in the v1 form (filter_contains / filter_field_eq)"
+            )
         proj = spec.mapper.fields if isinstance(spec.mapper, _MapProject) else ()
         if any(isinstance(f, (Long, Scaled)) for f in proj):
             # the exact 64-bit kinds live in the payload lane's device
@@ -586,6 +606,8 @@ def plan_spec(spec: TransformSpec, py_fn=None):
         # to 3; columnar requires an exact integer) and deployed spec JSON
         # must not change outputs across an upgrade. v2 columnar projection
         # is opted into by writing a where() stage.
+        return PayloadPlan(spec)
+    if isinstance(spec.mapper, _MapProjectJson):
         return PayloadPlan(spec)
     return HostPlan(spec, "identity")
 

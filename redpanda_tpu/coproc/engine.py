@@ -60,11 +60,12 @@ from redpanda_tpu.ops.pipeline import (
     IN_META,
     make_packed_pipeline,
     make_packed_pipeline_host,
+    unpack_reason,
     unpack_result,
 )
 
 logger = logging.getLogger("rptpu.coproc.engine")
-from redpanda_tpu.ops.transforms import TransformSpec
+from redpanda_tpu.ops.transforms import JSON_MALFORMED, JSON_PATH_MISS, TransformSpec
 from redpanda_tpu.coproc import (
     batch_codec,
     colcache,
@@ -362,6 +363,13 @@ class _Launch:
         self._park_staged()
         out, out_len, keep = unpack_result(packed, self.r_out)
         n = len(self.fits)
+        if eng is not None and getattr(self._plan, "structural", False):
+            # a structural program says why it dropped a row, beside the
+            # keep column; an oversize row (staged empty) is n_oversize_rows'
+            why = np.bincount(unpack_reason(packed, self.r_out)[:n][self.fits], minlength=3)
+            eng._stat_add("n_json_rows", float(n))
+            eng._stat_add("n_json_malformed_rows", float(why[JSON_MALFORMED]))
+            eng._stat_add("n_json_path_miss_rows", float(why[JSON_PATH_MISS]))
         return out[:n], out_len[:n], keep[:n] & self.fits
 
     def _payload_host_fallback(self) -> np.ndarray:
@@ -1745,6 +1753,8 @@ class TpuEngine:
                 probes.coproc_kept_rows.inc(v)
             elif key == "bytes_out":
                 probes.coproc_output_bytes.inc(v)
+            elif key in probes.coproc_json_rows:
+                probes.coproc_json_rows[key].inc(v)
             elif key in probes.coproc_uncompress:
                 probes.coproc_uncompress[key].inc(v)
             elif key in probes.coproc_seal:

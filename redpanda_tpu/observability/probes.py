@@ -189,6 +189,22 @@ coproc_uncompress = {
         "Payload bytes the explode's decompress produced",
     ),
 }
+# Rows a structural program (map_project_json) read as JSON, and of those
+# the ones it dropped, by reason: counted by the harvest from the reason
+# byte of the result row; keyed by the engine's stats() name.
+coproc_json_rows = {
+    key: registry.counter(
+        "coproc_json_rows_total",
+        "Rows a launch ran through a structural (JSON read as JSON) program, and "
+        "those it dropped as not one sound JSON object or for a path it could not read",
+        outcome=outcome,
+    )
+    for key, outcome in (
+        ("n_json_rows", "read"),
+        ("n_json_malformed_rows", "malformed"),
+        ("n_json_path_miss_rows", "path_miss"),
+    )
+}
 # The seal (TpuEngine._seal_jobs): output batches sealed and the crossings
 # that served them (one a native call that sealed a launch's batches, one a
 # batch on the per-batch road); keyed by the engine's stats() name.
@@ -549,6 +565,7 @@ __all__ = [
     "coproc_harvest_padded",
     "coproc_host_pool_busy",
     "coproc_input_wait_hist",
+    "coproc_json_rows",
     "coproc_kept_rows",
     "coproc_launch_rows_hist",
     "coproc_leakwatch_imbalance",

@@ -42,6 +42,7 @@ from redpanda_tpu.ops.transforms import (
     compile_transform,
     compile_transform_host,
     packbits,
+    reports_reason,
     transform_out_width,
 )
 
@@ -49,7 +50,8 @@ from redpanda_tpu.ops.transforms import (
 # then 4 pad bytes (keeps the row 8-byte aligned for the host packer).
 IN_META = 8
 # Trailing metadata columns of the packed output row: int32 LE out_len,
-# uint8 keep flag, 3 pad bytes.
+# uint8 keep flag, uint8 reason (why the row was dropped, of a spec that
+# ``reports_reason``: transforms.JSON_*; 0 from every other), 2 pad bytes.
 OUT_META = 8
 
 
@@ -73,7 +75,9 @@ def _packed_body(
     (jax.numpy on the device, numpy for the engine's host fallback).
     ``scope``: ``jax.named_scope`` for the device program, whose name
     (``jit_rp_payload_transform``) is this function's. ``mask_only``: the
-    result is the keep mask alone, bit-packed (uint8 [N/8])."""
+    result is the keep mask alone, bit-packed (uint8 [N/8]). A ``tfn``
+    compiled ``with_reason`` gives a fourth result, which rides in the
+    first of the three trailing bytes that were padding."""
 
     def rp_payload_transform(staged):
         with scope("parse"):
@@ -81,7 +85,7 @@ def _packed_body(
             c = staged[:, r_in : r_in + 4].astype(xp.int32)
             lens = c[:, 0] | (c[:, 1] << 8) | (c[:, 2] << 16) | (c[:, 3] << 24)
         with scope("transform"):
-            out, out_len, keep = tfn(data, lens)
+            out, out_len, keep, *reason = tfn(data, lens)
         with scope("frame"):
             if mask_only:
                 return packbits(xp, keep)
@@ -91,8 +95,9 @@ def _packed_body(
                 axis=1,
             )
             keepb = keep.astype(xp.uint8)[:, None]
-            pad = xp.zeros((out.shape[0], OUT_META - 5), dtype=xp.uint8)
-            return xp.concatenate([out, lenb, keepb, pad], axis=1)
+            meta = [lenb, keepb] + [why[:, None] for why in reason]
+            pad = xp.zeros((out.shape[0], OUT_META - 5 - len(reason)), dtype=xp.uint8)
+            return xp.concatenate([out, *meta, pad], axis=1)
 
     return rp_payload_transform
 
@@ -100,7 +105,7 @@ def _packed_body(
 @functools.lru_cache(maxsize=64)
 def _packed_pipeline_cached(spec_json: str, r_in: int, mask_only: bool):
     spec = TransformSpec.from_json(spec_json)
-    tfn = compile_transform(spec, r_in)
+    tfn = compile_transform(spec, r_in, reports_reason(spec) and not mask_only)
     r_out = transform_out_width(spec, r_in)
     body = _packed_body(jnp, tfn, r_in, jax.named_scope, mask_only)
     return jax.jit(body), r_out
@@ -119,7 +124,9 @@ def make_packed_pipeline_host(
     the engine's payload-lane host fallback."""
     import numpy as np
 
-    tfn = compile_transform_host(spec, int(r_in))
+    tfn = compile_transform_host(
+        spec, int(r_in), reports_reason(spec) and not mask_only
+    )
     return _packed_body(np, tfn, int(r_in), mask_only=mask_only)
 
 
@@ -156,3 +163,9 @@ def unpack_result(packed, r_out: int):
     out_len = packed[:, r_out : r_out + 4].copy().view(np.int32).reshape(-1)
     keep = packed[:, r_out + 4].astype(bool)
     return out, out_len, keep
+
+
+def unpack_reason(packed, r_out: int):
+    """The reason column of a fetched packed result (uint8 [N]): why each
+    row of a spec that ``reports_reason`` was dropped, 0 for a kept one."""
+    return packed[:, r_out + 5]

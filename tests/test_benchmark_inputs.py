@@ -16,7 +16,10 @@ properties of that file's ``test_generator_contract`` are written for the
 ``{"level", "code", "msg", "pad"}`` document of ~1 KB, so the case here
 calls that function for every generator it fits and holds the same two
 properties in NEXmark's own terms; the stream's shapes (proportions, sizes,
-price law, timestamps) follow.
+price law, timestamps) follow. PR 40 added a third generator
+(``docs_nobench.make_objects``, configuration ``nobench64p-q2``) the same
+way: the five points of the contract, then NoBench's shapes in their own
+terms.
 """
 
 import collections
@@ -56,21 +59,51 @@ NEXMARK_FIELDS = {
 }
 
 
+NOBENCH = "docs_nobench.make_objects"
+NOBENCH_CONFIG = os.path.join("benchmarks", "configs", "nobench64p-q2.json")
+NOBENCH_FIELDS = ["str1", "str2", "num", "bool", "dyn1", "dyn2", "nested_arr", "nested_obj"]
+OWN_SHAPES = {NEXMARK: (NEXMARK_CONFIG, 400), NOBENCH: (NOBENCH_CONFIG, 600)}
+
+
+def _nobench_object(v: bytes, n: int) -> dict:
+    """One object of ``docs_nobench.py`` held to NoBench's shape; returns it."""
+    assert b"\\" not in v and json.dumps(json.loads(v)).encode() == v  # ", " and ": ", ASCII
+    doc = json.loads(v)
+    first = n % 100 * 10
+    assert list(doc) == NOBENCH_FIELDS + [f"sparse_{first + k:03d}" for k in range(10)] + ["thousandth"]
+    assert doc["num"] == n and doc["thousandth"] == n % 1000 and type(doc["bool"]) is bool
+    assert list(doc["nested_obj"]) == ["str", "num"] and doc["nested_obj"]["num"] == n ^ 1
+    assert type(doc["dyn1"]) in (int, str) and type(doc["dyn2"]) in (int, str, bool)
+    assert 0 <= len(doc["nested_arr"]) <= 7 and all(type(w) is str for w in doc["nested_arr"])
+    strings = [doc["str1"], doc["str2"], doc["nested_obj"]["str"]] + [
+        doc[k] for k in doc if k.startswith("sparse_")]
+    assert all(len(s) in (8, 16, 24, 32) and set(s) <= set("ABCDEFGHIJKLMNOPQRSTUVWXYZ234567")
+               for s in strings)
+    # the collision the structural read exists for: the top-level num comes first
+    assert v.index(b'"num": ') < v.index(b'"nested_obj": ') < v.rindex(b'"num": ')
+    return doc
+
+
 @pytest.mark.parametrize("generator", bench.GENERATORS)
 @pytest.mark.parametrize("prop", ["bytes", "seed_alone", "independent_of_only",
                                   "inside_the_stated_widths", "fields_in_order"])
 def test_generator_contract(generator, prop):
-    if generator != NEXMARK or prop in ("bytes", "seed_alone", "independent_of_only"):
+    if generator not in OWN_SHAPES or prop in ("bytes", "seed_alone", "independent_of_only"):
         return bench.test_generator_contract(generator, prop)
     a = bench._make(generator, 2**31 + 11, 8, 64)
     if prop == "inside_the_stated_widths":
-        d = bench.load(NEXMARK_CONFIG)["documents"]
+        config, too_narrow = OWN_SHAPES[generator]
+        d = bench.load(config)["documents"]
         stream = {"seed": 2**31 + 11, "partitions": 8, "records_per_partition": 64}
         bench.loadgen.check_documents(a, d, stream, None)
         with pytest.raises(bench.wire.InputShapeError, match="documents.generator"):
-            bench.loadgen.check_documents(a, {**d, "bytes_max": 400}, stream, None)
+            bench.loadgen.check_documents(a, {**d, "bytes_max": too_narrow}, stream, None)
         with pytest.raises(bench.wire.InputShapeError, match="documents.generator"):
             bench.loadgen.check_documents({**a, 2: a[2][:-1]}, d, stream, None)
+    elif generator == NOBENCH:
+        for p, part in a.items():
+            for i, v in enumerate(part):
+                _nobench_object(v, p * 64 + i)
     else:
         for p, part in a.items():
             for i, v in enumerate(part):
@@ -126,6 +159,51 @@ def test_the_nexmark_configuration_feeds_the_sources_shapes():
     bids = [json.loads(v) for part in values.values() for v in part if v[14:15] == b"2"]
     assert 0.45 < sum(b["auction"] % 100 == 0 for b in bids) / len(bids) < 0.56
     assert 0.70 < sum(b["bidder"] % 100 == 1 for b in bids) / len(bids) < 0.80
+
+
+def test_the_nobench_configuration_feeds_the_sources_shapes():
+    """Configuration ``nobench64p-q2``: its generator gives NoBench's
+    object (the ten sparse keys of one cluster, ``thousandth == num % 1000``,
+    the ``nested_obj`` of object ``n XOR 1``, a stock library's separators,
+    every value one that ``json.loads`` reads), at the sizes the
+    configuration states, and its reference keeps every object and recovers
+    its number (point 5 of the contract, which ``transform_rate`` counts
+    by)."""
+    c = bench.load(NOBENCH_CONFIG)
+    assert c["documents"]["generator"] == NOBENCH and c["records_per_batch"] == 32
+    assert c["producer"] == {"compression": "none"} and c["broker_properties"] == {"coproc_enable": "true"}
+    assert c["reduced"] == ["brokers", "replication"] and len(c["source"]) <= 200
+    per = 1000
+    stream = {"seed": 2**31 + 5, "partitions": 4, "records_per_partition": per}
+    values = bench.loadgen.document_source(c["documents"])(stream)
+    ref, params = bench.loadgen.load_reference(c["reference"]["name"]), c["reference"]["params"]
+    docs, sizes = [], []
+    for p, part in values.items():
+        outs = [ref.reference(v, **params) for v in part]
+        assert all(len(o) == 70 for o in outs)  # every object kept
+        assert [ref.sequence(o) for o in outs] == [p * per + i for i in range(per)]
+        for i, v in enumerate(part):
+            doc = _nobench_object(v, p * per + i)
+            partner = json.loads(part[i ^ 1])
+            assert doc["nested_obj"] == {"str": partner["str1"], "num": partner["num"]}
+            assert outs[i][2 : 2 + len(partner["str1"])] == partner["str1"].encode()
+            docs.append(doc)
+            sizes.append(len(v))
+    d = c["documents"]
+    assert d["bytes_min"] <= min(sizes) and max(sizes) <= d["bytes_max"] < 1024
+    assert 600 <= statistics.mean(sizes) <= 670
+    # any one sparse attribute is in 1% of the objects; the dynamic types' shares
+    assert sum("sparse_110" in doc for doc in docs) == len(docs) // 100
+    assert 0.93 < sum(type(doc["dyn1"]) is int for doc in docs) / len(docs) < 0.97
+    kinds = collections.Counter(type(doc["dyn2"]) for doc in docs)
+    assert all(0.29 < kinds[k] / len(docs) < 0.38 for k in (int, str, bool))
+    lengths = collections.Counter(len(doc["nested_arr"]) for doc in docs)
+    assert sorted(lengths) == list(range(8)) and all(0.09 < n / len(docs) < 0.16 for n in lengths.values())
+    words = collections.Counter(w for doc in docs for w in doc["nested_arr"])
+    assert len(words) == 50 and max(words.values()) < 3 * min(words.values())
+    # an odd partition size has no partner for its last object: refused, not wrapped
+    with pytest.raises(ValueError, match="even"):
+        bench.loadgen.document_source(c["documents"])({**stream, "records_per_partition": 7})
 
 
 def test_the_pinned_cells_are_the_manifests_uncompressed_cells():
