@@ -365,6 +365,101 @@ def main() -> int:
         else:
             check("seal_many without libzstd serves no zstd job", seal(4, 1 << 16)[0] == -1)
 
+    # ---- the log's framing, a list of batches a call, against the Python
+    # append (models/record.py with_base_offset(..).encode_internal(): the
+    # stated header with the assigned base offset and the header CRC over
+    # the 57 little-endian bytes after it, then the payload) and against
+    # verify_kafka_crc (the Kafka CRC over the big-endian prefix + payload)
+    if hasattr(dll, "rp_frame_internal_many"):
+        import threading
+
+        dll.rp_frame_internal_many.restype = ctypes.c_int64
+        dll.rp_frame_internal_many.argtypes = [
+            ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int32, ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+        ]
+        pack = "<IiqbiHiqqqhii"
+
+        def sealed(payload, btype, attrs, delta, ts0, ts1, pid, epoch, seq, count):
+            crc = crc32c_ref(struct.pack(">Hiqqqhii", attrs, delta, ts0, ts1, pid, epoch, seq, count) + payload)
+            return [0xDEAD, 61 + len(payload), 99, btype, crc - (1 << 32) if crc >> 31 else crc,
+                    attrs, delta, ts0, ts1, pid, epoch, seq, count]
+
+        def py_frame(fields, payload, base):
+            fields = fields[:2] + [base] + fields[3:]
+            header_crc = crc32c_ref(struct.pack("<" + pack[2:], *fields[1:]))
+            return struct.pack(pack, header_crc, *fields[1:]) + payload, header_crc
+
+        bodies = [b"", b"x", bytes(range(256)) * 12, b"\xff" * 61, b"tail" * 700]
+        heads = [
+            sealed(bodies[0], 1, 0, 0, 0, 0, -1, -1, -1, 0),
+            sealed(bodies[1], 1, 4, 0, 1700000000000, 1700000000000, -1, -1, -1, 1),
+            sealed(bodies[2], 5, 0x14, 31, 1700000000001, 1700000000032, 7, 3, 100, 32),
+            sealed(bodies[3], 1, 0xFFFF, 2**31 - 2, -1, 2**62, 2**62, -2, 2**31 - 1, 2**31 - 1),
+            sealed(bodies[4], 1, 4, 6, 5, 11, -1, -1, -1, 7),
+        ]
+        nb = len(bodies)
+
+        def frame(hs, first, verify, cap=None, payloads=bodies):
+            joined = b"".join(struct.pack(pack, *h) for h in hs)
+            total = sum(h[1] for h in hs)
+            cap = total if cap is None else cap
+            dst = ctypes.create_string_buffer(max(cap, 1))
+            out = (ctypes.c_int64 * len(hs))()
+            rc = dll.rp_frame_internal_many(
+                joined, (ctypes.c_char_p * len(hs))(*payloads), len(hs), first, verify,
+                dst, cap, out)
+            return rc, dst.raw[: max(rc, 0)], list(out)
+
+        def py_frames(hs, first, skip=()):
+            blob, crcs, nxt = b"", [], first
+            for b, h in enumerate(hs):
+                if b in skip:
+                    crcs.append(-1)
+                    continue
+                f, c = py_frame(h, bodies[b], nxt)
+                blob += f
+                crcs.append(c)
+                nxt += h[6] + 1
+            return len(blob), blob, crcs
+
+        for verify in (0, 1):
+            check(f"frame_internal_many == with_base_offset().encode_internal(), verify={verify}",
+                  frame(heads, 2**40 + 5, verify) == py_frames(heads, 2**40 + 5))
+        check("frame_internal_many: one batch, as a produce appends it",
+              frame(heads[2:3], 0, 0, payloads=bodies[2:3])
+              == (heads[2][1], py_frame(heads[2], bodies[2], 0)[0], [py_frame(heads[2], bodies[2], 0)[1]]))
+        for bad in ((0,), (2,), (4,), (1, 2), tuple(range(nb))):
+            torn = [(b[:len(b) // 2] + bytes([b[len(b) // 2] ^ 1]) + b[len(b) // 2 + 1:])
+                    if i in bad and b else b for i, b in enumerate(bodies)]
+            hs = [h if (i not in bad or bodies[i]) else h[:4] + [h[4] ^ 1] + h[5:]
+                  for i, h in enumerate(heads)]  # an empty payload: tear the stated CRC
+            check(f"frame_internal_many verify leaves {bad} out, neighbours contiguous",
+                  frame(hs, 7, 1, payloads=torn) == py_frames(heads, 7, skip=bad))
+            got = frame(hs, 7, 0, payloads=torn)
+            check(f"frame_internal_many without verify frames {bad} as stated",
+                  got[0] == sum(h[1] for h in heads) and -1 not in got[2])
+        total = sum(h[1] for h in heads)
+        check("frame_internal_many dst too small: -1", frame(heads, 0, 1, cap=total - 1)[0] == -1)
+        check("frame_internal_many dst with room to spare", frame(heads, 0, 1, cap=total + 99) == py_frames(heads, 0))
+        check("frame_internal_many size_bytes under 61: -1",
+              frame([heads[0][:1] + [60] + heads[0][2:]], 0, 0, payloads=bodies[:1])[0] == -1)
+        check("frame_internal_many empty list", frame([], 3, 1, payloads=[]) == (0, b"", []))
+        want = py_frames(heads, 11)
+        results = []
+
+        def many_times():
+            results.append(all(frame(heads, 11, 1) == want for _ in range(200)))
+
+        threads = [threading.Thread(target=many_times) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        check("frame_internal_many from four threads at once", results == [True] * 4)
+    else:
+        check("rp_frame_internal_many symbol present", False)
+
     print(("PASS" if failures == 0 else f"FAIL ({failures})"))
     return 1 if failures else 0
 

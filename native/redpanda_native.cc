@@ -643,6 +643,72 @@ int64_t rp_seal_many(const uint8_t* const* payloads,
   return failed;
 }
 
+// ---------------------------------------------------------------- append
+static inline uint32_t get_le32(const uint8_t* p) {
+  return (uint32_t)p[0] | (uint32_t)p[1] << 8 | (uint32_t)p[2] << 16 |
+         (uint32_t)p[3] << 24;
+}
+
+// The log's offset-assigning append of a list of batches, one crossing a
+// list (storage/log.py): what RecordBatch.with_base_offset(..)
+// .encode_internal() writes a batch, and verify_kafka_crc() where asked.
+// heads holds the n batches' 61-byte internal headers as the batches state
+// them (RecordBatchHeader.encode; base offset and header_crc are not
+// read); payloads[b] holds the size_bytes - 61 bytes header b states (the
+// caller has checked each length). Batch b is framed at dst + pos, frames
+// back to back: its header with base offset `next` and the header_crc over
+// the 57 bytes after it, then its payload, copied once; out_header_crc[b]
+// is that header_crc and `next` moves on by last_offset_delta + 1. With
+// verify != 0 a batch whose Kafka CRC over attrs..records (the big-endian
+// header prefix, then the payload) is not the header's crc is left out:
+// out_header_crc[b] = -1, no frame, no offset taken, its neighbours
+// contiguous. Returns the bytes written, or -1, with dst nobody's, for a
+// size_bytes under 61 or a frame that dst_cap does not hold.
+// Single-threaded by design: a list is ~10 us of memcpy and CRC.
+int64_t rp_frame_internal_many(const uint8_t* heads,
+                               const uint8_t* const* payloads, int64_t n,
+                               int64_t first_base_offset, int32_t verify,
+                               uint8_t* dst, int64_t dst_cap,
+                               int64_t* out_header_crc) {
+  // attrs, last offset delta, the two timestamps, producer id / epoch /
+  // base sequence, record count: header bytes 21..61, each field reversed
+  static const int kPrefixWidths[8] = {2, 4, 8, 8, 8, 2, 4, 4};
+  int64_t pos = 0;
+  int64_t next = first_base_offset;
+  for (int64_t b = 0; b < n; b++) {
+    const uint8_t* h = heads + 61 * b;
+    int64_t size = (int64_t)(int32_t)get_le32(h + 4);
+    if (size < 61 || size > dst_cap - pos) return -1;
+    size_t len = (size_t)(size - 61);
+    if (verify) {
+      uint8_t be[40];
+      uint8_t* p = be;
+      const uint8_t* f = h + 21;
+      for (int w : kPrefixWidths) {
+        for (int i = 0; i < w; i++) p[i] = f[w - 1 - i];
+        p += w;
+        f += w;
+      }
+      uint32_t c = rp_crc32c_update(0xFFFFFFFFu, be, sizeof be);
+      c = rp_crc32c_update(c, payloads[b], len) ^ 0xFFFFFFFFu;
+      if (c != get_le32(h + 17)) {
+        out_header_crc[b] = -1;
+        continue;
+      }
+    }
+    uint8_t* o = dst + pos;
+    memcpy(o, h, 61);
+    put_le(o + 8, (uint64_t)next, 8);
+    uint32_t header_crc = rp_crc32c(o + 4, 57);
+    put_le(o, header_crc, 4);
+    memcpy(o + 61, payloads[b], len);
+    out_header_crc[b] = (int64_t)header_crc;
+    next += (int64_t)(int32_t)get_le32(h + 23) + 1;
+    pos += size;
+  }
+  return pos;
+}
+
 // Build a records payload from kept transform outputs: record i (where
 // keep[i] != 0) becomes {attrs=0, ts_delta=0, offset_delta=seq, key=null,
 // value=rows[i][:lens[i]], headers=0}. Writes payload to dst (caller sizes

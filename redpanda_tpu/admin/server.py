@@ -659,7 +659,10 @@ class AdminServer:
                 {"enabled": False, "hint": "coproc_enable is false"}
             )
         from redpanda_tpu import native
-        from redpanda_tpu.observability.probes import coproc_tick_hist
+        from redpanda_tpu.observability.probes import (
+            coproc_tick_hist,
+            storage_append_crossing_batches_hist,
+        )
 
         stats = api.engine.stats()
         return web.json_response({
@@ -679,6 +682,14 @@ class AdminServer:
                 "ticks": coproc_tick_hist["tick"].hist.count,
                 "read_us": coproc_tick_hist["read"].hist.sum,
                 "read_hidden_us": coproc_tick_hist["read_hidden"].hist.sum,
+            },
+            # the log's offset-assigning appends (every partition's: produce,
+            # materialized write): batches framed over the framing calls
+            # that framed them, one native crossing a list where the native
+            # library serves, a call a batch on the per-batch loop
+            "append": {
+                "framings": storage_append_crossing_batches_hist.hist.count,
+                "batches": storage_append_crossing_batches_hist.hist.sum,
             },
             "breaker": stats.pop("breaker", None),
             # multi-chip meshrunner block surfaced explicitly (devices,

@@ -454,6 +454,14 @@ async def cmd_debug(args) -> int:
                 f"({ra['ticks']} ticks; ~0% on a live stream, which leaves no "
                 f"backlog to read ahead of)"
             )
+        ap = body.get("append") or {}
+        if ap.get("framings"):
+            # storage_append_crossing_batches: _sum over _count
+            print(
+                f"append:  {ap['batches'] / ap['framings']:.1f} batches a framing "
+                f"call of the log's appends ({ap['framings']} calls; one native "
+                f"crossing frames a list, the per-batch loop reads 1.0)"
+            )
         mesh = body.get("mesh")
         if mesh:
             print(
@@ -1140,7 +1148,9 @@ def build_parser() -> argparse.ArgumentParser:
              "explode*, pack, dispatch > h2d, fetch > wait_h2d + wait_program "
              "+ wait_d2h, rebuild / frame_gather, seal, ...); read-ahead: the "
              "share of the pacemaker's read hidden under the previous tick's "
-             "engine phase (coproc_tick_latency_us phase=read_hidden over read)",
+             "engine phase (coproc_tick_latency_us phase=read_hidden over read); "
+             "append: batches a framing call of the log's appends "
+             "(storage_append_crossing_batches)",
     )
     dc.add_argument("--json", action="store_true", help="raw JSON, no rendering")
     dres = dsub.add_parser(

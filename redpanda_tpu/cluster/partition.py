@@ -76,8 +76,10 @@ class DirectConsensus:
     def start_offset(self) -> int:
         return self.log.offsets().start_offset
 
-    async def replicate(self, batches: list[RecordBatch], level: int) -> ReplicateResult:
-        res = await self.log.append(batches, term=self._term)
+    async def replicate(
+        self, batches: list[RecordBatch], level: int, *, verify_crc: bool = False
+    ) -> ReplicateResult:
+        res = await self.log.append(batches, term=self._term, verify_crc=verify_crc)
         self._commit_monitor.notify(res.last_offset)
         if level == ConsistencyLevel.quorum_ack:
             await self.log.flush()
@@ -226,8 +228,15 @@ class Partition:
         return None
 
     # -------------------------------------------------------------- io
-    async def replicate(self, batches: list[RecordBatch], level: int) -> ReplicateResult:
-        res = await self.consensus.replicate(batches, level)
+    async def replicate(
+        self, batches: list[RecordBatch], level: int, *, verify_crc: bool = False
+    ) -> ReplicateResult:
+        """``verify_crc``: have the log leave out a batch whose Kafka CRC
+        does not match (``DiskLog.append``). A direct log's to give: the
+        materialized write asks for it, and raft's replicate takes no such
+        argument."""
+        asked = {"verify_crc": True} if verify_crc else {}
+        res = await self.consensus.replicate(batches, level, **asked)
         base = getattr(res, "base_offset", None)
         if base is None:
             # raft's ReplicateResult carries only last_offset; offsets are

@@ -548,7 +548,9 @@ class ScriptContext:
 
     async def _write_materialized(self, source: NTP, batches: list) -> bool:
         """do_write_materialized_partition (script_context_backend.cc:40-68):
-        CRC check + append directly to the materialized log, no raft.
+        CRC check + append directly to the materialized log, no raft. The
+        check rides the append (``verify_crc``): the log leaves a batch
+        whose Kafka CRC does not match out, and says so.
         Returns True when the source's offset may advance."""
         if not batches:
             return True  # everything filtered out: the read is still acked
@@ -557,14 +559,7 @@ class ScriptContext:
         partition = await pm.ensure_materialized(source, mntp)
         if partition is None:
             return False  # create raced/failed: retry this read next tick
-        good = []
-        for b in batches:
-            if b.verify_kafka_crc():
-                good.append(b)
-            else:
-                logger.error("dropping corrupt transformed batch for %s", mntp)
-        if good:
-            await partition.replicate(good, 2)  # no_ack: direct log write
+        await partition.replicate(batches, 2, verify_crc=True)  # no_ack: direct log write
         return True
 
 

@@ -302,6 +302,23 @@ class _NativeLib:
                 + [ctypes.c_int32]
             )
             self.has_seal_many = bool(dll.rp_seal_available())
+        # the log's offset-assigning append, a list of batches a crossing.
+        # Bound through PyDLL: the call KEEPS the interpreter lock. It is
+        # microseconds of memcpy and CRC on the event loop's thread, and a
+        # CDLL call, which drops the lock, waits up to a switch interval to
+        # take it back beside a busy thread (PERF.md section 6, PR 42: a
+        # one-batch append 26 us alone, 150-170 beside a spinning thread,
+        # 30-47 with the lock kept).
+        self.has_frame_internal_many = hasattr(dll, "rp_frame_internal_many")
+        if self.has_frame_internal_many:
+            fn = ctypes.PyDLL(dll._name).rp_frame_internal_many
+            fn.restype = ctypes.c_int64
+            fn.argtypes = [
+                ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
+                ctypes.c_int64, ctypes.c_void_p,
+            ]
+            self._frame_internal_many = fn
         dll.rp_json_find.restype = ctypes.c_int32
         dll.rp_json_find.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int32,
@@ -806,6 +823,36 @@ class _NativeLib:
         if failed < 0:
             return None
         return out_off, out_len, out_attrs, crc, header_crc
+
+    def frame_internal_many(
+        self, heads: bytes, payloads: list[bytes], nbytes: int,
+        first_base_offset: int, verify: bool,
+    ) -> tuple[bytearray, list[int]]:
+        """The internal frames of a list of batches, back to back, with
+        base offsets assigned from ``first_base_offset`` on, in ONE
+        crossing (rp_frame_internal_many): what
+        ``RecordBatch.with_base_offset(..).encode_internal()`` writes a
+        batch. ``heads``: the batches' 61-byte headers as they state them
+        (``RecordBatchHeader.encode``), joined; ``payloads``: their
+        ``bytes``, each as long as its header's ``size_bytes`` less 61 (the
+        crossing reads that many: the CALLER checks); ``nbytes``: the sum
+        of the ``size_bytes``. Returns ``(frames, header_crcs)``: one
+        ``header_crc`` a batch, or -1 for a batch that ``verify`` left out
+        (its Kafka CRC does not match: no frame, no offset)."""
+        n = len(payloads)
+        if len(heads) != 61 * n:
+            raise ValueError("one 61-byte header a payload")
+        frames = bytearray(nbytes)
+        header_crcs = (ctypes.c_int64 * n)()
+        written = self._frame_internal_many(
+            heads, (ctypes.c_char_p * n)(*payloads), n, first_base_offset,
+            verify, ctypes.byref(ctypes.c_char.from_buffer(frames)), nbytes,
+            header_crcs,
+        )
+        if written < 0:
+            raise ValueError("frames do not fit nbytes, or a size_bytes under 61")
+        del frames[written:]
+        return frames, header_crcs[:]
 
     def explode_find(
         self,

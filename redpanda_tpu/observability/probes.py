@@ -25,6 +25,13 @@ from redpanda_tpu.observability.trace import tracer
 storage_append_hist = registry.histogram(
     "storage_append_latency_us", "Storage log append latency (us)"
 )
+# One sample per framing of an append: the batches one native crossing
+# framed (DiskLog._append_framed), 1 for a batch the per-batch loop
+# encoded (no native library, a follower's append). Batches, not us.
+storage_append_crossing_batches_hist = registry.histogram(
+    "storage_append_crossing_batches",
+    "Batches framed into the log by one framing call of an append",
+)
 storage_read_hist = registry.histogram(
     "storage_read_latency_us",
     "Storage log read latency, lock wait included (us)",
@@ -590,6 +597,7 @@ __all__ = [
     "observe_us",
     "raft_replicate_hist",
     "rpc_request_hist",
+    "storage_append_crossing_batches_hist",
     "storage_append_hist",
     "storage_flush_hist",
     "storage_housekeeping_hist",

@@ -15,13 +15,19 @@ slice directly out of the read blob.
 from __future__ import annotations
 
 import asyncio
+import logging
 import os
 import time
 from dataclasses import dataclass, field
 
+from redpanda_tpu import native
 from redpanda_tpu.finjector import honey_badger
 from redpanda_tpu.models.fundamental import NTP
-from redpanda_tpu.models.record import RecordBatch
+from redpanda_tpu.models.record import (
+    INTERNAL_HEADER_SIZE,
+    RecordBatch,
+    RecordBatchHeader,
+)
 from redpanda_tpu.observability import probes, stages
 from redpanda_tpu.storage.segment import Segment
 from redpanda_tpu.storage.recovery import recover_segment
@@ -30,6 +36,8 @@ from redpanda_tpu.storage.recovery import recover_segment
 # log_failure_probes {append, roll, truncate}, driven over the admin
 # honey-badger API like tests/rptest services/honey_badger.py)
 honey_badger.register_probe("storage", "log_append", "log_roll", "log_truncate")
+
+logger = logging.getLogger("rptpu.storage")
 
 
 @dataclass
@@ -160,9 +168,17 @@ class DiskLog:
 
     # ------------------------------------------------------------ append
     async def append(
-        self, batches: list[RecordBatch], *, term: int | None = None, assign_offsets: bool = True
+        self,
+        batches: list[RecordBatch],
+        *,
+        term: int | None = None,
+        assign_offsets: bool = True,
+        verify_crc: bool = False,
     ) -> AppendResult:
-        """Append sealed batches; assigns monotone base offsets by default."""
+        """Append sealed batches; assigns monotone base offsets by default.
+        ``verify_crc``: a batch whose Kafka CRC does not match its header's
+        is logged and left out, takes no offset, and its neighbours land
+        with contiguous offsets."""
         if not batches:
             off = self.offsets()
             return AppendResult(off.dirty_offset + 1, off.dirty_offset, 0)
@@ -182,24 +198,34 @@ class DiskLog:
             )
         t0 = stages.begin("storage.append")
         try:
-            return await self._append_locked(batches, term, assign_offsets)
+            return await self._append_locked(batches, term, assign_offsets, verify_crc)
         finally:
             if acct is not None:
                 acct.release(reserved)
             stages.close("storage.append", probes.storage_append_hist, t0)
 
     async def _append_locked(
-        self, batches: list[RecordBatch], term: int | None, assign_offsets: bool
+        self,
+        batches: list[RecordBatch],
+        term: int | None,
+        assign_offsets: bool,
+        verify_crc: bool,
     ) -> AppendResult:
         async with self._lock:
             honey_badger.inject_sync("storage", "log_append")
             if term is not None and term > self._term:
                 self._term = term
             seg = self._active_segment_for_append()
+            framed = self._frame(batches, seg.dirty_offset + 1, verify_crc) if assign_offsets else None
+            if framed is not None:
+                return self._append_framed(seg, batches, *framed)
             next_offset = seg.dirty_offset + 1
             first = None
             size = 0
             for batch in batches:
+                if verify_crc and not batch.verify_kafka_crc():
+                    logger.error("dropping corrupt batch for %s", self.ntp)
+                    continue
                 if assign_offsets:
                     batch = batch.with_base_offset(next_offset)
                     batch.header.term = self._term
@@ -218,18 +244,93 @@ class DiskLog:
                 seg = self._segment_for_term(seg, batch.header.term)
                 seg = self._maybe_roll(seg)
                 seg.append(batch)
-                # hot tail into the cache: fetch-after-produce never touches
-                # the segment file (batch_cache put-on-append)
-                self._cache_put(batch)
+                probes.storage_append_crossing_batches_hist.record(1)
                 size += batch.size_bytes
                 next_offset = batch.last_offset + 1
-                for fn in self.append_listeners:
-                    fn(batch.header.type, batch.base_offset, batch.last_offset)
+                self._appended(batch)
             if self.config.fsync_on_append:
                 seg.fsync()
                 self._committed = seg.dirty_offset
             last = next_offset - 1
             return AppendResult(first if first is not None else last + 1, last, size)
+
+    def _appended(self, batch: RecordBatch) -> None:
+        # hot tail into the cache: fetch-after-produce never touches
+        # the segment file (batch_cache put-on-append)
+        self._cache_put(batch)
+        for fn in self.append_listeners:
+            fn(batch.header.type, batch.base_offset, batch.last_offset)
+
+    @staticmethod
+    def _frame(batches: list[RecordBatch], first_offset: int, verify_crc: bool):
+        """The list's internal frames with offsets assigned from
+        ``first_offset`` on, in one native crossing
+        (``native.frame_internal_many``); None where the per-batch loop has
+        to serve: no native library, or a payload that is not the ``bytes``
+        its header sizes."""
+        lib = native.lib
+        if lib is None or not lib.has_frame_internal_many:
+            return None
+        heads, payloads = [], []
+        nbytes = 0
+        for batch in batches:
+            header, payload = batch.header, batch.payload
+            if type(payload) is not bytes or len(payload) + INTERNAL_HEADER_SIZE != header.size_bytes:
+                return None
+            heads.append(header.encode())
+            payloads.append(payload)
+            nbytes += header.size_bytes
+        return lib.frame_internal_many(
+            b"".join(heads), payloads, nbytes, first_offset, verify_crc
+        )
+
+    def _append_framed(
+        self, seg: Segment, batches: list[RecordBatch], frames: bytearray, header_crcs: list[int]
+    ) -> AppendResult:
+        """Land a framed list (``_frame``): the bytes go to a segment in one
+        piece, a roll in mid-list splits them at the roll; what stays per
+        batch is the ``RecordBatch`` the cache and the listeners see, the
+        index and the roll check. The files are the per-batch loop's, byte
+        for byte."""
+        term = self._term
+        seg = self._segment_for_term(seg, term)
+        first = next_offset = seg.dirty_offset + 1
+        landed = pos = 0  # frames[landed:pos] are tracked by seg, not yet its bytes
+        try:
+            for batch, header_crc in zip(batches, header_crcs):
+                if header_crc < 0:
+                    logger.error("dropping corrupt batch for %s", self.ntp)
+                    continue
+                if self._should_roll(seg):
+                    seg.write_tracked(memoryview(frames)[landed:pos])
+                    landed = pos
+                    seg = self._roll(seg)
+                h = batch.header
+                batch = RecordBatch(
+                    RecordBatchHeader(
+                        header_crc, h.size_bytes, next_offset, h.type, h.crc,
+                        h.attrs, h.last_offset_delta, h.first_timestamp,
+                        h.max_timestamp, h.producer_id, h.producer_epoch,
+                        h.base_sequence, h.record_count, term,
+                    ),
+                    batch.payload,
+                )
+                seg.track(batch)
+                pos += h.size_bytes
+                next_offset += h.last_offset_delta + 1
+                self._appended(batch)
+        finally:
+            # whatever ended the loop, the segment holds what it tracked:
+            # the whole of `frames` handed over where no roll split it
+            whole = landed == 0 and pos == len(frames)
+            seg.write_tracked(frames if whole else memoryview(frames)[landed:pos])
+        probes.storage_append_crossing_batches_hist.record(
+            len(header_crcs) - header_crcs.count(-1)
+        )
+        if self.config.fsync_on_append:
+            seg.fsync()
+            self._committed = seg.dirty_offset
+        return AppendResult(first, next_offset - 1, pos)
 
     def _active_segment_for_append(self) -> Segment:
         if not self.segments or not self.segments[-1].writable:
@@ -258,20 +359,22 @@ class DiskLog:
         self._active_created_at = time.monotonic()
         return new
 
-    def _maybe_roll(self, seg: Segment) -> Segment:
-        too_big = seg.size_bytes >= self.config.max_segment_size
-        too_old = (
+    def _should_roll(self, seg: Segment) -> bool:
+        return seg.size_bytes >= self.config.max_segment_size or (
             seg.size_bytes > 0
             and (time.monotonic() - self._active_created_at) >= self.config.segment_age_s
         )
-        if too_big or too_old:
-            honey_badger.inject_sync("storage", "log_roll")
-            seg.release_appender()
-            new = Segment(self.dir, seg.dirty_offset + 1, self._term).create()
-            self.segments.append(new)
-            self._active_created_at = time.monotonic()
-            return new
-        return seg
+
+    def _roll(self, seg: Segment) -> Segment:
+        honey_badger.inject_sync("storage", "log_roll")
+        seg.release_appender()
+        new = Segment(self.dir, seg.dirty_offset + 1, self._term).create()
+        self.segments.append(new)
+        self._active_created_at = time.monotonic()
+        return new
+
+    def _maybe_roll(self, seg: Segment) -> Segment:
+        return self._roll(seg) if self._should_roll(seg) else seg
 
     async def flush(self):
         async with self._lock:
@@ -407,8 +510,6 @@ class DiskLog:
                 at = 0
                 new_dirty = seg.base_offset - 1
                 new_max_ts = -1
-                from redpanda_tpu.models.record import INTERNAL_HEADER_SIZE
-
                 while at + INTERNAL_HEADER_SIZE <= len(blob):
                     batch, consumed = RecordBatch.decode_internal(blob, at)
                     if batch.last_offset >= offset:

@@ -28,7 +28,9 @@ class MemLog:
         dirty = self._batches[-1].last_offset if self._batches else self._start_offset - 1
         return LogOffsets(self._start_offset, dirty, dirty)
 
-    async def append(self, batches, *, term=None, assign_offsets: bool = True) -> AppendResult:
+    async def append(
+        self, batches, *, term=None, assign_offsets: bool = True, verify_crc: bool = False
+    ) -> AppendResult:
         if term is not None:
             self._term = max(self._term, term)
         off = self.offsets()
@@ -36,6 +38,8 @@ class MemLog:
         first = None
         size = 0
         for batch in batches:
+            if verify_crc and not batch.verify_kafka_crc():
+                continue  # left out, no offset taken (DiskLog.append)
             if assign_offsets:
                 batch = batch.with_base_offset(next_offset)
                 batch.header.term = self._term

@@ -166,14 +166,32 @@ class Segment:
 
     # ------------------------------------------------------------ append
     def append(self, batch: RecordBatch) -> None:
+        self.track(batch)
+        self.write_tracked(batch.encode_internal())
+
+    def track(self, batch: RecordBatch) -> None:
+        """Account for `batch` as the next frame of this segment: index,
+        size, dirty offset, newest timestamp. Its bytes are owed:
+        `write_tracked` takes the frames of one or many tracked batches,
+        in their order, before anything reads or closes the segment."""
         assert self._file is not None, "segment not writable"
-        encoded = batch.encode_internal()
+        header = batch.header
         # this batch's file position == bytes appended so far (incl. buffered)
-        self.index.maybe_track(batch.header, self.size_bytes)
-        self._buf += encoded
-        self.size_bytes += len(encoded)
-        self.dirty_offset = batch.last_offset
-        self.max_timestamp = max(self.max_timestamp, batch.header.max_timestamp)
+        self.index.maybe_track(header, self.size_bytes)
+        self.size_bytes += header.size_bytes
+        self.dirty_offset = header.base_offset + header.last_offset_delta
+        if header.max_timestamp > self.max_timestamp:
+            self.max_timestamp = header.max_timestamp
+
+    def write_tracked(self, frames) -> None:
+        """Take the frames of the batches tracked since the last call. A
+        `bytearray` is the caller's to give away: an empty buffer adopts it
+        and copies nothing (an acks=all produce, flushed after every
+        append, always finds the buffer empty)."""
+        if not self._buf and type(frames) is bytearray:
+            self._buf = frames
+        else:
+            self._buf += frames
         if len(self._buf) >= self.APPEND_BUF_LIMIT:
             self.flush_buffer()
 

@@ -604,3 +604,63 @@ def test_rpk_debug_coproc_prints_the_read_ahead_share(tmp_path, capsys):
     body = json.loads(printed[printed.rindex("\n{\n"):])  # the --json call's
     ra = body["read_ahead"]
     assert 0 < ra["read_hidden_us"] <= ra["read_us"] and ra["ticks"] >= 2
+    # the log's appends: storage_append_crossing_batches, _sum over _count
+    assert "append:  " in printed and "batches a framing call" in printed
+    assert body["append"]["batches"] >= body["append"]["framings"] > 0
+
+
+# ------------------------------------------------------------------ (i)
+@pytest.mark.parametrize("corrupt", [(1,), (0, 1, 2)], ids=["one_of_three", "the_whole_reply"])
+def test_a_corrupt_batch_in_the_reply_is_left_out_and_the_offset_moves(tmp_path, caplog, corrupt):
+    """The materialized write's CRC re-check rides the log's append
+    (``verify_crc``): a batch of the reply whose payload no longer matches
+    its seal is left out and logged, its neighbours land with contiguous
+    offsets, and the source offset advances as it always has (the input was
+    read and transformed; the next tick must not read it again)."""
+    from dataclasses import replace
+
+    async def main():
+        storage, broker, server, api = await _start(tmp_path)
+        try:
+            engine = api.pacemaker.engine
+            ctx = await _parked(api, broker)
+            await _backlog(broker, batches=3)
+            api.pacemaker.max_batch_size = 1 << 20  # the whole backlog a read
+            real = ctx._write_materialized
+
+            async def tear_partition_1(source, batches):
+                if source.partition == 1:
+                    assert len(batches) == 3
+                    batches = [
+                        RecordBatch(
+                            replace(b.header),
+                            b.payload[:-1] + bytes([b.payload[-1] ^ 0x01]),
+                        ) if i in corrupt else b
+                        for i, b in enumerate(batches)
+                    ]
+                return await real(source, batches)
+
+            ctx._write_materialized = tear_partition_1
+            with caplog.at_level("ERROR", logger="rptpu.storage"):
+                assert await _tick_under_a_held_engine(ctx, engine) is True
+            # every partition's offset stands at the end of what was read
+            assert _drained(ctx, batches=3)
+            dropped = [r for r in caplog.records if "dropping corrupt batch" in r.getMessage()]
+            assert len(dropped) == len(corrupt)
+            assert "src.$proj$" in dropped[0].getMessage()
+            assert await ctx.tick() is False  # nothing is read again
+            got = await _materialized(broker, "proj")
+            assert [len(part) for part in got] == [3, 3 - len(corrupt), 3]
+            p1 = broker.partition_manager.get(NTP.kafka("src.$proj$", 1))
+            landed = await p1.make_reader(0, 1 << 30) if p1 is not None else []
+            expect = 0
+            for b in landed:
+                assert b.base_offset == expect and b.verify_kafka_crc()
+                expect = b.last_offset + 1
+            # the sound batches are the ones partition 0 and 2 hold in
+            # their place: same script, same documents but for the code
+            assert [b.header.record_count for b in landed] == [DOCS // 2] * len(landed)
+        finally:
+            await _stop(storage, server, api)
+
+    run(main())

@@ -819,3 +819,330 @@ def test_kvstore_opfuzz_vs_model(tmp_path):
             assert kv.get(KeySpace.storage, k) == model.get(k)
     finally:
         kv.stop()
+
+
+# ------------------------------------------------- one framing crossing a list
+# An offset-assigning append frames its list in one native crossing
+# (DiskLog._frame / _append_framed); the per-batch loop stays for a follower's
+# append and for a process with no native library. Both leave the same files.
+def _mixed(n, seed=0, ts0=1_700_000_000_000):
+    """`n` sealed batches of mixed sizes and record counts, as a reply's."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(n):
+        recs = [
+            Record(offset_delta=j, timestamp_delta=j, value=rng.bytes(int(rng.integers(1, 700))))
+            for j in range(int(rng.integers(1, 6)))
+        ]
+        out.append(RecordBatch.build(
+            recs, first_timestamp=ts0 + 10 * i, max_timestamp=ts0 + 10 * i + len(recs) - 1
+        ))
+    return out
+
+
+def _flipped(batch):
+    """`batch` with one payload byte flipped under its sealed header."""
+    from dataclasses import replace
+
+    payload = bytearray(batch.payload)
+    payload[len(payload) // 2] ^= 0x40
+    return RecordBatch(replace(batch.header), bytes(payload))
+
+
+def _withhold(monkeypatch, what):
+    from redpanda_tpu import native
+
+    if what == "crossing":
+        monkeypatch.setattr(native.lib, "has_frame_internal_many", False)
+    elif what == "native":
+        monkeypatch.setattr(native, "lib", None)
+
+
+def _crossing_samples():
+    from redpanda_tpu.observability import probes
+
+    h = probes.storage_append_crossing_batches_hist.hist
+    return h.count, h.sum
+
+
+async def _write_log(base_dir, calls, *, roll=None, verify=False, manager=False):
+    """A log written by one append a (batches, term) of `calls`. Returns what
+    the calls returned, what the listeners and the cache saw, and, the log
+    closed, every file it left with its bytes."""
+    cfg = LogConfig(base_dir=str(base_dir))
+    if roll is not None:
+        cfg.max_segment_size = roll
+    ntp = NTP.kafka("framed", 0)
+    mgr = LogManager(cfg) if manager else None
+    log = await (mgr.manage(ntp) if manager else DiskLog.open(ntp, cfg))
+    seen = []
+    log.append_listeners.append(lambda *a: seen.append(a))
+    results = []
+    for batches, term in calls:
+        r = await log.append(batches, term=term, verify_crc=verify)
+        results.append((r.base_offset, r.last_offset, r.byte_size))
+    cached = []
+    if manager:
+        cached = [
+            (vars(b.header), b.payload)
+            for (_, _), b in sorted(mgr.batch_cache._lru.items(), key=lambda kv: kv[0][1])
+        ]
+    index = [[(e.rel_offset, e.file_pos, e.timestamp) for e in s.index.entries] for s in log.segments]
+    await log.close()
+    files = {}
+    for fn in sorted(os.listdir(log.dir)):
+        with open(os.path.join(log.dir, fn), "rb") as f:
+            files[fn] = f.read()
+    return {"results": results, "seen": seen, "cached": cached, "index": index, "files": files}
+
+
+@pytest.mark.skipif(
+    not getattr(__import__("redpanda_tpu.native").native.lib, "has_frame_internal_many", False),
+    reason="no native framing crossing here",
+)
+@pytest.mark.parametrize("roll", [None, 2500], ids=["one_segment", "roll_in_mid_list"])
+@pytest.mark.parametrize("n", [1, 2, 9, 64])
+def test_a_framed_append_leaves_the_per_batch_loops_files(tmp_path, monkeypatch, n, roll):
+    """Segment files and index entries byte for byte, the results, the
+    batches the cache holds (field for field what with_base_offset gave)
+    and the listeners' calls: lists of 1, 2, 9 and 64 mixed batches, a
+    roll in mid-list, a term change between calls."""
+    calls = [(_mixed(n, seed=n), 1), (_mixed(max(1, n // 2), seed=n + 100), 1),
+             (_mixed(n, seed=n + 200), 3)]
+
+    async def main():
+        before = _crossing_samples()
+        framed = await _write_log(tmp_path / "framed", calls, roll=roll, manager=True)
+        crossings = _crossing_samples()
+        # one sample a crossing, holding the list's batches
+        assert crossings[0] - before[0] == len(calls)
+        assert crossings[1] - before[1] == sum(len(b) for b, _ in calls)
+        _withhold(monkeypatch, "crossing")
+        looped = await _write_log(tmp_path / "looped", calls, roll=roll, manager=True)
+        per_batch = _crossing_samples()
+        # the loop: one sample a batch, each 1
+        assert per_batch[0] - crossings[0] == per_batch[1] - crossings[1] == sum(
+            len(b) for b, _ in calls
+        )
+        return framed, looped
+
+    framed, looped = _run(main())
+    assert sorted(framed["files"]) == sorted(looped["files"])
+    for fn in framed["files"]:
+        assert framed["files"][fn] == looped["files"][fn], fn
+    if roll is not None and n >= 9:
+        assert sum(fn.endswith(".log") for fn in framed["files"]) > 2
+    assert any(fn.endswith("-3-v1.log") for fn in framed["files"])  # the term change
+    assert framed["index"] == looped["index"]
+    assert framed["results"] == looped["results"]
+    assert framed["seen"] == looped["seen"]
+    assert framed["cached"] == looped["cached"] and framed["cached"]
+    assert all(h["term"] in (1, 3) and h["base_offset"] >= 0 for h, _ in framed["cached"])
+
+
+@pytest.mark.parametrize("how", ["crossing", "loop", "no_native"])
+@pytest.mark.parametrize("n", [9, 64])
+def test_a_framed_log_recovers_and_reads_back_what_was_appended(tmp_path, monkeypatch, how, n):
+    """recover_segment over the reopened tail and a scanned read give the
+    appended batches back, offsets assigned, whichever road framed them."""
+    _withhold(monkeypatch, {"loop": "crossing", "no_native": "native"}.get(how))
+    batches = _mixed(n, seed=7 * n)
+    cfg = LogConfig(base_dir=str(tmp_path), max_segment_size=6000)
+    ntp = NTP.kafka("framed", 0)
+
+    async def main():
+        log = await DiskLog.open(ntp, cfg)
+        r = await log.append(batches, term=2)
+        span = sum(b.header.last_offset_delta + 1 for b in batches)
+        assert (r.base_offset, r.last_offset) == (0, span - 1)
+        assert r.byte_size == sum(b.size_bytes for b in batches)
+        await log.close()
+        log = await DiskLog.open(ntp, cfg)  # recover_segment scans the tail
+        assert log.offsets().dirty_offset == span - 1
+        got = await log.read(0, max_bytes=1 << 30)
+        await log.close()
+        return got
+
+    got = _run(main())
+    assert len(got) == n
+    expect = 0
+    for b, src in zip(got, batches):
+        assert b.base_offset == expect and b.payload == src.payload
+        assert b.verify_header_crc() and b.verify_kafka_crc()
+        assert b.header.crc == src.header.crc and b.header.record_count == src.header.record_count
+        expect = b.last_offset + 1
+
+
+@pytest.mark.parametrize("how", ["crossing", "loop", "no_native"])
+@pytest.mark.parametrize("corrupt", [(0,), (4,), (8,), (2, 3), tuple(range(9))],
+                         ids=["first", "middle", "last", "two", "all"])
+def test_a_verifying_append_leaves_a_corrupt_batch_out(tmp_path, monkeypatch, caplog, how, corrupt):
+    """One payload byte flipped: the batch is left out and logged, takes no
+    offset, and its neighbours land with contiguous offsets; the files are
+    those of an append of the sound batches alone."""
+    _withhold(monkeypatch, {"loop": "crossing", "no_native": "native"}.get(how))
+    sound = _mixed(9, seed=5)
+    given = [(_flipped(b) if i in corrupt else b) for i, b in enumerate(sound)]
+    kept = [b for i, b in enumerate(sound) if i not in corrupt]
+
+    async def main():
+        with caplog.at_level("ERROR", logger="rptpu.storage"):
+            checked = await _write_log(
+                tmp_path / "checked", [(given, 1), (sound[:2], 1)], verify=True
+            )
+        plain = await _write_log(tmp_path / "plain", [(kept, 1), (sound[:2], 1)])
+        return checked, plain
+
+    checked, plain = _run(main())
+    dropped = [r for r in caplog.records if "dropping corrupt batch" in r.getMessage()]
+    assert len(dropped) == len(corrupt)
+    assert "framed/0" in dropped[0].getMessage() or "framed" in dropped[0].getMessage()
+    assert checked["files"] == plain["files"]
+    assert checked["results"] == plain["results"]
+    assert checked["seen"] == plain["seen"]
+    span = sum(b.header.last_offset_delta + 1 for b in kept)
+    assert checked["results"][0][:2] == (0, span - 1)  # nothing taken by the dropped
+    assert checked["results"][1][0] == span  # the next append follows on
+
+
+def test_an_append_that_does_not_verify_lands_a_corrupt_batch_as_before(tmp_path):
+    """verify_crc is the caller's to ask: a produce's append (the front end
+    checked the CRC on the way in) reads no payload twice."""
+    given = [_flipped(b) for b in _mixed(3, seed=1)]
+
+    async def main():
+        log = await DiskLog.open(NTP.kafka("framed", 0), LogConfig(base_dir=str(tmp_path)))
+        r = await log.append(given, term=1)
+        got = await log.read(0)
+        await log.close()
+        return r, got
+
+    r, got = _run(main())
+    assert len(got) == 3 and r.base_offset == 0
+    assert not any(b.verify_kafka_crc() for b in got)
+    assert all(b.verify_header_crc() for b in got)
+
+
+@pytest.mark.parametrize("payload_type", [bytearray, memoryview])
+def test_a_payload_that_is_not_bytes_takes_the_loop_and_the_same_files(tmp_path, payload_type):
+    sound = _mixed(4, seed=9)
+    other = [RecordBatch(b.header, payload_type(b.payload)) for b in sound]
+
+    async def main():
+        before = _crossing_samples()
+        a = await _write_log(tmp_path / "a", [(other, 1)])
+        assert _crossing_samples()[0] - before[0] == 4  # one a batch: the loop
+        return a, await _write_log(tmp_path / "b", [(sound, 1)])
+
+    a, b = _run(main())
+    assert a["files"] == b["files"] and a["results"] == b["results"]
+
+
+@pytest.mark.parametrize("how", ["crossing", "loop"])
+def test_a_roll_that_fails_in_mid_list_keeps_what_landed_before_it(tmp_path, monkeypatch, how):
+    """The log_roll probe fires at the list's first roll: the batches before
+    it are in the log, whole and readable, and the next append follows on."""
+    from redpanda_tpu.finjector import ProbeTriggered, honey_badger
+
+    _withhold(monkeypatch, {"loop": "crossing"}.get(how))
+    batches = _mixed(9, seed=3)
+    cfg = LogConfig(base_dir=str(tmp_path), max_segment_size=2500)
+
+    async def main():
+        log = await DiskLog.open(NTP.kafka("framed", 0), cfg)
+        honey_badger.enable()
+        try:
+            honey_badger.set_exception("storage", "log_roll")
+            with pytest.raises(ProbeTriggered):
+                await log.append(batches, term=1)
+        finally:
+            honey_badger.unset("storage", "log_roll")
+            honey_badger.disable()
+        landed = await log.read(0, max_bytes=1 << 30)
+        dirty = log.offsets().dirty_offset
+        r = await log.append(batches[:1], term=1)
+        await log.close()
+        return landed, dirty, r
+
+    landed, dirty, r = _run(main())
+    assert 0 < len(landed) < 9
+    assert [b.payload for b in landed] == [b.payload for b in batches[: len(landed)]]
+    assert all(b.verify_header_crc() for b in landed)
+    assert dirty == landed[-1].last_offset and r.base_offset == dirty + 1
+
+
+@pytest.mark.parametrize("fsync", [False, True])
+def test_fsync_on_append_commits_a_framed_list(tmp_path, fsync):
+    async def main():
+        cfg = LogConfig(base_dir=str(tmp_path), fsync_on_append=fsync)
+        log = await DiskLog.open(NTP.kafka("framed", 0), cfg)
+        r = await log.append(_mixed(9, seed=2), term=1)
+        off = log.offsets()
+        await log.close()
+        return r, off
+
+    r, off = _run(main())
+    assert off.dirty_offset == r.last_offset
+    assert off.committed_offset == (r.last_offset if fsync else -1)
+
+
+@pytest.mark.skipif(
+    not getattr(__import__("redpanda_tpu.native").native.lib, "has_frame_internal_many", False),
+    reason="no native framing crossing here",
+)
+@pytest.mark.parametrize("n", [1, 9])
+@pytest.mark.parametrize("flush", ["flush_after_each", "flush_after_first", "no_flush"])
+def test_an_empty_segment_buffer_adopts_the_framed_bytes(tmp_path, monkeypatch, n, flush):
+    """A framed list that finds the segment's buffer empty (every acks=all
+    produce: the log is flushed after each append) becomes the buffer, not
+    a copy of it; one that finds bytes waiting is added to them. The file
+    is the per-batch loop's either way."""
+    first, calls = _mixed(1, seed=99), [_mixed(n, seed=n + i) for i in range(4)]
+
+    async def write(base_dir, framed):
+        log = await DiskLog.open(NTP.kafka("framed", 0), LogConfig(base_dir=str(base_dir)))
+        adopted = []
+        await log.append(first, term=1)
+        await log.flush()
+        seg = log.segments[-1]
+        for i, batches in enumerate(calls):
+            waiting, held = bool(seg._buf), seg._buf
+            await log.append(batches, term=1)
+            assert seg is log.segments[-1]
+            # an empty buffer is replaced by the frames, a filled one is kept and grown
+            adopted.append(seg._buf is not held)
+            assert adopted[-1] == (framed and not waiting), (i, waiting)
+            assert len(seg._buf) == seg.size_bytes - seg._file.tell()
+            if flush == "flush_after_each" or (flush == "flush_after_first" and i == 0):
+                await log.flush()
+                assert not seg._buf
+        read_back = await log.read(0)
+        await log.close()
+        files = {}
+        for fn in sorted(os.listdir(log.dir)):
+            with open(os.path.join(log.dir, fn), "rb") as fh:
+                files[fn] = fh.read()
+        return adopted, [b.payload for b in read_back], files
+
+    async def main():
+        framed = await write(tmp_path / "framed", True)
+        _withhold(monkeypatch, "crossing")
+        return framed, await write(tmp_path / "looped", False)
+
+    framed, looped = _run(main())
+    assert framed[0].count(True) == {"flush_after_each": 4, "flush_after_first": 2, "no_flush": 1}[flush]
+    assert framed[1] == looped[1] == [b.payload for batches in [first, *calls] for b in batches]
+    assert framed[2] == looped[2] and framed[2]
+
+
+def test_mem_log_takes_verify_crc_like_the_disk_log():
+    sound = _mixed(3, seed=4)
+
+    async def main():
+        log = MemLog(NTP.kafka("framed", 0))
+        r = await log.append([sound[0], _flipped(sound[1]), sound[2]], term=1, verify_crc=True)
+        return r, await log.read(0)
+
+    r, got = _run(main())
+    assert [b.payload for b in got] == [sound[0].payload, sound[2].payload]
+    assert got[1].base_offset == got[0].last_offset + 1 == r.last_offset - got[1].header.last_offset_delta
