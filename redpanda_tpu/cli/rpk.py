@@ -726,7 +726,8 @@ async def cmd_debug(args) -> int:
                 f"/{auto.get('group_ticks_cap')} "
                 f"launch_depth={auto.get('launch_depth')}"
                 f"/{auto.get('launch_depth_cap')} "
-                f"hold={auto.get('hold_s')}s"
+                f"hold={auto.get('hold_s')}s "
+                f"last_move_on={auto.get('evidence') or '(none)'}"
             )
         return 0
 
@@ -759,6 +760,16 @@ async def cmd_debug(args) -> int:
                 (posture.get("deadlines_ms") or {}).items()
             ):
                 print(f"  deadline[{dom}]".ljust(22) + f"{ms}ms")
+            auto = posture.get("autotune")
+            if auto:
+                print(
+                    "  autotune".ljust(22)
+                    + f"group_ticks={auto.get('group_ticks')}"
+                    f"/{auto.get('group_ticks_cap')} "
+                    f"launch_depth={auto.get('launch_depth')}"
+                    f"/{auto.get('launch_depth_cap')} "
+                    f"last_move_on={auto.get('evidence') or '(none)'}"
+                )
         else:
             print("no live coproc engine (journal below is process-wide)")
         summary = body.get("summary") or {}

@@ -259,7 +259,10 @@ PROPERTIES: list[Property] = [
         "Let the governor move group_ticks_per_launch/launch_depth "
         "dynamically (hysteresis-bounded, journaled under the admission "
         "domain) off the success-only dispatch-leg p99.9 and the budget "
-        "plane's occupancy; false pins the static knobs",
+        "plane's occupancy, and, where a script launches nothing on the "
+        "device, grow group_ticks_per_launch on a backlog (a run of "
+        "launches that the read budget cut short; counted in launches, "
+        "not seconds); false pins the static knobs against both rules",
         True, bool,
     ),
     # --- coproc multi-chip mesh (coproc/meshrunner.py)

@@ -130,9 +130,16 @@ _DEADLINE_JOURNAL_DELTA = 0.2    # journal a change only when >= 20%
 # sits relative to the static deadline floor before we grow (cheap legs:
 # deepen batching toward the ~90%-utilization posture) or shrink (tail
 # approaching the deadline: trade launch depth for latency).
+# A lane that launches nothing on the device never has a dispatch-leg
+# sample; its evidence is the backlog the pacemaker sees in its own reads
+# (Governor.note_launch), and its clock is launches, not seconds: after
+# this many completed launches in a row that the read budget cut short,
+# each with an engine phase under the grow fraction of the deadline,
+# group_ticks grows one step.
 AUTOTUNE_HOLD_S = 5.0
 _AUTOTUNE_GROW_FRAC = 0.5
 _AUTOTUNE_SHRINK_FRAC = 0.8
+_AUTOTUNE_BACKLOG_LAUNCHES = 3
 
 # posture verdict -> gauge value per domain (unknown/undecided = -1)
 _STATE_ENCODING: dict[str, dict[str, float]] = {
@@ -735,8 +742,18 @@ class Governor:
         verdicts. ``pressure_fn() -> (level, occupancy)`` is the budget
         plane's signal (None = no plane: the latency guard still runs).
         The configured values are the STARTING point; verdicts move within
-        [1, cap] and may only change once per ``hold_s`` (hysteresis) —
-        the same floors/caps posture as the adaptive-deadline machinery."""
+        [1, cap] on one of two kinds of evidence, each with its clock:
+
+        - the device leg's tail against the deadline (``device_dispatch``
+          p99.9, once it has ``min_samples``), and the budget plane's
+          pressure: at most one move per ``hold_s`` (hysteresis), the same
+          floors/caps posture as the adaptive-deadline machinery;
+        - where no device leg has ever completed, the backlog
+          (``note_launch``): ``group_ticks`` grows one step per
+          ``_AUTOTUNE_BACKLOG_LAUNCHES`` budget-cut launches, with no wait
+          on the clock between two such grows, except for ``hold_s`` after
+          any shrink. ``launch_depth`` bounds staged device bytes, about
+          which a backlog says nothing: that rule never moves it."""
         with self._lock:
             self._auto = {
                 "enabled": bool(enabled),
@@ -747,13 +764,40 @@ class Governor:
                 "hold_s": max(0.0, float(hold_s)),
                 "last_change": -float("inf"),
                 "pressure_fn": pressure_fn,
+                # what moved the knobs last: "device_leg", "backlog" or
+                # "pressure" (None: nothing has)
+                "evidence": None,
+                # note_launch's books: completed launches in a row that
+                # the read budget cut short under a cheap engine phase, and
+                # the newest launch's engine phase
+                "backlog_run": 0,
+                "engine_s": 0.0,
             }
+
+    def note_launch(self, budget_cut: bool, engine_s: float) -> None:
+        """One completed launch, told by the pacemaker once a tick on the
+        loop thread: ``budget_cut`` says every partition that gave records
+        stopped short of the LSO it read against (the byte budget ended the
+        read, not the log: more is waiting), ``engine_s`` how long the
+        engine held the launch. The books of ``launch_knobs``' backlog
+        rule; nothing is decided here."""
+        auto = self._auto
+        if auto is None:
+            return
+        cheap = engine_s < _AUTOTUNE_GROW_FRAC * self._policy.deadline_s
+        with self._lock:
+            auto["backlog_run"] = auto["backlog_run"] + 1 if budget_cut and cheap else 0
+            auto["engine_s"] = engine_s
 
     def launch_knobs(self) -> dict:
         """Current {"group_ticks", "launch_depth"} — recomputed here (the
         pacemaker polls once per tick), journaled under the ADMISSION
-        domain only when a knob actually moves, and held still inside the
-        hysteresis window no matter what the inputs do."""
+        domain only when a knob actually moves (each entry says which
+        evidence moved it), and held still inside the hysteresis window no
+        matter what the inputs do. The device leg's tail and the pressure
+        level move both knobs, on the ``hold_s`` clock; with no device-leg
+        sample at all, a run of budget-cut launches grows ``group_ticks``
+        on the launch clock (``configure_autotune``)."""
         auto = self._auto
         if auto is None:
             return {"group_ticks": 1, "launch_depth": 4}
@@ -764,6 +808,7 @@ class Governor:
             now = self._clock()
             if now - auto["last_change"] < auto["hold_s"]:
                 return {"group_ticks": gt, "launch_depth": ld}
+            run, engine_s = auto["backlog_run"], auto["engine_s"]
         # inputs read OUTSIDE the lock (pressure_fn reaches the plane,
         # the histogram percentile walks buckets)
         level, occ = "ok", 0.0
@@ -777,20 +822,35 @@ class Governor:
                 faults.note_failure("autotune_pressure", exc)
                 logger.exception("autotune pressure source failed")
         hist = self._stage_hist(faults.DEVICE_DISPATCH)
-        p999_us = hist.percentile(99.9) if hist.count >= self._min_samples else None
+        count = hist.count
+        p999_us = hist.percentile(99.9) if count >= self._min_samples else None
         floor_us = self._policy.deadline_s * 1e6
-        new_gt, new_ld, verdict = gt, ld, None
+        new_gt, new_ld, verdict, evidence = gt, ld, None, "device_leg"
         if level == "critical":
             # memory first: collapse to the floors so held staged bytes
             # drain; admission keeps shedding the excess meanwhile
-            new_gt, new_ld, verdict = 1, 1, "floor"
+            new_gt, new_ld, verdict, evidence = 1, 1, "floor", "pressure"
         elif level == "warn":
             new_gt, new_ld = max(1, gt - 1), max(1, ld - 1)
-            verdict = "shrink"
+            verdict, evidence = "shrink", "pressure"
+        elif count == 0:
+            # no device leg has ever completed (the lane launches nothing
+            # on the device; an idle engine; a host-pinned box): the only
+            # evidence there will ever be is the backlog. Launches the read
+            # budget keeps cutting short, each cheap against the deadline,
+            # grow the read, one step per run and with no wait on the
+            # clock; an engine phase near the deadline steps it down. With
+            # neither (nothing launched, or reads that end at the LSO: a
+            # live stream) the configured knobs HOLD.
+            evidence = "backlog"
+            if engine_s * 1e6 > _AUTOTUNE_SHRINK_FRAC * floor_us:
+                new_gt, verdict = max(1, gt - 1), "shrink"
+            elif run >= _AUTOTUNE_BACKLOG_LAUNCHES:
+                new_gt, verdict = min(auto["group_ticks_cap"], gt + 1), "grow"
         elif p999_us is None:
-            # no device-leg evidence yet (idle engine, host-pinned box):
-            # HOLD the configured knobs — growing on zero samples would
-            # ratchet to the caps exactly when nothing supports it
+            # a device leg has run, but too few for a tail: HOLD — growing
+            # on a sample or two would ratchet to the caps exactly when
+            # nothing supports it
             pass
         elif p999_us > _AUTOTUNE_SHRINK_FRAC * floor_us:
             # device-leg tail approaching the deadline: trade depth for
@@ -803,29 +863,41 @@ class Governor:
             verdict = "grow"
         if (new_gt, new_ld) == (gt, ld):
             return {"group_ticks": gt, "launch_depth": ld}
+        on_launch_clock = evidence == "backlog" and verdict == "grow"
         with self._lock:
             # re-check under the lock: a concurrent caller may have moved
-            # the knobs (and armed the hold window) while we read inputs
-            if self._clock() - auto["last_change"] < auto["hold_s"]:
+            # the knobs (and armed the hold window, or spent the run of
+            # launches) while we read inputs
+            if self._clock() - auto["last_change"] < auto["hold_s"] or (
+                on_launch_clock and auto["backlog_run"] < run
+            ):
                 return {
                     "group_ticks": auto["group_ticks"],
                     "launch_depth": auto["launch_depth"],
                 }
             auto["group_ticks"], auto["launch_depth"] = new_gt, new_ld
-            auto["last_change"] = self._clock()
+            auto["evidence"] = evidence
+            # every move spends the backlog's books: the next grow on them
+            # takes a whole new run of launches at the new size
+            auto["backlog_run"], auto["engine_s"] = 0, 0.0
+            if not on_launch_clock:
+                auto["last_change"] = self._clock()
         self._emit(
             ADMISSION,
             verdict,
-            f"launch knobs {verdict}: group_ticks {gt} -> {new_gt}, "
-            f"launch_depth {ld} -> {new_ld} (pressure {level}, occupancy "
-            f"{occ:.2f}, dispatch-leg p99.9 "
+            f"launch knobs {verdict} on {evidence}: group_ticks {gt} -> "
+            f"{new_gt}, launch_depth {ld} -> {new_ld} (pressure {level}, "
+            f"occupancy {occ:.2f}, dispatch-leg p99.9 "
             f"{'n/a' if p999_us is None else int(p999_us)} us vs floor "
-            f"{int(floor_us)} us)",
+            f"{int(floor_us)} us, {run} budget-cut launches in a row)",
             {
+                "evidence": evidence,
                 "pressure": level,
                 "occupancy": round(occ, 4),
                 "p999_us": None if p999_us is None else int(p999_us),
                 "floor_us": int(floor_us),
+                "backlog_launches": run,
+                "engine_us": int(engine_s * 1e6),
                 "group_ticks": new_gt,
                 "launch_depth": new_ld,
                 "prev_group_ticks": gt,
@@ -857,6 +929,7 @@ class Governor:
                 for k in (
                     "enabled", "group_ticks", "group_ticks_cap",
                     "launch_depth", "launch_depth_cap", "hold_s",
+                    "evidence",
                 )
             }
 

@@ -194,6 +194,15 @@ class ScriptContext:
         (script_context.cc's read → process → write → last_acked order) —
         advancing at read time would drop records on any write failure.
 
+        The read budget is ``max_batch_size`` times the governor's
+        ``group_ticks`` knob, which moves on two kinds of evidence
+        (``Governor.launch_knobs``): the device leg's tail, on a clock of
+        seconds, and, for a lane with no device leg, what this tick tells
+        it when it completes (``Governor.note_launch``): whether every
+        partition that gave records is in ``behind``, on a clock of
+        launches. A live stream's reads end at the LSO, ``behind`` is empty
+        and that rule never fires.
+
         Every phase is a stage (observability/stages.py): always a sample
         in ``coproc_tick_latency_us{phase=}`` and, in a profile, an
         ``rp:coproc.*`` annotation; with tracing on, a span under the tick.
@@ -496,6 +505,13 @@ class ScriptContext:
                 if await self._write_materialized(item.source, item.batches):
                     self.offsets[item.source] = read_high[item.source]
                     moved = True
+        gov = getattr(pm.engine, "governor", None)
+        if gov is not None:
+            # the launch knob's evidence where no device leg will ever give
+            # it any: was this launch cut by the read budget in every
+            # partition that gave records (a backlog), and how long did the
+            # engine's two calls hold it
+            gov.note_launch(len(behind) == len(items), sum(legs))
         return moved, None
 
     def _input_ntps(self) -> list[NTP]:

@@ -17,7 +17,7 @@ import time
 import pytest
 
 from redpanda_tpu.cluster.topic_table import TopicConfig
-from redpanda_tpu.coproc import leakwatch
+from redpanda_tpu.coproc import governor, leakwatch
 from redpanda_tpu.coproc.api import CoprocApi
 from redpanda_tpu.kafka.server.broker import Broker, BrokerConfig
 from redpanda_tpu.kafka.server.protocol import KafkaServer
@@ -660,6 +660,127 @@ def test_a_corrupt_batch_in_the_reply_is_left_out_and_the_offset_moves(tmp_path,
             # the sound batches are the ones partition 0 and 2 hold in
             # their place: same script, same documents but for the code
             assert [b.header.record_count for b in landed] == [DOCS // 2] * len(landed)
+        finally:
+            await _stop(storage, server, api)
+
+    run(main())
+
+
+# ------------------------------------------------------------------ (j)
+class _NoDeviceLeg:
+    """The ``device_dispatch`` histogram of a lane that launches nothing on
+    the device: never a sample. (The process-wide one holds whatever the
+    payload tests before this one launched.)"""
+
+    count = 0
+
+    def percentile(self, q):
+        return 0
+
+    def record(self, v):
+        self.count += 1
+
+
+CAP = 4
+
+
+async def _on_the_host_lane(api, broker):
+    """The columnar script (evaluated on the host at this size) parked over
+    an empty topic, under a governor that has never seen a device leg and
+    keeps a journal of its own; one batch is a little under the read budget
+    at the knob's start, as in the catch-up cells (31.9 KB under 32 KiB: two
+    batches a read, and ``knob + 1`` a read from there)."""
+    gov = api.pacemaker.engine.governor
+    legs = _NoDeviceLeg()
+    gov._stage_hist = lambda domain: legs
+    gov._journal = governor.DecisionJournal(64)
+    gov.configure_autotune(group_ticks=1, group_ticks_cap=CAP, launch_depth=4)
+    ctx = await _parked(api, broker)
+    for part in range(PARTITIONS):
+        await _append(broker, "src", part, _docs(DOCS))
+    (batch,) = await broker.get_partition("src", 0).make_reader(0, 1 << 20)
+    api.pacemaker.max_batch_size = batch.size_bytes + 4
+    return ctx, gov, legs
+
+
+def _batches_a_partition(launch):
+    sizes = {(last - first + 1) // DOCS for _part, first, last in launch}
+    assert len(sizes) == 1, launch
+    return sizes.pop()
+
+
+def test_a_backlog_on_the_host_lane_grows_the_read_to_the_cap_by_the_launch(tmp_path):
+    K = governor._AUTOTUNE_BACKLOG_LAUNCHES
+
+    async def main():
+        storage, broker, server, api = await _start(tmp_path)
+        try:
+            pm, engine = api.pacemaker, api.pacemaker.engine
+            ctx, gov, legs = await _on_the_host_lane(api, broker)
+            for part in range(PARTITIONS):
+                for _ in range(40):  # every batch the size of the first
+                    await _append(broker, "src", part, _docs(DOCS))
+            t_start = time.monotonic()
+            knobs = []
+            real = pm.launch_knobs
+            pm.launch_knobs = lambda: knobs.append(real()) or knobs[-1]
+            while await ctx.tick():
+                pass
+            del pm.launch_knobs
+            assert _drained(ctx, batches=41) and legs.count == 0
+            # one step a K launches, whatever the clock says (the 5 s hold
+            # of the device rule would have allowed one move in this time)
+            assert time.monotonic() - t_start < 5.0
+            ticks = [k["group_ticks"] for k in knobs]
+            assert ticks[:3 * K + 1] == [1] * K + [2] * K + [3] * K + [CAP]
+            assert set(ticks[3 * K:]) == {CAP}
+            assert {k["launch_depth"] for k in knobs} == {4}
+            # what a partition gave a launch: knob + 1 batches, the launch
+            # after a move still at the size it was read ahead at. At the
+            # cap a read is CAP x max_batch_size, rounded up to whole batches
+            reads = [_batches_a_partition(launch) for launch in engine.launches]
+            assert reads[:3 * K + 2] == [2] * (K + 1) + [3] * K + [4] * K + [CAP + 1]
+            assert set(reads[3 * K + 1:-1]) == {CAP + 1} and sum(reads) == 41
+            batch = pm.max_batch_size - 4
+            assert CAP * pm.max_batch_size <= (CAP + 1) * batch < (CAP + 1) * pm.max_batch_size
+            moves = gov._journal.entries(domain="admission")[::-1]
+            assert [(e["verdict"], e["inputs"]["evidence"], e["inputs"]["group_ticks"])
+                    for e in moves] == [("grow", "backlog", g) for g in (2, 3, CAP)]
+            assert gov.autotune_snapshot()["evidence"] == "backlog"
+
+            # a live stream after it: a knob left at the cap only raises a
+            # budget that no read reaches, and a read that ends at the LSO
+            # is no evidence
+            del engine.launches[:]
+            for k in range(2 * K):
+                for part in range(PARTITIONS):
+                    await _append(broker, "src", part, _docs(DOCS))
+                assert await ctx.tick() is True and ctx._ahead is None
+            assert [_batches_a_partition(launch) for launch in engine.launches] == [1] * 2 * K
+            assert len(gov._journal.entries(domain="admission")) == 3
+        finally:
+            await _stop(storage, server, api)
+
+    run(main())
+
+
+def test_a_live_trickle_on_the_host_lane_never_moves_the_knob(tmp_path):
+    async def main():
+        storage, broker, server, api = await _start(tmp_path)
+        try:
+            engine = api.pacemaker.engine
+            ctx, gov, legs = await _on_the_host_lane(api, broker)
+            for k in range(12):
+                # now one batch a partition, now two: each read ends at the LSO
+                for part in range(PARTITIONS):
+                    for j in range(1 + k % 2):
+                        await _append(broker, "src", part, _docs(DOCS))
+                assert await ctx.tick() is True and ctx._ahead is None
+            assert _drained(ctx, batches=1 + 6 * 1 + 6 * 2)
+            assert len(engine.launches) == 12 and legs.count == 0
+            snap = gov.autotune_snapshot()
+            assert (snap["group_ticks"], snap["launch_depth"], snap["evidence"]) == (1, 4, None)
+            assert gov._journal.entries(domain="admission") == []
         finally:
             await _stop(storage, server, api)
 

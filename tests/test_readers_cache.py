@@ -493,6 +493,7 @@ def test_script_over_a_multi_segment_backlog_matches_the_reference(tmp_path):
     read-ahead windows; the materialized topic holds exactly what the plain
     reference makes of every input record, in order."""
     import time
+    import types
 
     from redpanda_tpu.cluster.topic_table import TopicConfig
     from redpanda_tpu.coproc.api import CoprocApi
@@ -515,6 +516,10 @@ def test_script_over_a_multi_segment_backlog_matches_the_reference(tmp_path):
         api = await CoprocApi(broker).start()
         api.poll_interval_s = 0.02
         broker.coproc_api = api
+        # as in the cell, no launch of this process has run on the device
+        # (the process-wide histogram holds what earlier tests launched)
+        no_legs = types.SimpleNamespace(count=0, percentile=lambda q: 0, record=lambda v: None)
+        api.engine.governor._stage_hist = lambda domain: no_legs
         try:
             # ~1.5 MB a partition in segments of 512 KiB: 16 batches each
             await broker.create_topic(TopicConfig("src", parts, segment_size=512 << 10))
@@ -553,10 +558,15 @@ def test_script_over_a_multi_segment_backlog_matches_the_reference(tmp_path):
                 got = await part.make_reader(0, 1 << 30)
                 assert [r.value for b in got for r in b.records()] == want[p]
             after = rc.stats()
-            reads = {k: after[k] - before[k] for k in ("hits", "window_reads", "file_reads")}
-            # 48 batches a partition at two a read: the reads continued from
-            # cursors, and most of them touched no file
-            assert reads["hits"] >= 40 and reads["window_reads"] > reads["file_reads"], reads
+            reads = {k: after[k] - before[k]
+                     for k in ("hits", "misses", "window_reads", "file_reads")}
+            # every read but a partition's first continued from a cursor,
+            # and some touched no file. 48 batches a partition, two a read
+            # at first and one more every three launches (the launch knob
+            # grows on a backlog where nothing has run on the device:
+            # test_pacemaker_read_ahead (j)): 14 reads a partition, not 24
+            assert reads["misses"] == parts and 20 <= reads["hits"] < 40, reads
+            assert reads["window_reads"] >= 4, reads
         finally:
             await api.stop()
             await server.stop()

@@ -332,6 +332,147 @@ def test_autotune_latency_guard_shrinks():
     assert g.launch_knobs() == {"group_ticks": 2, "launch_depth": 2}
 
 
+# The second kind of evidence (no device leg has ever completed): a backlog,
+# told by the pacemaker launch by launch, on a clock of launches.
+K = governor._AUTOTUNE_BACKLOG_LAUNCHES
+
+
+def _backlog_launches(g, n, *, cut=True, engine_s=0.01):
+    """``n`` ticks as the pacemaker makes them: poll the knobs, then tell
+    the governor how the launch went. The knobs as the last poll gave them."""
+    for _ in range(n):
+        k = g.launch_knobs()
+        g.note_launch(cut, engine_s)
+    return k
+
+
+def _admission(g):
+    """The knobs' moves, oldest first."""
+    return g._journal.entries(domain=governor.ADMISSION)[::-1]
+
+
+def test_autotune_backlog_grows_by_the_launch_not_by_the_clock():
+    t = [0.0]
+    hist = _FakeHist()  # never a device-leg sample
+    g = _autotune_gov(lambda: t[0], hist, [("ok", 0.1)])
+    seen = []
+    for _ in range(4 * K):
+        seen.append(g.launch_knobs()["group_ticks"])
+        g.note_launch(True, 0.01)
+    # one step a K launches with the clock standing still, to the cap (4)
+    # and not beyond
+    assert seen == [2] * K + [3] * K + [4] * (2 * K)
+    assert g.launch_knobs() == {"group_ticks": 4, "launch_depth": 2}
+    entries = _admission(g)
+    assert [e["verdict"] for e in entries] == ["grow", "grow"]
+    for e, (prev, new) in zip(entries, [(2, 3), (3, 4)]):
+        i = e["inputs"]
+        assert i["evidence"] == "backlog" and i["backlog_launches"] == K
+        assert (i["prev_group_ticks"], i["group_ticks"]) == (prev, new)
+        assert i["prev_launch_depth"] == i["launch_depth"] == 2  # unmoved
+        assert i["p999_us"] is None
+    assert g.autotune_snapshot()["evidence"] == "backlog"
+    # the device rule says so too, once there is a device leg to go by
+    hist.count, hist._p = 1000, 0.9 * 1e6
+    assert g.launch_knobs() == {"group_ticks": 3, "launch_depth": 1}
+    assert _admission(g)[-1]["inputs"]["evidence"] == "device_leg"
+    assert g.autotune_snapshot()["evidence"] == "device_leg"
+
+
+def _launches_that_reach_the_lso(g, hist):
+    _backlog_launches(g, 4 * K, cut=False)
+
+
+def _a_cut_launch_now_and_then(g, hist):
+    for _ in range(4):
+        _backlog_launches(g, K - 1)
+        _backlog_launches(g, 1, cut=False)
+
+
+def _a_device_sample_under_min_samples(g, hist):
+    hist.record(1)  # a payload lane: the device rule's HOLD, not this rule
+    _backlog_launches(g, 4 * K)
+
+
+def _launches_not_cheap_enough(g, hist):
+    # over the grow fraction of the 1 s deadline, under the shrink fraction
+    _backlog_launches(g, 4 * K, engine_s=0.6)
+
+
+def _autotune_off(g, hist):
+    g.configure_autotune(enabled=False, group_ticks=2, launch_depth=2)
+    _backlog_launches(g, 4 * K)
+
+
+@pytest.mark.parametrize("how", [
+    _launches_that_reach_the_lso, _a_cut_launch_now_and_then,
+    _a_device_sample_under_min_samples, _launches_not_cheap_enough,
+    _autotune_off,
+])
+def test_autotune_backlog_rule_holds_without_its_evidence(how):
+    t = [0.0]
+    hist = _FakeHist()
+    g = _autotune_gov(lambda: t[0], hist, [("ok", 0.1)])
+    how(g, hist)
+    t[0] = 100.0  # and the clock does not stand in for the evidence
+    assert g.launch_knobs() == {"group_ticks": 2, "launch_depth": 2}
+    assert _admission(g) == []
+    assert g.autotune_snapshot()["evidence"] is None
+
+
+@pytest.mark.parametrize("level, after", [
+    ("warn", {"group_ticks": 2, "launch_depth": 1}),
+    ("critical", {"group_ticks": 1, "launch_depth": 1}),
+])
+def test_autotune_backlog_grow_waits_out_the_hold_after_a_shrink(level, after):
+    t = [0.0]
+    pressure = [("ok", 0.1)]
+    g = _autotune_gov(lambda: t[0], _FakeHist(), pressure)
+    assert _backlog_launches(g, K + 1) == {"group_ticks": 3, "launch_depth": 2}
+    # pressure shrinks as it always has, at once after a launch-counted grow
+    # (which armed no hold), and its own move arms the hold
+    pressure[0] = (level, 0.95)
+    assert g.launch_knobs() == after
+    assert _admission(g)[-1]["inputs"]["evidence"] == "pressure"
+    # pressure gone, the backlog still there: nothing grows inside hold_s,
+    # however many launches are cut
+    pressure[0] = ("ok", 0.1)
+    t[0] = 9.0
+    assert _backlog_launches(g, 3 * K) == after
+    t[0] = 10.5
+    grown = dict(after, group_ticks=after["group_ticks"] + 1)
+    assert g.launch_knobs() == grown
+    assert _backlog_launches(g, K + 1)["group_ticks"] == grown["group_ticks"] + 1
+    assert [e["verdict"] for e in _admission(g)] == [
+        "grow", "floor" if level == "critical" else "shrink", "grow", "grow",
+    ]
+    # while it lasts, pressure keeps shrinking one step a hold_s
+    pressure[0] = ("warn", 0.95)
+    held = g.launch_knobs()
+    assert held["group_ticks"] == grown["group_ticks"]
+    assert _backlog_launches(g, K + 1) == held
+    t[0] = 21.0
+    assert g.launch_knobs()["group_ticks"] == grown["group_ticks"] - 1
+
+
+def test_autotune_backlog_slow_engine_phase_steps_down():
+    t = [0.0]
+    g = _autotune_gov(lambda: t[0], _FakeHist(), [("ok", 0.1)])
+    assert _backlog_launches(g, K + 1) == {"group_ticks": 3, "launch_depth": 2}
+    # one launch whose engine phase is over 80% of the 1 s deadline: a step
+    # down of group_ticks (launch_depth is not this rule's), on the hold clock
+    g.note_launch(True, 0.9)
+    assert g.launch_knobs() == {"group_ticks": 2, "launch_depth": 2}
+    e = _admission(g)[-1]
+    assert e["verdict"] == "shrink" and e["inputs"]["evidence"] == "backlog"
+    assert e["inputs"]["engine_us"] == 900000
+    # spent with the move: the same reading does not step down twice
+    t[0] = 11.0
+    assert g.launch_knobs() == {"group_ticks": 2, "launch_depth": 2}
+    # and cheap budget-cut launches grow it again
+    assert _backlog_launches(g, K + 1) == {"group_ticks": 3, "launch_depth": 2}
+
+
 # ------------------------------------------------------------------ pressure hooks
 def test_arena_trim_and_colcache_pressure_hooks():
     plane = BudgetPlane(1 << 20)
