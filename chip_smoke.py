@@ -59,7 +59,7 @@ NEEDLE = b'"level":"warn"'
 PARTITIONS = 64
 RECORDS_PER_PARTITION = 1024
 RECORDS_PER_BATCH = 32
-# stage B: the bench's launch geometry (bench.py P / GROUP / DEPTH / ROW_STRIDE)
+# stage B's launch geometry: ticks fused a launch, launches in flight, row stride
 TICKS_PER_LAUNCH = 16
 LAUNCH_DEPTH = 3
 WARM_LAUNCHES = 8
@@ -558,7 +558,7 @@ def run_lane(name, spec_json, values, ref_fn, ticks_per_launch, **engine_kw) -> 
 
 
 def device_programs(seed: int) -> list[dict]:
-    """Each remaining device program once, at the shape bench.py runs it,
+    """Each remaining device program once, at a launch-sized shape,
     against its host oracle. Touches JAX."""
     import jax
 

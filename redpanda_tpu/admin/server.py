@@ -702,7 +702,7 @@ class AdminServer:
 
     async def _governor(self, req: web.Request) -> web.Response:
         """The coproc decision plane (coproc/governor.py): every adaptive
-        decision this process made — columnar backend, parse ladder,
+        decision this process made — columnar backend,
         mesh-vs-single, device_lz4, breaker transitions, harvest path,
         adaptive deadlines — as a journal (newest-first, with
         measured inputs + verdict + reason + active-config snapshot) plus

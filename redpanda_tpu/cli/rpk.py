@@ -489,7 +489,7 @@ async def cmd_debug(args) -> int:
             v = round(v, 6) if isinstance(v, float) else v
             print(f"  {k:<28}{v}")
         for k in (
-            "columnar_backend", "columnar_probe", "parse_path", "parse_probe",
+            "columnar_backend", "columnar_probe", "parse_path",
             "colcache", "arena", "staging_arena", "uncompress_arena",
             "seal_arena", "breakers", "lockwatch",
             "leakwatch", "mesh_error", "device_launches_by_script",

@@ -222,11 +222,6 @@ PROPERTIES: list[Property] = [
         True, bool,
     ),
     Property(
-        "coproc_structural_parse",
-        "Allow the structural-index fused parse ladder (rp_explode_find2 + one fused extraction crossing); the engine still MEASURES fused-vs-staged on the first representative launch and pins the winner. False pins the scalar staged ladder outright",
-        True, bool,
-    ),
-    Property(
         "coproc_device_column_cache_mb",
         "LRU byte budget for the device-resident column cache (repeat scripts over unchanged batch windows skip the host parse/extract ladder and the H2D replay); 0 disables it",
         32, int, _non_negative,

@@ -57,7 +57,6 @@ class CoprocApi:
         self.engine = TpuEngine(
             host_workers=_knob("coproc_host_workers", None),
             gather_frame=_knob("coproc_gather_frame", True),
-            structural_parse=_knob("coproc_structural_parse", None),
             device_column_cache_mb=_knob(
                 "coproc_device_column_cache_mb", 32
             ),

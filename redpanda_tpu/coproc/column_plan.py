@@ -192,24 +192,24 @@ class ColumnarPlan:
         return slots
 
     # ------------------------------------------------------------ device
-    def compile_device(self, mesh=None):
-        """jit fn(*cols) -> packed keep bits (uint8 [n/8]).
+    def compile_device(self):
+        """jit fn(*cols) -> packed keep bits (uint8 [n/8]), one
+        single-device program.
 
         Each DevCol contributes inputs in order: str -> (bytes [n, w] u8,
         vlen [n] i32); num -> (f32 [n], i32 [n], flags [n] u8);
-        exists -> (u8 [n]). Rows shard over `mesh`'s 'p' axis when given.
+        exists -> (u8 [n]).
         """
-        key = id(mesh) if mesh is not None else None
-        fn = self._fn_cache.get(key)
+        fn = self._fn_cache.get("single")
         if fn is not None:
             return fn
         with self._fn_lock:
-            fn = self._fn_cache.get(key)
+            fn = self._fn_cache.get("single")
             if fn is not None:
                 return fn
-            return self._compile_device_locked(key, mesh)
+            return self._compile_device_locked()
 
-    def _compile_device_locked(self, key, mesh):
+    def _compile_device_locked(self):
         import jax
         import jax.numpy as jnp
 
@@ -228,21 +228,8 @@ class ColumnarPlan:
             with jax.named_scope("frame"):
                 return _packbits(jnp, keep)
 
-        if mesh is None:
-            fn = jax.jit(rp_columnar_predicate)
-        else:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            row_sharded = NamedSharding(mesh, PartitionSpec("p"))
-            shardings = []
-            for c in self.dev_cols:
-                shardings += [row_sharded] * _COL_ARITY[c.kind]
-            fn = jax.jit(
-                rp_columnar_predicate,
-                in_shardings=tuple(shardings),
-                out_shardings=NamedSharding(mesh, PartitionSpec()),
-            )
-        self._fn_cache[key] = fn
+        fn = jax.jit(rp_columnar_predicate)
+        self._fn_cache["single"] = fn
         return fn
 
     def compile_device_stacked(self, mesh):

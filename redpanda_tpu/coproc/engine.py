@@ -1002,10 +1002,7 @@ class Ticket:
 class TpuEngine:
     """HandleTable + batched async device execution.
 
-    ``mesh``: optional jax.sharding.Mesh with a 'p' axis; columnar launches
-    then run SPMD with record rows sharded over the mesh (the per-shard
-    pacemaker-fiber analogue of coproc/pacemaker.h:41-145 — one engine, all
-    chips). ``force_mode`` pins every script to one execution mode
+    ``force_mode`` pins every script to one execution mode
     ("payload" forces the full-row staging path, "columnar_host" pins the
     numpy predicate, "columnar_device" pins the device predicate; used by
     the bench to measure each half).
@@ -1043,12 +1040,9 @@ class TpuEngine:
         row_stride: int = 1024,
         compress_threshold: int = 512,
         output_codec: Compression = Compression.zstd,
-        mesh=None,
         force_mode: str | None = None,
         host_workers: int | None = None,
         gather_frame: bool = True,
-        structural_parse: bool | None = None,
-        structural_probe: bool = True,
         device_column_cache_mb: int | None = None,
         mesh_devices: int | None = None,
         mesh_backend: str | None = None,
@@ -1128,7 +1122,6 @@ class TpuEngine:
         self._row_stride = row_stride
         self._compress_threshold = compress_threshold
         self._output_codec = output_codec
-        self._mesh = mesh
         self._force_mode = force_mode
         # width of the mesh lane's per-device host ladder
         # (coproc/host_pool.py; the pool is built with the mesh runner
@@ -1151,40 +1144,6 @@ class TpuEngine:
         )
         self._seal_pool = batch_codec.Arena(
             max_free=_STAGING_MAX_PARKED, quantum=_UNCOMPRESS_QUANTUM
-        )
-        # Structural-index parse path (native rp_explode_find2 +
-        # rp_extract_cols2): fused-vs-staged is a MEASURED per-engine
-        # decision — the first representative columnar launch times BOTH
-        # full ladders on its own batches and the winner pins
-        # (governor.PROBE_MARGIN; the scalar staged ladder is the
-        # known path, so structural must show a real win). config
-        # coproc_structural_parse=False pins staged outright;
-        # structural_probe=False pins structural unmeasured (bench
-        # ablations / parity tests need the fused lane deterministically).
-        self._structural_enabled = (
-            True if structural_parse is None else bool(structural_parse)
-        )
-        self._parse_probe_enabled = bool(structural_probe)
-        self._parse_probe: dict | None = None
-        if not self._structural_enabled:
-            self._parse_decision: str | None = "staged"
-            # operator pin, not a measurement: posture only
-            self.governor.note_posture(governor.PARSE_PATH, "staged")
-        elif not self._parse_probe_enabled:
-            self._parse_decision = "structural"
-            self.governor.note_posture(governor.PARSE_PATH, "structural")
-        else:
-            self._parse_decision = None
-        self._parse_decision_lock = lockwatch.wrap(
-            threading.Lock(), "TpuEngine._parse_decision_lock"
-        )
-        # serializes calibration EXECUTION only (see _parse_path): never
-        # held while publishing or reading the decision fields
-        self._parse_probe_run_lock = lockwatch.wrap(
-            threading.Lock(), "TpuEngine._parse_probe_run_lock"
-        )
-        self.governor.update_config_snapshot(
-            structural_parse=self._structural_enabled
         )
         # Device-resident column cache (coproc/colcache.py): repeat
         # scripts over unchanged batch windows skip the whole host ladder
@@ -1287,6 +1246,9 @@ class TpuEngine:
             threading.Lock(), "TpuEngine._compile_lock"
         )
         self._device_launches: dict[int, int] = defaultdict(int)
+        # the parse ladder the last columnar launch ran ("structural" |
+        # "staged"); None before one
+        self._parse_path: str | None = None
         self._pipelines: dict[int, tuple] = {}  # payload: script_id -> (fn, r_out)
         self._plans: dict[int, object] = {}  # script_id -> execution plan
         self._stats: dict[str, float] = defaultdict(float)
@@ -1562,6 +1524,7 @@ class TpuEngine:
             out = dict(self._stats)
             out["device"] = dict(self._device) if self._device else None
             out["device_launches_by_script"] = dict(self._device_launches)
+            out["parse_path"] = self._parse_path
             # every device program this engine compiled: a new row bucket
             # is a new program, and this is where that cost shows
             out["compiled_programs"] = [
@@ -1588,10 +1551,6 @@ class TpuEngine:
             # same posture: outstanding balances + imbalance count ride
             # stats() into the status/debug surfaces
             out["leakwatch"] = leakwatch.snapshot()
-        with self._parse_decision_lock:
-            out["parse_path"] = self._parse_decision
-            if self._parse_probe is not None:
-                out["parse_probe"] = dict(self._parse_probe)
         if self._colcache is not None:
             out["colcache"] = self._colcache.stats()
         if self._admission is not None:
@@ -2088,7 +2047,6 @@ class TpuEngine:
         if (
             plan.mode == "columnar"
             and self._colcache is not None
-            and self._mesh is None
             and all_batches
         ):
             key = (script_id, colcache.fingerprint(all_batches))
@@ -2099,20 +2057,14 @@ class TpuEngine:
                 return
             self._count_colcache(False)
             store_key = key
-        # decide the parse ladder BEFORE the stage timer starts: the first
-        # representative launch runs the fused-vs-staged calibration here,
-        # and its four ladder passes must not masquerade as that launch's
-        # t_explode_find* stage time
-        parse = (
-            self._parse_path(plan, all_batches)
-            if plan.mode == "columnar"
-            else "staged"
-        )
+        # the parse ladder is the plan's: nested paths and general
+        # projections keep the staged one (ColumnarPlan.structural_eligible)
+        structural = plan.mode == "columnar" and plan.structural_eligible()
         # the annotation takes the lane's first-choice name; a lane that
         # falls back closes under the stage that ran (histogram and ring)
         if plan.mode == "columnar":
             t0 = _stage_t0(
-                "t_explode_find2" if parse == "structural" else "t_explode_find"
+                "t_explode_find2" if structural else "t_explode_find"
             )
         else:
             t0 = _stage_t0(
@@ -2122,7 +2074,7 @@ class TpuEngine:
         if plan.mode == "columnar":
             paths = plan.flat_paths()
             sp = None
-            if parse == "structural":
+            if structural:
                 # STRUCTURAL fused lane: payload bytes cross the native
                 # boundary once as a pointer table (no Python-side join;
                 # the blob is built in-crossing only for passthrough
@@ -2134,6 +2086,7 @@ class TpuEngine:
                 )
             if sp is not None:
                 self._stat_stage("t_explode_find2", t0)
+                self._note_parse_path("structural")
                 launch.ranges = sp.ranges
                 n = sp.n
                 launch.n = n
@@ -2144,8 +2097,10 @@ class TpuEngine:
                 self._dispatch_columnar_fused(launch, plan, sp, store_key)
                 return
             # STAGED lane: framing parse + k-path JSON walk in one scalar
-            # native crossing (rp_explode_find) — the parity oracle, and
-            # the measured pick on boxes where structural doesn't win
+            # native crossing (rp_explode_find) — the parity oracle, the
+            # ladder of the plans the structural one cannot serve, and
+            # where a library without the structural entry lands
+            self._note_parse_path("staged")
             fused = batch_codec.explode_and_find(
                 all_batches, paths, count=self._count_uncompress
             )
@@ -2201,118 +2156,11 @@ class TpuEngine:
         else:  # host: materialized lazily at harvest
             launch._exploded = exploded
 
-    # ------------------------------------------------------ parse-path probe
-    def _parse_path(self, plan, all_batches) -> str:
-        """Which parse ladder this launch runs: the measured per-engine
-        fused-vs-staged decision, gated by plan eligibility (nested paths
-        or general projections keep the staged ladder regardless). Until
-        a representative launch has probed, small launches take the known
-        staged path without pinning anything.
-
-        Same two-lock discipline as the columnar-backend probe: the RUN
-        lock serializes calibration EXECUTION (concurrent first launches
-        must not measure against each other's load), while the short
-        decision lock guards only the field — stats() readers never wait
-        behind the four ladder passes a calibration runs."""
-        if not self._structural_enabled or not plan.structural_eligible():
-            return "staged"
-        with self._parse_decision_lock:
-            decision = self._parse_decision
-        if decision is not None:
-            return decision
-        n = sum(b.header.record_count for b in all_batches)
-        if n < _PROBE_MIN_ROWS:
-            return "staged"
-        with self._parse_probe_run_lock:
-            with self._parse_decision_lock:
-                decision = self._parse_decision
-            if decision is None:
-                self._calibrate_parse_path(plan, all_batches)
-                with self._parse_decision_lock:
-                    decision = self._parse_decision
-        return decision
-
-    def _measure_parse_ratio(self, plan, all_batches) -> tuple[float, float]:
-        """(t_staged, t_structural) for this launch's REAL parse+extract
-        ladders, each best-of-2."""
-        paths = plan.flat_paths()
-        n = sum(b.header.record_count for b in all_batches)
-        n_pad = _bucket_rows(n)
-
-        def staged():
-            fused = batch_codec.explode_and_find(all_batches, paths)
-            if fused is None:
-                raise RuntimeError("staged native ladder unavailable")
-            ex, types, vs, ve = fused
-            cache = plan.make_cache_from_tables(ex, paths, types, vs, ve)
-            if plan.dev_cols:
-                plan.extract_device_inputs(
-                    ex.joined, ex.offsets, ex.sizes, n_pad, cache
-                )
-            if not plan.passthrough:
-                plan.extract_projection(ex.joined, ex.offsets, ex.sizes, cache)
-
-        def structural():
-            sp = batch_codec.explode_find_structural(
-                all_batches, paths, need_joined=plan.byte_identity
-            )
-            if sp is None:
-                raise RuntimeError("structural native ladder unavailable")
-            plan.extract_fused(sp, n_pad)
-
-        t_staged = t_structural = float("inf")
-        for _ in range(2):
-            t0 = time.perf_counter()
-            staged()
-            t_staged = min(t_staged, time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            structural()
-            t_structural = min(t_structural, time.perf_counter() - t0)
-        return t_staged, t_structural
-
-    def _calibrate_parse_path(self, plan, all_batches) -> None:
-        """One-shot engine-sticky fused-vs-staged pin off the first
-        representative columnar launch. Caller holds the probe RUN lock;
-        the decision fields publish under the short decision lock."""
-        try:
-            t_staged, t_structural = self._measure_parse_ratio(
-                plan, all_batches
-            )
-        except Exception as exc:
-            # a box whose probe blows up runs the known staged ladder
-            # forever — classified so the demotion is visible on /metrics
-            faults.note_failure("parse_calibration", exc)
-            logger.exception("parse-path calibration failed; keeping staged")
-            with self._parse_decision_lock:
-                self._parse_decision = "staged"
-            self.governor.record(
-                governor.PARSE_PATH,
-                "staged",
-                f"calibration FAILED ({faults.kind_of(exc)}); keeping the "
-                "scalar staged ladder",
-                {"error": faults.kind_of(exc)},
-            )
-            return
-        ratio = t_staged / t_structural if t_structural > 0 else 0.0
-        decision = "structural" if ratio >= governor.PROBE_MARGIN else "staged"
-        probe = {
-            "t_staged_ms": round(t_staged * 1e3, 3),
-            "t_structural_ms": round(t_structural * 1e3, 3),
-            "speedup": round(ratio, 3),
-            "chosen": decision,
-        }
-        with self._parse_decision_lock:
-            self._parse_decision = decision
-            self._parse_probe = probe
-        logger.info("parse-path calibration: %s", probe)
-        self.governor.record(
-            governor.PARSE_PATH,
-            decision,
-            f"measured parse+extract ladders: staged {t_staged * 1e3:.3f} ms"
-            f" vs structural {t_structural * 1e3:.3f} ms (structural must "
-            f"win {governor.PROBE_MARGIN}x; engine-sticky)",
-            dict(probe),
-        )
+    def _note_parse_path(self, ladder: str) -> None:
+        """``stats()["parse_path"]``: the parse ladder the last columnar
+        launch ran."""
+        with self._stats_lock:
+            self._parse_path = ladder
 
     def _count_colcache(self, hit: bool) -> None:
         if hit:
@@ -2485,7 +2333,6 @@ class TpuEngine:
             or plan.mode != "columnar"
             or not plan.dev_cols
             or self._force_mode == "columnar_host"
-            or self._mesh is not None
         ):
             return False
         decision = runner.decision
@@ -2528,9 +2375,8 @@ class TpuEngine:
             )
             return False
         parts = runner.shard_ranges(counts)
-        # parse ladder decided ONCE per launch (may calibrate, inline) —
-        # shard workers must not race the calibration or mix ladders
-        structural = self._parse_path(plan, all_batches) == "structural"
+        structural = plan.structural_eligible()
+        self._note_parse_path("structural" if structural else "staged")
         paths = plan.flat_paths()
         # one COMMON row bucket across every device shard: the stacked
         # SPMD input is one [D, n_pad, ...] array per column
@@ -2879,7 +2725,7 @@ class TpuEngine:
             return
         use_host = self._force_mode == "columnar_host"
         backend = TpuEngine.sticky_columnar_backend()
-        if self._force_mode is None and self._mesh is None:
+        if self._force_mode is None:
             if backend is None:
                 if n_pad >= _PROBE_MIN_ROWS:
                     # double-checked under the probe RUN lock:
@@ -2923,7 +2769,7 @@ class TpuEngine:
         else:
             def leg():
                 faults.inject(faults.DEVICE_DISPATCH)
-                fn = plan.compile_device(self._mesh)
+                fn = plan.compile_device()
                 args = dev_cols
                 if args is None:
                     if entry is not None:
@@ -3092,7 +2938,7 @@ class TpuEngine:
         t_host = _t.perf_counter() - t0
 
         def _device_leg() -> float:
-            fn = plan.compile_device(None)
+            fn = plan.compile_device()
             np.asarray(fn(*cols))  # compile + first-launch warmup
             t1 = _t.perf_counter()
             np.asarray(fn(*cols))  # steady-state launch + fetch

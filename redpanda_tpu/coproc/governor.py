@@ -1,11 +1,11 @@
 """The coproc governor: one decision plane for every adaptive choice.
 
 The engine carries a family of measured probes — the columnar
-device-vs-host backend probe, the parse-ladder probe, the mesh-vs-single
-calibration, the device_lz4 keep-or-kill probe, the circuit breakers and
-the harvest framing path — and before this module each made its call in its
-own corner: a self-demoted lane or a tripped breaker could silently halve
-the headline rb/s with no forensic trail beyond scattered stats keys. The
+device-vs-host backend probe, the mesh-vs-single calibration, the
+device_lz4 keep-or-kill probe, the circuit breakers and the harvest
+framing path — and before this module each made its call in its own
+corner: a self-demoted lane or a tripped breaker could silently halve the
+headline rb/s with no forensic trail beyond scattered stats keys. The
 governor routes every such decision through ONE policy surface:
 
 - **Decision journal** — a bounded in-memory ring of every adaptive
@@ -60,11 +60,6 @@ DEVICE_LZ4 = "device_lz4"
 BREAKER = "breaker"
 HARVEST_PATH = "harvest_path"
 DEADLINE = "deadline"
-# structural-index parse: the engine's measured fused-vs-staged probe
-# (staged = scalar rp_explode_find ladder + per-column gathers; structural
-# = rp_explode_find2 + one fused extraction crossing) journals its pick
-# here — slower boxes self-demote honestly (PROBE_MARGIN)
-PARSE_PATH = "parse_path"
 # device-resident column cache (coproc/colcache.py): budget/eviction
 # pressure notes land here when the cache has to shed entries
 COLUMN_CACHE = "column_cache"
@@ -95,12 +90,12 @@ TREND = "trend"
 
 DOMAINS = (
     COLUMNAR_BACKEND, DEVICE_LZ4, BREAKER, HARVEST_PATH, DEADLINE,
-    PARSE_PATH, COLUMN_CACHE, LOCKWATCH, LEAKWATCH, MESH, ADMISSION, TREND,
+    COLUMN_CACHE, LOCKWATCH, LEAKWATCH, MESH, ADMISSION, TREND,
 )
 
-# A measured A/B (parse ladder, mesh vs single device, the raft device
-# plane) pins the new road only when it beats the known one by this
-# ratio: a borderline reading keeps the predictable path.
+# A measured A/B (mesh vs single device, the raft device plane) pins the
+# new road only when it beats the known one by this ratio: a borderline
+# reading keeps the predictable path.
 PROBE_MARGIN = 1.25
 
 # fault domains that get their own breaker + adaptive deadline. Each
@@ -146,7 +141,6 @@ _STATE_ENCODING: dict[str, dict[str, float]] = {
     COLUMNAR_BACKEND: {"host": 0.0, "device": 1.0},
     DEVICE_LZ4: {"host": 0.0, "device": 1.0},
     HARVEST_PATH: {"padded": 0.0, "gather": 1.0},
-    PARSE_PATH: {"staged": 0.0, "structural": 1.0},
     MESH: {"single": 0.0, "mesh": 1.0},
 }
 
@@ -980,7 +974,6 @@ class Governor:
             COLUMNAR_BACKEND: modes.get(COLUMNAR_BACKEND),
             DEVICE_LZ4: modes.get(DEVICE_LZ4),
             HARVEST_PATH: modes.get(HARVEST_PATH),
-            PARSE_PATH: modes.get(PARSE_PATH),
             MESH: modes.get(MESH),
             ADMISSION: modes.get(ADMISSION),
             "autotune": self.autotune_snapshot(),

@@ -104,7 +104,7 @@ class MeshRunner:
         self.mesh = partition_mesh(devices=devices)
         self.n_devices = len(devices)
         self._probe_enabled = bool(probe)
-        # two-lock discipline (the columnar-backend / parse-path shape):
+        # two-lock discipline (the columnar-backend probe's shape):
         # the RUN lock serializes calibration EXECUTION; the short
         # decision lock guards the fields so stats() readers never wait
         # behind a calibration's timed passes
@@ -210,7 +210,7 @@ class MeshRunner:
             mesh_fn = self.predicate_fn(plan)
             args = self.stack_and_put(stacked)
             np.asarray(mesh_fn(*args))  # compile + warmup off the clock
-            single_fn = plan.compile_device(None)
+            single_fn = plan.compile_device()
             np.asarray(single_fn(*flat))
             for _ in range(2):
                 t0 = time.perf_counter()

@@ -339,15 +339,19 @@ class ScriptContext:
 
     async def _see_read_ahead_out(self, ticket) -> None:
         """Between a tick's two executor calls: wait for what is being read
-        ahead before the harvest goes out. The harvest's host stages (gather
-        or rebuild, and above all the seal: a compress and a CRC a batch)
-        drop and retake the interpreter lock once a batch, and beside a
-        loop thread that is reading, each retake waits out a partition's
-        read: measured on the chip, the seal took 2.4x and 5.8x its time
-        and ate the overlap (PERF.md section 6, PR 36). The submit's
-        crossings (explode, pack) and the launch's transfer, which is in
-        flight from the dispatch on, are what the read overlaps; the rest of
-        it runs here in one stretch, with the worker idle."""
+        ahead before the harvest goes out. The submit's crossings (explode,
+        pack) and the launch's transfer, which is in flight from the
+        dispatch on, are what the read overlaps; the rest of it runs here
+        in one stretch, with the worker idle. Measured on the chip with
+        this wait taken out (PR 38, two pairs a cell, over the
+        one-crossing seal, which no longer pays beside a reading loop):
+        the read then runs beside the harvest's Python and is the slower
+        for it (28 -> 34.5 ms a tick, 10 ms of it no longer hidden), the
+        hand-off back grows (3.7 -> 5.7 ms), and json64p-v1.catchup
+        drained 8.2% and 2.2% slower, json64p-where.catchup 4.8% and
+        6.4% slower (its short engine phase ends before the read has had
+        its turns of the loop), nexmark64p-q1.catchup the same to 0.2%
+        (ROADMAP.md A1 (6))."""
         ahead = self._ahead
         if ahead is None or ahead.task.done():
             return

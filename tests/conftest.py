@@ -2,7 +2,7 @@
 
 Tests run on a virtual 8-device CPU mesh so that every sharding/collective
 code path is exercised without TPU hardware (the driver separately dry-runs
-the multi-chip path; bench.py runs on the real chip).
+the multi-chip path; benchmarks/run.py and chip_smoke.py run on the real chip).
 
 The env vars MUST be set before jax is imported anywhere.
 """

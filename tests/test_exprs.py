@@ -208,24 +208,34 @@ class TestSerde:
 
 
 class TestEngineColumnar:
-    def _run(self, spec, docs, **engine_kw):
+    def _run(self, spec, docs, n_batches=1, stats=None, **engine_kw):
+        """The kept values of one launch over ``docs`` in ``n_batches``
+        batches; ``stats``, when given, takes the engine's afterwards."""
         vals = [json.dumps(d, separators=(",", ":")).encode() for d in docs]
-        recs = [
-            Record(offset_delta=i, timestamp_delta=i, value=v)
-            for i, v in enumerate(vals)
+        per = -(-len(vals) // n_batches)
+        batches = [
+            RecordBatch.build(
+                [
+                    Record(offset_delta=i, timestamp_delta=i, value=v)
+                    for i, v in enumerate(vals[s : s + per])
+                ],
+                base_offset=s, first_timestamp=5,
+            )
+            for s in range(0, len(vals), per)
         ]
-        batch = RecordBatch.build(recs, base_offset=0, first_timestamp=5)
         eng = TpuEngine(row_stride=256, **engine_kw)
         try:
             codes = eng.enable_coprocessors([(1, spec.to_json(), ("t",))])
             assert codes[0] == 0
-            req = ProcessBatchRequest([ProcessBatchItem(1, NTP.kafka("t", 0), [batch])])
+            req = ProcessBatchRequest([ProcessBatchItem(1, NTP.kafka("t", 0), batches)])
             reply = eng.process_batch(req)
             assert len(reply.items) == 1
             out = []
             for b in reply.items[0].batches:
                 assert b.verify_kafka_crc()
                 out.extend(r.value for r in b.records())
+            if stats is not None:
+                stats.update(eng.stats())
             return out
         finally:
             eng.shutdown()
@@ -329,14 +339,18 @@ class TestEngineColumnar:
         eng.shutdown()
 
     def test_mesh_columnar(self, eight_devices):
-        from redpanda_tpu.parallel.mesh import partition_mesh
-
-        mesh = partition_mesh(8)
+        # the chips the way a broker reaches them: the mesh lane, which
+        # declines a launch of one batch
         spec = where(
             (field("level") == "error") & (field("code") >= 100)
         ) | map_project(Int("code"), Str("msg", 16))
-        out_mesh = self._run(spec, DOCS * 6, mesh=mesh)
-        out_single = self._run(spec, DOCS * 6)
+        stats: dict = {}
+        out_mesh = self._run(
+            spec, DOCS * 6, n_batches=8, stats=stats,
+            mesh_devices=8, mesh_backend="cpu", mesh_probe=False,
+        )
+        assert stats["n_mesh_launches"] == 1
+        out_single = self._run(spec, DOCS * 6, n_batches=8)
         assert out_mesh == out_single
 
     def test_contains_window_with_merged_width(self):
@@ -403,12 +417,17 @@ class TestEngineColumnar:
         req = ProcessBatchRequest([ProcessBatchItem(1, NTP.kafka("t", 0), [batch])])
         eng.process_batch(req)
         st = eng.stats()
-        for k in ("t_extract_pred", "t_dispatch", "t_fetch",
+        for k in ("t_dispatch", "t_fetch",
                   "t_rebuild", "bytes_h2d", "bytes_d2h", "n_records"):
             assert k in st, k
-        # columnar launches use the FUSED explode+find pass when the native
-        # symbol exists, the split stages otherwise
-        assert "t_explode_find" in st or ("t_explode" in st and "t_find" in st)
+        # an eligible plan's launch runs the structural ladder when the
+        # native entry exists, the staged one (fused explode+find, or the
+        # split stages without the library) otherwise
+        if st["parse_path"] == "structural":
+            assert "t_explode_find2" in st and "t_fused_extract" in st
+        else:
+            assert "t_extract_pred" in st
+            assert "t_explode_find" in st or ("t_explode" in st and "t_find" in st)
         assert st["bytes_d2h"] < st["bytes_h2d"]
         assert st["n_records"] == len(DOCS)
         eng.shutdown()

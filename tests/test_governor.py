@@ -3,8 +3,8 @@
 Four sides of coproc/governor.py:
 
 - the decision journal: entries for every decision domain under real
-  launches (columnar backend probe, parse-ladder probe, device_lz4
-  probe, breaker transitions, harvest-path mode),
+  launches (columnar backend probe, device_lz4 probe, breaker
+  transitions, harvest-path mode),
   bounded capacity, monotonic seq, per-entry inputs/verdict/reason/config;
 - adaptive deadlines: provably track the observed stage p99.9 against an
   injected histogram source, never undercut the configured static floor,
@@ -105,8 +105,8 @@ def _domains():
 # ------------------------------------------------------------ decision journal
 def test_journal_covers_the_engine_domains_under_real_launches(monkeypatch):
     """Every decision the engine takes lands in the journal from REAL code
-    paths: a big columnar launch drives the backend probe, the parse-ladder
-    probe and the harvest-path verdict; an armed mask-fetch fault drives a
+    paths: a big columnar launch drives the backend probe and the
+    harvest-path verdict; an armed mask-fetch fault drives a
     breaker transition; the lz4 probe drives device_lz4."""
     TpuEngine.reset_columnar_probe()
     # pure filter => passthrough plan => gather framing; 64 batches x 32
@@ -120,10 +120,9 @@ def test_journal_covers_the_engine_domains_under_real_launches(monkeypatch):
         EnableResponseCode.success
     ]
     big = _req(parts=64, n=32)
-    engine.process_batch(big)  # first columnar launch: both probes
+    engine.process_batch(big)  # first columnar launch: the backend probe
     got = _domains()
     assert governor.COLUMNAR_BACKEND in got
-    assert governor.PARSE_PATH in got
     assert governor.HARVEST_PATH in got
 
     # breaker transition through the real data path: a starved harvester
@@ -149,7 +148,7 @@ def test_journal_covers_the_engine_domains_under_real_launches(monkeypatch):
     got = _domains()
     assert governor.DEVICE_LZ4 in got
     for domain in (
-        governor.COLUMNAR_BACKEND, governor.PARSE_PATH, governor.DEVICE_LZ4,
+        governor.COLUMNAR_BACKEND, governor.DEVICE_LZ4,
         governor.BREAKER, governor.HARVEST_PATH,
     ):
         assert domain in got, f"missing journal domain {domain}"
@@ -164,9 +163,9 @@ def test_journal_covers_the_engine_domains_under_real_launches(monkeypatch):
         assert isinstance(e["config"], dict)
         assert e["ts"] > 0
     # engine-made decisions carry the active-config snapshot
-    cal = [e for e in entries if e["domain"] == governor.PARSE_PATH][0]
+    cal = [e for e in entries if e["domain"] == governor.COLUMNAR_BACKEND][0]
     assert "device_deadline_ms" in cal["config"]
-    assert cal["inputs"].get("chosen") in ("staged", "structural")
+    assert cal["inputs"].get("chosen") in ("host", "device")
 
 
 def test_journal_bounded_capacity_and_summary():

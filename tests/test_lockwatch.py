@@ -124,9 +124,7 @@ def test_lockwatch_off_installs_no_wrapper():
     engine = TpuEngine(host_workers=2)
     try:
         assert not isinstance(engine._stats_lock, lockwatch.WatchedLock)
-        assert not isinstance(
-            engine._parse_decision_lock, lockwatch.WatchedLock
-        )
+        assert not isinstance(engine._compile_lock, lockwatch.WatchedLock)
         assert not isinstance(
             engine_mod._mask_claim_lock, lockwatch.WatchedLock
         )

@@ -12,6 +12,7 @@ multibyte, nested containers, null/empty values, truncated records.
 
 from __future__ import annotations
 
+import contextlib
 import json
 
 import numpy as np
@@ -245,10 +246,36 @@ def _fresh_probe():
     yield
 
 
+@contextlib.contextmanager
+def _ladder(monkeypatch, mode: str):
+    """Run the body on the ``structural`` ladder (the rule's pick for an
+    eligible plan) or on the ``staged`` one as its oracle: a library
+    without the structural entry answers None, and every launch (and mesh
+    shard) takes the fall-through the engine keeps for it."""
+    with monkeypatch.context() as m:
+        if mode == "staged":
+            m.setattr(
+                batch_codec, "explode_find_structural", lambda *a, **k: None
+            )
+        yield
+
+
+def _count_calls(monkeypatch, name: str, calls: dict) -> None:
+    real = getattr(batch_codec, name)
+
+    def counted(*a, **k):
+        calls[name] += 1
+        return real(*a, **k)
+
+    monkeypatch.setattr(batch_codec, name, counted)
+
+
 class TestEngineParity:
     @pytest.mark.parametrize("spec", [PROJ_SPEC, PASS_SPEC], ids=["proj", "pass"])
     @pytest.mark.parametrize("mesh", [0, 4], ids=["inline", "mesh"])
-    def test_structural_vs_staged_bit_identical(self, spec, mesh, eight_devices):
+    def test_structural_vs_staged_bit_identical(
+        self, spec, mesh, eight_devices, monkeypatch
+    ):
         # the mesh cell runs the same ladders per device shard
         # (_shard_ladder) on a 2-worker pool; force_mode unset there (a
         # columnar_host pin declines the lane)
@@ -263,26 +290,27 @@ class TestEngineParity:
             if mesh
             else {}
         )
-        for mode, kw in (
-            ("staged", dict(structural_parse=False)),
-            ("structural", dict(structural_parse=True, structural_probe=False)),
-        ):
-            engine = _engine(**lane, **kw)
+        for mode in ("staged", "structural"):
+            engine = _engine(**lane)
             try:
                 codes = engine.enable_coprocessors(
                     [(1, spec.to_json(), ("bench",))]
                 )
                 assert codes == [0]
-                replies[mode] = (
-                    _payloads(engine.process_batch(req)),
-                    _payloads(engine.process_batch(adv)),
-                )
+                with _ladder(monkeypatch, mode):
+                    replies[mode] = (
+                        _payloads(engine.process_batch(req)),
+                        _payloads(engine.process_batch(adv)),
+                    )
                 stats = engine.stats()
             finally:
                 engine.shutdown()
             if mesh:
                 assert stats["n_mesh_launches"] == 2
-            if mode == "structural" and _native_available():
+            if mode == "staged":
+                assert stats.get("t_explode_find2", 0.0) == 0.0
+                assert stats.get("t_shard_explode_find2", 0.0) == 0.0
+            elif _native_available():
                 if mesh:
                     # both launches fanned out: the structural lane ran
                     # per shard (per-shard CPU-seconds under t_shard_*)
@@ -298,22 +326,24 @@ class TestEngineParity:
         # a .so without the structural symbols (or no native at all) must
         # degrade to the staged/python ladder with identical output
         req = _request(n_items=2, records=16)
-        engine = _engine(structural_parse=False)
+        engine = _engine()
         try:
             engine.enable_coprocessors([(1, PROJ_SPEC.to_json(), ("bench",))])
-            baseline = _payloads(engine.process_batch(req))
+            with _ladder(monkeypatch, "staged"):
+                baseline = _payloads(engine.process_batch(req))
+            assert engine.stats()["parse_path"] == "staged"
         finally:
             engine.shutdown()
         monkeypatch.setattr(batch_codec, "_native", lambda: None)
         monkeypatch.setattr(cp, "_native", lambda: None)
-        engine = _engine(structural_parse=True, structural_probe=False)
+        engine = _engine()
         try:
             engine.enable_coprocessors([(1, PROJ_SPEC.to_json(), ("bench",))])
             assert _payloads(engine.process_batch(req)) == baseline
         finally:
             engine.shutdown()
 
-    def test_zero_record_and_compressed_batches(self):
+    def test_zero_record_and_compressed_batches(self, monkeypatch):
         recs = [
             Record(offset_delta=i, value=v)
             for i, v in enumerate(ADVERSARIAL_VALUES[:6])
@@ -328,61 +358,68 @@ class TestEngineParity:
             [ProcessBatchItem(1, NTP.kafka("bench", 0), batches)]
         )
         out = {}
-        for mode, kw in (
-            ("staged", dict(structural_parse=False)),
-            ("structural", dict(structural_parse=True, structural_probe=False)),
-        ):
-            engine = _engine(**kw)
+        for mode in ("staged", "structural"):
+            engine = _engine()
             try:
                 engine.enable_coprocessors([(1, PASS_SPEC.to_json(), ("bench",))])
-                out[mode] = _payloads(engine.process_batch(req))
+                with _ladder(monkeypatch, mode):
+                    out[mode] = _payloads(engine.process_batch(req))
             finally:
                 engine.shutdown()
         assert out["staged"] == out["structural"]
 
 
 @pytest.mark.skipif(not _native_available(), reason="native structural symbols unavailable")
-class TestParsePathProbe:
-    def test_probe_pins_and_journals(self):
-        engine = _engine(structural_parse=True, structural_probe=True)
-        try:
-            engine.enable_coprocessors([(1, PROJ_SPEC.to_json(), ("bench",))])
-            # big enough to be representative (>= _PROBE_MIN_ROWS records)
-            engine.process_batch(_request(n_items=32, records=32))
-            stats = engine.stats()
-            assert stats["parse_path"] in ("staged", "structural")
-            probe = stats["parse_probe"]
-            assert probe["chosen"] == stats["parse_path"]
-            assert probe["t_staged_ms"] > 0 and probe["t_structural_ms"] > 0
-            entries = gov_mod.journal.entries(domain=gov_mod.PARSE_PATH)
-            assert any(
-                e["engine"] == engine.governor.engine_tag
-                and e["verdict"] == stats["parse_path"]
-                for e in entries
-            )
-        finally:
-            engine.shutdown()
+class TestParsePathRule:
+    """The ladder is the plan's (``structural_eligible``), at every launch
+    size, from the first launch: nothing is measured and nothing pinned."""
 
-    def test_small_launches_do_not_pin(self):
-        engine = _engine(structural_parse=True, structural_probe=True)
+    @pytest.mark.parametrize(
+        "n_items,records", [(1, 1), (2, 32), (64, 64)],
+        ids=["1row", "64rows", "4096rows"],
+    )
+    def test_eligible_plan_runs_structural_from_first_launch(
+        self, n_items, records, monkeypatch
+    ):
+        calls = {"explode_find_structural": 0, "explode_and_find": 0}
+        for name in calls:
+            _count_calls(monkeypatch, name, calls)
+        seq0 = gov_mod.journal.summary()["seq"]
+        engine = _engine()
         try:
             engine.enable_coprocessors([(1, PROJ_SPEC.to_json(), ("bench",))])
-            engine.process_batch(_request(n_items=2, records=16))
             assert engine.stats()["parse_path"] is None
-        finally:
-            engine.shutdown()
-
-    def test_config_pin_staged(self):
-        engine = _engine(structural_parse=False)
-        try:
-            engine.enable_coprocessors([(1, PROJ_SPEC.to_json(), ("bench",))])
-            engine.process_batch(_request(n_items=32, records=32))
+            engine.process_batch(_request(n_items=n_items, records=records))
             stats = engine.stats()
-            assert stats["parse_path"] == "staged"
-            assert "parse_probe" not in stats
-            assert stats.get("t_explode_find2", 0.0) == 0.0
         finally:
             engine.shutdown()
+        assert stats["n_launches"] == 1
+        assert stats["n_records"] == n_items * records
+        assert stats["parse_path"] == "structural"
+        assert stats["t_explode_find2"] > 0.0
+        assert stats.get("t_explode_find", 0.0) == 0.0
+        # the launch's own pass and no other: no calibration
+        assert calls == {"explode_find_structural": 1, "explode_and_find": 0}
+        assert "parse_probe" not in stats
+        assert not [
+            e for e in gov_mod.journal.entries(domain="parse_path")
+            if e["seq"] > seq0
+        ]
+
+    def test_ineligible_plan_runs_staged(self):
+        engine = _engine()
+        try:
+            engine.enable_coprocessors(
+                [(1, where(field("a.b") == 1).to_json(), ("bench",))]
+            )
+            engine.process_batch(_request(n_items=2, records=16))
+            stats = engine.stats()
+        finally:
+            engine.shutdown()
+        assert stats["parse_path"] == "staged"
+        assert stats.get("t_explode_find2", 0.0) == 0.0
+        # a nested path leaves the one-crossing find too: explode, then find
+        assert stats.get("t_explode_find", 0.0) + stats.get("t_explode", 0.0) > 0.0
 
 
 class TestColumnCache:

@@ -89,16 +89,16 @@ def bench_explode_find(secs: float) -> dict:
     also reported so the kernel and the fusion are attributable
     separately.
 
-    Shapes: ``flat`` is the bench.py 64p headline shape (~1KB records, one
+    Shapes: ``flat`` is the 64-partition catch-up shape (~1KB records, one
     long string value — the scalar walker's memchr best case, where the
     two ladders are closest); ``nested`` buries an unselected nested
     container the scalar walker must skip byte-at-a-time; ``stringified``
     carries a stringified-JSON msg (escaped quotes everywhere — the
     memchr-restart pathology, and THE log-analytics shape the structural
     escape mask exists for). --assert-explode-speedup gates
-    ``explode_find_speedup`` = staged/structural on the stringified shape;
-    the engine's own parse-path probe decides per box which ladder
-    production launches take (BENCH json records its verdict)."""
+    ``explode_find_speedup`` = staged/structural on the stringified shape.
+    The engine takes the structural ladder for every plan it can serve
+    (``ColumnarPlan.structural_eligible``)."""
     from redpanda_tpu.coproc import batch_codec
     from redpanda_tpu.coproc.column_plan import plan_spec
     from redpanda_tpu.models.record import Record, RecordBatch

@@ -8,7 +8,7 @@ PRs stop being unjudged by definition." This module is that diff.
 Usage::
 
     python -m tools.slodiff SLO_r10.json SLO_r14.json [--noise-band-pct 20]
-    python -m tools.slodiff BENCH_r02.json BENCH_r06.json --json
+    python -m tools.slodiff SLO_r14.json SLO_r17.json --json
 
 Verdict vocabulary:
 
@@ -171,7 +171,7 @@ def diff_slo(old: dict, new: dict, band_pct: float) -> dict:
     # load, so "p99 worse while throughput ROSE beyond the band" is an
     # ambiguous reading, not clean evidence of a code regression — say so
     # on the diff's face (the judge should re-run at matched load or
-    # bracket with a same-code A/A, exactly what bench.py's aa_skew does)
+    # bracket with a same-code A/A control)
     prod = next(
         (t for t in thr_items if t["name"] == "produced_records_per_s"),
         None,
