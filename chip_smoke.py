@@ -294,6 +294,98 @@ async def _drive_served_path(kafka_port, values, expected, deadline):
         await client.close()
 
 
+async def _drive_unmet_bucket(kafka_port, values, already: int, deadline):
+    """One batch of ``values`` to partition 0, and what the payload script
+    materialized of it (its output follows the ``already`` records the
+    backlog left in that partition)."""
+    from redpanda_tpu.kafka.client import KafkaClient
+    from redpanda_tpu.models.record import Record, RecordBatch
+
+    client = await KafkaClient([("127.0.0.1", kafka_port)]).connect()
+    try:
+        batch = RecordBatch.build([
+            Record(offset_delta=i, timestamp_delta=i, value=v)
+            for i, v in enumerate(values)
+        ], first_timestamp=2_000_000)
+        await client.produce_batches(TOPIC, 0, [batch], acks=-1)
+        mtopic, out, offset, idle = f"{TOPIC}.${PAYLOAD_SCRIPT}$", [], 0, 0
+        while time.monotonic() < deadline and idle < 3:
+            batches, _hwm = await client.fetch(mtopic, 0, offset, max_bytes=4 << 20)
+            for b in batches:
+                out.extend(r.value for r in b.records())
+                offset = b.last_offset + 1
+            if len(out) > already and not batches:
+                idle += 1
+            elif not batches:
+                await asyncio.sleep(0.2)
+        return out[already:]
+    finally:
+        await client.close()
+
+
+def precompile_check(ports: dict, seed: int, already: int, deadline: float) -> dict:
+    """Stage A's last step (PR 45): the payload script's ladder of device
+    programs was built at the deploy, off the serving path. Wait for
+    ``coproc_programs_ready`` to read the whole ladder, then launch at a
+    row bucket NO launch has met: no ``t_compile`` sample may appear, and
+    the records come back as the reference has them."""
+    from redpanda_tpu.coproc import reference, wasm_event
+
+    out: dict = {"failures": []}
+    fails = out["failures"]
+    sid = str(wasm_event.WasmEvent(PAYLOAD_SCRIPT, wasm_event.DEPLOY).script_id)
+    while True:
+        status = json.loads(_admin_get(ports["admin"], "/v1/coproc/status"))
+        ladder = (status["stats"].get("programs_ready") or {}).get(sid) or {}
+        if ladder.get("state") not in (None, "building") or time.monotonic() > deadline:
+            break
+        time.sleep(0.2)
+    metrics = _admin_get(ports["admin"], "/metrics").decode()
+    gauge = _metric_total(metrics, "coproc_programs_ready")
+    out.update(ladder=ladder, coproc_programs_ready=gauge)
+    if ladder.get("state") != "ready" or gauge < len(ladder.get("buckets") or [None]):
+        fails.append(f"the payload script's ladder is not ready: {ladder}, gauge {gauge}")
+        return out
+    stats = status["stats"]
+    met = {c["n_pad"] for c in stats["compiled_programs"]
+           if c["lane"] == "payload" and "t_precompile_s" not in c}
+    unmet = [b for b in ladder["buckets"] if b not in met and b <= 1024]
+    if not unmet:
+        fails.append(f"no small bucket is left that no launch has met: {sorted(met)}")
+        return out
+    bucket = unmet[0]
+    values = reference.make_documents(seed + 1, 1, bucket // 2 + 1)[0]
+    want = [o for o in map(reference_fns(BROKER_ROW_STRIDE)[PAYLOAD_SCRIPT], values)
+            if o is not None]
+    got = asyncio.run(_drive_unmet_bucket(ports["kafka"], values, already, deadline))
+    after = json.loads(_admin_get(ports["admin"], "/v1/coproc/status"))["stats"]
+    metrics_after = _admin_get(ports["admin"], "/metrics").decode()
+    compile_samples = [
+        sum(float(line.rsplit(" ", 1)[1]) for line in text.splitlines()
+            if "coproc_stage_latency_us_count" in line and 'stage="compile"' in line)
+        for text in (metrics, metrics_after)
+    ]
+    now_met = {c["n_pad"] for c in after["compiled_programs"]
+               if c["lane"] == "payload" and "t_precompile_s" not in c}
+    out.update(
+        bucket=bucket, rows=len(values), records_expected=len(want),
+        records_materialised=len(got), reference_match=got == want,
+        n_compiles=[stats.get("n_compiles", 0), after.get("n_compiles", 0)],
+        compile_samples=compile_samples, n_precompiles=after.get("n_precompiles", 0),
+        n_launch_cuts=after.get("n_launch_cuts", 0),
+    )
+    if got != want:
+        fails.append(f"bucket {bucket}: the materialized records differ from the reference")
+    if bucket not in now_met:
+        fails.append(f"no launch met bucket {bucket}: launches met {sorted(now_met)}")
+    if after.get("n_compiles", 0) != stats.get("n_compiles", 0) or (
+        compile_samples[0] != compile_samples[1]
+    ):
+        fails.append(f"a first run on the serving path: n_compiles {out['n_compiles']}, "
+                     f"t_compile samples {compile_samples}")
+    return out
+
+
 def stage_a(
     seed: int,
     partitions: int = PARTITIONS,
@@ -429,6 +521,12 @@ def stage_a(
             (native.get("symbols") or {"": False}).values()
         ):
             fails.append(f"native library incomplete in the broker: {native}")
+        # programs built before serving: a launch at a bucket no launch has
+        # met is no first run
+        result["precompile"] = pre = precompile_check(
+            ports, seed, len(got[PAYLOAD_SCRIPT][0]), time.monotonic() + 120.0
+        )
+        fails += [f"precompile: {f}" for f in pre.pop("failures")]
         return result
     except Exception:
         sys.stderr.write("---- broker log tail ----\n" + _tail(log_path) + "\n")

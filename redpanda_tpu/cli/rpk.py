@@ -472,6 +472,18 @@ async def cmd_debug(args) -> int:
                 f"rows_per_device={mesh.get('rows_per_device')}"
             )
         stats = body.get("stats") or {}
+        for sid, ladder in sorted((stats.get("programs_ready") or {}).items()):
+            # coproc_programs_ready{script=}: a payload script's row buckets
+            # whose device program was built ahead of need, at the deploy
+            buckets = ladder.get("buckets") or []
+            span = f" ({buckets[0]}-{buckets[-1]} rows)" if buckets else ""
+            print(
+                f"programs: script {sid}: {len(buckets)} row buckets ready{span} "
+                f"of a ladder to {ladder.get('top')} rows, {ladder.get('state')}; "
+                f"built off the serving path ({int(stats.get('n_precompiles', 0))} "
+                f"programs in {stats.get('t_precompile', 0.0):.1f} s), "
+                f"{int(stats.get('n_launch_cuts', 0))} launches cut to a ready bucket"
+            )
         if stats.get("n_json_rows"):
             # coproc_json_rows_total{outcome="read|malformed|path_miss"}
             print(

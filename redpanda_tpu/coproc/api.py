@@ -20,6 +20,7 @@ import logging
 from redpanda_tpu.coproc import faults, wasm_event
 from redpanda_tpu.coproc.engine import EnableResponseCode, TpuEngine
 from redpanda_tpu.coproc.pacemaker import Pacemaker
+from redpanda_tpu.metrics import registry
 from redpanda_tpu.models.fundamental import COPROC_INTERNAL_TOPIC, NTP
 from redpanda_tpu.cluster.topic_table import TopicConfig
 
@@ -93,6 +94,9 @@ class CoprocApi:
                 if plane is not None
                 else None
             ),
+            # the pacemaker's read of one tick a partition: with the cap it
+            # bounds a launch, which sizes the ladders of device programs
+            tick_read_bytes=max_batch,
         )
         self.pacemaker = Pacemaker(
             broker, self.engine,
@@ -282,11 +286,27 @@ class CoprocApi:
             )]
         else:
             codes = self.engine.enable_coprocessors(
-                [(ev.script_id, ev.spec_json, ev.input_topics)]
+                [(ev.script_id, ev.spec_json, ev.input_topics)],
+                partitions={
+                    t: len(md.assignments)
+                    for t in ev.input_topics
+                    if (md := self.broker.topic_table.get(t)) is not None
+                },
             )
         if codes[0] != EnableResponseCode.success:
             logger.error("enable %s failed: %s", ev.name, codes[0].name)
             return
+        # the script's ready row buckets, on /metrics from the deploy on (a
+        # redeploy under the name re-binds the series; a removed script's
+        # reads 0)
+        engine, sid = self.engine, ev.script_id
+        registry.gauge(
+            "coproc_programs_ready",
+            lambda: float(len(engine.programs_ready(sid))),
+            "Row buckets of a payload script whose device program is built "
+            "(ahead of need, off the serving path)",
+            script=ev.name,
+        )
         await self.pacemaker.add_source(ev.name, ev.script_id, ev.input_topics)
         self._active[ev.name] = ev
         logger.info("coprocessor %s enabled on %s", ev.name, list(ev.input_topics))

@@ -58,6 +58,7 @@ from redpanda_tpu.observability import probes, stages
 from redpanda_tpu.observability.trace import tracer
 from redpanda_tpu.ops.pipeline import (
     IN_META,
+    lower_packed_pipeline,
     make_packed_pipeline,
     make_packed_pipeline_host,
     unpack_reason,
@@ -170,6 +171,92 @@ def _bucket_rows(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+# The densest rows a launch's read is sized for: records an eighth of the
+# staging row wide. The ladder's top is the bucket of (the bytes the read
+# budget can hand one launch) / (row_stride / this); narrower records than
+# that make a launch the engine cuts (_Ladder).
+_LADDER_ROWS_PER_STRIDE = 8
+
+
+class _CutResult(tuple):
+    """The device results of one launch the engine cut to a ready row
+    bucket: its parts in row order, fetched as one (_Launch._fetch_legs)."""
+
+    __slots__ = ()
+
+
+class _Ladder:
+    """One payload pipeline's device programs by row bucket, lowered and
+    compiled ahead of need on a thread of their own (``rptpu-precompile``),
+    smallest bucket first, through JAX's ahead-of-time path
+    (``jit(f).lower(shape).compile()``: the persistent compilation cache
+    serves it like any jit call). Keyed by the jitted function, which
+    ops/pipeline.py caches by spec: K scripts of one spec share one ladder.
+
+    A launch asks ``program_for(n_pad)``: the bucket's program when it is
+    ready; while the ladder is still building, or for a launch over its
+    top, the LARGEST ready bucket (the launch is cut to it, never compiled
+    inline); with nothing ready yet it waits for the first bucket. A
+    ladder whose build failed answers None and the script serves through
+    the first-run path (_try_device_leg), as every script did before."""
+
+    def __init__(self, fn, stride: int, top: int):
+        self.fn = fn
+        self.stride = stride  # a staged row's bytes: row_stride + IN_META
+        self.top = top
+        self.programs: dict[int, object] = {}  # n_pad -> jax.stages.Compiled
+        self.seconds: dict[int, float] = {}  # n_pad -> its build seconds
+        self.failed: str | None = None
+        self.stopped = False
+        self.thread: threading.Thread | None = None
+        self.cond = threading.Condition()
+
+    def buckets(self) -> list[int]:
+        out, b = [], 128
+        while b <= self.top:
+            out.append(b)
+            b *= 2
+        return out
+
+    def ready(self) -> list[int]:
+        with self.cond:
+            return sorted(self.programs)
+
+    def program_for(self, n_pad: int, wait_s: float):
+        """(program, its row bucket) for a launch padded to ``n_pad`` rows:
+        a bucket below ``n_pad`` means the launch is to be cut to it.
+        (None, n_pad): no ladder to serve from, take the first-run path."""
+        deadline = time.monotonic() + wait_s
+        with self.cond:
+            while True:
+                prog = self.programs.get(n_pad)
+                if prog is not None:
+                    return prog, n_pad
+                if self.failed is not None or self.stopped:
+                    return None, n_pad
+                below = [b for b in self.programs if b < n_pad]
+                if below:
+                    b = max(below)
+                    return self.programs[b], b
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return None, n_pad
+                self.cond.wait(timeout=left)
+
+    def wait_first(self, n: int, wait_s: float) -> bool:
+        """Block until the ``n`` smallest buckets are ready (or the build
+        has ended one way or the other)."""
+        want = self.buckets()[:n]
+        deadline = time.monotonic() + wait_s
+        with self.cond:
+            while not (self.failed or self.stopped or all(b in self.programs for b in want)):
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return False
+                self.cond.wait(timeout=left)
+        return True
 
 
 # serializes _mask_state transitions between the harvester and timed-out
@@ -327,7 +414,11 @@ class _Launch:
         jax.block_until_ready(dev)  # host bits (a bare launch in tests) pass through
         self._stat("t_wait_program", t0, parent=parent)
         t0 = _stage_t0("t_wait_d2h")
-        out = np.asarray(dev)
+        if isinstance(dev, _CutResult):
+            # a cut launch: its parts in row order are the launch's result
+            out = np.concatenate([np.asarray(part) for part in dev])
+        else:
+            out = np.asarray(dev)
         self._stat("t_wait_d2h", t0, parent=parent)
         return out
 
@@ -1245,6 +1336,10 @@ class TpuEngine:
         self._compile_lock = lockwatch.wrap(
             threading.Lock(), "TpuEngine._compile_lock"
         )
+        # jitted payload pipeline -> its ladder of programs built ahead of
+        # need (_Ladder); guarded by _stats_lock. Only an engine whose
+        # governor knows the launch's read budget has any (_ladder_top)
+        self._ladders: dict[object, _Ladder] = {}
         self._device_launches: dict[int, int] = defaultdict(int)
         # the parse ladder the last columnar launch ran ("structural" |
         # "staged"); None before one
@@ -1282,9 +1377,18 @@ class TpuEngine:
         The engine must not process batches after shutdown."""
         with self._stats_lock:
             t, self._harvester = self._harvester, None
+            ladders = list(self._ladders.values())
         if t is not None and t.is_alive():
             self._harvest_q.put(None)
             t.join(timeout=60.0)
+        for ladder in ladders:
+            # a compile under way cannot be interrupted: the builder ends
+            # after it (a daemon thread; nothing waits on it past this)
+            with ladder.cond:
+                ladder.stopped = True
+                ladder.cond.notify_all()
+            if ladder.thread is not None:
+                ladder.thread.join(timeout=5.0)
         if self._host_pool is not None:
             self._host_pool.shutdown()
         with self._stats_lock:  # concurrent shutdowns: swap-then-remove once
@@ -1368,9 +1472,14 @@ class TpuEngine:
 
     # ------------------------------------------------------------ control
     def enable_coprocessors(
-        self, scripts: list[tuple[int, str, tuple[str, ...]]]
+        self, scripts: list[tuple[int, str, tuple[str, ...]]],
+        partitions: dict[str, int] | None = None,
     ) -> list[EnableResponseCode]:
-        """scripts: [(script_id, spec_json, input_topics)]."""
+        """scripts: [(script_id, spec_json, input_topics)]. ``partitions``:
+        input topic -> its partition count, where the caller knows it (the
+        broker does): with the governor's read budget it sizes the ladder
+        of device programs a payload script's launches can reach, which
+        starts building here, off this thread (_start_ladder)."""
         out = []
         for script_id, spec_json, topics in scripts:
             if script_id in self._handles:
@@ -1397,6 +1506,10 @@ class TpuEngine:
                     self._pipelines[script_id] = make_packed_pipeline(
                         spec, self._row_stride, self._mask_result(plan)
                     )
+                    self._start_ladder(
+                        self._pipelines[script_id][0],
+                        sum((partitions or {}).get(t, 1) for t in topics),
+                    )
                 self._plans[script_id] = plan
             except Exception as exc:
                 # bad spec from the wire, not a broker fault: refuse the
@@ -1409,6 +1522,109 @@ class TpuEngine:
             )
             out.append(EnableResponseCode.success)
         return out
+
+    # ------------------------------------------------------------ ladders
+    def _ladder_top(self, partitions: int) -> int | None:
+        """The largest row bucket a launch of a script over ``partitions``
+        partitions can reach: the bytes the governor's read budget hands
+        one launch (Governor.launch_read_bytes: partitions x a tick's read
+        x the launch knob's cap) at the densest rows the staging row is
+        sized for. None: nothing here sizes a launch (a bare engine with
+        no pacemaker's budget behind it), so no ladder is built and every
+        program is a first run on the serving path, as before."""
+        read_bytes = self.governor.launch_read_bytes(partitions)
+        if read_bytes is None:
+            return None
+        return _bucket_rows(read_bytes * _LADDER_ROWS_PER_STRIDE // self._row_stride)
+
+    def _start_ladder(self, fn, partitions: int) -> None:
+        """Build ``fn``'s programs for every row bucket up to the top, on a
+        thread of their own. A second script of one spec finds the ladder
+        there (or taller than it needs) and builds nothing; one over more
+        partitions raises the top and the builder goes on from where the
+        ladder stands."""
+        top = self._ladder_top(partitions)
+        if top is None:
+            return
+        with self._stats_lock:
+            ladder = self._ladders.get(fn)
+            if ladder is None:
+                ladder = self._ladders[fn] = _Ladder(
+                    fn, self._row_stride + IN_META, top
+                )
+            with ladder.cond:
+                ladder.top = max(ladder.top, top)
+                building = ladder.thread is not None and ladder.thread.is_alive()
+                if building or ladder.failed is not None or (
+                    len(ladder.programs) == len(ladder.buckets())
+                ):
+                    return
+                ladder.thread = threading.Thread(
+                    target=self._build_ladder, args=(ladder,),
+                    name="rptpu-precompile", daemon=True,
+                )
+                ladder.thread.start()
+
+    def _build_ladder(self, ladder: _Ladder) -> None:
+        """The ladder's builder thread: one ``lower().compile()`` a bucket,
+        smallest first, each a stage ``coproc.precompile`` (an ``rp:``
+        annotation on this thread's line of a profile, ``t_precompile`` and
+        ``n_precompiles`` in stats()). The first failure ends the build: the
+        script keeps serving, through the first-run path."""
+        try:
+            self.resolve_device()
+            for n_pad in ladder.buckets():
+                with ladder.cond:
+                    if ladder.stopped:
+                        return
+                    if n_pad in ladder.programs:
+                        continue
+                t0 = stages.begin("coproc.precompile", n_pad=n_pad)
+                program = lower_packed_pipeline(ladder.fn, (n_pad, ladder.stride))
+                dt = stages.close(
+                    "coproc.precompile", None, t0, trace_id=None
+                )
+                if tracer.enabled:
+                    tracer.record(
+                        "coproc.precompile", dt * 1e6, tracer.new_trace_id(),
+                        start_perf=float(t0), parent=None,
+                        n_pad=n_pad, seconds=round(dt, 4),
+                    )
+                self._stat_add("t_precompile", dt)
+                self._stat_add("n_precompiles", 1.0)
+                with self._stats_lock:
+                    # a launch at this bucket is past trace + compile
+                    self._ran_fns.add((ladder.fn, n_pad))
+                with ladder.cond:
+                    ladder.programs[n_pad] = program
+                    ladder.seconds[n_pad] = dt
+                    ladder.cond.notify_all()
+        except Exception as exc:
+            faults.note_failure("precompile", exc)
+            logger.exception("precompile of a payload program failed")
+            self._stat_add("n_precompile_failures", 1.0)
+            with ladder.cond:
+                ladder.failed = f"{faults.kind_of(exc)}: {exc}"
+                ladder.cond.notify_all()
+
+    def _ladder_of(self, script_id: int) -> _Ladder | None:
+        pipeline = self._pipelines.get(script_id)
+        with self._stats_lock:
+            return self._ladders.get(pipeline[0]) if pipeline else None
+
+    def programs_ready(self, script_id: int) -> list[int]:
+        """The row buckets whose device program is built for a script's
+        launches (``coproc_programs_ready{script=}`` counts them)."""
+        ladder = self._ladder_of(script_id)
+        return ladder.ready() if ladder is not None else []
+
+    def await_programs(self, script_id: int, n: int = 2, wait_s: float = 120.0) -> bool:
+        """Block until a script's ``n`` smallest buckets are built (the
+        pacemaker's fiber, off the loop, before it takes its first input):
+        the first launches of a deploy are then cut to a ready program and
+        none waits inside a tick. True at once for a script with no ladder."""
+        ladder = self._ladder_of(script_id)
+        return ladder is None or ladder.wait_first(n, wait_s)
 
     def enable_py_transform(
         self,
@@ -1531,6 +1747,31 @@ class TpuEngine:
                 {"script_id": k[0], "lane": k[1], "n_pad": k[2],
                  "t_first_run_s": round(v, 4)}
                 for k, v in self._compiled.items()
+            ]
+            met = set(self._compiled)
+            ladders = {
+                sid: self._ladders[fn]
+                for sid, (fn, _r) in list(self._pipelines.items())
+                if fn in self._ladders
+            }
+        # ... and every program built ahead of need, whether a launch has
+        # met it yet or not: a script's ready buckets and the seconds each
+        # took to build (what the benchmark's warm-up waits to stand still)
+        out["programs_ready"] = {}
+        for sid, ladder in ladders.items():
+            with ladder.cond:
+                built = dict(ladder.seconds)
+                state = ladder.failed or (
+                    "ready" if len(built) == len(ladder.buckets()) else "building"
+                )
+            out["programs_ready"][sid] = {
+                "buckets": sorted(built), "top": ladder.top, "state": state,
+            }
+            out["compiled_programs"] += [
+                {"script_id": sid, "lane": "payload", "n_pad": n_pad,
+                 "t_first_run_s": 0.0, "t_precompile_s": round(secs, 4)}
+                for n_pad, secs in sorted(built.items())
+                if (sid, "payload", n_pad) not in met
             ]
         out["host_workers"] = float(self._host_workers)
         # "breaker" keeps its historical engine-level shape (worst state,
@@ -1718,6 +1959,8 @@ class TpuEngine:
                 probes.coproc_uncompress[key].inc(v)
             elif key in probes.coproc_seal:
                 probes.coproc_seal[key].inc(v)
+            elif key in probes.coproc_precompile:
+                probes.coproc_precompile[key].inc(v)
 
     def _stat_stage(self, key: str, t0: float, trace_id=_AMBIENT, **ring) -> float:
         """Close one stage timer (``t0 = _stage_t0(key)``) through the stage
@@ -2591,8 +2834,15 @@ class TpuEngine:
                 _release_exploded(exploded)
             return
         value_bytes = float((exploded.sizes * launch.fits).sum(dtype=np.int64))
-        t0 = _stage_t0("t_pack")
         n_pad = _bucket_rows(n)
+        program, bucket = self._program_for(fn, n_pad)
+        if bucket < n_pad:
+            # no program is ready at this launch's bucket (it is over the
+            # ladder's top, or the ladder is still building): cut to the
+            # largest ready one, as many parts as hold its rows
+            n_pad = -(-n // bucket) * bucket
+            self._stat_add("n_launch_cuts", 1.0)
+        t0 = _stage_t0("t_pack")
         if isinstance(exploded, batch_codec.PtrExploded):
             staged = self._pack_staged_ptrs(exploded, n_pad)
         else:
@@ -2605,10 +2855,24 @@ class TpuEngine:
         # zeros that still cross the link)
         self._stat_add("bytes_staged", float(staged.nbytes))
         self._stat_add("bytes_staged_values", value_bytes)
-        self._launch_payload(launch, staged, n_pad, fn, r_out)
+        self._launch_payload(launch, staged, n_pad, fn, r_out, program, bucket)
+
+    def _program_for(self, fn, n_pad: int):
+        """(program, row bucket) a payload launch padded to ``n_pad`` rows
+        runs: its ladder's program at that bucket; the largest ready one
+        below it, which the launch is cut to; or (None, n_pad) where no
+        ladder serves ``fn`` (none was built, or its build failed): the
+        jitted function itself, whose first call at a bucket is a first run
+        on the serving path."""
+        with self._stats_lock:
+            ladder = self._ladders.get(fn)
+        if ladder is None:
+            return None, n_pad
+        return ladder.program_for(n_pad, _COMPILE_DEADLINE_S)
 
     def _launch_payload(
-        self, launch: _Launch, staged: np.ndarray, n_pad: int, fn, r_out: int
+        self, launch: _Launch, staged: np.ndarray, n_pad: int, fn, r_out: int,
+        program, bucket: int,
     ) -> None:
         """Issue one payload-plan device launch over a built staging
         matrix (breaker gate, fault envelope, exact host fallback) —
@@ -2645,28 +2909,36 @@ class TpuEngine:
         if n_oversize:
             self._stat_add("n_oversize_rows", float(n_oversize))
         t0 = _stage_t0("t_dispatch")
+        # a program built ahead of need, or the jitted function (whose
+        # first call at a bucket traces and compiles); over ``n_pad`` rows
+        # in one part, or, a cut launch, in parts of ``bucket`` rows
+        run = program or fn
 
         def leg():
             faults.inject(faults.DEVICE_DISPATCH)
             # the leg runs on the fault envelope's worker: no ambient trace
             t_h2d = _stage_t0("t_h2d")
-            dev = jax.device_put(staged)
+            dev = [
+                jax.device_put(staged[i : i + bucket])
+                for i in range(0, n_pad, bucket)
+            ]
             self._stat_stage("t_h2d", t_h2d, trace_id=launch.trace_id)
-            packed = fn(dev)
-            packed.copy_to_host_async()
+            parts = [run(part) for part in dev]
+            for part in parts:
+                part.copy_to_host_async()
             # the staged device array rides on the launch until the fetch
             # has timed its H2D (_Launch._fetch_legs drops it). Set here and
             # not handed back beside the result: the envelope's worker keeps
             # what a leg returned until its next job ends, and a 33.8 MB
             # device array must not wait on that
             launch._staged_dev = dev
-            return packed
+            return parts[0] if len(parts) == 1 else _CutResult(parts)
 
         packed = None
         if self._breaker.allow_device():
             packed = self._try_device_leg(
                 faults.DEVICE_DISPATCH, leg,
-                program=(launch.script_id, "payload", n_pad), fn=fn,
+                program=(launch.script_id, "payload", bucket), fn=fn,
             )
         if packed is None:
             # open breaker or exhausted retries: the exact host result, in
@@ -2986,7 +3258,11 @@ class TpuEngine:
         (``n_staging_reuses``), else a new one. _Launch._park_staged gives
         it back (its ``.base`` is the pool's buffer)."""
         stride = self._row_stride + IN_META
-        buf, reused = self._staging.take(n_pad * stride)
+        # asked for by the row bucket, whatever the rows staged: a cut
+        # launch (k parts of a smaller bucket) takes and parks a buffer of
+        # its uncut size, so the pool's few slots hold the buckets a ramp
+        # walks and never fill up with sizes no later launch can use
+        buf, reused = self._staging.take(_bucket_rows(n_pad) * stride)
         if reused:
             self._stat_add("n_staging_reuses", 1.0)
         return buf[: n_pad * stride].reshape(n_pad, stride)

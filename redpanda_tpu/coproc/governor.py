@@ -731,6 +731,7 @@ class Governor:
         launch_depth_cap: int = 8,
         hold_s: float = AUTOTUNE_HOLD_S,
         pressure_fn=None,
+        tick_read_bytes: int = 0,
     ) -> None:
         """Arm the dynamic ``group_ticks_per_launch`` / ``launch_depth``
         verdicts. ``pressure_fn() -> (level, occupancy)`` is the budget
@@ -747,7 +748,13 @@ class Governor:
           ``_AUTOTUNE_BACKLOG_LAUNCHES`` budget-cut launches, with no wait
           on the clock between two such grows, except for ``hold_s`` after
           any shrink. ``launch_depth`` bounds staged device bytes, about
-          which a backlog says nothing: that rule never moves it."""
+          which a backlog says nothing: that rule never moves it.
+
+        ``tick_read_bytes``: what the pacemaker reads a partition at
+        ``group_ticks`` 1 (its ``max_batch_size``); with the cap it bounds
+        the bytes one launch can be handed (``launch_read_bytes``), which
+        is what sizes a payload script's ladder of device programs. 0: no
+        pacemaker's budget is known here."""
         with self._lock:
             self._auto = {
                 "enabled": bool(enabled),
@@ -758,6 +765,7 @@ class Governor:
                 "hold_s": max(0.0, float(hold_s)),
                 "last_change": -float("inf"),
                 "pressure_fn": pressure_fn,
+                "tick_read_bytes": max(0, int(tick_read_bytes)),
                 # what moved the knobs last: "device_leg", "backlog" or
                 # "pressure" (None: nothing has)
                 "evidence": None,
@@ -767,6 +775,20 @@ class Governor:
                 "backlog_run": 0,
                 "engine_s": 0.0,
             }
+
+    def launch_read_bytes(self, partitions: int) -> int | None:
+        """The most bytes the read budget hands one launch of a script over
+        ``partitions`` partitions: a tick's read a partition times the
+        furthest the launch knob can go (its cap while the autotune may
+        move it, the configured value otherwise). None: no read budget is
+        known (autotune unarmed, or armed with no ``tick_read_bytes``)."""
+        auto = self._auto
+        if auto is None or not auto["tick_read_bytes"]:
+            return None
+        ticks = auto["group_ticks_cap"] if auto["enabled"] else auto["group_ticks"]
+        return max(1, int(partitions)) * auto["tick_read_bytes"] * max(
+            ticks, auto["group_ticks"]
+        )
 
     def note_launch(self, budget_cut: bool, engine_s: float) -> None:
         """One completed launch, told by the pacemaker once a tick on the

@@ -225,6 +225,24 @@ coproc_seal = {
         "Crossings that sealed output batches: one a many-batches native call, one a batch otherwise",
     ),
 }
+# Device programs built ahead of need (TpuEngine._build_ladder: one a row
+# bucket of a payload script's ladder, off the serving path) and launches
+# the engine cut to the largest ready bucket; keyed by the engine's stats()
+# name. A first run ON the serving path is n_compiles, and these are not.
+coproc_precompile = {
+    "n_precompiles": registry.counter(
+        "coproc_precompiles_total",
+        "Device programs lowered and compiled ahead of need, off the serving path",
+    ),
+    "n_precompile_failures": registry.counter(
+        "coproc_precompile_failures_total",
+        "Ladders whose build failed (their scripts serve through first runs)",
+    ),
+    "n_launch_cuts": registry.counter(
+        "coproc_launch_cuts_total",
+        "Launches cut to the largest row bucket whose program was ready",
+    ),
+}
 coproc_launch_rows_hist = registry.histogram(
     "coproc_launch_rows",
     "Records fused into one device launch (bucket size after shape rounding)",
@@ -579,6 +597,7 @@ __all__ = [
     "coproc_lockwatch_edges",
     "coproc_output_bytes",
     "coproc_oversize_rows",
+    "coproc_precompile",
     "coproc_retries_total",
     "coproc_seal",
     "coproc_shard_rows_hist",

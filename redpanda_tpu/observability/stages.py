@@ -64,13 +64,14 @@ class _Annotated(float):
     __slots__ = ("annotation",)
 
 
-def begin(name: str, *, annotate: bool = True) -> float:
+def begin(name: str, *, annotate: bool = True, **args) -> float:
     """Start stage ``name``: its ``t0`` (``time.perf_counter()``).
-    ``annotate=False`` keeps the stage off the profile (sink (b))."""
+    ``annotate=False`` keeps the stage off the profile (sink (b)); ``args``
+    ride on the profile's annotation as its arguments (a bucket's rows)."""
     ann = annotate and (_annotation or _bind_annotation())
     if not ann or not ann.is_enabled():
         return time.perf_counter()
-    a = ann("rp:" + name)
+    a = ann("rp:" + name, **args)
     a.__enter__()
     t0 = _Annotated(time.perf_counter())
     t0.annotation = a

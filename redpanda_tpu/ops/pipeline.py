@@ -117,6 +117,16 @@ def make_packed_pipeline(spec: TransformSpec, r_in: int, mask_only: bool = False
     return _packed_pipeline_cached(spec.to_json(), int(r_in), bool(mask_only))
 
 
+def lower_packed_pipeline(fn, shape: tuple[int, int]):
+    """The program of a packed pipeline (``make_packed_pipeline``'s ``fn``)
+    for one staged shape ``(n_pad, r_in + IN_META)``, lowered and compiled
+    without running it: a ``jax.stages.Compiled`` that takes the staged
+    device array. The persistent compilation cache serves it as it serves
+    a jit call, and the module keeps the function's name
+    (``jit_rp_payload_transform``)."""
+    return fn.lower(jax.ShapeDtypeStruct(shape, jnp.uint8)).compile()
+
+
 def make_packed_pipeline_host(
     spec: TransformSpec, r_in: int, mask_only: bool = False
 ):

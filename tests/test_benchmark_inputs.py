@@ -226,3 +226,66 @@ def test_the_pinned_cells_are_the_manifests_uncompressed_cells():
         generator, codec = inputs[cell]
         assert generator != "docs.make_documents" or codec != "none", cell
         assert callable(bench.loadgen.load_generator(generator)) and bench.wire.codec_id(codec) >= 0
+
+
+# ------------------------------------------------------------------ json64p-v1-zstd (PR 45)
+V1_ZSTD_CELL = "json64p-v1-zstd.paced"
+
+
+def test_the_live_zstd_configuration_feeds_the_zstd_catchup_cells_frames_byte_for_byte():
+    """``json64p-v1-zstd`` is ``json64p-v1``'s script and reference over
+    ``json64p-v1map-zstd``'s input: given one stream, its producers build
+    the frames that configuration's producers build, byte for byte, and
+    they are Zstd-sealed batches of 32 of ``docs_text.py``'s documents."""
+    man = bench.manifest()
+    new = bench.config_of(man, V1_ZSTD_CELL)
+    old = bench.config_of(man, "json64p-v1map-zstd.catchup")
+    plain = bench.config_of(man, "json64p-v1.catchup")
+    assert new["documents"] == old["documents"] and new["producer"] == old["producer"]
+    assert (new["script"], new["reference"], new["topic"], new["guarantees"]) == (
+        plain["script"], plain["reference"], plain["topic"], plain["guarantees"])
+    assert new["reduced"] == ["brokers", "replication"] == list(new["reduced_from"])
+    ours = bench.built_producers(man, V1_ZSTD_CELL)
+    theirs = []
+    for prod in ours:
+        spec = {**prod.spec, "documents": old["documents"],
+                "compression": old["producer"]["compression"]}
+        twin = bench.loadgen.Producer(spec)
+        twin.build()
+        theirs.append(twin)
+    n, digest = bench.frames_digest(ours)
+    assert (n, digest) == bench.frames_digest(theirs) and n == 64 * 19  # 608 records a partition
+    from redpanda_tpu.hashing.crc32c import crc32c
+
+    sizes = [s for prod in ours for per_part in prod.batch_bytes["main"].values() for s in per_part]
+    assert 250 < sum(sizes) / (32 * len(sizes)) < 420  # wire bytes a record
+    frame = ours[1].frames["main"][40][3]
+    batch = frame[-ours[1].batch_bytes["main"][40][3]:]
+    assert batch[21 + 1] & 0x07 == 4  # the attribute bits name Zstd
+    want = bench.docs_text.make_documents(bench.SEED, 64, 608, range(40, 41))[40][96:128]
+    assert bench.wire.decode_batch(batch, crc32c) == (0, want)
+
+
+def test_the_live_zstd_configurations_reference_keeps_a_third_and_its_sequence():
+    """Point 5 of the generator contract for the new pair of generator and
+    reference (``docs_text.make_documents`` under ``filter_contains``), and
+    the traffic file is ``paced.json`` key for key but the warm-up."""
+    c = bench.config_of(bench.manifest(), V1_ZSTD_CELL)
+    ref = bench.loadgen.load_reference(c["reference"]["name"])
+    params = c["reference"]["params"]
+    stream = {"seed": 2**31 + 5, "partitions": 4, "records_per_partition": 256}
+    values = bench.loadgen.document_source(c["documents"])(stream)
+    kept = 0
+    for p, part in values.items():
+        outs = [(i, ref.reference(v, **params)) for i, v in enumerate(part)]
+        assert [ref.sequence(o) for i, o in outs if o is not None] == [
+            p * 256 + i for i, o in outs if o is not None]
+        assert all(o == part[i] for i, o in outs if o is not None)  # the value itself
+        kept += sum(o is not None for _, o in outs)
+    assert 0.2 < kept / (4 * 256) < 0.4
+    paced = bench.load(os.path.join("benchmarks", "traffic", "paced.json"))
+    device = bench.load(os.path.join("benchmarks", "traffic", "paced-device.json"))
+    assert {k: v for k, v in device.items() if k not in ("warmup", "what")} == {
+        k: v for k, v in paced.items() if k not in ("warmup", "what")}
+    assert {k: device["warmup"][k] for k in ("min_s", "quiet_s", "cap_s")} == {
+        "min_s": 15, "quiet_s": 6, "cap_s": 50}

@@ -91,6 +91,14 @@ def test_stage_a_served_path_at_tiny_size():
         CPU_REFUSAL,
         "columnar probe has no device timing: the probe never ran",
     ]
+    # PR 45: the payload script's ladder was built at the deploy, and a
+    # launch at a bucket no launch had met was no first run
+    pre = r["precompile"]
+    assert pre["ladder"]["state"] == "ready" and pre["ladder"]["top"] == 8192
+    assert pre["coproc_programs_ready"] == len(pre["ladder"]["buckets"]) == 7
+    assert pre["reference_match"] and pre["records_materialised"] > 0
+    assert pre["n_compiles"] == [0, 0] and pre["compile_samples"][0] == pre["compile_samples"][1]
+    assert pre["n_precompiles"] == 7 and pre["rows"] == pre["bucket"] // 2 + 1
 
 
 def test_stage_b_engine_lanes_at_tiny_size():

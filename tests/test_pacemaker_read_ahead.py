@@ -785,3 +785,88 @@ def test_a_live_trickle_on_the_host_lane_never_moves_the_knob(tmp_path):
             await _stop(storage, server, api)
 
     run(main())
+
+
+# ------------------------------------------------------------ the live linger
+def test_a_live_tick_lingers_as_long_as_it_took_and_a_backlog_does_not(tmp_path):
+    """A tick whose reads all ended at the log's end leaves its own
+    duration in ``_linger_s``; one that the byte budget cut short of the
+    LSO (a backlog) leaves 0.0; a tick that found nothing leaves what the
+    fiber set before it."""
+
+    async def main():
+        storage, broker, server, api = await _start(tmp_path)
+        try:
+            ctx = await _parked(api, broker)
+            for part in range(PARTITIONS):
+                await _append(broker, "src", part, _docs(DOCS))
+            h0 = _hist("tick")
+            assert await ctx.tick()
+            h1 = _hist("tick")
+            # the tick's own sample, to the microsecond the histogram keeps
+            assert ctx._linger_s > 0.0
+            assert int(ctx._linger_s * 1e6) == h1[1] - h0[1]
+            # a backlog: two batches a partition, one a read
+            ctx._linger_s = 0.0
+            for part in range(PARTITIONS):
+                await _append(broker, "src", part, _docs(DOCS, base=100))
+                await _append(broker, "src", part, _docs(DOCS, base=200))
+            assert await ctx.tick()
+            assert ctx._linger_s == 0.0
+            # the rest of it: the read ends at the LSO again
+            assert await ctx.tick()
+            assert ctx._linger_s > 0.0
+            # nothing to read: no launch, nothing set
+            ctx._linger_s = 0.0
+            assert not await ctx.tick()
+            assert ctx._linger_s == 0.0
+        finally:
+            await _stop(storage, server, api)
+
+    run(main())
+
+
+@pytest.mark.parametrize("stream", ["live", "backlog"])
+def test_the_fiber_waits_out_a_live_ticks_linger_before_it_reads_again(tmp_path, stream):
+    """The fiber's loop: after a live tick that took ~0.3 s (a held engine)
+    the next launch's input is read no sooner than that long after the tick
+    ended (``idle_sleep_s`` permitting), though it lay in the log all the
+    while; over a backlog the next tick follows at once. Read from the
+    ``tick`` and ``gap`` samples the fiber itself records."""
+
+    async def main():
+        storage, broker, server, api = await _start(tmp_path)
+        try:
+            pm, engine = api.pacemaker, api.pacemaker.engine
+            await broker.create_topic(TopicConfig("src", PARTITIONS))
+            await _deployed(api, "proj")
+            pm.idle_sleep_s = 5.0  # the cap is not what this test reads
+            await asyncio.sleep(0.2)  # the fiber has found nothing and sleeps
+            engine.waiting.clear()
+            engine.door.clear()
+            tick0, gap0 = _hist("tick"), _hist("gap")
+            for part in range(PARTITIONS):
+                for k in range(1 if stream == "live" else 2):
+                    await _append(broker, "src", part, _docs(DOCS, base=100 * k))
+            await wait_until(engine.waiting.is_set, timeout=30.0, msg="first submit")
+            if stream == "live":
+                # the second launch's input arrives while the first is held
+                for part in range(PARTITIONS):
+                    await _append(broker, "src", part, _docs(DOCS, base=500))
+            await asyncio.sleep(0.3)
+            engine.door.set()
+            await wait_until(lambda: _hist("tick")[0] > tick0[0], msg="first tick")
+            tick1 = _hist("tick")
+            await wait_until(lambda: _hist("gap")[0] > gap0[0], msg="second tick")
+            gap1 = _hist("gap")
+            first_tick_s = (tick1[1] - tick0[1]) / (tick1[0] - tick0[0]) / 1e6
+            gap_s = (gap1[1] - gap0[1]) / (gap1[0] - gap0[0]) / 1e6
+            if stream == "live":
+                assert tick1[0] - tick0[0] == 1 and first_tick_s >= 0.3
+                assert gap_s >= 0.9 * first_tick_s, (gap_s, first_tick_s)
+            else:
+                assert gap_s < 0.1, gap_s
+        finally:
+            await _stop(storage, server, api)
+
+    run(main())
