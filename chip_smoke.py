@@ -574,6 +574,17 @@ def _reply_values(reply, partitions: int) -> list[list[bytes]]:
     return out
 
 
+def mixed_width(values: list[list[bytes]]) -> list[list[bytes]]:
+    """The backlog with fifteen documents in sixteen cut to their first
+    three fields (~50-120 B; the sixteenth keeps its ~1 KB): values in two
+    far-apart width classes, which the payload lane stages as two parts
+    (PR 47) once a launch is large enough for that to halve its bytes."""
+    return [
+        [v if i % 16 == 0 else v[: v.index(b',"pad":"')] + b"}" for i, v in enumerate(part)]
+        for part in values
+    ]
+
+
 def run_lane(name, spec_json, values, ref_fn, ticks_per_launch, **engine_kw) -> dict:
     """One engine, one script: a first launch on its own (compile + run),
     then WARM_LAUNCHES pipelined LAUNCH_DEPTH deep, cycling through the
@@ -640,6 +651,7 @@ def run_lane(name, spec_json, values, ref_fn, ticks_per_launch, **engine_kw) -> 
         "n_launches": int(stats.get("n_launches", 0)),
         "n_device_launches": int(stats.get("n_device_launches", 0)),
         "n_mesh_launches": int(stats.get("n_mesh_launches", 0)),
+        "n_split_launches": int(stats.get("n_split_launches", 0)),
         "n_fallback_rows": stats.get("n_fallback_rows", 0),
         "n_retries": stats.get("n_retries", 0),
         "bytes_h2d": int(stats.get("bytes_h2d", 0)),
@@ -785,9 +797,21 @@ def stage_b(
             "payload", sp[PAYLOAD_SCRIPT], values,
             refs[PAYLOAD_SCRIPT], ticks_per_launch, force_mode="payload",
         ),
+        # the same script over narrow and wide values mixed: each launch
+        # goes as a narrow matrix and a wide one, merged back in row order
+        run_lane(
+            "payload_mixed_width", sp[PAYLOAD_SCRIPT], mixed_width(values),
+            refs[PAYLOAD_SCRIPT], ticks_per_launch, force_mode="payload",
+        ),
     ]
     for lane in result["lanes"]:
         result["failures"] += lane["failures"]
+    mixed = result["lanes"][-1]
+    if mixed["rows_per_launch"] >= 1024 and mixed["n_split_launches"] != mixed["n_launches"]:
+        result["failures"].append(
+            f"payload_mixed_width: {mixed['n_split_launches']} of "
+            f"{mixed['n_launches']} launches were staged in two parts"
+        )
     if programs:
         result["programs"] = device_programs(seed)
         result["failures"] += [

@@ -207,13 +207,16 @@ def main() -> int:
         check("frame_many_gather_ptrs bounds", gather_ptrs(bad)[0] == -1)
 
     # ---- pointer-table pack (rp_pack_rows_ptrs): the payload lane's whole
-    # staging matrix in one crossing; parity with rp_pack_rows a batch plus
-    # the length column, into a matrix that held 0xFF, and the -1 on a bad span
+    # staging matrix in one crossing, or (a row selection) one part of a
+    # launch staged by width class; parity with rp_pack_rows a batch plus
+    # the length column, into a matrix that held 0xFF: the whole table and
+    # selections of its rows, at a stride narrower than the longest values
+    # (they stage length 0) and a narrower one still, pad rows cleared; -1
+    # and nothing written on a bad span, a row outside the table, rows that
+    # do not ascend
     if hasattr(dll, "rp_pack_rows_ptrs"):
         dll.rp_pack_rows_ptrs.restype = ctypes.c_int64
         dll.rp_pack_rows.restype = ctypes.c_int32
-        row = 48  # narrower than the longest values: they stage length 0
-        stride = row + 8
         groups = [values[:4], [], values[4:]]  # the null value rides last
         bufs = [b"\xff" + b"".join(v or b"" for v in g) for g in groups]
         offs, lens, bounds, pos_row = [], [], [], 0
@@ -225,49 +228,78 @@ def main() -> int:
                 pos += len(v or b"")
             bounds.append((pos_row, pos_row + len(g)))
             pos_row += len(g)
-        nn, n_pad = len(offs), len(offs) + 5
+        nn, pad = len(offs), 5
         starts = (ctypes.c_int64 * 3)(*(s for s, _ in bounds))
         ends = (ctypes.c_int64 * 3)(*(e for _, e in bounds))
         src_lens = (ctypes.c_int64 * 3)(*(len(b) for b in bufs))
 
-        def pack_ptrs(off_list):
-            dst = ctypes.create_string_buffer(b"\xff" * (n_pad * stride), n_pad * stride)
+        def pack_ptrs(off_list, sel, row):
+            """(rc, dst) of one crossing; ``sel`` None: the whole table."""
+            k, stride = nn if sel is None else len(sel), row + 8
+            dst = ctypes.create_string_buffer(
+                b"\xff" * ((k + pad) * stride), (k + pad) * stride
+            )
             rc = dll.rp_pack_rows_ptrs(
                 (ctypes.c_char_p * 3)(*bufs), src_lens,
                 (ctypes.c_int64 * nn)(*off_list), (ctypes.c_int32 * nn)(*lens),
-                starts, ends, ctypes.c_int64(3), dst, ctypes.c_int64(nn),
-                ctypes.c_int64(n_pad), ctypes.c_size_t(row),
+                starts, ends, ctypes.c_int64(3),
+                None if sel is None else (ctypes.c_int64 * max(k, 1))(*sel),
+                ctypes.c_int64(k), dst, ctypes.c_int64(k + pad),
+                ctypes.c_size_t(row),
             )
             return rc, dst.raw
 
-        expect = bytearray()
-        for g, buf, (s, e) in zip(groups, bufs, bounds):
-            k = e - s
-            part = ctypes.create_string_buffer(max(k * stride, 1))
-            dll.rp_pack_rows(
-                buf, (ctypes.c_int64 * k)(*offs[s:e]),
-                (ctypes.c_int32 * k)(*lens[s:e]), ctypes.c_size_t(k), part,
-                ctypes.c_size_t(stride),
+        def staged_rows(row):
+            """The table's rows as rp_pack_rows stages them a batch, plus
+            the length column."""
+            stride, out = row + 8, []
+            for buf, (s, e) in zip(bufs, bounds):
+                k = e - s
+                part = ctypes.create_string_buffer(max(k * stride, 1))
+                dll.rp_pack_rows(
+                    buf, (ctypes.c_int64 * k)(*offs[s:e]),
+                    (ctypes.c_int32 * k)(*lens[s:e]), ctypes.c_size_t(k), part,
+                    ctypes.c_size_t(stride),
+                )
+                for i in range(k):
+                    ln = lens[s + i]
+                    staged_len = ln if 0 <= ln <= row else 0
+                    r0 = part.raw[i * stride : i * stride + row]
+                    out.append(r0 + struct.pack("<I", staged_len) + b"\0" * 4)
+            return out
+
+        for row, sel in ((48, None), (48, [0, 2, 3, nn - 1]), (16, [1, nn - 2]),
+                         (48, list(range(nn))), (48, [])):
+            rows = staged_rows(row)
+            rc, got = pack_ptrs(offs, sel, row)
+            what = "the table" if sel is None else f"{len(sel)} rows"
+            check(
+                f"pack_rows_ptrs bytes (stride {row}, {what})",
+                rc == 0
+                and got == b"".join(rows[i] for i in (range(nn) if sel is None else sel))
+                + b"\0" * (pad * (row + 8)),
             )
-            for i in range(k):
-                ln = lens[s + i]
-                staged_len = ln if 0 <= ln <= row else 0
-                r0 = part.raw[i * stride : i * stride + row]
-                expect += r0 + struct.pack("<I", staged_len) + b"\0" * 4
-        expect += b"\0" * ((n_pad - nn) * stride)
-        rc, got = pack_ptrs(offs)
-        check("pack_rows_ptrs bytes", rc == 0 and got == bytes(expect))
         check(
             "pack_rows_ptrs stages oversize/null as length 0",
-            any(ln > row for ln in lens) and lens[-1] == -1,
+            any(ln > 48 for ln in lens) and lens[-1] == -1,
         )
         bad = list(offs)
         bad[3] = len(bufs[0])  # a span that ends past ITS buffer
-        rc, got = pack_ptrs(bad)
-        check(
-            "pack_rows_ptrs bounds (-1, nothing written)",
-            rc == -1 and got == b"\xff" * (n_pad * stride),
-        )
+
+        def untouched(k):
+            return b"\xff" * ((k + pad) * 56)
+
+        check("pack_rows_ptrs bounds (-1, nothing written)",
+              pack_ptrs(bad, None, 48) == (-1, untouched(nn))
+              and pack_ptrs(bad, [0, 3], 48) == (-1, untouched(2)))
+        check("pack_rows_ptrs skips an unselected bad span",
+              pack_ptrs(bad, [0, 2], 48)[0] == 0)
+        check("pack_rows_ptrs rows must ascend",
+              pack_ptrs(offs, [2, 1], 48) == (-1, untouched(2))
+              and pack_ptrs(offs, [1, 1], 48) == (-1, untouched(2)))
+        check("pack_rows_ptrs rows inside the table",
+              pack_ptrs(offs, [0, nn], 48) == (-1, untouched(2))
+              and pack_ptrs(offs, [-1, 0], 48) == (-1, untouched(2)))
 
     # ---- the seal, many batches a call, against the Python seal
     # (models/record.py reseal: the Kafka CRC over the big-endian header

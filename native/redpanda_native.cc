@@ -145,45 +145,53 @@ int32_t rp_pack_rows(const uint8_t* src, const int64_t* offsets,
 // The pointer-table twin of rp_pack_rows, and the payload staging lane's
 // whole pack stage in one crossing: batch r's records take their
 // (offset, len) RELATIVE to their own source buffer srcs[r] (one retained
-// decompressed payload buffer a batch: PtrExploded) and fill rows
-// [starts[r], ends[r]) of the launch's staging matrix dst
-// [n_pad, stride], stride = row_stride (the value part) + 8 meta bytes:
-// the value and its zeroed tail (rp_pack_rows over the batch's rows, so
-// the two symbols cannot diverge), then the LE32 length (0 for a null
-// value and for one wider than row_stride: staged, never transformed) and
-// four zero bytes; rows n..n_pad are cleared. dst may hold anything on
-// entry: a reused matrix comes out byte for byte like a fresh one. Every
-// span is bounds-checked against src_lens[r] BEFORE anything is written;
-// returns -1 on a span outside its buffer, else 0.
+// decompressed payload buffer a batch: PtrExploded) and own rows
+// [starts[r], ends[r]) of the table. Row j of the staging matrix dst
+// [n_pad, stride], stride = row_stride (the value part) + 8 meta bytes, is
+// the table's row rows[j] (row numbers, ascending: one part of a launch
+// staged by width class fills its matrix from the rows of its class), or
+// row j itself where rows is NULL (the whole table, k its row count): the
+// value and its zeroed tail as rp_pack_rows stages them, then the LE32
+// length (0 for a null value and for one wider than THIS matrix's
+// row_stride: staged, never transformed) and four zero bytes; rows
+// k..n_pad are cleared. dst may hold anything on entry: a reused matrix
+// comes out byte for byte like a fresh one. Everything is checked BEFORE
+// anything is written: returns -1 on a span outside its buffer
+// (src_lens[r]), on a row outside the table or on rows that do not ascend,
+// else 0.
 int64_t rp_pack_rows_ptrs(const uint8_t* const* srcs, const int64_t* src_lens,
                           const int64_t* offsets, const int32_t* lens,
                           const int64_t* starts, const int64_t* ends,
-                          int64_t n_batches, uint8_t* dst, int64_t n,
-                          int64_t n_pad, size_t row_stride) {
+                          int64_t n_batches, const int64_t* rows, int64_t k,
+                          uint8_t* dst, int64_t n_pad, size_t row_stride) {
   const size_t stride = row_stride + 8;
-  for (int64_t r = 0; r < n_batches; r++) {
-    for (int64_t i = starts[r]; i < ends[r]; i++) {
-      int64_t vlen = lens[i] < 0 ? 0 : lens[i];
-      if (offsets[i] < 0 || offsets[i] + vlen > src_lens[r]) return -1;
-    }
+  int64_t r = 0, prev = -1;
+  for (int64_t j = 0; j < k; j++) {
+    int64_t i = rows ? rows[j] : j;
+    if (i <= prev) return -1;
+    prev = i;
+    while (r < n_batches && i >= ends[r]) r++;
+    if (r == n_batches || i < starts[r]) return -1;
+    int64_t vlen = lens[i] < 0 ? 0 : lens[i];
+    if (offsets[i] < 0 || offsets[i] + vlen > src_lens[r]) return -1;
   }
-  for (int64_t r = 0; r < n_batches; r++) {
-    int64_t s = starts[r];
-    rp_pack_rows(srcs[r], offsets + s, lens + s, (size_t)(ends[r] - s),
-                 dst + (size_t)s * stride, stride);
-    for (int64_t i = s; i < ends[r]; i++) {
-      uint32_t len =
-          lens[i] < 0 || (size_t)lens[i] > row_stride ? 0u : (uint32_t)lens[i];
-      uint8_t* meta = dst + (size_t)i * stride + row_stride;
-      meta[0] = (uint8_t)len;
-      meta[1] = (uint8_t)(len >> 8);
-      meta[2] = (uint8_t)(len >> 16);
-      meta[3] = (uint8_t)(len >> 24);
-      std::memset(meta + 4, 0, 4);
-    }
+  r = 0;
+  for (int64_t j = 0; j < k; j++) {
+    int64_t i = rows ? rows[j] : j;
+    while (i >= ends[r]) r++;
+    size_t sz = lens[i] < 0 ? 0 : (size_t)lens[i];
+    uint32_t len = sz > row_stride ? 0u : (uint32_t)sz;
+    if (sz > row_stride) sz = row_stride;
+    uint8_t* row = dst + (size_t)j * stride;
+    std::memcpy(row, srcs[r] + offsets[i], sz);
+    std::memset(row + sz, 0, stride - sz);
+    row[row_stride] = (uint8_t)len;
+    row[row_stride + 1] = (uint8_t)(len >> 8);
+    row[row_stride + 2] = (uint8_t)(len >> 16);
+    row[row_stride + 3] = (uint8_t)(len >> 24);
   }
-  if (n_pad > n)
-    std::memset(dst + (size_t)n * stride, 0, (size_t)(n_pad - n) * stride);
+  if (n_pad > k)
+    std::memset(dst + (size_t)k * stride, 0, (size_t)(n_pad - k) * stride);
   return 0;
 }
 

@@ -484,6 +484,20 @@ async def cmd_debug(args) -> int:
                 f"programs in {stats.get('t_precompile', 0.0):.1f} s), "
                 f"{int(stats.get('n_launch_cuts', 0))} launches cut to a ready bucket"
             )
+            if ladder.get("strides"):
+                # the narrower staged rows the script's launches have shown,
+                # each with a ladder of its own (coproc_split_launches_total)
+                shown = ", ".join(
+                    f"{stride} B ({len(st.get('buckets') or [])} buckets, {st.get('state')})"
+                    for stride, st in sorted(
+                        ladder["strides"].items(), key=lambda kv: int(kv[0])
+                    )
+                )
+                print(
+                    f"strides:  script {sid}: rows also staged at {shown}; "
+                    f"{int(stats.get('n_split_launches', 0))} launches staged in two "
+                    f"parts by width class"
+                )
         if stats.get("n_json_rows"):
             # coproc_json_rows_total{outcome="read|malformed|path_miss"}
             print(

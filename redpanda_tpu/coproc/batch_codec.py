@@ -652,16 +652,21 @@ def frame_ranges_gather_ptrs(
     ]
 
 
-def pack_exploded_ptrs(pe: PtrExploded, dst: np.ndarray, row_stride: int) -> None:
+def pack_exploded_ptrs(
+    pe: PtrExploded, dst: np.ndarray, row_stride: int,
+    rows: np.ndarray | None = None,
+) -> None:
     """Fill a payload launch's staging matrix ``dst`` [n_pad, row_stride +
     8] from a pointer table in ONE native crossing (rp_pack_rows_ptrs):
     values, zeroed tails, LE32 lengths (0 for a null value and for one
     wider than ``row_stride``), zero meta bytes, cleared pad rows. ``dst``
-    may be a reused matrix holding anything. explode_ptrs hands out a
-    table only when the library has the symbol."""
+    may be a reused matrix holding anything. ``rows``: the table's rows
+    this matrix holds (row numbers, ascending: one part of a launch staged
+    by width class), None for all of them. explode_ptrs hands out a table
+    only when the library has the symbol."""
     starts, ends = _range_cols(pe.ranges)
     _native().pack_rows_ptrs(
-        pe.payloads, pe.offsets, pe.sizes, starts, ends, dst, row_stride
+        pe.payloads, pe.offsets, pe.sizes, starts, ends, dst, row_stride, rows
     )
 
 

@@ -66,7 +66,12 @@ from redpanda_tpu.ops.pipeline import (
 )
 
 logger = logging.getLogger("rptpu.coproc.engine")
-from redpanda_tpu.ops.transforms import JSON_MALFORMED, JSON_PATH_MISS, TransformSpec
+from redpanda_tpu.ops.transforms import (
+    JSON_MALFORMED,
+    JSON_PATH_MISS,
+    TransformSpec,
+    transform_out_width,
+)
 from redpanda_tpu.coproc import (
     batch_codec,
     colcache,
@@ -180,17 +185,128 @@ def _bucket_rows(n: int) -> int:
 _LADDER_ROWS_PER_STRIDE = 8
 
 
-class _CutResult(tuple):
-    """The device results of one launch the engine cut to a ready row
-    bucket: its parts in row order, fetched as one (_Launch._fetch_legs)."""
+# A staged row's width comes in classes of this many bytes: a part's stride
+# is the smallest multiple of it that holds the part's widest value, never
+# over the lane's row_stride (TpuEngine._plan_parts).
+_STRIDE_CLASS = 128
+# A narrower stride's program is built at once for the row bucket a launch's
+# mix fills when the read budget hands it all it can (_stride_ready), and
+# for a launch's own bucket only once launches have gone on asking for that
+# one bucket this long: twice the governor's hold between launch-knob moves,
+# so a step of the knob's ramp is not worth a trace beside the serving path
+# and a live stream's steady launches are.
+_WANT_HOLD_S = 2 * governor.AUTOTUNE_HOLD_S
+# A launch whose values fall in two far-apart width classes is staged as
+# two parts (its narrow rows, its wide rows) only where the one matrix
+# fitted to its widest value is at least this many times the two parts'
+# bytes, row buckets and meta columns counted as they are staged.
+_SPLIT_MIN_SAVING = 2
 
-    __slots__ = ()
+
+def _merge_parts(results: list[np.ndarray], rows: list[np.ndarray] | None):
+    """A launch's result out of its parts' results (each on the host: a
+    packed result matrix, or the bit-packed keep mask): part i holds the
+    launch's rows ``rows[i]`` in its first rows. One indexed store a part
+    puts them back in the launch's row order; from there on nothing knows
+    of the split. ``rows`` None: one part, its result is the launch's."""
+    if rows is None:
+        return results[0]
+    n = sum(len(r) for r in rows)
+    if results[0].ndim == 1:
+        keep = np.empty(n, dtype=np.uint8)
+        for bits, r in zip(results, rows):
+            keep[r] = np.unpackbits(bits)[: len(r)]
+        return np.packbits(keep)
+    out = np.empty((n, results[0].shape[1]), dtype=np.uint8)
+    for packed, r in zip(results, rows):
+        out[r] = packed[: len(r)]
+    return out
+
+
+class _Part:
+    """One staging matrix of a payload launch and the program it runs over
+    it: the whole launch at one stride, or the rows of one width class."""
+
+    __slots__ = ("stride", "rows", "staged", "n_pad", "fn", "program", "bucket")
+
+    def __init__(self, stride: int, rows: np.ndarray | None, fn):
+        self.stride = stride  # the value part of a staged row, without IN_META
+        self.rows = rows  # the launch's rows it holds, ascending; None: all
+        self.fn = fn
+        self.staged: np.ndarray | None = None
+        self.n_pad = 0
+        self.program = None
+        self.bucket = 0
+
+
+class _PartsResult:
+    """The device results of a launch that is more than one program run:
+    staged in two parts by width class, or cut to a ready row bucket, or
+    both. ``parts[i]`` are part i's results in row order (one, or one a
+    cut); fetched and merged as one (_Launch._fetch_legs)."""
+
+    __slots__ = ("parts", "rows")
+
+    def __init__(self, parts: list[list], rows: list[np.ndarray] | None):
+        self.parts = parts
+        self.rows = rows
+
+    def arrays(self) -> list:
+        return [dev for cuts in self.parts for dev in cuts]
+
+    def landed(self) -> np.ndarray:
+        return _merge_parts(
+            [
+                np.asarray(cuts[0]) if len(cuts) == 1
+                else np.concatenate([np.asarray(dev) for dev in cuts])
+                for cuts in self.parts
+            ],
+            self.rows,
+        )
+
+
+class _SpecPrograms:
+    """One payload spec's pipelines by staged width (ops/pipeline.py caches
+    them by ``r_in``): the lane's full ``row_stride`` from the deploy on,
+    and every narrower stride a launch of the spec has shown. The engine
+    keeps it by spec for its own life, so a later deploy of the spec finds
+    the strides and starts their ladders beside the full-width one."""
+
+    __slots__ = ("spec", "mask_only", "fns", "min_stride", "split_ok", "_lock")
+
+    def __init__(self, spec: TransformSpec, mask_only: bool, row_stride: int):
+        self.spec = spec
+        self.mask_only = mask_only
+        self._lock = lockwatch.wrap(threading.Lock(), "_SpecPrograms._lock")
+        fn, r_out = make_packed_pipeline(spec, row_stride, mask_only)
+        self.fns: dict[int, tuple] = {row_stride: (fn, r_out)}
+        # a projection's result row has a width of its own, which a staged
+        # row must hold (ops/transforms._validated); any other mapper's
+        # follows the staged row, so parts of two strides give rows of two
+        # widths and cannot be merged: such a spec is fitted, never split
+        # (the keep mask has no width)
+        fixed = transform_out_width(spec, row_stride + _STRIDE_CLASS) == r_out
+        self.min_stride = r_out if fixed else 1
+        self.split_ok = mask_only or fixed
+
+    def at(self, stride: int) -> tuple:
+        """(fn, r_out) at ``stride``."""
+        with self._lock:
+            got = self.fns.get(stride)
+            if got is None:
+                got = make_packed_pipeline(self.spec, stride, self.mask_only)
+                # a new dict, not a new key: whoever walks the old one
+                # (stats(), another script's launch) walks it whole
+                self.fns = {**self.fns, stride: got}
+        return got
 
 
 class _Ladder:
     """One payload pipeline's device programs by row bucket, lowered and
-    compiled ahead of need on a thread of their own (``rptpu-precompile``),
-    smallest bucket first, through JAX's ahead-of-time path
+    compiled ahead of need on the engine's builder thread
+    (``rptpu-precompile``: one program at a time, what the lazy ladders'
+    launches wanted before the lanes' own ladders, smallest first), through
+    JAX's ahead-of-time path
     (``jit(f).lower(shape).compile()``: the persistent compilation cache
     serves it like any jit call). Keyed by the jitted function, which
     ops/pipeline.py caches by spec: K scripts of one spec share one ladder.
@@ -200,17 +316,30 @@ class _Ladder:
     top, the LARGEST ready bucket (the launch is cut to it, never compiled
     inline); with nothing ready yet it waits for the first bucket. A
     ladder whose build failed answers None and the script serves through
-    the first-run path (_try_device_leg), as every script did before."""
+    the first-run path (_try_device_leg), as every script did before.
 
-    def __init__(self, fn, stride: int, top: int):
+    ``lazy``: the ladder of a stride narrower than the lane's own, which a
+    launch's values showed (TpuEngine._plan_parts). It holds the buckets
+    launches have asked for and no other: a launch is staged that narrow
+    only where ``has`` its bucket, and goes wider until then, so nothing
+    waits on it, and a stream of two widths costs two programs, not two
+    ladders of eleven (each a trace under the interpreter lock, beside the
+    serving path: PR 47's cold runs)."""
+
+    def __init__(self, fn, stride: int, top: int, lazy: bool = False):
         self.fn = fn
-        self.stride = stride  # a staged row's bytes: row_stride + IN_META
+        self.stride = stride  # a staged row's bytes, IN_META included
         self.top = top
+        self.lazy = lazy
         self.programs: dict[int, object] = {}  # n_pad -> jax.stages.Compiled
         self.seconds: dict[int, float] = {}  # n_pad -> its build seconds
         self.failed: str | None = None
         self.stopped = False
-        self.thread: threading.Thread | None = None
+        # the buckets launches went without (staged wider meanwhile): what
+        # a lazy ladder builds; and the ONE bucket the newest launches
+        # asked for, one after another, with the time of the first of them
+        self.wanted: set[int] = set()
+        self.asked: dict[int, float] = {}
         self.cond = threading.Condition()
 
     def buckets(self) -> list[int]:
@@ -244,6 +373,38 @@ class _Ladder:
                 if left <= 0:
                     return None, n_pad
                 self.cond.wait(timeout=left)
+
+    def has(self, n_pad: int, want: bool = True, hold_s: float = 0.0) -> bool:
+        """Whether a launch padded to ``n_pad`` rows runs a built program
+        as it is (over the top: cut to the top's). ``want``: a bucket that
+        is not there is left for the builder, once launches have asked for
+        it and no other over ``hold_s`` seconds."""
+        with self.cond:
+            n_pad = min(n_pad, self.top)
+            if n_pad in self.programs:
+                return True
+            if want and n_pad not in self.wanted:
+                now = time.monotonic()
+                if hold_s and n_pad not in self.asked:
+                    self.asked.clear()
+                    self.asked[n_pad] = now
+                if not hold_s or now - self.asked[n_pad] >= hold_s:
+                    self.wanted.add(n_pad)
+            return False
+
+    def missing(self) -> list[int]:
+        """The buckets still to build, smallest first: every bucket up to
+        the top, or, ``lazy``, the wanted ones. Caller holds ``cond``."""
+        want = self.wanted if self.lazy else self.buckets()
+        return sorted(b for b in want if b not in self.programs)
+
+    def next_bucket(self) -> int | None:
+        """The bucket to build next; None when the ladder stands (or will
+        never)."""
+        with self.cond:
+            if self.failed is not None or self.stopped:
+                return None
+            return next(iter(self.missing()), None)
 
     def wait_first(self, n: int, wait_s: float) -> bool:
         """Block until the ``n`` smallest buckets are ready (or the build
@@ -335,7 +496,7 @@ class _Launch:
                  "engine", "n", "_packed_dev", "_mask_dev", "_mask_np",
                  "_mask_event", "_proj_data", "_proj_ok", "_plan",
                  "_exploded", "_mat", "_gather_mat", "_framed", "_lock",
-                 "_shards", "trace_id", "_enq_t", "_cols", "_staged_np",
+                 "_shards", "trace_id", "_enq_t", "_cols", "_staged_parts",
                  "_staged_dev", "_fetch_span", "_mask_state")
 
     def __init__(self, script_id: int, policy: ErrorPolicy):
@@ -374,9 +535,11 @@ class _Launch:
         # without a fetch or a breaker verdict — one mask, one envelope,
         # one verdict, no matter how deep the harvest queue is.
         self._mask_state = "idle"
-        self._staged_np = None
-        # the staged matrix ON the device, kept beside the result only so
-        # that the fetch can time the H2D apart (_fetch_legs drops it)
+        # a payload launch's staging matrices (_Part: one, or two by width
+        # class), retained until the device result lands
+        self._staged_parts: list[_Part] | None = None
+        # the staged matrices ON the device, kept beside the result only so
+        # that the fetch can time the H2D apart (_fetch_legs drops them)
         self._staged_dev = None
         # ring id of this launch's coproc.stage.fetch span, minted ahead of
         # it: the link-wait legs name it as their parent, and on the mask
@@ -411,14 +574,14 @@ class _Launch:
             staged_dev = None
             self._stat("t_wait_h2d", t0, parent=parent)
         t0 = _stage_t0("t_wait_program")
-        jax.block_until_ready(dev)  # host bits (a bare launch in tests) pass through
+        several = isinstance(dev, _PartsResult)
+        # host bits (a bare launch in tests) pass through
+        jax.block_until_ready(dev.arrays() if several else dev)
         self._stat("t_wait_program", t0, parent=parent)
         t0 = _stage_t0("t_wait_d2h")
-        if isinstance(dev, _CutResult):
-            # a cut launch: its parts in row order are the launch's result
-            out = np.concatenate([np.asarray(part) for part in dev])
-        else:
-            out = np.asarray(dev)
+        # a launch staged in parts or cut: its parts' results, back in the
+        # launch's row order, are the launch's result
+        out = dev.landed() if several else np.asarray(dev)
         self._stat("t_wait_d2h", t0, parent=parent)
         return out
 
@@ -472,31 +635,40 @@ class _Launch:
         JAX_PLATFORMS=tpu has no CPU backend to fall back to. Raises when
         nothing was retained (the launch then follows ErrorPolicy, exactly
         like any unrecoverable script failure)."""
-        staged = self._staged_np
+        parts = self._staged_parts
         eng = self.engine
-        if staged is None or eng is None:
+        if parts is None or eng is None:
             raise RuntimeError(
                 "payload host fallback impossible: staged rows not retained"
             )
         plan = self._plan
-        packed = make_packed_pipeline_host(
-            plan.spec, eng._row_stride, eng._mask_result(plan)
-        )(staged)
+        mask_result = eng._mask_result(plan)
+        # each part at its own stride, merged as the device's parts are
+        packed = _merge_parts(
+            [
+                make_packed_pipeline_host(plan.spec, part.stride, mask_result)(
+                    part.staged
+                )
+                for part in parts
+            ],
+            [part.rows for part in parts] if len(parts) > 1 else None,
+        )
         # the device leg did not end in a landed result, so a transfer may
-        # still be reading the matrix: dropped here, never parked
+        # still be reading the matrices: dropped here, never parked
         # (_launch_payload has the rule)
-        self._staged_np = None  # pandalint: disable=RAC1101 -- the unlocked caller is _dispatch_payload, which runs BEFORE the launch is published to tickets (thread-local construction phase); every harvest-time caller reaches here under _Launch._lock via _materialize_locked / _gather_view
+        self._staged_parts = None  # pandalint: disable=RAC1101 -- the unlocked caller is _dispatch_payload, which runs BEFORE the launch is published to tickets (thread-local construction phase); every harvest-time caller reaches here under _Launch._lock via _materialize_locked / _gather_view
         eng._count_fallback(self.n)
         return packed
 
     def _park_staged(self) -> None:
-        """The launch's device result has landed, so the program has
-        consumed its input: the staging matrix goes back to the engine's
+        """The launch's device result has landed, so the programs have
+        consumed their input: the staging matrices go back to the engine's
         pool. After a host fallback there is none left to give
         (_launch_payload has the rule)."""
-        staged, self._staged_np = self._staged_np, None
-        if staged is not None and self.engine is not None:
-            self.engine._staging.release(staged.base)
+        parts, self._staged_parts = self._staged_parts, None
+        if parts is not None and self.engine is not None:
+            for part in parts:
+                self.engine._staging.release(part.staged.base)
 
     def _resolve_keep(self, slot, n: int) -> np.ndarray:
         """Resolve a keep mask from a mask holder — the launch itself or a
@@ -1340,11 +1512,19 @@ class TpuEngine:
         # need (_Ladder); guarded by _stats_lock. Only an engine whose
         # governor knows the launch's read budget has any (_ladder_top)
         self._ladders: dict[object, _Ladder] = {}
+        # the one thread that builds them (rptpu-precompile), alive while
+        # any ladder has a bucket to build; guarded by _stats_lock
+        self._precompiler: threading.Thread | None = None
         self._device_launches: dict[int, int] = defaultdict(int)
         # the parse ladder the last columnar launch ran ("structural" |
         # "staged"); None before one
         self._parse_path: str | None = None
         self._pipelines: dict[int, tuple] = {}  # payload: script_id -> (fn, r_out)
+        # payload: script_id -> its spec's pipelines by staged width, and
+        # those by (spec json, result format) for the engine's life: the
+        # strides a spec has shown outlive its scripts (_SpecPrograms)
+        self._lanes: dict[int, _SpecPrograms] = {}
+        self._spec_programs: dict[tuple, _SpecPrograms] = {}
         self._plans: dict[int, object] = {}  # script_id -> execution plan
         self._stats: dict[str, float] = defaultdict(float)
         self._stats_lock = lockwatch.wrap(
@@ -1378,17 +1558,18 @@ class TpuEngine:
         with self._stats_lock:
             t, self._harvester = self._harvester, None
             ladders = list(self._ladders.values())
+            builder = self._precompiler
         if t is not None and t.is_alive():
             self._harvest_q.put(None)
             t.join(timeout=60.0)
         for ladder in ladders:
-            # a compile under way cannot be interrupted: the builder ends
-            # after it (a daemon thread; nothing waits on it past this)
             with ladder.cond:
                 ladder.stopped = True
                 ladder.cond.notify_all()
-            if ladder.thread is not None:
-                ladder.thread.join(timeout=5.0)
+        if builder is not None:
+            # a compile under way cannot be interrupted: the builder ends
+            # after it (a daemon thread; nothing waits on it past this)
+            builder.join(timeout=5.0)
         if self._host_pool is not None:
             self._host_pool.shutdown()
         with self._stats_lock:  # concurrent shutdowns: swap-then-remove once
@@ -1503,13 +1684,24 @@ class TpuEngine:
                     # compilation and keep their columnar plan.
                     plan = PayloadPlan(spec)
                 if plan.mode == "payload":
-                    self._pipelines[script_id] = make_packed_pipeline(
-                        spec, self._row_stride, self._mask_result(plan)
+                    mask_only = self._mask_result(plan)
+                    with self._stats_lock:
+                        lane = self._spec_programs.get((spec.to_json(), mask_only))
+                    if lane is None:
+                        lane = _SpecPrograms(spec, mask_only, self._row_stride)
+                        with self._stats_lock:
+                            lane = self._spec_programs.setdefault(
+                                (spec.to_json(), mask_only), lane
+                            )
+                    self._lanes[script_id] = lane
+                    self._pipelines[script_id] = lane.fns[self._row_stride]
+                    # the full-width ladder, and one for every narrower
+                    # stride an earlier script of the spec has shown
+                    top = self._ladder_top(
+                        sum((partitions or {}).get(t, 1) for t in topics)
                     )
-                    self._start_ladder(
-                        self._pipelines[script_id][0],
-                        sum((partitions or {}).get(t, 1) for t in topics),
-                    )
+                    for stride, (fn, _r) in sorted(lane.fns.items(), reverse=True):
+                        self._start_ladder(fn, stride, top)
                 self._plans[script_id] = plan
             except Exception as exc:
                 # bad spec from the wire, not a broker fault: refuse the
@@ -1537,68 +1729,91 @@ class TpuEngine:
             return None
         return _bucket_rows(read_bytes * _LADDER_ROWS_PER_STRIDE // self._row_stride)
 
-    def _start_ladder(self, fn, partitions: int) -> None:
-        """Build ``fn``'s programs for every row bucket up to the top, on a
-        thread of their own. A second script of one spec finds the ladder
-        there (or taller than it needs) and builds nothing; one over more
+    def _start_ladder(self, fn, stride: int, top: int | None) -> None:
+        """Have ``fn``'s programs (rows ``stride`` wide) built on the
+        engine's builder thread: every row bucket up to ``top`` at the
+        lane's own stride, the buckets launches ask for at a narrower one
+        (a lazy ladder). A second script of one spec finds the ladder there
+        (or taller than it needs) and builds nothing; one over more
         partitions raises the top and the builder goes on from where the
-        ladder stands."""
-        top = self._ladder_top(partitions)
+        ladder stands. A ladder's top is in rows, the launch's, whatever
+        the stride."""
         if top is None:
             return
         with self._stats_lock:
             ladder = self._ladders.get(fn)
             if ladder is None:
                 ladder = self._ladders[fn] = _Ladder(
-                    fn, self._row_stride + IN_META, top
+                    fn, stride + IN_META, top, lazy=stride != self._row_stride
                 )
             with ladder.cond:
                 ladder.top = max(ladder.top, top)
-                building = ladder.thread is not None and ladder.thread.is_alive()
-                if building or ladder.failed is not None or (
-                    len(ladder.programs) == len(ladder.buckets())
-                ):
-                    return
-                ladder.thread = threading.Thread(
-                    target=self._build_ladder, args=(ladder,),
-                    name="rptpu-precompile", daemon=True,
-                )
-                ladder.thread.start()
+            self._wake_precompiler()
 
-    def _build_ladder(self, ladder: _Ladder) -> None:
-        """The ladder's builder thread: one ``lower().compile()`` a bucket,
-        smallest first, each a stage ``coproc.precompile`` (an ``rp:``
-        annotation on this thread's line of a profile, ``t_precompile`` and
-        ``n_precompiles`` in stats()). The first failure ends the build: the
-        script keeps serving, through the first-run path."""
+    def _wake_precompiler(self) -> None:
+        """The builder thread runs while any ladder lacks a bucket. Caller
+        holds _stats_lock, under which the builder also decides to end: a
+        ladder raised or a bucket wanted from then on starts a new one."""
+        if self._precompiler is None and any(
+            ladder.next_bucket() is not None for ladder in self._ladders.values()
+        ):
+            self._precompiler = threading.Thread(
+                target=self._precompile_loop, name="rptpu-precompile", daemon=True
+            )
+            self._precompiler.start()
+
+    def _precompile_loop(self) -> None:
+        """The builder thread: ONE program at a time, whatever the number
+        of ladders (a trace holds the interpreter lock and a compile takes
+        every core it finds: two builders at once halved the serving
+        path's host stages on the chip's host, PR 47). First what the
+        narrower strides' launches wanted: few programs (_stride_ready
+        asks for the ones a stream runs in its steady state, not for a
+        ramp step's), and the ones that take the stream's launches off the
+        lane's own, widest programs. Then the lanes' own ladders, smallest
+        bucket first (a launch is cut to the largest built one)."""
+        while True:
+            with self._stats_lock:
+                todo = [
+                    (not ladder.lazy, n_pad * ladder.stride, n_pad, ladder)
+                    for ladder in self._ladders.values()
+                    for n_pad in [ladder.next_bucket()]
+                    if n_pad is not None
+                ]
+                if not todo:
+                    self._precompiler = None
+                    return
+            _own, _bytes, n_pad, ladder = min(todo, key=lambda job: job[:3])
+            self._build_program(ladder, n_pad)
+
+    def _build_program(self, ladder: _Ladder, n_pad: int) -> None:
+        """One ``lower().compile()``: a stage ``coproc.precompile`` (an
+        ``rp:`` annotation on the builder's line of a profile,
+        ``t_precompile`` and ``n_precompiles`` in stats()). A failure ends
+        that ladder's build: its scripts keep serving, through the
+        first-run path."""
         try:
             self.resolve_device()
-            for n_pad in ladder.buckets():
-                with ladder.cond:
-                    if ladder.stopped:
-                        return
-                    if n_pad in ladder.programs:
-                        continue
-                t0 = stages.begin("coproc.precompile", n_pad=n_pad)
-                program = lower_packed_pipeline(ladder.fn, (n_pad, ladder.stride))
-                dt = stages.close(
-                    "coproc.precompile", None, t0, trace_id=None
+            t0 = stages.begin("coproc.precompile", n_pad=n_pad)
+            program = lower_packed_pipeline(ladder.fn, (n_pad, ladder.stride))
+            dt = stages.close(
+                "coproc.precompile", None, t0, trace_id=None
+            )
+            if tracer.enabled:
+                tracer.record(
+                    "coproc.precompile", dt * 1e6, tracer.new_trace_id(),
+                    start_perf=float(t0), parent=None,
+                    n_pad=n_pad, seconds=round(dt, 4),
                 )
-                if tracer.enabled:
-                    tracer.record(
-                        "coproc.precompile", dt * 1e6, tracer.new_trace_id(),
-                        start_perf=float(t0), parent=None,
-                        n_pad=n_pad, seconds=round(dt, 4),
-                    )
-                self._stat_add("t_precompile", dt)
-                self._stat_add("n_precompiles", 1.0)
-                with self._stats_lock:
-                    # a launch at this bucket is past trace + compile
-                    self._ran_fns.add((ladder.fn, n_pad))
-                with ladder.cond:
-                    ladder.programs[n_pad] = program
-                    ladder.seconds[n_pad] = dt
-                    ladder.cond.notify_all()
+            self._stat_add("t_precompile", dt)
+            self._stat_add("n_precompiles", 1.0)
+            with self._stats_lock:
+                # a launch at this bucket is past trace + compile
+                self._ran_fns.add((ladder.fn, n_pad))
+            with ladder.cond:
+                ladder.programs[n_pad] = program
+                ladder.seconds[n_pad] = dt
+                ladder.cond.notify_all()
         except Exception as exc:
             faults.note_failure("precompile", exc)
             logger.exception("precompile of a payload program failed")
@@ -1687,6 +1902,7 @@ class TpuEngine:
             if sid in self._handles:
                 del self._handles[sid]
                 self._pipelines.pop(sid, None)
+                self._lanes.pop(sid, None)
                 self._plans.pop(sid, None)
                 self._forget_programs(sid)
                 self.invalidate_columns(sid)
@@ -1699,6 +1915,7 @@ class TpuEngine:
         n = len(self._handles)
         self._handles.clear()
         self._pipelines.clear()
+        self._lanes.clear()
         self._plans.clear()
         self._forget_programs(None)
         self.invalidate_columns()
@@ -1745,34 +1962,48 @@ class TpuEngine:
             # is a new program, and this is where that cost shows
             out["compiled_programs"] = [
                 {"script_id": k[0], "lane": k[1], "n_pad": k[2],
-                 "t_first_run_s": round(v, 4)}
+                 "t_first_run_s": round(v, 4),
+                 **({"stride": k[3]} if len(k) > 3 else {})}
                 for k, v in self._compiled.items()
             ]
             met = set(self._compiled)
+            # a payload script's ladders by staged width, the lane's full
+            # row_stride first
             ladders = {
-                sid: self._ladders[fn]
-                for sid, (fn, _r) in list(self._pipelines.items())
-                if fn in self._ladders
+                sid: [
+                    (stride, self._ladders[fn])
+                    for stride, (fn, _r) in sorted(lane.fns.items(), reverse=True)
+                    if fn in self._ladders
+                ]
+                for sid, lane in list(self._lanes.items())
             }
         # ... and every program built ahead of need, whether a launch has
         # met it yet or not: a script's ready buckets and the seconds each
         # took to build (what the benchmark's warm-up waits to stand still)
         out["programs_ready"] = {}
-        for sid, ladder in ladders.items():
-            with ladder.cond:
-                built = dict(ladder.seconds)
-                state = ladder.failed or (
-                    "ready" if len(built) == len(ladder.buckets()) else "building"
-                )
-            out["programs_ready"][sid] = {
-                "buckets": sorted(built), "top": ladder.top, "state": state,
-            }
-            out["compiled_programs"] += [
-                {"script_id": sid, "lane": "payload", "n_pad": n_pad,
-                 "t_first_run_s": 0.0, "t_precompile_s": round(secs, 4)}
-                for n_pad, secs in sorted(built.items())
-                if (sid, "payload", n_pad) not in met
-            ]
+        for sid, by_stride in ladders.items():
+            for stride, ladder in by_stride:
+                with ladder.cond:
+                    built = dict(ladder.seconds)
+                    state = ladder.failed or (
+                        "building" if ladder.missing() else "ready"
+                    )
+                if stride == self._row_stride:
+                    out["programs_ready"][sid] = {
+                        "buckets": sorted(built), "top": ladder.top, "state": state,
+                    }
+                elif sid in out["programs_ready"]:
+                    # the narrower strides the spec's launches have shown
+                    out["programs_ready"][sid].setdefault("strides", {})[stride] = {
+                        "buckets": sorted(built), "state": state,
+                    }
+                out["compiled_programs"] += [
+                    {"script_id": sid, "lane": "payload", "n_pad": n_pad,
+                     "t_first_run_s": 0.0, "t_precompile_s": round(secs, 4),
+                     "stride": stride}
+                    for n_pad, secs in sorted(built.items())
+                    if (sid, "payload", n_pad, stride) not in met
+                ]
         out["host_workers"] = float(self._host_workers)
         # "breaker" keeps its historical engine-level shape (worst state,
         # summed counts); "breakers" is the per-domain split and
@@ -1941,6 +2172,8 @@ class TpuEngine:
                 probes.coproc_staged_rows.inc(v)
             elif key == "n_oversize_rows":
                 probes.coproc_oversize_rows.inc(v)
+            elif key == "n_split_launches":
+                probes.coproc_split_launches.inc(v)
             elif key == "bytes_staged":
                 probes.coproc_staged_bytes.inc(v)
             elif key == "bytes_staged_values":
@@ -2056,7 +2289,7 @@ class TpuEngine:
         return out
 
     def _try_device_leg(
-        self, domain: str, leg, program: tuple | None = None, fn=None
+        self, domain: str, leg, programs: list[tuple] | None = None
     ):
         """One device leg under the engine's fault envelope: the DOMAIN's
         per-attempt deadline (adaptive, governor-derived) + bounded retry
@@ -2069,37 +2302,39 @@ class TpuEngine:
         shape of a fault-tolerant device interaction; keeping it in one
         place keeps the breaker verdicts exhaustive.
 
-        ``program``: dispatch legs name the device program they launch as
-        ``(script_id, lane, n_pad)``. The first leg of a program traces
-        and compiles it, so it runs under _COMPILE_DEADLINE_S, alone (a
-        concurrent launch reaching the same program waits for it rather
-        than compile it again), and its wall time is ``t_compile``, not a
-        deadline sample. Every successful dispatch leg is one ``n_device_launches``.
-        ``fn``: the jitted function the leg calls, where scripts of one
-        spec share it (the payload lane's pipelines are cached by spec): a
+        ``programs``: dispatch legs name the device programs they launch,
+        each as ``((script_id, lane, n_pad[, stride]), fn)``. The first
+        leg of a program traces and compiles it, so it runs under
+        _COMPILE_DEADLINE_S, alone (a concurrent launch reaching the same
+        program waits for it rather than compile it again), and its wall
+        time is ``t_compile``, not a deadline sample; one unmet program
+        makes the leg a first run. Every successful dispatch leg is one
+        ``n_device_launches``, however many programs it runs (a payload
+        launch staged in two parts runs two). ``fn``: the jitted function
+        the leg calls, where scripts of one spec share it (the payload
+        lane's pipelines are cached by spec and stride; None elsewhere): a
         script whose function another script already ran at this row
-        bucket inherits that program, and its first launch is no first run.
+        bucket, or the ladder built, inherits that program, and its launch
+        is no first run.
         """
-        if program is not None:
+        if programs:
             with self._stats_lock:
-                known = program in self._compiled
-                if not known and (fn, program[2]) in self._ran_fns:
-                    self._compiled[program] = 0.0
-                    known = True
+                for key, fn in programs:
+                    if key not in self._compiled and (fn, key[2]) in self._ran_fns:
+                        self._compiled[key] = 0.0
+                known = all(key in self._compiled for key, _fn in programs)
                 unresolved = self._device is None
             if unresolved:
                 self.resolve_device()
             if not known:
                 with self._compile_lock:
                     with self._stats_lock:
-                        known = program in self._compiled
+                        known = all(key in self._compiled for key, _fn in programs)
                     if not known:
-                        return self._device_leg(domain, leg, program, True, fn)
-        return self._device_leg(domain, leg, program, False, fn)
+                        return self._device_leg(domain, leg, programs, True)
+        return self._device_leg(domain, leg, programs, False)
 
-    def _device_leg(
-        self, domain: str, leg, program, first_run: bool, fn=None
-    ):
+    def _device_leg(self, domain: str, leg, programs, first_run: bool):
         """_try_device_leg's envelope. Each SUCCESSFUL steady-state
         attempt's wall time feeds the governor's success-only device-leg
         histogram — the adaptive-deadline source. The timing wraps the leg
@@ -2136,13 +2371,16 @@ class TpuEngine:
             faults.note_failure(domain, exc, reraise_programming=True)
             gov.breaker_for(domain).record_failure()
             return None
-        if program is not None:
+        if programs:
             self._stat_add("n_device_launches", 1.0)
             with self._stats_lock:
-                self._compiled.setdefault(program, first_run_s)
-                if fn is not None:
-                    self._ran_fns.add((fn, program[2]))
-                self._device_launches[program[0]] += 1
+                for key, fn in programs:
+                    # the launch's first-run seconds go to the programs it
+                    # was the first to meet
+                    self._compiled.setdefault(key, first_run_s)
+                    if fn is not None:
+                        self._ran_fns.add((fn, key[2]))
+                self._device_launches[programs[0][0][0]] += 1
         return out
 
     def heartbeat(self) -> int:
@@ -2703,7 +2941,7 @@ class TpuEngine:
 
         mask = self._try_device_leg(
             faults.MESH_DISPATCH, leg,
-            program=(launch.script_id, "mesh", n_pad),
+            programs=[((launch.script_id, "mesh", n_pad), None)],
         )
         self._stat_stage("t_dispatch", t0)
         if mask is None:
@@ -2823,10 +3061,10 @@ class TpuEngine:
         pooled decompress buffers go back when that framing is done
         (_Launch.framed); any other launch has read its last payload byte
         once the matrix is packed, and gives them back here."""
-        fn, r_out = self._pipelines[launch.script_id]
-        launch.r_out = r_out
+        lane = self._lanes[launch.script_id]
+        launch.r_out = lane.fns[self._row_stride][1]
         launch.fits = exploded.sizes <= self._row_stride
-        retained = self._mask_result(launch._plan)
+        retained = lane.mask_only
         if retained:
             launch._exploded = exploded
         if n == 0:
@@ -2834,33 +3072,160 @@ class TpuEngine:
                 _release_exploded(exploded)
             return
         value_bytes = float((exploded.sizes * launch.fits).sum(dtype=np.int64))
-        n_pad = _bucket_rows(n)
-        program, bucket = self._program_for(fn, n_pad)
-        if bucket < n_pad:
-            # no program is ready at this launch's bucket (it is over the
-            # ladder's top, or the ladder is still building): cut to the
-            # largest ready one, as many parts as hold its rows
-            n_pad = -(-n // bucket) * bucket
-            self._stat_add("n_launch_cuts", 1.0)
+        parts = self._plan_parts(lane, exploded.sizes, launch.fits, n, int(value_bytes))
+        launch.r_out = lane.fns[parts[-1].stride][1]
+        pack = (
+            self._pack_staged_ptrs
+            if isinstance(exploded, batch_codec.PtrExploded)
+            else self._pack_staged
+        )
         t0 = _stage_t0("t_pack")
-        if isinstance(exploded, batch_codec.PtrExploded):
-            staged = self._pack_staged_ptrs(exploded, n_pad)
-        else:
-            staged = self._pack_staged(exploded, n_pad)
+        cut = False
+        for part in parts:
+            k = n if part.rows is None else len(part.rows)
+            part.n_pad = _bucket_rows(k)
+            part.program, part.bucket = self._program_for(part.fn, part.n_pad)
+            if part.bucket < part.n_pad:
+                # no program is ready at this part's bucket (it is over the
+                # ladder's top, or the ladder is still building): cut to
+                # the largest ready one, as many runs as hold its rows
+                part.n_pad = -(-k // part.bucket) * part.bucket
+                cut = True
+        # whether each part's matrix was a parked one. The larger matrix is
+        # taken first: the pool hands out its smallest parked buffer that
+        # is big enough, and the smaller part must not take the larger's
+        parked: list[bool] = []
+        for part in sorted(parts, key=lambda p: -p.n_pad * (p.stride + IN_META)):
+            part.staged = pack(exploded, part.n_pad, part.stride, part.rows, parked)
+        if all(parked):
+            # a launch that paid no first touch of a fresh matrix
+            self._stat_add("n_staging_reuses", 1.0)
         if not retained:
             _release_exploded(exploded)
         self._stat_stage("t_pack", t0)
-        # what the staging matrix holds against what it is: record bytes
-        # over rows x stride (a 130 B event in a 1,032 B row is mostly
-        # zeros that still cross the link)
-        self._stat_add("bytes_staged", float(staged.nbytes))
+        if cut:
+            self._stat_add("n_launch_cuts", 1.0)
+        if len(parts) > 1:
+            self._stat_add("n_split_launches", 1.0)
+        # what the staging matrices hold against what they are: record
+        # bytes over rows x stride (a 100 B event in a 1,032 B row is
+        # mostly zeros that still cross the link; in a 136 B row it is not)
+        self._stat_add("bytes_staged", float(sum(p.staged.nbytes for p in parts)))
         self._stat_add("bytes_staged_values", value_bytes)
-        self._launch_payload(launch, staged, n_pad, fn, r_out, program, bucket)
+        self._launch_payload(launch, parts)
+
+    def _plan_parts(
+        self, lane: _SpecPrograms, sizes: np.ndarray, fits: np.ndarray, n: int,
+        nbytes: int,
+    ) -> list[_Part]:
+        """How a payload launch is staged, read off its own values: ONE
+        part at the stride that fits (the smallest multiple of
+        _STRIDE_CLASS that holds the widest fitting value, never over the
+        lane's row_stride, which stays the limit a value is held to), or
+        TWO where one stride cannot fit: from the histogram of the values
+        over the width classes, the rows up to the narrow stride that
+        leaves the fewest staged bytes and the rest at the widest's, taken
+        only where that is _SPLIT_MIN_SAVING times under the one fitted
+        matrix (row buckets and IN_META counted as staged). A value that
+        is staged empty (null, empty, oversize) rides in the narrowest
+        class. A (stride, row bucket) first seen here is left for
+        ``rptpu-precompile`` to build and the launch goes at the narrowest
+        READY stride that holds it, unsplit (the lane's own at worst):
+        nothing compiles on the serving path for it."""
+        limit = self._row_stride
+        classes = -(-limit // _STRIDE_CLASS)
+        lo = -(-lane.min_stride // _STRIDE_CLASS)
+        cls = np.where(fits, (sizes + (_STRIDE_CLASS - 1)) // _STRIDE_CLASS, 0)
+        np.clip(cls, lo, classes, out=cls)
+        hist = np.bincount(cls, minlength=classes + 1)
+        widest = int(np.flatnonzero(hist)[-1])
+
+        def stride(c: int) -> int:
+            return min(c * _STRIDE_CLASS, limit)
+
+        wide = stride(widest)
+        best = None  # (staged bytes, narrow class, narrow rows)
+        if lane.split_ok:
+            below = 0
+            for c in range(lo, widest):
+                below += int(hist[c])
+                if not below:
+                    continue
+                staged = _bucket_rows(below) * (stride(c) + IN_META) + _bucket_rows(
+                    n - below
+                ) * (wide + IN_META)
+                if best is None or staged < best[0]:
+                    best = (staged, c, below)
+        split = best is not None and best[0] * _SPLIT_MIN_SAVING <= _bucket_rows(n) * (
+            wide + IN_META
+        )
+        if split:
+            _bytes, c, below = best
+            # both asked, so that both ladders start at the first sight
+            ready = [
+                self._stride_ready(lane, stride(c), below, n, nbytes),
+                self._stride_ready(lane, wide, n - below, n, nbytes),
+            ]
+            if all(ready):
+                narrow = cls <= c
+                return [
+                    _Part(stride(c), np.flatnonzero(narrow), lane.at(stride(c))[0]),
+                    _Part(wide, np.flatnonzero(~narrow), lane.at(wide)[0]),
+                ]
+        # one part: at the fitted stride, or, while its program is not built
+        # (asked for here unless the split's are), at the narrowest stride
+        # the spec has shown that is ready; the lane's own at worst
+        for st in sorted({wide, *(s for s in lane.fns if s > wide)}):
+            if self._stride_ready(lane, st, n, n, nbytes, start=st == wide and not split):
+                return [_Part(st, None, lane.at(st)[0])]
+        raise AssertionError("the lane's own stride is always ready")
+
+    def _stride_ready(
+        self, lane: _SpecPrograms, stride: int, k: int, n: int, nbytes: int,
+        start: bool = True,
+    ) -> bool:
+        """Whether a part ``stride`` wide that holds ``k`` of a launch's
+        ``n`` rows (``nbytes`` of values in all) runs a built program. The
+        lane's own stride always does (its ladder is built at deploy and a
+        launch waits for it or is cut to it), and on an engine that builds
+        no ladder every stride is a first run on the serving path, as the
+        lane's own is. Otherwise the stride's (lazy) ladder answers,
+        started the first time a launch shows the stride, and (``start``)
+        a bucket it lacks is left for the builder: at once the one the
+        same mix fills in the largest launch the read budget gives (so the
+        launch knob's ramp finds its last step built), and the part's own
+        where launches keep asking for it (_WANT_HOLD_S)."""
+        if stride == self._row_stride:
+            return True
+        fn = lane.at(stride)[0]
+        with self._stats_lock:
+            base = self._ladders.get(lane.fns[self._row_stride][0])
+            ladder = self._ladders.get(fn)
+        if base is None:
+            return True
+        if ladder is None or ladder.top < base.top:
+            if not start:
+                return False
+            self._start_ladder(fn, stride, base.top)
+            with self._stats_lock:
+                ladder = self._ladders[fn]
+        ready = ladder.has(_bucket_rows(k), want=start, hold_s=_WANT_HOLD_S)
+        if start:
+            # the largest launch: the ladders' top where the values are as
+            # dense as the top is sized for (_LADDER_ROWS_PER_STRIDE), as
+            # many fewer rows as these are wider (at least this launch's)
+            budget = base.top * self._row_stride // _LADDER_ROWS_PER_STRIDE
+            most = max(n, min(base.top, budget * n // max(nbytes, 1)))
+            ahead = ladder.has(_bucket_rows(k * most // n))
+            if not (ready and ahead):
+                with self._stats_lock:
+                    self._wake_precompiler()
+        return ready
 
     def _program_for(self, fn, n_pad: int):
-        """(program, row bucket) a payload launch padded to ``n_pad`` rows
+        """(program, row bucket) a payload part padded to ``n_pad`` rows
         runs: its ladder's program at that bucket; the largest ready one
-        below it, which the launch is cut to; or (None, n_pad) where no
+        below it, which the part is cut to; or (None, n_pad) where no
         ladder serves ``fn`` (none was built, or its build failed): the
         jitted function itself, whose first call at a bucket is a first run
         on the serving path."""
@@ -2870,75 +3235,89 @@ class TpuEngine:
             return None, n_pad
         return ladder.program_for(n_pad, _COMPILE_DEADLINE_S)
 
-    def _launch_payload(
-        self, launch: _Launch, staged: np.ndarray, n_pad: int, fn, r_out: int,
-        program, bucket: int,
-    ) -> None:
-        """Issue one payload-plan device launch over a built staging
-        matrix (breaker gate, fault envelope, exact host fallback) —
+    def _launch_payload(self, launch: _Launch, parts: list[_Part]) -> None:
+        """Issue one payload-plan device launch over its built staging
+        matrices (breaker gate, fault envelope, exact host fallback) —
         shared by the classic joined-blob and pointer-table staging
-        lanes. The result format follows the plan (_mask_result): the
-        packed result matrix, fetched at harvest by _mat_payload, or the
-        bit-packed keep mask, which rides the mask harvester and
-        _resolve_keep like a columnar predicate's.
+        lanes. One part, or two by width class: each its own H2D and its
+        own program, all inside ONE leg, so a launch is one device launch
+        and one verdict however it was staged. The result format follows
+        the plan (_mask_result): the packed result matrix, fetched at
+        harvest by _mat_payload, or the bit-packed keep mask, which rides
+        the mask harvester and _resolve_keep like a columnar predicate's.
 
         When a staging matrix goes back to the pool (the ONE rule):
         ``jax.device_put`` returns long before the matrix has crossed the
         link, and JAX reads the numpy memory until it has. So the launch
-        keeps the matrix until its device result has LANDED (the keep
+        keeps its matrices until its device result has LANDED (the keep
         mask resolved in _gather_view, or the result matrix fetched in
-        _mat_payload): the program has then consumed its input, and
-        _Launch._park_staged gives the matrix back. Whenever the host
+        _mat_payload): the programs have then consumed their input, and
+        _Launch._park_staged gives the matrices back. Whenever the host
         fallback runs instead (breaker open, retries exhausted, envelope
-        timed out, harvest demoted), _payload_host_fallback drops the
-        matrix after reading it: a tried device leg may still be reading
-        it, so it never re-enters the pool. An abandoned launch's matrix
-        goes with the launch. Nothing reads the staged device array after
-        the result has landed (on a backend whose device_put aliases
-        numpy memory that is what makes reuse safe)."""
+        timed out, harvest demoted), _payload_host_fallback drops them
+        after reading them: a tried device leg may still be reading one,
+        so none re-enters the pool. An abandoned launch's matrices go with
+        the launch. Nothing reads the staged device arrays after the
+        result has landed (on a backend whose device_put aliases numpy
+        memory that is what makes reuse safe)."""
         import jax
 
         mask_result = self._mask_result(launch._plan)
+        r_out = launch.r_out
         # retained until the result lands: the host fallback re-runs the
         # pipeline in numpy over exactly these rows
-        launch._staged_np = staged
-        # what the lane adds to a launch: the rows it pads the bucket with,
-        # and the values it drops for exceeding the staging row
+        launch._staged_parts = parts
+        # what the lane adds to a launch: the rows it pads the buckets
+        # with, and the values it drops for exceeding the staging row
+        n_pad = sum(part.n_pad for part in parts)
         self._stat_add("n_staged_rows", float(n_pad))
         n_oversize = launch.n - int(np.count_nonzero(launch.fits))
         if n_oversize:
             self._stat_add("n_oversize_rows", float(n_oversize))
         t0 = _stage_t0("t_dispatch")
-        # a program built ahead of need, or the jitted function (whose
-        # first call at a bucket traces and compiles); over ``n_pad`` rows
-        # in one part, or, a cut launch, in parts of ``bucket`` rows
-        run = program or fn
 
         def leg():
             faults.inject(faults.DEVICE_DISPATCH)
             # the leg runs on the fault envelope's worker: no ambient trace
             t_h2d = _stage_t0("t_h2d")
             dev = [
-                jax.device_put(staged[i : i + bucket])
-                for i in range(0, n_pad, bucket)
+                [
+                    jax.device_put(part.staged[i : i + part.bucket])
+                    for i in range(0, part.n_pad, part.bucket)
+                ]
+                for part in parts
             ]
             self._stat_stage("t_h2d", t_h2d, trace_id=launch.trace_id)
-            parts = [run(part) for part in dev]
-            for part in parts:
-                part.copy_to_host_async()
-            # the staged device array rides on the launch until the fetch
-            # has timed its H2D (_Launch._fetch_legs drops it). Set here and
-            # not handed back beside the result: the envelope's worker keeps
-            # what a leg returned until its next job ends, and a 33.8 MB
-            # device array must not wait on that
+            # a program built ahead of need, or the jitted function (whose
+            # first call at a bucket traces and compiles); over the part's
+            # rows in one run, or, a cut part, in runs of ``bucket`` rows
+            results = [
+                [(part.program or part.fn)(cut) for cut in cuts]
+                for part, cuts in zip(parts, dev)
+            ]
+            for cuts in results:
+                for result in cuts:
+                    result.copy_to_host_async()
+            # the staged device arrays ride on the launch until the fetch
+            # has timed their H2D (_Launch._fetch_legs drops them). Set here
+            # and not handed back beside the result: the envelope's worker
+            # keeps what a leg returned until its next job ends, and a
+            # 17.8 MB device array must not wait on that
             launch._staged_dev = dev
-            return parts[0] if len(parts) == 1 else _CutResult(parts)
+            if len(results) == 1 and len(results[0]) == 1:
+                return results[0][0]
+            return _PartsResult(
+                results, [part.rows for part in parts] if len(parts) > 1 else None
+            )
 
         packed = None
         if self._breaker.allow_device():
             packed = self._try_device_leg(
                 faults.DEVICE_DISPATCH, leg,
-                program=(launch.script_id, "payload", bucket), fn=fn,
+                programs=[
+                    ((launch.script_id, "payload", part.bucket, part.stride), part.fn)
+                    for part in parts
+                ],
             )
         if packed is None:
             # open breaker or exhausted retries: the exact host result, in
@@ -2955,7 +3334,7 @@ class TpuEngine:
         # the harvest domain's verdict, recorded at fetch time
         self._breaker.record_success()
         self._stat_stage("t_dispatch", t0)
-        self._stat_add("bytes_h2d", staged.nbytes)
+        self._stat_add("bytes_h2d", sum(part.staged.nbytes for part in parts))
         if mask_result:
             self._stat_add("bytes_d2h", n_pad // 8)
             self._enqueue_mask(launch, packed)
@@ -3061,7 +3440,7 @@ class TpuEngine:
 
             mask = self._try_device_leg(
                 faults.DEVICE_DISPATCH, leg,
-                program=(launch.script_id, "predicate", n_pad),
+                programs=[((launch.script_id, "predicate", n_pad), None)],
             )
             if mask is None:
                 launch._mask_np = plan.eval_host_mask(cols)
@@ -3252,48 +3631,56 @@ class TpuEngine:
             dict(TpuEngine._columnar_probe),
         )
 
-    def _take_staging(self, n_pad: int) -> np.ndarray:
-        """A [n_pad, row_stride + IN_META] staging matrix out of the pool,
-        holding anything: a parked one when one is big enough
-        (``n_staging_reuses``), else a new one. _Launch._park_staged gives
-        it back (its ``.base`` is the pool's buffer)."""
-        stride = self._row_stride + IN_META
+    def _take_staging(self, n_pad: int, stride: int, parked: list) -> np.ndarray:
+        """A [n_pad, stride + IN_META] staging matrix out of the pool,
+        holding anything: a parked one when one is big enough, else a new
+        one. Which it was is appended to ``parked`` (_dispatch_payload
+        counts a launch whose every matrix was a parked one in
+        ``n_staging_reuses``). _Launch._park_staged gives it back (its
+        ``.base`` is the pool's buffer)."""
+        stride += IN_META
         # asked for by the row bucket, whatever the rows staged: a cut
         # launch (k parts of a smaller bucket) takes and parks a buffer of
         # its uncut size, so the pool's few slots hold the buckets a ramp
         # walks and never fill up with sizes no later launch can use
         buf, reused = self._staging.take(_bucket_rows(n_pad) * stride)
-        if reused:
-            self._stat_add("n_staging_reuses", 1.0)
+        parked.append(reused)
         return buf[: n_pad * stride].reshape(n_pad, stride)
 
-    def _pack_staged(self, exploded, n_pad: int) -> np.ndarray:
-        """[n_pad, row_stride + IN_META] uint8: record bytes then LE32 length.
+    def _pack_staged(
+        self, exploded, n_pad: int, stride: int, rows: np.ndarray | None,
+        parked: list,
+    ) -> np.ndarray:
+        """[n_pad, stride + IN_META] uint8: record bytes then LE32 length,
+        of the launch's rows (``rows`` None) or of ``rows`` (one part of a
+        launch staged by width class).
 
         Records wider than the staging row cannot be transformed faithfully:
         their length is staged as 0 here and their keep bit is cleared after
         the launch via ``launch.fits`` (the reference bounds record size
         upstream via coproc_max_batch_size; truncating would corrupt data
-        silently).
+        silently). A part narrower than the lane's row_stride holds no
+        fitting value wider than itself (_plan_parts), so what it stages
+        as 0 is what the full-width matrix would.
         """
-        r = self._row_stride
-        n = len(exploded.sizes)
-        sizes = exploded.sizes
-        staged = self._take_staging(n_pad)
+        r = stride
+        sizes, offsets = exploded.sizes, exploded.offsets
+        if rows is not None:
+            sizes, offsets = sizes[rows], offsets[rows]
+        n = len(sizes)
+        staged = self._take_staging(n_pad, r, parked)
         try:
             from redpanda_tpu.native import lib
         except Exception:
             lib = None
         if lib is not None:
-            lib.pack_rows_into(
-                exploded.joined, exploded.offsets, sizes, staged[:n]
-            )
+            lib.pack_rows_into(exploded.joined, offsets, sizes, staged[:n])
         else:
             from redpanda_tpu.ops.packing import pack_rows
 
             vals = [
                 exploded.joined[o : o + s]
-                for o, s in zip(exploded.offsets, np.minimum(sizes, r))
+                for o, s in zip(offsets, np.minimum(sizes, r))
             ]
             staged[:n, :r] = pack_rows(vals, r)[0]
         staged[n:] = 0
@@ -3302,13 +3689,17 @@ class TpuEngine:
         staged[:n, r + 4 :] = 0
         return staged
 
-    def _pack_staged_ptrs(self, pe, n_pad: int) -> np.ndarray:
+    def _pack_staged_ptrs(
+        self, pe, n_pad: int, stride: int, rows: np.ndarray | None,
+        parked: list,
+    ) -> np.ndarray:
         """_pack_staged's pointer-table twin: the staging matrix fills
         straight from each batch's retained decompressed payload buffer
         (batch_codec.PtrExploded) in one native crossing — no joined blob
         is ever built or re-read. Byte-identical output to _pack_staged
         over the merged exploded table, into a fresh matrix or a reused
-        one (the staging parity test pins it)."""
-        staged = self._take_staging(n_pad)
-        batch_codec.pack_exploded_ptrs(pe, staged, self._row_stride)
+        one, at any stride and over any row selection (the staging parity
+        test pins it)."""
+        staged = self._take_staging(n_pad, stride, parked)
+        batch_codec.pack_exploded_ptrs(pe, staged, stride, rows)
         return staged

@@ -259,7 +259,7 @@ class _NativeLib:
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
-                ctypes.c_int64, ctypes.c_size_t,
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_size_t,
             ]
         self.has_parse_many_ptrs = hasattr(dll, "rp_parse_many_ptrs")
         if self.has_parse_many_ptrs:
@@ -410,22 +410,29 @@ class _NativeLib:
         ends: np.ndarray,
         dst: np.ndarray,
         row_stride: int,
+        rows: np.ndarray | None = None,
     ) -> None:
         """Fill a payload launch's whole staging matrix in ONE crossing
         (rp_pack_rows_ptrs): batch r's records, their (offset, len)
         relative to their own buffer ``srcs[r]`` (a list of ``bytes`` or
-        a pointer table, ``src_table``), become rows
-        [starts[r], ends[r]) of ``dst`` [n_pad, row_stride + 8]: value,
+        a pointer table, ``src_table``), are rows [starts[r], ends[r]) of
+        the table. Row j of ``dst`` [n_pad, row_stride + 8] is the table's
+        row ``rows[j]`` (row numbers, ascending: one part of a launch
+        staged by width class), or row j itself with ``rows`` None: value,
         zeroed tail, LE32 length (0 for a null value and for one wider
-        than ``row_stride``), four zero bytes; the rows past the last
-        range are cleared. ``dst`` may be a reused matrix holding
-        anything. The ranges must tile [0, n) in order; a span outside its
-        buffer is a ValueError and nothing has been written."""
+        than ``row_stride``), four zero bytes; the rows past the last one
+        are cleared. ``dst`` may be a reused matrix holding anything. The
+        ranges must tile [0, n) in order; a span outside its buffer, a row
+        outside the table or rows that do not ascend are a ValueError and
+        nothing has been written."""
         offsets = np.ascontiguousarray(offsets, dtype=np.int64)
         lens = np.ascontiguousarray(lens, dtype=np.int32)
         starts = np.ascontiguousarray(starts, dtype=np.int64)
         ends = np.ascontiguousarray(ends, dtype=np.int64)
+        if rows is not None:
+            rows = np.ascontiguousarray(rows, dtype=np.int64)
         n = len(offsets)
+        k = n if rows is None else len(rows)
         n_batches = len(starts)
         ptrs, src_lens = src_table(srcs)
         if len(ptrs) != n_batches or len(ends) != n_batches:
@@ -435,9 +442,10 @@ class _NativeLib:
         if dst.dtype != np.uint8 or not dst.flags["C_CONTIGUOUS"]:
             raise ValueError("pack_rows_ptrs dst must be contiguous uint8")
         n_pad, stride = dst.shape
-        if stride != row_stride + 8 or n_pad < n:
-            raise ValueError("pack_rows_ptrs dst shape does not fit the launch")
-        # every row is written exactly once: the ranges tile [0, n)
+        if stride != row_stride + 8 or n_pad < k:
+            raise ValueError("pack_rows_ptrs dst shape does not fit the rows")
+        # every row of the table belongs to exactly one batch: the ranges
+        # tile [0, n) (the C walk reads offsets[i] / lens[i] for i < n)
         edges = np.concatenate(([0], ends))
         if (
             edges[-1] != n
@@ -448,10 +456,13 @@ class _NativeLib:
         rc = self._dll.rp_pack_rows_ptrs(
             ptrs.ctypes.data, src_lens.ctypes.data, offsets.ctypes.data,
             lens.ctypes.data, starts.ctypes.data, ends.ctypes.data,
-            n_batches, dst.ctypes.data, n, n_pad, row_stride,
+            n_batches, None if rows is None else rows.ctypes.data, k,
+            dst.ctypes.data, n_pad, row_stride,
         )
         if rc < 0:
-            raise ValueError("pack span outside its source buffer")
+            raise ValueError(
+                "pack span outside its source buffer, or rows outside the table"
+            )
 
     def parse_record_values(self, payload: bytes, count: int) -> tuple[np.ndarray, np.ndarray]:
         """Offsets/lengths of each record's value within a batch payload."""

@@ -152,6 +152,12 @@ coproc_oversize_rows = registry.counter(
     "coproc_oversize_rows_total",
     "Values wider than the staging row, which the payload lane drops",
 )
+# Payload launches staged in two parts by width class (their narrow rows in
+# a narrow matrix, their few wide ones beside it: TpuEngine._plan_parts).
+coproc_split_launches = registry.counter(
+    "coproc_split_launches_total",
+    "Payload launches staged, shipped and run as two parts by width class",
+)
 # The staging matrices themselves, in bytes: rows x stride of every matrix
 # the lane packed, and the record bytes put into them (their ratio is what
 # of a launch's H2D is data; ~0.13 for 130 B events in 1,032 B rows).

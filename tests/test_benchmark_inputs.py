@@ -289,3 +289,39 @@ def test_the_live_zstd_configurations_reference_keeps_a_third_and_its_sequence()
         k: v for k, v in paced.items() if k not in ("warmup", "what")}
     assert {k: device["warmup"][k] for k in ("min_s", "quiet_s", "cap_s")} == {
         "min_s": 15, "quiet_s": 6, "cap_s": 50}
+
+
+@pytest.mark.parametrize("stats, want", [
+    ({"n_launches": 64.0, "n_split_launches": 64.0}, 1.0),   # NEXmark: every launch in two parts
+    ({"n_launches": 57.0, "n_split_launches": 0.0}, 0.0),
+    ({"n_launches": 57.0}, 0.0),                              # a program without the counter
+    ({"n_launches": 10.0, "n_split_launches": 4.0}, 0.4),
+    ({}, None),                                               # no launch: nothing to read
+])
+def test_split_launch_share_is_read_by_the_stats_ratio_reader_and_listed(stats, want):
+    """PR 47's per-layer metric is data alone: ``layer_metrics/split_launch_share.json``
+    is read by the reader the benchmark already had, over ``stats()``'s
+    ``n_split_launches`` and ``n_launches``, and ``BENCHMARK.json`` lists it
+    for the cells ``staged_value_share`` is listed for."""
+    import readers
+
+    directory = os.path.join(BENCH, "layer_metrics")
+    (d,) = [d for d in readers.load_definitions(directory) if d["name"] == "split_launch_share"]
+    (twin,) = [d for d in readers.load_definitions(directory) if d["name"] == "staged_value_share"]
+    assert d["read"]["kind"] == "stats_ratio" and d["read"]["kind"] in readers.KINDS
+    assert (d["read"]["num"], d["read"]["den"]) == (["n_split_launches"], ["n_launches"])
+    assert {k: d[k] for k in ("layer", "moves", "traffic", "source")} == {
+        "layer": "link", "moves": "transform_rate", "traffic": twin["traffic"],
+        "source": "program_counter"}
+    listed = {e["name"]: e for e in bench.manifest()["per_layer"]}
+    entry = listed["split_launch_share"]
+    assert entry == {**{k: d[k] for k in ("name", "unit", "better", "source", "layer", "moves")},
+                     "workloads": listed["staged_value_share"]["workloads"]}
+    assert bench.manifest()["per_layer"][-1] == entry  # appended, nothing before it moved
+    before = {"stats": {k: 3.0 for k in stats}, "metrics": {}}
+    after = {"stats": {k: 3.0 + v for k, v in stats.items()}, "metrics": {}}
+    got = readers.read_all(directory, kind="catchup", before=before, after=after,
+                           client={}, trace=None, window_s=40.0).get("split_launch_share")
+    assert got == (None if want is None else {"value": want, "unit": "ratio"})
+    assert "split_launch_share" not in readers.read_all(
+        directory, kind="paced", before=before, after=after, client={}, trace=None, window_s=40.0)

@@ -106,14 +106,41 @@ def test_stage_b_engine_lanes_at_tiny_size():
         2, partitions=4, records_per_partition=64, ticks_per_launch=1,
         programs=False,
     )
-    assert [lane["lane"] for lane in r["lanes"]] == ["columnar_device", "payload"]
+    assert [lane["lane"] for lane in r["lanes"]] == [
+        "columnar_device", "payload", "payload_mixed_width"]
     for lane in r["lanes"]:
         assert lane["reference_match"], lane
         assert lane["records_materialised"] == lane["records_expected"] > 0
         assert lane["n_device_launches"] >= lane["launches"]
-        assert lane["n_compiles"] == 1
         assert lane["failures"] == []
+    # 128 rows a launch are too few for two parts to halve the bytes, and
+    # eight wide documents a launch may or may not pass 1,024 B: the mixed
+    # lane runs one program a fitted stride (1,024 and 1,152)
+    assert [lane["n_compiles"] for lane in r["lanes"][:2]] == [1, 1]
+    assert r["lanes"][2]["n_compiles"] in (1, 2) and r["lanes"][2]["n_split_launches"] == 0
     assert r["failures"] == [CPU_REFUSAL]
+
+
+def test_the_mixed_width_lane_is_staged_in_two_parts_and_matches_the_reference():
+    """Stage B's third lane at the smallest size where the split halves a
+    launch's bytes (1,024 rows): every launch goes as a narrow matrix and a
+    wide one, and every reply is the plain reference's."""
+    from redpanda_tpu.coproc import reference
+
+    values = chip_smoke.mixed_width(reference.make_documents(2, 4, 512))
+    sizes = [len(v) for part in values for v in part]
+    assert sum(s <= 128 for s in sizes) == len(sizes) * 15 // 16 and max(sizes) > 900
+    lane = chip_smoke.run_lane(
+        "payload_mixed_width", chip_smoke.specs()[chip_smoke.PAYLOAD_SCRIPT], values,
+        chip_smoke.reference_fns(chip_smoke.ENGINE_ROW_STRIDE)[chip_smoke.PAYLOAD_SCRIPT],
+        8, force_mode="payload",
+    )
+    assert lane["rows_per_launch"] == 1024 and lane["reference_match"], lane
+    assert lane["records_materialised"] == lane["records_expected"] > 0
+    assert lane["n_split_launches"] == lane["n_launches"] == lane["launches"]
+    assert lane["n_compiles"] == 1 and lane["failures"] == []
+    # a narrow matrix of 136 B rows and a wide one of 1,160 B rows a launch
+    assert lane["bytes_h2d"] == lane["launches"] * (1024 * 136 + 128 * 1160)
 
 
 def test_stage_c_mesh_lane_on_virtual_devices():

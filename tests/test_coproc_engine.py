@@ -323,8 +323,9 @@ def _staging_batches(n_batches=5):
     return [shapes[i % len(shapes)] for i in range(n_batches)]
 
 
+@pytest.mark.parametrize("part", ["whole", "narrow_rows_at_64", "wide_rows", "every_third_row"])
 @pytest.mark.parametrize("pool", ["fresh", "reused_dirty", "smaller_after_larger"])
-def test_pack_staged_ptr_lane_bit_parity(pool, monkeypatch):
+def test_pack_staged_ptr_lane_bit_parity(pool, part, monkeypatch):
     """The pointer-table payload staging (_pack_staged_ptrs over
     batch_codec.explode_ptrs — no joined blob, one native crossing)
     produces byte-identical staging matrices to the classic joined-blob
@@ -332,7 +333,10 @@ def test_pack_staged_ptr_lane_bit_parity(pool, monkeypatch):
     records wider than the row stride — into a fresh matrix, into a parked
     one that a larger launch left all 0xFF, and into the front of a larger
     launch's matrix when the bucket shrinks: pad rows and meta bytes
-    included."""
+    included. ``part`` (PR 47): the whole launch at the lane's stride, or
+    one part of a launch staged by width class: a selection of its rows,
+    at a stride under the lane's own; the selection's matrix is the
+    selected rows of the whole launch's at that stride."""
     import numpy as np
 
     from redpanda_tpu.coproc import batch_codec
@@ -345,33 +349,46 @@ def test_pack_staged_ptr_lane_bit_parity(pool, monkeypatch):
     ex = batch_codec.explode_batches(batches)
     assert pe.ranges == ex.ranges
     assert np.array_equal(pe.sizes, ex.sizes)
+    n = len(ex.sizes)
+    stride, rows = {
+        "whole": (128, None),
+        "narrow_rows_at_64": (64, np.flatnonzero(ex.sizes <= 64)),
+        "wide_rows": (128, np.flatnonzero(ex.sizes > 64)),
+        "every_third_row": (128, np.arange(0, n, 3)),
+    }[part]
+    k = n if rows is None else len(rows)
+    assert 0 < k and (rows is None or k < n)
     oracle = TpuEngine(row_stride=128)
-    n_pad = _bucket_rows(len(ex.sizes))
-    classic = oracle._pack_staged(ex, n_pad)
-    assert classic.shape == (n_pad, 136) and n_pad > len(ex.sizes)
+    n_pad = _bucket_rows(k)
+    classic = oracle._pack_staged(ex, n_pad, stride, rows, [])
+    assert classic.shape == (n_pad, stride + 8) and n_pad > k
+    if rows is not None:
+        whole = oracle._pack_staged(ex, _bucket_rows(n), stride, None, [])
+        assert np.array_equal(classic[:k], whole[rows]) and not classic[k:].any()
     oracle.shutdown()
 
     engine = TpuEngine(row_stride=128)
     if pool == "reused_dirty":
-        big = engine._take_staging(4 * n_pad)
+        big = engine._take_staging(4 * n_pad, 128, [])
         big[:] = 0xFF
         engine._staging.release(big.base)
     elif pool == "smaller_after_larger":
         larger = batch_codec.explode_ptrs(_staging_batches(40))
         assert _bucket_rows(len(larger.sizes)) > n_pad
-        big = engine._pack_staged_ptrs(larger, _bucket_rows(len(larger.sizes)))
+        big = engine._pack_staged_ptrs(
+            larger, _bucket_rows(len(larger.sizes)), 128, None, []
+        )
         engine._staging.release(big.base)
-    ptr = engine._pack_staged_ptrs(pe, n_pad)
+    parked: list = []
+    ptr = engine._pack_staged_ptrs(pe, n_pad, stride, rows, parked)
     assert np.array_equal(classic, ptr)
     st = engine.stats()
     if pool == "fresh":
-        assert st["staging_arena"]["reuses"] == 0
-        assert "n_staging_reuses" not in st
+        assert st["staging_arena"]["reuses"] == 0 and parked == [False]
     else:
         # the parked matrix of the larger launch served this one
         assert ptr.base is big.base
-        assert st["staging_arena"]["reuses"] == 1
-        assert st["n_staging_reuses"] == 1
+        assert st["staging_arena"]["reuses"] == 1 and parked == [True]
     # the classic road draws from the same pool, and packs the same bytes
     # over whatever the matrix held, with the native library and without
     import redpanda_tpu.native as native_mod
@@ -380,7 +397,7 @@ def test_pack_staged_ptr_lane_bit_parity(pool, monkeypatch):
         monkeypatch.setattr(native_mod, "lib", lib)
         ptr[:] = 0xEE
         engine._staging.release(ptr.base)
-        again = engine._pack_staged(ex, n_pad)
+        again = engine._pack_staged(ex, n_pad, stride, rows, [])
         assert again.base is ptr.base and np.array_equal(classic, again)
     engine.shutdown()
 
@@ -438,6 +455,46 @@ def test_pack_rows_ptrs_bad_span_raises_and_writes_nothing():
     assert not (dst[n:] != 0).any()
 
 
+def test_pack_rows_ptrs_refuses_a_bad_row_selection_and_writes_nothing():
+    """The row selection's binding (PR 47): rows outside the table or out
+    of order, a selected span outside its buffer, a matrix of the wrong
+    shape are a ValueError with nothing written; an unselected bad span is
+    nobody's business."""
+    import numpy as np
+
+    from redpanda_tpu.coproc import batch_codec
+
+    pe = batch_codec.explode_ptrs(_staging_batches())
+    if pe is None:
+        pytest.skip("native packer unavailable")
+    from redpanda_tpu.native import lib
+
+    starts, ends = batch_codec._range_cols(pe.ranges)
+    n = len(pe.sizes)
+    dst = np.full((128, 72), 0xAB, np.uint8)
+    good = np.arange(1, n, 2)
+
+    def pack(rows, offsets=pe.offsets, into=dst, stride=64):
+        lib.pack_rows_ptrs(pe.payloads, offsets, pe.sizes, starts, ends, into, stride, rows)
+
+    for rows in ([0, n], [-1, 0], [3, 2], [2, 2]):
+        with pytest.raises(ValueError):
+            pack(np.array(rows))
+        assert (dst == 0xAB).all()
+    bad = pe.offsets.copy()
+    bad[1] = len(pe.payloads[0])
+    with pytest.raises(ValueError):
+        pack(good, offsets=bad)
+    with pytest.raises(ValueError):
+        pack(good, into=dst[:, :70])
+    with pytest.raises(ValueError):
+        pack(np.arange(n), into=dst[: n - 1])
+    assert (dst == 0xAB).all()
+    pack(np.arange(0, n, 2), offsets=bad)  # row 1 is not selected
+    k = len(range(0, n, 2))
+    assert not (dst[k:] != 0).any() and dst[:k].any()
+
+
 def test_pack_staged_null_empty_and_oversize_values_stage_length_zero():
     """A null value, an empty value and a value wider than the staging row
     all stage length 0 on both roads (the device's keep drops length 0,
@@ -453,14 +510,14 @@ def test_pack_staged_null_empty_and_oversize_values_stage_length_zero():
     )
     engine = TpuEngine(row_stride=64)
     ex = batch_codec.explode_batches([batch])
-    mats = [engine._pack_staged(ex, 128)]
+    mats = [engine._pack_staged(ex, 128, 64, None, [])]
     pe = batch_codec.explode_ptrs([batch])
     if pe is not None:
         assert [int(x) for x in pe.rel_len[0]] == [3, -1, 0, 64, 65, 200, 4]
-        dirty = engine._take_staging(128)
+        dirty = engine._take_staging(128, 64, [])
         dirty[:] = 0xFF
         engine._staging.release(dirty.base)
-        mats.append(engine._pack_staged_ptrs(pe, 128))
+        mats.append(engine._pack_staged_ptrs(pe, 128, 64, None, []))
         assert np.array_equal(mats[0], mats[1])
     for staged in mats:
         lens = staged[:, 64:68].copy().view("<i4")[:, 0]
@@ -715,7 +772,7 @@ def test_staging_pool_is_reset_and_trimmed_with_the_arena():
         "allocs": 0, "reuses": 0, "alloc_bytes": 0, "free_buffers": 0, "trims": 0,
     }
     assert st["arena"]["allocs"] == 0
-    held = [engine._take_staging(128) for _ in range(6)]
+    held = [engine._take_staging(128, 256, []) for _ in range(6)]
     for staged in held:
         engine._staging.release(staged.base)
     assert (
@@ -774,14 +831,18 @@ def test_payload_launch_accounting_follows_what_crosses(gather):
     assert stats["n_launches"] == stats["n_device_launches"] == 2
     assert stats["n_compiles"] == 1
     assert stats["n_staged_rows"] == 2 * 128 and stats["n_records"] == 2 * 51
-    assert stats["bytes_h2d"] == 2 * 128 * (256 + 8)
+    # the values are under 128 B: the matrix is fitted to them, a 136 B row
+    # in place of the lane's 264 B one, and on the matrix road a filter's
+    # result row follows it
+    assert stats["bytes_h2d"] == stats["bytes_staged"] == 2 * 128 * (128 + 8)
+    assert "n_split_launches" not in stats
     assert stats["t_fetch"] > 0
     if gather:
         assert stats["bytes_d2h"] == 2 * 128 // 8
         assert stats["n_frame_gather"] == 2 and stats["t_frame_gather"] > 0
         assert "t_rebuild" not in stats and posture == "gather"
     else:
-        assert stats["bytes_d2h"] == 2 * 128 * (256 + 8)
+        assert stats["bytes_d2h"] == 2 * 128 * (128 + 8)
         assert stats["n_frame_padded"] == 2 and "t_frame_gather" not in stats
         assert posture == "padded"
     mine = [

@@ -583,7 +583,17 @@ def test_payload_mask_harvest_matches_matrix_road(name, shape, use_native, monke
         assert "t_rebuild" not in stats_on
         # what crosses back is one bit a staged row
         assert stats_on.get("bytes_d2h", 0) == n_pad // 8
-        assert stats_off.get("bytes_d2h", 0) == n_pad * (_STRIDE + 8)
+        # ... and on the matrix road a filter's result row, as wide as the
+        # staged one: the smallest multiple of 128 B that holds the
+        # launch's widest fitting value (PR 47), plus the meta column
+        sizes = [
+            len(r.value or b"")
+            for req in reqs for item in req.items
+            for batch in item.batches for r in batch.records()
+        ]
+        fitted = 128 * max(-(-max((s for s in sizes if s <= _STRIDE), default=1) // 128), 1)
+        assert stats_off.get("bytes_d2h", 0) == (n_pad and n_pad * (fitted + 8))
+        assert stats_off.get("bytes_h2d", 0) == stats_off.get("bytes_d2h", 0)
         if use_native and n_pad:
             assert "t_explode_ptrs" in stats_on  # framed from the pointer table
     else:

@@ -24,7 +24,7 @@ import pytest
 
 from redpanda_tpu.coproc import EnableResponseCode, ProcessBatchRequest, TpuEngine
 from redpanda_tpu.coproc.column_plan import PayloadPlan, plan_spec
-from redpanda_tpu.coproc.engine import ProcessBatchItem
+from redpanda_tpu.coproc.engine import ProcessBatchItem, _bucket_rows
 from redpanda_tpu.models import NTP, Record, RecordBatch
 from redpanda_tpu.ops import transforms as T
 from redpanda_tpu.ops.exprs import field
@@ -347,13 +347,20 @@ def test_the_engine_gives_the_reference_bytes_on_nexmark_events(seed):
     assert stats["n_kept_rows"] == kept and stats["bytes_out"] == 24 * kept
     assert stats["n_device_launches"] == stats["n_launches"] == 1
     assert stats.get("n_fallback_rows", 0) == 0 and stats["n_frame_padded"] == 1
-    # the matrix itself against what it holds: rows x stride, and the bytes
-    # of the values that fit the row
-    assert stats["n_staged_rows"] == 8192
-    assert stats["bytes_staged"] == 8192 * (STRIDE + IN_META) == stats["bytes_h2d"]
-    assert stats["bytes_staged_values"] == sum(
-        len(v) for values in parts for v in values if len(v) <= STRIDE)
-    assert 0.05 < stats["bytes_staged_values"] / stats["bytes_staged"] < 0.2
+    # the matrices themselves against what they hold (PR 47: the Bids, and
+    # what is staged empty, in 136 B rows; the Auctions, the Persons and the
+    # edge values beside them at the stride of the widest that fits), and
+    # the bytes of the values that fit the lane's row
+    sizes = [len(v) for values in parts for v in values]
+    narrow = sum(s <= 128 or s > STRIDE for s in sizes)
+    wide = -(-max(s for s in sizes if s <= STRIDE) // 128) * 128
+    rows = (_bucket_rows(narrow), _bucket_rows(n_in - narrow))
+    assert rows == (4096, 512) and wide == STRIDE and stats["n_split_launches"] == 1
+    assert stats["n_staged_rows"] == sum(rows)
+    assert stats["bytes_staged"] == stats["bytes_h2d"] == (
+        rows[0] * (128 + IN_META) + rows[1] * (wide + IN_META))
+    assert stats["bytes_staged_values"] == sum(s for s in sizes if s <= STRIDE)
+    assert 0.4 < stats["bytes_staged_values"] / stats["bytes_staged"] < 0.7
 
 
 def test_the_staging_counters_reach_the_metrics_page():
@@ -371,7 +378,7 @@ def test_the_staging_counters_reach_the_metrics_page():
         stats = engine.stats()
     finally:
         engine.shutdown()
-    assert stats["bytes_staged"] == 128 * (STRIDE + IN_META)
+    assert stats["bytes_staged"] == 128 * (128 + IN_META)  # a row fitted to the Bids
     assert stats["bytes_staged_values"] == len(values[0]) + len(values[3])  # the ones that fit
     assert probes.coproc_staged_bytes.value - before[0] == stats["bytes_staged"]
     assert probes.coproc_staged_value_bytes.value - before[1] == stats["bytes_staged_values"]

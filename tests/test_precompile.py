@@ -1,7 +1,7 @@
 """Device programs are built before a script serves (PR 45, ROADMAP B-I.1).
 
 A payload script's programs, one a row bucket, are lowered and compiled at
-deploy on a thread of their own (``TpuEngine._build_ladder``), up to the
+deploy on the engine's builder thread (``TpuEngine._precompile_loop``), up to the
 largest bucket the governor's read budget can hand one launch. A launch then
 runs a ready program: at every bucket of the ladder no first run is left on
 the serving path, a launch over the top (or one that arrives while the ladder
@@ -332,6 +332,234 @@ def test_a_second_script_of_one_spec_builds_nothing():
         ) == [EnableResponseCode.success]
         _wait_ladder(engine, 4)
         assert engine.stats()["n_precompiles"] == built + 4
+    finally:
+        engine.shutdown()
+
+
+# ------------------------------------------------------------------ strides a launch shows (PR 47)
+def _narrow_documents(n: int) -> list:
+    """JSON documents under 128 B (the configurations' own are ~1 KB):
+    a launch of them shows the 128-byte stride."""
+    return [b'{"level":"%s","code":%d,"msg":"narrow %d"}'
+            % ((b"error", b"warn", b"info")[i % 3], i, i) for i in range(n)]
+
+
+def _wait_strides(engine: TpuEngine, script_id: int, strides: set, timeout_s: float = 120.0) -> dict:
+    t_end = time.monotonic() + timeout_s
+    while time.monotonic() < t_end:
+        shown = engine.stats()["programs_ready"][script_id].get("strides", {})
+        if strides <= set(shown) and all(shown[s]["state"] != "building" for s in strides):
+            return shown
+        time.sleep(0.02)
+    raise AssertionError(f"the ladders of {strides} did not finish: {shown}")
+
+
+@pytest.mark.parametrize("road", sorted(CONFIGS))
+def test_a_stride_first_seen_in_serving_builds_off_the_serving_path(road, monkeypatch):
+    """The first launch of narrow values finds no program 128 B wide: it
+    goes at the lane's own stride, which is built, and the bucket it went
+    without is built 128 B wide on ``rptpu-precompile`` (that bucket and no
+    other: a narrower stride's ladder holds what launches asked for); the
+    next launch runs it. No launch is a first run: every program's record
+    reads ``t_first_run_s`` 0.0."""
+    config = CONFIGS[road]
+    reference = _reference(config)
+    built = []  # (staged shape, the thread that built its program), in order
+    real = engine_mod.lower_packed_pipeline
+
+    def spy(fn, shape):
+        built.append((shape, threading.current_thread().name))
+        return real(fn, shape)
+
+    monkeypatch.setattr(engine_mod, "lower_packed_pipeline", spy)
+    engine = _engine(256)
+    try:
+        assert engine.enable_coprocessors(
+            [(1, _spec(config), ("bench",))], partitions={"bench": 1}
+        ) == [EnableResponseCode.success]
+        assert _wait_ladder(engine) == {"buckets": [128, 256], "top": 256, "state": "ready"}
+        values = _narrow_documents(200)
+        want = [o for o in map(reference, values) if o is not None]
+        assert 0 < len(want) < 200
+        got, _ = _launch(engine, values)
+        first = engine.stats()
+        assert got == want
+        assert first["bytes_h2d"] == 256 * (STRIDE + IN_META)  # served at a ready stride
+        assert "n_compiles" not in first and "n_launch_cuts" not in first
+        lane = engine._lanes[1]
+        assert sorted(lane.fns) == [128, STRIDE]
+        assert engine._ladders[lane.fns[128][0]].top == 256
+        shown = _wait_strides(engine, 1, {128})
+        assert shown == {128: {"buckets": [256], "state": "ready"}}
+        # all on the builder thread
+        assert built == [
+            ((128, STRIDE + IN_META), "rptpu-precompile"),
+            ((256, STRIDE + IN_META), "rptpu-precompile"),
+            ((256, 128 + IN_META), "rptpu-precompile"),
+        ]
+        assert engine.stats()["n_precompiles"] == 3
+        got, _ = _launch(engine, values)
+        second = engine.stats()
+        assert got == want
+        assert second["bytes_h2d"] - first["bytes_h2d"] == 256 * (128 + IN_META)
+        assert "n_compiles" not in second and "t_compile" not in second
+        assert second["n_device_launches"] == 2 and second.get("n_fallback_rows", 0) == 0
+        met = {(c["stride"], c["n_pad"]): c for c in second["compiled_programs"]
+               if "t_precompile_s" not in c}
+        assert set(met) == {(STRIDE, 256), (128, 256)}
+        assert all(c["t_first_run_s"] == 0.0 for c in second["compiled_programs"])
+        # smaller launches later ask for another bucket of the stride. One
+        # launch is no reason to build it (a step of the launch knob's
+        # ramp); launches that go on asking are: the builder, which had
+        # ended, takes it up, and until then they go wider
+        fewer = values[:90]
+        want = [o for o in map(reference, fewer) if o is not None]
+        monkeypatch.setattr(engine_mod, "_WANT_HOLD_S", 0.4)
+        assert _launch(engine, fewer)[0] == want
+        time.sleep(0.1)
+        assert engine.stats()["programs_ready"][1]["strides"] == {
+            128: {"buckets": [256], "state": "ready"}}
+        time.sleep(0.2)
+        assert _launch(engine, fewer)[0] == want  # asked for 0.3 s: not yet
+        time.sleep(0.2)
+        assert _launch(engine, fewer)[0] == want  # 0.5 s: wanted now
+        third = engine.stats()
+        assert third["bytes_h2d"] - second["bytes_h2d"] == 3 * 128 * (STRIDE + IN_META)
+        assert _wait_strides(engine, 1, {128}) == {128: {"buckets": [128, 256], "state": "ready"}}
+        assert _launch(engine, fewer)[0] == want
+        fourth = engine.stats()
+        assert fourth["bytes_h2d"] - third["bytes_h2d"] == 128 * (128 + IN_META)
+        assert "n_compiles" not in fourth and fourth["n_precompiles"] == 4
+    finally:
+        engine.shutdown()
+
+
+def test_a_second_deploy_of_the_spec_starts_the_remembered_strides_ladders(monkeypatch):
+    """The strides a spec's launches have shown outlive its scripts: a
+    later deploy of the spec finds their ladders at deploy (and raises
+    them with its own top), so its first narrow launch is staged narrow."""
+    reference = _reference(MATRIX)
+    monkeypatch.setattr(engine_mod, "_WANT_HOLD_S", 0.0)  # the first asking is enough
+    engine = _engine(256)
+    try:
+        assert engine.enable_coprocessors(
+            [(1, _spec(MATRIX), ("bench",))], partitions={"bench": 1}
+        ) == [EnableResponseCode.success]
+        _wait_ladder(engine)
+        values = _narrow_documents(100)
+        want = [o for o in map(reference, values) if o is not None]
+        assert _launch(engine, values)[0] == want
+        # the launch's own bucket, and the one its mix fills at the top
+        assert _wait_strides(engine, 1, {128}) == {
+            128: {"buckets": [128, 256], "state": "ready"}}
+        built = engine.stats()["n_precompiles"]
+        assert built == 4
+        engine.disable_coprocessors([1])
+        assert engine.stats()["programs_ready"] == {}
+        # the same spec again: both ladders stand, nothing is built
+        assert engine.enable_coprocessors(
+            [(2, _spec(MATRIX), ("bench",))], partitions={"bench": 1}
+        ) == [EnableResponseCode.success]
+        ready = engine.stats()["programs_ready"][2]
+        assert ready == {"buckets": [128, 256], "top": 256, "state": "ready",
+                         "strides": {128: {"buckets": [128, 256], "state": "ready"}}}
+        before = engine.stats()
+        assert _launch(engine, values, script_id=2)[0] == want
+        after = engine.stats()
+        assert after["bytes_h2d"] - before["bytes_h2d"] == 128 * (128 + IN_META)
+        assert after["n_precompiles"] == built and "n_compiles" not in after
+        # over more partitions: the lane's own ladder is raised at the
+        # deploy; the remembered stride's follows it in rows and goes on
+        # holding what launches have asked for, no more
+        assert engine.enable_coprocessors(
+            [(3, _spec(MATRIX), ("wide",))], partitions={"wide": 4}
+        ) == [EnableResponseCode.success]
+        assert _wait_ladder(engine, 3)["buckets"] == [128, 256, 512, 1024]
+        assert _wait_strides(engine, 3, {128})[128]["buckets"] == [128, 256]
+        assert engine.stats()["n_precompiles"] == built + 2
+        assert engine._ladders[engine._lanes[3].fns[128][0]].top == 1024
+        # another engine has shown nothing yet
+        other = _engine(256)
+        try:
+            other.enable_coprocessors([(1, _spec(MATRIX), ("bench",))], partitions={"bench": 1})
+            assert "strides" not in _wait_ladder(other)
+        finally:
+            other.shutdown()
+    finally:
+        engine.shutdown()
+
+
+def _padded_documents(n: int, size: int) -> list:
+    """JSON documents of exactly ``size`` bytes."""
+    out = []
+    for i in range(n):
+        head = b'{"level":"%s","code":%d,"msg":"m%d","pad":"' % (
+            (b"error", b"warn", b"info")[i % 3], i, i)
+        out.append(head + b"x" * (size - len(head) - 2) + b'"}')
+    return out
+
+
+@pytest.mark.parametrize("size,stride,ahead", [
+    (100, 128, 1024),   # as dense as the top is sized for: the top itself
+    (600, 640, 256),    # 4.7 times wider: the read budget holds 218 of them
+    (1000, 1024, None),  # the lane's own stride: its ladder stands, nothing to ask
+])
+def test_the_bucket_built_ahead_is_the_one_the_read_budget_fills(size, stride, ahead):
+    """A launch of 100 values, a step of the launch knob's ramp, asks at
+    once for the program its stream will run when the read budget hands a
+    launch all it can: the ladders' top in rows where the values are an
+    eighth of the lane's stride wide, as many fewer rows as they are wider.
+    Its own bucket (128) is not built for one asking."""
+    reference = _reference(MATRIX)
+    engine = _engine(1024)
+    try:
+        assert engine.enable_coprocessors(
+            [(1, _spec(MATRIX), ("bench",))], partitions={"bench": 1}
+        ) == [EnableResponseCode.success]
+        assert _wait_ladder(engine)["buckets"] == [128, 256, 512, 1024]
+        values = _padded_documents(100, size)
+        assert {len(v) for v in values} == {size}
+        want = [o for o in map(reference, values) if o is not None]
+        assert _launch(engine, values)[0] == want and want
+        assert engine.stats()["bytes_h2d"] == 128 * (STRIDE + IN_META)
+        if ahead is None:
+            assert "strides" not in _wait_ladder(engine)
+            assert sorted(engine._lanes[1].fns) == [STRIDE]
+        else:
+            assert _wait_strides(engine, 1, {stride}) == {
+                stride: {"buckets": [ahead], "state": "ready"}}
+        assert engine.stats()["n_precompiles"] == 4 + (ahead is not None)
+    finally:
+        engine.shutdown()
+
+
+def test_what_a_narrower_strides_launches_wanted_is_built_before_the_lanes_ladder_goes_on(
+    monkeypatch,
+):
+    """A stream shows its stride while the lane's own ladder is still
+    building: the one program it wants comes next, and the lane's ladder
+    goes on after it."""
+    built = []
+    real = engine_mod.lower_packed_pipeline
+
+    def slow(fn, shape):
+        built.append(shape)
+        time.sleep(0.5 if shape[0] >= 512 else 0.0)
+        return real(fn, shape)
+
+    monkeypatch.setattr(engine_mod, "lower_packed_pipeline", slow)
+    engine = _engine(2048)
+    try:
+        assert engine.enable_coprocessors(
+            [(1, _spec(MATRIX), ("bench",))], partitions={"bench": 1}
+        ) == [EnableResponseCode.success]
+        assert engine.await_programs(1, n=2)
+        _launch(engine, _narrow_documents(100))  # while (512, 1032) builds
+        assert _wait_ladder(engine)["buckets"] == [128, 256, 512, 1024, 2048]
+        _wait_strides(engine, 1, {128})
+        own = STRIDE + IN_META
+        assert built == [(128, own), (256, own), (512, own), (2048, 128 + IN_META),
+                         (1024, own), (2048, own)]
     finally:
         engine.shutdown()
 
