@@ -489,6 +489,71 @@ def main() -> int:
         for t in threads:
             t.join()
         check("frame_internal_many from four threads at once", results == [True] * 4)
+
+        # ---- a scanning read's walk over a window's frames, a window a
+        # call, against the frames above and Segment.scan's rules spelt out
+        if hasattr(dll, "rp_scan_internal_frames"):
+            dll.rp_scan_internal_frames.restype = ctypes.c_int32
+            dll.rp_scan_internal_frames.argtypes = [
+                ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_int64, ctypes.c_uint64, ctypes.c_uint64,
+                ctypes.c_void_p, ctypes.c_int64,
+            ]
+            _, window, hcrcs = py_frames(heads, 100)
+            known = (1 << 20) - 2  # types 1..19
+            no_max = 2**63 - 1
+
+            def scan(win, at=0, start=0, hi=no_max, budget=1 << 30, mask=known, cap=16):
+                table = (ctypes.c_int64 * (4 + 14 * cap))()
+                status = dll.rp_scan_internal_frames(
+                    win, len(win), at, start, hi, budget, known, mask, table, cap)
+                n = table[0]
+                return status, list(table[1:4]), [list(table[4 + 14 * r: 18 + 14 * r]) for r in range(n)]
+
+            def py_rows(keep):
+                rows, at, nxt = [], 0, 100
+                for b, h in enumerate(heads):
+                    if b in keep:
+                        rows.append([at, hcrcs[b], h[1], nxt, h[3], h[4] & 0xFFFFFFFF] + h[5:])
+                    at += h[1]
+                    nxt += h[6] + 1
+                return rows
+
+            sizes = [h[1] for h in heads]
+            ends = [sum(sizes[: b + 1]) for b in range(nb)]
+            bases = [r[3] for r in py_rows(range(nb))]
+            checks = {
+                "every frame kept, the window ends": scan(window)
+                == (1, [ends[-1], ends[-1], ends[-1]], py_rows(range(nb))),
+                "start_offset inside a batch keeps it": scan(window, start=bases[3] + 5)
+                == (1, [ends[-1], ends[-1], sizes[3] + sizes[4]], py_rows((3, 4))),
+                "max_offset: the frame past it is not consumed": scan(window, hi=bases[3] - 1)
+                == (0, [ends[2], ends[2], ends[2]], py_rows((0, 1, 2))),
+                "a filtered type after the last kept frame is consumed, not covered":
+                scan(window[: ends[2]], mask=1 << 1)
+                == (1, [ends[2], ends[1], ends[1]], py_rows((0, 1))),
+                "the budget ends the walk once taken": scan(window, budget=sizes[0] + 1)
+                == (0, [ends[1], ends[1], ends[1]], py_rows((0, 1))),
+                "a window that ends inside a header": scan(window[: ends[1] + 60])
+                == (1, [ends[1], ends[1], ends[1]], py_rows((0, 1))),
+                "a window that ends inside a payload": scan(window[: ends[2] - 1])
+                == (1, [ends[1], ends[1], ends[1]], py_rows((0, 1))),
+                "a full table": scan(window, cap=2)
+                == (3, [ends[1], ends[1], ends[1]], py_rows((0, 1))),
+                "from a position on": scan(window, at=ends[1])[2] == py_rows((2, 3, 4)),
+            }
+            for byte in (0, 9, 16, 30, 60):  # header_crc, base offset, type, a timestamp, the count
+                torn = bytearray(window)
+                torn[ends[0] + byte] ^= 0x40
+                checks[f"header byte {byte} flipped: not sound"] = scan(bytes(torn)) == (
+                    2, [ends[0], ends[0], ends[0]], py_rows((0,)))
+            torn = bytearray(window)
+            torn[ends[0] + 4: ends[0] + 8] = struct.pack("<i", 60)
+            checks["size_bytes under 61: not sound"] = scan(bytes(torn))[0] == 2
+            for name, ok in checks.items():
+                check("scan_internal_frames: " + name, ok)
+        else:
+            check("rp_scan_internal_frames symbol present", False)
     else:
         check("rp_frame_internal_many symbol present", False)
 

@@ -717,6 +717,87 @@ int64_t rp_frame_internal_many(const uint8_t* heads,
   return pos;
 }
 
+// ---------------------------------------------------------------- scan
+static inline int64_t get_le_signed(const uint8_t* p, int width) {
+  uint64_t v = 0;
+  for (int i = 0; i < width; i++) v |= (uint64_t)p[i] << (8 * i);
+  if (width < 8 && (v >> (8 * width - 1)) & 1) v |= ~(uint64_t)0 << (8 * width);
+  return (int64_t)v;
+}
+
+// A scanning read's walk over the internal frames of a window of a segment
+// file, one crossing a window (storage/segment.py Segment.scan): what
+// _FrameReader + RecordBatch.decode_internal + scan's three rules do a
+// frame. From win + at on, frame after frame: a frame is taken only whole
+// (its 61-byte header and its size_bytes inside win_len) and sound
+// (size_bytes >= 61, a type in known_types, header_crc == CRC-32C of header
+// bytes 4..61), asked in the reader's order; then scan's rules in scan's
+// order: a base offset past max_offset ends the walk with the frame NOT
+// consumed; a last offset under start_offset, or a type outside type_mask,
+// consumes the frame and keeps nothing; a kept frame writes one row (its
+// position in win, then the thirteen header fields, header_crc, crc and
+// attrs unsigned) and counts its size_bytes against budget, which ends the
+// walk once taken.
+// table: int64[4 + 14 * rows_cap]; table[0] rows written, table[1] the
+// position the walk stopped at (every frame before it consumed), table[2]
+// the position just past the last kept frame (-1: none kept), table[3] the
+// bytes kept; rows from table[4] on. Returns why the walk stopped: 0 done
+// (budget or max_offset), 1 the window ends inside the frame at table[1]
+// (or holds no more), 2 that frame is not sound (the caller decodes it the
+// slow way for the error), 3 rows_cap rows are written.
+int32_t rp_scan_internal_frames(const uint8_t* win, int64_t win_len,
+                                int64_t at, int64_t start_offset,
+                                int64_t max_offset, int64_t budget,
+                                uint64_t known_types, uint64_t type_mask,
+                                int64_t* table, int64_t rows_cap) {
+  // the fields after size_bytes: base offset, type, crc, attrs, last offset
+  // delta, the two timestamps, producer id / epoch, base sequence, records
+  static const int kWidths[11] = {8, 1, 4, 2, 4, 8, 8, 8, 2, 4, 4};
+  int64_t n = 0, kept_end = -1, taken = 0;
+  int32_t status;
+  for (;;) {
+    if (win_len - at < 61) { status = 1; break; }
+    const uint8_t* h = win + at;
+    int64_t size = (int64_t)(int32_t)get_le32(h + 4);
+    if (size >= 61 && win_len - at < size) { status = 1; break; }
+    int type = (int)(int8_t)h[16];
+    uint32_t header_crc = get_le32(h);
+    if (size < 61 || type < 0 || type > 63 || !(known_types >> type & 1) ||
+        rp_crc32c(h + 4, 57) != header_crc) {
+      status = 2;
+      break;
+    }
+    int64_t base = get_le_signed(h + 8, 8);
+    int64_t last = (int64_t)((uint64_t)base + (uint64_t)get_le_signed(h + 23, 4));
+    if (base > max_offset) { status = 0; break; }
+    if (last < start_offset || !(type_mask >> type & 1)) {
+      at += size;
+      continue;
+    }
+    if (n == rows_cap) { status = 3; break; }
+    int64_t* row = table + 4 + 14 * n++;
+    row[0] = at;
+    row[1] = (int64_t)header_crc;
+    row[2] = size;
+    const uint8_t* f = h + 8;
+    for (int i = 0; i < 11; i++) {
+      row[3 + i] = get_le_signed(f, kWidths[i]);
+      f += kWidths[i];
+    }
+    row[5] &= 0xFFFFFFFFll;  // crc and attrs: unsigned, as
+    row[6] &= 0xFFFFll;      // RecordBatchHeader.decode gives them
+    at += size;
+    kept_end = at;
+    taken += size;
+    if (taken >= budget) { status = 0; break; }
+  }
+  table[0] = n;
+  table[1] = at;
+  table[2] = kept_end;
+  table[3] = taken;
+  return status;
+}
+
 // Build a records payload from kept transform outputs: record i (where
 // keep[i] != 0) becomes {attrs=0, ts_delta=0, offset_delta=seq, key=null,
 // value=rows[i][:lens[i]], headers=0}. Writes payload to dst (caller sizes

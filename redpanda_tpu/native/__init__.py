@@ -12,6 +12,7 @@ import ctypes
 import fcntl
 import logging
 import os
+import struct
 import subprocess
 import typing
 
@@ -80,6 +81,12 @@ def _check_gather_cols(src_arr, offsets, lens, n: int) -> None:
         or int((offsets + np.maximum(lens, 0)).max()) > src_arr.nbytes
     ):
         raise ValueError("gather (offset, len) span outside the source blob")
+
+
+# rows a scan crossing's table holds (a 256 KiB window is 8-13 frames),
+# and a row: a frame's position, then its thirteen header fields
+_SCAN_ROWS = 64
+_SCAN_ROW = struct.Struct("<14q")
 
 
 class SrcTable(typing.NamedTuple):
@@ -319,6 +326,20 @@ class _NativeLib:
                 ctypes.c_int64, ctypes.c_void_p,
             ]
             self._frame_internal_many = fn
+        # a scanning read's walk over a window's frames, a window a
+        # crossing; PyDLL for the same reason: ~2 us of header CRCs on the
+        # loop's thread (PERF.md section 5, step 0 of ISSUE 48)
+        self.has_scan_internal_frames = hasattr(dll, "rp_scan_internal_frames")
+        if self.has_scan_internal_frames:
+            fn = ctypes.PyDLL(dll._name).rp_scan_internal_frames
+            fn.restype = ctypes.c_int32
+            fn.argtypes = [
+                ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+                ctypes.c_uint64, ctypes.c_uint64, ctypes.c_void_p,
+                ctypes.c_int64,
+            ]
+            self._scan_internal_frames = fn
         dll.rp_json_find.restype = ctypes.c_int32
         dll.rp_json_find.argtypes = [
             ctypes.c_char_p, ctypes.c_int64, ctypes.c_char_p, ctypes.c_int32,
@@ -864,6 +885,36 @@ class _NativeLib:
             raise ValueError("frames do not fit nbytes, or a size_bytes under 61")
         del frames[written:]
         return frames, header_crcs[:]
+
+    def scan_internal_frames(
+        self, window: bytes, at: int, start_offset: int, max_offset: int,
+        budget: int, known_types: int, type_mask: int,
+    ):
+        """A scanning read's walk over the internal frames of ``window``
+        from position ``at`` on, in ONE crossing (rp_scan_internal_frames):
+        every whole, sound frame (``size_bytes`` >= 61, a type whose bit
+        ``known_types`` has, the header CRC its header's) is held to
+        ``Segment.scan``'s rules in their order: a base offset over
+        ``max_offset`` ends the walk, the frame not consumed; a last offset
+        under ``start_offset`` or a type whose bit ``type_mask`` lacks is
+        passed over; a kept frame counts its ``size_bytes`` against
+        ``budget``, which ends the walk once taken. Returns ``(status,
+        stopped_at, kept_end, taken, rows)``: ``status`` 0 done, 1 the
+        window ends inside the frame at ``stopped_at`` (or holds no more),
+        2 that frame is not sound, 3 the table is full (call again from
+        ``stopped_at``); ``kept_end`` the position just past the last kept
+        frame, -1 if none; ``rows`` one tuple a kept frame: its position,
+        then ``RecordBatchHeader``'s thirteen packed fields in their order
+        (``type`` the plain number)."""
+        table = (ctypes.c_int64 * (4 + 14 * _SCAN_ROWS))()
+        status = self._scan_internal_frames(
+            window, len(window), at, start_offset, max_offset, budget,
+            known_types, type_mask, table, _SCAN_ROWS,
+        )
+        rows = _SCAN_ROW.iter_unpack(
+            memoryview(table).cast("B")[32 : 32 + _SCAN_ROW.size * table[0]]
+        )
+        return status, table[1], table[2], table[3], rows
 
     def explode_find(
         self,

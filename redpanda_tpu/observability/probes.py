@@ -32,6 +32,14 @@ storage_append_crossing_batches_hist = registry.histogram(
     "storage_append_crossing_batches",
     "Batches framed into the log by one framing call of an append",
 )
+# The read side's twin: one sample per native crossing of a scanning read
+# (Segment.scan) that kept batches, holding how many it decoded; 1 for each
+# batch the per-frame loop decoded (a library without the crossing). A read
+# the batch cache serves makes no scan and no sample. Batches, not us.
+storage_read_crossing_batches_hist = registry.histogram(
+    "storage_read_crossing_batches",
+    "Batches decoded by one native crossing of a scanning read",
+)
 storage_read_hist = registry.histogram(
     "storage_read_latency_us",
     "Storage log read latency, lock wait included (us)",
@@ -626,5 +634,6 @@ __all__ = [
     "storage_append_hist",
     "storage_flush_hist",
     "storage_housekeeping_hist",
+    "storage_read_crossing_batches_hist",
     "storage_read_hist",
 ]

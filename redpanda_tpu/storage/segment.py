@@ -17,12 +17,15 @@ import os
 import struct
 from dataclasses import dataclass
 
+from redpanda_tpu import native
 from redpanda_tpu.models.record import (
     INTERNAL_HEADER_SIZE,
     CorruptBatchError,
     RecordBatch,
     RecordBatchHeader,
+    RecordBatchType,
 )
+from redpanda_tpu.observability.probes import storage_read_crossing_batches_hist
 from redpanda_tpu.storage import file_sanitizer
 from redpanda_tpu.storage.readers_cache import ReadCursor
 
@@ -35,6 +38,11 @@ READ_AHEAD_BYTES = 256 * 1024
 _INDEX_ENTRY = struct.Struct("<IQq")  # rel_offset u32, file_pos u64, ts i64
 _INDEX_MAGIC = b"RPXI\x02"
 _INDEX_FOOTER = struct.Struct("<qq")  # dirty_offset i64, max_timestamp i64
+# what the scan crossing is told of batch types: the enum's members by
+# number, as a table and as the bits of a mask (a type is one signed byte)
+_TYPE_OF = {int(t): t for t in RecordBatchType}
+_KNOWN_TYPES = sum(1 << t for t in _TYPE_OF)
+_NO_MAX_OFFSET = (1 << 63) - 1
 
 
 @dataclass
@@ -279,6 +287,10 @@ class Segment:
         Frames consumed but filtered out AFTER the last kept batch are
         deliberately not covered by the cursor, so a continuation under a
         different type_filter re-scans them instead of silently skipping.
+
+        The frames are walked a window a native crossing where the library
+        has the entry (`_walk_windows`), else a frame at a time
+        (`_walk_frames`): one result either way.
         """
         if read_ahead and max_bytes < READ_AHEAD_BYTES:
             chunk = READ_AHEAD_BYTES
@@ -292,24 +304,13 @@ class Segment:
             pos = self.index.lookup(start_offset)
             frames = _FrameReader(self, pos, chunk)
         out: list[RecordBatch] = []
-        taken = 0
-        kept_end = pos  # file offset just past the last KEPT batch
-        for batch, end_pos in frames:
-            if max_offset is not None and batch.base_offset > max_offset:
-                break  # NOT consumed: cursor stays before this frame
-            if batch.last_offset < start_offset:
-                continue
-            if type_filter is not None and batch.header.type not in type_filter:
-                continue
-            # Runtime term context comes from the segment (the packed
-            # header carries no term; the reference derives it the same
-            # way, from the raft configuration tracking / segment naming)
-            batch.header.term = self.term
-            out.append(batch)
-            kept_end = end_pos
-            taken += batch.size_bytes
-            if taken >= max_bytes:
-                break
+        lib = native.lib
+        if lib is not None and lib.has_scan_internal_frames:
+            walk = self._walk_windows
+        else:
+            walk = self._walk_frames
+        # file offset just past the last KEPT batch
+        kept_end = walk(frames, out, pos, start_offset, max_bytes, type_filter, max_offset)
         # the unread rest travels on, unless the window moved past kept_end
         # (filtered frames) or a large request left more than a reader's own
         window, window_pos = frames.buf, frames.base
@@ -323,6 +324,92 @@ class Segment:
             ReadCursor(self.base_offset, kept_end, window, window_pos),
             frames.file_reads,
         )
+
+    def _walk_frames(
+        self, frames, out, kept_end, start_offset, max_bytes, type_filter, max_offset
+    ) -> int:
+        """`scan`'s walk a frame at a time: the batches it keeps go to
+        `out`; returns the file position just past the last of them, or
+        `kept_end` as given. Serves where the native library lacks the
+        crossing of `_walk_windows`."""
+        taken = 0
+        for batch, end_pos in frames:
+            if max_offset is not None and batch.base_offset > max_offset:
+                break  # NOT consumed: cursor stays before this frame
+            if batch.last_offset < start_offset:
+                continue
+            if type_filter is not None and batch.header.type not in type_filter:
+                continue
+            # Runtime term context comes from the segment (the packed
+            # header carries no term; the reference derives it the same
+            # way, from the raft configuration tracking / segment naming)
+            batch.header.term = self.term
+            out.append(batch)
+            storage_read_crossing_batches_hist.record(1)
+            kept_end = end_pos
+            taken += batch.size_bytes
+            if taken >= max_bytes:
+                break
+        return kept_end
+
+    def _walk_windows(
+        self, frames, out, kept_end, start_offset, max_bytes, type_filter, max_offset
+    ) -> int:
+        """`_walk_frames` in ONE native crossing a window
+        (`native.scan_internal_frames`) where that takes a decode, a header
+        CRC and three rules a frame in Python: the crossing checks every
+        whole frame of the window (size, type, header CRC) and holds it to
+        the same three rules in the same order, and the batches are made
+        here from the rows it filled, the payload one slice a batch as
+        `decode_internal` takes it. Whatever is not a whole sound frame is
+        the reader's: a window that ends inside a frame is read anew
+        (`whole_frame`) or raises as it does there, and a frame that is not
+        sound is decoded the loop's way for its error. The same batches,
+        cursor, file reads and exceptions; a one-frame read is cheaper this
+        way too (PERF.md section 5, step 0 of ISSUE 48), so no frame count
+        picks the road."""
+        if type_filter is None:
+            mask = _KNOWN_TYPES
+        else:
+            mask = sum(1 << t for t in type_filter if t in _TYPE_OF)
+        if max_offset is None:
+            max_offset = _NO_MAX_OFFSET
+        lib = native.lib
+        term = self.term
+        taken = 0
+        while True:
+            buf = frames.buf
+            status, frames.at, end, took, rows = lib.scan_internal_frames(
+                buf, frames.at, start_offset, max_offset, max_bytes - taken,
+                _KNOWN_TYPES, mask,
+            )
+            if end >= 0:
+                n = len(out)
+                for (at, header_crc, size, base, btype, crc, attrs, last_delta,
+                     first_ts, max_ts, pid, epoch, seq, count) in rows:
+                    out.append(
+                        RecordBatch(
+                            RecordBatchHeader(
+                                header_crc, size, base, _TYPE_OF[btype], crc,
+                                attrs, last_delta, first_ts, max_ts, pid,
+                                epoch, seq, count, term,
+                            ),
+                            buf[at + INTERNAL_HEADER_SIZE : at + size],
+                        )
+                    )
+                storage_read_crossing_batches_hist.record(len(out) - n)
+                kept_end = frames.base + end
+                taken += took
+            if status == 0:
+                return kept_end
+            if status == 1:
+                if not frames.whole_frame():
+                    return kept_end
+            elif status == 2:
+                RecordBatch.decode_internal(buf, frames.at)
+                raise CorruptBatchError(
+                    f"unsound batch frame ({self.data_path} pos {frames.base + frames.at})"
+                )
 
     def first_offset_with_ts(self, ts: int) -> int | None:
         """First batch offset whose max_timestamp >= ts (index-accelerated).
@@ -430,7 +517,9 @@ class _FrameReader:
         self.buf, self.base, self.at = more, pos, 0
         return True
 
-    def __next__(self) -> tuple[RecordBatch, int]:
+    def whole_frame(self) -> bool:
+        """Have the window hold the next frame whole, read anew where it
+        does not; False at the file's end."""
         while True:
             have = len(self.buf) - self.at
             if have < INTERNAL_HEADER_SIZE:
@@ -441,7 +530,7 @@ class _FrameReader:
                         f"partial batch header at EOF ({self.seg.data_path}"
                         f" pos {self.base + self.at})"
                     )
-                raise StopIteration
+                return False
             frame_len = RecordBatch.peek_size(self.buf, self.at)
             if have < frame_len:
                 if self._refill(frame_len):
@@ -450,6 +539,11 @@ class _FrameReader:
                     f"batch frame overruns EOF ({self.seg.data_path} pos "
                     f"{self.base + self.at}, size_bytes={frame_len})"
                 )
-            batch, consumed = RecordBatch.decode_internal(self.buf, self.at)
-            self.at += consumed
-            return batch, self.base + self.at
+            return True
+
+    def __next__(self) -> tuple[RecordBatch, int]:
+        if not self.whole_frame():
+            raise StopIteration
+        batch, consumed = RecordBatch.decode_internal(self.buf, self.at)
+        self.at += consumed
+        return batch, self.base + self.at

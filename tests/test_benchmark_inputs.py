@@ -317,7 +317,7 @@ def test_split_launch_share_is_read_by_the_stats_ratio_reader_and_listed(stats, 
     entry = listed["split_launch_share"]
     assert entry == {**{k: d[k] for k in ("name", "unit", "better", "source", "layer", "moves")},
                      "workloads": listed["staged_value_share"]["workloads"]}
-    assert bench.manifest()["per_layer"][-1] == entry  # appended, nothing before it moved
+    assert bench.manifest()["per_layer"][87] == entry  # appended as the 88th, nothing before it moved
     before = {"stats": {k: 3.0 for k in stats}, "metrics": {}}
     after = {"stats": {k: 3.0 + v for k, v in stats.items()}, "metrics": {}}
     got = readers.read_all(directory, kind="catchup", before=before, after=after,
@@ -325,3 +325,44 @@ def test_split_launch_share_is_read_by_the_stats_ratio_reader_and_listed(stats, 
     assert got == (None if want is None else {"value": want, "unit": "ratio"})
     assert "split_launch_share" not in readers.read_all(
         directory, kind="paced", before=before, after=after, client={}, trace=None, window_s=40.0)
+
+
+@pytest.mark.parametrize("metrics, want", [
+    ({"_sum": 576.0, "_count": 64.0}, 9.0),    # one crossing a partition read
+    ({"_sum": 576.0, "_count": 96.0}, 6.0),    # every other read's window ran out: two crossings
+    ({"_sum": 576.0, "_count": 576.0}, 1.0),   # the per-frame loop: a sample a batch
+    ({"_sum": 0.0, "_count": 0.0}, None),      # no scan in the window: nothing to read
+    ({}, None),                                # a program without the histogram
+])
+def test_read_batches_per_crossing_is_read_by_the_histogram_reader_and_listed(metrics, want):
+    """PR 48's per-layer metric is data alone:
+    ``layer_metrics/read_batches_per_crossing.json`` is read by the reader the
+    benchmark already had, over the histogram ``storage_read_crossing_batches``,
+    and ``BENCHMARK.json`` lists it for the cells its append-side twin is
+    listed for: the six catch-up cells, and no ``paced.`` twin (a live tick's
+    reads are served by the batch cache and make no scan)."""
+    import readers
+
+    directory = os.path.join(BENCH, "layer_metrics")
+    defs = {d["name"]: d for d in readers.load_definitions(directory)}
+    d, twin = defs["read_batches_per_crossing"], defs["append_batches_per_crossing"]
+    assert "paced.read_batches_per_crossing" not in defs
+    assert d["read"]["kind"] == "histogram_delta" and d["read"]["kind"] in readers.KINDS
+    assert d["read"]["metric"] == "storage_read_crossing_batches"
+    assert {k: d[k] for k in ("layer", "unit", "better", "moves", "traffic", "source")} == {
+        k: twin[k] for k in ("layer", "unit", "better", "moves", "traffic", "source")}
+    listed = {e["name"]: e for e in bench.manifest()["per_layer"]}
+    entry = listed["read_batches_per_crossing"]
+    assert entry == {**{k: d[k] for k in ("name", "unit", "better", "source", "layer", "moves")},
+                     "workloads": listed["append_batches_per_crossing"]["workloads"]}
+    assert len(entry["workloads"]) == 6 and all(w.endswith(".catchup") for w in entry["workloads"])
+    assert bench.manifest()["per_layer"][88] == entry  # appended as the 89th, nothing before it moved
+    series = "storage_read_crossing_batches"
+    before = {"stats": {}, "metrics": {series + k: 5.0 for k in metrics}}
+    after = {"stats": {}, "metrics": {series + k: 5.0 + v for k, v in metrics.items()}}
+    got = readers.read_all(directory, kind="catchup", before=before, after=after,
+                           client={}, trace=None, window_s=40.0).get("read_batches_per_crossing")
+    assert got == (None if want is None else {"value": want, "unit": "batches"})
+    assert "read_batches_per_crossing" not in readers.read_all(
+        directory, kind="paced", before=before, after=after, client={}, trace=None, window_s=40.0)
+
