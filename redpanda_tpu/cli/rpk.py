@@ -495,8 +495,9 @@ async def cmd_debug(args) -> int:
                 )
                 print(
                     f"strides:  script {sid}: rows also staged at {shown}; "
-                    f"{int(stats.get('n_split_launches', 0))} launches staged in two "
-                    f"parts by width class"
+                    f"{int(stats.get('n_split_launches', 0))} launches staged in "
+                    f"parts by width class ({int(stats.get('n_parts', 0))} parts in "
+                    f"all, {int(stats.get('n_wide_rows', 0))} rows over 1,024 B)"
                 )
         if stats.get("n_json_rows"):
             # coproc_json_rows_total{outcome="read|malformed|path_miss"}

@@ -210,6 +210,11 @@ PROPERTIES: list[Property] = [
     Property("coproc_enable", "Enable the TPU transform engine", False, bool),
     Property("coproc_max_batch_size", "Max read per ntp per tick", 32 * 1024, int, _positive),
     Property("coproc_max_inflight_bytes", "Read semaphore budget", 10 * 1024 * 1024, int, _positive),
+    Property(
+        "coproc_max_value_bytes",
+        "The widest value the device (payload) lane transforms: a wider one is dropped, never truncated, and counted (coproc_oversize_rows_total). Values over 1,024 B are staged in width classes of their own (2,048, 4,096, ... up to this limit) as further parts of the same launch",
+        1024, int, _positive,
+    ),
     Property("coproc_offset_flush_interval_ms", "Offset snapshot cadence", 300_000, int, _positive),
     Property(
         "coproc_host_workers",

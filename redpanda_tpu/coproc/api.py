@@ -56,6 +56,8 @@ class CoprocApi:
         # None -> the engine resolves min(4, cores); the property default
         # matches, so an unset config and a default config agree
         self.engine = TpuEngine(
+            # the lane's stated limit: the widest value it stages
+            row_stride=_knob("coproc_max_value_bytes", 1024),
             host_workers=_knob("coproc_host_workers", None),
             gather_frame=_knob("coproc_gather_frame", True),
             device_column_cache_mb=_knob(

@@ -153,6 +153,8 @@ class ProcessBatchReply:
 # launch_depth launches that land together. NOT leakwatch resources: a
 # launch whose device leg failed drops its matrix on purpose
 # (_launch_payload), and an abandoned launch's buffers go with the launch.
+# The staging pool parks this many a part a launch can have (_staging_slots:
+# a launch staged in five parts gives five matrices back at once).
 _STAGING_MAX_PARKED = 4
 # A decompress buffer (and a seal's frame buffer) is asked for in steps of
 # this many bytes, so that a script's launches (45-50 MB of decompressed
@@ -185,10 +187,14 @@ def _bucket_rows(n: int) -> int:
 _LADDER_ROWS_PER_STRIDE = 8
 
 
-# A staged row's width comes in classes of this many bytes: a part's stride
-# is the smallest multiple of it that holds the part's widest value, never
-# over the lane's row_stride (TpuEngine._plan_parts).
+# A staged row's width comes in classes: up to _BODY_STRIDE bytes a class is
+# this many bytes and a part's stride is the smallest multiple of it that
+# holds the part's widest value; above, a class is twice the one below it
+# (2,048, 4,096, ... the lane's limit, ``row_stride``, the widest): a tail of
+# large values is few rows, and classes 128 B apart would give each a
+# matrix and a program of its own (TpuEngine._plan_parts).
 _STRIDE_CLASS = 128
+_BODY_STRIDE = 1024
 # A narrower stride's program is built at once for the row bucket a launch's
 # mix fills when the read budget hands it all it can (_stride_ready), and
 # for a launch's own bucket only once launches have gone on asking for that
@@ -196,11 +202,99 @@ _STRIDE_CLASS = 128
 # so a step of the knob's ramp is not worth a trace beside the serving path
 # and a live stream's steady launches are.
 _WANT_HOLD_S = 2 * governor.AUTOTUNE_HOLD_S
-# A launch whose values fall in two far-apart width classes is staged as
-# two parts (its narrow rows, its wide rows) only where the one matrix
-# fitted to its widest value is at least this many times the two parts'
-# bytes, row buckets and meta columns counted as they are staged.
+# Until then a part rides in the next part up whose program is built, the
+# lane's own stride at worst. Where that stride is a class above
+# _BODY_STRIDE the way up ends at rows many times wider than the part's, so
+# there a part first rides in a ready bucket of its OWN stride of up to this
+# many times its rows, padded to it: the steps of the ramp from a quarter
+# of its end on run the programs built for its end.
+_PAD_MAX = 4
+# A launch's first part (the one that holds its rows up to _BODY_STRIDE
+# wide: the whole launch on a lane whose limit is no wider) is staged as two
+# (its narrow rows, its wide rows) only where the one matrix fitted to its
+# widest value is at least this many times the two parts' bytes, row buckets
+# and meta columns counted as they are staged.
 _SPLIT_MIN_SAVING = 2
+# Above _BODY_STRIDE every class is a part of its own where that leaves at
+# least this many staged bytes fewer than riding in the next part up: what
+# a part's matrix, transfer, program and merge are worth. (Classes that
+# double leave a ratio like _SPLIT_MIN_SAVING's at its edge launch after
+# launch: a power-law tail puts about the same bytes in each.)
+_PART_MIN_SAVING_BYTES = 1 << 20
+
+
+def _class_strides(limit: int) -> np.ndarray:
+    """The staged widths a lane whose values may be ``limit`` bytes wide
+    chooses from, ascending, the last one ``limit`` itself."""
+    body = min(limit, _BODY_STRIDE)
+    out = [min(c * _STRIDE_CLASS, body) for c in range(1, -(-body // _STRIDE_CLASS) + 1)]
+    while out[-1] < limit:
+        out.append(min(out[-1] * 2, limit))
+    return np.array(out, dtype=np.int64)
+
+
+def _staged_bytes(rows: int, stride: int) -> int:
+    return _bucket_rows(rows) * (stride + IN_META)
+
+
+def _plan_cuts(hist: np.ndarray, strides: np.ndarray, split_ok: bool) -> list[int]:
+    """The parts of a launch whose rows fall in the width classes as
+    ``hist`` counts them: the class each part ends at (its stride is that
+    class's), ascending, the last one the widest class that holds a row.
+
+    Above _BODY_STRIDE: the cheapest grouping of the classes into parts,
+    each part charged _PART_MIN_SAVING_BYTES over its matrix (the rows up to
+    _BODY_STRIDE wide enter as one class at their own widest stride, and
+    ride in the first part above where a part of their own would not
+    pay). The first part, which holds those rows, is then one part at its
+    stride, or two where that at least halves its matrix
+    (_SPLIT_MIN_SAVING): the narrow stride up to _BODY_STRIDE that leaves
+    the fewest staged bytes, and the rest at the part's own. Where no row
+    is wider than _BODY_STRIDE that is all there is: PR 47's rule.
+    ``split_ok`` False: one part, fitted."""
+    widest = int(np.flatnonzero(hist)[-1])
+    if not split_ok:
+        return [widest]
+    body = min(int(np.searchsorted(strides, _BODY_STRIDE)), len(strides) - 1)
+    below = np.flatnonzero(hist[: body + 1])
+    cuts = [widest]
+    if widest > body:
+        # the classes above the body, and the body's rows as one item at
+        # their own widest class: cheapest[j] is what items[:j] cost staged
+        # as parts, starts[j] where the last of those parts begins
+        items = [(int(below[-1]), int(hist[: body + 1].sum()))] if len(below) else []
+        items += [(c, int(hist[c])) for c in range(body + 1, widest + 1) if hist[c]]
+        cheapest, starts = [0], [0]
+        for j in range(1, len(items) + 1):
+            stride = int(strides[items[j - 1][0]])
+            cost, rows = None, 0
+            for i in range(j - 1, -1, -1):
+                rows += items[i][1]
+                c = cheapest[i] + _staged_bytes(rows, stride) + _PART_MIN_SAVING_BYTES
+                if cost is None or c < cost:
+                    cost, start = c, i
+            cheapest.append(cost)
+            starts.append(start)
+        cuts, j = [], len(items)
+        while j:
+            cuts.append(items[j - 1][0])
+            j = starts[j]
+        cuts.reverse()
+    # the first part (it holds the rows up to _BODY_STRIDE, where the launch
+    # has any): one stride, or two
+    top = cuts[0]
+    n, wide = int(hist[: top + 1].sum()), int(strides[top])
+    best, narrow = None, 0
+    for c in range(min(top, body + 1)):
+        narrow += int(hist[c])
+        if not narrow:
+            continue
+        staged = _staged_bytes(narrow, int(strides[c])) + _staged_bytes(n - narrow, wide)
+        if best is None or staged < best[0]:
+            best = (staged, c)
+    if best is not None and best[0] * _SPLIT_MIN_SAVING <= _staged_bytes(n, wide):
+        cuts.insert(0, best[1])
+    return cuts
 
 
 def _merge_parts(results: list[np.ndarray], rows: list[np.ndarray] | None):
@@ -241,7 +335,7 @@ class _Part:
 
 class _PartsResult:
     """The device results of a launch that is more than one program run:
-    staged in two parts by width class, or cut to a ready row bucket, or
+    staged in several parts by width class, or cut to a ready row bucket, or
     both. ``parts[i]`` are part i's results in row order (one, or one a
     cut); fetched and merged as one (_Launch._fetch_legs)."""
 
@@ -272,7 +366,7 @@ class _SpecPrograms:
     keeps it by spec for its own life, so a later deploy of the spec finds
     the strides and starts their ladders beside the full-width one."""
 
-    __slots__ = ("spec", "mask_only", "fns", "min_stride", "split_ok", "_lock")
+    __slots__ = ("spec", "mask_only", "fns", "min_stride", "split_ok", "partitions", "_lock")
 
     def __init__(self, spec: TransformSpec, mask_only: bool, row_stride: int):
         self.spec = spec
@@ -288,6 +382,14 @@ class _SpecPrograms:
         fixed = transform_out_width(spec, row_stride + _STRIDE_CLASS) == r_out
         self.min_stride = r_out if fixed else 1
         self.split_ok = mask_only or fixed
+        # the most partitions a script of the spec reads (_ladder_top)
+        self.partitions = 1
+
+    def read_from(self, partitions: int) -> None:
+        """A deploy of the spec over ``partitions`` partitions: the ladders
+        are sized for the script that reads the most."""
+        with self._lock:
+            self.partitions = max(self.partitions, partitions)
 
     def at(self, stride: int) -> tuple:
         """(fn, r_out) at ``stride``."""
@@ -326,11 +428,12 @@ class _Ladder:
     ladders of eleven (each a trace under the interpreter lock, beside the
     serving path: PR 47's cold runs)."""
 
-    def __init__(self, fn, stride: int, top: int, lazy: bool = False):
+    def __init__(self, fn, stride: int, top: int, lazy: bool = False, pad_max: int = 1):
         self.fn = fn
         self.stride = stride  # a staged row's bytes, IN_META included
         self.top = top
         self.lazy = lazy
+        self.pad_max = pad_max  # _padded
         self.programs: dict[int, object] = {}  # n_pad -> jax.stages.Compiled
         self.seconds: dict[int, float] = {}  # n_pad -> its build seconds
         self.failed: str | None = None
@@ -353,16 +456,26 @@ class _Ladder:
         with self.cond:
             return sorted(self.programs)
 
+    def _padded(self, n_pad: int) -> int | None:
+        """The smallest ready bucket a launch padded to ``n_pad`` rows runs
+        whole: its own, or one up to ``pad_max`` times it (a step of the
+        launch knob's ramp rides in the program built ahead for the ramp's
+        end, and pays that many pad rows for it). Caller holds ``cond``."""
+        return min(
+            (b for b in self.programs if n_pad <= b <= n_pad * self.pad_max), default=None
+        )
+
     def program_for(self, n_pad: int, wait_s: float):
         """(program, its row bucket) for a launch padded to ``n_pad`` rows:
-        a bucket below ``n_pad`` means the launch is to be cut to it.
-        (None, n_pad): no ladder to serve from, take the first-run path."""
+        a bucket above ``n_pad`` means the launch is to be padded to it, a
+        bucket below that it is to be cut to it. (None, n_pad): no ladder
+        to serve from, take the first-run path."""
         deadline = time.monotonic() + wait_s
         with self.cond:
             while True:
-                prog = self.programs.get(n_pad)
-                if prog is not None:
-                    return prog, n_pad
+                b = self._padded(n_pad)
+                if b is not None:
+                    return self.programs[b], b
                 if self.failed is not None or self.stopped:
                     return None, n_pad
                 below = [b for b in self.programs if b < n_pad]
@@ -376,9 +489,10 @@ class _Ladder:
 
     def has(self, n_pad: int, want: bool = True, hold_s: float = 0.0) -> bool:
         """Whether a launch padded to ``n_pad`` rows runs a built program
-        as it is (over the top: cut to the top's). ``want``: a bucket that
-        is not there is left for the builder, once launches have asked for
-        it and no other over ``hold_s`` seconds."""
+        whole (over the top: cut to the top's; in a ready bucket of up to
+        ``pad_max`` times its own: padded to it). ``want``: its own bucket,
+        where it is not there, is left for the builder, once launches have
+        asked for it and no other over ``hold_s`` seconds."""
         with self.cond:
             n_pad = min(n_pad, self.top)
             if n_pad in self.programs:
@@ -390,7 +504,7 @@ class _Ladder:
                     self.asked[n_pad] = now
                 if not hold_s or now - self.asked[n_pad] >= hold_s:
                     self.wanted.add(n_pad)
-            return False
+            return self._padded(n_pad) is not None
 
     def missing(self) -> list[int]:
         """The buckets still to build, smallest first: every bucket up to
@@ -535,8 +649,8 @@ class _Launch:
         # without a fetch or a breaker verdict — one mask, one envelope,
         # one verdict, no matter how deep the harvest queue is.
         self._mask_state = "idle"
-        # a payload launch's staging matrices (_Part: one, or two by width
-        # class), retained until the device result lands
+        # a payload launch's staging matrices (_Part: one, or one a width
+        # class the launch is staged in), retained until the device result lands
         self._staged_parts: list[_Part] | None = None
         # the staged matrices ON the device, kept beside the result only so
         # that the fetch can time the H2D apart (_fetch_legs drops them)
@@ -1401,7 +1515,7 @@ class TpuEngine:
         # launches through the arena (reset_arenas() for tests).
         self._gather_frame = bool(gather_frame)
         self._arena = leakwatch.wrap(batch_codec.Arena(), "engine.arena")
-        self._staging = batch_codec.Arena(max_free=_STAGING_MAX_PARKED)
+        self._staging = batch_codec.Arena(max_free=self._staging_slots())
         self._uncompress_pool = batch_codec.Arena(
             max_free=_STAGING_MAX_PARKED, quantum=_UNCOMPRESS_QUANTUM
         )
@@ -1697,11 +1811,11 @@ class TpuEngine:
                     self._pipelines[script_id] = lane.fns[self._row_stride]
                     # the full-width ladder, and one for every narrower
                     # stride an earlier script of the spec has shown
-                    top = self._ladder_top(
-                        sum((partitions or {}).get(t, 1) for t in topics)
-                    )
+                    lane.read_from(sum((partitions or {}).get(t, 1) for t in topics))
                     for stride, (fn, _r) in sorted(lane.fns.items(), reverse=True):
-                        self._start_ladder(fn, stride, top)
+                        self._start_ladder(
+                            fn, stride, self._ladder_top(lane.partitions, stride)
+                        )
                 self._plans[script_id] = plan
             except Exception as exc:
                 # bad spec from the wire, not a broker fault: refuse the
@@ -1716,18 +1830,24 @@ class TpuEngine:
         return out
 
     # ------------------------------------------------------------ ladders
-    def _ladder_top(self, partitions: int) -> int | None:
-        """The largest row bucket a launch of a script over ``partitions``
-        partitions can reach: the bytes the governor's read budget hands
-        one launch (Governor.launch_read_bytes: partitions x a tick's read
-        x the launch knob's cap) at the densest rows the staging row is
-        sized for. None: nothing here sizes a launch (a bare engine with
-        no pacemaker's budget behind it), so no ladder is built and every
+    def _ladder_top(self, partitions: int, stride: int | None = None) -> int | None:
+        """The largest row bucket a part ``stride`` wide (the lane's own by
+        default) can reach in a launch of a script over ``partitions``
+        partitions: the bytes the governor's read budget hands one launch
+        (Governor.launch_read_bytes: partitions x a tick's read x the
+        launch knob's cap) at the densest rows the stride is sized for. Up
+        to _BODY_STRIDE the strides share one top (the lane's own, where
+        its limit is no wider: a launch's rows come with its values, not
+        with the limit); a class above it has as many fewer rows as it is
+        wider. None: nothing here sizes a launch (a bare engine with no
+        pacemaker's budget behind it), so no ladder is built and every
         program is a first run on the serving path, as before."""
         read_bytes = self.governor.launch_read_bytes(partitions)
         if read_bytes is None:
             return None
-        return _bucket_rows(read_bytes * _LADDER_ROWS_PER_STRIDE // self._row_stride)
+        own = self._row_stride
+        dense = max(own if stride is None else stride, min(own, _BODY_STRIDE))
+        return _bucket_rows(read_bytes * _LADDER_ROWS_PER_STRIDE // dense)
 
     def _start_ladder(self, fn, stride: int, top: int | None) -> None:
         """Have ``fn``'s programs (rows ``stride`` wide) built on the
@@ -1744,7 +1864,8 @@ class TpuEngine:
             ladder = self._ladders.get(fn)
             if ladder is None:
                 ladder = self._ladders[fn] = _Ladder(
-                    fn, stride + IN_META, top, lazy=stride != self._row_stride
+                    fn, stride + IN_META, top, lazy=stride != self._row_stride,
+                    pad_max=_PAD_MAX if self._row_stride > _BODY_STRIDE else 1,
                 )
             with ladder.cond:
                 ladder.top = max(ladder.top, top)
@@ -2140,13 +2261,20 @@ class TpuEngine:
         alloc/reuse accounting — and an engine parked after a giant launch
         can use this to return the held buffers to the allocator."""
         self._arena = leakwatch.wrap(batch_codec.Arena(), "engine.arena")
-        self._staging = batch_codec.Arena(max_free=_STAGING_MAX_PARKED)
+        self._staging = batch_codec.Arena(max_free=self._staging_slots())
         self._uncompress_pool = batch_codec.Arena(
             max_free=_STAGING_MAX_PARKED, quantum=_UNCOMPRESS_QUANTUM
         )
         self._seal_pool = batch_codec.Arena(
             max_free=_STAGING_MAX_PARKED, quantum=_UNCOMPRESS_QUANTUM
         )
+
+    def _staging_slots(self) -> int:
+        """The staging pool's slots: _STAGING_MAX_PARKED for the matrices of
+        the strides up to _BODY_STRIDE, as every lane had, and as many again
+        a width class above it (a launch parks a matrix a part)."""
+        wide = int(np.count_nonzero(_class_strides(self._row_stride) > _BODY_STRIDE))
+        return _STAGING_MAX_PARKED * (1 + wide)
 
     def reset_stats(self) -> None:
         with self._stats_lock:
@@ -2174,6 +2302,8 @@ class TpuEngine:
                 probes.coproc_oversize_rows.inc(v)
             elif key == "n_split_launches":
                 probes.coproc_split_launches.inc(v)
+            elif key in probes.coproc_width_classes:
+                probes.coproc_width_classes[key].inc(v)
             elif key == "bytes_staged":
                 probes.coproc_staged_bytes.inc(v)
             elif key == "bytes_staged_values":
@@ -2310,7 +2440,7 @@ class TpuEngine:
         time is ``t_compile``, not a deadline sample; one unmet program
         makes the leg a first run. Every successful dispatch leg is one
         ``n_device_launches``, however many programs it runs (a payload
-        launch staged in two parts runs two). ``fn``: the jitted function
+        launch staged in k parts runs k). ``fn``: the jitted function
         the leg calls, where scripts of one spec share it (the payload
         lane's pipelines are cached by spec and stride; None elsewhere): a
         script whose function another script already ran at this row
@@ -3081,8 +3211,8 @@ class TpuEngine:
         )
         t0 = _stage_t0("t_pack")
         cut = False
-        for part in parts:
-            k = n if part.rows is None else len(part.rows)
+        held = [n if part.rows is None else len(part.rows) for part in parts]
+        for part, k in zip(parts, held):
             part.n_pad = _bucket_rows(k)
             part.program, part.bucket = self._program_for(part.fn, part.n_pad)
             if part.bucket < part.n_pad:
@@ -3091,6 +3221,8 @@ class TpuEngine:
                 # the largest ready one, as many runs as hold its rows
                 part.n_pad = -(-k // part.bucket) * part.bucket
                 cut = True
+            # ... or padded to a larger ready one (_Ladder._padded)
+            part.n_pad = max(part.n_pad, part.bucket)
         # whether each part's matrix was a parked one. The larger matrix is
         # taken first: the pool hands out its smallest parked buffer that
         # is big enough, and the smaller part must not take the larger's
@@ -3100,11 +3232,24 @@ class TpuEngine:
         if all(parked):
             # a launch that paid no first touch of a fresh matrix
             self._stat_add("n_staging_reuses", 1.0)
+        # the parts above _BODY_STRIDE: their rows, and what of their
+        # matrices is value bytes (read before the table may go back)
+        wide = [part for part in parts if part.stride > _BODY_STRIDE]
+        wide_rows = sum(k for part, k in zip(parts, held) if part.stride > _BODY_STRIDE)
+        wide_values = sum(
+            int((exploded.sizes * launch.fits if part.rows is None
+                 else exploded.sizes[part.rows]).sum(dtype=np.int64))
+            for part in wide
+        )
         if not retained:
             _release_exploded(exploded)
-        self._stat_stage("t_pack", t0)
+        self._stat_stage(
+            "t_pack", t0, parts=len(parts),
+            strides=[part.stride for part in parts], rows=held,
+        )
         if cut:
             self._stat_add("n_launch_cuts", 1.0)
+        self._stat_add("n_parts", float(len(parts)))
         if len(parts) > 1:
             self._stat_add("n_split_launches", 1.0)
         # what the staging matrices hold against what they are: record
@@ -3112,73 +3257,66 @@ class TpuEngine:
         # mostly zeros that still cross the link; in a 136 B row it is not)
         self._stat_add("bytes_staged", float(sum(p.staged.nbytes for p in parts)))
         self._stat_add("bytes_staged_values", value_bytes)
+        if wide:
+            self._stat_add("n_wide_rows", float(wide_rows))
+            self._stat_add("bytes_staged_wide", float(sum(p.staged.nbytes for p in wide)))
+            self._stat_add("bytes_staged_values_wide", float(wide_values))
         self._launch_payload(launch, parts)
 
     def _plan_parts(
         self, lane: _SpecPrograms, sizes: np.ndarray, fits: np.ndarray, n: int,
         nbytes: int,
     ) -> list[_Part]:
-        """How a payload launch is staged, read off its own values: ONE
-        part at the stride that fits (the smallest multiple of
-        _STRIDE_CLASS that holds the widest fitting value, never over the
-        lane's row_stride, which stays the limit a value is held to), or
-        TWO where one stride cannot fit: from the histogram of the values
-        over the width classes, the rows up to the narrow stride that
-        leaves the fewest staged bytes and the rest at the widest's, taken
-        only where that is _SPLIT_MIN_SAVING times under the one fitted
-        matrix (row buckets and IN_META counted as staged). A value that
+        """How a payload launch is staged, read off its own values: the
+        histogram of the values over the width classes (_class_strides:
+        128 B apart up to _BODY_STRIDE, doubling from there to the lane's
+        ``row_stride``, which stays the limit a value is held to) and the
+        parts _plan_cuts makes of it: ONE part at the stride that fits
+        where one stride can, TWO (PR 47) where the rows up to
+        _BODY_STRIDE fall in two far-apart classes, and a part a class
+        above it where a tail of wider values pays for them. A value that
         is staged empty (null, empty, oversize) rides in the narrowest
         class. A (stride, row bucket) first seen here is left for
-        ``rptpu-precompile`` to build and the launch goes at the narrowest
-        READY stride that holds it, unsplit (the lane's own at worst):
-        nothing compiles on the serving path for it."""
-        limit = self._row_stride
-        classes = -(-limit // _STRIDE_CLASS)
-        lo = -(-lane.min_stride // _STRIDE_CLASS)
-        cls = np.where(fits, (sizes + (_STRIDE_CLASS - 1)) // _STRIDE_CLASS, 0)
-        np.clip(cls, lo, classes, out=cls)
-        hist = np.bincount(cls, minlength=classes + 1)
-        widest = int(np.flatnonzero(hist)[-1])
-
-        def stride(c: int) -> int:
-            return min(c * _STRIDE_CLASS, limit)
-
-        wide = stride(widest)
-        best = None  # (staged bytes, narrow class, narrow rows)
-        if lane.split_ok:
-            below = 0
-            for c in range(lo, widest):
-                below += int(hist[c])
-                if not below:
-                    continue
-                staged = _bucket_rows(below) * (stride(c) + IN_META) + _bucket_rows(
-                    n - below
-                ) * (wide + IN_META)
-                if best is None or staged < best[0]:
-                    best = (staged, c, below)
-        split = best is not None and best[0] * _SPLIT_MIN_SAVING <= _bucket_rows(n) * (
-            wide + IN_META
-        )
-        if split:
-            _bytes, c, below = best
-            # both asked, so that both ladders start at the first sight
-            ready = [
-                self._stride_ready(lane, stride(c), below, n, nbytes),
-                self._stride_ready(lane, wide, n - below, n, nbytes),
-            ]
-            if all(ready):
-                narrow = cls <= c
-                return [
-                    _Part(stride(c), np.flatnonzero(narrow), lane.at(stride(c))[0]),
-                    _Part(wide, np.flatnonzero(~narrow), lane.at(wide)[0]),
-                ]
-        # one part: at the fitted stride, or, while its program is not built
-        # (asked for here unless the split's are), at the narrowest stride
-        # the spec has shown that is ready; the lane's own at worst
-        for st in sorted({wide, *(s for s in lane.fns if s > wide)}):
-            if self._stride_ready(lane, st, n, n, nbytes, start=st == wide and not split):
-                return [_Part(st, None, lane.at(st)[0])]
-        raise AssertionError("the lane's own stride is always ready")
+        ``rptpu-precompile`` to build and its rows ride in the next part up
+        whose program is built (the widest in the narrowest READY stride
+        the spec has shown, the lane's own at worst): nothing compiles on
+        the serving path for it."""
+        strides = _class_strides(self._row_stride)
+        lo = int(np.searchsorted(strides, lane.min_stride))
+        cls = np.where(fits, np.searchsorted(strides, sizes), 0)
+        np.clip(cls, lo, None, out=cls)
+        hist = np.bincount(cls, minlength=len(strides))
+        cuts = _plan_cuts(hist, strides, lane.split_ok)
+        rows = np.diff(np.cumsum(hist)[cuts], prepend=0).tolist()
+        plan = [(c, int(strides[c]), k) for c, k in zip(cuts, rows)]
+        # every part asked, so that every ladder starts at the first sight
+        ready = [self._stride_ready(lane, stride, k, n, nbytes) for _c, stride, k in plan]
+        if not all(ready):
+            held, plan, waiting = plan, [], 0
+            for (c, stride, k), ok in zip(held, ready):
+                if waiting:
+                    k += waiting
+                    ok = self._stride_ready(lane, stride, k, n, nbytes, start=False)
+                if ok:
+                    plan.append((c, stride, k))
+                waiting = 0 if ok else k
+            if waiting:
+                c, wide, _k = held[-1]
+                stride = next(
+                    st for st in sorted(s for s in lane.fns if s > wide)
+                    if self._stride_ready(lane, st, waiting, n, nbytes, start=False)
+                )
+                plan.append((c, stride, waiting))
+        if len(plan) == 1:
+            stride = plan[0][1]
+            return [_Part(stride, None, lane.at(stride)[0])]
+        parts, below = [], -1
+        for c, stride, _k in plan:
+            parts.append(_Part(
+                stride, np.flatnonzero((cls > below) & (cls <= c)), lane.at(stride)[0]
+            ))
+            below = c
+        return parts
 
     def _stride_ready(
         self, lane: _SpecPrograms, stride: int, k: int, n: int, nbytes: int,
@@ -3195,29 +3333,33 @@ class TpuEngine:
         same mix fills in the largest launch the read budget gives (so the
         launch knob's ramp finds its last step built), and the part's own
         where launches keep asking for it (_WANT_HOLD_S)."""
-        if stride == self._row_stride:
+        own = self._row_stride
+        if stride == own:
             return True
         fn = lane.at(stride)[0]
         with self._stats_lock:
-            base = self._ladders.get(lane.fns[self._row_stride][0])
+            base = self._ladders.get(lane.fns[own][0])
             ladder = self._ladders.get(fn)
-        if base is None:
+        top = self._ladder_top(lane.partitions, stride)
+        if base is None or top is None:
             return True
-        if ladder is None or ladder.top < base.top:
+        if ladder is None or ladder.top < top:
             if not start:
                 return False
-            self._start_ladder(fn, stride, base.top)
+            self._start_ladder(fn, stride, top)
             with self._stats_lock:
                 ladder = self._ladders[fn]
         ready = ladder.has(_bucket_rows(k), want=start, hold_s=_WANT_HOLD_S)
         if start:
-            # the largest launch: the ladders' top where the values are as
-            # dense as the top is sized for (_LADDER_ROWS_PER_STRIDE), as
+            # the largest launch: the most rows a ladder is sized for where
+            # the values are as dense as that (_LADDER_ROWS_PER_STRIDE), as
             # many fewer rows as these are wider (at least this launch's)
-            budget = base.top * self._row_stride // _LADDER_ROWS_PER_STRIDE
-            most = max(n, min(base.top, budget * n // max(nbytes, 1)))
+            rows_top = self._ladder_top(lane.partitions, _STRIDE_CLASS)
+            budget = rows_top * min(own, _BODY_STRIDE) // _LADDER_ROWS_PER_STRIDE
+            most = max(n, min(rows_top, budget * n // max(nbytes, 1)))
             ahead = ladder.has(_bucket_rows(k * most // n))
-            if not (ready and ahead):
+            # ... or a wanted bucket whose rows ride padded meanwhile
+            if not (ready and ahead) or ladder.next_bucket() is not None:
                 with self._stats_lock:
                     self._wake_precompiler()
         return ready
@@ -3239,7 +3381,7 @@ class TpuEngine:
         """Issue one payload-plan device launch over its built staging
         matrices (breaker gate, fault envelope, exact host fallback) —
         shared by the classic joined-blob and pointer-table staging
-        lanes. One part, or two by width class: each its own H2D and its
+        lanes. One part, or several by width class: each its own H2D and its
         own program, all inside ONE leg, so a launch is one device launch
         and one verdict however it was staged. The result format follows
         the plan (_mask_result): the packed result matrix, fetched at
@@ -3287,7 +3429,11 @@ class TpuEngine:
                 ]
                 for part in parts
             ]
-            self._stat_stage("t_h2d", t_h2d, trace_id=launch.trace_id)
+            self._stat_stage(
+                "t_h2d", t_h2d, trace_id=launch.trace_id,
+                strides=[part.stride for part in parts],
+                rows=[part.n_pad for part in parts],
+            )
             # a program built ahead of need, or the jitted function (whose
             # first call at a bucket traces and compiles); over the part's
             # rows in one run, or, a cut part, in runs of ``bucket`` rows

@@ -160,12 +160,37 @@ coproc_oversize_rows = registry.counter(
     "coproc_oversize_rows_total",
     "Values wider than the staging row, which the payload lane drops",
 )
-# Payload launches staged in two parts by width class (their narrow rows in
-# a narrow matrix, their few wide ones beside it: TpuEngine._plan_parts).
+# Payload launches staged in more than one part by width class (their narrow
+# rows in a narrow matrix, their few wide ones beside it, and one more a
+# class above 1,024 B: TpuEngine._plan_parts; coproc_launch_parts_total
+# counts the parts).
 coproc_split_launches = registry.counter(
     "coproc_split_launches_total",
-    "Payload launches staged, shipped and run as two parts by width class",
+    "Payload launches staged, shipped and run as two or more parts by width class",
 )
+# A launch's parts by width class, keyed by the engine's stats() name: the
+# matrices a launch is staged as (one, PR 47's two, or one a class above
+# 1,024 B where the lane's limit, coproc_max_value_bytes, is wider), and of
+# those above 1,024 B the rows, the matrices' bytes and the value bytes in
+# them (the last two's ratio is what the wider classes' padding costs).
+coproc_width_classes = {
+    "n_parts": registry.counter(
+        "coproc_launch_parts_total",
+        "Staging matrices (parts by width class) of the payload lane's launches",
+    ),
+    "n_wide_rows": registry.counter(
+        "coproc_wide_rows_total",
+        "Values staged in width classes above 1,024 B",
+    ),
+    "bytes_staged_wide": registry.counter(
+        "coproc_staged_wide_bytes_total",
+        "Bytes of the staging matrices wider than 1,024 B (rows x stride)",
+    ),
+    "bytes_staged_values_wide": registry.counter(
+        "coproc_staged_wide_value_bytes_total",
+        "Record value bytes packed into staging matrices wider than 1,024 B",
+    ),
+}
 # The staging matrices themselves, in bytes: rows x stride of every matrix
 # the lane packed, and the record bytes put into them (their ratio is what
 # of a launch's H2D is data; ~0.13 for 130 B events in 1,032 B rows).
@@ -621,6 +646,7 @@ __all__ = [
     "coproc_staged_value_bytes",
     "coproc_tick_hist",
     "coproc_uncompress",
+    "coproc_width_classes",
     "host_pool_task_finished",
     "host_pool_task_started",
     "kafka_fetch_hist",

@@ -46,6 +46,11 @@ from test_inputs import (  # noqa: E402,F401
     test_the_compressed_fixtures_frames_are_zstd_sealed_and_a_third_the_size,
     test_unknown_generators_are_refused_by_name,
 )
+from test_tail import (  # noqa: E402,F401  (PR 49: docs_tail.make_documents, json64p-v1-tail)
+    test_the_generator_holds_the_contract,
+    test_the_reference_recovers_each_kept_inputs_sequence_and_drops_none_for_its_size,
+    test_the_size_law_gives_its_shares_within_a_percent,
+)
 
 
 NEXMARK = "docs_nexmark.make_events"
@@ -339,7 +344,8 @@ def test_read_batches_per_crossing_is_read_by_the_histogram_reader_and_listed(me
     ``layer_metrics/read_batches_per_crossing.json`` is read by the reader the
     benchmark already had, over the histogram ``storage_read_crossing_batches``,
     and ``BENCHMARK.json`` lists it for the cells its append-side twin is
-    listed for: the six catch-up cells, and no ``paced.`` twin (a live tick's
+    listed for: the catch-up cells (six then, seven with PR 49's
+    ``json64p-v1-tail.catchup``), and no ``paced.`` twin (a live tick's
     reads are served by the batch cache and make no scan)."""
     import readers
 
@@ -355,7 +361,7 @@ def test_read_batches_per_crossing_is_read_by_the_histogram_reader_and_listed(me
     entry = listed["read_batches_per_crossing"]
     assert entry == {**{k: d[k] for k in ("name", "unit", "better", "source", "layer", "moves")},
                      "workloads": listed["append_batches_per_crossing"]["workloads"]}
-    assert len(entry["workloads"]) == 6 and all(w.endswith(".catchup") for w in entry["workloads"])
+    assert len(entry["workloads"]) == 7 and all(w.endswith(".catchup") for w in entry["workloads"])
     assert bench.manifest()["per_layer"][88] == entry  # appended as the 89th, nothing before it moved
     series = "storage_read_crossing_batches"
     before = {"stats": {}, "metrics": {series + k: 5.0 for k in metrics}}
