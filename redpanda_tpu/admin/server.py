@@ -660,6 +660,7 @@ class AdminServer:
             )
         from redpanda_tpu import native
         from redpanda_tpu.observability.probes import (
+            COPROC_ENGINE_PHASES,
             coproc_tick_hist,
             storage_append_crossing_batches_hist,
         )
@@ -682,6 +683,17 @@ class AdminServer:
                 "ticks": coproc_tick_hist["tick"].hist.count,
                 "read_us": coproc_tick_hist["read"].hist.sum,
                 "read_hidden_us": coproc_tick_hist["read_hidden"].hist.sum,
+            },
+            # the engine phase of a tick as the sum of its legs
+            # (probes.COPROC_ENGINE_PHASES; the worker's two calls and their
+            # self times are stats' t_submit / t_harvest / t_*_self)
+            "tick_account": {
+                "ticks": coproc_tick_hist["engine_run"].hist.count,
+                "engine_us": coproc_tick_hist["engine"].hist.sum,
+                **{
+                    phase + "_us": coproc_tick_hist[phase].hist.sum
+                    for phase in COPROC_ENGINE_PHASES
+                },
             },
             # the log's offset-assigning appends (every partition's: produce,
             # materialized write): batches framed over the framing calls

@@ -17,6 +17,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import threading
 
 from redpanda_tpu import rpc
 from redpanda_tpu.admin import AdminServer
@@ -24,6 +25,7 @@ from redpanda_tpu.config import Configuration
 from redpanda_tpu.kafka.server.broker import Broker, BrokerConfig
 from redpanda_tpu.kafka.server.protocol import KafkaServer
 from redpanda_tpu.metrics import registry
+from redpanda_tpu.observability import stages
 from redpanda_tpu.storage.log_manager import StorageApi
 
 logger = logging.getLogger("rptpu.app")
@@ -548,13 +550,27 @@ class Application:
 
     # ------------------------------------------------------------ shutdown
     async def stop(self) -> None:
-        """Reverse-order stop (application.cc:179-185)."""
-        for svc in reversed(self._stop_order):
-            try:
-                await svc.stop()
-            except Exception:
-                logger.exception("stopping %s failed", type(svc).__name__)
+        """Reverse-order stop (application.cc:179-185). Each service's stop
+        is a stage (``app.stop.<service>``: ring only, no histogram, off the
+        profile), and one log line at the end says what each took and which
+        threads still live: where a stop is slow, which service held it."""
+        took = []
+        with stages.stage("app.stop", annotate=False, root=True):
+            for svc in reversed(self._stop_order):
+                name = type(svc).__name__
+                st = stages.stage("app.stop." + name, annotate=False)
+                try:
+                    with st:
+                        await svc.stop()
+                except Exception:
+                    logger.exception("stopping %s failed", name)
+                took.append(f"{name} {st.t1 - st.t0:.3f}")
         self._stop_order.clear()
+        logger.info(
+            "stopped %d services (s each: %s); threads alive: %s",
+            len(took), ", ".join(took) or "-",
+            ", ".join(sorted(t.name for t in threading.enumerate())),
+        )
         # uninstall OUR plane (if still current): a stopped app's module-
         # level plane would otherwise keep gating later brokers/tests in
         # this interpreter and pin its gauges' weakref alive forever

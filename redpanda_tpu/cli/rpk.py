@@ -454,6 +454,32 @@ async def cmd_debug(args) -> int:
                 f"({ra['ticks']} ticks; ~0% on a live stream, which leaves no "
                 f"backlog to read ahead of)"
             )
+        ta = body.get("tick_account") or {}
+        if ta.get("ticks"):
+            # coproc_tick_latency_us{phase=}: the engine phase as the sum of
+            # its legs, ms a productive tick; the worker's two calls and
+            # what no t_* stage inside them covers, ms a launch (stats'
+            # t_submit / t_harvest / t_submit_self / t_harvest_self)
+            st = body.get("stats") or {}
+            tick_ms = {
+                k[:-3]: v / ta["ticks"] / 1000.0
+                for k, v in ta.items() if k.endswith("_us")
+            }
+            launches = max(st.get("n_launches", 0), 1)
+            call_ms = {
+                k: st.get("t_" + k, 0.0) / launches * 1000.0
+                for k in ("submit", "submit_self", "harvest", "harvest_self")
+            }
+            print(
+                f"tick:    engine {tick_ms['engine']:.2f} ms a tick = prepare "
+                f"{tick_ms['engine_prepare']:.2f} + out {tick_ms['handoff_out']:.2f} "
+                f"+ run {tick_ms['engine_run']:.2f} + back {tick_ms['handoff_back']:.2f} "
+                f"+ read-ahead wait {tick_ms['read_ahead_wait']:.2f} ({ta['ticks']} "
+                f"ticks; engine also counts ticks that timed out or were shed); the "
+                f"worker's run a launch: submit {call_ms['submit']:.2f} (self "
+                f"{call_ms['submit_self']:.2f}) + harvest {call_ms['harvest']:.2f} "
+                f"(self {call_ms['harvest_self']:.2f}) ms"
+            )
         ap = body.get("append") or {}
         if ap.get("framings"):
             # storage_append_crossing_batches: _sum over _count

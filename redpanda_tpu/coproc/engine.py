@@ -728,6 +728,10 @@ class _Launch:
                 eng.governor.breaker_for(faults.HARVEST).record_success()
         self._stat("t_fetch", t0, span_id=self._fetch_span)
         self._packed_dev = None
+        # unpack: the fetched matrix split into rows, lengths and keep bits
+        # (strided column copies over every row: 1.5 ms of a NEXmark launch
+        # on the chip's host, PR 52), the staging matrices parked
+        t0 = _stage_t0("t_unpack")
         self._park_staged()
         out, out_len, keep = unpack_result(packed, self.r_out)
         n = len(self.fits)
@@ -738,7 +742,9 @@ class _Launch:
             eng._stat_add("n_json_rows", float(n))
             eng._stat_add("n_json_malformed_rows", float(why[JSON_MALFORMED]))
             eng._stat_add("n_json_path_miss_rows", float(why[JSON_PATH_MISS]))
-        return out[:n], out_len[:n], keep[:n] & self.fits
+        kept = keep[:n] & self.fits
+        self._stat("t_unpack", t0)
+        return out[:n], out_len[:n], kept
 
     def _payload_host_fallback(self) -> np.ndarray:
         """Fail closed per-launch: re-run the packed pipeline in numpy over
@@ -1189,9 +1195,9 @@ class _Launch:
         if self.engine is not None:
             self.engine._stat_stage(key, t0, trace_id=self.trace_id, **ring)
         else:
-            stages.close(
+            _stage_closed(stages.close(
                 "coproc.stage." + key[2:], None, t0, trace_id=self.trace_id, **ring
-            )
+            ))
 
 
 def _pack_values(ex, stride: int):
@@ -1240,10 +1246,46 @@ _UNKNOWN, _EMPTY, _DEREGISTERED, _LAUNCHED = range(4)
 _AMBIENT = stages.AMBIENT
 
 
+class _SelfTime(threading.local):
+    """One thread's books for the self time of the engine call it runs
+    (``TpuEngine.submit``, ``Ticket.result``): how deep the open ``t_*``
+    stages nest on it, and the seconds of those closed at the top level
+    since the call began. A call's self time is its duration less
+    ``covered``: a stage closed inside another counts once (``t_h2d`` inside
+    ``t_dispatch``), and one that ran on another thread (the fault
+    envelope's worker, the harvester, a mesh shard) is in that thread's
+    books, which nobody reads. Two additions a stage; no ring."""
+
+    depth = 0
+    covered = 0.0
+
+
+_self_time = _SelfTime()
+
+
+def _call_t0() -> float:
+    """Begin an engine call on this thread: its clock read, with the
+    thread's self-time books opened afresh (a stage an earlier call left
+    open on an exception must not nest this one's)."""
+    _self_time.depth = 0
+    _self_time.covered = 0.0
+    return time.perf_counter()
+
+
 def _stage_t0(key: str) -> float:
     """Begin the engine stage whose stat key is ``t_<stage>``: the ``t0``
     that ``_stat_stage`` / ``_Launch._stat`` closes."""
+    _self_time.depth += 1
     return stages.begin("coproc.stage." + key[2:])
+
+
+def _stage_closed(dt: float) -> None:
+    """The other half of ``_stage_t0``'s bookkeeping, on the closing thread."""
+    acct = _self_time
+    acct.depth -= 1
+    if acct.depth <= 0:
+        acct.depth = 0
+        acct.covered += dt
 
 
 # Columnar backend probe: don't pin the process-wide device-vs-host choice
@@ -1287,7 +1329,7 @@ class Ticket:
         self.worker_clock = (0.0, 0.0)
 
     def result(self) -> ProcessBatchReply:
-        t_run = time.perf_counter()
+        t_run = _call_t0()
         try:
             # a stage: on a profile the executor thread's line shows it
             # beside the loop's rp:coproc.harvest.wait
@@ -1295,7 +1337,7 @@ class Ticket:
                 return self._result_impl()
         finally:
             self._engine._release_admission(self)
-            self.worker_clock = (t_run, time.perf_counter())  # pandalint: disable=RAC1101 -- a ticket is harvested by one call; its reader is the fiber that awaited that call's executor future (the future's completion is the hand-off)
+            self.worker_clock = (t_run, self._engine._call_done("t_harvest", t_run))  # pandalint: disable=RAC1101 -- a ticket is harvested by one call; its reader is the fiber that awaited that call's executor future (the future's completion is the hand-off)
 
     def _result_impl(self) -> ProcessBatchReply:
         reply = ProcessBatchReply()
@@ -2341,8 +2383,20 @@ class TpuEngine:
         dt = stages.close(
             "coproc.stage." + key[2:], None, t0, trace_id=trace_id, **ring
         )
+        _stage_closed(dt)
         self._stat_add(key, dt)
         return dt
+
+    def _call_done(self, key: str, t_run: float) -> float:
+        """End the engine call begun at ``t_run = _call_t0()`` on this
+        thread: its clock read (the caller's ``worker_clock`` takes the same
+        one), the call's seconds into ``t_<call>`` and what no top-level
+        stage of this thread covered into ``t_<call>_self``."""
+        t_done = time.perf_counter()
+        dt = t_done - t_run
+        self._stat_add(key, dt)
+        self._stat_add(key + "_self", max(dt - _self_time.covered, 0.0))
+        return t_done
 
     def _count_fallback(self, n: int) -> None:
         """Account records whose stages re-executed on the pure-host
@@ -2527,9 +2581,13 @@ class TpuEngine:
         return self.submit(req).result()
 
     def submit(self, req: ProcessBatchRequest) -> Ticket:
-        t_run = time.perf_counter()
-        ticket = self.submit_group([req])[0]
-        ticket.worker_clock = (t_run, time.perf_counter())
+        t_run = _call_t0()
+        try:
+            ticket = self.submit_group([req])[0]
+        finally:
+            # a shed or failed submit has its time too (and no ticket)
+            t_done = self._call_done("t_submit", t_run)
+        ticket.worker_clock = (t_run, t_done)
         return ticket
 
     def submit_group(self, reqs: list[ProcessBatchRequest]) -> list[Ticket]:
@@ -3191,6 +3249,10 @@ class TpuEngine:
         pooled decompress buffers go back when that framing is done
         (_Launch.framed); any other launch has read its last payload byte
         once the matrix is packed, and gives them back here."""
+        # plan: which values fit, and the launch's parts by width class with
+        # their row selections (passes over the sizes of every record: 5 ms
+        # of a 186,000-row NEXmark launch on the chip's host, PR 52)
+        t_plan = _stage_t0("t_plan")
         lane = self._lanes[launch.script_id]
         launch.r_out = lane.fns[self._row_stride][1]
         launch.fits = exploded.sizes <= self._row_stride
@@ -3200,10 +3262,12 @@ class TpuEngine:
         if n == 0:
             if not retained:
                 _release_exploded(exploded)
+            self._stat_stage("t_plan", t_plan)
             return
         value_bytes = float((exploded.sizes * launch.fits).sum(dtype=np.int64))
         parts = self._plan_parts(lane, exploded.sizes, launch.fits, n, int(value_bytes))
         launch.r_out = lane.fns[parts[-1].stride][1]
+        self._stat_stage("t_plan", t_plan, parts=len(parts))
         pack = (
             self._pack_staged_ptrs
             if isinstance(exploded, batch_codec.PtrExploded)

@@ -39,7 +39,7 @@ from redpanda_tpu.coproc.engine import (
 from redpanda_tpu.models.fundamental import NTP, MaterializedNTP
 from redpanda_tpu.observability import stages
 from redpanda_tpu.observability.probes import (
-    COPROC_HANDOFF_PHASES,
+    COPROC_ENGINE_PHASES,
     coproc_input_wait_hist,
     coproc_tick_hist,
     record_us,
@@ -72,11 +72,12 @@ def _release_abandoned(engine):
     return cb
 
 
-def _note_handoff(legs: list, wait_span, t_out: float, ticket) -> None:
+def _note_handoff(legs: list, wait_span, t_out: float, ticket) -> float:
     """One executor call's three legs from its four clock reads: ``t_out``
     on the loop just before ``run_in_executor``, the worker's two around
     the engine call (``Ticket.worker_clock``), the fiber's resume, read
-    here. Handed to the executor -> the worker runs it, the worker's own
+    here and returned (what comes next in the engine phase begins on it).
+    Handed to the executor -> the worker runs it, the worker's own
     time, worker done -> the fiber runs again: added to the tick's sums,
     and the loop-side wait span carries the two hand-offs for ``rpk debug
     trace``."""
@@ -87,6 +88,7 @@ def _note_handoff(legs: list, wait_span, t_out: float, ticket) -> None:
     legs[2] += t_back - t_done
     wait_span.set("out_us", int((t_run - t_out) * 1e6))
     wait_span.set("back_us", int((t_back - t_done) * 1e6))
+    return t_back
 
 
 class _ReadAhead:
@@ -370,9 +372,15 @@ class ScriptContext:
                 ahead_partitions=len(ahead.reads),
             )
 
-    async def _see_read_ahead_out(self, ticket) -> None:
+    async def _see_read_ahead_out(self, ticket, t_back: float) -> float:
         """Between a tick's two executor calls: wait for what is being read
-        ahead before the harvest goes out. The submit's crossings (explode,
+        ahead before the harvest goes out; returns the clock read the
+        harvest goes out on, which is ``t_back`` (the fiber's resume after
+        the submit) itself where there was nothing to wait for, so that the
+        tick's ``read_ahead_wait`` sample, the difference of the two, is 0
+        there and the engine phase stays the sum of its legs. The stage is
+        the annotation and the ring span; the sample is the caller's, from
+        the shared reads. The submit's crossings (explode,
         pack) and the launch's transfer, which is in flight from the
         dispatch on, are what the read overlaps; the rest of it runs here
         in one stretch, with the worker idle. Measured on the chip with
@@ -387,7 +395,7 @@ class ScriptContext:
         (ROADMAP.md A1 (6))."""
         ahead = self._ahead
         if ahead is None or ahead.task.done():
-            return
+            return t_back
         ahead.yields = False
         try:
             with stages.stage("coproc.read_ahead.wait"):
@@ -398,6 +406,7 @@ class ScriptContext:
             # this ticket will never be harvested
             self.pacemaker.engine._release_admission(ticket)
             raise
+        return time.perf_counter()
 
     def _drop_ahead(self) -> None:
         """The tick it ran under failed, timed out or was shed, or the
@@ -451,13 +460,18 @@ class ScriptContext:
                 pm._launch_inflight += 1
         try:
             # engine: request built and submit dispatched to reply in hand,
-            # executor queueing included; the two waits are its children in
-            # the ring. Its two executor calls are clocked on both threads:
-            # one sample a tick in each of COPROC_HANDOFF_PHASES, the sum over both
-            # calls, once both have come back (a timed-out or shed tick
+            # executor queueing included; the waits are its children in
+            # the ring. The phase is the sum of COPROC_ENGINE_PHASES by
+            # construction: each leg begins on the clock read that ended
+            # the one before it, the first on the stage's own t0 and the
+            # last on its t1. Its two executor calls are clocked on both
+            # threads: one sample a tick in each of COPROC_HANDOFF_PHASES,
+            # the sum over both calls. All five are recorded once both
+            # calls have come back (a timed-out, cancelled or shed tick
             # records none)
             legs = [0.0, 0.0, 0.0]
-            with stages.stage("coproc.engine", coproc_tick_hist["engine"]):
+            eng = stages.stage("coproc.engine", coproc_tick_hist["engine"])
+            with eng:
                 # Submit AND harvest run in worker threads: the first
                 # dispatch of a spec jit-compiles for seconds, and anything
                 # that blocks the broker's event loop that long stops raft
@@ -480,6 +494,7 @@ class ScriptContext:
                 # mid-envelope ticks.
                 deadline_s = pm.tick_deadline_for(pm.engine)
                 t_out = time.perf_counter()
+                prepare_s = t_out - eng.t0
                 sub_fut = loop.run_in_executor(ex, pm.engine.submit, req)
                 self._begin_read_ahead(behind, read_budget)
                 try:
@@ -487,7 +502,7 @@ class ScriptContext:
                         ticket = await asyncio.wait_for(
                             asyncio.shield(sub_fut), timeout=deadline_s
                         )
-                        _note_handoff(legs, wait, t_out, ticket)
+                        t_back = _note_handoff(legs, wait, t_out, ticket)
                 except (asyncio.TimeoutError, asyncio.CancelledError):
                     # timeout OR fiber cancellation (script removal): the
                     # executor thread cannot be cancelled, and the shielded
@@ -496,15 +511,15 @@ class ScriptContext:
                     # shut one abandoned tick at a time
                     sub_fut.add_done_callback(_release_abandoned(pm.engine))
                     raise
-                await self._see_read_ahead_out(ticket)
-                t_out = time.perf_counter()
+                t_out = await self._see_read_ahead_out(ticket, t_back)
+                ahead_wait_s = t_out - t_back
                 res_fut = loop.run_in_executor(ex, ticket.result)
                 try:
                     with stages.stage("coproc.harvest.wait") as wait:
                         reply = await asyncio.wait_for(
                             asyncio.shield(res_fut), timeout=deadline_s
                         )
-                        _note_handoff(legs, wait, t_out, ticket)
+                        t_back = _note_handoff(legs, wait, t_out, ticket)
                 except (asyncio.TimeoutError, asyncio.CancelledError):
                     # shield the work item too: an un-started queued
                     # result() would otherwise be CANCELLED outright and
@@ -514,7 +529,12 @@ class ScriptContext:
                     # harmless either way.
                     pm.engine._release_admission(ticket)
                     raise
-            for phase, dt in zip(COPROC_HANDOFF_PHASES, legs):
+            # the fiber's resume to the phase's end (two stage exits) is the
+            # hand-off back's
+            legs[2] += eng.t1 - t_back
+            for phase, dt in zip(
+                COPROC_ENGINE_PHASES, (prepare_s, *legs, ahead_wait_s)
+            ):
                 coproc_tick_hist[phase].record(int(dt * 1e6))
         except ShedError as exc:
             # admission refused the staged bytes BEFORE any dispatch:

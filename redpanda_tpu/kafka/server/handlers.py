@@ -24,6 +24,7 @@ from redpanda_tpu.observability.probes import (
     kafka_fetch_parks,
     kafka_fetch_serve_hist,
     kafka_fetch_wake_hist,
+    kafka_produce_stage_hist as _produce_stage,
 )
 from redpanda_tpu.raft.types import RaftError
 from redpanda_tpu.security.acl import AclOperation, ResourceType
@@ -242,6 +243,13 @@ async def handle_produce(ctx) -> dict | None:
         # carried out to the dispatch layer so the histogram record there
         # can attach a trace exemplar when this request breaches
         ctx.trace_id = sp.trace_id
+        # the gate's wait ended where this span begins; closed inside it
+        # (back-dated by its length), so that its ring span hangs under the
+        # request's
+        stages.close(
+            "kafka.produce.queue", _produce_stage["queue"],
+            time.perf_counter() - ctx.queue_s,
+        )
         return await _do_handle_produce(ctx)
 
 
@@ -359,7 +367,8 @@ async def _produce_one(broker, topic: str, p: dict, level: int, api_version: int
         )
 
         try:
-            batches = [convert_message_set(records)]
+            with stages.stage("kafka.produce.decode", _produce_stage["decode"]):
+                batches = [convert_message_set(records)]
         except LegacyUnsupportedError:
             return _produce_partition_error(index, E.unsupported_for_message_format)
         except LegacyBatchError:
@@ -369,16 +378,18 @@ async def _produce_one(broker, topic: str, p: dict, level: int, api_version: int
             # CRC validation goes through the measured adapter boundary
             # (ops/crc_backend.py): batched host SSE4.2 or device kernel,
             # whichever the process-wide probe picked.
-            adapted = decode_wire_batches(records, verify_crc=False)
+            with stages.stage("kafka.produce.decode", _produce_stage["decode"]):
+                adapted = decode_wire_batches(records, verify_crc=False)
         except EOFError:
             return _produce_partition_error(index, E.corrupt_message)
         from redpanda_tpu.ops.crc_backend import default_backend_async
 
-        v2 = [a for a in adapted if a.v2_format]
-        ok = (await default_backend_async()).validate(
-            [a.batch.crc_region() for a in v2],
-            [a.batch.header.crc for a in v2],
-        )
+        with stages.stage("kafka.produce.crc", _produce_stage["crc"]):
+            v2 = [a for a in adapted if a.v2_format]
+            ok = (await default_backend_async()).validate(
+                [a.batch.crc_region() for a in v2],
+                [a.batch.header.crc for a in v2],
+            )
         ok_iter = iter(ok)
         for a in adapted:
             # kafka_batch_adapter.cc:93-121: per batch IN ORDER, reject legacy
@@ -396,7 +407,8 @@ async def _produce_one(broker, topic: str, p: dict, level: int, api_version: int
     # append run atomically inside the stm
     if any(b.header.producer_id >= 0 for b in batches):
         stm = await broker.recovered_rm_stm(partition)
-        code, result = await stm.replicate(batches, level)
+        with stages.stage("kafka.produce.replicate", _produce_stage["replicate"]):
+            code, result = await stm.replicate(batches, level)
         if code != E.none:
             return _produce_partition_error(index, code)
         if result is None:
@@ -409,7 +421,8 @@ async def _produce_one(broker, topic: str, p: dict, level: int, api_version: int
                 "log_start_offset": partition.start_offset,
             }
     else:
-        result = await partition.replicate(batches, level)
+        with stages.stage("kafka.produce.replicate", _produce_stage["replicate"]):
+            result = await partition.replicate(batches, level)
     return {
         "partition_index": index,
         "error_code": 0,

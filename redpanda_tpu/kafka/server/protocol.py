@@ -55,7 +55,7 @@ from redpanda_tpu.observability.probes import (  # noqa: E402
 class RequestContext:
     """Per-request context handed to handlers (kafka::request_context)."""
 
-    __slots__ = ("broker", "header", "request", "connection", "trace_id")
+    __slots__ = ("broker", "header", "request", "connection", "trace_id", "queue_s")
 
     def __init__(self, broker, header: RequestHeader, request: dict, connection):
         self.broker = broker
@@ -67,6 +67,10 @@ class RequestContext:
         # AFTER the span closed, so exemplar capture needs the id carried
         # out-of-band (observability/probes.py trace exemplars)
         self.trace_id = None
+        # seconds this request waited at the qdc gate before its handler
+        # ran (protocol._dispatch); the produce handler records it as its
+        # ``queue`` stage, under its own span
+        self.queue_s = 0.0
 
     @property
     def api_version(self) -> int:
@@ -240,6 +244,7 @@ class Connection:
         if gated:
             await self.server.qdc.acquire()
         t_svc = loop.time()
+        ctx.queue_s = t_svc - t0
         try:
             response = await handler(ctx)
         except KafkaError as e:

@@ -64,6 +64,25 @@ raft_replicate_hist = registry.histogram(
 kafka_produce_hist = registry.histogram(
     "kafka_produce_latency_us", "Produce handler latency (microseconds)"
 )
+# The produce handler's inside, for what kafka_produce_latency_us times
+# from outside. ``queue``: one sample a request, the qdc gate's acquire in
+# protocol._dispatch (0 with the gate off). ``decode`` (the wire batches
+# parsed), ``crc`` (the backend awaited and the batched validate) and
+# ``replicate`` (the await of partition.replicate / rm_stm.replicate: raft,
+# the log's append and flush, and the awaits between them on a loop that may
+# run late): one sample a PARTITION of a request, each only where the
+# handler got that far (a refused partition records what ran before the
+# refusal). A produce v0-2 MessageSet is converted in one call, under
+# ``decode``.
+KAFKA_PRODUCE_STAGES = ("queue", "decode", "crc", "replicate")
+kafka_produce_stage_hist = {
+    stage: registry.histogram(
+        "kafka_produce_stage_latency_us",
+        "Produce handler time by stage (us)",
+        stage=stage,
+    )
+    for stage in KAFKA_PRODUCE_STAGES
+}
 kafka_fetch_hist = registry.histogram(
     "kafka_fetch_latency_us",
     "Fetch handler latency incl. long-poll wait (microseconds)",
@@ -108,19 +127,28 @@ rpc_request_hist = registry.histogram(
 # read-ahead a backlog engages: under the submit call and, between the two
 # calls, under the launch's transfer; 0 for a tick that read for itself),
 # so sum(read_hidden) / sum(read) is the share of the read that left the
-# fiber's own read stretch. What it cost the engine phase it ran inside is
-# engine - (handoff_out + engine_run + handoff_back): the fiber seeing the
-# read-ahead out before it sends the harvest.
-# The engine phase is two executor calls (submit, harvest); summed over
-# both, handoff_out + engine_run + handoff_back = engine less the request's
-# construction: call handed to the executor -> the worker runs it (queue,
-# thread wake, interpreter lock), the worker's own time in the call, worker
-# done -> the fiber runs again (the wake-up and a late loop). A timed-out
-# or shed tick records none of the three.
+# fiber's own read stretch.
+# The engine phase is a sum, by construction (each leg begins on the clock
+# read that ended the one before it; each sample truncates to a us):
+#   engine = engine_prepare + handoff_out + engine_run + handoff_back
+#            + read_ahead_wait
+# engine_prepare: the phase's entry -> the submit is handed to the executor
+# (the request's construction, the tick deadline's derivation).
+# The phase's two executor calls (submit, harvest), summed over both:
+# handoff_out, call handed to the executor -> the worker runs it (queue,
+# thread wake, interpreter lock); engine_run, the worker's own time in the
+# call; handoff_back, worker done -> the fiber runs again (the wake-up and a
+# late loop), and after the harvest on to the phase's end.
+# read_ahead_wait: between the two calls, the fiber seeing the read-ahead out
+# before it sends the harvest (what the read cost the engine phase it ran
+# inside); 0 for a tick with nothing read ahead, or whose read-ahead had
+# ended under the submit.
+# A timed-out, cancelled or shed tick records ``engine`` and none of the five.
 COPROC_HANDOFF_PHASES = ("handoff_out", "engine_run", "handoff_back")
+COPROC_ENGINE_PHASES = ("engine_prepare", *COPROC_HANDOFF_PHASES, "read_ahead_wait")
 COPROC_TICK_PHASES = (
     "tick", "read", "read_hidden", "gate", "engine", "write", "gap",
-    *COPROC_HANDOFF_PHASES,
+    *COPROC_ENGINE_PHASES,
 )
 coproc_tick_hist = {
     phase: registry.histogram(

@@ -20,12 +20,15 @@ clock read at each end, and the same two reads feed
     with ``parent`` = the span that was ambient when the stage began.
 
 Two forms. ``with stage(name, hist):`` wraps a block and makes the stage
-the ambient span for what runs inside. ``t0 = begin(name)`` ...
-``close(name, hist, t0)`` is for code that holds its ``t0`` across
-branches, threads or awaits (the engine's ``t_*`` stages, the pacemaker's
-phases); the annotation has to be entered at the start, so ``begin`` takes
-the name too. ``close`` also takes a plain ``time.perf_counter()`` value:
-such a stage has sinks (a) and (c) only.
+the ambient span for what runs inside (its own two reads are ``.t0`` and
+``.t1``, for a caller whose neighbouring intervals begin or end on them).
+``t0 = begin(name)`` ... ``close(name, hist, t0)`` is for code that holds
+its ``t0`` across branches, threads or awaits (the engine's ``t_*`` stages,
+the pacemaker's phases); the annotation has to be entered at the start, so
+``begin`` takes the name too. ``close`` also takes a plain
+``time.perf_counter()`` value, back-dated where the caller holds a duration
+and not a start (the produce handler's ``queue``): such a stage has sinks
+(a) and (c) only.
 
 Stages that await on the event loop interleave with other coroutines'
 stages on the loop thread's line of the profile; they need not nest.
@@ -111,7 +114,10 @@ class stage:
     when tracing is off. ``annotate`` is ``begin``'s; the other keywords
     are ``Tracer.span``'s (``root``, ``trace_id``, ``no_slow``, ``node``)."""
 
-    __slots__ = ("_name", "_hist", "_span", "_t0", "_annotate")
+    # ``t0`` / ``t1``: the stage's own two clock reads, for a caller whose
+    # neighbouring intervals have to begin or end on them (the pacemaker's
+    # engine phase is the sum of its legs by sharing these)
+    __slots__ = ("_name", "_hist", "_span", "t0", "t1", "_annotate")
 
     def __init__(self, name: str, hist=None, *, annotate: bool = True, **span_kw) -> None:
         self._name = name
@@ -120,7 +126,7 @@ class stage:
         self._span = tracer.span(name, **span_kw) if tracer.enabled else _NOOP
 
     def __enter__(self):
-        self._t0 = t0 = begin(self._name, annotate=self._annotate)
+        self.t0 = t0 = begin(self._name, annotate=self._annotate)
         self._span.enter_at(t0)
         return self._span
 
@@ -128,8 +134,8 @@ class stage:
         # close(), with the span this stage entered: with none to commit the
         # ring takes nothing (a mid-path stage outside any trace must not
         # mint an orphan)
-        t1 = time.perf_counter()
-        t0 = self._t0
+        self.t1 = t1 = time.perf_counter()
+        t0 = self.t0
         if type(t0) is _Annotated:
             t0.annotation.__exit__(None, None, None)
         if self._hist is not None:

@@ -372,3 +372,131 @@ def test_read_batches_per_crossing_is_read_by_the_histogram_reader_and_listed(me
     assert "read_batches_per_crossing" not in readers.read_all(
         directory, kind="paced", before=before, after=after, client={}, trace=None, window_s=40.0)
 
+
+
+# ------------------------------------------------------------------ PR 52: the tick's account
+# name -> what its reader must name: (series, labels) of a histogram_delta
+# file, (numerator, denominator) of a stats_ratio one
+PR52_FILES = {
+    "tick_read_ahead_wait_ms": ("coproc_tick_latency_us", {"phase": "read_ahead_wait"}),
+    "tick_engine_prepare_ms": ("coproc_tick_latency_us", {"phase": "engine_prepare"}),
+    "paced.tick_engine_prepare_ms": ("coproc_tick_latency_us", {"phase": "engine_prepare"}),
+    "worker_submit_ms_per_launch": ("t_submit", "n_launches"),
+    "worker_harvest_ms_per_launch": ("t_harvest", "n_launches"),
+    "worker_submit_self_ms_per_launch": ("t_submit_self", "n_launches"),
+    "worker_harvest_self_ms_per_launch": ("t_harvest_self", "n_launches"),
+    "paced.worker_submit_ms_per_launch": ("t_submit", "n_launches"),
+    "paced.worker_harvest_ms_per_launch": ("t_harvest", "n_launches"),
+    "paced.worker_submit_self_ms_per_launch": ("t_submit_self", "n_launches"),
+    "paced.worker_harvest_self_ms_per_launch": ("t_harvest_self", "n_launches"),
+    "dispatch_ms_per_launch": ("t_dispatch", "n_device_launches"),
+    "paced.dispatch_ms_per_launch": ("t_dispatch", "n_device_launches"),
+    # the two stages step 0 found (PERF.md section 5)
+    "plan_ms_per_launch": ("t_plan", "n_device_launches"),
+    "paced.plan_ms_per_launch": ("t_plan", "n_device_launches"),
+    "unpack_ms_per_launch": ("t_unpack", "n_device_launches"),
+    "paced.produce_queue_ms": ("kafka_produce_stage_latency_us", {"stage": "queue"}),
+    "paced.produce_decode_ms": ("kafka_produce_stage_latency_us", {"stage": "decode"}),
+    "paced.produce_crc_ms": ("kafka_produce_stage_latency_us", {"stage": "crc"}),
+    "paced.produce_replicate_ms": ("kafka_produce_stage_latency_us", {"stage": "replicate"}),
+}
+
+
+@pytest.fixture(scope="module")
+def live_broker(tmp_path_factory):
+    """What a CPU broker really exports once it has served a produce and a
+    launch on each of the payload lane's roads (the keep mask; the result
+    matrix) through its pacemaker: the parsed ``/metrics`` text
+    and the engine's ``stats()``, as the benchmark's snapshot holds them."""
+    import asyncio
+
+    import readers
+    import test_pacemaker_read_ahead as ra
+    from redpanda_tpu.cluster.topic_table import TopicConfig
+    from redpanda_tpu.kafka.client.client import KafkaClient
+    from redpanda_tpu.metrics import registry
+    from redpanda_tpu.models.fundamental import NTP
+    from redpanda_tpu.ops.transforms import Int, Str, filter_contains, map_project
+
+    out = {}
+
+    async def main():
+        tmp = tmp_path_factory.mktemp("live")
+        storage, broker, server, api = await ra._start(tmp)
+        client = await KafkaClient([("127.0.0.1", server.port)]).connect()
+        try:
+            await broker.create_topic(TopicConfig("src", 1))
+            ctx = await ra._deployed(api, "live", "payload")
+            matrix_road = (filter_contains(b'"level":"error"')
+                           | map_project(Int("code"), Str("msg", 16))).to_json()
+            await api.deploy("live_map", matrix_road, ["src"])
+            await ra.wait_until(lambda: "live_map" in api.pacemaker.scripts(), msg="deployed")
+            ctxs = [ctx, api.pacemaker.scripts()["live_map"]]
+            for k in range(3):
+                await client.produce("src", 0, ra._docs(64, base=64 * k))
+            await ra.wait_until(
+                lambda: all(c.offsets.get(NTP.kafka("src", 0)) == 3 * 64 - 1 for c in ctxs),
+                msg="transformed")
+            out["metrics"] = readers.parse_prometheus(registry.render_prometheus())
+            out["stats"] = api.pacemaker.engine.stats()
+        finally:
+            await client.close()
+            await ra._stop(storage, server, api)
+
+    ra.run(main())
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(PR52_FILES))
+def test_a_pr52_metric_file_names_what_the_program_exports_and_is_listed(name, live_broker):
+    """Each of PR 52's files is data alone: it loads, its reader is one the
+    benchmark had, what it names is a series or a ``stats()`` key a live CPU
+    broker really exports (a renamed key fails here, not as a null on the
+    ledger), and ``BENCHMARK.json`` lists it over cells that exist and run
+    its traffic kind."""
+    import readers
+
+    directory = os.path.join(BENCH, "layer_metrics")
+    defs = {d["name"]: d for d in readers.load_definitions(directory)}
+    d, want = defs[name], PR52_FILES[name]
+    paced = name.startswith("paced.")
+    assert d["traffic"] == (["paced"] if paced else ["catchup"])
+    assert d["moves"] == ("e2e_p95_ms" if paced else "transform_rate")
+    assert (d["source"], d["unit"], d["better"]) == ("program_span", "ms", "lower")
+    read = d["read"]
+    assert read["kind"] in readers.KINDS
+    assert "the parent" in read["why"] or "nothing to read" in read["why"]  # says what a parent reads
+    stats, metrics = live_broker["stats"], live_broker["metrics"]
+    if read["kind"] == "histogram_delta":
+        assert (read["metric"], read["labels"]) == want and read["scale"] == 0.001
+        for suffix in ("_sum", "_count"):
+            assert any(
+                series.partition("{")[0] == read["metric"] + suffix
+                and all(f'{k}="{v}"' in series for k, v in read["labels"].items())
+                for series in metrics), (name, suffix)
+        assert readers.metric_total(metrics, read["metric"] + "_count", read["labels"]) > 0
+    else:
+        assert read["kind"] == "stats_ratio" and read["scale"] == 1000.0
+        assert (read["num"], read["den"]) == ([want[0]], [want[1]])
+        assert stats[want[0]] > 0 and stats[want[1]] > 0
+    # the reader over a window of that broker: a number; over the parent's
+    # (no series, no key): nothing for a histogram, 0 for a ratio
+    empty = {"metrics": {}, "stats": {}}
+    kind = d["traffic"][0]
+    got = readers.read_all(directory, kind=kind, before=empty, after=live_broker,
+                           client={}, trace=None, window_s=40.0)
+    assert got[name]["value"] >= 0 and got[name]["unit"] == "ms"
+    parent = {"metrics": {}, "stats": {want[1]: 5.0} if read["kind"] == "stats_ratio" else {}}
+    gone = readers.read_all(directory, kind=kind, before=empty, after=parent,
+                            client={}, trace=None, window_s=40.0).get(name)
+    assert gone == (None if read["kind"] == "histogram_delta" else {"value": 0.0, "unit": "ms"})
+    man = bench.manifest()
+    (entry,) = [e for e in man["per_layer"] if e["name"] == name]
+    assert entry == {**{k: d[k] for k in ("name", "unit", "better", "source", "layer", "moves")},
+                     "workloads": entry["workloads"]}
+    cells = {w["name"]: w for w in man["workloads"]}
+    assert entry["workloads"] and set(entry["workloads"]) <= set(cells)
+    for cell in entry["workloads"]:
+        traffic = bench.load(os.path.join("benchmarks", "traffic", cells[cell]["traffic"] + ".json"))
+        assert traffic["kind"] == kind, (name, cell)
+    assert man["per_layer"].index(entry) >= 92  # appended: nothing that was there moved

@@ -340,3 +340,51 @@ def test_a_host_fallback_records_no_link_wait_leg(domain):
     assert reply.items[0].batches[0].header.record_count == 8
     assert stats["n_fallback_rows"] == 8
     assert not [k for k in stats if k.startswith("t_wait_")]
+
+
+# ------------------------------------------------------------------ PR 52: shared clock reads
+@pytest.mark.parametrize("on", [False, True], ids=["tracing_off", "tracing_on"])
+def test_close_takes_a_back_dated_start_for_a_duration_the_caller_already_has(on):
+    """The produce handler's ``queue`` stage: the caller holds seconds, not
+    a start, and closes the stage inside the span it belongs under."""
+    import time
+
+    tracer.reset()
+    tracer.configure(enabled=on)
+    try:
+        hist = Histogram("t_us", "")
+        with stages.stage("root", root=True) as root:
+            dt = stages.close("kid.back_dated", hist, time.perf_counter() - 0.002)
+        assert 0.002 <= dt < 0.0021 and hist.hist.count == 1
+        assert 2000 <= hist.hist.sum < 2100
+        spans = _spans()
+        if not on:
+            assert spans == {}
+            return
+        kid = spans["kid.back_dated"]
+        assert kid["parent"] == root.span_id and 2000 <= kid["dur_us"] < 2100
+        assert kid["start_us"] < spans["root"]["start_us"]  # it began before its parent did
+    finally:
+        tracer.configure(enabled=False)
+        tracer.reset()
+
+
+def test_a_with_stage_hands_out_its_own_two_clock_reads():
+    """What lets a caller's neighbouring intervals begin and end on the
+    stage's reads (the pacemaker's engine phase as the sum of its legs)."""
+    import time
+
+    hist = Histogram("t_us", "")
+    before = time.perf_counter()
+    st = stages.stage("unit.stage", hist)
+    with st:
+        inside = time.perf_counter()
+    after = time.perf_counter()
+    assert before <= st.t0 <= inside <= st.t1 <= after
+    assert hist.hist.sum == int((st.t1 - st.t0) * 1e6)
+    # a stage that raised has its end too
+    st = stages.stage("unit.raises")
+    with pytest.raises(ValueError):
+        with st:
+            raise ValueError("inside")
+    assert st.t1 >= st.t0
